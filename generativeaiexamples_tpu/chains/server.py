@@ -1049,6 +1049,10 @@ def main(argv: Optional[list[str]] = None) -> None:
     if pid_path:
         logger.info("pid file: %s", pid_path)
 
+    # The example may build an on-device encoder (embeddings.model_engine
+    # tpu-jax): its programs go to the one persistent compile cache.
+    from ..utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     example_cls = discover_example(args.example)
     example = example_cls()
     web.run_app(create_app(example, args.upload_dir),

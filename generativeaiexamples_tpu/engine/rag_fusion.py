@@ -5,9 +5,9 @@ The reference's QA chatbot crosses three process boundaries on its hot
 path — embed (GPU), Milvus search (gRPC), Triton prefill (gRPC)
 (reference: RetrievalAugmentedGeneration/common/server.py:121-142 and
 examples/developer_rag/chains.py:101-127). The host round trips between
-them are pure latency; on a remote-attached TPU each blocking
-device<->host sync costs tens of milliseconds, so a chatbot TTFT pays
-them twice (embedding readback, then first-token readback).
+them are pure latency: each blocking device<->host sync is a round
+trip, and a chatbot TTFT pays them twice (embedding readback, then
+first-token readback).
 
 TPU-native answer: keep the corpus ON the device and compile the chain
 itself into the admission program —

@@ -1,9 +1,8 @@
 """Token-budget continuous scheduler: chunked-prefill / decode interleaving.
 
-The prefill wall (BENCH_r05): e2e chat TTFT is 180 ms of which
-``engine_first_readback`` is 173 ms — prefill IS the TTFT budget, and the
-engine's former run-prefill-to-completion admission let one long prompt
-monopolize the serve loop while every occupied decode slot starved. The
+The prefill wall: prefill IS the TTFT budget, and the engine's former
+run-prefill-to-completion admission let one long prompt monopolize the
+serve loop while every occupied decode slot starved. The
 cure is the Sarathi/Orca recipe adapted to this engine's multi-step
 rounds: plan each engine round as a MIX of decode steps for armed slots
 plus prefill *chunks* for admitted requests, sized so the whole round
@@ -40,12 +39,15 @@ from __future__ import annotations
 
 import glob
 import json
+import logging
 import math
 import os
 import re
 import threading
 from dataclasses import dataclass, field, replace
 from typing import Optional, Sequence
+
+logger = logging.getLogger(__name__)
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__))))
@@ -154,12 +156,19 @@ class StepCostModel:
 
     @classmethod
     def load(cls, path: Optional[str] = None,
-             topology: Optional[str] = None) -> "StepCostModel":
+             topology: Optional[str] = None,
+             platform: Optional[str] = None) -> "StepCostModel":
         """Resolve the deployment's cost model: explicit ``path``, else
         ``SCHED_PROFILE_JSON``, else the newest committed
         ``PROFILE_rNN.json`` at the repo root, else defaults. A missing
-        or malformed artifact degrades silently to defaults — the
-        scheduler must never keep an engine from building.
+        or malformed artifact is skipped with a WARNING — the scheduler
+        must never keep an engine from building, but it says so.
+
+        ``platform``: the engine's device platform (``"tpu"``,
+        ``"cpu"``). An artifact that records a different ``platform``
+        was timed on another kind of device and is skipped: a rate
+        measured elsewhere is not a prior, and the built-in defaults
+        plus the online calibrator start closer than it does.
 
         ``topology``: the engine's mesh label (:func:`topology_key`).
         Precedence per docs/scheduler.md: an artifact whose own label or
@@ -191,6 +200,12 @@ class StepCostModel:
             try:
                 with open(cand) as f:
                     profile = json.load(f)
+                measured_on = profile.get("platform")
+                if platform and measured_on and measured_on != platform:
+                    logger.info(
+                        "cost artifact %s skipped: measured on %s, "
+                        "engine runs on %s", cand, measured_on, platform)
+                    continue
                 model = cls._from_artifact(profile, topology,
                                            os.path.basename(cand))
                 if model is not None:
@@ -199,7 +214,9 @@ class StepCostModel:
                     fallback = cls.from_profile(
                         profile, source=os.path.basename(cand))
             except (OSError, ValueError, TypeError, AttributeError,
-                    KeyError):
+                    KeyError) as exc:
+                logger.warning("cost artifact %s unusable (%s: %s)",
+                               cand, type(exc).__name__, exc)
                 continue
         return fallback if fallback is not None else cls()
 

@@ -66,10 +66,7 @@ def _use_paged_kernel(cfg: LlamaConfig, page: int) -> bool:
                           cfg.head_dim)
     if flag == "1":
         return ok
-    try:
-        return ok and jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return ok and jax.default_backend() == "tpu"
 
 Params = dict[str, Any]
 KVCache = dict[str, jax.Array]  # {"k": (L,B,T,KV,hd), "v": (L,B,T,KV,hd)}
@@ -283,7 +280,6 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
                     interpret=interp)
 
         if mesh is not None and "tp" in mesh.shape:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             kv_spec = P(None, None, "tp", None, None)
             sc_spec = P(None, None, "tp", None)
@@ -298,9 +294,9 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
                 in_specs = ((P(None, "tp", None), kv_spec, kv_spec)
                             + head_specs + (P(), P(), P(), P(), P()))
                 out_specs = (P(None, "tp", None), kv_spec, kv_spec)
-            call_kernel = shard_map(
+            call_kernel = jax.shard_map(
                 call_kernel, mesh=mesh, in_specs=in_specs,
-                out_specs=out_specs, check_rep=False)
+                out_specs=out_specs, check_vma=False)
 
         def layer_k(carry, lp):
             if quant:
@@ -1012,7 +1008,6 @@ def apply_sp(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     tokens/positions: (B, S) with S divisible by sp. Returns logits
     (B, S, V) float32, sharded (dp, sp) like the inputs.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.ring_attention import ring_gqa_attention
@@ -1038,10 +1033,10 @@ def apply_sp(params: Params, cfg: LlamaConfig, tokens: jax.Array,
         return unembed(params_l, cfg, h)
 
     seq_spec = P(dp, "sp")
-    return shard_map(fwd, mesh=mesh,
-                     in_specs=(seq_spec, seq_spec, P()),
-                     out_specs=P(dp, "sp", None),
-                     check_rep=False)(tokens, positions, params)
+    return jax.shard_map(fwd, mesh=mesh,
+                         in_specs=(seq_spec, seq_spec, P()),
+                         out_specs=P(dp, "sp", None),
+                         check_vma=False)(tokens, positions, params)
 
 
 def validate_sp_mesh(mesh, S: int, fn_name: str = "sp") -> int:
@@ -1087,7 +1082,6 @@ def apply_prefill_sp(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     consumes them without a host round trip — and last_logits (B, V)
     replicated.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.ring_attention import ring_gqa_attention
@@ -1125,12 +1119,12 @@ def apply_prefill_sp(params: Params, cfg: LlamaConfig, tokens: jax.Array,
         return ks, vs, h_last
 
     seq_spec = P(dp, "sp")
-    k, v, h_last = shard_map(
+    k, v, h_last = jax.shard_map(
         fwd, mesh=mesh,
         in_specs=(seq_spec, seq_spec, P(dp), P()),
         out_specs=(P(None, dp, "sp", None, None),
                    P(None, dp, "sp", None, None), P(dp, None)),
-        check_rep=False)(tokens, positions, length, params)
+        check_vma=False)(tokens, positions, length, params)
     logits = unembed(params, cfg, h_last[:, None])[:, 0]   # (B, V)
     return k, v, logits
 

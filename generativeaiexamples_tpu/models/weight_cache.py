@@ -7,10 +7,9 @@ stack's conversion is cheaper than an engine build but still real work —
 torch-format parsing, key mapping, transpose/stack, quantization — and
 it runs on every server start. This module is the SURVEY §5 "orbax-style
 sharded weight cache": the CONVERTED (and, when requested, quantized)
-parameter tree saved once in orbax's on-disk format, keyed by the same
-identity string the XLA compile cache uses (model name + dtype + quant +
-checkpoint content hash), so a restart loads arrays straight from disk
-and skips conversion entirely.
+parameter tree saved once in orbax's on-disk format, keyed by model
+name + dtype + quant + checkpoint content hash, so a restart loads
+arrays straight from disk and skips conversion entirely.
 
 Layout: ``$GAIE_WEIGHT_CACHE_DIR (default ~/.cache/generativeaiexamples_tpu/
 weights)/<identity>/tree``. Disable with ``GAIE_WEIGHT_CACHE=0``.

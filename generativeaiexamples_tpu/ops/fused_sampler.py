@@ -462,7 +462,6 @@ def fused_unembed_sample_tp(mesh, axis: str, head_tree, head_specs,
     the single-chip stream and the materialized oracle. The returned
     (B,) tokens are replicated on every chip — harvest-safe by
     construction."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_shards, v_local, tile = _shard_geometry(mesh, axis, vocab_size,
@@ -508,8 +507,8 @@ def fused_unembed_sample_tp(mesh, axis: str, head_tree, head_specs,
     args = (head_tree, hn, temp, top_k, top_p, rep_pen, seen_words,
             banned_words) + ((ban_tok, ban_hit) if has_ban else ())
     in_specs = (head_specs,) + (P(),) * (len(args) - 1)
-    return shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=P(), check_rep=False)(*args)
+    return jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(), check_vma=False)(*args)
 
 
 def fused_verify_sample(tile_logits_fn, vocab_size: int, *, key, u, temp,
@@ -586,7 +585,6 @@ def fused_verify_sample_tp(mesh, axis: str, head_tree, head_specs,
     :func:`fused_unembed_sample_tp`; the draft token's scaled logit
     lives on exactly one shard, so its gather is a ``psum`` over zeros
     elsewhere. Verdicts come back replicated on every chip."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_shards, v_local, tile = _shard_geometry(mesh, axis, vocab_size,
@@ -634,8 +632,8 @@ def fused_verify_sample_tp(mesh, axis: str, head_tree, head_specs,
             banned_words, draft_ids) + ((ban_tok, ban_hit) if has_ban
                                         else ())
     in_specs = (head_specs,) + (P(),) * (len(args) - 1)
-    return shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=(P(), P()), check_rep=False)(*args)
+    return jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=(P(), P()), check_vma=False)(*args)
 
 
 def verify_reference_tiled(logits, key, u, temp, top_k, top_p, draft_ids,

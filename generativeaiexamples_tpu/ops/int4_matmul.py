@@ -205,11 +205,7 @@ def int4_matmul(x: jax.Array, q4: jax.Array, scale: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        # CompilerParams was TPUCompilerParams before jax 0.4.34-ish;
-        # resolve whichever this runtime ships so the kernel (and its
-        # interpret-mode tests) work across the supported range.
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xe, xo, q4, s_arg)

@@ -127,9 +127,8 @@ def _use_int4_kernel(w: QTensor) -> bool:
     """Packed-int4 Pallas matmul gate: TPU backend, 2D weight, kernel-
     supported geometry (ops/int4_matmul.py). The XLA path must unpack
     the nibbles to a full int8 tensor inside the decode scan — 5x the
-    int4 HBM bytes per step (measured r5: 72 vs 504 tok/s on 7B) — so
-    the kernel is the difference between int4 being a capacity+speed win
-    and a capacity-only trade."""
+    int4 HBM bytes per step — so the kernel is the difference between
+    int4 being a capacity+speed win and a capacity-only trade."""
     if os.environ.get("GENAI_TPU_INT4_KERNEL", "1") == "0":
         return False
     q4 = w["q4"]
@@ -139,11 +138,8 @@ def _use_int4_kernel(w: QTensor) -> bool:
     gs = 0
     if is_grouped(w):
         gs = (2 * q4.shape[0]) // w["gscale"].shape[-2]
-    try:
-        return (jax.default_backend() == "tpu"
-                and supported(2 * q4.shape[0], q4.shape[1], group_size=gs))
-    except Exception:  # noqa: BLE001 — no backend yet
-        return False
+    return (jax.default_backend() == "tpu"
+            and supported(2 * q4.shape[0], q4.shape[1], group_size=gs))
 
 
 def matmul(x: jax.Array, w: Union[jax.Array, QTensor]) -> jax.Array:

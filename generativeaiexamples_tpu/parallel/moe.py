@@ -116,8 +116,7 @@ def ep_expert_ffn(mesh: Mesh, expert_in: jax.Array, w_gate: jax.Array,
         out = _expert_ffn(ei, g, u, d)
         return jax.lax.psum(out, "tp")
 
-    from .compat import shard_map
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("ep", None, None), P("ep", None, "tp"),
                   P("ep", None, "tp"), P("ep", "tp", None)),

@@ -28,7 +28,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..models import llama
 from ..models.configs import LlamaConfig
 from ..utils.errors import ShardingError
-from .compat import pcast, shard_map
 
 
 def pipeline_forward(mesh: Mesh, params: llama.Params, cfg: LlamaConfig,
@@ -86,9 +85,10 @@ def pipeline_forward(mesh: Mesh, params: llama.Params, cfg: LlamaConfig,
 
         # carries become device-varying after axis_index/ppermute; mark the
         # initial values as varying over pp so the scan types line up
-        recv0 = pcast(jnp.zeros((mb, S, cfg.hidden_size), embed.dtype),
-                      ("pp",), to="varying")
-        outbuf0 = pcast(
+        recv0 = jax.lax.pcast(
+            jnp.zeros((mb, S, cfg.hidden_size), embed.dtype),
+            ("pp",), to="varying")
+        outbuf0 = jax.lax.pcast(
             jnp.zeros((B, S, cfg.hidden_size), embed.dtype),
             ("pp",), to="varying")
         (_, outbuf), _ = jax.lax.scan(
@@ -103,7 +103,7 @@ def pipeline_forward(mesh: Mesh, params: llama.Params, cfg: LlamaConfig,
         # unpipelined forward's default
         kv_valid_len = jnp.full((B,), S, jnp.int32)
     layer_specs = jax.tree.map(lambda _: P("pp"), params["layers"])
-    hidden = shard_map(
+    hidden = jax.shard_map(
         stage_fn, mesh=mesh,
         in_specs=(layer_specs, P(), P(), P(), P()),
         out_specs=P())(

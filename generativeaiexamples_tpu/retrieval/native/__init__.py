@@ -1,8 +1,11 @@
 """ctypes loader for the native top-k kernels, compiled on demand.
 
-First call compiles ``topk.cpp`` with g++ (OpenMP) into a cached shared
-library next to this file; if no toolchain is available the callers fall
-back to numpy transparently. This is the framework's own native-code answer
+First call compiles ``topk.cpp`` with g++ (OpenMP) into a shared library
+next to this file, named by the SOURCE'S CONTENT HASH — so a binary built
+from other source (a stale one, or one copied in with the tree) is never
+loaded, whatever its mtime says. If no toolchain is available the callers
+fall back to numpy, logged once at WARNING. This is the framework's own
+native-code answer
 to the reference's FAISS / knowhere C++ search engines
 (reference: common/utils.py:181-198).
 """
@@ -10,6 +13,7 @@ to the reference's FAISS / knowhere C++ search engines
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -22,7 +26,9 @@ logger = logging.getLogger(__name__)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "topk.cpp")
-_LIB = os.path.join(_HERE, "libgaietopk.so")
+with open(_SRC, "rb") as _f:
+    _LIB = os.path.join(
+        _HERE, f"libgaietopk-{hashlib.sha256(_f.read()).hexdigest()[:12]}.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -35,13 +41,18 @@ _u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
 
 
 def _compile() -> bool:
+    # Build under a private name, then rename into place: a concurrent
+    # process never loads a half-written library.
+    tmp = f"{_LIB}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-fopenmp", "-shared", "-fPIC", "-std=c++17",
-           _SRC, "-o", _LIB]
+           _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _LIB)
         return True
     except (OSError, subprocess.SubprocessError) as exc:
-        logger.info("native topk unavailable (%s); using numpy fallback", exc)
+        logger.warning(
+            "native topk unavailable (%s); using the numpy fallback", exc)
         return False
 
 
@@ -52,21 +63,20 @@ def load() -> Optional[ctypes.CDLL]:
         if _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_LIB) or (
-                os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
-            if not _compile():
-                return None
+        if not os.path.exists(_LIB) and not _compile():
+            return None
         try:
             lib = ctypes.CDLL(_LIB)
         except OSError:
-            # Stale/foreign-arch binary (e.g. copied between hosts):
+            # Right source, foreign architecture (copied between hosts):
             # rebuild once before giving up.
             if not _compile():
                 return None
             try:
                 lib = ctypes.CDLL(_LIB)
             except OSError as exc:
-                logger.info("native topk load failed: %s", exc)
+                logger.warning("native topk load failed (%s); using the "
+                               "numpy fallback", exc)
                 return None
         lib.gaie_brute_topk.argtypes = [
             _f32p, ctypes.c_void_p, ctypes.c_void_p, _i64, _i64,

@@ -33,7 +33,7 @@ Two pieces:
   ``ROUTER_SLO_WINDOW_S`` age out of the rates, so a past incident stops
   dragging attainment once the window turns over.
 
-Outcome taxonomy (one row per terminal outcome, plus one per failed
+Outcome classes (one row per terminal outcome, plus one per failed
 connect attempt — attempt rows are attributed to the replica that
 failed, which is what makes a partitioned replica's attainment drop
 while its healthy siblings', and the fleet totals, stay consistent):

@@ -44,7 +44,7 @@ Failure semantics (the part routers get wrong):
   records the failure and it is marked unreachable so the NEXT request
   places elsewhere at once.
 - Any other upstream HTTP status is relayed as-is — the replica's 429 /
-  503 / 504 taxonomy (docs/robustness.md) already says the right thing;
+  503 / 504 classification (docs/robustness.md) already says the right thing;
   the router adds only ``503 no_replicas`` (nothing placeable) and
   ``502 replica_error`` (retry budget exhausted).
 
@@ -767,7 +767,7 @@ class FleetRouter:
             rep.breaker.record_success()
             if upstream.status >= 400:
                 # 503/504 are backpressure/deadline sheds in the replica
-                # taxonomy (docs/robustness.md); everything else relayed
+                # classification (docs/robustness.md); everything else relayed
                 # at >= 400 is an error outcome.
                 self.flight.complete_request(
                     tl, outcome=("shed" if upstream.status in (503, 504)

@@ -6,7 +6,9 @@ once."""
 
 from __future__ import annotations
 
-# Peak HBM bandwidth (bytes/s) by TPU generation, public spec numbers.
+# Peak HBM bandwidth (bytes/s), matched as a substring of the lower-cased
+# ``device_kind`` JAX reports. Source: Google Cloud TPU documentation,
+# the system-architecture page of each generation ("TPU v5e": 819 GB/s).
 PEAK_HBM_BW = {
     "v4": 1.2e12,
     "v5 lite": 819e9, "v5e": 819e9,
@@ -16,9 +18,13 @@ PEAK_HBM_BW = {
 
 
 def peak_bw(device) -> float:
-    """Peak HBM bytes/s for a jax device (assumes v5e when unknown)."""
+    """Peak HBM bytes/s for a jax device. A kind the table does not list
+    raises: a roofline share against another chip's peak is not a
+    number."""
     kind = getattr(device, "device_kind", "").lower()
     for key, bw in PEAK_HBM_BW.items():
         if key in kind:
             return bw
-    return 819e9
+    raise ValueError(
+        f"no peak HBM bandwidth for device kind {kind!r}; add it to "
+        f"PEAK_HBM_BW (utils/hbm.py) with its source")

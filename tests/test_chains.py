@@ -235,7 +235,7 @@ def test_server_upload_generate_search(tmp_path):
 def test_server_pre_stream_error_is_real_http_status():
     """A failure BEFORE the first generated chunk is a real 500 with a
     JSON body + X-Request-ID — not a 200 SSE carrying '[error]' text
-    (docs/robustness.md error taxonomy)."""
+    (docs/robustness.md error classes)."""
     class BrokenExample(BaseExample):
         def llm_chain(self, context, question, num_tokens):
             raise RuntimeError("boom")

@@ -1,0 +1,350 @@
+"""Compile the serving hot path for a TPU v5e that is DESCRIBED, not
+attached (on-chip-measurement guide §2.3): the chip's own compiler runs
+here on the CPU sandbox and raises what it would raise on the machine —
+misaligned slices, too much VMEM, programs that do not fit 16 GB,
+kernels that cannot be partitioned. Nothing executes, so these tests say
+nothing about results or times; they guard every later PR's kernels and
+step programs at llama-2-7b width (depth cut to 2 layers so each compile
+stays seconds) at no chip time.
+
+Code that asks ``jax.default_backend()`` still sees the CPU here, so the
+tests steer it (``tpu_backend`` fixture) — otherwise the model would
+embed the INTERPRETED kernel, or skip it.
+"""
+
+import dataclasses
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
+from jax.sharding import (NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from generativeaiexamples_tpu.models import llama
+from generativeaiexamples_tpu.models.configs import get_model_config
+from generativeaiexamples_tpu.ops.quant import quantize_params
+
+PAGE = 128
+CFG = dataclasses.replace(get_model_config("llama-2-7b-chat"), num_layers=2)
+HBM_BYTES = 16 << 30    # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"TPU topology cannot be described here: {exc}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_compile_cache():
+    """A described-topology compile can be written to the persistent
+    cache but never read back without a chip (the next run warns and
+    recompiles) — keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def tpu_backend(monkeypatch):
+    """Steer the kernel gates (models/llama.py, ops/quant.py) onto their
+    TPU branch: compiled Pallas, not interpret mode."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def on(tree, sharding):
+    """Shapes of ``tree`` placed on ``sharding``."""
+    return jax.tree.map(lambda x: sds(x.shape, x.dtype, sharding), tree)
+
+
+def param_shapes(cfg, quant="int8"):
+    def make(key):
+        return quantize_params(
+            llama.init_params(cfg, key, dtype=jnp.bfloat16), quant)
+    return jax.eval_shape(make, jax.random.key(0))
+
+
+def assert_fits(compiled, budget=HBM_BYTES):
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert total < budget, (total, m)
+    return total
+
+
+# ------------------------------------------------------------- kernels
+
+
+@pytest.mark.parametrize("batch,heads,kv_heads,kv_int8", [
+    (8, 32, 32, False), (64, 32, 32, False),     # llama-2-7b, bf16 KV
+    (8, 32, 32, True), (64, 32, 32, True),       # llama-2-7b, int8 KV
+    (8, 40, 40, False),                          # llama-2-13b
+    (8, 64, 8, False),                           # GQA, 8 queries per KV head
+    (8, 8, 8, False),                            # the 7B shard at tp=4
+    (1, 8, 8, False),                            # one slot (smoke tp4 step)
+], ids=["7b-b8", "7b-b64", "7b-int8kv-b8", "7b-int8kv-b64", "13b", "gqa8",
+        "tp4-shard", "tp4-shard-b1"])
+def test_paged_attention_kernel_compiles(topo, batch, heads, kv_heads,
+                                         kv_int8):
+    from generativeaiexamples_tpu.ops.paged_attention import (
+        paged_attention_decode)
+    dev = SingleDeviceSharding(topo.devices[0])
+    H, KV, hd = heads, kv_heads, CFG.head_dim
+    L, N, W = 2, batch * 4 + 1, 8
+    pool_dt = jnp.int8 if kv_int8 else jnp.bfloat16
+    pool = sds((L, N, KV, PAGE, hd), pool_dt, dev)
+    scales = sds((L, N, KV, PAGE), jnp.bfloat16, dev)
+    i32 = lambda *shape: sds(shape, jnp.int32, dev)  # noqa: E731
+    cur = sds((batch, KV, hd), jnp.bfloat16, dev)
+
+    def step(q, pk, pv, ks, vs, tbl, lens, ck, cv, wp, off, li):
+        extra = dict(pool_ks=ks, pool_vs=vs) if kv_int8 else {}
+        return paged_attention_decode(q, pk, pv, tbl, lens, ck, cv, wp,
+                                      off, li, **extra)
+
+    compiled = jax.jit(step).lower(
+        sds((batch, H, hd), jnp.bfloat16, dev), pool, pool, scales, scales,
+        i32(batch, W), i32(batch), cur, cur, i32(batch), i32(batch),
+        i32(1)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("group", [0, 128], ids=["perchannel", "g128"])
+@pytest.mark.parametrize("K,N,M", [
+    (4096, 11008, 8),           # gate/up projection, decode rows
+    (11008, 4096, 8),           # down projection
+    (4096, 4096, 8),            # attention projections
+    (4096, 4096, 512),          # ... at prefill rows
+    (4096, 11008, 512),
+    (4096, 32000, 8),           # lm_head
+    (5120, 13824, 8),           # llama-2-13b / codellama-13b widths
+    (13824, 5120, 8),
+    (5120, 5120, 8),
+], ids=["gate", "down", "attn", "attn-prefill", "gate-prefill", "head",
+        "13b-gate", "13b-down", "13b-attn"])
+def test_int4_matmul_kernel_compiles(topo, K, N, group, M):
+    from generativeaiexamples_tpu.ops.int4_matmul import (int4_matmul,
+                                                          supported)
+    assert supported(K, N, group_size=group)
+    dev = SingleDeviceSharding(topo.devices[0])
+    scale = (sds((K // group, N), jnp.bfloat16, dev) if group
+             else sds((N,), jnp.bfloat16, dev))
+    compiled = int4_matmul.lower(
+        sds((M, K), jnp.bfloat16, dev), sds((K // 2, N), jnp.int8, dev),
+        scale).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("greedy", [True, False], ids=["greedy", "sampled"])
+def test_fused_sampler_tail_256k_vocab_compiles(topo, greedy):
+    """The vocab-tiled unembed+sampling tail at nemotron-8b-chat's 256k
+    vocabulary (ROADMAP R0's first dense cell): (rows, V) logits must
+    never materialize — 8 rows x 256k x f32 is small, but the same tail
+    runs ba*S verify rows, and the tile stream is the design."""
+    from generativeaiexamples_tpu.ops.fused_sampler import (
+        fused_unembed_sample)
+    from generativeaiexamples_tpu.ops.sampling import mask_words
+    cfg = dataclasses.replace(get_model_config("nemotron-8b-chat"),
+                              num_layers=1)
+    dev = SingleDeviceSharding(topo.devices[0])
+    params = on(param_shapes(cfg), dev)
+    B, V = 8, cfg.vocab_size
+    assert V == 256000
+    words = sds((B, mask_words(V)), jnp.uint32, dev)
+    f32 = sds((B,), jnp.float32, dev)
+
+    def tail(params, hn, key, temp, top_k, top_p, rep_pen, seen, banned):
+        return fused_unembed_sample(
+            lambda t0, tile: llama.lm_head_tile(params, cfg, hn, t0, tile),
+            V, key=key, temp=temp, top_k=top_k, top_p=top_p,
+            rep_pen=rep_pen, seen_words=seen, banned_words=banned,
+            greedy=greedy)
+
+    compiled = jax.jit(tail).lower(
+        params, sds((B, cfg.hidden_size), jnp.bfloat16, dev),
+        jax.eval_shape(lambda: jax.random.key(0)), f32,
+        sds((B,), jnp.int32, dev), f32, f32, words, words).compile()
+    # temp excludes the weights: a materialized (B, V) f32 alone is 8 MB,
+    # a few live copies of it (sort, mask, noise) would show here.
+    assert compiled.memory_analysis().temp_size_in_bytes < (32 << 20)
+
+
+# ------------------------------------------------- engine step programs
+
+
+@pytest.fixture(scope="module")
+def engine(topo):
+    """A real Engine at llama-2-7b width / 2 layers / int8 weights whose
+    jitted programs are LOWERED for the described chip. It lives on the
+    CPU (zero weights; nothing runs), with ``jax.default_backend``
+    steered so the kernel path and row-major layout pins are armed the
+    way they are on the chip."""
+    from generativeaiexamples_tpu.engine import Engine, EngineConfig
+    from generativeaiexamples_tpu.models.tokenizer import ByteTokenizer
+    mp = pytest.MonkeyPatch()
+    mp.setattr(jax, "default_backend", lambda: "tpu")
+    params = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                          param_shapes(CFG))
+    eng = Engine(params, CFG, ByteTokenizer(), EngineConfig(
+        max_slots=8, max_input_length=2048, max_output_length=128,
+        prefill_buckets=(512, 1024), max_prefill_bucket=1024,
+        kv_pool_tokens=8 * 1024, steps_per_round=8))
+    assert eng._use_kernel and eng._fused_tail and not eng.downgrades
+    yield eng
+    mp.undo()
+
+
+def engine_args(eng, topo):
+    """(params, state) shapes placed on the described chip, the pool in
+    the engine's pinned row-major layout."""
+    dev = SingleDeviceSharding(topo.devices[0])
+    state = on({k: v for k, v in eng._state.items() if k != "cache"}, dev)
+    state["cache"] = {
+        k: sds(v.shape, v.dtype, Format(
+            Layout(major_to_minor=tuple(range(v.ndim))), dev))
+        for k, v in eng._state["cache"].items()}
+    return on(eng.params, dev), state, dev
+
+
+def test_prefill_bucket_program_compiles(engine, topo, tpu_backend):
+    """Fused prefill + first-token sample + page scatter, S=1024."""
+    params, state, dev = engine_args(engine, topo)
+    S = 1024
+    i32 = sds((), jnp.int32, dev)
+    f32 = sds((), jnp.float32, dev)
+    from generativeaiexamples_tpu.ops.sampling import mask_words
+    compiled = engine._prefill_insert.lower(
+        state, params, sds((1, S), jnp.int32, dev), i32, i32,
+        sds((engine._pmax,), jnp.int32, dev), f32, i32, f32, f32,
+        sds((mask_words(CFG.vocab_size),), jnp.uint32, dev),
+        sds((engine.MAX_BAD_SEQS, engine.MAX_BAD_LEN), jnp.int32, dev),
+        sds((engine.MAX_BAD_SEQS,), jnp.int32, dev),
+        jax.eval_shape(lambda: jax.random.key(0)), i32,
+        sds((), jnp.bool_, dev), True).compile()
+    assert_fits(compiled)
+
+
+def test_decode_round_program_compiles(engine, topo, tpu_backend):
+    """8 fused decode steps: Pallas paged attention with the pool aliased
+    through the scan carry, then the fused vocab-tiled sampling tail."""
+    params, state, dev = engine_args(engine, topo)
+    B = engine.cfg.max_slots
+    fn = engine._round_fn(engine._pmax, 8, True, B)
+    compiled = fn.lower(params, state,
+                        jax.eval_shape(lambda: jax.random.key(0)),
+                        sds((B,), jnp.int32, dev)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert_fits(compiled)
+    # the pool is donated and aliased in place: no second pool in temps
+    pool_bytes = sum(v.nbytes for v in engine._state["cache"].values())
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
+def test_chunked_prefill_program_compiles(engine, topo, tpu_backend):
+    """One non-final chunk of the paged (chunked) prefill admission."""
+    params, state, dev = engine_args(engine, topo)
+    window = engine._windows[-1]
+    fn = engine._chunk_extend_fn(window, "accum")
+    i32 = sds((), jnp.int32, dev)
+    compiled = fn.lower(state, params, sds((1, 512), jnp.int32, dev),
+                        i32, i32, i32,
+                        sds((1, window), jnp.int32, dev)).compile()
+    assert_fits(compiled)
+
+
+def test_decode_step_kernel_path_compiles(topo, tpu_backend):
+    """The bare model step the engine's round scans: 2 layers at 7B
+    width, int8 weights, ``use_kernel=True`` -> one Pallas call."""
+    dev = SingleDeviceSharding(topo.devices[0])
+    B, W, N = 8, 8, 65
+    pool = sds((2, N, CFG.num_kv_heads, PAGE, CFG.head_dim),
+               jnp.bfloat16, dev)
+    i32 = lambda *shape: sds(shape, jnp.int32, dev)  # noqa: E731
+
+    def step(params, tok, pos, cache, tbl, valid, wp, off):
+        return llama.apply_decode_paged(params, CFG, tok, pos, cache, tbl,
+                                        valid, wp, off, use_kernel=True)
+
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        on(param_shapes(CFG), dev), i32(B, 1), i32(B, 1),
+        {"k": pool, "v": pool}, i32(B, W), i32(B), i32(B),
+        i32(B)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert_fits(compiled)
+
+
+def test_tp4_decode_step_compiles(topo, tpu_backend, monkeypatch):
+    """The same step on the described 2x2 mesh at tp=4: params and pool
+    sharded as the engine shards them, the kernel under ``shard_map``,
+    then the tp-sharded fused sampling tail. Each device must hold about
+    a quarter of the sharded bytes."""
+    from generativeaiexamples_tpu.ops.fused_sampler import (
+        fused_unembed_sample_tp)
+    from generativeaiexamples_tpu.ops.sampling import mask_words
+    from generativeaiexamples_tpu.parallel.mesh import MeshPlan, make_mesh
+    from generativeaiexamples_tpu.parallel.sharding import (
+        llama_param_specs, paged_kv_cache_spec, shard_params)
+    mesh = make_mesh(MeshPlan(tp=4), topo.devices)
+    shapes = param_shapes(CFG)
+    # shard_params' own spec derivation (QTensor leaves included), with
+    # placement swapped for shape annotation
+    with monkeypatch.context() as mp:
+        mp.setattr(jax, "device_put",
+                   lambda x, s: sds(x.shape, x.dtype, s))
+        params = shard_params(shapes, mesh, llama_param_specs(CFG, mesh))
+    rep = NamedSharding(mesh, P())
+    B, W, N, V = 8, 8, 65, CFG.vocab_size
+    kv_spec = paged_kv_cache_spec(CFG, mesh)["k"]
+    pool = sds((2, N, CFG.num_kv_heads, PAGE, CFG.head_dim), jnp.bfloat16,
+               NamedSharding(mesh, kv_spec))
+    i32 = lambda *shape: sds(shape, jnp.int32, rep)  # noqa: E731
+    f32 = sds((B,), jnp.float32, rep)
+    words = sds((B, mask_words(V)), jnp.uint32, rep)
+    head_specs = llama.lm_head_specs(shapes, mesh)
+
+    def step(params, tok, pos, cache, tbl, valid, wp, off, key, temp,
+             top_k, top_p, rep_pen, seen, banned):
+        h, cache = llama.apply_decode_paged(
+            params, CFG, tok, pos, cache, tbl, valid, wp, off,
+            use_kernel=True, mesh=mesh, return_hidden=True)
+        hn = llama.unembed_norm(params, CFG, h[:, 0])
+        tok = fused_unembed_sample_tp(
+            mesh, "tp", llama.lm_head_subtree(params), head_specs,
+            lambda head, rows, t0, tile: llama.lm_head_tile(
+                head, CFG, rows, t0, tile),
+            V, hn=hn, key=key, temp=temp, top_k=top_k, top_p=top_p,
+            rep_pen=rep_pen, seen_words=seen, banned_words=banned,
+            greedy=True)    # the sampled stream compiles ~10 s slower
+        return tok, cache
+
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        params, i32(B, 1), i32(B, 1), {"k": pool, "v": pool}, i32(B, W),
+        i32(B), i32(B), i32(B), jax.eval_shape(lambda: jax.random.key(0)),
+        f32, i32(B), f32, f32, words, words).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-reduce" in text or "all-gather" in text
+    # memory_analysis is per device: arguments ~ a quarter of the
+    # sharded parameter + pool bytes (embed and norms replicate)
+    whole = sum(x.size * x.dtype.itemsize
+                for x in jax.tree.leaves((shapes, pool, pool)))
+    per_dev = compiled.memory_analysis().argument_size_in_bytes
+    assert per_dev < 0.45 * whole, (per_dev, whole)

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 
 from generativeaiexamples_tpu.ops.fused_sampler import (
@@ -179,9 +180,9 @@ def test_choose_tile_alignment():
 
 
 def _jaxprs_in(val):
-    if isinstance(val, jax.core.ClosedJaxpr):
+    if isinstance(val, jax.extend.core.ClosedJaxpr):
         yield val.jaxpr
-    elif isinstance(val, jax.core.Jaxpr):
+    elif isinstance(val, jax.extend.core.Jaxpr):
         yield val
     elif isinstance(val, (list, tuple)):
         for v in val:

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 
 from generativeaiexamples_tpu.engine import (Engine, EngineConfig,
@@ -133,9 +134,9 @@ def test_tp2_sharded_fused_vs_materialized_tail_parity(params,
 
 
 def _jaxprs_in(val):
-    if isinstance(val, jax.core.ClosedJaxpr):
+    if isinstance(val, jax.extend.core.ClosedJaxpr):
         yield val.jaxpr
-    elif isinstance(val, jax.core.Jaxpr):
+    elif isinstance(val, jax.extend.core.Jaxpr):
         yield val
     elif isinstance(val, (list, tuple)):
         for v in val:

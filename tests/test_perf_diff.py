@@ -208,14 +208,15 @@ def test_cli_exit_codes(tmp_path, capsys):
 
 
 @pytest.mark.parametrize("pair,expect", [
-    (("BENCH_r04.json", "BENCH_r05.json"), 0),   # r05 did not regress r04
-    (("BENCH_r01.json", "BENCH_r05.json"), 0),   # the whole trajectory
+    (("bench_round_b.json", "bench_round_c.json"), 0),   # c did not regress b
+    (("bench_round_a.json", "bench_round_c.json"), 0),   # the whole trajectory
 ])
 def test_committed_artifacts_gate(pair, expect):
-    """Tier-1 over the committed round artifacts: the recorded perf
-    trajectory is monotone enough that each later round passes the gate
-    against the earlier one (p99 wobble gets a wider threshold — single
-    -digit-sample tail percentiles jitter between runs)."""
-    base, new = (os.path.join(REPO, p) for p in pair)
+    """Tier-1 over committed driver-shaped artifacts (synthetic fixtures
+    — the repo holds no chip record yet): each later round passes the
+    gate against the earlier one (p99 wobble gets a wider threshold —
+    single-digit-sample tail percentiles jitter between runs)."""
+    base, new = (os.path.join(REPO, "tests", "fixtures", "perf_gate", p)
+                 for p in pair)
     rc = main([base, new, "--threshold", "engine_p99_ttft_ms=20"])
     assert rc == expect

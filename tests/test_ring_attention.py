@@ -11,7 +11,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from generativeaiexamples_tpu.models import llama
@@ -39,13 +38,13 @@ def test_ring_matches_dense_attention(cpu_devices, causal):
     mesh = make_mesh(MeshPlan(sp=8), cpu_devices[:8])
     q, k, v, pos = _qkv(jax.random.key(0))
 
-    ring = shard_map(
+    ring = jax.shard_map(
         lambda q, k, v, p: ring_gqa_attention(
             q, k, v, p, axis_name="sp", axis_size=8, causal=causal),
         mesh=mesh,
         in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp"),
                   P(None, "sp")),
-        out_specs=P(None, "sp"), check_rep=False)
+        out_specs=P(None, "sp"), check_vma=False)
     got = jax.jit(ring)(q, k, v, pos)
     want = gqa_attention(q, k, v, pos, causal=causal)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -57,12 +56,12 @@ def test_ring_respects_cross_shard_causality(cpu_devices):
     the tail of the sequence cannot change the head's output."""
     mesh = make_mesh(MeshPlan(sp=8), cpu_devices[:8])
     q, k, v, pos = _qkv(jax.random.key(1))
-    ring = shard_map(
+    ring = jax.shard_map(
         lambda q, k, v, p: ring_gqa_attention(
             q, k, v, p, axis_name="sp", axis_size=8),
         mesh=mesh,
         in_specs=(P(None, "sp"),) * 4,
-        out_specs=P(None, "sp"), check_rep=False)
+        out_specs=P(None, "sp"), check_vma=False)
     base = jax.jit(ring)(q, k, v, pos)
     k2 = k.at[:, 32:].add(7.0)
     v2 = v.at[:, 32:].add(-3.0)

@@ -13,7 +13,6 @@ import jax.numpy as jnp
 from generativeaiexamples_tpu.models import llama, weight_cache
 from generativeaiexamples_tpu.models.configs import LLAMA_TINY
 from generativeaiexamples_tpu.ops.quant import quantize_params
-from generativeaiexamples_tpu.parallel.compat import tree_leaves_with_path
 
 
 @pytest.fixture(autouse=True)
@@ -23,8 +22,8 @@ def cache_in_tmp(tmp_path, monkeypatch):
 
 
 def _tree_equal(a, b):
-    flat_a = tree_leaves_with_path(a)
-    flat_b = dict(tree_leaves_with_path(b))
+    flat_a = jax.tree.leaves_with_path(a)
+    flat_b = dict(jax.tree.leaves_with_path(b))
     assert len(flat_a) == len(flat_b)
     for path, leaf in flat_a:
         other = flat_b[path]
