@@ -1,0 +1,142 @@
+"""``benchmarks/run.py`` end to end: the tiny rehearsal configuration on
+the CPU prints the contract's last line; a published-width cell on
+anything but a TPU exits non-zero with no result."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(os.path.dirname(HERE))
+REHEARSAL = os.path.join(HERE, "rehearsal")
+RUN = [sys.executable, os.path.join(REPO, "benchmarks", "run.py")]
+
+
+def run(args, timeout=600):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_RUN="ignored")
+    env.pop("XLA_FLAGS", None)
+    return subprocess.run(RUN + args, cwd=REPO, env=env, timeout=timeout,
+                          capture_output=True, text=True)
+
+
+def rehearsal(cell, trace):
+    p = run(["--benchmark-json", os.path.join(REHEARSAL, "BENCHMARK.json"),
+             "--data", REHEARSAL, "--workload", cell,
+             "--seed", str(2 ** 31 + 5), "--seconds", "2",
+             "--trace", str(trace)])
+    assert p.returncode == 0, p.stderr[-3000:]
+    lines = [ln for ln in p.stdout.splitlines() if ln.strip()]
+    return [json.loads(ln) for ln in lines]
+
+
+@pytest.fixture(scope="module")
+def open_lines():
+    return rehearsal("tiny-dense.tiny-open", 0)
+
+
+@pytest.fixture(scope="module")
+def closed_traced_lines():
+    return rehearsal("tiny-dense.tiny-closed", 1)
+
+
+def test_last_line_has_exactly_the_contracts_keys(open_lines):
+    last = open_lines[-1]
+    assert set(last) == {"correct", "attempted", "failed", "metrics",
+                         "device"}
+    assert last["correct"] is True and last["failed"] == 0
+    assert last["attempted"] == 24            # 12 a second for 2 seconds
+    assert set(last["device"]) == {"platform", "kind", "count",
+                                   "memory_peak_bytes"}
+    assert last["device"]["platform"] == "cpu"
+
+
+def test_untraced_run_reports_the_cells_end_to_end_metrics(open_lines):
+    m = open_lines[-1]["metrics"]
+    assert set(m) == {"ttft_p90_ms", "tpot_p50_ms", "setup_s"}
+    for name, unit in (("ttft_p90_ms", "ms"), ("tpot_p50_ms", "ms"),
+                       ("setup_s", "s")):
+        assert m[name]["unit"] == unit and m[name]["value"] > 0
+
+
+def test_earlier_lines_say_what_the_numbers_rest_on(open_lines):
+    setup = next(ln for ln in open_lines if ln.get("phase") == "setup")
+    window = next(ln for ln in open_lines if ln.get("phase") == "window")
+    assert setup["logits_check"]["prefill_median_rel_err"] <= 0.05
+    assert setup["logits_check"]["decode_median_rel_err"] <= 0.05
+    tok = setup["engine_tokens_check"]
+    assert tok["compared"] >= 2
+    assert tok["within_tolerance"] >= tok["compared"] / 2
+    assert setup["engine"]["downgrades"] == 0
+    assert setup["warmup"]["uncovered"] == []
+    assert window["samples"]["requests"] == 24
+    assert window["samples"]["beyond_p90_ttft"] == 2
+    assert len(window["budget_tokens"]) == 2 and window["problems"] == []
+
+
+def test_traced_run_reports_per_layer_metrics_and_leaves_out_what_it_cannot_read(
+        closed_traced_lines):
+    last = closed_traced_lines[-1]
+    assert last["correct"] is True and last["attempted"] > 3
+    m = last["metrics"]
+    # counters and spans are read on any platform ...
+    # (the engine loop's dispatch time is read here under its grouped
+    # name, through the quantity's one file)
+    for name in ("tput.dispatch_ms_per_round", "compiles_in_window",
+                 "pool_pages", "decode_steps_per_round"):
+        assert name in m, name
+    assert "dispatch_ms_per_round" not in m
+    assert m["pool_pages"]["unit"] == "pages"
+    # ... a CPU trace has no device plane, so no device number is made up
+    for name in ("decode_step_ms", "device_idle_pct"):
+        assert name not in m
+    assert "busy_s" not in last["device"] and "breakdown" not in last
+    # metrics of other cells stay out
+    assert "queue_wait_p90_ms" not in m
+
+
+def test_published_width_cell_off_the_tpu_exits_nonzero_with_no_result():
+    p = run(["--workload", "nemotron-8b-chat.chat-steady", "--seed", "1",
+             "--seconds", "1", "--trace", "0"], timeout=300)
+    assert p.returncode != 0
+    assert "needs a TPU" in p.stderr
+    assert not [ln for ln in p.stdout.splitlines()
+                if ln.startswith("{") and '"metrics"' in ln]
+
+
+def test_unknown_cell_exits_nonzero():
+    p = run(["--workload", "no-such.cell", "--seed", "1", "--seconds", "1",
+             "--trace", "0"], timeout=120)
+    assert p.returncode != 0 and '"metrics"' not in p.stdout
+
+
+def sensitivity(args, timeout=600):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    return subprocess.run(
+        [sys.executable, os.path.join(REPO, "benchmarks",
+                                      "check_sensitivity.py")] + args,
+        cwd=REPO, env=env, timeout=timeout, capture_output=True, text=True)
+
+
+def test_check_sensitivity_runs_the_check_plain_and_with_the_int8_fault():
+    """The second run really differs: the int8 pool moves the decode
+    steps and leaves the one-shot prefill, which never reads the pool,
+    where it was."""
+    p = sensitivity(["--benchmark-json",
+                     os.path.join(REHEARSAL, "BENCHMARK.json"),
+                     "--config", "tiny-dense", "--seed", str(2 ** 31 + 5)])
+    lines = [json.loads(ln) for ln in p.stdout.splitlines() if ln.strip()]
+    assert [ln["kv_int8_fault"] for ln in lines] == [False, True]
+    plain, fault = (ln["readings"] for ln in lines)
+    assert lines[0]["passed"] is True and p.returncode == 0
+    assert fault["prefill_median_rel_err"] == plain["prefill_median_rel_err"]
+    assert fault["decode_median_rel_err"] != plain["decode_median_rel_err"]
+
+
+def test_check_sensitivity_off_the_tpu_exits_nonzero_at_published_widths():
+    p = sensitivity(["--config", "nemotron-8b-chat"], timeout=120)
+    assert p.returncode == 2 and "needs a TPU" in p.stderr
+    assert p.stdout.strip() == ""
