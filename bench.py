@@ -2339,8 +2339,8 @@ def run_e2e_bench(engine, embedder, n_requests: int):
     request and looks its completed timeline up afterwards — the same
     path an operator debugging one slow production request takes via
     /debug/requests, so the bench exercises (and validates) the
-    recorder itself instead of the former process-global
-    set_stage_collector hook. Process-GLOBAL pipeline stages
+    recorder itself instead of a process-global stage hook.
+    Process-GLOBAL pipeline stages
     (harvest wait per round, loop phases) are not per-request facts and
     therefore no longer appear in this breakdown — they live in the
     artifact's ``engine_pipeline`` block (pipeline_snapshot)."""

@@ -65,7 +65,7 @@ from ..models.configs import LlamaConfig
 from ..models.tokenizer import Tokenizer
 from ..obs import flight as obs_flight
 from ..obs import rounds as obs_rounds
-from ..obs.tracing import record_stage
+from ..obs.tracing import phase, record_stage
 from ..ops.fused_sampler import (choose_tile, fused_unembed_sample,
                                  fused_unembed_sample_tp,
                                  fused_verify_sample,
@@ -76,7 +76,7 @@ from ..ops.sampling import (apply_repetition_penalty, mask_words,
                             set_token_bits, unpack_mask)
 from ..parallel.sharding import (llama_param_specs, paged_kv_cache_spec,
                                  shard_params)
-from ..utils import faults
+from ..utils import compile_cache, faults
 from ..utils.errors import (ConfigError, EngineError, RoleMismatchError,
                             SchedulerFullError)
 from ..utils.hbm import peak_bw
@@ -225,7 +225,21 @@ _STATS_TEMPLATE = {
     # the headroom model is short for this geometry and the engine
     # serves with fewer pages than the device's free memory promised.
     "pool_shrinks": 0,
+    # KV pool occupancy (gauge, set when a round begins): pages live
+    # requests hold = total - free - evictable prefix-cache pages; and
+    # dispatched rounds in which an admission the plan offered was
+    # refused for want of pages (RoundRecord.blocked_on_pages > 0).
+    "pool_used_pages": 0,
+    "pool_blocked_rounds": 0,
 }
+
+# The process's program build log (utils/compile_cache.py), read into
+# every stats snapshot: programs JAX built — compiled or loaded from the
+# persistent cache — and the seconds spent tracing, lowering, compiling
+# and loading them. Process-wide, not per engine.
+_BUILD_LOG_KEYS = ("programs_built", "program_trace_s", "program_lower_s",
+                   "program_compile_s", "program_cache_load_s",
+                   "program_cache_hits")
 
 
 def engine_stat_keys() -> tuple[str, ...]:
@@ -240,6 +254,7 @@ def engine_stat_keys() -> tuple[str, ...]:
                "spec_acceptance_rate", "spec_tokens_per_step",
                "sched_cost_drift_ratio",
                "kv_tier_host_pages", "kv_restore_hit_rate", "uptime_s")
+            + _BUILD_LOG_KEYS
             + tuple(CacheStats().snapshot()) + ("prefix_cache_pages",))
 
 
@@ -602,6 +617,7 @@ class Engine:
     def __init__(self, params: llama.Params, model_cfg: LlamaConfig,
                  tokenizer: Tokenizer, cfg: EngineConfig = EngineConfig(),
                  mesh: Optional[Mesh] = None):
+        compile_cache.install_build_log()  # count this engine's programs
         self.model_cfg = model_cfg
         self.cfg = cfg
         self.tokenizer = tokenizer
@@ -1406,6 +1422,7 @@ class Engine:
         # history/alert consumers join cumulative-counter resets against.
         out["uptime_s"] = round(
             time.monotonic() - self._created_monotonic, 3)
+        out.update(compile_cache.build_log())
         return out
 
     def _bump(self, key: str, n: int = 1) -> None:
@@ -2610,7 +2627,22 @@ class Engine:
         server arms it from ``X-Deadline-Ms``). Expired in queue → the
         request is dropped before prefill (finish ``deadline_queue``);
         passed mid-decode → generation stops at the next harvested
-        token (finish ``deadline``)."""
+        token (finish ``deadline``).
+
+        The whole call is one ``engine_submit`` span on the CALLER's
+        thread, carrying the request id the stream will bear."""
+        adopted = None
+        if request_id is None:
+            adopted = obs_flight.current_request_id()
+            if adopted is None:
+                request_id = obs_flight.mint_request_id()
+        with phase("engine_submit", request_id=request_id or adopted):
+            return self._submit(prompt_ids, params, request_id, deadline_t)
+
+    def _submit(self, prompt_ids: Sequence[int],
+                params: Optional[SamplingParams],
+                request_id: Optional[str],
+                deadline_t: Optional[float]) -> TokenStream:
         if self._fatal is not None:
             raise EngineError("engine is dead") from self._fatal
         params = params or SamplingParams()
@@ -3220,31 +3252,33 @@ class Engine:
         try:
             while (not self._stopped.is_set() and self._gen == gen
                    and self._fatal is None):
-                t0 = time.monotonic()
-                did_drain = self._drain_completed()
-                did_work = did_drain
-                t1 = time.monotonic()
-                # Only phases that did work get recorded: idle iterations
-                # would race a first-wins stage collector with
-                # meaningless ~0 values.
-                if did_drain:
-                    record_stage("loop_drain", t1 - t0)
-                self._pull_pending()
-                did_work |= self._drain_control()
-                did_work |= self._cull_backlog()
-                # Online calibration: fold any new measured-round
-                # evidence into the planning model BEFORE this round is
-                # planned (cheap version check; no-op when pinned).
-                if self._calib is not None and self._sched.recalibrate():
-                    with self._stats_lock:
-                        self._stats["sched_round_budget_tokens"] = \
-                            self._sched.round_budget_tokens
-                        self._stats["sched_budget_recalibrations"] += 1
-                plan = self._plan_round()
-                did_work |= self._execute_plan(plan)
+                did_work = False
+                if not self._completed.empty():
+                    # Idle iterations stay out of the histogram and the
+                    # trace: the span exists only when there is a
+                    # completion to retire.
+                    with phase("loop_drain"):
+                        did_work = self._drain_completed()
+                with phase("loop_plan") as planning:
+                    self._pull_pending()
+                    did_work |= self._drain_control()
+                    did_work |= self._cull_backlog()
+                    # Online calibration: fold any new measured-round
+                    # evidence into the planning model BEFORE this round
+                    # is planned (cheap version check; no-op when
+                    # pinned).
+                    if self._calib is not None \
+                            and self._sched.recalibrate():
+                        with self._stats_lock:
+                            self._stats["sched_round_budget_tokens"] = \
+                                self._sched.round_budget_tokens
+                            self._stats["sched_budget_recalibrations"] += 1
+                    plan = self._plan_round()
+                did_work |= self._execute_plan(plan, planning.seconds)
                 self._guard_live()
                 if not did_work:
-                    self._wake.wait(timeout=0.05)
+                    with phase("loop_idle"):
+                        self._wake.wait(timeout=0.05)
                     self._wake.clear()
             if self._fatal is not None and self._gen == gen:
                 # The harvest worker died: it set _fatal and woke us; fan
@@ -3381,9 +3415,10 @@ class Engine:
                     continue
                 if kind == "first":
                     _, req, first_tok, rec = item
-                    arr = np.asarray(first_tok)  # blocks off-thread
-                    wait = time.monotonic() - t0
-                    record_stage("engine_first_readback", wait)
+                    rid = -1 if rec is None else rec.round_id
+                    with phase("engine_first_readback", round_id=rid) as ph:
+                        arr = np.asarray(first_tok)  # blocks off-thread
+                    wait = ph.seconds
                     self._bump("first_readback_ms", wait * 1e3)
                     self._bump("first_readbacks")
                     tl = req.stream.timeline
@@ -3392,55 +3427,65 @@ class Engine:
                     if self._gen != gen:
                         return
                     emitted_first = not req.done
-                    if not req.done:
-                        if arr.ndim == 0:
-                            self._emit_token(req, int(arr))
-                        else:
-                            # Fused-RAG aux row:
-                            # [first_token, prompt_len, top_ids...]
-                            req.stream.source_ids = [int(x)
-                                                     for x in arr[2:]]
-                            self._emit_token(req, int(arr[0]))
+                    with phase("engine_emit", round_id=rid, tokens=1) as ph:
+                        if not req.done:
+                            if arr.ndim == 0:
+                                self._emit_token(req, int(arr))
+                            else:
+                                # Fused-RAG aux row:
+                                # [first_token, prompt_len, top_ids...]
+                                req.stream.source_ids = [
+                                    int(x) for x in arr[2:]]
+                                self._emit_token(req, int(arr[0]))
                     self.rounds.first_token(rec, wait_ms=wait * 1e3,
-                                            counted=emitted_first)
+                                            counted=emitted_first,
+                                            emit_ms=ph.seconds * 1e3)
                 else:
-                    if kind == "verify":
-                        _, members, toks_dev, acc_dev, drafted, rec = item
-                        accs = np.asarray(acc_dev)   # blocks off-thread
-                    else:
-                        _, members, toks_dev, rec = item
-                        accs = drafted = None
-                    toks = np.asarray(toks_dev)  # (K, B); blocks off-thread
-                    wait = time.monotonic() - t0
-                    record_stage("engine_harvest_wait", wait)
+                    rec = item[-1]
+                    rid = -1 if rec is None else rec.round_id
+                    with phase("engine_harvest_wait", round_id=rid) as ph:
+                        if kind == "verify":
+                            _, members, toks_dev, acc_dev, drafted, _ = item
+                            accs = np.asarray(acc_dev)  # blocks off-thread
+                        else:
+                            _, members, toks_dev, _ = item
+                            accs = drafted = None
+                        # (K, B); blocks off-thread
+                        toks = np.asarray(toks_dev)
+                    wait = ph.seconds
                     self._bump("harvest_wait_ms", wait * 1e3)
                     self._bump("harvest_rounds")
                     if self._gen != gen:
                         return
                     emitted: dict[int, int] = {}
-                    for k in range(toks.shape[0]):
-                        row = toks[k]
-                        for slot, req in members.items():
-                            if req.done:
-                                # A host-detected finish (stop word /
-                                # cancel / deadline) mid-burst: trailing
-                                # device-accepted tokens are DISCARDED —
-                                # never streamed, never counted, never
-                                # fed to the drafter (the slot retires,
-                                # so the device's advanced pos is moot).
-                                continue
-                            tok = int(row[slot])
-                            if tok < 0:
-                                continue  # inactive on-device at this step
-                            emitted[slot] = emitted.get(slot, 0) + 1
-                            self._emit_token(req, tok)
-                    # ONE timeline event per request per round (token
-                    # count), never per token — the flight recorder's
-                    # token-path budget. Ring appends are lock-free.
-                    for slot, n in emitted.items():
-                        tl = members[slot].stream.timeline
-                        if tl is not None:
-                            tl.event("decode_round", n)
+                    with phase("engine_emit", round_id=rid,
+                               tokens=int((toks[:, list(members)] >= 0)
+                                          .sum())) as ph:
+                        for k in range(toks.shape[0]):
+                            row = toks[k]
+                            for slot, req in members.items():
+                                if req.done:
+                                    # A host-detected finish (stop word
+                                    # / cancel / deadline) mid-burst:
+                                    # trailing device-accepted tokens
+                                    # are DISCARDED — never streamed,
+                                    # never counted, never fed to the
+                                    # drafter (the slot retires, so the
+                                    # device's advanced pos is moot).
+                                    continue
+                                tok = int(row[slot])
+                                if tok < 0:
+                                    continue  # inactive at this step
+                                emitted[slot] = emitted.get(slot, 0) + 1
+                                self._emit_token(req, tok)
+                        # ONE timeline event per request per round
+                        # (token count), never per token — the flight
+                        # recorder's token-path budget. Ring appends
+                        # are lock-free.
+                        for slot, n in emitted.items():
+                            tl = members[slot].stream.timeline
+                            if tl is not None:
+                                tl.event("decode_round", n)
                     accepted = 0
                     if kind == "verify":
                         accepted = self._finish_verify(members, accs,
@@ -3448,7 +3493,8 @@ class Engine:
                     self.rounds.complete_part(
                         rec, tokens=sum(emitted.values()),
                         spec_accepted=accepted,
-                        harvest_wait_ms=wait * 1e3)
+                        harvest_wait_ms=wait * 1e3,
+                        emit_ms=ph.seconds * 1e3)
                     with self._pipe_lock:
                         # Guarded by the generation check just above: a
                         # worker disowned during the readback must not
@@ -3669,45 +3715,63 @@ class Engine:
                 total += len(proposal)
         return plan if total else None
 
-    def _execute_plan(self, plan) -> bool:
+    def _execute_plan(self, plan, plan_s: float = 0.0) -> bool:
         """Dispatch one round plan: the decode round first (the latency-
         critical work for every armed stream), then the granted prefill
         chunks. Stops admitting on pool backpressure; counts the round
         as interleaved when both kinds of work actually dispatched.
 
         Round telemetry: the plan opens a RoundRecord (scheduler-side
-        half), each dispatch fills its execution fields, and the harvest
-        worker completes it — a prefill-only round gets a completion
-        MARKER in the harvest queue (a scalar output of the last chunk's
-        program, so its readback lands exactly when the chunk's device
-        work finishes)."""
-        rec = None
-        if plan.decode_steps or plan.chunks:
-            rec = self.rounds.begin(
-                engine_tag=self._engine_tag,
-                budget_tokens=plan.budget_tokens,
-                decode_steps=plan.decode_steps,
-                decode_cost_tokens=plan.decode_cost_tokens,
-                active_decodes=plan.active_decodes,
-                kind=("verify" if (plan.decode_steps
-                                   and self._draft_plan is not None)
-                      else "decode" if plan.decode_steps else "prefill"),
-                on_complete=self._on_round_complete)
+        half; ``plan_s`` is the host time the plan took), each dispatch
+        fills its execution fields, and the harvest worker completes it
+        — a prefill-only round gets a completion MARKER in the harvest
+        queue (a scalar output of the last chunk's program, so its
+        readback lands exactly when the chunk's device work finishes).
+        The ``engine_round`` span covers begin to seal and carries the
+        record's id, so trace and /debug/rounds join."""
+        if not (plan.decode_steps or plan.chunks):
+            return False
+        used = self._pool_used_pages()
+        with self._stats_lock:
+            self._stats["pool_used_pages"] = used
+        rec = self.rounds.begin(
+            engine_tag=self._engine_tag,
+            budget_tokens=plan.budget_tokens,
+            decode_steps=plan.decode_steps,
+            decode_cost_tokens=plan.decode_cost_tokens,
+            active_decodes=plan.active_decodes,
+            kind=("verify" if (plan.decode_steps
+                               and self._draft_plan is not None)
+                  else "decode" if plan.decode_steps else "prefill"),
+            plan_ms=plan_s * 1e3, pool_used_pages=used,
+            on_complete=self._on_round_complete)
         try:
-            return self._execute_plan_inner(plan, rec)
+            with phase("engine_round", round_id=rec.round_id,
+                       kind=rec.kind, t_mono_ns=time.monotonic_ns()):
+                return self._execute_plan_inner(plan, rec)
         except BaseException:
             # The round died mid-dispatch (fault injection, _StaleLoop
             # from a reset, a device error): an unsealed record would
             # sit in the ring as not-done debris forever — drop it. A
             # SEALED record's fate rides the harvest pipeline as usual.
-            if rec is not None and not rec._sealed:
+            if not rec._sealed:
                 self.rounds.discard(rec)
             raise
+
+    def _pool_used_pages(self) -> int:
+        """KV pool pages live requests hold right now: total - free -
+        evictable prefix-cache pages (refcount 0: warm, but reclaimable
+        the moment an admission needs them). O(1), scheduler thread."""
+        used = self._n_pages - 1 - len(self._free_pages)
+        cache = self._prefix_cache
+        if cache is not None:
+            used -= cache.cached_pages - cache.pinned_pages
+        return used
 
     def _execute_plan_inner(self, plan, rec) -> bool:
         did = False
         decoded = False
-        t0 = time.monotonic()
+        rid = rec.round_id
         if plan.decode_steps:
             if self._draft_plan is not None:
                 decoded = self._dispatch_verify(self._draft_plan, rec)
@@ -3717,66 +3781,73 @@ class Engine:
             if decoded:
                 did = True
                 self._bump("sched_decode_tokens", plan.decode_cost_tokens)
-                record_stage("loop_dispatch", time.monotonic() - t0)
-        t1 = time.monotonic()
         prefilled = 0
         grants: list[tuple[str, int]] = []
         marker = None
-        for key, grant in plan.chunks:
-            req: _Request = key
-            if req.slot < 0:
-                if not self._free_slots:
-                    break
-                ok = self._begin_prefill(req, rec)
-                if ok is None:     # dropped (cancel raced the grant)
-                    continue
-                if not ok:         # pool backpressure: stop admitting
-                    break
-            n, m = self._advance_prefill(req, grant, rec)
-            self._guard_live()
-            if n:
-                did = True
-                prefilled += n
-                grants.append((req.stream.request_id, n))
-                if m is not None:
-                    marker = m
-                if rec is not None:
-                    # Prefill traffic estimate: each chunk streams the
-                    # weights once and writes its tokens' KV.
-                    rec.hbm_bytes += self._param_bytes \
-                        + n * self._kv_bytes_per_token()
+        if plan.chunks:
+            with phase("loop_admit", round_id=rid) as ph:
+                for key, grant in plan.chunks:
+                    req: _Request = key
+                    if req.slot < 0 and not self._free_slots:
+                        break
+                    with phase("chunk_dispatch", round_id=rid,
+                               request_id=req.stream.request_id,
+                               **self._chunk_shape(req, grant)) as ch:
+                        n, m = 0, None
+                        ok = True if req.slot >= 0 \
+                            else self._begin_prefill(req, rec)
+                        if ok:
+                            n, m = self._advance_prefill(req, grant, rec)
+                        ch.record = bool(n)
+                    if ok is None:     # dropped (cancel raced the grant)
+                        continue
+                    if not ok:         # pool backpressure: stop admitting
+                        rec.blocked_on_pages += 1
+                        break
+                    self._guard_live()
+                    if n:
+                        did = True
+                        prefilled += n
+                        grants.append((req.stream.request_id, n))
+                        if m is not None:
+                            marker = m
+                        # Prefill traffic estimate: each chunk streams
+                        # the weights once and writes its tokens' KV.
+                        rec.hbm_bytes += self._param_bytes \
+                            + n * self._kv_bytes_per_token()
+                ph.record = bool(prefilled)
         if prefilled:
             self._bump("sched_prefill_tokens", prefilled)
-            record_stage("loop_admit", time.monotonic() - t1)
             if decoded:
                 self._bump("sched_interleaved_rounds")
-        if rec is not None:
-            parts = int(decoded)
-            if prefilled and marker is not None:
-                # Completion marker: a scalar OUTPUT of the last chunk's
-                # program (never part of the donated state). The harvest
-                # worker's np.asarray on it blocks until that program —
-                # and, the device stream being FIFO, every earlier chunk
-                # of this round — has executed: the honest end-of-round
-                # signal for prefill work that otherwise produces no
-                # readback until a slot arms.
-                parts += 1
-                self._assert_harvestable(marker)
-                self._harvest_q.put(("mark", rec, marker))
-            if parts == 0:
-                self.rounds.discard(rec)
-            else:
-                if not decoded:
-                    rec.kind = "prefill"
-                elif prefilled:
-                    rec.kind = "mixed" if rec.kind == "decode" \
-                        else rec.kind
-                self.rounds.seal(
-                    rec, parts=parts, prefill_tokens=prefilled,
-                    grants=grants,
-                    modeled_ms=self._modeled_round_ms(
-                        rec, plan.decode_steps if decoded else 0,
-                        prefilled))
+        parts = int(decoded)
+        if prefilled and marker is not None:
+            # Completion marker: a scalar OUTPUT of the last chunk's
+            # program (never part of the donated state). The harvest
+            # worker's np.asarray on it blocks until that program —
+            # and, the device stream being FIFO, every earlier chunk
+            # of this round — has executed: the honest end-of-round
+            # signal for prefill work that otherwise produces no
+            # readback until a slot arms.
+            parts += 1
+            self._assert_harvestable(marker)
+            self._harvest_q.put(("mark", rec, marker))
+        if parts == 0:
+            self.rounds.discard(rec)
+        else:
+            if not decoded:
+                rec.kind = "prefill"
+            elif prefilled:
+                rec.kind = "mixed" if rec.kind == "decode" \
+                    else rec.kind
+            if rec.blocked_on_pages:
+                self._bump("pool_blocked_rounds")
+            self.rounds.seal(
+                rec, parts=parts, prefill_tokens=prefilled,
+                grants=grants,
+                modeled_ms=self._modeled_round_ms(
+                    rec, plan.decode_steps if decoded else 0,
+                    prefilled))
         return did
 
     def _modeled_round_ms(self, rec, decode_steps: int,
@@ -3849,6 +3920,29 @@ class Engine:
         except Exception:  # noqa: BLE001 — telemetry must never raise
             logger.debug("round completion accounting failed",
                          exc_info=True)
+
+    def _chunk_shape(self, req: _Request, grant: int) -> dict:
+        """Arguments of a ``chunk_dispatch`` span: the tokens this grant
+        will compute, the bucket they are padded to, and which chunk
+        program runs (``one-shot`` = the fused prefill+insert, ``first``
+        / ``middle`` = extend, ``final``). For a request not yet
+        admitted this is the plan's view, taken before the prefix
+        lookup: a prefix-cache hit shrinks the real chunk (the round
+        record's grants are exact)."""
+        if req.rag is not None:
+            bucket = self._fused_rag.spec.bucket
+            return {"tokens": bucket, "padded": bucket, "mode": "one-shot"}
+        total, pos = len(req.prompt_ids), req.pf_pos
+        first = req.pf is None or pos == req.pf["start_tok"]
+        n = min(grant, total - pos, self._buckets[-1])
+        final = pos + n >= total
+        if not final:
+            n = (n // self.cfg.page_size) * self.cfg.page_size
+        mode = ("one-shot" if final and pos == 0
+                and total <= self._buckets[-1]
+                else "final" if final else "first" if first else "middle")
+        return {"tokens": n, "padded": self._bucket_for(n) if n > 0 else 0,
+                "mode": mode}
 
     def _begin_prefill(self, req: _Request, rec=None):
         """Admission half 1: allocate the slot and pages, take prefix-
@@ -4231,7 +4325,6 @@ class Engine:
         else:
             window = self._window_for(_ceil_div(need, self.cfg.page_size))
         greedy = all(r.greedy for r in members.values())
-        key = jax.random.fold_in(self._base_key, next(self._step_counter))
         # Active-slot compaction: the fused tail unembeds/samples only
         # the armed slots, padded to the smallest compiled rung (padding
         # indices == max_slots: gathers clamp, scatters drop). The
@@ -4239,6 +4332,18 @@ class Engine:
         # geometry) always runs full-width.
         B = self.cfg.max_slots
         ba = self._ba_for(len(members)) if self._fused_tail else B
+        with phase("loop_dispatch",
+                   round_id=-1 if rec is None else rec.round_id,
+                   steps=steps, rows=len(members), ba=ba):
+            self._launch_round(members, window, steps, greedy, ba, rec)
+        return True
+
+    def _launch_round(self, members: dict, window: int, steps: int,
+                      greedy: bool, ba: int, rec) -> None:
+        """The ``loop_dispatch`` span's body: launch the round program,
+        start its async readback, and hand it to the harvest worker."""
+        B = self.cfg.max_slots
+        key = jax.random.fold_in(self._base_key, next(self._step_counter))
         act = np.full((ba,), B, np.int32)
         act[:len(members)] = sorted(members)
         new_state, toks = self._round_fn(window, steps, greedy, ba)(
@@ -4282,7 +4387,6 @@ class Engine:
         self._assert_harvestable(toks)
         self._harvest_q.put(("round", members, toks, rec))
         self._bump("decode_steps", steps)
-        return True
 
     def _dispatch_verify(self, drafts: dict, rec=None) -> bool:
         """Dispatch one speculative VERIFY round: every armed slot rides
@@ -4317,53 +4421,56 @@ class Engine:
             draft_np[slot, :k] = toks[:k]
             n_np[slot] = k
             drafted[slot] = k
-        key = jax.random.fold_in(self._base_key, next(self._step_counter))
-        t0 = time.monotonic()
-        new_state, (toks, acc) = self._verify_fn(window, greedy, ba)(
-            self.params, self._state, key, jnp.asarray(act),
-            jnp.asarray(draft_np), jnp.asarray(n_np))
-        self._guard_live()  # reset() may have run while the round compiled
-        self._state = new_state
-        dt = time.monotonic() - t0
-        # Speculative overhead attribution: host-side dispatch time of
-        # the verify round, globally and on each member's timeline (one
-        # stage event per round per slot — the decode_round budget).
-        record_stage("engine_verify", dt)
-        for req in members.values():
-            tl = req.stream.timeline
-            if tl is not None:
-                tl.stage("engine_verify", dt)
-        if self._fused_tail:
-            self._bump("sampler_rows_sampled", ba * S)
-            self._bump("sampler_rows_skipped", (B - ba) * S)
-        try:
-            toks.copy_to_host_async()
-            acc.copy_to_host_async()
-        except Exception:  # noqa: BLE001 — optional fast path
-            pass
-        if rec is not None:
-            pages_per_step = sum(
-                _ceil_div(max(1, r.proj_pos + 1), page)
-                for r in members.values())
-            rec.decode_slots = len(members)
-            rec.spec_drafted = sum(drafted.values())
-            rec.verify_positions = S * len(members)
-            rec.pages_touched += pages_per_step
-            rec.hbm_bytes += (
-                self._param_bytes
-                + pages_per_step * page * self._kv_bytes_per_token())
-        for req in members.values():
-            req.proj_pos = min(req.proj_pos + S, req.extent)
-        with self._pipe_lock:
-            self._inflight_rounds += 1
-            depth = self._inflight_rounds
-        with self._stats_lock:
-            if depth > self._stats["dispatch_depth_peak"]:
-                self._stats["dispatch_depth_peak"] = depth
-        self._assert_harvestable(toks, acc)
-        self._harvest_q.put(("verify", members, toks, acc, drafted, rec))
-        self._bump("decode_steps")
-        self._bump("spec_verify_rounds")
+        with phase("loop_dispatch",
+                   round_id=-1 if rec is None else rec.round_id,
+                   steps=1, rows=len(members), ba=ba):
+            key = jax.random.fold_in(self._base_key, next(self._step_counter))
+            t0 = time.monotonic()
+            new_state, (toks, acc) = self._verify_fn(window, greedy, ba)(
+                self.params, self._state, key, jnp.asarray(act),
+                jnp.asarray(draft_np), jnp.asarray(n_np))
+            self._guard_live()  # reset() may have run while the round compiled
+            self._state = new_state
+            dt = time.monotonic() - t0
+            # Speculative overhead attribution: host-side dispatch time of
+            # the verify round, globally and on each member's timeline (one
+            # stage event per round per slot — the decode_round budget).
+            record_stage("engine_verify", dt)
+            for req in members.values():
+                tl = req.stream.timeline
+                if tl is not None:
+                    tl.stage("engine_verify", dt)
+            if self._fused_tail:
+                self._bump("sampler_rows_sampled", ba * S)
+                self._bump("sampler_rows_skipped", (B - ba) * S)
+            try:
+                toks.copy_to_host_async()
+                acc.copy_to_host_async()
+            except Exception:  # noqa: BLE001 — optional fast path
+                pass
+            if rec is not None:
+                pages_per_step = sum(
+                    _ceil_div(max(1, r.proj_pos + 1), page)
+                    for r in members.values())
+                rec.decode_slots = len(members)
+                rec.spec_drafted = sum(drafted.values())
+                rec.verify_positions = S * len(members)
+                rec.pages_touched += pages_per_step
+                rec.hbm_bytes += (
+                    self._param_bytes
+                    + pages_per_step * page * self._kv_bytes_per_token())
+            for req in members.values():
+                req.proj_pos = min(req.proj_pos + S, req.extent)
+            with self._pipe_lock:
+                self._inflight_rounds += 1
+                depth = self._inflight_rounds
+            with self._stats_lock:
+                if depth > self._stats["dispatch_depth_peak"]:
+                    self._stats["dispatch_depth_peak"] = depth
+            self._assert_harvestable(toks, acc)
+            self._harvest_q.put(("verify", members, toks, acc, drafted, rec))
+            self._bump("decode_steps")
+            self._bump("spec_verify_rounds")
         return True
 
     def _emit_token(self, req: _Request, token: int) -> None:

@@ -135,6 +135,9 @@ class PrefixCache:
     # freed page plus O(stale) skips, never an O(entries) rescan per
     # admission (warm-chat steady state evicts nearly every admission).
     _heap: list = field(default_factory=list)
+    # Entries some live request holds a ref on (refcount > 0), kept as a
+    # count so the pool gauge (Engine: pool_used_pages) costs O(1).
+    pinned_pages: int = 0
     stats: CacheStats = field(default_factory=CacheStats)
 
     def __len__(self) -> int:
@@ -174,6 +177,7 @@ class PrefixCache:
         pages = []
         for h in hashes:
             e = self._entries[h]
+            self.pinned_pages += e.refcount == 0
             e.refcount += 1
             pages.append(e.page)
         return pages
@@ -194,6 +198,7 @@ class PrefixCache:
         for h in hashes:
             e = self._entries[h]
             e.refcount -= 1
+            self.pinned_pages -= e.refcount == 0
             e.tick = self._tick
             if e.refcount < 0:  # pragma: no cover - invariant guard
                 raise AssertionError("prefix cache refcount underflow")
@@ -212,6 +217,7 @@ class PrefixCache:
         if parent is not None:
             self._entries[parent].children += 1
         self._entries[h] = _Entry(page=page, parent=parent, refcount=1)
+        self.pinned_pages += 1
         self._pages[page] = h
         self.stats.inserted_pages += 1
         return True

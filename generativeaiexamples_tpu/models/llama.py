@@ -47,6 +47,25 @@ from ..ops.rmsnorm import layernorm1p, rmsnorm
 from ..ops.rope import apply_rope, rope_frequencies
 from .configs import LlamaConfig
 
+#: Stage names inside every device program (``jax.named_scope``: HLO
+#: metadata only — numerics, program count and jitted-function names are
+#: untouched). The same names in the decode, verify, prefill and chunk
+#: programs, so a profile splits a step by stage however the compiler
+#: numbers its fusions: ``embed``; per layer ``attn_proj`` (norm, QKV,
+#: rope, output projection), ``attn`` (paged kernel or gather path, and
+#: the KV write), ``mlp`` (dense FFN) or ``moe_route`` + ``moe_experts``
+#: (parallel/moe.py); ``tail`` (final norm, lm_head tile stream,
+#: penalties, sampling) with ``tail_select`` inside it (top-k / top-p
+#: candidate handling: where the sort is). docs/observability.md lists
+#: them; the benchmark's scope reader keeps an equal tuple.
+SCOPES = ("embed", "attn_proj", "attn", "mlp", "moe_route", "moe_experts",
+          "tail", "tail_select")
+
+
+def _embed(params: "Params", tokens: jax.Array) -> jax.Array:
+    with jax.named_scope("embed"):
+        return jnp.take(params["embed"], tokens, axis=0)
+
 
 def use_paged_kernel(cfg: LlamaConfig, page: int) -> bool:
     """Public alias: whether the Pallas paged-attention decode kernel will
@@ -240,7 +259,7 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     page = kv_cache["k"].shape[3]  # (L, N, KV, page, hd)
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
                                 cfg.rope_scaling_factor)
-    h = jnp.take(params["embed"], tokens, axis=0)
+    h = _embed(params, tokens)
     pos_in_win = positions[:, 0]  # logical index of the current token
     rows = jnp.arange(B)
 
@@ -378,23 +397,24 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
         flat = flat.at[:, flat_idx].set(new.astype(pool.dtype))
         return flat.reshape(L_, N_, KV_, page_, hd_)
 
-    if quant:
-        from ..ops.kv_quant import quantize_rows
+    with jax.named_scope("attn"):      # the KV write
+        if quant:
+            from ..ops.kv_quant import quantize_rows
 
-        def write_scale(pool, new_s):
-            flat = pool.reshape(L_, N_ * KV_ * page_)
-            flat = flat.at[:, flat_idx].set(new_s.astype(pool.dtype))
-            return flat.reshape(L_, N_, KV_, page_)
+            def write_scale(pool, new_s):
+                flat = pool.reshape(L_, N_ * KV_ * page_)
+                flat = flat.at[:, flat_idx].set(new_s.astype(pool.dtype))
+                return flat.reshape(L_, N_, KV_, page_)
 
-        kq, ksn = quantize_rows(new_k)
-        vq, vsn = quantize_rows(new_v)
-        cache = {"k": write(kv_cache["k"], kq),
-                 "v": write(kv_cache["v"], vq),
-                 "ks": write_scale(kv_cache["ks"], ksn),
-                 "vs": write_scale(kv_cache["vs"], vsn)}
-    else:
-        cache = {"k": write(kv_cache["k"], new_k),
-                 "v": write(kv_cache["v"], new_v)}
+            kq, ksn = quantize_rows(new_k)
+            vq, vsn = quantize_rows(new_v)
+            cache = {"k": write(kv_cache["k"], kq),
+                     "v": write(kv_cache["v"], vq),
+                     "ks": write_scale(kv_cache["ks"], ksn),
+                     "vs": write_scale(kv_cache["vs"], vsn)}
+        else:
+            cache = {"k": write(kv_cache["k"], new_k),
+                     "v": write(kv_cache["v"], new_v)}
     return (h if return_hidden else unembed(params, cfg, h)), cache
 
 
@@ -439,7 +459,7 @@ def apply_verify_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     page = kv_cache["k"].shape[3]  # (L, N, KV, page, hd)
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
                                 cfg.rope_scaling_factor)
-    h = jnp.take(params["embed"], tokens, axis=0)
+    h = _embed(params, tokens)
     rows = jnp.arange(B)
     quant = kv_cache_quantized(kv_cache)
 
@@ -483,23 +503,24 @@ def apply_verify_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
         flat = flat.at[:, flat_idx].set(new.astype(pool.dtype))
         return flat.reshape(L_, N_, KV_, page_, hd_)
 
-    if quant:
-        from ..ops.kv_quant import quantize_rows
+    with jax.named_scope("attn"):      # the KV write
+        if quant:
+            from ..ops.kv_quant import quantize_rows
 
-        def write_scale(pool, new_s):
-            flat = pool.reshape(L_, N_ * KV_ * page_)
-            flat = flat.at[:, flat_idx].set(new_s.astype(pool.dtype))
-            return flat.reshape(L_, N_, KV_, page_)
+            def write_scale(pool, new_s):
+                flat = pool.reshape(L_, N_ * KV_ * page_)
+                flat = flat.at[:, flat_idx].set(new_s.astype(pool.dtype))
+                return flat.reshape(L_, N_, KV_, page_)
 
-        kq, ksn = quantize_rows(new_k)
-        vq, vsn = quantize_rows(new_v)
-        cache = {"k": write(kv_cache["k"], kq),
-                 "v": write(kv_cache["v"], vq),
-                 "ks": write_scale(kv_cache["ks"], ksn),
-                 "vs": write_scale(kv_cache["vs"], vsn)}
-    else:
-        cache = {"k": write(kv_cache["k"], new_k),
-                 "v": write(kv_cache["v"], new_v)}
+            kq, ksn = quantize_rows(new_k)
+            vq, vsn = quantize_rows(new_v)
+            cache = {"k": write(kv_cache["k"], kq),
+                     "v": write(kv_cache["v"], vq),
+                     "ks": write_scale(kv_cache["ks"], ksn),
+                     "vs": write_scale(kv_cache["vs"], vsn)}
+        else:
+            cache = {"k": write(kv_cache["k"], new_k),
+                     "v": write(kv_cache["v"], new_v)}
     return (h if return_hidden else unembed(params, cfg, h)), cache
 
 
@@ -662,7 +683,7 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     nb = C // page
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
                                 cfg.rope_scaling_factor)
-    h = jnp.take(params["embed"], tokens, axis=0)
+    h = _embed(params, tokens)
     start = positions[0, 0]  # absolute position of the chunk's first row
 
     quant = kv_cache_quantized(kv_cache)
@@ -702,23 +723,24 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
                              cfg.head_dim).swapaxes(2, 3)
         return pool.at[:, dest].set(blocks.astype(pool.dtype))
 
-    if quant:
-        from ..ops.kv_quant import quantize_rows
-        kq, ksn = quantize_rows(new_k)           # scales: (L, C, KV)
-        vq, vsn = quantize_rows(new_v)
+    with jax.named_scope("attn"):      # the KV write
+        if quant:
+            from ..ops.kv_quant import quantize_rows
+            kq, ksn = quantize_rows(new_k)           # scales: (L, C, KV)
+            vq, vsn = quantize_rows(new_v)
 
-        def write_scale(pool, new_s):
-            blocks = new_s.reshape(L_, nb, page,
-                                   cfg.num_kv_heads).swapaxes(2, 3)
-            return pool.at[:, dest].set(blocks.astype(pool.dtype))
+            def write_scale(pool, new_s):
+                blocks = new_s.reshape(L_, nb, page,
+                                       cfg.num_kv_heads).swapaxes(2, 3)
+                return pool.at[:, dest].set(blocks.astype(pool.dtype))
 
-        cache = {"k": write(kv_cache["k"], kq),
-                 "v": write(kv_cache["v"], vq),
-                 "ks": write_scale(kv_cache["ks"], ksn),
-                 "vs": write_scale(kv_cache["vs"], vsn)}
-    else:
-        cache = {"k": write(kv_cache["k"], new_k),
-                 "v": write(kv_cache["v"], new_v)}
+            cache = {"k": write(kv_cache["k"], kq),
+                     "v": write(kv_cache["v"], vq),
+                     "ks": write_scale(kv_cache["ks"], ksn),
+                     "vs": write_scale(kv_cache["vs"], vsn)}
+        else:
+            cache = {"k": write(kv_cache["k"], new_k),
+                     "v": write(kv_cache["v"], new_v)}
     if not with_logits:
         return h, cache
     return unembed(params, cfg, h), cache
@@ -761,17 +783,20 @@ def _moe_mlp(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig) -> jax.Ar
         raise ValueError(f"unknown moe_impl {cfg.moe_impl!r}; "
                          f"expected 'sparse' or 'dense'")
     B, S, D = x.shape
-    logits = x @ lp["router"]  # (B,S,E)
-    weights, idx = jax.lax.top_k(logits, cfg.num_experts_per_tok)
-    weights = jax.nn.softmax(weights.astype(jnp.float32), axis=-1).astype(x.dtype)
-    # gates: (B,S,E) with softmaxed weights at the top-k positions
-    gates = jnp.zeros_like(logits).at[
-        jnp.arange(B)[:, None, None], jnp.arange(S)[None, :, None], idx
-    ].set(weights)
-    gate = jax.nn.silu(jnp.einsum("bsd,edf->bsef", x, lp["w_gate"]))
-    up = jnp.einsum("bsd,edf->bsef", x, lp["w_up"])
-    down = jnp.einsum("bsef,efd->bsed", gate * up, lp["w_down"])
-    return jnp.einsum("bsed,bse->bsd", down, gates)
+    with jax.named_scope("moe_route"):
+        logits = x @ lp["router"]  # (B,S,E)
+        weights, idx = jax.lax.top_k(logits, cfg.num_experts_per_tok)
+        weights = jax.nn.softmax(weights.astype(jnp.float32),
+                                 axis=-1).astype(x.dtype)
+        # gates: (B,S,E) with softmaxed weights at the top-k positions
+        gates = jnp.zeros_like(logits).at[
+            jnp.arange(B)[:, None, None], jnp.arange(S)[None, :, None], idx
+        ].set(weights)
+    with jax.named_scope("moe_experts"):
+        gate = jax.nn.silu(jnp.einsum("bsd,edf->bsef", x, lp["w_gate"]))
+        up = jnp.einsum("bsd,edf->bsef", x, lp["w_up"])
+        down = jnp.einsum("bsef,efd->bsed", gate * up, lp["w_down"])
+        return jnp.einsum("bsed,bse->bsd", down, gates)
 
 
 def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
@@ -791,39 +816,48 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
     decode). Returns (h, new_cache_or_None).
     """
     B, S, _ = h.shape
-    x = block_norm(h, lp, "attn_norm", cfg)
-    q = qmm(x, lp["wq"])
-    k = qmm(x, lp["wk"])
-    v = qmm(x, lp["wv"])
-    if "bq" in lp:
-        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-    q = q.reshape(B, S, cfg.num_heads, cfg.head_dim)
-    k = k.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
-    v = v.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
-    q, k = apply_rope(q, k, positions, inv_freq)
-    if attend is not None:
-        attn, new_cache = attend(q, k, v)
-    elif cache_kv is not None:
-        kc, vc = cache_kv
-        # Write this chunk at its absolute positions (rows contiguous).
-        kc = jax.vmap(
-            lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s, 0, 0))
-        )(kc, k, row_start)
-        vc = jax.vmap(
-            lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s, 0, 0))
-        )(vc, v, row_start)
-        attn = gqa_attention(q, kc, vc, positions, kv_valid_len)
-        new_cache = (kc, vc)
-    else:
-        attn = gqa_attention(q, k, v, positions, kv_valid_len)
-        new_cache = None
-    attn_out = qmm(attn.reshape(B, S, cfg.q_dim), lp["wo"])
-    if "bo" in lp:
-        attn_out = attn_out + lp["bo"]
-    h = h + attn_out
-    x = block_norm(h, lp, "mlp_norm", cfg)
-    mlp = _moe_mlp(x, lp, cfg) if cfg.num_experts else _dense_mlp(x, lp, cfg)
-    return h + mlp, new_cache
+    with jax.named_scope("attn_proj"):
+        x = block_norm(h, lp, "attn_norm", cfg)
+        q = qmm(x, lp["wq"])
+        k = qmm(x, lp["wk"])
+        v = qmm(x, lp["wv"])
+        if "bq" in lp:
+            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+        q = q.reshape(B, S, cfg.num_heads, cfg.head_dim)
+        k = k.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
+        v = v.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
+        q, k = apply_rope(q, k, positions, inv_freq)
+    with jax.named_scope("attn"):
+        if attend is not None:
+            attn, new_cache = attend(q, k, v)
+        elif cache_kv is not None:
+            kc, vc = cache_kv
+            # Write this chunk at its absolute positions (rows contiguous).
+            kc = jax.vmap(
+                lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s, 0, 0))
+            )(kc, k, row_start)
+            vc = jax.vmap(
+                lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s, 0, 0))
+            )(vc, v, row_start)
+            attn = gqa_attention(q, kc, vc, positions, kv_valid_len)
+            new_cache = (kc, vc)
+        else:
+            attn = gqa_attention(q, k, v, positions, kv_valid_len)
+            new_cache = None
+    with jax.named_scope("attn_proj"):
+        attn_out = qmm(attn.reshape(B, S, cfg.q_dim), lp["wo"])
+        if "bo" in lp:
+            attn_out = attn_out + lp["bo"]
+        h = h + attn_out
+    if cfg.num_experts:
+        with jax.named_scope("moe_route"):
+            x = block_norm(h, lp, "mlp_norm", cfg)
+        mlp = _moe_mlp(x, lp, cfg)
+        with jax.named_scope("moe_experts"):
+            return h + mlp, new_cache
+    with jax.named_scope("mlp"):
+        x = block_norm(h, lp, "mlp_norm", cfg)
+        return h + _dense_mlp(x, lp, cfg), new_cache
 
 
 def run_layers(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
@@ -847,10 +881,11 @@ def unembed_norm(params: Params, cfg: LlamaConfig, h: jax.Array
     """The final-norm half of ``unembed`` — the fused vocab-tiled sampler
     (ops/fused_sampler.py) applies it once and then streams the vocab
     projection itself via ``lm_head_tile``."""
-    if cfg.norm == "layernorm1p":
-        return layernorm1p(h, params["final_norm"], params["final_norm_b"],
-                           cfg.rms_norm_eps)
-    return rmsnorm(h, params["final_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("tail"):
+        if cfg.norm == "layernorm1p":
+            return layernorm1p(h, params["final_norm"],
+                               params["final_norm_b"], cfg.rms_norm_eps)
+        return rmsnorm(h, params["final_norm"], cfg.rms_norm_eps)
 
 
 # lm_head QTensor leaves sliced along the vocab (output) axis; K-axis
@@ -930,11 +965,12 @@ def unembed(params: Params, cfg: LlamaConfig, h: jax.Array) -> jax.Array:
     projection every decode step (ops/quant.py matmul_f32)."""
     h = unembed_norm(params, cfg, h)
     head = params.get("lm_head")
-    if head is None:
-        return jax.lax.dot_general(
-            h, params["embed"], (((h.ndim - 1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    return qmm_f32(h, head)
+    with jax.named_scope("tail"):
+        if head is None:
+            return jax.lax.dot_general(
+                h, params["embed"], (((h.ndim - 1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        return qmm_f32(h, head)
 
 
 def apply(params: Params, cfg: LlamaConfig, tokens: jax.Array,
@@ -953,7 +989,7 @@ def apply(params: Params, cfg: LlamaConfig, tokens: jax.Array,
                  causal masking only.
     Returns (logits (B,S,V) or hidden (B,S,D), updated cache or None).
     """
-    h = jnp.take(params["embed"], tokens, axis=0)
+    h = _embed(params, tokens)
     row_start = positions[:, 0]
     if kv_cache is not None and kv_valid_len is None:
         kv_valid_len = positions[:, -1] + 1
@@ -976,11 +1012,7 @@ def apply(params: Params, cfg: LlamaConfig, tokens: jax.Array,
         new_cache = None
 
     if return_hidden:
-        if cfg.norm == "layernorm1p":
-            return layernorm1p(h, params["final_norm"],
-                               params["final_norm_b"],
-                               cfg.rms_norm_eps), new_cache
-        return rmsnorm(h, params["final_norm"], cfg.rms_norm_eps), new_cache
+        return unembed_norm(params, cfg, h), new_cache
     return unembed(params, cfg, h), new_cache
 
 

@@ -157,15 +157,15 @@ class RoundRecord:
         # identity / plan (scheduler thread, begin)
         "round_id", "engine_tag", "t_start", "wall_start", "kind",
         "budget_tokens", "decode_steps", "decode_cost_tokens",
-        "active_decodes",
+        "active_decodes", "plan_ms", "pool_used_pages",
         # dispatch (scheduler thread, filled until seal)
         "decode_slots", "spec_drafted", "verify_positions",
         "prefill_tokens", "grants", "pages_touched", "hbm_bytes",
-        "kv_restore_pages",
+        "kv_restore_pages", "blocked_on_pages",
         "dispatch_ms", "modeled_ms", "t_dispatch_done",
         # execution (harvest thread)
-        "harvest_wait_ms", "first_readback_ms", "tokens_emitted",
-        "first_tokens", "spec_accepted",
+        "harvest_wait_ms", "first_readback_ms", "emit_ms",
+        "tokens_emitted", "first_tokens", "spec_accepted",
         # finalization
         "device_ms", "round_ms", "bw_util", "drift_ratio", "done",
         # bookkeeping
@@ -182,6 +182,14 @@ class RoundRecord:
         self.decode_steps = 0
         self.decode_cost_tokens = 0
         self.active_decodes = 0
+        # Host time of the planning phase that produced this round
+        # (the loop_plan span: intake pull, control ops, backlog cull,
+        # recalibration, _plan_round) — beside dispatch_ms and emit_ms
+        # it is the host's split of a round without a profiler.
+        self.plan_ms = 0.0
+        # Pool occupancy when the round began: pages held by live
+        # requests (total - free - evictable prefix-cache pages).
+        self.pool_used_pages = 0
         self.decode_slots = 0
         self.spec_drafted = 0
         self.verify_positions = 0
@@ -194,11 +202,18 @@ class RoundRecord:
         # are folded into hbm_bytes; the count is kept separately so
         # the round record shows restore work explicitly.
         self.kv_restore_pages = 0
+        # Requests the plan offered a chunk that _begin_prefill refused
+        # for want of pages this round (pool backpressure).
+        self.blocked_on_pages = 0
         self.dispatch_ms = 0.0
         self.modeled_ms = 0.0
         self.t_dispatch_done = self.t_start
         self.harvest_wait_ms = 0.0
         self.first_readback_ms = 0.0
+        # Harvest-thread host time from readback done to the last token
+        # of this round fed to its stream (the engine_emit span:
+        # detokenize, stop check, stream feed).
+        self.emit_ms = 0.0
         self.tokens_emitted = 0
         self.first_tokens = 0
         self.spec_accepted = 0
@@ -226,6 +241,8 @@ class RoundRecord:
                 "decode_steps": self.decode_steps,
                 "decode_cost_tokens": self.decode_cost_tokens,
                 "active_decodes": self.active_decodes,
+                "plan_ms": round(self.plan_ms, 3),
+                "pool_used_pages": self.pool_used_pages,
                 "prefill_grants": [
                     {"request_id": rid, "tokens": n}
                     for rid, n in self.grants],
@@ -236,8 +253,10 @@ class RoundRecord:
                 "decode_slots": self.decode_slots,
                 "prefill_tokens": self.prefill_tokens,
                 "dispatch_ms": round(self.dispatch_ms, 3),
+                "blocked_on_pages": self.blocked_on_pages,
                 "harvest_wait_ms": round(self.harvest_wait_ms, 3),
                 "first_readback_ms": round(self.first_readback_ms, 3),
+                "emit_ms": round(self.emit_ms, 3),
                 "device_ms": round(self.device_ms, 3),
                 "round_ms": round(self.round_ms, 3),
             },
@@ -280,6 +299,7 @@ class RoundRecorder:
     def begin(self, *, engine_tag: str = "", budget_tokens: int = 0,
               decode_steps: int = 0, decode_cost_tokens: int = 0,
               active_decodes: int = 0, kind: str = "decode",
+              plan_ms: float = 0.0, pool_used_pages: int = 0,
               on_complete: Optional[Callable[[RoundRecord], None]] = None
               ) -> RoundRecord:
         """Open this round's record (scheduler thread). The record is
@@ -290,6 +310,8 @@ class RoundRecorder:
         rec.decode_steps = int(decode_steps)
         rec.decode_cost_tokens = int(decode_cost_tokens)
         rec.active_decodes = int(active_decodes)
+        rec.plan_ms = float(plan_ms)
+        rec.pool_used_pages = int(pool_used_pages)
         rec._cb = on_complete
         with self._lock:
             self._ring.append(rec)
@@ -332,7 +354,8 @@ class RoundRecorder:
 
     def complete_part(self, rec: Optional[RoundRecord], *,
                       tokens: int = 0, spec_accepted: int = 0,
-                      harvest_wait_ms: float = 0.0) -> None:
+                      harvest_wait_ms: float = 0.0,
+                      emit_ms: float = 0.0) -> None:
         """One harvested device output of this round (harvest thread).
         The last part — once the scheduler has sealed the expected
         count — finalizes the record."""
@@ -341,6 +364,7 @@ class RoundRecorder:
         rec.tokens_emitted += int(tokens)
         rec.spec_accepted += int(spec_accepted)
         rec.harvest_wait_ms += float(harvest_wait_ms)
+        rec.emit_ms += float(emit_ms)
         finalize = False
         with self._lock:
             rec._done_parts += 1
@@ -349,7 +373,8 @@ class RoundRecorder:
             self._finalize(rec)
 
     def first_token(self, rec: Optional[RoundRecord], *,
-                    wait_ms: float = 0.0, counted: bool = True) -> None:
+                    wait_ms: float = 0.0, counted: bool = True,
+                    emit_ms: float = 0.0) -> None:
         """A first-token readback attributed to the round that armed the
         request (harvest thread). Does NOT count toward the round's
         completion parts — the prefill completion marker follows it in
@@ -357,6 +382,7 @@ class RoundRecorder:
         if rec is None:
             return
         rec.first_readback_ms += float(wait_ms)
+        rec.emit_ms += float(emit_ms)
         if counted:
             rec.first_tokens += 1
 

@@ -128,31 +128,61 @@ def instrumented(name: str):
     return deco
 
 
-# Optional in-process stage-timing hook: callable(stage_name, seconds).
-# Installed by diagnostics (set_stage_collector) for ad-hoc first-wins
-# capture; record_stage additionally ALWAYS feeds the current request's
-# flight-recorder timeline (obs/flight.py) and the labeled
-# engine_stage_seconds histogram (obs/metrics.py observe_stage), so the
-# per-stage breakdown exists in production scrapes and /debug/requests
-# without any collector installed.
-_stage_collector: Optional[Any] = None
-
-
-def set_stage_collector(cb: Optional[Any]) -> None:
-    """Install (or clear, with None) the process-local stage-timing hook."""
-    global _stage_collector
-    _stage_collector = cb
-
-
 def record_stage(name: str, seconds: float) -> None:
-    """Report one stage duration: to the installed collector (if any),
-    to the bound request timeline, and to the stage histogram."""
-    cb = _stage_collector
-    if cb is not None:
-        cb(name, seconds)
+    """Report one stage duration to the bound request timeline
+    (obs/flight.py) and the labeled ``engine_stage_seconds`` histogram
+    (obs/metrics.py observe_stage): the per-stage breakdown exists in
+    production scrapes and /debug/requests with nothing installed."""
     from .flight import record_current_stage
     record_current_stage(name, seconds)
     _metrics.observe_stage(name, seconds)
+
+
+_annotation = None
+
+
+def _load_annotation():
+    """jax.profiler.TraceAnnotation, imported at first use: the chain
+    server's handlers import this module without needing JAX."""
+    global _annotation
+    from jax.profiler import TraceAnnotation
+    _annotation = TraceAnnotation
+    return _annotation
+
+
+class phase:
+    """One host phase of the engine, as a span on the profiler's clock.
+
+    ``with phase("loop_dispatch", round_id=7): ...`` enters a
+    ``jax.profiler.TraceAnnotation`` (a TraceMe: with no profiler
+    session it costs an activity check; with one, the span lands on the
+    host plane of the same ``.xplane.pb`` as the device plane, its
+    keyword arguments as the event's stats) and on exit hands the
+    elapsed time to :func:`record_stage` under the same name — a span's
+    name IS its ``engine_stage_seconds`` stage. ``seconds`` holds the
+    elapsed time after exit. ``record=False`` keeps the span and skips
+    the stage record (set it inside the block too: a phase that turned
+    out to do no work stays out of the histogram). Takes no lock,
+    allocates nothing but itself, reads no environment."""
+
+    __slots__ = ("name", "record", "seconds", "_ann", "_t0")
+
+    def __init__(self, name: str, record: bool = True, **args: Any):
+        self.name = name
+        self.record = record
+        self.seconds = 0.0
+        self._ann = (_annotation or _load_annotation())(name, **args)
+
+    def __enter__(self) -> "phase":
+        self._ann.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.monotonic() - self._t0
+        self._ann.__exit__(*exc)
+        if self.record:
+            record_stage(self.name, self.seconds)
 
 
 @contextmanager

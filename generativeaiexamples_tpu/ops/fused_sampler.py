@@ -197,12 +197,13 @@ def _sample_stream(masked_tile, noise_tile, key, tf, n_tiles: int,
         # far, with their ids and Gumbel perturbations. Concatenating
         # carry-first preserves ascending-id order among equal values —
         # the same tie order as the oracle's stable argsort.
-        av = jnp.concatenate([cv, scaled], axis=-1)
-        ai = jnp.concatenate([ci, idb], axis=-1)
-        ap = jnp.concatenate([cp, pert], axis=-1)
-        cv, sel = jax.lax.top_k(av, cand_k)
-        ci = jnp.take_along_axis(ai, sel, axis=-1)
-        cp = jnp.take_along_axis(ap, sel, axis=-1)
+        with jax.named_scope("tail_select"):
+            av = jnp.concatenate([cv, scaled], axis=-1)
+            ai = jnp.concatenate([ci, idb], axis=-1)
+            ap = jnp.concatenate([cp, pert], axis=-1)
+            cv, sel = jax.lax.top_k(av, cand_k)
+            ci = jnp.take_along_axis(ai, sel, axis=-1)
+            cp = jnp.take_along_axis(ap, sel, axis=-1)
         return (cv, ci, cp, lse, bpert, bpid, braw, brid), None
 
     init = (jnp.full((B, cand_k), -jnp.inf, jnp.float32),
@@ -259,12 +260,13 @@ def _verify_stream(masked_tile, noise_tile, key, tf, draft_ids,
         npert, npid = jnp.where(un, nb, npert), jnp.where(un, ni, npid)
         # candidate merge (identical to the sampling stream: carry-first
         # preserves the oracle's stable tie order)
-        av = jnp.concatenate([cv, scaled], axis=-1)
-        ai = jnp.concatenate([ci, idb], axis=-1)
-        ap = jnp.concatenate([cp, pert], axis=-1)
-        cv, sel = jax.lax.top_k(av, cand_k)
-        ci = jnp.take_along_axis(ai, sel, axis=-1)
-        cp = jnp.take_along_axis(ap, sel, axis=-1)
+        with jax.named_scope("tail_select"):
+            av = jnp.concatenate([cv, scaled], axis=-1)
+            ai = jnp.concatenate([ci, idb], axis=-1)
+            ap = jnp.concatenate([cp, pert], axis=-1)
+            cv, sel = jax.lax.top_k(av, cand_k)
+            ci = jnp.take_along_axis(ai, sel, axis=-1)
+            cp = jnp.take_along_axis(ap, sel, axis=-1)
         return (cv, ci, cp, lse, braw, brid, sd, sfound, npert, npid), None
 
     init = (jnp.full((R, cand_k), -jnp.inf, jnp.float32),
@@ -285,6 +287,7 @@ def _verify_stream(masked_tile, noise_tile, key, tf, draft_ids,
 # ------------------------------------------------------------ finalizers
 
 
+@jax.named_scope("tail_select")
 def _finalize_sample(cv, ci, cp, lse, bpid, brid, *, temp, top_k, top_p,
                      vocab_size: int, cand_k: int) -> jax.Array:
     """Resolve top-k/top-p truncation from the candidate carry alone and
@@ -309,6 +312,7 @@ def _finalize_sample(cv, ci, cp, lse, bpid, brid, *, temp, top_k, top_p,
     return jnp.where(is_greedy, brid, sampled).astype(jnp.int32)
 
 
+@jax.named_scope("tail_select")
 def _finalize_verify(cv, ci, cp, lse, brid, sd, sfound, npid, *, u, temp,
                      top_k, top_p, draft_ids, vocab_size: int,
                      cand_k: int) -> tuple[jax.Array, jax.Array]:
@@ -364,6 +368,7 @@ def _merge_running_max(axis: str, val, idx):
     return take(vs), take(ids)
 
 
+@jax.named_scope("tail_select")
 def _merge_candidates(axis: str, cv, ci, cp, cand_k: int):
     """Combine per-shard candidate carries: gather the (B, cand_k) rows
     shard-major and re-take the stable top-k. Each global top-cand_k
@@ -402,6 +407,7 @@ def _shard_geometry(mesh, axis: str, vocab_size: int,
 # ------------------------------------------------------------ public API
 
 
+@jax.named_scope("tail")
 def fused_unembed_sample(tile_logits_fn, vocab_size: int, *, key, temp,
                          top_k, top_p, rep_pen, seen_words, banned_words,
                          ban_tok=None, ban_hit=None, greedy: bool = False,
@@ -442,6 +448,7 @@ def fused_unembed_sample(tile_logits_fn, vocab_size: int, *, key, temp,
                             vocab_size=vocab_size, cand_k=cand_k)
 
 
+@jax.named_scope("tail")
 def fused_unembed_sample_tp(mesh, axis: str, head_tree, head_specs,
                             local_tile_fn, vocab_size: int, *, hn, key,
                             temp, top_k, top_p, rep_pen, seen_words,
@@ -511,6 +518,7 @@ def fused_unembed_sample_tp(mesh, axis: str, head_tree, head_specs,
                          out_specs=P(), check_vma=False)(*args)
 
 
+@jax.named_scope("tail")
 def fused_verify_sample(tile_logits_fn, vocab_size: int, *, key, u, temp,
                         top_k, top_p, rep_pen, seen_words, banned_words,
                         draft_ids, ban_tok=None, ban_hit=None,
@@ -572,6 +580,7 @@ def fused_verify_sample(tile_logits_fn, vocab_size: int, *, key, u, temp,
                             cand_k=cand_k)
 
 
+@jax.named_scope("tail")
 def fused_verify_sample_tp(mesh, axis: str, head_tree, head_specs,
                            local_tile_fn, vocab_size: int, *, hn, key, u,
                            temp, top_k, top_p, rep_pen, seen_words,

@@ -208,5 +208,6 @@ def int4_matmul(x: jax.Array, q4: jax.Array, scale: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="int4_matmul",
     )(xe, xo, q4, s_arg)
     return out[:M].reshape(*lead, N)

@@ -329,6 +329,7 @@ def paged_attention_decode(q: jax.Array, pool_k: jax.Array,
         # cv=9)
         input_output_aliases={6: 1, 7: 2},
         interpret=interpret,
+        name="paged_attn_decode",
     )(block_table, lengths, write_page, write_offset, layer,
       q, pool_k, pool_v, cur_k, cur_v)
 
@@ -600,6 +601,7 @@ def _paged_attention_decode_quant(q, pool_k, pool_v, pool_ks, pool_vs,
         # pool_v=7, pool_ks=8, pool_vs=9, ck=10, cv=11
         input_output_aliases={6: 1, 7: 2, 8: 3, 9: 4},
         interpret=interpret,
+        name="paged_attn_decode_int8kv",
     )(block_table, lengths, write_page, write_offset, layer,
       q, pool_k, pool_v, pool_ks, pool_vs, cur_k, cur_v)
 

@@ -77,6 +77,7 @@ def set_token_bits(words: jax.Array, tokens: jax.Array,
     return words.at[rows, wi].set(words[rows, wi] | bit)
 
 
+@jax.named_scope("tail")
 def sample(logits: jax.Array, key: jax.Array, temperature: jax.Array,
            top_k: jax.Array, top_p: jax.Array) -> jax.Array:
     """Sample next tokens.
@@ -94,27 +95,28 @@ def sample(logits: jax.Array, key: jax.Array, temperature: jax.Array,
     temp = jnp.maximum(temperature, 1e-6)[:, None]
     scaled = lf / temp
 
-    # Rank of each vocab entry (0 = best) via descending sort.
-    sort_idx = jnp.argsort(-scaled, axis=-1)                     # (B, V)
-    ranks = jnp.zeros_like(sort_idx).at[
-        jnp.arange(B)[:, None], sort_idx
-    ].set(jnp.broadcast_to(jnp.arange(V), (B, V)))
+    with jax.named_scope("tail_select"):   # the vocab-wide sort
+        # Rank of each vocab entry (0 = best) via descending sort.
+        sort_idx = jnp.argsort(-scaled, axis=-1)                     # (B, V)
+        ranks = jnp.zeros_like(sort_idx).at[
+            jnp.arange(B)[:, None], sort_idx
+        ].set(jnp.broadcast_to(jnp.arange(V), (B, V)))
 
-    k = jnp.where(top_k[:, None] <= 0, V, top_k[:, None])
-    keep = ranks < k
+        k = jnp.where(top_k[:, None] <= 0, V, top_k[:, None])
+        keep = ranks < k
 
-    # top-p: keep the smallest prefix of sorted probs with cumsum >= p.
-    sorted_logits = jnp.take_along_axis(scaled, sort_idx, axis=-1)
-    sorted_probs = jax.nn.softmax(sorted_logits, axis=-1)
-    cum = jnp.cumsum(sorted_probs, axis=-1)
-    p = jnp.where((top_p[:, None] <= 0) | (top_p[:, None] >= 1.0),
-                  1.0, top_p[:, None])
-    # token at sorted position j survives if the cumulative mass *before* it
-    # is < p (so the first token always survives).
-    sorted_keep_p = (cum - sorted_probs) < p
-    keep_p = jnp.zeros_like(keep).at[
-        jnp.arange(B)[:, None], sort_idx
-    ].set(sorted_keep_p)
+        # top-p: keep the smallest prefix of sorted probs with cumsum >= p.
+        sorted_logits = jnp.take_along_axis(scaled, sort_idx, axis=-1)
+        sorted_probs = jax.nn.softmax(sorted_logits, axis=-1)
+        cum = jnp.cumsum(sorted_probs, axis=-1)
+        p = jnp.where((top_p[:, None] <= 0) | (top_p[:, None] >= 1.0),
+                      1.0, top_p[:, None])
+        # token at sorted position j survives if the cumulative mass
+        # *before* it is < p (so the first token always survives).
+        sorted_keep_p = (cum - sorted_probs) < p
+        keep_p = jnp.zeros_like(keep).at[
+            jnp.arange(B)[:, None], sort_idx
+        ].set(sorted_keep_p)
 
     masked = jnp.where(keep & keep_p, scaled, NEG_INF)
     sampled = jax.random.categorical(key, masked, axis=-1).astype(jnp.int32)
@@ -136,6 +138,7 @@ def seen_mask(token_history: jax.Array, valid_len: jax.Array,
     ].max(pos_valid)
 
 
+@jax.named_scope("tail")
 def apply_repetition_penalty(logits: jax.Array, seen: jax.Array,
                              penalty: jax.Array) -> jax.Array:
     """CTRL-style repetition penalty over already-seen tokens.

@@ -97,15 +97,22 @@ def sparse_moe_ffn(x: jax.Array, lp: dict[str, jax.Array],
     B, S, D = x.shape
     T = B * S
     x_flat = x.reshape(T, D)
-    logits = x_flat.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
     C = expert_capacity(T, cfg.num_experts, cfg.num_experts_per_tok,
                         cfg.moe_capacity_factor)
-    expert, slot, weight, keep = route_topk(logits,
-                                            cfg.num_experts_per_tok, C)
-    expert_in = _dispatch(x_flat, expert, slot, keep, cfg.num_experts, C)
-    expert_out = _expert_ffn(expert_in, lp["w_gate"], lp["w_up"],
-                             lp["w_down"])
-    return _combine(expert_out, expert, slot, weight, keep, T).reshape(B, S, D)
+    # Stage scopes (models/llama.py SCOPES): routing, the capacity
+    # scatter and the weighted gather are ``moe_route``; the expert
+    # einsums — the weight stream — are ``moe_experts``.
+    with jax.named_scope("moe_route"):
+        logits = x_flat.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
+        expert, slot, weight, keep = route_topk(
+            logits, cfg.num_experts_per_tok, C)
+        expert_in = _dispatch(x_flat, expert, slot, keep, cfg.num_experts, C)
+    with jax.named_scope("moe_experts"):
+        expert_out = _expert_ffn(expert_in, lp["w_gate"], lp["w_up"],
+                                 lp["w_down"])
+    with jax.named_scope("moe_route"):
+        return _combine(expert_out, expert, slot, weight, keep,
+                        T).reshape(B, S, D)
 
 
 def ep_expert_ffn(mesh: Mesh, expert_in: jax.Array, w_gate: jax.Array,
@@ -131,13 +138,17 @@ def ep_sparse_moe_ffn(mesh: Mesh, x: jax.Array, lp: dict[str, jax.Array],
     B, S, D = x.shape
     T = B * S
     x_flat = x.reshape(T, D)
-    logits = x_flat.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
     C = expert_capacity(T, cfg.num_experts, cfg.num_experts_per_tok,
                         cfg.moe_capacity_factor)
     # capacity must tile over ep shards evenly for the shard_map specs
-    expert, slot, weight, keep = route_topk(logits,
-                                            cfg.num_experts_per_tok, C)
-    expert_in = _dispatch(x_flat, expert, slot, keep, cfg.num_experts, C)
-    expert_out = ep_expert_ffn(mesh, expert_in, lp["w_gate"], lp["w_up"],
-                               lp["w_down"])
-    return _combine(expert_out, expert, slot, weight, keep, T).reshape(B, S, D)
+    with jax.named_scope("moe_route"):
+        logits = x_flat.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
+        expert, slot, weight, keep = route_topk(
+            logits, cfg.num_experts_per_tok, C)
+        expert_in = _dispatch(x_flat, expert, slot, keep, cfg.num_experts, C)
+    with jax.named_scope("moe_experts"):
+        expert_out = ep_expert_ffn(mesh, expert_in, lp["w_gate"],
+                                   lp["w_up"], lp["w_down"])
+    with jax.named_scope("moe_route"):
+        return _combine(expert_out, expert, slot, weight, keep,
+                        T).reshape(B, S, D)

@@ -123,7 +123,11 @@ def test_paged_attention_kernel_compiles(topo, batch, heads, kv_heads,
         sds((batch, H, hd), jnp.bfloat16, dev), pool, pool, scales, scales,
         i32(batch, W), i32(batch), cur, cur, i32(batch), i32(batch),
         i32(1)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's stable name, for whoever reads a profile
+    assert ("paged_attn_decode_int8kv" if kv_int8
+            else "paged_attn_decode") in text
 
 
 @pytest.mark.parametrize("group", [0, 128], ids=["perchannel", "g128"])
@@ -149,7 +153,8 @@ def test_int4_matmul_kernel_compiles(topo, K, N, group, M):
     compiled = int4_matmul.lower(
         sds((M, K), jnp.bfloat16, dev), sds((K // 2, N), jnp.int8, dev),
         scale).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and '"int4_matmul"' in text
 
 
 @pytest.mark.parametrize("greedy", [True, False], ids=["greedy", "sampled"])

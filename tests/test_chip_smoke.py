@@ -88,13 +88,17 @@ def test_compile_cache_placed_from_outside_or_fixed(monkeypatch):
                         lambda name, value: updates.append((name, value)))
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/outside")
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # wherever a cache is in force its key takes the metadata in: the
+    # stage scopes a profile reads must be this checkout's own
+    keyed = ("jax_compilation_cache_include_metadata_in_key", True)
     assert compile_cache.enable_compile_cache() == "/placed/outside"
-    assert updates == []
+    assert updates == [keyed]
 
+    del updates[:]
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     fixed = os.path.join(REPO, ".jax_cache")
     assert compile_cache.enable_compile_cache() == fixed
-    assert updates == [("jax_compilation_" + "cache_dir", fixed)]
+    assert updates == [keyed, ("jax_compilation_" + "cache_dir", fixed)]
     assert compile_cache.enable_compile_cache() == fixed   # never moves
 
     # CPU exclusion: XLA:CPU results encode the build host's features

@@ -6,7 +6,8 @@ ship undocumented."""
 
 import pytest
 
-from tools.check_metrics_docs import (BEGIN, END, ROUNDS_BEGIN, ROUNDS_END,
+from tools.check_metrics_docs import (BEGIN, END, PROCESS_BEGIN,
+                                      PROCESS_END, ROUNDS_BEGIN, ROUNDS_END,
                                       ROUTER_BEGIN, ROUTER_END, check,
                                       documented_gauges,
                                       documented_round_metrics,
@@ -23,7 +24,8 @@ def test_checker_flags_ghost_and_missing_gauges():
     ghost = (f"{BEGIN}\n| `engine_requests` | x |\n"
              f"| `engine_not_a_real_stat` | x |\n{END}\n"
              f"{ROUTER_BEGIN}{ROUTER_END}"   # other fences: own tests
-             f"{ROUNDS_BEGIN}{ROUNDS_END}")
+             f"{ROUNDS_BEGIN}{ROUNDS_END}"
+             f"{PROCESS_BEGIN}{PROCESS_END}")
     errors = check(ghost)
     assert any("engine_not_a_real_stat" in e for e in errors)
     assert any("engine_tokens_generated" in e for e in errors)  # missing
