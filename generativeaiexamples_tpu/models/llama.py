@@ -823,6 +823,17 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
         v = qmm(x, lp["wv"])
         if "bq" in lp:
             q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+        # Keep the head split OUT of the matmuls. Without the barrier the
+        # TPU compiler folds `reshape(B, S, H, hd)` into each dot and
+        # emits a convolution over the head axis whose kernel is the
+        # weight viewed [K, H, hd] and wanted K-minor: the whole stacked
+        # weight is then transposed into a temporary once a program, and
+        # each layer's slice is copied out of it BEFORE its matmul
+        # instead of streaming through it. With it the three compile as
+        # wo / w_up / w_down do: one fusion that takes (stack, layer
+        # index, scale, x), the slice inside, the stack read in place
+        # (tests/test_chip_compile.py holds this on the compiled text).
+        q, k, v = jax.lax.optimization_barrier((q, k, v))
         q = q.reshape(B, S, cfg.num_heads, cfg.head_dim)
         k = k.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
         v = v.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)

@@ -353,3 +353,153 @@ def test_tp4_decode_step_compiles(topo, tpu_backend, monkeypatch):
                 for x in jax.tree.leaves((shapes, pool, pool)))
     per_dev = compiled.memory_analysis().argument_size_in_bytes
     assert per_dev < 0.45 * whole, (per_dev, whole)
+
+
+# --------------------------------- how the layer scan reads its weights
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk_512"])
+@pytest.mark.parametrize("model", ["nemotron-8b-chat",
+                                   "mixtral-8x7b-instruct"])
+def test_layer_weights_are_read_in_place(topo, tpu_backend, model, program):
+    """Every stacked int8 layer matrix is consumed by the fusion that
+    holds its matmul: the per-layer slice happens INSIDE that fusion and
+    the stack is read in the layout it is stored in. What this guards
+    against (ISSUE 26): the TPU compiler folding the head reshape that
+    follows the q/k/v projections into the dot, which turns each into a
+    convolution over the head axis whose kernel wants the weight K-minor
+    — a transposing ``copy`` of the whole stack a program, and every
+    layer's slice copied out before its matmul instead of streaming
+    through it (2.9 of 17.5 ms a decode step on nemotron-8b-chat)."""
+    from tools.dump_hlo import weight_report
+    # the benchmark's widths (benchmarks/configs/*.json), 2 layers deep
+    cfg = dataclasses.replace(get_model_config(model), num_layers=2)
+    dev = SingleDeviceSharding(topo.devices[0])
+    params = on(param_shapes(cfg), dev)
+    N, W = 65, 8
+    pool = sds((2, N, cfg.num_kv_heads, PAGE, cfg.head_dim),
+               jnp.bfloat16, dev)
+    cache = {"k": pool, "v": pool}
+    i32 = lambda *shape: sds(shape, jnp.int32, dev)  # noqa: E731
+    if program == "decode_step":
+        B = 16
+
+        def step(params, tok, pos, cache, tbl, valid, wp, off):
+            return llama.apply_decode_paged(
+                params, cfg, tok, pos, cache, tbl, valid, wp, off,
+                use_kernel=True)
+
+        lowered = jax.jit(step, donate_argnums=(3,)).lower(
+            params, i32(B, 1), i32(B, 1), cache, i32(B, W), i32(B),
+            i32(B), i32(B))
+    else:
+        def chunk(params, tok, pos, cache, tbl, valid, start):
+            return llama.apply_prefill_paged(params, cfg, tok, pos, cache,
+                                             tbl, valid, start)
+
+        lowered = jax.jit(chunk, donate_argnums=(3,)).lower(
+            params, i32(1, 512), i32(1, 512), cache, i32(1, W), i32(1),
+            i32())
+    report = weight_report(lowered.compile().as_text())
+    expected = {"wq", "wk", "wv", "wo"}
+    if not cfg.num_experts:     # a MoE block's experts are bf16 einsums
+        expected |= {"w_up", "w_down"}      # squared-ReLU FFN: no gate
+    assert set(report["weights"]) == expected, report
+    assert not report["stack_copies"], report["stack_copies"]
+    assert not report["slice_results"], report["slice_results"]
+    assert set(report["matmul_fusions"]) == expected, report
+
+
+_T3 = ("(s32[]{:T(128)}, bf16[8,64]{1,0:T(8,128)(2,1)}, "
+       "/*index=2*/s8[2,64,64]{2,1,0:T(8,128)(4,1)})")
+
+# A two-layer scan over one stacked weight, as optimised HLO prints it.
+# %(fused)s are the fusions' computations, %(layer)s the loop body's
+# use of the stack %%w, %(stack)s what the entry hands the loop.
+_HLO_LOOP = """HloModule jit_step, is_scheduled=true
+
+%(fused)s
+
+%%cond (arg: (s32[], bf16[8,64], s8[2,64,64])) -> pred[] {
+  %%arg = """ + _T3 + """ parameter(0)
+  %%i = s32[]{:T(128)} get-tuple-element(%%arg), index=0
+  %%n = s32[]{:T(128)} constant(2)
+  ROOT %%lt = pred[]{:T(512)} compare(%%i, %%n), direction=LT
+}
+
+%%body (arg: (s32[], bf16[8,64], s8[2,64,64])) -> (s32[], bf16[8,64], s8[2,64,64]) {
+  %%arg = """ + _T3 + """ parameter(0)
+  %%i = s32[]{:T(128)} get-tuple-element(%%arg), index=0
+  %%h = bf16[8,64]{1,0:T(8,128)(2,1)} get-tuple-element(%%arg), index=1
+  %%w = s8[2,64,64]{2,1,0:T(8,128)(4,1)} get-tuple-element(%%arg), index=2
+%(layer)s
+  ROOT %%t = """ + _T3 + """ tuple(%%i, %%fusion.7, %%w)
+}
+
+ENTRY %%main (h0: bf16[8,64], wq: s8[2,64,64]) -> bf16[8,64] {
+  %%h0 = bf16[8,64]{1,0:T(8,128)(2,1)} parameter(0)
+  %%wq = s8[2,64,64]{2,1,0:T(8,128)(4,1)} parameter(1), sharding={replicated}, metadata={op_name="params[\\'layers\\'][\\'wq\\'][\\'q\\']"}
+%(stack)s
+  %%zero = s32[]{:T(128)} constant(0)
+  %%init = """ + _T3 + """ tuple(%%zero, %%h0, %%stack)
+  %%loop = """ + _T3 + """ while(%%init), condition=%%cond, body=%%body
+  ROOT %%out = bf16[8,64]{1,0:T(8,128)(2,1)} get-tuple-element(%%loop), index=1
+}
+"""
+
+_HLO_FORMS = {
+    # the slice inside the matmul's fusion, the stack read as stored
+    "in_place": dict(
+        fused="""%fused_dot (p0: s8[2,64,64], p1: s32[], p2: bf16[8,64]) -> bf16[8,64] {
+  %p0 = s8[2,64,64]{2,1,0:T(8,128)(4,1)} parameter(0)
+  %p1 = s32[]{:T(128)} parameter(1)
+  %p2 = bf16[8,64]{1,0:T(8,128)(2,1)} parameter(2)
+  %ds = s8[1,64,64]{2,1,0:T(8,128)(4,1)} dynamic-slice(%p0, %p1), dynamic_slice_sizes={1,64,64}
+  %b = s8[64,64]{1,0:T(8,128)(4,1)} bitcast(%ds)
+  ROOT %dot.1 = bf16[8,64]{1,0:T(8,128)(2,1)} convolution(%p2, %b), dim_labels=bf_io->bf
+}""",
+        layer="  %fusion.7 = bf16[8,64]{1,0:T(8,128)(2,1)} "
+              "fusion(%w, %i, %h), kind=kOutput, calls=%fused_dot, "
+              'metadata={op_name="jit(step)/while/body/attn_proj/dot"}',
+        stack="  %stack = s8[2,64,64]{2,1,0:T(8,128)(4,1)} bitcast(%wq)"),
+    # as the parent compiled it: the stack transposed once, each layer's
+    # slice copied out of it, the matmul fed from the copy
+    "copied": dict(
+        fused="""%fused_slice (p0: s8[2,64,64], p1: s32[]) -> s8[1,64,64] {
+  %p0 = s8[2,64,64]{1,2,0:T(8,128)(4,1)} parameter(0)
+  %p1 = s32[]{:T(128)} parameter(1)
+  ROOT %ds = s8[1,64,64]{1,2,0:T(8,128)(4,1)} dynamic-slice(%p0, %p1), dynamic_slice_sizes={1,64,64}
+}
+
+%fused_dot_sliced (p0: s8[1,64,64], p2: bf16[8,64]) -> bf16[8,64] {
+  %p0 = s8[1,64,64]{1,2,0:T(8,128)(4,1)} parameter(0)
+  %p2 = bf16[8,64]{1,0:T(8,128)(2,1)} parameter(1)
+  %b = s8[64,64]{0,1:T(8,128)(4,1)} bitcast(%p0)
+  ROOT %dot.2 = bf16[8,64]{1,0:T(8,128)(2,1)} convolution(%p2, %b), dim_labels=bf_io->bf
+}""",
+        layer="  %slice_fusion.3 = s8[1,64,64]{1,2,0:T(8,128)(4,1)} "
+              "fusion(%w, %i), kind=kLoop, calls=%fused_slice\n"
+              "  %fusion.7 = bf16[8,64]{1,0:T(8,128)(2,1)} "
+              "fusion(%slice_fusion.3, %h), kind=kOutput, "
+              "calls=%fused_dot_sliced",
+        stack="  %stack = s8[2,64,64]{1,2,0:T(8,128)(4,1)} copy(%wq)"),
+}
+
+
+@pytest.mark.parametrize("form", ["in_place", "copied"])
+def test_weight_report_follows_a_stack_through_the_loop(form):
+    """``tools/dump_hlo.weight_report`` on hand-written optimised HLO:
+    a weight is named only at the entry parameter, and is found again
+    as an element of the ``while`` tuple inside the layer loop."""
+    from tools.dump_hlo import weight_report
+    report = weight_report(_HLO_LOOP % _HLO_FORMS[form])
+    assert report["weights"] == ["wq"]
+    if form == "in_place":
+        assert report["matmul_fusions"] == {"wq": ["fusion.7"]}
+        assert not report["stack_copies"] and not report["slice_results"]
+    else:
+        assert report["matmul_fusions"] == {}
+        assert [c.split(" ")[0] for c in report["stack_copies"]] == [
+            "stack"]
+        assert [c.split(" ")[0] for c in report["slice_results"]] == [
+            "slice_fusion.3"]

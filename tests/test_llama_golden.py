@@ -149,6 +149,62 @@ def test_moe_forward_runs():
     assert bool(jnp.isfinite(logits).all())
 
 
+@pytest.mark.parametrize("attn_bias", [False, True], ids=["nobias", "bias"])
+@pytest.mark.parametrize("quant", ["", "int8", "int4_awq"],
+                         ids=["raw", "int8", "int4g32"])
+def test_decoder_layer_barrier_changes_no_arithmetic(monkeypatch, quant,
+                                                     attn_bias):
+    """``decoder_layer`` holds an ``optimization_barrier`` between the
+    q/k/v matmuls and the head reshape — a fence for the TPU compiler's
+    fusion choices (tests/test_chip_compile.py), not arithmetic. Output
+    and the gradient of a scalar loss (what ``training.py`` takes through
+    the layer) must equal, bit for bit, the layer without it."""
+    import dataclasses
+
+    from generativeaiexamples_tpu.ops.quant import quantize_params
+    cfg = dataclasses.replace(LLAMA_TINY, attn_bias=attn_bias)
+    params = llama.init_params(cfg, jax.random.key(1), dtype=jnp.bfloat16)
+    if quant:
+        params = quantize_params(params, quant, group_size=32)
+    lp = jax.tree.map(lambda x: x[0], params["layers"])
+    if attn_bias:   # init_params zero-fills biases: make them count
+        for i, b in enumerate(("bq", "bk", "bv", "bo")):
+            lp[b] = jax.random.normal(jax.random.key(10 + i), lp[b].shape,
+                                      jnp.float32).astype(lp[b].dtype)
+    B, S = 2, 9
+    h = jax.random.normal(jax.random.key(2), (B, S, cfg.hidden_size),
+                          jnp.float32).astype(jnp.bfloat16)
+    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    inv_freq = llama.rope_frequencies(cfg.head_dim, cfg.rope_theta,
+                                      cfg.rope_scaling_factor)
+    probe = jax.random.normal(jax.random.key(3), h.shape, jnp.float32)
+
+    # raw weights are differentiable too; quantized leaves are integers
+    argnums = (0,) if quant else (0, 1)
+
+    def run():      # fresh closures: each call traces anew
+        def layer(h, lp):
+            out, _ = llama.decoder_layer(h, lp, cfg, pos, inv_freq,
+                                         jnp.full((B,), S, jnp.int32))
+            return out
+
+        def loss(h, lp):
+            return jnp.sum(layer(h, lp).astype(jnp.float32) * probe)
+
+        return (jax.jit(layer)(h, lp),
+                jax.jit(jax.grad(loss, argnums=argnums))(h, lp))
+
+    fenced = run()
+    skipped = []
+    monkeypatch.setattr(jax.lax, "optimization_barrier",
+                        lambda x: (skipped.append(1), x)[1])
+    plain = run()
+    assert len(skipped) == 2    # forward and gradient both traced anew
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+        np.asarray(a.astype(jnp.float32)), np.asarray(b.astype(jnp.float32))),
+        fenced, plain)
+
+
 def _meta_state_dict(hf_model, cfg):
     """Render HF weights under Meta/fairscale names + interleaved RoPE."""
     import torch
