@@ -91,11 +91,15 @@ class Context:
 
     # ----------------------------------------------- occupancy (roofline)
 
-    def occupancy(self, samples: int = 400) -> Optional[tuple]:
-        """Mean active decoding sequences and mean total context tokens
-        over the traced interval, from the load generator's stamps: a
-        request decodes from its first token to its finish, its context
-        growing by one token a step (linearly in between)."""
+    def occupancy(self, samples: int = 400) -> Optional[list]:
+        """The contexts of the sequences decoding at each of ``samples``
+        instants of the traced interval, ONE BY ONE (a list of lists;
+        instants with none decoding are left out), from the load
+        generator's stamps: a request decodes from its first token to
+        its finish, its context growing by one token a step (linearly in
+        between). A cost that is not linear in a row's context (a window
+        layer attends ``min(context, window)``) is taken per row and
+        instant and averaged after (``mean_occupancy``)."""
         if self.trace_t0 is None or self.trace_t1 is None:
             return None
         spans = []
@@ -105,17 +109,27 @@ class Context:
             end = r.finish_t if r.finish_t is not None else self.trace_t1
             spans.append((r.first_token_t, max(end, r.first_token_t + 1e-9),
                           len(r.request.prompt_ids), r.tokens))
-        rows_sum = ctx_sum = n = 0
+        out = []
         for i in range(samples):
             t = self.trace_t0 + (i + 0.5) / samples * (
                 self.trace_t1 - self.trace_t0)
             active = [(p + toks * (t - a) / (b - a))
                       for a, b, p, toks in spans if a <= t <= b]
             if active:
-                rows_sum += len(active)
-                ctx_sum += sum(active)
-                n += 1
-        return (rows_sum / n, ctx_sum / n) if n else None
+                out.append(active)
+        return out or None
+
+    def mean_occupancy(self, attended=sum, samples: int = 400
+                       ) -> Optional[tuple]:
+        """Mean active decoding sequences and mean attended context
+        tokens over the traced interval: ``attended(contexts)`` of each
+        instant's rows (their sum; ``costs.attended_tokens`` where
+        layers attend a window)."""
+        occ = self.occupancy(samples)
+        if occ is None:
+            return None
+        return (sum(len(a) for a in occ) / len(occ),
+                sum(attended(a) for a in occ) / len(occ))
 
 
 def read_layer_metric(ctx: Context, metric: dict) -> Optional[float]:

@@ -8,6 +8,14 @@ the attention, dense-MLP and lm_head matrices as one byte a weight plus
 a float32 scale per output channel; expert matrices stay bf16, as
 ``ops/quant.py`` leaves them; the embedding stays bf16 and a step reads
 only the rows it looks up).
+
+Two optional keys of the ``model`` group say which layers attend a
+window, named for what they are and for no model: ``sliding_window``
+(tokens; absent or 0 = none) and ``window_layers`` (one 0/1 a layer, or
+a period that is repeated over the layers; absent = all 0). A window
+layer attends ``min(context, window)`` tokens of a row, so the KV bytes
+and attention operations are summed over layers AND rows
+(``attended_tokens``): the rows' contexts one by one, never their sum.
 """
 
 from __future__ import annotations
@@ -68,8 +76,51 @@ def expected_experts_touched(experts: int, per_tok: int, rows: float) -> float:
 
 
 def kv_bytes_per_token(m: dict, kv_dtype_bytes: int = 2) -> int:
+    """Bytes one token's keys and values take over ALL layers (a window
+    layer stores a token like any other, until it is released)."""
     return (m["num_layers"] * m["num_kv_heads"] * m["head_dim"]
             * 2 * kv_dtype_bytes)
+
+
+def layer_windows(m: dict) -> list:
+    """Per layer, the window it attends in tokens (0 = the whole
+    context), from ``sliding_window`` and ``window_layers``."""
+    L = m["num_layers"]
+    window = int(m.get("sliding_window") or 0)
+    pattern = list(m.get("window_layers") or [])
+    if not window or not pattern:
+        return [0] * L
+    return [window if pattern[i % len(pattern)] else 0 for i in range(L)]
+
+
+def attended_tokens(m: dict, contexts) -> float:
+    """Context tokens one decode step attends, summed over the rows and
+    AVERAGED over the layers: each layer attends ``min(context, its
+    window)`` of each row. Without a window layer this is the sum of the
+    contexts, exactly."""
+    windows = layer_windows(m)
+    if not any(windows):
+        return sum(contexts)
+    return sum(sum(min(c, w) if w else c for c in contexts)
+               for w in windows) / len(windows)
+
+
+def attended_in_prefill(m: dict, tokens: int, context: int) -> float:
+    """Sum over a chunk's ``tokens`` new tokens of the context tokens
+    each attends (causal: ``context`` cached ones plus half the chunk),
+    averaged over the layers. Without a window layer:
+    tokens x (context + tokens / 2), exactly."""
+    def one(w):
+        if not w or context + tokens <= w:
+            return tokens * (context + tokens / 2)
+        if context >= w:
+            return tokens * w
+        grow = w - context           # tokens whose context still grows
+        return grow * (context + grow / 2) + (tokens - grow) * w
+    windows = layer_windows(m)
+    if not any(windows):
+        return one(0)
+    return sum(one(w) for w in windows) / len(windows)
 
 
 def weight_bytes_resident(m: dict, quant: str) -> int:
@@ -93,7 +144,9 @@ def weight_bytes_resident(m: dict, quant: str) -> int:
 def decode_step(m: dict, quant: str, rows: float, kv_tokens: float,
                 kv_dtype_bytes: int = 2) -> dict:
     """Operations and bytes ONE decode step needs with ``rows`` active
-    sequences whose contexts sum to ``kv_tokens`` tokens.
+    sequences that attend ``kv_tokens`` context tokens: the sum of their
+    contexts, or with window layers ``attended_tokens`` of them (a mean
+    over time of either is as good: every term is linear in it).
 
     Bytes: every matmul weight once as stored (for experts: the experts
     the rows reach, in bf16), the lm_head once, the KV cache of the live
@@ -146,10 +199,47 @@ def prefill_tokens(m: dict, quant: str, tokens: int, context: int = 0) -> dict:
         mlp_f = sum(2 * r * c for r, c in s["mlp"])
     kv_b = kv_bytes_per_token(m)
     flops = tokens * L * (attn_f + mlp_f) + 2 * D * V \
-        + L * 4 * H * hd * tokens * (context + tokens / 2)
+        + L * 4 * H * hd * attended_in_prefill(m, tokens, context)
     bytes_ = L * (attn_b + mlp_b) + _wbytes(D, V, quant) \
-        + (context + tokens) * kv_b
+        + (attended_tokens(m, [context]) + tokens) * kv_b
     return {"bytes": bytes_, "flops": flops}
+
+
+STAGES = ("attn", "mlp", "tail")
+
+
+def decode_stage(m: dict, quant: str, stage: str, rows: float,
+                 kv_tokens: float, kv_dtype_bytes: int = 2) -> dict:
+    """Operations and bytes ONE named stage of a decode step needs, with
+    ``rows`` and ``kv_tokens`` as ``decode_step`` takes them. The stages
+    are the program's scopes (``readers/device_scope.py``): ``attn`` =
+    the KV cache of the live contexts once plus one new row a sequence
+    (the projections are a stage of their own, not counted here);
+    ``mlp`` = the MLP's weights, or the router and the touched experts'
+    weights; ``tail`` = the ``lm_head`` as stored, once. The parts do
+    not add up to the step: projections, norms and the embedding are in
+    none of them."""
+    s = layer_shapes(m)
+    L, D, V = m["num_layers"], m["hidden_size"], m["vocab_size"]
+    H, hd = m["num_heads"], m["head_dim"]
+    if stage == "attn":
+        kv_b = kv_bytes_per_token(m, kv_dtype_bytes)
+        return {"bytes": (kv_tokens + rows) * kv_b,
+                "flops": L * 4 * H * hd * kv_tokens}
+    if stage == "mlp":
+        if s["experts"]:
+            k = m.get("num_experts_per_tok", 2)
+            one = sum(r * c for r, c in s["mlp"])
+            touched = expected_experts_touched(s["experts"], k, rows)
+            return {"bytes": L * (touched * 2 * one + 2 * D * s["experts"]),
+                    "flops": rows * L * (k * 2 * one + 2 * D * s["experts"])}
+        return {"bytes": L * sum(_wbytes(r, c, quant) for r, c in s["mlp"]),
+                "flops": rows * L * sum(2 * r * c for r, c in s["mlp"])}
+    if stage == "tail":
+        head_b = (_wbytes(D, V, quant)
+                  if not m.get("tie_word_embeddings", False) else 2 * D * V)
+        return {"bytes": head_b, "flops": rows * 2 * D * V}
+    raise ValueError(f"no cost for stage {stage!r}; known: {STAGES}")
 
 
 def least_seconds(cost: dict, peak: dict, quant: str = "") -> dict:

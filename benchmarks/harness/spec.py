@@ -55,6 +55,18 @@ class Cell:
     per_layer: list         # layer_metrics/<name>.json, merged entries
 
 
+def check_config(config: dict, path: str) -> None:
+    """A configuration names its plain reference: a file under
+    ``references/``, which decides ``correct``."""
+    ref = config.get("reference")
+    if not isinstance(ref, str) or not NAME_RE.match(ref):
+        raise SpecError(f"{path}: a configuration names its plain "
+                        f"reference (\"reference\": \"<name>\" loads "
+                        f"benchmarks/references/<name>.py)")
+    if not os.path.exists(os.path.join(HERE, "references", ref + ".py")):
+        raise SpecError(f"{path}: no benchmarks/references/{ref}.py")
+
+
 class Spec:
     def __init__(self, benchmark_json: str | None = None,
                  data_dir: str | None = None):
@@ -101,6 +113,7 @@ class Spec:
                             f"{entry['config']!r}")
         config = load_json(os.path.join(os.path.dirname(self.path),
                                         cfg_entry["file"]))
+        check_config(config, cfg_entry["file"])
         workload = self._data("workloads", name)
         if (workload["config"], workload["traffic"]) != (
                 entry["config"], entry["traffic"]):

@@ -6,8 +6,10 @@ Copied in idea from ``chip_smoke.py`` (``make_params``,
 ``engine_report``, ``check_engine``, ``device_info``) and kept here so a
 later change to that script cannot move the yardstick. From the package
 this module takes only what is measured: ``Engine``, ``EngineConfig``,
-``SamplingParams``, ``llama``, ``quantize_params``, the tokenizer, the
-encoder's parameters and ``enable_compile_cache``.
+``SamplingParams``, the paged model programs of ``llama`` and its weight
+initialiser, ``quantize_params``, the tokenizer, the encoder's
+parameters and ``enable_compile_cache``. What the paged programs are
+held to is the benchmark's own: ``benchmarks/references/``.
 """
 
 from __future__ import annotations
@@ -152,32 +154,45 @@ def make_tokenizer(vocab_size: int):
 # ---------------------------------------------------------- logits check
 
 
+def load_reference(config: dict):
+    """The configuration's plain reference, found by name like a reader:
+    ``"reference": "<name>"`` is ``benchmarks/references/<name>.py``."""
+    import importlib
+    return importlib.import_module(
+        f"benchmarks.references.{config['reference']}")
+
+
 def logits_check(params, cfg, config: dict, seed: int,
-                 kv_quantized: bool = False) -> dict:
-    """The paged path against the plain forward, on the cell's weights.
+                 kv_quantized: bool = False, forward=None) -> dict:
+    """The paged path against the configuration's plain reference, on
+    the cell's weights as they are stored.
 
     For ``prompts`` seeded prompts (a whole number of pages, one
-    sequence at a time, so capacity routing groups the same tokens on
-    both sides and drops nothing at decode): the logits of
-    ``apply_prefill_paged`` at the last ``positions`` prompt positions,
-    and the logits of ``decode_steps`` teacher-forced steps of
-    ``apply_decode_paged`` (the Pallas kernel where the engine arms it),
-    against ``llama.apply`` over a dense cache. The error of one
-    position is max |paged - plain| over max |plain|.
+    sequence at a time): the logits of ``apply_prefill_paged`` at the
+    last ``positions`` prompt positions, and the logits of
+    ``decode_steps`` teacher-forced steps of ``apply_decode_paged``
+    through the pool (the Pallas kernel where the engine arms it) —
+    prefill and decoding through the cache — against ONE float32 pass
+    without a cache: ``benchmarks/references/<name>.py`` ``forward``
+    over prompt + chain (``forward`` overrides it: the tests put a
+    broken one there). The greedy chain is the reference's own, a
+    forward a step over one buffer of prompt + ``decode_steps`` ids
+    (causal: what follows a position does not move it). The error of
+    one position is max |paged - reference| over max |reference|.
 
     Held, each at the configuration's own number: the MEDIAN prompt
     position and the median decode step to ``median_tolerance``, and the
     share of positions over ``tolerance`` to ``max_share_over`` (0 holds
     the maximum). A dense model holds every position. With random
     weights a sparse-expert router has near-ties, and the few bf16 ulps
-    by which the two attention orders differ flip a top-2 choice at some
-    positions (on the chip about one in ten over four Mixtral layers),
+    by which the program's stream differs from float32 flip a top-2
+    choice (or which assignment a full expert drops) at some positions,
     which moves THAT position's logits by a third of their scale; a
     precision fault moves every position. ``kv_quantized`` runs the
     paged side over an int8 pool where bf16 is stated — the fault
-    ``check_sensitivity.py`` injects to show that these numbers catch
-    one. Returns the reference logits too, for the engine's own tokens
-    to be held against."""
+    ``check_sensitivity.py`` injects to show whether these numbers
+    catch one. Returns the reference logits too, for the engine's own
+    tokens to be held against."""
     import statistics
 
     import jax
@@ -186,6 +201,8 @@ def logits_check(params, cfg, config: dict, seed: int,
 
     from generativeaiexamples_tpu.models import llama
 
+    if forward is None:
+        forward = load_reference(config).forward
     lc = config["logits_check"]
     page = int(config["engine"].get("page_size", 128))
     S = int(lc.get("prompt_pages", 2)) * page
@@ -194,24 +211,25 @@ def logits_check(params, cfg, config: dict, seed: int,
     nb = -(-(S + n_dec) // page)
     use_kernel = llama.use_paged_kernel(cfg, page)
     i32 = lambda *v: jnp.asarray(v, jnp.int32)  # noqa: E731
+    # the model group as the file has it, and which tokens the paged
+    # side routes together (a reference under a capacity asks): the
+    # prompt as one chunk, then each decode step alone
+    model = dict(config["model"], routed_together=[S] + [1] * n_dec)
 
-    @jax.jit
-    def reference(p, ids):
-        pos = jnp.arange(S, dtype=jnp.int32)[None, :]
-        cache = llama.init_kv_cache(cfg, 1, nb * page, jnp.bfloat16)
-        logits, cache = llama.apply(p, cfg, ids, pos, cache,
-                                    kv_valid_len=i32(S))
-        prefill = logits[0, S - n_pos:].astype(jnp.float32)
-        nxt = jnp.argmax(prefill[-1]).astype(jnp.int32)
+    def reference(ids_np):
+        """Prefill logits, the greedy chain and each step's logits."""
+        buf = np.concatenate([ids_np, np.zeros(n_dec, ids_np.dtype)])
+        prefill = forward(params, model, buf[None],
+                          np.arange(S - n_pos, S))
+        nxt = int(jnp.argmax(prefill[-1]))
         toks, steps = [], []
         for i in range(n_dec):
             toks.append(nxt)
-            step, cache = llama.apply(p, cfg, nxt[None, None],
-                                      i32(S + i)[None], cache,
-                                      kv_valid_len=i32(S + i + 1))
-            steps.append(step[0, 0].astype(jnp.float32))
-            nxt = jnp.argmax(steps[-1]).astype(jnp.int32)
-        return prefill, jnp.stack(toks), jnp.stack(steps)
+            buf[S + i] = nxt
+            steps.append(forward(params, model, buf[None],
+                                 np.arange(S + i, S + i + 1))[0])
+            nxt = int(jnp.argmax(steps[-1]))
+        return prefill, jnp.asarray(toks, jnp.int32), jnp.stack(steps)
 
     @jax.jit
     def paged(p, ids, toks):
@@ -245,9 +263,9 @@ def logits_check(params, cfg, config: dict, seed: int,
     prompts = []
     for _ in range(int(lc["prompts"])):
         ids_np = rng.integers(3, cfg.vocab_size, size=S)
-        ids = jnp.asarray(ids_np, jnp.int32)[None, :]
-        ref_pre, toks, ref_steps = reference(params, ids)
-        got_pre, got_steps = paged(params, ids, toks)
+        ref_pre, toks, ref_steps = reference(ids_np)
+        got_pre, got_steps = paged(
+            params, jnp.asarray(ids_np, jnp.int32)[None, :], toks)
         check(bool(jnp.all(jnp.isfinite(got_pre))
                    & jnp.all(jnp.isfinite(got_steps))),
               "paged path: logits not finite")
@@ -261,21 +279,24 @@ def logits_check(params, cfg, config: dict, seed: int,
             "ref_logits": np.concatenate(
                 [np.asarray(ref_pre[-1:]), np.asarray(ref_steps)])})
     every = sorted(prefill_errs + decode_errs)
-    out = {"prompt_tokens": S, "kernel_path": bool(use_kernel),
+    out = {"reference": config["reference"], "prompt_tokens": S,
+           "kernel_path": bool(use_kernel),
            "positions": len(prefill_errs), "decode_steps": len(decode_errs),
            "prefill_median_rel_err": statistics.median(prefill_errs),
            "decode_median_rel_err": statistics.median(decode_errs),
+           "decode_rel_errs": decode_errs,      # the steps through the pool
            "rel_err_p75_p90": [every[int(0.75 * len(every))],
                                every[int(0.90 * len(every))]],
            "max_rel_err": every[-1],
            "share_over_tolerance": sum(e > tol for e in every) / len(every),
            "tolerance": tol, "median_tolerance": median_tol,
+           "max_share_over": float(lc["max_share_over"]),
            "prompts": prompts}
     faults = []
     for what in ("prefill", "decode"):
         got = out[f"{what}_median_rel_err"]
         if got > median_tol:
-            faults.append(f"{what} logits differ from the plain forward by "
+            faults.append(f"{what} logits differ from the reference by "
                           f"{got:.4f} of their scale at the median "
                           f"position (> {median_tol})")
     if out["share_over_tolerance"] > float(lc["max_share_over"]):

@@ -1,8 +1,10 @@
 """A decode step's share of its roofline: the least time the chip could
 take for the step (``harness/costs.py``: the larger of bytes over peak
 bytes/s and operations over peak FLOP/s, for the mean active rows and
-their mean total context over the traced interval, both from the load
-generator's own stamps) over the step's device time from the trace.
+the mean context tokens they attend over the traced interval — each
+row's own context against each layer's window, ``attended_tokens`` —
+both from the load generator's own stamps) over the step's device time
+from the trace.
 
 args: ``modules`` (regular expression of the decode round's module).
 Leaves ``ctx.notes["roofline"]`` with the bound that binds.
@@ -15,11 +17,12 @@ from benchmarks.readers import device_trace
 def read(ctx, modules):
     step_ms = device_trace.read(ctx, "module_ms_per", modules=modules,
                                 per="step")
-    occ = ctx.occupancy()
+    model = ctx.cell.config["model"]
+    occ = ctx.mean_occupancy(lambda c: costs.attended_tokens(model, c))
     if step_ms is None or occ is None:
         return None
     rows, kv_tokens = occ
-    cost = costs.decode_step(ctx.cell.config["model"],
+    cost = costs.decode_step(model,
                              ctx.cell.config.get("weight_quant", ""),
                              rows, kv_tokens)
     least = costs.least_seconds(cost, ctx.peaks)

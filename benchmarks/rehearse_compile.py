@@ -5,7 +5,9 @@ compiler says of their memory. Nothing runs; no time comes of it.
     JAX_PLATFORMS=cpu python benchmarks/rehearse_compile.py <config> \
         [--layers N] [--engine-layers M]
 
-Compiles (1) the weights program (init + quantize, ``--layers`` deep,
+Loads the configuration's plain reference as ``run.py`` does (a
+configuration without one is refused here as there) and says which it
+is; compiles (1) the weights program (init + quantize, ``--layers`` deep,
 default the configuration's own) and, on an engine built on the CPU with
 zero weights ``--engine-layers`` deep (default 2: the layers are a scan,
 so the programs' temporaries do not grow with depth), (2) the 8-step
@@ -55,6 +57,7 @@ def main(argv=None) -> int:
     jax.config.update("jax_enable_compilation_cache", False)
     config = spec.load_json(os.path.join(spec.HERE, "configs",
                                          args.config + ".json"))
+    spec.check_config(config, args.config)
     cfg = system.model_config(config)
     if args.layers:
         cfg = dataclasses.replace(cfg, num_layers=args.layers)
@@ -74,7 +77,8 @@ def main(argv=None) -> int:
         return quantize_params(p, quant) if quant else p
 
     key = jax.eval_shape(lambda: jax.random.key(0))
-    out = {"config": args.config, "layers": cfg.num_layers}
+    out = {"config": args.config, "layers": cfg.num_layers,
+           "reference": system.load_reference(config).__name__}
     out["weights_program"] = mem(jax.jit(
         make, out_shardings=dev).lower(key).compile())
     print(json.dumps(out), flush=True)

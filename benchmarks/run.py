@@ -4,7 +4,9 @@
         --trace <0|1>
 
 One process holds the chip from first to last: weights from the seed,
-the logits check, the engine, the warm-up (all counted as ``setup_s``),
+the logits check against the configuration's plain reference
+(``benchmarks/references/``), the engine, the warm-up (all counted as
+``setup_s``),
 then the measured window, then one JSON object on the LAST line of
 standard output: ``correct``, ``attempted``, ``failed``, ``metrics``,
 ``device`` (and ``breakdown`` with ``--trace 1``). ``--trace 0`` reports
@@ -76,6 +78,8 @@ class Runner:
         self.cell, self.seed = cell, int(seed)
         self.problems: list = []
         self.engine = None
+        self.logits: dict = {}      # the logits check's readings
+        self.tokens = None          # the engine-token check's
 
     # ------------------------------------------------------------ set-up
 
@@ -104,6 +108,7 @@ class Runner:
             return fn(*a, **kw)
         except CheckFailed as exc:
             self.problems.append(str(exc))
+            self.logits = getattr(exc, "readings", None) or self.logits
             return None
 
     def build(self, seconds: float) -> None:
@@ -146,6 +151,9 @@ class Runner:
             self.engine, cell, self.sampling,
             lambda: self.generator(self.seed + 1, seconds), self.log)
         t_warm = time.monotonic() - t
+        if ref is not None:
+            self.logits = {k: v for k, v in ref.items() if k != "prompts"}
+        self.tokens = tok
         self.report = system.engine_report(self.engine)
         self.programs = set(system.engine_programs(self.engine))
         self._try(system.check_engine, self.report, config)
@@ -155,8 +163,7 @@ class Runner:
               "engine_build_s": t_build, "warmup_s": t_warm,
               "programs_built": len(self.log.events),
               "from_cache": self.log.cache_hits,
-              "logits_check": None if ref is None else {
-                  k: v for k, v in ref.items() if k != "prompts"},
+              "logits_check": self.logits or None,
               "engine_tokens_check": tok, "warmup": warm,
               "engine": self.report})
 
@@ -319,6 +326,26 @@ class Runner:
             out["breakdown"] = ctx.trace.breakdown()
         return out
 
+    def compared(self) -> list:
+        """Each number ``correct`` compared beside its limit, one line
+        each: the last lines of a run's standard error."""
+        lc = self.cell.config["logits_check"]
+        g = self.logits
+        ref = f"references/{self.cell.config['reference']}.py"
+        lines = [f"logits_check vs {ref}: {what}_median_rel_err="
+                 f"{g.get(what + '_median_rel_err')} "
+                 f"limit={lc['median_tolerance']}"
+                 for what in ("prefill", "decode")]
+        lines.append(f"logits_check: share_over_{lc['tolerance']}="
+                     f"{g.get('share_over_tolerance')} (max_rel_err="
+                     f"{g.get('max_rel_err')}) limit={lc['max_share_over']}")
+        t = self.tokens or {}
+        lines.append(f"engine_tokens: within_{lc['tolerance']}="
+                     f"{t.get('within_tolerance')} of {t.get('compared')} "
+                     f"(worst_gap={t.get('worst_gap')}) "
+                     f"limit>={lc['min_token_agreement']} of compared")
+        return lines + [f"problem: {p}" for p in self.problems]
+
     def close(self) -> None:
         if self.engine is not None:
             self.engine.stop()
@@ -408,6 +435,7 @@ def main(argv=None) -> int:
         runner.close()
         faulthandler.cancel_dump_traceback_later()
     emit(result)
+    print("\n".join(runner.compared()), file=sys.stderr, flush=True)
     return 0
 
 

@@ -319,6 +319,44 @@ def test_round_fields_mean_and_share():
         round_fields.read(ctx, "pool_used_pages", "max")
 
 
+def test_stage_roofline_divides_a_stages_least_time_by_its_device_time(
+        scoped_ctx):
+    """On the recorded trace: the fixture's program is a toy, so only the
+    arithmetic is held — least time of the stage's own bytes and
+    operations over the stage's device time, a stage without time left
+    out."""
+    from benchmarks.harness import costs
+    from benchmarks.readers import stage_roofline
+    model = {"vocab_size": 32000, "hidden_size": 512,
+             "intermediate_size": 1024, "num_layers": 2, "num_heads": 8,
+             "num_kv_heads": 8, "head_dim": 64}
+    scoped_ctx.cell.config = {"model": model, "weight_quant": "int8"}
+    scoped_ctx.peaks = costs.peaks("TPU v5 lite")
+    assert stage_roofline.read(scoped_ctx, "attn", "(^|/)attn(/|$)",
+                               DECODE) is None        # no rows stamped
+    scoped_ctx.trace_t0, scoped_ctx.trace_t1 = 0.0, 1.0
+    stream = types.SimpleNamespace(first_token_time=-1.0, finish_time=2.0,
+                                   token_ids=[5] * 30, finish_reason="length")
+    from benchmarks.harness.loadgen import Row
+    from benchmarks.harness.traffic import Request
+    scoped_ctx.rows = [Row(Request(i, [3] * 100, 30, 1), 0.0, 0.0,
+                           stream=stream) for i in range(4)]
+    for stage in costs.STAGES:
+        scope = f"(^|/){stage}(/|$)"
+        share = stage_roofline.read(scoped_ctx, stage, scope, DECODE)
+        note = scoped_ctx.notes["stage_roofline"][stage]
+        ms = device_scope.read(scoped_ctx, scope, DECODE)
+        assert note["stage_ms"] == ms and share > 0
+        rows, kv = scoped_ctx.mean_occupancy()
+        assert rows == 4 and kv == pytest.approx(4 * 115.0, rel=0.01)
+        least = costs.least_seconds(costs.decode_stage(
+            model, "int8", stage, rows, kv), scoped_ctx.peaks)
+        assert share == pytest.approx(100 * least["seconds"] * 1e3 / ms)
+        assert note["bound"] == least["bound"]
+    assert stage_roofline.read(scoped_ctx, "attn", "no_such_scope",
+                               DECODE) is None
+
+
 # ------------------------------------------------- the entries themselves
 
 
@@ -326,7 +364,10 @@ NEW = {"decode_attn_ms": "device_scope", "decode_mlp_ms": "device_scope",
        "decode_tail_ms": "device_scope", "plan_ms_per_round": "host_spans",
        "dispatch_ms_per_program": "host_spans",
        "setup_programs": "engine_stats", "setup_program_s": "engine_stats",
-       "pool_blocked_rounds_pct": "round_fields"}
+       "pool_blocked_rounds_pct": "round_fields",
+       "decode_attn_roofline": "stage_roofline",
+       "decode_mlp_roofline": "stage_roofline",
+       "decode_tail_roofline": "stage_roofline"}
 
 
 @pytest.mark.parametrize("name,reader", sorted(NEW.items()))
@@ -354,7 +395,9 @@ def test_every_cell_reports_the_new_entries_the_issue_lists():
                   per_cell["nemotron-8b-chat.rag-prefill"])
     common = {"setup_programs", "setup_program_s"}
     decode = {"decode_attn_ms", "decode_mlp_ms", "decode_tail_ms",
-              "plan_ms_per_round", "dispatch_ms_per_program"}
+              "plan_ms_per_round", "dispatch_ms_per_program",
+              "decode_attn_roofline", "decode_mlp_roofline",
+              "decode_tail_roofline"}
     assert common | decode <= cs and "pool_blocked_rounds_pct" not in cs
     assert common | decode | {"pool_blocked_rounds_pct"} <= db
     assert common | {"tput.plan_ms_per_round", "pool_blocked_rounds_pct",

@@ -73,15 +73,19 @@ def test_config_entry(entry):
     assert m.num_layers == pub.get("num_hidden_layers",
                                    pub.get("num_layers"))
     for field in ("engine", "engine_why", "logits_check", "deployment",
-                  "assumed", "weight_quant", "encoder_resident", "chips"):
+                  "assumed", "weight_quant", "encoder_resident", "chips",
+                  "reference"):
         assert field in cfg
     assert set(cfg["engine"]) <= set(cfg["engine_why"])
+    # the plain reference is a file of its own, found by name
+    assert os.path.exists(os.path.join(
+        REPO, "benchmarks", "references", cfg["reference"] + ".py"))
     lc = cfg["logits_check"]
     assert set(lc) == {"prompts", "prompt_pages", "positions", "decode_steps",
                        "tolerance", "median_tolerance", "max_share_over",
                        "min_token_agreement", "why"}
     assert 0 < lc["median_tolerance"] <= lc["tolerance"] <= 0.05
-    assert 0 <= lc["max_share_over"] <= 0.2
+    assert 0 <= lc["max_share_over"] <= 0.3
     if not cfg["model"].get("num_experts"):
         # dense: every position held, and most engine tokens
         assert lc["max_share_over"] == 0 and lc["min_token_agreement"] >= 0.8
@@ -162,7 +166,8 @@ def test_layer_metric_entry_and_file_agree(m):
     # the file says how the quantity is read; which cells report it and
     # what it moves there are BENCHMARK.json's
     f = SPEC.layer_metric(m["name"])
-    assert set(f) == {"unit", "better", "source", "layer", "reader", "args"}
+    assert set(f) - {"note"} == {"unit", "better", "source", "layer",
+                                 "reader", "args"}
     for k in ("unit", "better", "source", "layer"):
         assert f[k] == m[k], k
     # moves names an end-to-end metric that each of its cells reports

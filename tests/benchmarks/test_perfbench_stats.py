@@ -123,10 +123,18 @@ def test_unknown_metric_raises():
 def test_occupancy_from_the_generators_stamps():
     c = ctx_of([row(0.0, 1.0, 3.0, 20), row(0.0, 2.0, 4.0, 20)])
     c.trace_t0, c.trace_t1 = 2.0, 3.0
-    rows_mean, kv = c.occupancy(samples=1000)
+    rows_mean, kv = c.mean_occupancy(samples=1000)
     assert rows_mean == pytest.approx(2.0)
     # contexts: 10 + 20*(t-1)/2 and 10 + 20*(t-2)/2, mean over [2,3]
     assert kv == pytest.approx(10 + 15 + 10 + 5, rel=1e-3)
+    # the rows one by one: a cost that is not linear in a row's context
+    # (a window) is taken per row, then averaged
+    occ = c.occupancy(samples=1000)
+    assert len(occ) == 1000 and all(len(a) == 2 for a in occ)
+    assert occ[0][0] == pytest.approx(20.0, abs=0.02)
+    assert occ[0][1] == pytest.approx(10.0, abs=0.02)
+    capped = c.mean_occupancy(lambda a: sum(min(x, 20.0) for x in a), 1000)
+    assert capped[1] == pytest.approx(20 + 15, rel=1e-3)
     c.trace_t0, c.trace_t1 = 8.0, 9.0
-    assert c.occupancy() is None
+    assert c.occupancy() is None and c.mean_occupancy() is None
     assert math.isinf(st.MISS)
