@@ -231,6 +231,17 @@ _STATS_TEMPLATE = {
     # refused for want of pages (RoundRecord.blocked_on_pages > 0).
     "pool_used_pages": 0,
     "pool_blocked_rounds": 0,
+    # Window layers (LlamaConfig.sliding_window): pages of the live
+    # contexts the decode rounds did NOT read because they lie behind a
+    # layer's window, in whole pages averaged over the layers (the unit
+    # of round_pages_touched). 0 for a model without window layers.
+    "kv_pages_skipped": 0.0,
+    # Dropless experts (moe_impl "dropless"): the sum over decode rounds
+    # of the mean distinct experts a layer's rows reached in a step, and
+    # the rounds that reported one (their ratio is the mean experts a
+    # layer streams a step). 0 for every other model.
+    "experts_touched_sum": 0.0,
+    "experts_touched_rounds": 0,
 }
 
 # The process's program build log (utils/compile_cache.py), read into
@@ -830,6 +841,22 @@ class Engine:
         # and the chip's peak bandwidth (0 on CPU — no roofline there).
         self._param_bytes = sum(
             int(x.nbytes) for x in jax.tree.leaves(self.params))
+        # Per-layer kinds of the model (models/configs.py): the share of
+        # its layers that attend a window (the decode KERNEL starts their
+        # page loop at the window's first page; the gather path masks
+        # and skips nothing), and whether its experts are dropless (the
+        # decode program then returns the experts its rows touched, and
+        # a step streams those experts' weights, not all).
+        mc = self.model_cfg
+        self._window_share = (
+            sum(1 for w in mc.layer_windows if w) / mc.num_layers
+            if self._use_kernel else 0.0)
+        self._moe_stats = bool(mc.num_experts
+                               and mc.moe_impl == "dropless")
+        self._expert_bytes = sum(
+            int(leaf.nbytes) for name in ("w_gate", "w_up", "w_down")
+            for leaf in jax.tree.leaves(self.params["layers"][name])
+        ) if self._moe_stats else 0
         dev0 = self._devices()[0]
         self._hbm_peak = 0.0 if dev0.platform == "cpu" else peak_bw(dev0)
         # Model-vs-measured drift: EWMA of (round wall / modeled round
@@ -1601,12 +1628,16 @@ class Engine:
                     # loop trips ceil(pos/page) times — an inactive slot
                     # (pos -> 0) streams nothing, so dead slots cost no HBM.
                     eff_pos = jnp.where(active, pos, 0)
-                    net, cache = llama.apply_decode_paged(
+                    # dropless experts: idle slots touch no expert, and
+                    # the step says how many its rows reached (``aux``)
+                    moe = (dict(active=active, stats=True)
+                           if self._moe_stats else {})
+                    net, cache, *aux = llama.apply_decode_paged(
                         params, mcfg, st["last_token"][:, None],
                         eff_pos[:, None], st["cache"], st["table"][:, :window],
                         pos + 1, wp, eff_pos % page,
                         use_kernel=self._use_kernel, mesh=self.mesh,
-                        return_hidden=fused)
+                        return_hidden=fused, **moe)
                     if fused:
                         hn = llama.unembed_norm(params, mcfg,
                                                 net[:, 0])       # (B, D)
@@ -1666,11 +1697,20 @@ class Engine:
                             jnp.concatenate([st["recent"][:, 1:],
                                              tok[:, None]], axis=1),
                             st["recent"]))
+                    if aux:
+                        return new_st, (emitted, aux[0]["experts_touched"],
+                                        jnp.any(active))
                     return new_st, emitted
 
                 state, toks = jax.lax.scan(body, state,
                                            jax.random.split(key, steps))
                 state = dict(state, cache=self._pin_cache(state["cache"]))
+                if self._moe_stats:
+                    # mean over the steps that had a row to decode
+                    toks, touched, live = toks
+                    n = jnp.sum(live)
+                    return state, (toks, jnp.sum(
+                        jnp.where(live, touched, 0.0)) / jnp.maximum(n, 1))
                 return state, toks
             return decode_round
 
@@ -3447,11 +3487,17 @@ class Engine:
                         if kind == "verify":
                             _, members, toks_dev, acc_dev, drafted, _ = item
                             accs = np.asarray(acc_dev)  # blocks off-thread
+                            touched = None
                         else:
-                            _, members, toks_dev, _ = item
+                            _, members, toks_dev, touched, _ = item
                             accs = drafted = None
                         # (K, B); blocks off-thread
                         toks = np.asarray(toks_dev)
+                        if touched is not None:
+                            touched = float(np.asarray(touched))
+                            with self._stats_lock:
+                                self._stats["experts_touched_sum"] += touched
+                                self._stats["experts_touched_rounds"] += 1
                     wait = ph.seconds
                     self._bump("harvest_wait_ms", wait * 1e3)
                     self._bump("harvest_rounds")
@@ -3494,7 +3540,8 @@ class Engine:
                         rec, tokens=sum(emitted.values()),
                         spec_accepted=accepted,
                         harvest_wait_ms=wait * 1e3,
-                        emit_ms=ph.seconds * 1e3)
+                        emit_ms=ph.seconds * 1e3,
+                        experts_touched=touched or 0.0)
                     with self._pipe_lock:
                         # Guarded by the generation check just above: a
                         # worker disowned during the readback must not
@@ -4348,6 +4395,9 @@ class Engine:
         act[:len(members)] = sorted(members)
         new_state, toks = self._round_fn(window, steps, greedy, ba)(
             self.params, self._state, key, jnp.asarray(act))
+        touched = None
+        if self._moe_stats:
+            toks, touched = toks
         self._guard_live()  # reset() may have run while the round compiled
         self._state = new_state
         if self._fused_tail:
@@ -4361,6 +4411,8 @@ class Engine:
             # the round's tokens already on the host instead of paying a
             # blocking readback per round.
             toks.copy_to_host_async()
+            if touched is not None:
+                touched.copy_to_host_async()
         except Exception:  # noqa: BLE001 — optional fast path
             pass
         if rec is not None:
@@ -4368,14 +4420,15 @@ class Engine:
             # step must read (per-slot ceil(pos/page), pre-advance) and
             # the HBM traffic they plus the weight stream imply.
             page = self.cfg.page_size
-            pages_per_step = sum(
-                _ceil_div(max(1, r.proj_pos + 1), page)
-                for r in members.values())
+            pages_per_step, skipped = self._pages_read(members)
             rec.decode_slots = len(members)
-            rec.pages_touched += pages_per_step * steps
-            rec.hbm_bytes += steps * (
-                self._param_bytes
-                + pages_per_step * page * self._kv_bytes_per_token())
+            rec.pages_touched += round(pages_per_step * steps)
+            rec.kv_pages_skipped += skipped * steps
+            rec.hbm_bytes += round(steps * (
+                self._step_weight_bytes(len(members))
+                + pages_per_step * page * self._kv_bytes_per_token()))
+            if skipped:
+                self._bump("kv_pages_skipped", skipped * steps)
         for req in members.values():
             req.proj_pos = min(req.proj_pos + steps, req.extent)
         with self._pipe_lock:
@@ -4385,8 +4438,37 @@ class Engine:
             if depth > self._stats["dispatch_depth_peak"]:
                 self._stats["dispatch_depth_peak"] = depth
         self._assert_harvestable(toks)
-        self._harvest_q.put(("round", members, toks, rec))
+        self._harvest_q.put(("round", members, toks, touched, rec))
         self._bump("decode_steps", steps)
+
+    def _step_weight_bytes(self, rows: int) -> float:
+        """Weight bytes one decode step of ``rows`` rows streams: all of
+        them, but of dropless experts only those the rows are expected
+        to reach (each row k of E at random) — the plan cannot know the
+        routing; the round's ``experts_touched`` says what it was."""
+        if not self._expert_bytes:
+            return self._param_bytes
+        mc = self.model_cfg
+        idle = (1.0 - mc.num_experts_per_tok / mc.num_experts) ** rows
+        return self._param_bytes - self._expert_bytes * idle
+
+    def _pages_read(self, members: dict) -> tuple[float, float]:
+        """Pages ONE decode step reads and pages it skips, summed over
+        the rows and averaged over the layers: every layer reads a row's
+        live pages but a window layer, which starts at the page of the
+        first key its query attends (ops/paged_attention.py) — so a
+        round's pages, bytes and bandwidth share follow
+        ``min(context, window)`` in those layers."""
+        page = self.cfg.page_size
+        W, share = self.model_cfg.sliding_window, self._window_share
+        read = skipped = 0.0
+        for r in members.values():
+            full = _ceil_div(max(1, r.proj_pos + 1), page)
+            behind = share * (max(r.proj_pos - W + 1, 0) // page) \
+                if share else 0.0
+            read += full - behind
+            skipped += behind
+        return read, skipped
 
     def _dispatch_verify(self, drafts: dict, rec=None) -> bool:
         """Dispatch one speculative VERIFY round: every armed slot rides
@@ -4449,6 +4531,8 @@ class Engine:
             except Exception:  # noqa: BLE001 — optional fast path
                 pass
             if rec is not None:
+                # the verify forward gathers whole windows whatever the
+                # layer (the jnp path masks, it does not skip)
                 pages_per_step = sum(
                     _ceil_div(max(1, r.proj_pos + 1), page)
                     for r in members.values())

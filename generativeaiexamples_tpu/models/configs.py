@@ -46,6 +46,76 @@ class LlamaConfig:
     mlp: str = "swiglu"
     attn_bias: bool = False   # biases on wq/wk/wv/wo
     mlp_bias: bool = False    # biases on the MLP projections
+    # Per-layer kinds, each a 0/1 pattern repeated over the depth (the
+    # flags ride the one layer scan as per-layer inputs, models/llama.py
+    # ``layer_kinds``; an empty pattern is the plain model and adds
+    # nothing to any program):
+    #   sliding_window / window_layers: a layer whose flag is 1 attends
+    #         only the last ``sliding_window`` keys (itself among them);
+    #         0 / () = every layer attends its whole context
+    #   rope_layers: a layer whose flag is 0 applies NO rotary embedding;
+    #         () = every layer rotates
+    sliding_window: int = 0
+    window_layers: tuple = ()
+    rope_layers: tuple = ()
+    # MoE variants beyond Mixtral's:
+    #   mlp "relu_glu":  an expert (or dense MLP) gated by relu, not silu
+    #   moe_impl "dropless": assignments sorted by expert and a grouped
+    #         product over blocks of rows (parallel/moe.py); no capacity,
+    #         nothing dropped at any batch; decode streams only the
+    #         experts the rows touch
+    #   router_input: "mlp_norm" (Mixtral: the router reads the normed
+    #         post-attention stream) | "block_input" (the router reads
+    #         the stream as it ENTERS the block, un-normed, before the
+    #         attention; its logits are handed past it to the experts)
+    router_input: str = "mlp_norm"
+    # How ``llama.init_params`` draws a random tree (tests, benchmarks;
+    # served weights come from ``import_hf`` and ignore it):
+    #   "fan_in": every matrix N(0, 1/fan_in), embedding rows of norm 1
+    #   "unit_stream": embedding entries of unit variance; the two
+    #         projections that write to the stream (``wo``, ``w_down``)
+    #         scaled by 1/sqrt(2L) as GPT-2 and Megatron draw them; ``wq``
+    #         times 4. A stream a router can read un-normed without
+    #         collapsing, and heads that choose keys, so that a wrong
+    #         window or rotary layer shows at the logits
+    weight_init: str = "fan_in"
+
+    def __post_init__(self):
+        # JSON hands lists; a frozen dataclass used as a jit static and
+        # an lru_cache key must hash
+        for name in ("window_layers", "rope_layers"):
+            object.__setattr__(self, name,
+                               tuple(int(x) for x in getattr(self, name)))
+        object.__setattr__(self, "sliding_window",
+                           int(self.sliding_window or 0))
+        if self.sliding_window == 1:
+            raise ValueError("sliding_window 1 attends no cached key; "
+                             "use 0 (none) or >= 2")
+        if self.router_input not in ("mlp_norm", "block_input"):
+            raise ValueError(f"unknown router_input {self.router_input!r}")
+        if self.weight_init not in ("fan_in", "unit_stream"):
+            raise ValueError(f"unknown weight_init {self.weight_init!r}")
+
+    def layer_pattern(self, pattern: tuple, default: int) -> tuple:
+        """A 0/1 period repeated over the depth (``default`` where the
+        pattern is empty)."""
+        if not pattern:
+            return (default,) * self.num_layers
+        return tuple(pattern[i % len(pattern)]
+                     for i in range(self.num_layers))
+
+    @property
+    def layer_windows(self) -> tuple:
+        """Per layer, the window it attends in tokens (0 = whole
+        context)."""
+        if not self.sliding_window:
+            return (0,) * self.num_layers
+        return tuple(self.sliding_window * f
+                     for f in self.layer_pattern(self.window_layers, 0))
+
+    @property
+    def layer_rope(self) -> tuple:
+        return self.layer_pattern(self.rope_layers, 1)
 
     @property
     def q_dim(self) -> int:
@@ -86,6 +156,19 @@ MIXTRAL_8X7B = LlamaConfig(hidden_size=4096, intermediate_size=14336,
                            rope_theta=1_000_000.0,
                            max_position_embeddings=32768,
                            num_experts=8, num_experts_per_tok=2)
+
+# A 21B-total / 3B-active sparse model (PowerInfer SmallThinker-21BA3B-
+# Instruct config.json): 64 narrow ReLU-gated experts, 6 a token, routed
+# from the block's input; one global layer without rotary embedding then
+# three 4096-token window layers with it, thirteen times over.
+SMALLTHINKER_21B_A3B = LlamaConfig(
+    vocab_size=151936, hidden_size=2560, intermediate_size=768,
+    num_layers=52, num_heads=28, num_kv_heads=4, head_dim=128,
+    max_position_embeddings=16384, rope_theta=1_500_000.0,
+    rms_norm_eps=1e-6, num_experts=64, num_experts_per_tok=6,
+    moe_impl="dropless", mlp="relu_glu", router_input="block_input",
+    sliding_window=4096, window_layers=(0, 1, 1, 1),
+    rope_layers=(0, 1, 1, 1), weight_init="unit_stream")
 
 # GPT-Next / Nemotron-8B (the reference's second served family:
 # ensemble_models/gptnext/, docs/rag/support_matrix.md:14 sizing;
@@ -134,6 +217,7 @@ MODEL_REGISTRY: dict[str, LlamaConfig] = {
     "codellama-13b-instruct": CODELLAMA_13B,
     "mixtral-8x7b-instruct": MIXTRAL_8X7B,
     "nemotron-8b-chat": NEMOTRON_8B,
+    "smallthinker-21b-a3b-instruct": SMALLTHINKER_21B_A3B,
     "gptnext-tiny": GPTNEXT_TINY,
     "llama-tiny": LLAMA_TINY,
     "golden-tiny": GOLDEN_TINY,

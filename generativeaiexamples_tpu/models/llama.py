@@ -103,15 +103,41 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
         return (jax.random.normal(rng, shape, jnp.float32)
                 * (fan_in ** -0.5)).astype(dtype)
 
+    # ``cfg.weight_init`` "unit_stream" (models/configs.py) draws a tree
+    # with a trained model's stream, not only its shapes; "fan_in" keeps
+    # the draw below bit for bit (a test pins two trees). (1) Embedding
+    # entries of unit variance, not 1/sqrt(D): under rows of norm 1 the
+    # first attention output outweighs the token's own row, every
+    # position's stream is soon its context's running mean and all rows
+    # choose the same few experts (PERF.md section 7 row 2: Mixtral's
+    # router under "fan_in"). (2) The two projections that write to the
+    # stream, ``wo`` and ``w_down``, scaled by 1/sqrt(2L) as GPT-2 and
+    # Megatron initialise them: a block then adds a tenth of the stream,
+    # not half of it. Without (2) one top-k near-tie that bf16 flips
+    # against float32 moves a position's logits by 5-30 % (a third of
+    # the positions of a 4608-token prompt lay over 0.05, chip, PR 28)
+    # and greedy decoding falls into one repeated token in 2 requests of
+    # 16; with it the share over 0.05 is 0-2 % and no request repeats.
+    # (3) ``wq`` times 4, so that a query's scores have a standard
+    # deviation of 4, not 1: its softmax rests on a handful of keys, as
+    # a trained head's does. Under scores of deviation 1 a head averages
+    # thousands of near-equal keys to almost nothing, and a program with
+    # the wrong window, or rotary where none belongs, reads within the
+    # sound error of a 4608-token prompt's logits; with (3) it reads
+    # 4-18 times over it (chip, PR 28; PERF.md section 6).
+    stream_draw = cfg.weight_init == "unit_stream"
+    q_gain = 16 if stream_draw else 1
+    resid = 2 * L if stream_draw else 1
+
     # layernorm1p stores weights centered at zero (applied as 1 + w)
     norm_w = jnp.zeros if cfg.norm == "layernorm1p" else jnp.ones
     layers: dict[str, jax.Array] = {
         "attn_norm": norm_w((L, D), dtype),
         "mlp_norm": norm_w((L, D), dtype),
-        "wq": norm(next(k), (L, D, H * hd), D),
+        "wq": norm(next(k), (L, D, H * hd), D / q_gain),
         "wk": norm(next(k), (L, D, KV * hd), D),
         "wv": norm(next(k), (L, D, KV * hd), D),
-        "wo": norm(next(k), (L, H * hd, D), H * hd),
+        "wo": norm(next(k), (L, H * hd, D), H * hd * resid),
     }
     if cfg.norm == "layernorm1p":
         layers["attn_norm_b"] = jnp.zeros((L, D), dtype)
@@ -127,7 +153,7 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
             "router": norm(next(k), (L, D, E), D),
             "w_gate": norm(next(k), (L, E, D, F), D),
             "w_up": norm(next(k), (L, E, D, F), D),
-            "w_down": norm(next(k), (L, E, F, D), F),
+            "w_down": norm(next(k), (L, E, F, D), F * resid),
         })
     elif cfg.mlp == "squared_relu":
         # GPT-Next MLP: no gate projection
@@ -145,7 +171,7 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
             "w_down": norm(next(k), (L, F, D), F),
         })
     params: Params = {
-        "embed": norm(next(k), (V, D), D),
+        "embed": norm(next(k), (V, D), 1 if stream_draw else D),
         "layers": layers,
         "final_norm": norm_w((D,), dtype),
     }
@@ -228,14 +254,59 @@ def kernel_tp_compatible(cfg: LlamaConfig, mesh) -> bool:
             and (cfg.num_kv_heads // tp) > 0)
 
 
+_EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def layer_kinds(cfg: LlamaConfig) -> dict[str, jax.Array]:
+    """The per-layer flags that ride the layer scan beside the stacked
+    weights: ``window`` (L,) int32, the keys a layer attends (0 = its
+    whole context), and ``rope`` (L,) bool. Empty for a model whose
+    layers are all of one kind: its programs take no extra input."""
+    kinds: dict[str, jax.Array] = {}
+    if any(cfg.layer_windows):
+        kinds["window"] = jnp.asarray(cfg.layer_windows, jnp.int32)
+    if not all(cfg.layer_rope):
+        kinds["rope"] = jnp.asarray(cfg.layer_rope, bool)
+    return kinds
+
+
+def scan_layers(params: Params, cfg: LlamaConfig
+                ) -> tuple[dict[str, jax.Array], dict[str, jax.Array]]:
+    """What the one layer scan iterates over, and what it closes over:
+    ``(xs, held)``. ``xs`` is the stacked layer tree plus the per-layer
+    kinds; a scan body rebuilds a layer's parameters as ``{**lp,
+    **held}``. ``held`` is empty but for dropless experts: their
+    (L, E, in, out) stacks stay OUT of the scan's sliced inputs and the
+    layer carries its ``layer_index``, so an expert's matrix is sliced
+    out of the whole stack where it is used (parallel/moe.py) — a scan
+    that slices a layer's experts first hands the inner block loop a
+    copy of the layer's whole slab."""
+    layers = dict(params["layers"])
+    held: dict[str, jax.Array] = {}
+    if cfg.num_experts and cfg.moe_impl == "dropless":
+        held = {n: layers.pop(n) for n in _EXPERT_LEAVES}
+        layers["layer_index"] = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    layers.update(layer_kinds(cfg))
+    return layers, held
+
+
 def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
                        positions: jax.Array, kv_cache: KVCache,
                        block_table: jax.Array, kv_valid_len: jax.Array,
                        write_page: jax.Array, write_offset: jax.Array,
                        use_kernel: Optional[bool] = None,
                        mesh=None, return_hidden: bool = False,
+                       active: Optional[jax.Array] = None,
+                       stats: bool = False,
                        ) -> tuple[jax.Array, KVCache]:
     """Single-token decode step over the paged KV pool.
+
+    ``active`` (B,) bool: the rows that hold a sequence. Only dropless
+    experts read it (an idle slot then touches no expert); every other
+    layer computes idle rows and the caller discards them. ``stats``
+    appends a third result, ``{"experts_touched": ()}``: the distinct
+    experts a layer's rows reached, averaged over the layers (0 for a
+    model without dropless experts).
 
     tokens/positions: (B, 1). block_table: (B, P) — physical page id of each
     slot's logical page, sliced by the engine to the smallest window covering
@@ -262,6 +333,7 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     h = _embed(params, tokens)
     pos_in_win = positions[:, 0]  # logical index of the current token
     rows = jnp.arange(B)
+    xs, held = scan_layers(params, cfg)
 
     # use_kernel: the caller (engine) decides — the Pallas path has no
     # SPMD partitioning rule, so mesh/TP serving must take the jnp path.
@@ -286,17 +358,21 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
         # gather path (VERDICT r3 weak #3).
         interp = jax.default_backend() != "tpu"
 
+        # ``win``: the layer's window as one more (1,) operand, only in
+        # a model that has window layers
         if quant:
             def call_kernel(q, pk, pv, ks, vs, ck, cv, li, tbl, lens,
-                            wp, off):
+                            wp, off, *win):
                 return paged_attention_decode(
                     q, pk, pv, tbl, lens, ck, cv, wp, off, li,
-                    pool_ks=ks, pool_vs=vs, interpret=interp)
+                    pool_ks=ks, pool_vs=vs, interpret=interp,
+                    window=win[0] if win else None)
         else:
-            def call_kernel(q, pk, pv, ck, cv, li, tbl, lens, wp, off):
+            def call_kernel(q, pk, pv, ck, cv, li, tbl, lens, wp, off,
+                            *win):
                 return paged_attention_decode(
                     q, pk, pv, tbl, lens, ck, cv, wp, off, li,
-                    interpret=interp)
+                    interpret=interp, window=win[0] if win else None)
 
         if mesh is not None and "tp" in mesh.shape:
             from jax.sharding import PartitionSpec as P
@@ -313,11 +389,16 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
                 in_specs = ((P(None, "tp", None), kv_spec, kv_spec)
                             + head_specs + (P(), P(), P(), P(), P()))
                 out_specs = (P(None, "tp", None), kv_spec, kv_spec)
+            if "window" in xs:
+                in_specs = in_specs + (P(),)
             call_kernel = jax.shard_map(
                 call_kernel, mesh=mesh, in_specs=in_specs,
                 out_specs=out_specs, check_vma=False)
 
         def layer_k(carry, lp):
+            lp = {**lp, **held}
+            win = (lp["window"][None],) if "window" in lp else ()
+            aux = {} if stats else None
             if quant:
                 h, pk, pv, ks, vs, li = carry
             else:
@@ -328,36 +409,38 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
                     attn, pk2, pv2, ks2, vs2 = call_kernel(
                         q[:, 0], pk, pv, ks, vs, k[:, 0].astype(dt),
                         v[:, 0].astype(dt), li, block_table, pos_in_win,
-                        write_page, write_offset)
+                        write_page, write_offset, *win)
                     return attn[:, None], (pk2, pv2, ks2, vs2)
                 attn, pk2, pv2 = call_kernel(
                     q[:, 0], pk, pv, k[:, 0].astype(dt),
                     v[:, 0].astype(dt), li, block_table, pos_in_win,
-                    write_page, write_offset)
+                    write_page, write_offset, *win)
                 return attn[:, None], (pk2, pv2)
 
             if quant:
                 h, (pk, pv, ks, vs) = decoder_layer(
                     h, lp, cfg, positions, inv_freq, kv_valid_len,
-                    attend=attend)
-                return (h, pk, pv, ks, vs, li + 1), None
+                    attend=attend, row_mask=active, aux=aux)
+                return (h, pk, pv, ks, vs, li + 1), _touched(aux)
             h, (pk, pv) = decoder_layer(h, lp, cfg, positions, inv_freq,
-                                        kv_valid_len, attend=attend)
-            return (h, pk, pv, li + 1), None
+                                        kv_valid_len, attend=attend,
+                                        row_mask=active, aux=aux)
+            return (h, pk, pv, li + 1), _touched(aux)
 
         li0 = jnp.zeros((1,), jnp.int32)
         if quant:
-            (h, pk, pv, ks, vs, _), _ = jax.lax.scan(
+            (h, pk, pv, ks, vs, _), touched = jax.lax.scan(
                 layer_k, (h, kv_cache["k"], kv_cache["v"],
-                          kv_cache["ks"], kv_cache["vs"], li0),
-                params["layers"])
-            out = h if return_hidden else unembed(params, cfg, h)
-            return out, {"k": pk, "v": pv, "ks": ks, "vs": vs}
-        (h, pk, pv, _), _ = jax.lax.scan(
-            layer_k, (h, kv_cache["k"], kv_cache["v"], li0),
-            params["layers"])
-        return (h if return_hidden else unembed(params, cfg, h)), \
-            {"k": pk, "v": pv}
+                          kv_cache["ks"], kv_cache["vs"], li0), xs)
+            cache = {"k": pk, "v": pv, "ks": ks, "vs": vs}
+        else:
+            (h, pk, pv, _), touched = jax.lax.scan(
+                layer_k, (h, kv_cache["k"], kv_cache["v"], li0), xs)
+            cache = {"k": pk, "v": pv}
+        out = h if return_hidden else unembed(params, cfg, h)
+        if stats:
+            return out, cache, {"experts_touched": jnp.mean(touched)}
+        return out, cache
 
     def layer(h: jax.Array, xs):
         if quant:
@@ -365,6 +448,8 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
         else:
             lp, kc, vc = xs
             ksc = vsc = None
+        lp = {**lp, **held}
+        aux = {} if stats else None
 
         def attend(q, k, v):
             kg = _gathered_window(kc, ksc, block_table, B, P, page, cfg,
@@ -375,16 +460,19 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
             # write happens in the post-scan scatter).
             kg = kg.at[rows, pos_in_win].set(k[:, 0].astype(kg.dtype))
             vg = vg.at[rows, pos_in_win].set(v[:, 0].astype(vg.dtype))
-            return gqa_attention(q, kg, vg, positions, kv_valid_len), \
+            return gqa_attention(q, kg, vg, positions, kv_valid_len,
+                                 window=lp.get("window")), \
                 (k[:, 0], v[:, 0])
 
-        return decoder_layer(h, lp, cfg, positions, inv_freq, kv_valid_len,
-                             attend=attend)
+        h, new_kv = decoder_layer(h, lp, cfg, positions, inv_freq,
+                                  kv_valid_len, attend=attend,
+                                  row_mask=active, aux=aux)
+        return h, (new_kv, _touched(aux))
 
-    xs = (params["layers"], kv_cache["k"], kv_cache["v"])
+    xs = (xs, kv_cache["k"], kv_cache["v"])
     if quant:
         xs = xs + (kv_cache["ks"], kv_cache["vs"])
-    h, (new_k, new_v) = jax.lax.scan(layer, h, xs)
+    h, ((new_k, new_v), touched) = jax.lax.scan(layer, h, xs)
     # new_k/new_v: (L, B, KV, hd) -> one scatter into the (donated) pool.
     # Flattening (N, KV, page) into one dim keeps the scatter single-axis
     # and layout-neutral.
@@ -415,7 +503,17 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
         else:
             cache = {"k": write(kv_cache["k"], new_k),
                      "v": write(kv_cache["v"], new_v)}
-    return (h if return_hidden else unembed(params, cfg, h)), cache
+    out = h if return_hidden else unembed(params, cfg, h)
+    if stats:
+        return out, cache, {"experts_touched": jnp.mean(touched)}
+    return out, cache
+
+
+def _touched(aux: Optional[dict]):
+    """A layer's ``experts_touched`` as a scan output (None: not asked)."""
+    if aux is None:
+        return None
+    return aux.get("experts_touched", jnp.float32(0.0))
 
 
 def apply_verify_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
@@ -469,6 +567,7 @@ def apply_verify_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
         else:
             lp, kc, vc = xs
             ksc = vsc = None
+        lp = {**lp, **held}
 
         def attend(q, k, v):
             kg = _gathered_window(kc, ksc, block_table, B, P, page, cfg,
@@ -481,13 +580,15 @@ def apply_verify_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
             # scatter — they can only belong to masked garbage rows.
             kg = kg.at[rows[:, None], positions].set(k.astype(kg.dtype))
             vg = vg.at[rows[:, None], positions].set(v.astype(vg.dtype))
-            return gqa_attention(q, kg, vg, positions, kv_valid_len), \
+            return gqa_attention(q, kg, vg, positions, kv_valid_len,
+                                 window=lp.get("window")), \
                 (k, v)
 
         return decoder_layer(h, lp, cfg, positions, inv_freq, kv_valid_len,
                              attend=attend)
 
-    xs = (params["layers"], kv_cache["k"], kv_cache["v"])
+    layers, held = scan_layers(params, cfg)
+    xs = (layers, kv_cache["k"], kv_cache["v"])
     if quant:
         xs = xs + (kv_cache["ks"], kv_cache["vs"])
     h, (new_k, new_v) = jax.lax.scan(layer, h, xs)
@@ -526,7 +627,8 @@ def apply_verify_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
 
 def _paged_prefix_attention(q, k_self, v_self, kc, vc, ksc, vsc,
                             block_table, start, kv_valid_len, page: int,
-                            cfg: LlamaConfig, block_pages: int = 8):
+                            cfg: LlamaConfig, block_pages: int = 8,
+                            window: Optional[jax.Array] = None):
     """Chunk queries attend [pooled prefix] + [their own chunk], with the
     prefix STREAMED from the pool in ``block_pages``-page blocks under an
     online softmax.
@@ -548,6 +650,11 @@ def _paged_prefix_attention(q, k_self, v_self, kc, vc, ksc, vsc,
                   (page-aligned); pool rows with logical position >=
                   start are masked (stale/future)
     kv_valid_len: (1,) int32 — start + valid tokens in this chunk
+    window:       () int32 or None — this layer's window in keys (0 =
+                  whole context): the query at position p attends keys
+                  p - window < j <= p, and prefix blocks wholly behind
+                  the FIRST query's window are skipped like those past
+                  the prefix
     Returns (1, C, H, hd) in q.dtype.
     """
     B, C, H, hd = q.shape
@@ -565,6 +672,9 @@ def _paged_prefix_attention(q, k_self, v_self, kc, vc, ksc, vsc,
     qf = q[0].reshape(C, KV, G, hd)
     tblk = block_pages * page
     rel = jnp.arange(C, dtype=jnp.int32)
+    if window is not None:
+        # first key each query attends (0 where the layer has no window)
+        lo = jnp.where(window > 0, start + rel - window + 1, 0)  # (C,)
 
     def online(carry, s, mask, vb):
         """One online-softmax update. s: (KV, G, C, T) f32 scores,
@@ -602,11 +712,26 @@ def _paged_prefix_attention(q, k_self, v_self, kc, vc, ksc, vsc,
             # prefix rows only: pool rows at/past `start` are stale
             # (this chunk's own rows land post-scan) — and every prefix
             # row is causally visible to every chunk query (t < start)
-            return online(carry, s, t < start, vb)
+            mask = t < start
+            # ... and the V rows no query may read are zeroed, not only
+            # their probabilities: a block that holds the end of the
+            # prefix also holds pages past it — this chunk's own, stale,
+            # and past the extent the TRASH page, where the decode kernel
+            # parks idle slots' rows beside whatever its scratch held.
+            # One non-finite value there and 0 x NaN = NaN reaches every
+            # query of the chunk through the PV product (PERF.md section
+            # 7 row 1: a request answers with garbage from then on).
+            vb = jnp.where(mask[:, None, None], vb, 0)
+            if window is not None:
+                mask = mask[None, :] & (t[None, :] >= lo[:, None])
+            return online(carry, s, mask, vb)
         # blocks wholly past the prefix would be gathered then fully
-        # masked — skip their HBM reads and matmuls at runtime
-        return jax.lax.cond(bi * tblk < start, live,
-                            lambda c: c, carry), None
+        # masked — skip their HBM reads and matmuls at runtime; so
+        # would blocks wholly behind the first query's window
+        wanted = bi * tblk < start
+        if window is not None:
+            wanted = wanted & ((bi + 1) * tblk > lo[0])
+        return jax.lax.cond(wanted, live, lambda c: c, carry), None
 
     m0 = jnp.full((KV, G, C), -1e30, jnp.float32)
     l0 = jnp.zeros((KV, G, C), jnp.float32)
@@ -630,6 +755,8 @@ def _paged_prefix_attention(q, k_self, v_self, kc, vc, ksc, vsc,
                        preferred_element_type=jnp.float32) * scale
         ok = (tloc[None, :] <= rel[:, None]) \
             & ((start + tloc) < kv_valid_len[0])[None, :]
+        if window is not None:
+            ok = ok & ((start + tloc)[None, :] >= lo[:, None])
         return online(carry, s, ok, vb), None
 
     (m, l, acc), _ = jax.lax.scan(
@@ -694,6 +821,7 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
         else:
             lp, kc, vc = xs
             ksc = vsc = None
+        lp = {**lp, **held}
 
         def attend(q, k, v):
             # prefix streamed from the pool block-by-block (online
@@ -703,13 +831,14 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
             # this path's memory.
             attn = _paged_prefix_attention(
                 q, k, v, kc, vc, ksc, vsc, block_table, start,
-                kv_valid_len, page, cfg)
+                kv_valid_len, page, cfg, window=lp.get("window"))
             return attn, (k[0], v[0])
 
         return decoder_layer(h, lp, cfg, positions, inv_freq, kv_valid_len,
                              attend=attend)
 
-    xs = (params["layers"], kv_cache["k"], kv_cache["v"])
+    layers, held = scan_layers(params, cfg)
+    xs = (layers, kv_cache["k"], kv_cache["v"])
     if quant:
         xs = xs + (kv_cache["ks"], kv_cache["vs"])
     h, (new_k, new_v) = jax.lax.scan(layer, h, xs)
@@ -758,7 +887,8 @@ def _dense_mlp(x: jax.Array, lp: dict[str, jax.Array],
         if "b_down" in lp:
             out = out + lp["b_down"]
         return out
-    gate = jax.nn.silu(qmm(x, lp["w_gate"]))
+    act = jax.nn.relu if cfg.mlp == "relu_glu" else jax.nn.silu
+    gate = act(qmm(x, lp["w_gate"]))
     return qmm(gate * qmm(x, lp["w_up"]), lp["w_down"])
 
 
@@ -771,17 +901,41 @@ def block_norm(x: jax.Array, lp: dict[str, jax.Array], key: str,
     return rmsnorm(x, lp[key], cfg.rms_norm_eps)
 
 
-def _moe_mlp(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig) -> jax.Array:
+def _router_logits(x: jax.Array, lp: dict[str, jax.Array]) -> jax.Array:
+    return x.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
+
+
+def _moe_mlp(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
+             router_logits: Optional[jax.Array] = None,
+             row_mask: Optional[jax.Array] = None,
+             aux: Optional[dict] = None) -> jax.Array:
     """Mixtral MLP. Default is the sparse top-k capacity-routed path
     (parallel/moe.py, O(tokens*k) expert FLOPs); ``moe_impl="dense"``
     keeps the zero-gated all-experts formulation (O(tokens*E), no
-    capacity drops) as the parity oracle."""
+    capacity drops) as the parity oracle; ``"dropless"`` sorts the
+    assignments by expert and drops nothing (parallel/moe.py), with
+    ``router_logits`` (B, S, E) from where the configuration's router
+    reads (None: from ``x``)."""
+    if cfg.moe_impl == "dropless":
+        from ..parallel.moe import dropless_moe_ffn
+        if router_logits is None:
+            with jax.named_scope("moe_route"):
+                router_logits = _router_logits(x, lp)
+        out, touched = dropless_moe_ffn(x, router_logits, lp, cfg, row_mask)
+        if aux is not None:
+            aux["experts_touched"] = touched
+        return out
+    if router_logits is not None or cfg.mlp != "swiglu":
+        raise ValueError(
+            f"moe_impl {cfg.moe_impl!r} routes after the norm through "
+            f"SwiGLU experts only; router_input 'block_input' and mlp "
+            f"{cfg.mlp!r} need moe_impl 'dropless'")
     if cfg.moe_impl == "sparse":
         from ..parallel.moe import sparse_moe_ffn
         return sparse_moe_ffn(x, lp, cfg)
     if cfg.moe_impl != "dense":
         raise ValueError(f"unknown moe_impl {cfg.moe_impl!r}; "
-                         f"expected 'sparse' or 'dense'")
+                         f"expected 'sparse', 'dense' or 'dropless'")
     B, S, D = x.shape
     with jax.named_scope("moe_route"):
         logits = x @ lp["router"]  # (B,S,E)
@@ -804,7 +958,8 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
                   kv_valid_len: Optional[jax.Array],
                   cache_kv: Optional[tuple[jax.Array, jax.Array]] = None,
                   row_start: Optional[jax.Array] = None,
-                  attend=None):
+                  attend=None, row_mask: Optional[jax.Array] = None,
+                  aux: Optional[dict] = None):
     """One transformer block. The single source of layer math shared by the
     full forward (``apply``), the paged decode (``apply_decode_paged``
     supplies a paged ``attend``), and the pipeline-parallel stage loop
@@ -814,8 +969,21 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
     ``row_start + offset`` per row. ``attend(q, k, v) -> (attn, new_cache)``
     overrides the whole KV-write + attention step (used by the paged
     decode). Returns (h, new_cache_or_None).
+
+    Per-layer kinds ride in ``lp`` (``layer_kinds``): ``window`` masks the
+    built-in attention (an ``attend`` closure reads it itself) and
+    ``rope`` switches the rotary embedding off for a layer. ``row_mask``
+    (B,) marks the rows that hold a sequence, for dropless experts;
+    ``aux``, a dict, receives what a layer has to say beside its output
+    (``experts_touched``).
     """
     B, S, _ = h.shape
+    router_logits = None
+    if cfg.num_experts and cfg.router_input == "block_input":
+        # the router reads the stream as it enters the block
+        with jax.named_scope("moe_route"):
+            router_logits = _router_logits(h, lp)
+    window = lp.get("window")
     with jax.named_scope("attn_proj"):
         x = block_norm(h, lp, "attn_norm", cfg)
         q = qmm(x, lp["wq"])
@@ -837,7 +1005,11 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
         q = q.reshape(B, S, cfg.num_heads, cfg.head_dim)
         k = k.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
         v = v.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
-        q, k = apply_rope(q, k, positions, inv_freq)
+        if "rope" in lp:
+            qr, kr = apply_rope(q, k, positions, inv_freq)
+            q, k = jnp.where(lp["rope"], qr, q), jnp.where(lp["rope"], kr, k)
+        else:
+            q, k = apply_rope(q, k, positions, inv_freq)
     with jax.named_scope("attn"):
         if attend is not None:
             attn, new_cache = attend(q, k, v)
@@ -850,10 +1022,12 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
             vc = jax.vmap(
                 lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s, 0, 0))
             )(vc, v, row_start)
-            attn = gqa_attention(q, kc, vc, positions, kv_valid_len)
+            attn = gqa_attention(q, kc, vc, positions, kv_valid_len,
+                                 window=window)
             new_cache = (kc, vc)
         else:
-            attn = gqa_attention(q, k, v, positions, kv_valid_len)
+            attn = gqa_attention(q, k, v, positions, kv_valid_len,
+                                 window=window)
             new_cache = None
     with jax.named_scope("attn_proj"):
         attn_out = qmm(attn.reshape(B, S, cfg.q_dim), lp["wo"])
@@ -863,7 +1037,7 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
     if cfg.num_experts:
         with jax.named_scope("moe_route"):
             x = block_norm(h, lp, "mlp_norm", cfg)
-        mlp = _moe_mlp(x, lp, cfg)
+        mlp = _moe_mlp(x, lp, cfg, router_logits, row_mask, aux)
         with jax.named_scope("moe_experts"):
             return h + mlp, new_cache
     with jax.named_scope("mlp"):
@@ -879,11 +1053,18 @@ def run_layers(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
                                 cfg.rope_scaling_factor)
 
+    if jax.tree.leaves(layers)[0].shape[0] == cfg.num_layers:
+        xs, held = scan_layers({"layers": layers}, cfg)
+    else:                       # a pipeline stage's share of the depth
+        _refuse_kinds(cfg, "run_layers over a partial layer stack")
+        xs, held = layers, {}
+
     def body(h, lp):
-        h, _ = decoder_layer(h, lp, cfg, positions, inv_freq, kv_valid_len)
+        h, _ = decoder_layer(h, {**lp, **held}, cfg, positions, inv_freq,
+                             kv_valid_len)
         return h, None
 
-    h, _ = jax.lax.scan(body, h, layers)
+    h, _ = jax.lax.scan(body, h, xs)
     return h
 
 
@@ -1009,14 +1190,17 @@ def apply(params: Params, cfg: LlamaConfig, tokens: jax.Array,
         inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
                                     cfg.rope_scaling_factor)
 
+        layers, held = scan_layers(params, cfg)
+
         def layer_cached(h, xs):
             lp, kc, vc = xs  # kc/vc: (B,T,KV,hd)
-            h, new_kv = decoder_layer(h, lp, cfg, positions, inv_freq,
-                                      kv_valid_len, (kc, vc), row_start)
+            h, new_kv = decoder_layer(h, {**lp, **held}, cfg, positions,
+                                      inv_freq, kv_valid_len, (kc, vc),
+                                      row_start)
             return h, new_kv
 
         h, (new_k, new_v) = jax.lax.scan(
-            layer_cached, h, (params["layers"], kv_cache["k"], kv_cache["v"]))
+            layer_cached, h, (layers, kv_cache["k"], kv_cache["v"]))
         new_cache: Optional[KVCache] = {"k": new_k, "v": new_v}
     else:
         h = run_layers(params["layers"], cfg, h, positions, kv_valid_len)
@@ -1056,6 +1240,7 @@ def apply_sp(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     from ..parallel.ring_attention import ring_gqa_attention
 
     n_sp = validate_sp_mesh(mesh, tokens.shape[1], "apply_sp")
+    _refuse_kinds(cfg, "apply_sp")
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
                                 cfg.rope_scaling_factor)
     dp = "dp" if int(mesh.shape.get("dp", 1)) > 1 else None
@@ -1080,6 +1265,16 @@ def apply_sp(params: Params, cfg: LlamaConfig, tokens: jax.Array,
                          in_specs=(seq_spec, seq_spec, P()),
                          out_specs=P(dp, "sp", None),
                          check_vma=False)(tokens, positions, params)
+
+
+def _refuse_kinds(cfg: LlamaConfig, fn_name: str) -> None:
+    """Paths that scan the raw layer tree (ring attention, which has no
+    window mask either; a pipeline stage's partial stack)."""
+    if layer_kinds(cfg) or (cfg.num_experts
+                            and cfg.moe_impl == "dropless"):
+        raise NotImplementedError(
+            f"{fn_name}: per-layer kinds and dropless experts are not "
+            f"supported here")
 
 
 def validate_sp_mesh(mesh, S: int, fn_name: str = "sp") -> int:
@@ -1131,6 +1326,7 @@ def apply_prefill_sp(params: Params, cfg: LlamaConfig, tokens: jax.Array,
 
     B, S = tokens.shape
     n_sp = validate_sp_mesh(mesh, S, "apply_prefill_sp")
+    _refuse_kinds(cfg, "apply_prefill_sp")
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
                                 cfg.rope_scaling_factor)
     # serving prefill is B=1: batch shards over dp only when divisible,

@@ -161,11 +161,12 @@ class RoundRecord:
         # dispatch (scheduler thread, filled until seal)
         "decode_slots", "spec_drafted", "verify_positions",
         "prefill_tokens", "grants", "pages_touched", "hbm_bytes",
-        "kv_restore_pages", "blocked_on_pages",
+        "kv_restore_pages", "blocked_on_pages", "kv_pages_skipped",
         "dispatch_ms", "modeled_ms", "t_dispatch_done",
         # execution (harvest thread)
         "harvest_wait_ms", "first_readback_ms", "emit_ms",
         "tokens_emitted", "first_tokens", "spec_accepted",
+        "experts_touched",
         # finalization
         "device_ms", "round_ms", "bw_util", "drift_ratio", "done",
         # bookkeeping
@@ -205,6 +206,11 @@ class RoundRecord:
         # Requests the plan offered a chunk that _begin_prefill refused
         # for want of pages this round (pool backpressure).
         self.blocked_on_pages = 0
+        # Pages of the live contexts this round's decode steps did not
+        # read because they lie behind a window layer's window (whole
+        # pages averaged over the layers, the unit of pages_touched;
+        # from the plan, scheduler thread). 0 without window layers.
+        self.kv_pages_skipped = 0.0
         self.dispatch_ms = 0.0
         self.modeled_ms = 0.0
         self.t_dispatch_done = self.t_start
@@ -217,6 +223,10 @@ class RoundRecord:
         self.tokens_emitted = 0
         self.first_tokens = 0
         self.spec_accepted = 0
+        # Mean distinct experts a layer's rows reached in a decode step
+        # of this round: a scalar the decode program returns beside its
+        # tokens (harvest thread). 0 without dropless experts.
+        self.experts_touched = 0.0
         self.device_ms = 0.0
         self.round_ms = 0.0
         self.bw_util = 0.0
@@ -265,6 +275,8 @@ class RoundRecord:
                 "first_tokens": self.first_tokens,
                 "spec_accepted": self.spec_accepted,
                 "pages_touched": self.pages_touched,
+                "kv_pages_skipped": round(self.kv_pages_skipped, 2),
+                "experts_touched": round(self.experts_touched, 2),
                 "kv_restore_pages": self.kv_restore_pages,
                 "hbm_bytes_est": self.hbm_bytes,
                 "bw_util": round(self.bw_util, 4),
@@ -355,7 +367,8 @@ class RoundRecorder:
     def complete_part(self, rec: Optional[RoundRecord], *,
                       tokens: int = 0, spec_accepted: int = 0,
                       harvest_wait_ms: float = 0.0,
-                      emit_ms: float = 0.0) -> None:
+                      emit_ms: float = 0.0,
+                      experts_touched: float = 0.0) -> None:
         """One harvested device output of this round (harvest thread).
         The last part — once the scheduler has sealed the expected
         count — finalizes the record."""
@@ -365,6 +378,8 @@ class RoundRecorder:
         rec.spec_accepted += int(spec_accepted)
         rec.harvest_wait_ms += float(harvest_wait_ms)
         rec.emit_ms += float(emit_ms)
+        if experts_touched:
+            rec.experts_touched = float(experts_touched)
         finalize = False
         with self._lock:
             rec._done_parts += 1

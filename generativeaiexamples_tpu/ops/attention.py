@@ -29,7 +29,8 @@ _CHUNK = 512  # key-block size for the online-softmax path
 
 def gqa_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                   q_positions: jax.Array, kv_valid_len: jax.Array | None = None,
-                  *, causal: bool = True) -> jax.Array:
+                  *, causal: bool = True,
+                  window: jax.Array | None = None) -> jax.Array:
     """Grouped-query attention over an absolute-position KV buffer.
 
     q_positions: (B, S) int32 — absolute position of each query token.
@@ -38,6 +39,8 @@ def gqa_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     causal: query at position p attends keys at cache indices <= p. The KV
         buffer is indexed by absolute position (index i holds the token at
         position i), which is what the slotted cache guarantees.
+    window: () int32 (traced: a layer's own) or None — the query at
+        position p attends only keys p - window < i <= p; 0 = no window.
 
     Long key buffers take a flash-style chunked path: keys are consumed in
     ``_CHUNK`` blocks with an online softmax, so peak memory holds one
@@ -50,11 +53,20 @@ def gqa_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     chunk = next((c for c in (_CHUNK, 256, 128) if T % c == 0), None)
     if T > _CHUNK and chunk is not None:
         return _gqa_chunked(q, k, v, q_positions, kv_valid_len,
-                            causal=causal, chunk=chunk)
-    return _gqa_dense(q, k, v, q_positions, kv_valid_len, causal=causal)
+                            causal=causal, chunk=chunk, window=window)
+    return _gqa_dense(q, k, v, q_positions, kv_valid_len, causal=causal,
+                      window=window)
 
 
-def _gqa_dense(q, k, v, q_positions, kv_valid_len, *, causal):
+def _window_mask(mask, key_idx, q_positions, window):
+    """``mask`` (B, S, T) with the keys behind each query's window out."""
+    if window is None:
+        return mask
+    lo = jnp.where(window > 0, q_positions - window + 1, 0)     # (B, S)
+    return mask & (key_idx[None, None, :] >= lo[:, :, None])
+
+
+def _gqa_dense(q, k, v, q_positions, kv_valid_len, *, causal, window=None):
     B, S, H, hd = q.shape
     _, T, KV, _ = k.shape
     G = H // KV
@@ -73,6 +85,7 @@ def _gqa_dense(q, k, v, q_positions, kv_valid_len, *, causal):
         mask = key_idx[None, None, :] <= q_positions[:, :, None]
     if kv_valid_len is not None:
         mask = mask & (key_idx[None, None, :] < kv_valid_len[:, None, None])
+    mask = _window_mask(mask, key_idx, q_positions, window)
     scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
 
     probs = jax.nn.softmax(scores, axis=-1)
@@ -80,7 +93,8 @@ def _gqa_dense(q, k, v, q_positions, kv_valid_len, *, causal):
     return out.reshape(B, S, H, hd).astype(q.dtype)
 
 
-def _gqa_chunked(q, k, v, q_positions, kv_valid_len, *, causal, chunk):
+def _gqa_chunked(q, k, v, q_positions, kv_valid_len, *, causal, chunk,
+                 window=None):
     """Online-softmax over key blocks. Operands stay in their storage
     dtype into the MXU (f32 accumulation via preferred_element_type) —
     casting whole K/V to f32 up front doubled their HBM traffic."""
@@ -108,6 +122,7 @@ def _gqa_chunked(q, k, v, q_positions, kv_valid_len, *, causal, chunk):
         if kv_valid_len is not None:
             mask = mask & (key_idx[None, None, :]
                            < kv_valid_len[:, None, None])
+        mask = _window_mask(mask, key_idx, q_positions, window)
         maskb = mask[:, None, None, :, :]
         scores = jnp.where(maskb, scores, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))

@@ -84,7 +84,8 @@ def paged_attention_decode(q: jax.Array, pool_k: jax.Array,
                            write_offset: jax.Array, layer: jax.Array,
                            *, pool_ks: jax.Array | None = None,
                            pool_vs: jax.Array | None = None,
-                           interpret: bool = False):
+                           interpret: bool = False,
+                           window: jax.Array | None = None):
     """GQA decode attention + KV append over a paged pool, one query token
     per slot.
 
@@ -115,6 +116,17 @@ def paged_attention_decode(q: jax.Array, pool_k: jax.Array,
                                        row in-kernel and writes its scale
                                        back through the already-streamed
                                        scale page.
+    window:       (1,) int32           OPTIONAL: the layer's window in
+                                       keys (0 = its whole context). The
+                                       query, at position ``lengths[b]``,
+                                       attends itself and the cached keys
+                                       at positions > lengths[b] - window:
+                                       a slot's page loop starts at the
+                                       page that holds the first of them
+                                       and masks that page's rows below
+                                       it, so pages wholly behind the
+                                       window are never read. None traces
+                                       the kernel without any of this.
     Returns (attn (B, H, hd) in q.dtype, new_pool_k, new_pool_v[,
     new_pool_ks, new_pool_vs]) with the pools aliased in place. Scaling
     (1/sqrt(hd)) applied here.
@@ -131,20 +143,31 @@ def paged_attention_decode(q: jax.Array, pool_k: jax.Array,
         return _paged_attention_decode_quant(
             q, pool_k, pool_v, pool_ks, pool_vs, block_table, lengths,
             cur_k, cur_v, write_page, write_offset, layer,
-            interpret=interpret)
+            interpret=interpret, window=window)
     Gs = group_size(B)
+    windowed = window is not None
 
-    def kernel(tbl_ref, len_ref, wp_ref, off_ref, l_ref, q_ref,
-               k_hbm, v_hbm, ck_ref, cv_ref, out_ref, opk_ref, opv_ref,
-               kbuf, vbuf, accs, ms, ls, stk, stv, krw, vrw, sem, rw_sem):
+    def kernel(tbl_ref, len_ref, wp_ref, off_ref, l_ref, *refs):
+        if windowed:
+            win_ref, refs = refs[0], refs[1:]
+        (q_ref, k_hbm, v_hbm, ck_ref, cv_ref, out_ref, opk_ref, opv_ref,
+         kbuf, vbuf, accs, ms, ls, stk, stv, krw, vrw, sem, rw_sem) = refs
         gi = pl.program_id(0)
         li = l_ref[0]
         b0 = gi * Gs
+
+        def first_key(length):
+            """The first cached position a slot's query attends."""
+            return _first_key(length, win_ref[0])
+
         # Per-slot live page counts and their flat prefix starts: the
         # group's pages stream as ONE flat sequence t in [0, total),
         # slot boundaries invisible to the DMA pipeline.
         counts = [jax.lax.div(len_ref[b0 + i] + (page - 1), page)
                   for i in range(Gs)]
+        if windowed:      # a slot's pages from its window's first on
+            counts = [c - jax.lax.div(first_key(len_ref[b0 + i]), page)
+                      for i, c in enumerate(counts)]
         starts = [jnp.int32(0)]
         for c in counts:
             starts.append(starts[-1] + c)
@@ -172,6 +195,8 @@ def paged_attention_decode(q: jax.Array, pool_k: jax.Array,
             return sidx, t - base, cnt
 
         def dmas(sidx, w, slot):
+            if windowed:
+                w = w + jax.lax.div(first_key(len_ref[b0 + sidx]), page)
             pg = tbl_ref[b0 + sidx, w]
             return (pltpu.make_async_copy(k_hbm.at[li, pg], kbuf.at[slot],
                                           sem.at[slot, 0]),
@@ -212,8 +237,16 @@ def paged_attention_decode(q: jax.Array, pool_k: jax.Array,
             scores = jax.lax.dot_general(
                 qv, kp, (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32) * scale  # (KV,G,page)
-            valid = (w * page + jax.lax.broadcasted_iota(
-                jnp.int32, (1, 1, page), 2)) < length
+            if windowed:
+                # rows of the window's first page below its first key
+                # (every streamed page keeps at least one valid row)
+                lo = first_key(length)
+                tpos = (w + jax.lax.div(lo, page)) * page \
+                    + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page), 2)
+                valid = (tpos < length) & (tpos >= lo)
+            else:
+                valid = (w * page + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, 1, page), 2)) < length
             scores = jnp.where(valid, scores, NEG)
 
             m = ms[sidx][..., None]
@@ -287,8 +320,9 @@ def paged_attention_decode(q: jax.Array, pool_k: jax.Array,
         for wcp in writes:
             wcp.wait()
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,   # table, lengths, write page/offset, layer
+    n_pre = 5 + windowed         # table, lengths, write page/offset,
+    grid_spec = pltpu.PrefetchScalarGridSpec(      # layer[, window]
+        num_scalar_prefetch=n_pre,
         grid=(B // Gs,),
         in_specs=[
             pl.BlockSpec((Gs, H, hd), lambda g, *_: (g, 0, 0)),
@@ -327,17 +361,23 @@ def paged_attention_decode(q: jax.Array, pool_k: jax.Array,
         # operand numbering includes the scalar-prefetch args (tbl=0,
         # lens=1, wp=2, off=3, layer=4, q=5, pool_k=6, pool_v=7, ck=8,
         # cv=9)
-        input_output_aliases={6: 1, 7: 2},
+        input_output_aliases={n_pre + 1: 1, n_pre + 2: 2},
         interpret=interpret,
         name="paged_attn_decode",
     )(block_table, lengths, write_page, write_offset, layer,
-      q, pool_k, pool_v, cur_k, cur_v)
+      *((window,) if windowed else ()), q, pool_k, pool_v, cur_k, cur_v)
+
+
+def _first_key(length, window):
+    """First cached position the query at position ``length`` attends
+    under ``window`` keys (itself among them; 0 = no window)."""
+    return jnp.where(window > 0, jnp.maximum(length - window + 1, 0), 0)
 
 
 def _paged_attention_decode_quant(q, pool_k, pool_v, pool_ks, pool_vs,
                                   block_table, lengths, cur_k, cur_v,
                                   write_page, write_offset, layer,
-                                  *, interpret=False):
+                                  *, interpret=False, window=None):
     """int8-KV variant of the decode kernel (see paged_attention_decode).
 
     Same slot-grouped program structure — flat cross-slot page loop,
@@ -371,17 +411,27 @@ def _paged_attention_decode_quant(q, pool_k, pool_v, pool_ks, pool_vs,
     scale = hd ** -0.5
     cd = q.dtype  # compute dtype for the MXU dots
     Gs = group_size(B)
+    windowed = window is not None
 
-    def kernel(tbl_ref, len_ref, wp_ref, off_ref, l_ref, q_ref,
-               k_hbm, v_hbm, ks_hbm, vs_hbm, ck_ref, cv_ref,
-               out_ref, opk_ref, opv_ref, opks_ref, opvs_ref,
-               kbuf, vbuf, ksbuf, vsbuf, accs, ms, ls,
-               stk, stv, stks, stvs, krw, vrw, ksrw, vsrw, sem, rw_sem):
+    def kernel(tbl_ref, len_ref, wp_ref, off_ref, l_ref, *refs):
+        if windowed:
+            win_ref, refs = refs[0], refs[1:]
+        (q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, ck_ref, cv_ref,
+         out_ref, opk_ref, opv_ref, opks_ref, opvs_ref,
+         kbuf, vbuf, ksbuf, vsbuf, accs, ms, ls,
+         stk, stv, stks, stvs, krw, vrw, ksrw, vsrw, sem, rw_sem) = refs
         gi = pl.program_id(0)
         li = l_ref[0]
         b0 = gi * Gs
+
+        def first_key(length):
+            return _first_key(length, win_ref[0])
+
         counts = [jax.lax.div(len_ref[b0 + i] + (page - 1), page)
                   for i in range(Gs)]
+        if windowed:      # as the bf16 kernel: from the window's page on
+            counts = [c - jax.lax.div(first_key(len_ref[b0 + i]), page)
+                      for i, c in enumerate(counts)]
         starts = [jnp.int32(0)]
         for c in counts:
             starts.append(starts[-1] + c)
@@ -405,6 +455,8 @@ def _paged_attention_decode_quant(q, pool_k, pool_v, pool_ks, pool_vs,
             return sidx, t - base, cnt
 
         def dmas(sidx, w, slot):
+            if windowed:
+                w = w + jax.lax.div(first_key(len_ref[b0 + sidx]), page)
             pg = tbl_ref[b0 + sidx, w]
             pairs = ((k_hbm, kbuf), (v_hbm, vbuf),
                      (ks_hbm, ksbuf), (vs_hbm, vsbuf))
@@ -443,8 +495,14 @@ def _paged_attention_decode_quant(q, pool_k, pool_v, pool_ks, pool_vs,
                 qv, kp, (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32)          # (KV,G,page)
             scores = scores * ks[:, None, :] * scale
-            valid = (w * page + jax.lax.broadcasted_iota(
-                jnp.int32, (1, 1, page), 2)) < length
+            if windowed:
+                lo = first_key(length)
+                tpos = (w + jax.lax.div(lo, page)) * page \
+                    + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page), 2)
+                valid = (tpos < length) & (tpos >= lo)
+            else:
+                valid = (w * page + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, 1, page), 2)) < length
             scores = jnp.where(valid, scores, NEG)
 
             m = ms[sidx][..., None]
@@ -548,8 +606,9 @@ def _paged_attention_decode_quant(q, pool_k, pool_v, pool_ks, pool_vs,
         for wcp in writes:
             wcp.wait()
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,   # table, lengths, write page/offset, layer
+    n_pre = 5 + windowed         # table, lengths, write page/offset,
+    grid_spec = pltpu.PrefetchScalarGridSpec(      # layer[, window]
+        num_scalar_prefetch=n_pre,
         grid=(B // Gs,),
         in_specs=[
             pl.BlockSpec((Gs, H, hd), lambda g, *_: (g, 0, 0)),
@@ -599,15 +658,16 @@ def _paged_attention_decode_quant(q, pool_k, pool_v, pool_ks, pool_vs,
         ],
         # operands: tbl=0, lens=1, wp=2, off=3, layer=4, q=5, pool_k=6,
         # pool_v=7, pool_ks=8, pool_vs=9, ck=10, cv=11
-        input_output_aliases={6: 1, 7: 2, 8: 3, 9: 4},
+        input_output_aliases={n_pre + 1 + i: 1 + i for i in range(4)},
         interpret=interpret,
         name="paged_attn_decode_int8kv",
     )(block_table, lengths, write_page, write_offset, layer,
+      *((window,) if windowed else ()),
       q, pool_k, pool_v, pool_ks, pool_vs, cur_k, cur_v)
 
 
 def paged_attention_decode_reference(q, pool_k, pool_v, block_table,
-                                     lengths, cur_k, cur_v):
+                                     lengths, cur_k, cur_v, window=0):
     """Pure-jnp attention oracle with identical masking/softmax semantics
     (tests + non-TPU backends); the pool append is left to the caller.
     This is the gather formulation the kernel replaces."""
@@ -624,6 +684,9 @@ def paged_attention_decode_reference(q, pool_k, pool_v, block_table,
                         precision=jax.lax.Precision.HIGHEST) * scale
     tpos = jnp.arange(W * page)[None, None, None, :]
     scores = jnp.where(tpos < lengths[:, None, None, None], scores, NEG)
+    if window:       # keys behind the window (the query sits at lengths)
+        lo = jnp.maximum(lengths - window + 1, 0)
+        scores = jnp.where(tpos >= lo[:, None, None, None], scores, NEG)
     s_cur = jnp.einsum("bkgd,bkd->bkg", qg, cur_k.astype(jnp.float32),
                        precision=jax.lax.Precision.HIGHEST) * scale
     all_scores = jnp.concatenate([scores, s_cur[..., None]], axis=-1)

@@ -19,6 +19,18 @@ Design (TPU-first):
   all-to-all over ICI on its own. ``ep_expert_ffn`` is the explicit
   ``shard_map`` equivalent (experts over ``ep``, FFN width over ``tp`` with
   a psum), used where manual control is wanted and as the parity oracle.
+- **Dropless** (``moe_impl="dropless"``, ``dropless_moe_ffn``): many
+  narrow experts and several a token leave no capacity worth having (a
+  capacity that can drop nothing is C = T: E/k times the work). The
+  assignments are sorted by expert, each expert's rows padded to whole
+  blocks of ``bm`` rows, and ONE loop walks the blocks that hold rows —
+  a block is one expert's weights against ``bm`` rows. Shapes are static
+  (at most ``T*k // bm + E`` blocks); the trip count is dynamic, so a
+  decode step streams the weights of the experts its rows touch and no
+  others, and nothing is dropped at any batch. On TPU the loop is the
+  Pallas kernel of ops/grouped_ffn.py, whose pipeline fetches the next
+  block's weights while this one computes; elsewhere a ``fori_loop`` of
+  plain dots, which is also the kernel's oracle.
 """
 
 from __future__ import annotations
@@ -28,6 +40,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.configs import LlamaConfig
+from ..ops import grouped_ffn
 
 
 def expert_capacity(n_tokens: int, n_experts: int, k: int,
@@ -152,3 +165,148 @@ def ep_sparse_moe_ffn(mesh: Mesh, x: jax.Array, lp: dict[str, jax.Array],
     with jax.named_scope("moe_route"):
         return _combine(expert_out, expert, slot, weight, keep,
                         T).reshape(B, S, D)
+
+
+# ------------------------------------------------------------- dropless
+
+
+def dropless_block_rows(n_tokens: int) -> int:
+    """Rows of one block of the grouped product: a decode batch's rows in
+    one block an expert (a row chooses an expert at most once), a prefill
+    chunk's in blocks of 64 (its experts average T*k/E rows)."""
+    return min(64, max(16, -(-n_tokens // 16) * 16))
+
+
+def route_sorted(router_logits: jax.Array, k: int, bm: int,
+                 row_mask: jax.Array | None = None) -> dict:
+    """Top-k routing laid out for a grouped product: the T*k assignments
+    sorted by expert, each expert's run padded to whole ``bm``-row blocks.
+
+    router_logits: (T, E). ``row_mask`` (T,) bool: rows that route
+    nowhere (idle decode slots) — they touch no expert. Returns
+      weight        (T, k) f32   softmax over the chosen k
+      token         (R,)         source token of each padded row
+      valid         (R,) bool    padded rows that hold an assignment
+      row_of        (T, k)       padded row of each assignment
+      block_expert  (NB,)        expert of each block
+      n_blocks      ()           blocks that hold rows (the loop's bound)
+      touched       ()           distinct experts with at least one row
+    with R = NB * bm, NB = T*k // bm + E (static).
+    """
+    T, E = router_logits.shape
+    A = T * k
+    w, idx = jax.lax.top_k(router_logits, k)                    # (T, k)
+    weight = jax.nn.softmax(w.astype(jnp.float32), axis=-1)
+    expert = idx.reshape(A).astype(jnp.int32)                   # token-major
+    # A counting sort, not ``argsort``: a claim's rank among its expert's
+    # claims is a running count down its expert's column (token order is
+    # kept, as ``route_topk`` keeps it) — one cumsum where a sort, a
+    # search and two scatters were (1.6 ms of a 13 ms decode step, 5 ms
+    # of a 45 ms chunk; chip, PR 28).
+    claims = jax.nn.one_hot(expert, E, dtype=jnp.int32)         # (A, E)
+    claimed = jnp.ones((A,), bool)
+    if row_mask is not None:        # idle rows claim nothing
+        claimed = jnp.repeat(row_mask, k)
+        claims = claims * claimed[:, None].astype(jnp.int32)
+        weight = weight * row_mask[:, None]
+    rank = jnp.take_along_axis(jnp.cumsum(claims, axis=0) - claims,
+                               expert[:, None], axis=1)[:, 0]
+    counts = jnp.sum(claims, axis=0)                            # (E,)
+    blocks = (counts + bm - 1) // bm
+    bend = jnp.cumsum(blocks)
+    bstart = bend - blocks                      # an expert's first block
+    NB = A // bm + E
+    # the expert of block b: how many experts' blocks end at or before b
+    block_expert = jnp.minimum(jnp.sum(
+        bend[None, :] <= jnp.arange(NB, dtype=jnp.int32)[:, None], axis=1),
+        E - 1).astype(jnp.int32)
+    row_of = bstart[expert] * bm + rank                         # (A,)
+    # the claim each padded row holds (-1: padding), by one scatter of
+    # row numbers; rows are unique, idle claims are sent out of range
+    src = jnp.full((NB * bm,), -1, jnp.int32).at[
+        jnp.where(claimed, row_of, NB * bm)].set(
+        jnp.arange(A, dtype=jnp.int32), mode="drop")
+    return {"weight": weight, "token": jnp.maximum(src, 0) // k,
+            "valid": src >= 0,
+            "row_of": jnp.where(claimed, row_of, 0).reshape(T, k),
+            "block_expert": block_expert, "n_blocks": bend[-1],
+            "touched": jnp.sum(counts > 0).astype(jnp.float32)}
+
+
+def block_loop_ffn(x_pad: jax.Array, block_expert: jax.Array,
+                   n_blocks: jax.Array, layer_index: jax.Array,
+                   w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                   *, bm: int, relu: bool) -> jax.Array:
+    """``ops/grouped_ffn.py`` ``grouped_expert_ffn`` as a ``fori_loop`` of
+    plain dots over the blocks that hold rows: what runs off the TPU, and
+    the kernel's oracle. Rows of blocks without rows stay zero."""
+    act = jax.nn.relu if relu else jax.nn.silu
+
+    def expert(w, e):
+        return jax.lax.dynamic_slice(
+            w, (layer_index, e, 0, 0), (1, 1) + w.shape[2:])[0, 0]
+
+    def block(b, y_pad):
+        e = block_expert[b]
+        xb = jax.lax.dynamic_slice_in_dim(x_pad, b * bm, bm)
+        gate = jnp.dot(xb, expert(w_gate, e),
+                       preferred_element_type=jnp.float32)
+        up = jnp.dot(xb, expert(w_up, e), preferred_element_type=jnp.float32)
+        yb = jnp.dot((act(gate) * up).astype(x_pad.dtype), expert(w_down, e),
+                     preferred_element_type=jnp.float32)
+        return jax.lax.dynamic_update_slice_in_dim(
+            y_pad, yb.astype(x_pad.dtype), b * bm, 0)
+
+    return jax.lax.fori_loop(0, n_blocks, block, jnp.zeros_like(x_pad))
+
+
+def dropless_moe_ffn(x: jax.Array, router_logits: jax.Array,
+                     lp: dict[str, jax.Array], cfg: LlamaConfig,
+                     row_mask: jax.Array | None = None):
+    """Dropless sparse MoE layer: (B, S, D) -> ((B, S, D), touched).
+
+    ``router_logits`` (B, S, E) are the caller's (they may come from
+    another point of the block than ``x``). Every token gets all k of its
+    experts whatever the batch, and a token's output does not depend on
+    its neighbours. ``touched`` is the number of distinct experts the
+    rows reached: the experts whose weights this call read.
+
+    ``lp`` holds the layer's own (E, in, out) expert stacks or — where
+    the caller keeps the stacks out of its layer scan and hands
+    ``layer_index`` (models/llama.py ``scan_layers``) — the whole
+    (L, E, in, out) stacks: an expert's matrix is sliced where it is
+    used, so that no program copies a layer's slab of experts.
+    """
+    B, S, D = x.shape
+    T = B * S
+    k = cfg.num_experts_per_tok
+    bm = dropless_block_rows(T)
+    relu = cfg.mlp == "relu_glu"
+    x_flat = x.reshape(T, D)
+    w_gate, w_up, w_down = (lp[n] if lp[n].ndim == 4 else lp[n][None]
+                            for n in ("w_gate", "w_up", "w_down"))
+    li = jnp.asarray(lp.get("layer_index", 0), jnp.int32)
+    with jax.named_scope("moe_route"):
+        rt = route_sorted(
+            router_logits.reshape(T, cfg.num_experts), k, bm,
+            None if row_mask is None else jnp.repeat(row_mask, S))
+        x_pad = jnp.where(rt["valid"][:, None], x_flat[rt["token"]], 0)
+
+    ffn = (grouped_ffn.grouped_expert_ffn
+           if grouped_ffn.use_kernel(D, w_gate.shape[-1], bm, x.dtype)
+           else block_loop_ffn)
+    with jax.named_scope("moe_experts"):
+        y_pad = ffn(x_pad, rt["block_expert"], rt["n_blocks"], li, w_gate,
+                    w_up, w_down, bm=bm, relu=relu)
+    return _combine_sorted(y_pad, rt, x.dtype, (B, S, D))
+
+
+def _combine_sorted(y_pad, rt, dtype, shape):
+    """Each token's k expert outputs back from their padded rows,
+    weighted. A row that routed nowhere (weight 0) reads nothing: the
+    kernel leaves blocks without rows unwritten."""
+    with jax.named_scope("moe_route"):
+        w = rt["weight"][..., None]                             # (T, k, 1)
+        y = jnp.where(w > 0, y_pad[rt["row_of"]].astype(jnp.float32), 0.0)
+        out = jnp.sum(y * w, axis=1)
+        return out.astype(dtype).reshape(shape), rt["touched"]
