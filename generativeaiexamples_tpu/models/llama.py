@@ -628,7 +628,8 @@ def apply_verify_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
 def _paged_prefix_attention(q, k_self, v_self, kc, vc, ksc, vsc,
                             block_table, start, kv_valid_len, page: int,
                             cfg: LlamaConfig, block_pages: int = 8,
-                            window: Optional[jax.Array] = None):
+                            window: Optional[jax.Array] = None,
+                            layer: jax.Array | int = 0):
     """Chunk queries attend [pooled prefix] + [their own chunk], with the
     prefix STREAMED from the pool in ``block_pages``-page blocks under an
     online softmax.
@@ -643,8 +644,9 @@ def _paged_prefix_attention(q, k_self, v_self, kc, vc, ksc, vsc,
     k/v_self:     (1, C, KV, hd) this chunk's post-rope K/V (NOT yet in
                   the pool — the pool's rows for these positions are
                   stale, so the self part computes in-register)
-    kc/vc:        (N, KV, page, hd) one layer's pool (int8 when ksc/vsc
-                  per-row scale layers are given)
+    kc/vc:        (L, N, KV, page, hd) the WHOLE pool, or one layer's
+                  (N, KV, page, hd); int8 when ksc/vsc, the per-row
+                  scales of the same leading axes, are given
     block_table:  (1, P) logical→physical window
     start:        () int32 — absolute position of the chunk's first row
                   (page-aligned); pool rows with logical position >=
@@ -655,6 +657,13 @@ def _paged_prefix_attention(q, k_self, v_self, kc, vc, ksc, vsc,
                   p - window < j <= p, and prefix blocks wholly behind
                   the FIRST query's window are skipped like those past
                   the prefix
+    layer:        () int32 — the layer to read of a whole pool. A
+                  block's pages are gathered by (layer, page) in ONE
+                  step, over the pool's flattened leading axes: a
+                  ``pool[layer]`` first is invariant in the block loop,
+                  and XLA hoists it into a copy of the layer's whole
+                  slab (2 x 142 MB a layer of a 1088-page pool) to read
+                  eight pages of it
     Returns (1, C, H, hd) in q.dtype.
     """
     B, C, H, hd = q.shape
@@ -664,6 +673,10 @@ def _paged_prefix_attention(q, k_self, v_self, kc, vc, ksc, vsc,
     P = block_table.shape[1]
     nb = -(-P // block_pages)
     tbl = jnp.pad(block_table[0], (0, nb * block_pages - P))
+    tbl = tbl + layer * kc.shape[-4]
+    kc, vc = (a.reshape((-1,) + a.shape[-3:]) for a in (kc, vc))
+    if ksc is not None:
+        ksc, vsc = (a.reshape((-1,) + a.shape[-2:]) for a in (ksc, vsc))
     cd = q.dtype
     # operands stay in storage dtype into the MXU with f32 accumulation
     # (casting whole K/V blocks to f32 up front would double the
@@ -795,10 +808,11 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     returns full (1, C, V) logits instead (a large transient at big
     vocab x chunk; only for callers that truly need every position).
 
-    Same memory discipline as the decode path's jnp branch: the layer
-    scan only READS the pool; per-layer chunk KV is collected as stacked
-    scan outputs and scattered into the pages once, after the scan — the
-    chunk rides the gathered window in-register for its own attention.
+    Memory discipline: the layer scan only READS the pool, whole and in
+    place — a layer's prefix blocks are gathered out of it by (layer,
+    page); per-layer chunk KV is collected as stacked scan outputs and
+    scattered into the pages once, after the scan — the chunk rides its
+    own attention in-register.
     """
     B, C = tokens.shape
     if B != 1:
@@ -815,12 +829,8 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
 
     quant = kv_cache_quantized(kv_cache)
 
-    def layer(h: jax.Array, xs):
-        if quant:
-            lp, kc, vc, ksc, vsc = xs
-        else:
-            lp, kc, vc = xs
-            ksc = vsc = None
+    def layer(carry, lp):
+        h, li = carry
         lp = {**lp, **held}
 
         def attend(q, k, v):
@@ -830,18 +840,22 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
             # the full gathered window — prefix length does not bound
             # this path's memory.
             attn = _paged_prefix_attention(
-                q, k, v, kc, vc, ksc, vsc, block_table, start,
-                kv_valid_len, page, cfg, window=lp.get("window"))
+                q, k, v, kv_cache["k"], kv_cache["v"], kv_cache.get("ks"),
+                kv_cache.get("vs"), block_table, start, kv_valid_len, page,
+                cfg, window=lp.get("window"), layer=li)
             return attn, (k[0], v[0])
 
-        return decoder_layer(h, lp, cfg, positions, inv_freq, kv_valid_len,
-                             attend=attend)
+        h, new_kv = decoder_layer(h, lp, cfg, positions, inv_freq,
+                                  kv_valid_len, attend=attend)
+        return (h, li + 1), new_kv
 
+    # The pool stays OUT of the scan's sliced inputs (as ``xs``, each
+    # iteration's slice of it is a copy of the layer's whole K and V
+    # slab): the body closes over it whole and counts its layer in the
+    # carry, as the decode kernel path does.
     layers, held = scan_layers(params, cfg)
-    xs = (layers, kv_cache["k"], kv_cache["v"])
-    if quant:
-        xs = xs + (kv_cache["ks"], kv_cache["vs"])
-    h, (new_k, new_v) = jax.lax.scan(layer, h, xs)
+    (h, _), (new_k, new_v) = jax.lax.scan(
+        layer, (h, jnp.zeros((), jnp.int32)), layers)
     # new_k/new_v: (L, C, KV, hd) -> (L, nb, KV, page, hd) page blocks,
     # scattered at the chunk's physical pages in one shot.
     L_ = new_k.shape[0]

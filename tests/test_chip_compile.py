@@ -503,3 +503,169 @@ def test_weight_report_follows_a_stack_through_the_loop(form):
             "stack"]
         assert [c.split(" ")[0] for c in report["slice_results"]] == [
             "slice_fusion.3"]
+
+
+# ------------------------------ how a chunk program reads the KV pool
+
+
+@pytest.mark.parametrize("kv", ["bf16kv", "int8kv"])
+@pytest.mark.parametrize("model,layers", [
+    ("nemotron-8b-chat", 2), ("mixtral-8x7b-instruct", 2),
+    ("smallthinker-21b-a3b-instruct", 4)],      # one period of kinds
+    ids=["nemotron", "mixtral", "smallthinker"])
+def test_chunk_program_reads_the_pool_in_place(topo, tpu_backend, model,
+                                               layers, kv):
+    """The 512-token chunk program holds NO instruction whose result is
+    a layer's slab of the pool (N, KV, page, hd), or a second pool: the
+    layer scan keeps the pool out of its sliced inputs and a prefix
+    block's pages are gathered by (layer, page) out of the whole array.
+    What this guards against (ISSUE 29): with the pool among the scan's
+    ``xs`` every layer of every chunk program copied its whole K and V
+    slab (``dynamic-slice_bitcast_fusion.4/.5``: 2 x 142 MB a layer of
+    ``long-context-decode``'s 1088 pages, 30 % of a chunk program) to
+    attend eight pages of it. The pool is sized so that a bf16 slab is
+    256 MiB — no activation of a chunk comes near it — and the program's
+    temporaries must stay under ONE slab, the donated pool updated in
+    place."""
+    from tools.dump_hlo import pool_report
+    cfg = dataclasses.replace(get_model_config(model), num_layers=layers)
+    dev = SingleDeviceSharding(topo.devices[0])
+    n_pages = 1 + (256 << 20) // (cfg.num_kv_heads * PAGE * cfg.head_dim * 2)
+    cache = on(jax.eval_shape(lambda: llama.init_paged_kv_cache(
+        cfg, n_pages, PAGE, quantized=kv == "int8kv")), dev)
+    i32 = lambda *shape: sds(shape, jnp.int32, dev)  # noqa: E731
+
+    def chunk(params, tok, pos, cache, tbl, valid, start):
+        return llama.apply_prefill_paged(params, cfg, tok, pos, cache, tbl,
+                                         valid, start)
+
+    compiled = jax.jit(chunk, donate_argnums=(3,)).lower(
+        on(param_shapes(cfg), dev), i32(1, 512), i32(1, 512), cache,
+        i32(1, 24), i32(1), i32()).compile()
+    leaves = [(v.dtype.name, v.shape) for v in cache.values()]
+    assert pool_report(compiled.as_text(), leaves) == []
+    m = compiled.memory_analysis()
+    pool_bytes = sum(v.size * v.dtype.itemsize for v in cache.values())
+    slab = cache["k"].size * cache["k"].dtype.itemsize // layers
+    assert m.temp_size_in_bytes < slab, (m.temp_size_in_bytes, slab)
+    assert m.alias_size_in_bytes >= pool_bytes, m
+
+
+_POOL = "bf16[2,9,4,16,64]{4,3,2,1,0:T(8,128)(2,1)}"
+_SLAB = "bf16[9,4,16,64]{3,2,1,0:T(8,128)(2,1)}"
+_BLOCK = "bf16[8,4,16,64]{3,2,1,0:T(8,128)(2,1)}"
+_H = "bf16[32,64]{1,0:T(8,128)(2,1)}"
+_T4 = f"(s32[]{{:T(128)}}, {_H}, {_POOL}, s32[8]{{0:T(128)}})"
+
+# A two-layer chunk program over one pool array, as optimised HLO prints
+# it: the layer loop gathers a block's eight pages (@LAYER@), then the
+# entry scatters the chunk's own pages into the donated pool in place.
+_HLO_CHUNK = f"""HloModule jit_chunk, is_scheduled=true, input_output_alias={{ {{1}}: (1, {{}}, may-alias) }}
+
+@FUSED@
+
+%fused_attend (p0: bf16[32,64], p1: bf16[8,4,16,64]) -> bf16[32,64] {{
+  %p0 = {_H} parameter(0)
+  %p1 = {_BLOCK} parameter(1)
+  %kb = bf16[64,512]{{1,0:T(8,128)(2,1)}} bitcast(%p1)
+  %s = bf16[32,512]{{1,0:T(8,128)(2,1)}} dot(%p0, %kb), lhs_contracting_dims={{1}}, rhs_contracting_dims={{0}}
+  ROOT %o = {_H} dot(%s, %kb), lhs_contracting_dims={{1}}, rhs_contracting_dims={{1}}
+}}
+
+%fused_scatter (p0: bf16[2,9,4,16,64], p1: s32[2], p2: bf16[2,2,4,16,64]) -> bf16[2,9,4,16,64] {{
+  %p0 = {_POOL} parameter(0)
+  %p1 = s32[2]{{0:T(128)}} parameter(1)
+  %p2 = bf16[2,2,4,16,64]{{4,3,2,1,0:T(8,128)(2,1)}} parameter(2)
+  ROOT %scatter.1 = {_POOL} scatter(%p0, %p1, %p2), update_window_dims={{0,2,3,4}}, inserted_window_dims={{1}}, scatter_dims_to_operand_dims={{1}}, index_vector_dim=1, to_apply=%assign
+}}
+
+%cond (arg: (s32[], bf16[32,64], bf16[2,9,4,16,64], s32[8])) -> pred[] {{
+  %arg = {_T4} parameter(0)
+  %i = s32[]{{:T(128)}} get-tuple-element(%arg), index=0
+  %n = s32[]{{:T(128)}} constant(2)
+  ROOT %lt = pred[]{{:T(512)}} compare(%i, %n), direction=LT
+}}
+
+%body (arg: (s32[], bf16[32,64], bf16[2,9,4,16,64], s32[8])) -> (s32[], bf16[32,64], bf16[2,9,4,16,64], s32[8]) {{
+  %arg = {_T4} parameter(0)
+  %i = s32[]{{:T(128)}} get-tuple-element(%arg), index=0
+  %h = {_H} get-tuple-element(%arg), index=1
+  %pool = {_POOL} get-tuple-element(%arg), index=2
+  %pages = s32[8]{{0:T(128)}} get-tuple-element(%arg), index=3
+@LAYER@
+  %fusion.9 = {_H} fusion(%h, %gather_fusion.2), kind=kOutput, calls=%fused_attend
+  %one = s32[]{{:T(128)}} constant(1)
+  %next = s32[]{{:T(128)}} add(%i, %one)
+  ROOT %t = {_T4} tuple(%next, %fusion.9, %pool, %pages)
+}}
+
+ENTRY %main (h0: bf16[32,64], k: bf16[2,9,4,16,64], pages: s32[8], dest: s32[2], new: bf16[2,2,4,16,64]) -> (bf16[32,64], bf16[2,9,4,16,64]) {{
+  %h0 = {_H} parameter(0)
+  %k = {_POOL} parameter(1), metadata={{op_name="kv_cache[\\'k\\']"}}
+  %pages = s32[8]{{0:T(128)}} parameter(2)
+  %dest = s32[2]{{0:T(128)}} parameter(3)
+  %new = bf16[2,2,4,16,64]{{4,3,2,1,0:T(8,128)(2,1)}} parameter(4)
+@ENTRY@
+  %zero = s32[]{{:T(128)}} constant(0)
+  %init = {_T4} tuple(%zero, %h0, %read, %pages)
+  %loop = {_T4} while(%init), condition=%cond, body=%body
+  %out = {_H} get-tuple-element(%loop), index=1
+  %scatter_fusion = {_POOL} fusion(%k, %dest, %new), kind=kInput, calls=%fused_scatter
+  ROOT %r = ({_H}, {_POOL}) tuple(%out, %scatter_fusion)
+}}
+"""
+
+_POOL_FORMS = {
+    # eight pages gathered by (layer, page) out of the whole pool
+    "in_place": dict(
+        fused=f"""%fused_gather (p0: bf16[18,4,16,64], p1: s32[8], p2: s32[]) -> bf16[8,4,16,64] {{
+  %p0 = bf16[18,4,16,64]{{3,2,1,0:T(8,128)(2,1)}} parameter(0)
+  %p1 = s32[8]{{0:T(128)}} parameter(1)
+  %p2 = s32[]{{:T(128)}} parameter(2)
+  ROOT %g = {_BLOCK} gather(%p0, %p1), offset_dims={{1,2,3}}, collapsed_slice_dims={{0}}, start_index_map={{0}}, index_vector_dim=1, slice_sizes={{1,4,16,64}}
+}}""",
+        layer=f"  %flat = bf16[18,4,16,64]{{3,2,1,0:T(8,128)(2,1)}} "
+              f"bitcast(%pool)\n"
+              f"  %gather_fusion.2 = {_BLOCK} fusion(%flat, %pages, %i), "
+              f"kind=kLoop, calls=%fused_gather",
+        entry=f"  %read = {_POOL} bitcast(%k)"),
+    # as the parent compiled it: the layer's slab sliced out of the pool
+    # first (and, for the report's other half, a second pool made for
+    # the loop to read)
+    "copied": dict(
+        fused=f"""%fused_slice (p0: bf16[2,9,4,16,64], p1: s32[]) -> bf16[9,4,16,64] {{
+  %p0 = {_POOL} parameter(0)
+  %p1 = s32[]{{:T(128)}} parameter(1)
+  %c0 = s32[]{{:T(128)}} constant(0)
+  %ds = bf16[1,9,4,16,64]{{4,3,2,1,0:T(8,128)(2,1)}} dynamic-slice(%p0, %p1, %c0, %c0, %c0, %c0), dynamic_slice_sizes={{1,9,4,16,64}}
+  ROOT %b = {_SLAB} bitcast(%ds)
+}}
+
+%fused_gather (p0: bf16[9,4,16,64], p1: s32[8]) -> bf16[8,4,16,64] {{
+  %p0 = {_SLAB} parameter(0)
+  %p1 = s32[8]{{0:T(128)}} parameter(1)
+  ROOT %g = {_BLOCK} gather(%p0, %p1), offset_dims={{1,2,3}}, collapsed_slice_dims={{0}}, start_index_map={{0}}, index_vector_dim=1, slice_sizes={{1,4,16,64}}
+}}""",
+        layer=f"  %dynamic-slice_bitcast_fusion.4 = {_SLAB} fusion(%pool, "
+              f"%i), kind=kLoop, calls=%fused_slice\n"
+              f"  %gather_fusion.2 = {_BLOCK} fusion("
+              f"%dynamic-slice_bitcast_fusion.4, %pages), kind=kLoop, "
+              f"calls=%fused_gather",
+        entry=f"  %read = {_POOL} copy(%k)"),
+}
+
+
+@pytest.mark.parametrize("form", ["in_place", "copied"])
+def test_pool_report_finds_a_slab_and_a_second_pool(form):
+    """``tools/dump_hlo.pool_report`` on hand-written optimised HLO:
+    a slab sliced out inside the layer loop and a whole-pool ``copy``
+    are reported; parameters, tuple elements, bitcasts and the in-place
+    scatter of the donated pool are not."""
+    from tools.dump_hlo import pool_report
+    text = _HLO_CHUNK
+    for mark, part in _POOL_FORMS[form].items():
+        text = text.replace(f"@{mark.upper()}@", part)
+    found = pool_report(text, [("bfloat16", (2, 9, 4, 16, 64))])
+    assert [f.split(" ")[0] for f in found] == {
+        "in_place": [],
+        "copied": ["dynamic-slice_bitcast_fusion.4", "read"]}[form]

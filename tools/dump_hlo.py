@@ -13,8 +13,10 @@ memory only): an engine built on the CPU with zero weights
 ``--engine-layers`` deep, its 8-step decode round (greedy and sampled)
 and one 512-token chunk of the chunked prefill, lowered for the
 described chip. Writes ``<out>/<config>.<program>.hlo.txt`` and prints
-one JSON line: each program's temporaries and what :func:`weight_report`
-finds in its text. Nothing runs; no time comes of it.
+one JSON line: each program's temporaries, what :func:`weight_report`
+finds in its text and, as ``pool_copies``, what :func:`pool_report` does
+(instructions that copy a layer's slab of the KV pool, or a whole pool).
+Nothing runs; no time comes of it.
 """
 
 from __future__ import annotations
@@ -209,10 +211,69 @@ def weight_report(text: str) -> dict:
     return report
 
 
+# opcodes whose result is no new buffer: a view of, or a name for, a
+# value that exists already
+_VIEWS = ("parameter", "bitcast", "get-tuple-element", "tuple", "while",
+          "conditional", "call", "optimization-barrier")
+
+
+def pool_report(text: str, leaves: list[tuple[str, tuple]]) -> list[str]:
+    """The instructions of a program whose RESULT is one layer's slab of
+    the paged KV pool, or a whole pool: a copy of KV nobody asked for.
+
+    ``leaves``: (dtype name, shape) of each pool array, layer-major —
+    ``("bfloat16", (L, N, KV, page, hd))``, and under int8 KV the
+    ``(L, N, KV, page)`` scale arrays too. A result matches by element
+    type and shape, leading 1s aside: a slab ``(N, ...)``, a pool
+    ``(L, N, ...)`` or the pool with its two leading axes flattened.
+    Not reported: the opcodes of ``_VIEWS``, and an instruction that
+    takes a whole pool and returns one — the in-place update of the
+    donated argument (``memory_analysis()`` says whether it stayed in
+    place: temporaries then hold no second pool). A fusion's inside is
+    judged by its caller, as in :func:`weight_report`."""
+    comps = parse_hlo(text)
+    fused = {_attr(i, "calls") for ins in comps.values() for i in ins
+             if i["op"] == "fusion"}
+    hlo_type = {"bfloat16": "bf16", "float32": "f32", "int8": "s8"}
+
+    def typed_dims(shape):
+        m = re.match(r"(\w+)\[([\d,]+)\]", shape)
+        if m is None:       # a tuple, a token, a scalar
+            return None
+        dims = [int(d) for d in m.group(2).split(",")]
+        while len(dims) > 1 and dims[0] == 1:
+            dims.pop(0)
+        return m.group(1), tuple(dims)
+
+    pools, slabs = set(), set()
+    for dtype, (n_layers, n_pages, *rest) in leaves:
+        t = hlo_type[dtype]
+        pools |= {(t, (n_layers, n_pages, *rest)),
+                  (t, (n_layers * n_pages, *rest))}
+        slabs.add((t, (n_pages, *rest)))
+    wanted = pools | slabs
+    found = []
+    for comp, instrs in comps.items():
+        if comp in fused:
+            continue
+        made_by = {i["name"]: typed_dims(i["shape"]) for i in instrs}
+        for ins in instrs:
+            made = made_by[ins["name"]]
+            if ins["op"] in _VIEWS or made not in wanted:
+                continue
+            if made in pools and ins["op"] != "copy" and any(
+                    made_by.get(o) in pools for o in ins["operands"]):
+                continue
+            found.append(f"{ins['name']} = {ins['shape']} {ins['op']}"
+                         f"({','.join(ins['operands'])})")
+    return found
+
+
 def engine_programs(config_name: str, engine_layers: int,
                     pool_tokens: int = 16 * 1024):
-    """Yield (program name, compiled) for the decode rounds and one
-    chunk program of ``benchmarks/configs/<config_name>.json``."""
+    """Yield (program name, compiled, pool leaves) for the decode rounds
+    and one chunk program of ``benchmarks/configs/<config_name>.json``;
+    the leaves are :func:`pool_report`'s (dtype name, shape) pairs."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
@@ -257,6 +318,7 @@ def engine_programs(config_name: str, engine_layers: int,
         k: sds(v.shape, v.dtype, Format(
             Layout(major_to_minor=tuple(range(v.ndim))), dev))
         for k, v in eng._state["cache"].items()}
+    leaves = [(v.dtype.name, v.shape) for v in eng._state["cache"].values()]
     p_sds = on(eng.params)
     key = jax.eval_shape(lambda: jax.random.key(0))
     B = eng.cfg.max_slots
@@ -264,12 +326,12 @@ def engine_programs(config_name: str, engine_layers: int,
         fn = eng._round_fn(eng._pmax, 8, greedy, B)
         yield (f"decode_round_{'greedy' if greedy else 'sampled'}",
                fn.lower(p_sds, state, key,
-                        sds((B,), jnp.int32)).compile())
+                        sds((B,), jnp.int32)).compile(), leaves)
     window = eng._pmax
     i32 = sds((), jnp.int32)
     yield ("chunk_extend_512", eng._chunk_extend_fn(window, "accum").lower(
         state, p_sds, sds((1, 512), jnp.int32), i32, i32, i32,
-        sds((1, window), jnp.int32)).compile())
+        sds((1, window), jnp.int32)).compile(), leaves)
 
 
 def main(argv=None) -> int:
@@ -284,7 +346,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     os.makedirs(args.out, exist_ok=True)
     out = {"config": args.config, "engine_layers": args.engine_layers}
-    for name, compiled in engine_programs(
+    for name, compiled, leaves in engine_programs(
             args.config, args.engine_layers, args.pool_tokens):
         text = compiled.as_text()
         path = os.path.join(args.out, f"{args.config}.{name}.hlo.txt")
@@ -293,6 +355,7 @@ def main(argv=None) -> int:
         out[name] = {
             "file": path,
             "temporaries": compiled.memory_analysis().temp_size_in_bytes,
+            "pool_copies": pool_report(text, leaves),
             **weight_report(text)}
     print(json.dumps(out), flush=True)
     return 0
