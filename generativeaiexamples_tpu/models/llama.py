@@ -9,7 +9,9 @@ Design choices (TPU-first, not a port):
 - **Stacked layer params + ``lax.scan``**: every per-layer tensor is stacked
   along a leading L axis and the decoder scans over layers. One layer gets
   traced/compiled, not 32/40/80 — compile time stays flat with depth, and
-  sharding rules are written once per leaf.
+  sharding rules are written once per leaf. ONE function runs that scan
+  (``_run_stack``) and one writes the paged pool (``_write_pool``); the
+  forwards (``apply*``, ``run_layers``) hand them only what differs.
 - **Absolute-position KV cache**: cache index == token position. Prefill and
   decode are the same function with different (tokens, positions) shapes; no
   dynamic shapes ever reach XLA.
@@ -68,15 +70,11 @@ def _embed(params: "Params", tokens: jax.Array) -> jax.Array:
 
 
 def use_paged_kernel(cfg: LlamaConfig, page: int) -> bool:
-    """Public alias: whether the Pallas paged-attention decode kernel will
-    be used for this config (the engine pins pool layouts accordingly)."""
-    return _use_paged_kernel(cfg, page)
-
-
-def _use_paged_kernel(cfg: LlamaConfig, page: int) -> bool:
-    """Pallas paged-attention gate: on TPU backends with kernel-supported
-    geometry (lane-aligned head_dim/page), unless disabled via
-    GENAI_TPU_PAGED_KERNEL=0. Other backends take the jnp gather path."""
+    """Whether the Pallas paged-attention decode kernel will be used for
+    this config (the engine pins pool layouts accordingly): on TPU
+    backends with kernel-supported geometry (lane-aligned
+    head_dim/page), unless disabled via GENAI_TPU_PAGED_KERNEL=0. Other
+    backends take the jnp gather path."""
     flag = os.environ.get("GENAI_TPU_PAGED_KERNEL", "auto")
     if flag == "0":
         return False
@@ -225,18 +223,23 @@ def kv_cache_quantized(kv_cache: KVCache) -> bool:
     return "ks" in kv_cache
 
 
-def _gathered_window(pool_layer, scales_layer, block_table, B, P, page,
+def _gathered_window(kv_cache: KVCache, name: str, layer, block_table,
                      cfg: LlamaConfig, dtype):
-    """One layer's slot windows gathered from the paged pool:
-    (N, KV, page, hd) -> (B, P*page, KV, hd), dequantizing int8 pages via
-    their per-row scales (``scales_layer`` (N, KV, page), or None for a
-    full-precision pool). Shared by the decode and chunked-prefill jnp
-    paths."""
-    g = pool_layer[block_table]                 # (B, P, KV, page, hd)
-    if scales_layer is not None:
+    """One layer's slot windows of leaf ``name`` ("k" or "v") gathered
+    from the WHOLE paged pool, (L, N, KV, page, hd) -> (B, P*page, KV,
+    hd), by (layer, page) in one step over the pool's flattened leading
+    axes (a ``pool[layer]`` first is a copy of the layer's whole slab);
+    int8 pages are dequantized via their per-row scales."""
+    pool = kv_cache[name]
+    B, P = block_table.shape
+    pages = block_table + layer * pool.shape[1]
+    g = pool.reshape((-1,) + pool.shape[2:])[pages]  # (B, P, KV, page, hd)
+    if kv_cache_quantized(kv_cache):
         from ..ops.kv_quant import dequantize_rows
-        g = dequantize_rows(g, scales_layer[block_table], dtype)
-    return g.swapaxes(2, 3).reshape(B, P * page, cfg.num_kv_heads,
+        scales = kv_cache[name + "s"]                   # (L, N, KV, page)
+        g = dequantize_rows(
+            g, scales.reshape((-1,) + scales.shape[2:])[pages], dtype)
+    return g.swapaxes(2, 3).reshape(B, P * pool.shape[3], cfg.num_kv_heads,
                                     cfg.head_dim)
 
 
@@ -252,9 +255,6 @@ def kernel_tp_compatible(cfg: LlamaConfig, mesh) -> bool:
         return False
     return (cfg.num_kv_heads % tp == 0 and cfg.num_heads % tp == 0
             and (cfg.num_kv_heads // tp) > 0)
-
-
-_EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 
 
 def layer_kinds(cfg: LlamaConfig) -> dict[str, jax.Array]:
@@ -284,10 +284,129 @@ def scan_layers(params: Params, cfg: LlamaConfig
     layers = dict(params["layers"])
     held: dict[str, jax.Array] = {}
     if cfg.num_experts and cfg.moe_impl == "dropless":
-        held = {n: layers.pop(n) for n in _EXPERT_LEAVES}
+        held = {n: layers.pop(n) for n in ("w_gate", "w_up", "w_down")}
         layers["layer_index"] = jnp.arange(cfg.num_layers, dtype=jnp.int32)
     layers.update(layer_kinds(cfg))
     return layers, held
+
+
+def _run_stack(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
+               positions: jax.Array, inv_freq: jax.Array,
+               kv_valid_len: Optional[jax.Array], attend=None, *,
+               state=None, xs: Optional[dict] = None,
+               row_mask: Optional[jax.Array] = None, stats: bool = False):
+    """The ONE scan over the layer stack; every forward is a use of it
+    and hands it only what differs. ``attend(q, k, v, lp, li, state) ->
+    (attn, out)`` is a layer's KV step (None: attention over the tokens
+    given); ``lp`` holds the layer's parameters, its kinds and its slice
+    of ``xs``, ``li`` is its index. Returns ``(h, out, touched)``, the
+    last each layer's ``experts_touched`` (L,) under ``stats``.
+
+    What the scan iterates over and what it closes over was decided on
+    the chip, three times, and is written down here and in ``scan_layers``:
+
+    - *state carried* (``state`` given; ``out`` is the next layer's
+      state and, at the end, the result): the decode kernel path. The
+      pool rides the carry and passes through the Pallas call aliased
+      in place — attention read and row append happen inside the kernel,
+      so no XLA gather or scatter ever touches the pool, no layout is
+      fought over and the carry is not double-buffered.
+    - *pool held, rows out* (no ``state``; ``out`` is stacked over the
+      layers): every other forward that has a pool. The body closes
+      over the pool WHOLE and reads it by (layer, page) over its
+      flattened leading axes; a layer's new rows are scan outputs and
+      ONE write after the scan puts them in the pool (``_write_pool``).
+      Handed to the scan as sliced inputs, each iteration's slice of the
+      pool is a copy of the layer's whole K and V slab (PR 29: 2 x 142 MB
+      a layer to attend eight pages), and as sliced-in / stacked-out it
+      is a second pool in loop temporaries (the round-2 OOM).
+    - dropless expert stacks are held too, never sliced by the scan
+      (``scan_layers``; PR 28).
+
+    A new layer kind adds its flag in ``layer_kinds`` and reads it in
+    ``decoder_layer`` or an ``attend``; a new cache format edits
+    ``_write_pool``, the two readers (``_gathered_window``,
+    ``_paged_prefix_attention``) and the kernel call.
+    """
+    if jax.tree.leaves(layers)[0].shape[0] == cfg.num_layers:
+        stack, held = scan_layers({"layers": layers}, cfg)
+    else:                       # a pipeline stage's share of the depth
+        _refuse_kinds(cfg, "a partial layer stack")
+        stack, held = layers, {}
+    stack = {**stack, **(xs or {})}
+    carried = state is not None
+
+    def body(carry, lp):
+        h, state, li = carry
+        lp = {**lp, **held}
+        aux = {} if stats else None
+        h, out = decoder_layer(
+            h, lp, cfg, positions, inv_freq, kv_valid_len,
+            attend=attend and (lambda q, k, v: attend(q, k, v, lp, li,
+                                                       state)),
+            row_mask=row_mask, aux=aux)
+        if carried:
+            state, out = out, None
+        touched = None if aux is None else aux.get("experts_touched",
+                                                   jnp.float32(0.0))
+        return (h, state, li + 1), (out, touched)
+
+    # the Pallas call takes its layer as a (1,) scalar-prefetch operand;
+    # the held form indexes the flattened pool with a scalar
+    carry = (h, state, jnp.zeros((1,) if carried else (), jnp.int32))
+    (h, state, _), (out, touched) = jax.lax.scan(body, carry, stack)
+    return h, (state if carried else out), touched
+
+
+def _write_pool(kv_cache: KVCache, new_k: jax.Array, new_v: jax.Array,
+                pages: jax.Array, offsets: Optional[jax.Array] = None
+                ) -> KVCache:
+    """The one place that writes rows into the paged pool and knows its
+    format: the layers' new K and V, stacked (L, ...) as the scan gave
+    them, are quantised when the pool has scale planes and written in
+    ONE scatter a leaf, after the scan. Two destinations:
+
+    - rows (``offsets`` given): new (L, B, S, KV, hd) to ``pages`` /
+      ``offsets`` (B, S), each token's physical page and row in it (a
+      decode step is S = 1): one (layer, flat row) index per (slot,
+      token, kv-head) over (N, KV, page) flattened. Indexed ``[:, row]``
+      instead, the TPU compiler relayouts the WHOLE pool to scatter and
+      back (compile, PR 30).
+    - whole pages (``offsets`` None): a chunk's new (L, C, KV, hd), C a
+      page multiple, to its C / page physical ``pages``.
+    """
+    L, N, KV, page, _ = kv_cache["k"].shape
+    new = {"k": new_k, "v": new_v}
+    if kv_cache_quantized(kv_cache):
+        from ..ops.kv_quant import quantize_rows
+        new["k"], new["ks"] = quantize_rows(new_k)    # scales: (..., KV)
+        new["v"], new["vs"] = quantize_rows(new_v)
+    if offsets is None:
+        def put(pool, rows):
+            blocks = rows.reshape((L, -1, page) + rows.shape[2:])
+            return pool.at[:, pages].set(
+                blocks.swapaxes(2, 3).astype(pool.dtype))
+    else:
+        flat_idx = ((pages[..., None] * KV + jnp.arange(KV)) * page
+                    + offsets[..., None])                   # (B, S, KV)
+        layer = jnp.arange(L)[:, None, None, None]
+
+        def put(pool, rows):
+            flat = pool.reshape((L, N * KV * page) + pool.shape[4:])
+            return flat.at[layer, flat_idx[None]].set(
+                rows.astype(pool.dtype)).reshape(pool.shape)
+
+    with jax.named_scope("attn"):      # the KV write
+        return {name: put(kv_cache[name], rows)
+                for name, rows in new.items()}
+
+
+def _step_result(params: Params, cfg: LlamaConfig, h: jax.Array,
+                 cache: KVCache, touched, return_hidden: bool, stats: bool):
+    out = h if return_hidden else unembed(params, cfg, h)
+    if stats:
+        return out, cache, {"experts_touched": jnp.mean(touched)}
+    return out, cache
 
 
 def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
@@ -318,212 +437,79 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     tail does its own norm + streamed projection; see
     ops/fused_sampler.py).
 
-    Memory discipline: the layer scan only READS the pool; each layer's new
-    K/V (tiny) is collected as a scan output and the pool is updated with
-    ONE in-place scatter afterwards. Routing the pool itself through the
-    scan as sliced-xs/stacked-ys would make XLA materialize a second full
-    copy of the pool as loop temporaries — 2x pool HBM, the round-2 bench
-    OOM. The current token instead rides the gathered attention window.
+    ``use_kernel``: the engine decides (None = auto, for single-device
+    callers). True is the Pallas kernel with the pool in the layer
+    scan's carry (``_run_stack``); False — the CPU, a mesh the kernel
+    refuses — is the one-token case of ``apply_verify_paged``.
     """
-    B, S = tokens.shape
-    P = block_table.shape[1]
-    page = kv_cache["k"].shape[3]  # (L, N, KV, page, hd)
+    if use_kernel is None:
+        use_kernel = use_paged_kernel(cfg, kv_cache["k"].shape[3])
+    if not use_kernel:
+        return apply_verify_paged(
+            params, cfg, tokens, positions, kv_cache, block_table,
+            kv_valid_len, write_page[:, None], write_offset[:, None],
+            return_hidden, active=active, stats=stats)
+    from ..ops.paged_attention import paged_attention_decode
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
                                 cfg.rope_scaling_factor)
     h = _embed(params, tokens)
     pos_in_win = positions[:, 0]  # logical index of the current token
-    rows = jnp.arange(B)
-    xs, held = scan_layers(params, cfg)
+    # int8-KV pools: the kernel quantizes the appended row itself, so
+    # the current token's K/V pass in compute dtype, not pool dtype.
+    dt = h.dtype if kv_cache_quantized(kv_cache) else kv_cache["k"].dtype
+    interp = jax.default_backend() != "tpu"
 
-    # use_kernel: the caller (engine) decides — the Pallas path has no
-    # SPMD partitioning rule, so mesh/TP serving must take the jnp path.
-    # None = auto for single-device callers.
-    if use_kernel is None:
-        use_kernel = _use_paged_kernel(cfg, page)
-    quant = kv_cache_quantized(kv_cache)
-    if use_kernel:
-        # Kernel path: the pools ride the scan CARRY and pass through the
-        # Pallas call aliased in place (attention read + row append happen
-        # inside the kernel). No XLA gather/scatter ever touches the pool,
-        # so no layout fights and no carry double-buffering.
-        from ..ops.paged_attention import paged_attention_decode
-        # int8-KV pools: the kernel quantizes the appended row itself, so
-        # the current token's K/V pass in compute dtype, not pool dtype.
-        dt = h.dtype if quant else kv_cache["k"].dtype
+    # ``win``: the layer's window as one more (1,) operand, only in a
+    # model that has window layers
+    def call_kernel(q, pool, ck, cv, li, tbl, lens, wp, off, *win):
+        attn, *leaves = paged_attention_decode(
+            q, pool["k"], pool["v"], tbl, lens, ck, cv, wp, off, li,
+            pool_ks=pool.get("ks"), pool_vs=pool.get("vs"),
+            interpret=interp, window=win[0] if win else None)
+        return attn, dict(zip(("k", "v", "ks", "vs"), leaves))
+
+    if mesh is not None and "tp" in mesh.shape:
         # Pallas has no SPMD partitioning rule, so under a tp mesh the
         # call is shard_mapped: each device runs the kernel on its own
         # H/tp query heads and KV/tp pool shard — table/positions are
         # replicated, and the append lands in the local shard. This is
         # what keeps the v5e-8 TP serving config off the ~10x-slower
         # gather path (VERDICT r3 weak #3).
-        interp = jax.default_backend() != "tpu"
+        from jax.sharding import PartitionSpec as P
+        heads = P(None, "tp", None)                     # q, ck, cv, attn
+        pool_specs = {name: P(None, None, "tp", *(None,) * (leaf.ndim - 3))
+                      for name, leaf in kv_cache.items()}
+        call_kernel = jax.shard_map(
+            call_kernel, mesh=mesh,
+            in_specs=(heads, pool_specs, heads, heads)
+            + (P(),) * (5 + any(cfg.layer_windows)),
+            out_specs=(heads, pool_specs), check_vma=False)
 
-        # ``win``: the layer's window as one more (1,) operand, only in
-        # a model that has window layers
-        if quant:
-            def call_kernel(q, pk, pv, ks, vs, ck, cv, li, tbl, lens,
-                            wp, off, *win):
-                return paged_attention_decode(
-                    q, pk, pv, tbl, lens, ck, cv, wp, off, li,
-                    pool_ks=ks, pool_vs=vs, interpret=interp,
-                    window=win[0] if win else None)
-        else:
-            def call_kernel(q, pk, pv, ck, cv, li, tbl, lens, wp, off,
-                            *win):
-                return paged_attention_decode(
-                    q, pk, pv, tbl, lens, ck, cv, wp, off, li,
-                    interpret=interp, window=win[0] if win else None)
+    def attend(q, k, v, lp, li, pool):
+        win = (lp["window"][None],) if "window" in lp else ()
+        attn, pool = call_kernel(
+            q[:, 0], pool, k[:, 0].astype(dt), v[:, 0].astype(dt), li,
+            block_table, pos_in_win, write_page, write_offset, *win)
+        return attn[:, None], pool
 
-        if mesh is not None and "tp" in mesh.shape:
-            from jax.sharding import PartitionSpec as P
-            kv_spec = P(None, None, "tp", None, None)
-            sc_spec = P(None, None, "tp", None)
-            head_specs = (P(None, "tp", None),) * 2  # ck, cv
-            if quant:
-                in_specs = ((P(None, "tp", None), kv_spec, kv_spec,
-                             sc_spec, sc_spec) + head_specs
-                            + (P(), P(), P(), P(), P()))
-                out_specs = (P(None, "tp", None), kv_spec, kv_spec,
-                             sc_spec, sc_spec)
-            else:
-                in_specs = ((P(None, "tp", None), kv_spec, kv_spec)
-                            + head_specs + (P(), P(), P(), P(), P()))
-                out_specs = (P(None, "tp", None), kv_spec, kv_spec)
-            if "window" in xs:
-                in_specs = in_specs + (P(),)
-            call_kernel = jax.shard_map(
-                call_kernel, mesh=mesh, in_specs=in_specs,
-                out_specs=out_specs, check_vma=False)
-
-        def layer_k(carry, lp):
-            lp = {**lp, **held}
-            win = (lp["window"][None],) if "window" in lp else ()
-            aux = {} if stats else None
-            if quant:
-                h, pk, pv, ks, vs, li = carry
-            else:
-                h, pk, pv, li = carry
-
-            def attend(q, k, v):
-                if quant:
-                    attn, pk2, pv2, ks2, vs2 = call_kernel(
-                        q[:, 0], pk, pv, ks, vs, k[:, 0].astype(dt),
-                        v[:, 0].astype(dt), li, block_table, pos_in_win,
-                        write_page, write_offset, *win)
-                    return attn[:, None], (pk2, pv2, ks2, vs2)
-                attn, pk2, pv2 = call_kernel(
-                    q[:, 0], pk, pv, k[:, 0].astype(dt),
-                    v[:, 0].astype(dt), li, block_table, pos_in_win,
-                    write_page, write_offset, *win)
-                return attn[:, None], (pk2, pv2)
-
-            if quant:
-                h, (pk, pv, ks, vs) = decoder_layer(
-                    h, lp, cfg, positions, inv_freq, kv_valid_len,
-                    attend=attend, row_mask=active, aux=aux)
-                return (h, pk, pv, ks, vs, li + 1), _touched(aux)
-            h, (pk, pv) = decoder_layer(h, lp, cfg, positions, inv_freq,
-                                        kv_valid_len, attend=attend,
-                                        row_mask=active, aux=aux)
-            return (h, pk, pv, li + 1), _touched(aux)
-
-        li0 = jnp.zeros((1,), jnp.int32)
-        if quant:
-            (h, pk, pv, ks, vs, _), touched = jax.lax.scan(
-                layer_k, (h, kv_cache["k"], kv_cache["v"],
-                          kv_cache["ks"], kv_cache["vs"], li0), xs)
-            cache = {"k": pk, "v": pv, "ks": ks, "vs": vs}
-        else:
-            (h, pk, pv, _), touched = jax.lax.scan(
-                layer_k, (h, kv_cache["k"], kv_cache["v"], li0), xs)
-            cache = {"k": pk, "v": pv}
-        out = h if return_hidden else unembed(params, cfg, h)
-        if stats:
-            return out, cache, {"experts_touched": jnp.mean(touched)}
-        return out, cache
-
-    def layer(h: jax.Array, xs):
-        if quant:
-            lp, kc, vc, ksc, vsc = xs
-        else:
-            lp, kc, vc = xs
-            ksc = vsc = None
-        lp = {**lp, **held}
-        aux = {} if stats else None
-
-        def attend(q, k, v):
-            kg = _gathered_window(kc, ksc, block_table, B, P, page, cfg,
-                                  h.dtype)
-            vg = _gathered_window(vc, vsc, block_table, B, P, page, cfg,
-                                  h.dtype)
-            # Current token joins the window in-register (its pool
-            # write happens in the post-scan scatter).
-            kg = kg.at[rows, pos_in_win].set(k[:, 0].astype(kg.dtype))
-            vg = vg.at[rows, pos_in_win].set(v[:, 0].astype(vg.dtype))
-            return gqa_attention(q, kg, vg, positions, kv_valid_len,
-                                 window=lp.get("window")), \
-                (k[:, 0], v[:, 0])
-
-        h, new_kv = decoder_layer(h, lp, cfg, positions, inv_freq,
-                                  kv_valid_len, attend=attend,
-                                  row_mask=active, aux=aux)
-        return h, (new_kv, _touched(aux))
-
-    xs = (xs, kv_cache["k"], kv_cache["v"])
-    if quant:
-        xs = xs + (kv_cache["ks"], kv_cache["vs"])
-    h, ((new_k, new_v), touched) = jax.lax.scan(layer, h, xs)
-    # new_k/new_v: (L, B, KV, hd) -> one scatter into the (donated) pool.
-    # Flattening (N, KV, page) into one dim keeps the scatter single-axis
-    # and layout-neutral.
-    L_, N_, KV_, page_, hd_ = kv_cache["k"].shape
-    flat_idx = ((write_page[:, None] * KV_ + jnp.arange(KV_)[None, :])
-                * page_ + write_offset[:, None])               # (B, KV)
-
-    def write(pool, new):
-        flat = pool.reshape(L_, N_ * KV_ * page_, hd_)
-        flat = flat.at[:, flat_idx].set(new.astype(pool.dtype))
-        return flat.reshape(L_, N_, KV_, page_, hd_)
-
-    with jax.named_scope("attn"):      # the KV write
-        if quant:
-            from ..ops.kv_quant import quantize_rows
-
-            def write_scale(pool, new_s):
-                flat = pool.reshape(L_, N_ * KV_ * page_)
-                flat = flat.at[:, flat_idx].set(new_s.astype(pool.dtype))
-                return flat.reshape(L_, N_, KV_, page_)
-
-            kq, ksn = quantize_rows(new_k)
-            vq, vsn = quantize_rows(new_v)
-            cache = {"k": write(kv_cache["k"], kq),
-                     "v": write(kv_cache["v"], vq),
-                     "ks": write_scale(kv_cache["ks"], ksn),
-                     "vs": write_scale(kv_cache["vs"], vsn)}
-        else:
-            cache = {"k": write(kv_cache["k"], new_k),
-                     "v": write(kv_cache["v"], new_v)}
-    out = h if return_hidden else unembed(params, cfg, h)
-    if stats:
-        return out, cache, {"experts_touched": jnp.mean(touched)}
-    return out, cache
-
-
-def _touched(aux: Optional[dict]):
-    """A layer's ``experts_touched`` as a scan output (None: not asked)."""
-    if aux is None:
-        return None
-    return aux.get("experts_touched", jnp.float32(0.0))
+    h, cache, touched = _run_stack(
+        params["layers"], cfg, h, positions, inv_freq, kv_valid_len, attend,
+        state=kv_cache, row_mask=active, stats=stats)
+    return _step_result(params, cfg, h, cache, touched, return_hidden,
+                        stats)
 
 
 def apply_verify_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
                        positions: jax.Array, kv_cache: KVCache,
                        block_table: jax.Array, kv_valid_len: jax.Array,
                        write_pages: jax.Array, write_offsets: jax.Array,
-                       return_hidden: bool = False,
+                       return_hidden: bool = False, *,
+                       active: Optional[jax.Array] = None,
+                       stats: bool = False,
                        ) -> tuple[jax.Array, KVCache]:
     """Multi-token decode step over the paged KV pool: the speculative-
-    decoding VERIFICATION forward (engine/spec_decode.py).
+    decoding VERIFICATION forward (engine/spec_decode.py), and at S = 1
+    the decode step wherever the Pallas kernel does not run.
 
     Scores ``S`` consecutive positions per slot in ONE forward — the
     last accepted token plus up to S-1 draft tokens — so the engine can
@@ -535,6 +521,7 @@ def apply_verify_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     causal mask inside :func:`gqa_attention` restricts each query to
     keys at positions <= its own, so draft token j attends the pool
     prefix plus drafts 0..j-1 exactly as a sequential decode would.
+    ``active`` / ``stats``: as in ``apply_decode_paged``.
 
     Rollback discipline: rejected drafts need NO explicit undo.  Their
     K/V rows land at positions past the last accepted token; the engine
@@ -543,86 +530,34 @@ def apply_verify_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     — pages never advance past the last accepted token and prefix-cache
     block hashes (pure prompt blocks) stay consistent.
 
-    This is the jnp gather path only — the mirror of
-    ``apply_decode_paged``'s fallback branch generalized to S tokens.
     The Pallas decode kernel stays single-token (its per-slot DMA loop
-    is shaped around one query row); verify rounds take this path on
-    every backend, trading a gathered window per layer for the K+1
-    scoring positions.  Same memory discipline: the layer scan only
-    READS the pool, each layer's new K/V rides the scan outputs, and
-    the pool is updated with one post-scan scatter.
+    is shaped around one query row); verify rounds take this gather
+    path on every backend, trading a gathered window per layer for the
+    K+1 scoring positions. Pool held, rows out (``_run_stack``).
     """
-    B, S = tokens.shape
-    P = block_table.shape[1]
-    page = kv_cache["k"].shape[3]  # (L, N, KV, page, hd)
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
                                 cfg.rope_scaling_factor)
-    h = _embed(params, tokens)
-    rows = jnp.arange(B)
-    quant = kv_cache_quantized(kv_cache)
+    rows = jnp.arange(tokens.shape[0])[:, None]
 
-    def layer(h: jax.Array, xs):
-        if quant:
-            lp, kc, vc, ksc, vsc = xs
-        else:
-            lp, kc, vc = xs
-            ksc = vsc = None
-        lp = {**lp, **held}
-
-        def attend(q, k, v):
-            kg = _gathered_window(kc, ksc, block_table, B, P, page, cfg,
-                                  h.dtype)
-            vg = _gathered_window(vc, vsc, block_table, B, P, page, cfg,
-                                  h.dtype)
+    def attend(q, k, v, lp, li, _):
+        def window(name, new):
+            g = _gathered_window(kv_cache, name, li, block_table, cfg,
+                                 q.dtype)
             # All S current tokens join the window in-register at their
             # logical positions (their pool writes happen in the
             # post-scan scatter); positions past the window drop on
             # scatter — they can only belong to masked garbage rows.
-            kg = kg.at[rows[:, None], positions].set(k.astype(kg.dtype))
-            vg = vg.at[rows[:, None], positions].set(v.astype(vg.dtype))
-            return gqa_attention(q, kg, vg, positions, kv_valid_len,
-                                 window=lp.get("window")), \
-                (k, v)
+            return g.at[rows, positions].set(new.astype(g.dtype))
 
-        return decoder_layer(h, lp, cfg, positions, inv_freq, kv_valid_len,
-                             attend=attend)
+        return gqa_attention(q, window("k", k), window("v", v), positions,
+                             kv_valid_len, window=lp.get("window")), (k, v)
 
-    layers, held = scan_layers(params, cfg)
-    xs = (layers, kv_cache["k"], kv_cache["v"])
-    if quant:
-        xs = xs + (kv_cache["ks"], kv_cache["vs"])
-    h, (new_k, new_v) = jax.lax.scan(layer, h, xs)
-    # new_k/new_v: (L, B, S, KV, hd) -> one scatter into the pool, one
-    # flat row index per (slot, token, kv-head).
-    L_, N_, KV_, page_, hd_ = kv_cache["k"].shape
-    flat_idx = ((write_pages[:, :, None] * KV_
-                 + jnp.arange(KV_)[None, None, :])
-                * page_ + write_offsets[:, :, None])       # (B, S, KV)
-
-    def write(pool, new):
-        flat = pool.reshape(L_, N_ * KV_ * page_, hd_)
-        flat = flat.at[:, flat_idx].set(new.astype(pool.dtype))
-        return flat.reshape(L_, N_, KV_, page_, hd_)
-
-    with jax.named_scope("attn"):      # the KV write
-        if quant:
-            from ..ops.kv_quant import quantize_rows
-
-            def write_scale(pool, new_s):
-                flat = pool.reshape(L_, N_ * KV_ * page_)
-                flat = flat.at[:, flat_idx].set(new_s.astype(pool.dtype))
-                return flat.reshape(L_, N_, KV_, page_)
-
-            kq, ksn = quantize_rows(new_k)
-            vq, vsn = quantize_rows(new_v)
-            cache = {"k": write(kv_cache["k"], kq),
-                     "v": write(kv_cache["v"], vq),
-                     "ks": write_scale(kv_cache["ks"], ksn),
-                     "vs": write_scale(kv_cache["vs"], vsn)}
-        else:
-            cache = {"k": write(kv_cache["k"], new_k),
-                     "v": write(kv_cache["v"], new_v)}
-    return (h if return_hidden else unembed(params, cfg, h)), cache
+    h, (new_k, new_v), touched = _run_stack(
+        params["layers"], cfg, _embed(params, tokens), positions, inv_freq,
+        kv_valid_len, attend, row_mask=active, stats=stats)
+    cache = _write_pool(kv_cache, new_k, new_v, write_pages, write_offsets)
+    return _step_result(params, cfg, h, cache, touched, return_hidden,
+                        stats)
 
 
 def _paged_prefix_attention(q, k_self, v_self, kc, vc, ksc, vsc,
@@ -808,82 +743,39 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     returns full (1, C, V) logits instead (a large transient at big
     vocab x chunk; only for callers that truly need every position).
 
-    Memory discipline: the layer scan only READS the pool, whole and in
-    place — a layer's prefix blocks are gathered out of it by (layer,
-    page); per-layer chunk KV is collected as stacked scan outputs and
-    scattered into the pages once, after the scan — the chunk rides its
-    own attention in-register.
+    The pool is held, the rows come out (``_run_stack``): a layer's
+    prefix blocks are gathered out of the whole pool by (layer, page),
+    and the chunk rides its own attention in-register.
     """
     B, C = tokens.shape
     if B != 1:
         raise ValueError("apply_prefill_paged is single-request (B=1)")
-    P = block_table.shape[1]
     page = kv_cache["k"].shape[3]  # (L, N, KV, page, hd)
     if C % page:
         raise ValueError(f"chunk {C} not a page ({page}) multiple")
-    nb = C // page
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
                                 cfg.rope_scaling_factor)
     h = _embed(params, tokens)
     start = positions[0, 0]  # absolute position of the chunk's first row
 
-    quant = kv_cache_quantized(kv_cache)
+    def attend(q, k, v, lp, li, _):
+        # prefix streamed from the pool block-by-block (online softmax)
+        # + the chunk's own K/V in-register; the pool write happens in
+        # the one post-scan scatter. Never materializes the full
+        # gathered window — prefix length does not bound this path's
+        # memory.
+        attn = _paged_prefix_attention(
+            q, k, v, kv_cache["k"], kv_cache["v"], kv_cache.get("ks"),
+            kv_cache.get("vs"), block_table, start, kv_valid_len, page,
+            cfg, window=lp.get("window"), layer=li)
+        return attn, (k[0], v[0])
 
-    def layer(carry, lp):
-        h, li = carry
-        lp = {**lp, **held}
-
-        def attend(q, k, v):
-            # prefix streamed from the pool block-by-block (online
-            # softmax) + the chunk's own K/V in-register; the pool write
-            # happens in the one post-scan scatter. Never materializes
-            # the full gathered window — prefix length does not bound
-            # this path's memory.
-            attn = _paged_prefix_attention(
-                q, k, v, kv_cache["k"], kv_cache["v"], kv_cache.get("ks"),
-                kv_cache.get("vs"), block_table, start, kv_valid_len, page,
-                cfg, window=lp.get("window"), layer=li)
-            return attn, (k[0], v[0])
-
-        h, new_kv = decoder_layer(h, lp, cfg, positions, inv_freq,
-                                  kv_valid_len, attend=attend)
-        return (h, li + 1), new_kv
-
-    # The pool stays OUT of the scan's sliced inputs (as ``xs``, each
-    # iteration's slice of it is a copy of the layer's whole K and V
-    # slab): the body closes over it whole and counts its layer in the
-    # carry, as the decode kernel path does.
-    layers, held = scan_layers(params, cfg)
-    (h, _), (new_k, new_v) = jax.lax.scan(
-        layer, (h, jnp.zeros((), jnp.int32)), layers)
-    # new_k/new_v: (L, C, KV, hd) -> (L, nb, KV, page, hd) page blocks,
-    # scattered at the chunk's physical pages in one shot.
-    L_ = new_k.shape[0]
-    dest = jax.lax.dynamic_slice(block_table[0], (start_page_idx,), (nb,))
-
-    def write(pool, new):
-        blocks = new.reshape(L_, nb, page, cfg.num_kv_heads,
-                             cfg.head_dim).swapaxes(2, 3)
-        return pool.at[:, dest].set(blocks.astype(pool.dtype))
-
-    with jax.named_scope("attn"):      # the KV write
-        if quant:
-            from ..ops.kv_quant import quantize_rows
-            kq, ksn = quantize_rows(new_k)           # scales: (L, C, KV)
-            vq, vsn = quantize_rows(new_v)
-
-            def write_scale(pool, new_s):
-                blocks = new_s.reshape(L_, nb, page,
-                                       cfg.num_kv_heads).swapaxes(2, 3)
-                return pool.at[:, dest].set(blocks.astype(pool.dtype))
-
-            cache = {"k": write(kv_cache["k"], kq),
-                     "v": write(kv_cache["v"], vq),
-                     "ks": write_scale(kv_cache["ks"], ksn),
-                     "vs": write_scale(kv_cache["vs"], vsn)}
-        else:
-            cache = {"k": write(kv_cache["k"], new_k),
-                     "v": write(kv_cache["v"], new_v)}
+    h, (new_k, new_v), _ = _run_stack(params["layers"], cfg, h, positions,
+                                      inv_freq, kv_valid_len, attend)
+    # new_k/new_v: (L, C, KV, hd), to the chunk's physical pages
+    dest = jax.lax.dynamic_slice(block_table[0], (start_page_idx,),
+                                 (C // page,))
+    cache = _write_pool(kv_cache, new_k, new_v, dest)
     if not with_logits:
         return h, cache
     return unembed(params, cfg, h), cache
@@ -970,19 +862,14 @@ def _moe_mlp(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
 def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
                   positions: jax.Array, inv_freq: jax.Array,
                   kv_valid_len: Optional[jax.Array],
-                  cache_kv: Optional[tuple[jax.Array, jax.Array]] = None,
-                  row_start: Optional[jax.Array] = None,
                   attend=None, row_mask: Optional[jax.Array] = None,
                   aux: Optional[dict] = None):
-    """One transformer block. The single source of layer math shared by the
-    full forward (``apply``), the paged decode (``apply_decode_paged``
-    supplies a paged ``attend``), and the pipeline-parallel stage loop
-    (``parallel/pipeline.py``).
+    """One transformer block. The single source of layer math: every
+    forward reaches it through ``_run_stack``.
 
-    cache_kv: optional (kc, vc) of shape (B, T, KV, hd); new K/V written at
-    ``row_start + offset`` per row. ``attend(q, k, v) -> (attn, new_cache)``
-    overrides the whole KV-write + attention step (used by the paged
-    decode). Returns (h, new_cache_or_None).
+    ``attend(q, k, v) -> (attn, out)`` is the whole KV step (cache read,
+    write and attention; ``out`` is whatever the forward carries or
+    collects); None attends the tokens given. Returns (h, out or None).
 
     Per-layer kinds ride in ``lp`` (``layer_kinds``): ``window`` masks the
     built-in attention (an ``attend`` closure reads it itself) and
@@ -1027,18 +914,6 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
     with jax.named_scope("attn"):
         if attend is not None:
             attn, new_cache = attend(q, k, v)
-        elif cache_kv is not None:
-            kc, vc = cache_kv
-            # Write this chunk at its absolute positions (rows contiguous).
-            kc = jax.vmap(
-                lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s, 0, 0))
-            )(kc, k, row_start)
-            vc = jax.vmap(
-                lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s, 0, 0))
-            )(vc, v, row_start)
-            attn = gqa_attention(q, kc, vc, positions, kv_valid_len,
-                                 window=window)
-            new_cache = (kc, vc)
         else:
             attn = gqa_attention(q, k, v, positions, kv_valid_len,
                                  window=window)
@@ -1066,20 +941,7 @@ def run_layers(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
     no KV cache — the per-stage body for pipeline parallelism."""
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
                                 cfg.rope_scaling_factor)
-
-    if jax.tree.leaves(layers)[0].shape[0] == cfg.num_layers:
-        xs, held = scan_layers({"layers": layers}, cfg)
-    else:                       # a pipeline stage's share of the depth
-        _refuse_kinds(cfg, "run_layers over a partial layer stack")
-        xs, held = layers, {}
-
-    def body(h, lp):
-        h, _ = decoder_layer(h, {**lp, **held}, cfg, positions, inv_freq,
-                             kv_valid_len)
-        return h, None
-
-    h, _ = jax.lax.scan(body, h, xs)
-    return h
+    return _run_stack(layers, cfg, h, positions, inv_freq, kv_valid_len)[0]
 
 
 def unembed_norm(params: Params, cfg: LlamaConfig, h: jax.Array
@@ -1195,31 +1057,27 @@ def apply(params: Params, cfg: LlamaConfig, tokens: jax.Array,
                  causal masking only.
     Returns (logits (B,S,V) or hidden (B,S,D), updated cache or None).
     """
-    h = _embed(params, tokens)
-    row_start = positions[:, 0]
-    if kv_cache is not None and kv_valid_len is None:
-        kv_valid_len = positions[:, -1] + 1
-
+    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
+                                cfg.rope_scaling_factor)
+    attend = xs = None
     if kv_cache is not None:
-        inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
-                                    cfg.rope_scaling_factor)
+        if kv_valid_len is None:
+            kv_valid_len = positions[:, -1] + 1
+        row_start = positions[:, 0]
+        # Write this chunk at its absolute positions (rows contiguous).
+        put = jax.vmap(
+            lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s, 0, 0)))
+        xs = {"cache_k": kv_cache["k"], "cache_v": kv_cache["v"]}
 
-        layers, held = scan_layers(params, cfg)
+        def attend(q, k, v, lp, li, _):
+            kc = put(lp["cache_k"], k, row_start)   # (B,T,KV,hd)
+            vc = put(lp["cache_v"], v, row_start)
+            return gqa_attention(q, kc, vc, positions, kv_valid_len,
+                                 window=lp.get("window")), (kc, vc)
 
-        def layer_cached(h, xs):
-            lp, kc, vc = xs  # kc/vc: (B,T,KV,hd)
-            h, new_kv = decoder_layer(h, {**lp, **held}, cfg, positions,
-                                      inv_freq, kv_valid_len, (kc, vc),
-                                      row_start)
-            return h, new_kv
-
-        h, (new_k, new_v) = jax.lax.scan(
-            layer_cached, h, (layers, kv_cache["k"], kv_cache["v"]))
-        new_cache: Optional[KVCache] = {"k": new_k, "v": new_v}
-    else:
-        h = run_layers(params["layers"], cfg, h, positions, kv_valid_len)
-        new_cache = None
-
+    h, new, _ = _run_stack(params["layers"], cfg, _embed(params, tokens),
+                           positions, inv_freq, kv_valid_len, attend, xs=xs)
+    new_cache = None if new is None else dict(zip("kv", new))
     if return_hidden:
         return unembed_norm(params, cfg, h), new_cache
     return unembed(params, cfg, h), new_cache
@@ -1262,16 +1120,12 @@ def apply_sp(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     def fwd(tokens_l, positions_l, params_l):
         h = jnp.take(params_l["embed"], tokens_l, axis=0)
 
-        def attend(q, k, v):
+        def attend(q, k, v, *_):
             return ring_gqa_attention(q, k, v, positions_l,
                                       axis_name="sp", axis_size=n_sp), None
 
-        def body(h, lp):
-            h, _ = decoder_layer(h, lp, cfg, positions_l, inv_freq,
-                                 None, attend=attend)
-            return h, None
-
-        h, _ = jax.lax.scan(body, h, params_l["layers"])
+        h = _run_stack(params_l["layers"], cfg, h, positions_l, inv_freq,
+                       None, attend)[0]
         return unembed(params_l, cfg, h)
 
     seq_spec = P(dp, "sp")
@@ -1352,17 +1206,13 @@ def apply_prefill_sp(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     def fwd(tokens_l, positions_l, length_l, params_l):
         h = jnp.take(params_l["embed"], tokens_l, axis=0)
 
-        def attend(q, k, v):
+        def attend(q, k, v, *_):
             return ring_gqa_attention(q, k, v, positions_l,
                                       axis_name="sp",
                                       axis_size=n_sp), (k, v)
 
-        def body(h, lp):
-            h, kv = decoder_layer(h, lp, cfg, positions_l, inv_freq,
-                                  None, attend=attend)
-            return h, kv
-
-        h, (ks, vs) = jax.lax.scan(body, h, params_l["layers"])
+        h, (ks, vs), _ = _run_stack(params_l["layers"], cfg, h, positions_l,
+                                    inv_freq, None, attend)
         # Last valid position's hidden state: the row lives on exactly
         # one sp shard — mask-select locally, then one psum makes it
         # replicated. (B, D) is tiny; the unembed runs on it outside.

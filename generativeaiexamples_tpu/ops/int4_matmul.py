@@ -34,13 +34,22 @@ scales are folded into the unpacked weight tile and the product is cast
 to the ACTIVATION dtype before the dot, so on real (bf16) configs every
 dequantized weight rounds through bf16 on its way to the MXU. The XLA
 fallback (``quant.matmul``) instead applies group scales in f32 after
-the partial dots, so the kernel carries ~0.2-0.4% RMS relative error
-the fallback does not (measured ~0.23% RMS / ~4e-3 bound on the test
-geometries; with f32 activations the paths agree to ~1e-6 — the error
-IS the bf16 weight rounding, not the kernel math). Bit-closeness to the
-XLA path would need one extra f32 accumulator per group per k-tile;
-the bandwidth win is the point of this kernel, so the rounding stays.
-The bound is pinned by tests/test_int4_matmul.py
+the partial dots. What that costs, by the reasoning and as measured
+(CPU, interpreted, PR 30): a value rounded to bf16 is off by 2^-9
+relative at most, 0.166 % RMS, and a dot of K such weights against any
+activations is off by the same 0.166 % whatever K, N, M or the group
+size — the kernel's output equals an f32 matmul over the bf16-rounded
+dequantized weights to 1e-6, and reads 0.148-0.176 % RMS against the
+f32 matmul over the unrounded ones (eight geometries, two seeds). The
+test compares against the fallback's output, which is itself ROUNDED TO
+bf16 (another, independent 0.165 %), so it reads sqrt 2 times that:
+0.2344 % at K = 256 in two groups of 128, 0.2341 % at K = 1024 in two
+groups of 512, 0.221-0.238 % over all sixteen. The bound is 4e-3, 1.7
+times the largest reading. With f32 activations the paths agree to
+~1e-6 — the error IS the bf16 weight rounding, not the kernel math.
+Bit-closeness to the XLA path would need one extra f32 accumulator per
+group per k-tile; the bandwidth win is the point of this kernel, so the
+rounding stays. Both are pinned by tests/test_int4_matmul.py
 (test_grouped_bf16_rounding_trade_within_documented_bound).
 """
 
@@ -174,6 +183,14 @@ def int4_matmul(x: jax.Array, q4: jax.Array, scale: jax.Array,
         # tiles); the k slice happens in-register at lane-aligned offsets
         xe_k = xe_ref[:, pl.ds(k * bk, bk)]
         xo_k = xo_ref[:, pl.ds(k * bk, bk)]
+        if interpret:
+            # off the TPU the operands, ALREADY rounded to the activation
+            # dtype, multiply as f32: the MXU's bf16 x bf16 products are
+            # exact in f32 too, and the CPU backend has no bf16 x bf16 ->
+            # f32 dot at every geometry (jax 0.9: UNIMPLEMENTED in
+            # DotThunk at K = 256, M = 8)
+            xe_k, xo_k, lo, hi = (a.astype(jnp.float32)
+                                  for a in (xe_k, xo_k, lo, hi))
         part = (
             jax.lax.dot(xe_k, lo, preferred_element_type=jnp.float32)
             + jax.lax.dot(xo_k, hi, preferred_element_type=jnp.float32))

@@ -408,25 +408,62 @@ def test_capacity_routing_refuses_what_only_dropless_has():
         llama._moe_mlp(jnp.zeros((1, 1, 32)), {}, cfg)
 
 
-# The two architectures the benchmark already had: their parameter trees
-# for a seed and the lowered text of their decode step, as the parent of
-# this change had them (sha256 at tiny sizes, int8 weights; computed on
-# the parent commit with the same script). A change that moves one of
-# these moves the two configurations' cells: re-pin only on purpose (a
-# new JAX re-words the text: re-pin BOTH sides from one commit).
+# The three architectures the benchmark has: their parameter trees for a
+# seed and the lowered text of the programs a cell runs or falls back to
+# (sha256 at tiny sizes, int8 weights: ``lowered_text`` below, hashed).
+# ``kernel`` / ``jnp``: the decode step over a bf16 pool, Pallas call or
+# gather path; ``kernel_int8kv``: the kernel step over an int8 pool;
+# ``chunk``: ``apply_prefill_paged``, bf16 pool; ``verify``: the
+# three-token verify forward. A change that moves a ``kernel`` or
+# ``chunk`` pin moves the configurations' cells: re-pin only on purpose
+# (a new JAX re-words the text: re-pin BOTH sides from one commit).
+# All were computed on PR 30's parent. PR 30 (one layer-stack driver,
+# one pool writer) left the two trees, ``kernel`` of mixtral and
+# nemotron and all three ``chunk`` pins as the parent had them, and
+# re-pinned: ``jnp`` and ``verify`` (on purpose: the gather path reads
+# the pool by (layer, page) and no longer takes it as scan inputs);
+# ``kernel_int8kv`` (the carry holds the cache dict, so the loop's
+# operands are k, ks, v, vs where they were k, v, ks, vs: six lines of
+# ~8,500, the same count); ``smallthinker_decode_kernel`` (the window's
+# (1,) view is taken where the kernel is called, not at the head of the
+# body: the same lines but for their numbering, one of them elsewhere).
 PINS = {
     "mixtral_tree":
         "122c47ae888d512af2a4f299e95930ba1ba1700cf8489900e35aef13fdbde819",
     "mixtral_decode_jnp":
-        "8058ad1338768ef49b42313000e643b702f31e2c70864a44123015d797468ebe",
+        "7c618560d2e46b0c39cb885fb57bb9bac97a06c5aaa8b9f974f4184dee279aea",
     "mixtral_decode_kernel":
         "490c8024b37f88077809206032731335a051af3803ea9359babf3067c9e4868a",
+    "mixtral_decode_kernel_int8kv":
+        "37f3a97a6dfc8c94215d92a151abcbe8f9db487c787c38433d60c7ec4ddbb212",
+    "mixtral_decode_chunk":
+        "3eb70b1e4f361b6ba50da74fcf6d536a546e0a9337710defa74a616ffe73b139",
+    "mixtral_decode_verify":
+        "e4e4bff37a0f61755a0b72e9e3590dc45da49641e7c41426dfd524c7d4264a4f",
     "nemotron_tree":
         "9017a962e8f5a4bfaa6cf3ab591cb069937ac3d0de8c3c7cd8fe7985ac4c51b7",
     "nemotron_decode_jnp":
-        "249af3fc66c7c197b38fac8555136a0f11ecb522190baef70933f5e1f950df7e",
+        "dd7b48109ef552fac43c1d2a096571d411b0666258a3b884758dc4b73310a87e",
     "nemotron_decode_kernel":
         "8742589a84520739873f5ade3ffef388b8b90cc4c0c35c116c7267511c97d841",
+    "nemotron_decode_kernel_int8kv":
+        "66b1b7d9e33bc19aa2c2b7e82b3e22aee9491fd025cd8b1b1c38b71e36a9874c",
+    "nemotron_decode_chunk":
+        "0183d7a9d5e042285508ba75eafc858d679ecd3a1fc34d875c81117140354913",
+    "nemotron_decode_verify":
+        "addba2725a059d2f6049cc3b6af2aeceb6d30558f90deb5228a87169b5fd2a5c",
+    "smallthinker_tree":
+        "ed0e86ded7ac3df11ba816fd6860a3a488d3d7c6b77e9745d5dd99c481f21849",
+    "smallthinker_decode_jnp":
+        "12f9e8868087f77d626a59aa60e7f106d9ab8dc73e2485b158d6898e1620436a",
+    "smallthinker_decode_kernel":
+        "c32f8a14269971750f3eb58a56c47427ec6e8206d3b5905c4276aa596bd91030",
+    "smallthinker_decode_kernel_int8kv":
+        "3f24a21e36f8b97de0cd161fc9d5b4b4b33bf780cc14fd7b063fbf97cc1370bd",
+    "smallthinker_decode_chunk":
+        "adffc4e47943af8f524d230fff966cd02e9ff0599f8cd8f6b13ba5a78105c85e",
+    "smallthinker_decode_verify":
+        "7dea63cdadd9ced0ea43598d2156d5e701daae2fb17632c518866d70112b307d",
 }
 TINY = {
     "mixtral": LlamaConfig(
@@ -436,6 +473,7 @@ TINY = {
         num_experts_per_tok=2),
     "nemotron": dataclasses.replace(GPTNEXT_TINY, head_dim=128, num_heads=2,
                                     num_kv_heads=2, hidden_size=256),
+    "smallthinker": CFG,
 }
 
 
@@ -459,19 +497,38 @@ def test_existing_trees_unchanged(old_arch):
     assert h.hexdigest() == PINS[name + "_tree"]
 
 
-@pytest.mark.parametrize("path", ["jnp", "kernel"])
-def test_existing_decode_programs_unchanged(old_arch, path):
-    name, cfg, p = old_arch
-    pool = llama.init_paged_kv_cache(cfg, 5, PAGE, jnp.bfloat16)
+def lowered_text(cfg, p, path):
+    """The lowered text of one program of ``cfg`` over the tree ``p``."""
+    pool = llama.init_paged_kv_cache(cfg, 5, PAGE, jnp.bfloat16,
+                                     quantized=path == "kernel_int8kv")
     B = 2
+    z = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
+    if path == "chunk":
+        def chunk(p, pool, tok, pos, table, start_page):
+            return llama.apply_prefill_paged(p, cfg, tok, pos, pool, table,
+                                             pos[:, -1] + 1, start_page)
+        return jax.jit(chunk).lower(p, pool, z(1, PAGE), z(1, PAGE), z(1, 4),
+                                    z()).as_text()
+    if path == "verify":
+        def verify(p, pool, tok, pos, table, wp, off):
+            return llama.apply_verify_paged(p, cfg, tok, pos, pool, table,
+                                            pos[:, -1] + 1, wp, off)
+        return jax.jit(verify).lower(p, pool, z(B, 3), z(B, 3), z(B, 4),
+                                     z(B, 3), z(B, 3)).as_text()
 
     def step(p, pool, tok, pos, table, wp, off, use_kernel):
         return llama.apply_decode_paged(p, cfg, tok, pos, pool, table,
                                         pos[:, 0] + 1, wp, off,
                                         use_kernel=use_kernel)
-    z = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
-    text = jax.jit(step, static_argnums=(7,)).lower(
+    return jax.jit(step, static_argnums=(7,)).lower(
         p, pool, z(B, 1), z(B, 1), z(B, 4), z(B), z(B),
-        path == "kernel").as_text()
+        path != "jnp").as_text()
+
+
+@pytest.mark.parametrize("path", ["jnp", "kernel", "kernel_int8kv", "chunk",
+                                  "verify"])
+def test_existing_decode_programs_unchanged(old_arch, path):
+    name, cfg, p = old_arch
+    text = lowered_text(cfg, p, path)
     assert hashlib.sha256(text.encode()).hexdigest() \
         == PINS[f"{name}_decode_{path}"]

@@ -2,7 +2,7 @@
 
 Runs the kernel in interpreter mode on the CPU test mesh — numerics are
 exact there, so tolerances are tight. On TPU the same kernel runs compiled
-(gated by models.llama._use_paged_kernel)."""
+(gated by models.llama.use_paged_kernel)."""
 
 import jax
 import jax.numpy as jnp
