@@ -143,12 +143,15 @@ _STATS_TEMPLATE = {
     "deadline_queue_drops": 0,
     "deadline_stops": 0,
     # Token-budget scheduler (engine/scheduler.py): the resolved
-    # per-round budget, cumulative prefill tokens it granted as chunks,
-    # cumulative decode token-equivalents charged against it, and how
-    # many rounds actually mixed a decode dispatch with prefill chunks
-    # (the interleaving the budget exists to enable).
+    # per-round budget, cumulative prefill tokens it granted as chunks
+    # and the tokens of the compiled shapes those chunks ran in (their
+    # ratio is how full the chunk programs are), cumulative decode
+    # token-equivalents charged against it, and how many rounds actually
+    # mixed a decode dispatch with prefill chunks (the interleaving the
+    # budget exists to enable).
     "sched_round_budget_tokens": 0,
     "sched_prefill_tokens": 0,
+    "sched_prefill_padded_tokens": 0,
     "sched_decode_tokens": 0,
     "sched_interleaved_rounds": 0,
     # Fused unembed/sampling tail (ops/fused_sampler.py): slot-rows that
@@ -227,8 +230,9 @@ _STATS_TEMPLATE = {
     "pool_shrinks": 0,
     # KV pool occupancy (gauge, set when a round begins): pages live
     # requests hold = total - free - evictable prefix-cache pages; and
-    # dispatched rounds in which an admission the plan offered was
-    # refused for want of pages (RoundRecord.blocked_on_pages > 0).
+    # dispatched rounds in which a waiting request was kept out for want
+    # of pages (RoundRecord.blocked_on_pages > 0): refused at admission,
+    # or not offered since the pool last refused it.
     "pool_used_pages": 0,
     "pool_blocked_rounds": 0,
     # Window layers (LlamaConfig.sliding_window): pages of the live
@@ -261,7 +265,7 @@ def engine_stat_keys() -> tuple[str, ...]:
     from .prefix_cache import CacheStats
     return (tuple(_STATS_TEMPLATE)
             + ("dispatch_queue_depth", "queue_waiting",
-               "sched_prefill_share",
+               "sched_prefill_share", "sched_prefill_fill",
                "spec_acceptance_rate", "spec_tokens_per_step",
                "sched_cost_drift_ratio",
                "kv_tier_host_pages", "kv_restore_hit_rate", "uptime_s")
@@ -594,6 +598,10 @@ class _Request:
     prefill_done: bool = False
     pf_pos: int = 0
     pf: Optional[dict] = None
+    # Pages the pool could give (free + evictable) when it last refused
+    # this request's admission; None = never refused. Until the pool
+    # can give more, _plan_round does not offer the request again.
+    refused_avail: Optional[int] = None
     # Speculative decoding (spec on only): the request's prompt-lookup
     # drafter (host token index over prompt + generated), its adaptive
     # draft-length controller, and the prompt's device length (the rag
@@ -828,7 +836,7 @@ class Engine:
                                  else cfg.sched_round_budget_tokens),
             chunk_tokens=(int(env_chunk) if env_chunk
                           else cfg.sched_prefill_chunk_tokens),
-            max_one_shot_tokens=self._buckets[-1],
+            chunk_shapes=self._buckets,
             calibrator=self._calib)
         # Round telemetry (obs/rounds.py): per-round plan+execution
         # records behind GET /debug/rounds, the engine_round_* metric
@@ -956,6 +964,10 @@ class Engine:
         # Draft plan staged between _plan_round and _execute_plan
         # (serve-loop thread only): {slot: [draft token ids]}.
         self._draft_plan: Optional[dict] = None
+        # 1 when the last _plan_round held backlog requests back because
+        # the pool that refused one has not grown since (staged the same
+        # way, for the round record's blocked_on_pages).
+        self._held_on_pool = 0
         # Active-row ladder for the fused tail: decode rounds gather the
         # armed slots into the smallest rung >= the live count, so the
         # unembed/sampling tail is sized to OCCUPANCY, not max_slots.
@@ -1408,6 +1420,12 @@ class Engine:
         out["sched_prefill_share"] = (
             round(out["sched_prefill_tokens"] / sched_total, 4)
             if sched_total else 0.0)
+        # How full the chunk programs ran: tokens computed over the
+        # tokens of the shapes they were padded to.
+        out["sched_prefill_fill"] = (
+            round(out["sched_prefill_tokens"]
+                  / out["sched_prefill_padded_tokens"], 4)
+            if out["sched_prefill_padded_tokens"] else 0.0)
         # Speculative decoding: acceptance rate over all drafted tokens,
         # and tokens emitted per verify slot-step (>1 = the speculative
         # multiplier is real; 0.0 until the first verify round runs).
@@ -3693,6 +3711,8 @@ class Engine:
                        deadline_t=r.deadline_t, seq=r.seq, started=True)
             for r in self._slots.values() if not r.prefill_done]
         backlog_jobs = []
+        self._held_on_pool = 0
+        now = time.monotonic()
         if self._free_slots:
             for req, _sp in self._backlog:
                 # Pre-admission estimate: the full prompt (a prefix-cache
@@ -3705,10 +3725,26 @@ class Engine:
                 backlog_jobs.append(PrefillJob(
                     key=req, remaining=remaining,
                     deadline_t=req.deadline_t, seq=req.seq))
+            # Pool backpressure is global: an admission the pool refused
+            # stops admitting for the round (_execute_plan_inner), so a
+            # request it refused, and whatever the planner would admit
+            # after it, is not offered again until the pool can give
+            # more pages than it could then. A grant for a request that
+            # cannot start is a whole chunk program taken from the
+            # prefills in flight.
+            if any(j.key.refused_avail is not None for j in backlog_jobs):
+                avail = self._pool_avail_pages()
+                backlog_jobs = self._sched.order(backlog_jobs, now)
+                for n, job in enumerate(backlog_jobs):
+                    if job.key.refused_avail is not None \
+                            and avail <= job.key.refused_avail:
+                        self._held_on_pool = 1
+                        del backlog_jobs[n:]
+                        break
         return self._sched.plan_round(
             decode_steps=steps, active_decodes=len(armed),
             inflight=inflight, backlog=backlog_jobs,
-            now=time.monotonic(), max_new=len(self._free_slots),
+            now=now, max_new=len(self._free_slots),
             decode_cost_tokens=verify_cost)
 
     def _any_draftable(self, armed) -> bool:
@@ -3792,6 +3828,9 @@ class Engine:
                   else "decode" if plan.decode_steps else "prefill"),
             plan_ms=plan_s * 1e3, pool_used_pages=used,
             on_complete=self._on_round_complete)
+        # Requests the planner was not offered because the pool that
+        # refused them has not grown since wait on pages this round too.
+        rec.blocked_on_pages = self._held_on_pool
         try:
             with phase("engine_round", round_id=rec.round_id,
                        kind=rec.kind, t_mono_ns=time.monotonic_ns()):
@@ -3805,15 +3844,20 @@ class Engine:
                 self.rounds.discard(rec)
             raise
 
-    def _pool_used_pages(self) -> int:
-        """KV pool pages live requests hold right now: total - free -
+    def _pool_avail_pages(self) -> int:
+        """KV pool pages an admission could get right now: free plus
         evictable prefix-cache pages (refcount 0: warm, but reclaimable
         the moment an admission needs them). O(1), scheduler thread."""
-        used = self._n_pages - 1 - len(self._free_pages)
+        avail = len(self._free_pages)
         cache = self._prefix_cache
         if cache is not None:
-            used -= cache.cached_pages - cache.pinned_pages
-        return used
+            avail += cache.cached_pages - cache.pinned_pages
+        return avail
+
+    def _pool_used_pages(self) -> int:
+        """KV pool pages live requests hold right now: total less what
+        an admission could get."""
+        return self._n_pages - 1 - self._pool_avail_pages()
 
     def _execute_plan_inner(self, plan, rec) -> bool:
         did = False
@@ -3828,7 +3872,7 @@ class Engine:
             if decoded:
                 did = True
                 self._bump("sched_decode_tokens", plan.decode_cost_tokens)
-        prefilled = 0
+        prefilled = padded = 0
         grants: list[tuple[str, int]] = []
         marker = None
         if plan.chunks:
@@ -3855,6 +3899,7 @@ class Engine:
                     if n:
                         did = True
                         prefilled += n
+                        padded += self._bucket_for(n)
                         grants.append((req.stream.request_id, n))
                         if m is not None:
                             marker = m
@@ -3865,6 +3910,7 @@ class Engine:
                 ph.record = bool(prefilled)
         if prefilled:
             self._bump("sched_prefill_tokens", prefilled)
+            self._bump("sched_prefill_padded_tokens", padded)
             if decoded:
                 self._bump("sched_interleaved_rounds")
         parts = int(decoded)
@@ -3891,7 +3937,7 @@ class Engine:
                 self._bump("pool_blocked_rounds")
             self.rounds.seal(
                 rec, parts=parts, prefill_tokens=prefilled,
-                grants=grants,
+                prefill_padded_tokens=padded, grants=grants,
                 modeled_ms=self._modeled_round_ms(
                     rec, plan.decode_steps if decoded else 0,
                     prefilled))
@@ -4034,6 +4080,7 @@ class Engine:
             if need_new > len(self._free_pages):
                 if k_use:
                     self._prefix_cache.release(hashes[:k_use])
+                req.refused_avail = self._pool_avail_pages()
                 return False  # pool backpressure: wait for pages
         self._backlog = [e for e in self._backlog if e[0] is not req]
         slot = self._free_slots.pop()

@@ -8,7 +8,9 @@ rounds: plan each engine round as a MIX of decode steps for armed slots
 plus prefill *chunks* for admitted requests, sized so the whole round
 stays under a per-round token budget derived from a measured step-cost
 model — decode keeps flowing at its usual cadence while long prefills
-make page-quantized progress in the gaps.
+progress in the gaps, a whole chunk-program shape at a time (a grant is
+priced and sized in the shapes the engine's chunk programs are compiled
+in, so no program runs mostly padding).
 
 Division of labor:
 
@@ -37,6 +39,7 @@ deployment (docs/configuration.md).
 
 from __future__ import annotations
 
+import bisect
 import glob
 import json
 import logging
@@ -483,6 +486,7 @@ class TokenBudgetScheduler:
                  round_budget_tokens: Optional[int] = None,
                  chunk_tokens: Optional[int] = None,
                  max_one_shot_tokens: Optional[int] = None,
+                 chunk_shapes: Optional[Sequence[int]] = None,
                  calibrator: Optional[OnlineCalibrator] = None):
         self._static_cost = cost
         # Online calibration (``OnlineCalibrator``): when installed, the
@@ -502,27 +506,82 @@ class TokenBudgetScheduler:
         else:
             budget = derive_round_budget(cost, steps_per_round, page_size)
         self.round_budget_tokens = budget
+        # The shapes the engine's chunk programs are compiled in (its
+        # prefill-bucket ladder, the sizes a dispatched chunk is padded
+        # to). None — a scheduler built without an engine — means every
+        # page multiple is a shape.
+        self._ladder = (tuple(sorted({int(s) for s in chunk_shapes}))
+                        if chunk_shapes else None)
+        # Above this, a request is never one-shot even on an idle engine:
+        # the largest compiled shape, which is also the largest single
+        # DISPATCH the engine can execute — a grant beyond it would
+        # deduct budget for tokens _advance_prefill clamps away.
+        if self._ladder is not None:
+            max_one_shot_tokens = self._ladder[-1]
+        self.max_one_shot_tokens = max_one_shot_tokens
         # Per-chunk cap: a single request's grant within one round.
         # Defaults to the whole budget (the budget is already the round
         # latency bound); the knob exists to force finer interleaving.
-        self.chunk_tokens = max(page_size, int(chunk_tokens)) \
-            if chunk_tokens else budget
-        # Above this, a request is never one-shot even on an idle engine
-        # (the engine passes its largest prefill bucket).
-        self.max_one_shot_tokens = max_one_shot_tokens
-        if max_one_shot_tokens is not None:
-            # The bucket is also the largest single DISPATCH the engine
-            # can execute: a grant beyond it would deduct budget for
-            # tokens _advance_prefill clamps away — planned work that
-            # evaporates instead of going to other waiting prefills.
-            self.chunk_tokens = min(self.chunk_tokens,
-                                    max(page_size, max_one_shot_tokens))
+        self._set_chunk_cap(int(chunk_tokens) if chunk_tokens else budget)
         # Fair-rotation cursor: when the leftover is too small for every
-        # job to get a page (the 1-page default budget is the common
-        # case), WHO gets this round's page rotates across rounds so a
-        # waiting job's admission is bounded by ~len(jobs) rounds.
+        # job to get a shape (one bucket a round is the common case),
+        # WHO gets this round's shape rotates across rounds so a waiting
+        # job's next grant is bounded by ~len(jobs) rounds.
         self._rr = 0
         self._calib_version = -1   # last calibrator version recalibrated at
+
+    def _set_chunk_cap(self, cap: int) -> None:
+        """Fix ``chunk_tokens`` (one grant's cap, never past the largest
+        dispatchable shape) and the grant shapes under it, ascending.
+        With a ladder whose smallest shape is over the cap, that shape is
+        the one grant size: the device runs (and the budget is charged)
+        no smaller program, so a finer grant would only be padding."""
+        page = self.page_size
+        cap = max(page, cap)
+        if self.max_one_shot_tokens is not None:
+            cap = min(cap, max(page, self.max_one_shot_tokens))
+        self.chunk_tokens = cap
+        if self._ladder is None:
+            self._shapes = tuple(range(page, cap + 1, page))
+        else:
+            self._shapes = (tuple(s for s in self._ladder if s <= cap)
+                            or self._ladder[:1])
+        # A job that fits ONE program (a short prompt, or a long
+        # prompt's tail) may still be cut at the page multiples under
+        # the smallest shape, as before whole-shape grants: see
+        # shapes_of(). Nothing under a ladder that starts at one page.
+        self._fine_shapes = tuple(
+            range(page, self._shapes[0], page)) + self._shapes
+
+    def shapes_of(self, remaining: int) -> tuple:
+        """The sizes a job with ``remaining`` tokens left can be granted
+        (its final grant apart). A job that needs more than one largest
+        program is granted whole programs: the ladder's shapes. A job
+        that fits one keeps the page multiples under the smallest
+        shape too — NOT because they are good grants (such a grant
+        runs padded to the smallest shape): the benchmark's warm-up
+        (benchmarks/harness/system.py ``missing``) reaches the chunk
+        programs of small windows only through page-sized shared
+        grants of short prompts, and retries for seconds when it
+        cannot (docs/scheduler.md, PERF.md section 7)."""
+        return (self._shapes if remaining > self._shapes[-1]
+                else self._fine_shapes)
+
+    @staticmethod
+    def _within(shapes: tuple, room: int) -> int:
+        """Largest of ``shapes`` that ``room`` tokens of budget pay for
+        (0 when even the smallest does not fit)."""
+        i = bisect.bisect_right(shapes, room)
+        return shapes[i - 1] if i else 0
+
+    @staticmethod
+    def _charge(shapes: tuple, tokens: int) -> int:
+        """What a grant of ``tokens`` takes off the budget: the smallest
+        of ``shapes`` that covers it (0 for no grant)."""
+        if tokens <= 0:
+            return 0
+        i = bisect.bisect_left(shapes, tokens)
+        return shapes[min(i, len(shapes) - 1)]
 
     @property
     def cost(self) -> StepCostModel:
@@ -551,13 +610,8 @@ class TokenBudgetScheduler:
             return False
         self.round_budget_tokens = budget
         if not self._chunk_pinned:
-            # The chunk cap follows the budget (its documented default),
-            # still clamped to the largest dispatchable bucket.
-            cap = budget
-            if self.max_one_shot_tokens is not None:
-                cap = min(cap, max(self.page_size,
-                                   self.max_one_shot_tokens))
-            self.chunk_tokens = cap
+            # The chunk cap follows the budget (its documented default).
+            self._set_chunk_cap(budget)
         return True
 
     # ------------------------------------------------------------ slack
@@ -605,18 +659,25 @@ class TokenBudgetScheduler:
         differently — a speculative verify round scores S positions per
         slot in one step (StepCostModel.verify_cost_tokens).
 
-        Grants are whole pages except a job's FINAL grant (the engine's
-        final-chunk program takes any tail length). Two liveness
-        guarantees: if prefill work exists, at least one page is granted
-        even when decode consumed the whole budget (a saturated decode
-        fleet must not starve admission forever), and on an IDLE engine
-        (nothing decoding, nothing else waiting) a lone job up to 2x the
-        round budget (and never past ``max_one_shot_tokens``, the
-        largest compiled bucket) is granted whole — chunking a typical
-        prompt would tax its TTFT with extra dispatches while protecting
-        nobody, but an UNBOUNDED one-shot is un-preemptible once
-        dispatched and would re-open the prefill wall for a request
-        arriving moments later.
+        Grants come in the chunk programs' own SHAPES (the engine's
+        bucket ladder; every page multiple when the scheduler was built
+        without one): a job's FINAL grant is whatever remains (the
+        final-chunk program takes any tail), every other grant is
+        exactly a shape, and the budget is charged the shape a grant
+        runs in — what the device pays — not the tokens it computes
+        (:meth:`shapes_of` has the one exception, for a job that fits
+        one program).
+        Two liveness guarantees: if prefill work exists, at least one
+        smallest shape is granted even when decode consumed the whole
+        budget (a saturated decode fleet must not starve admission
+        forever, and a decoding batch never cuts a lone prefill under a
+        whole program), and on an IDLE engine (nothing decoding, nothing
+        else waiting) a lone job up to 2x the round budget (and never
+        past ``max_one_shot_tokens``, the largest compiled bucket) is
+        granted whole — chunking a typical prompt would tax its TTFT
+        with extra dispatches while protecting nobody, but an UNBOUNDED
+        one-shot is un-preemptible once dispatched and would re-open
+        the prefill wall for a request arriving moments later.
         """
         plan = RoundPlan(decode_steps=decode_steps,
                          active_decodes=active_decodes,
@@ -628,10 +689,11 @@ class TokenBudgetScheduler:
         jobs = list(inflight) + admitted
         if not jobs:
             return plan
-        page = self.page_size
-        leftover = self.round_budget_tokens - plan.decode_cost_tokens
-        # Liveness floor: decode saturation may never starve prefill.
-        leftover = max(leftover, page)
+        floor = self._shapes[0]
+        # Liveness floor: decode saturation may never starve prefill,
+        # nor shave the leftover under one whole program.
+        leftover = max(self.round_budget_tokens - plan.decode_cost_tokens,
+                       floor)
         # Idle engine, one waiter: whole-prompt grant (see docstring) —
         # but only up to 2x the round budget (and never past the largest
         # compiled bucket). A dispatched grant is un-preemptible, so an
@@ -648,53 +710,44 @@ class TokenBudgetScheduler:
                 and jobs[0].remaining <= one_shot_cap):
             plan.chunks.append((jobs[0].key, jobs[0].remaining))
             return plan
-        # Two-phase packing. Phase 1 hands every job a FAIR SHARE of the
-        # leftover (page-quantized, one page minimum): a short prompt
-        # behind a long in-flight prefill admits THIS round instead of
-        # waiting out the whole long prefill — strict priority order
-        # would starve it, which is the head-of-line blocking this
-        # scheduler exists to kill. Phase 2 re-grants whatever the
-        # fair pass left unused (jobs smaller than their share) to the
-        # highest-priority jobs so no budget is wasted.
-        share = max(page, (leftover // len(jobs)) // page * page)
-        # Scarcity rotation: when the leftover can't give every job a
-        # page (e.g. the 1-page default budget), a fixed packing order
-        # would hand the SAME job the page every round — strict
-        # head-of-line blocking in fair-share clothing. Rotating who
-        # packs first across rounds bounds any job's wait for its next
-        # page to ~len(jobs) rounds.
+        # Two-phase packing. Phase 1 hands every job a FAIR SHARE: the
+        # largest shape the leftover pays for each of them. A short
+        # prompt behind a long in-flight prefill gets in within
+        # ~len(jobs) plans instead of waiting out the whole long
+        # prefill — strict priority order would starve it, which is the
+        # head-of-line blocking this scheduler exists to kill. Phase 2
+        # re-grants whatever the fair pass left unused (jobs smaller
+        # than their share) to the highest-priority jobs, raising a
+        # grant to the next shape the leftover still pays for.
+        per_job = leftover // len(jobs)
+        # Scarcity rotation: when the leftover cannot give every job a
+        # shape (one bucket a round under several prefills), a fixed
+        # packing order would hand the SAME job the shape every round —
+        # strict head-of-line blocking in fair-share clothing — and a
+        # share cut finer than a shape would run every job's program
+        # mostly empty. Rotating who packs first across rounds bounds
+        # any job's wait for its next grant to ~len(jobs) rounds.
         order_idx = list(range(len(jobs)))
-        if leftover < page * len(jobs):
+        if per_job < floor:
             start = self._rr % len(jobs)
             order_idx = order_idx[start:] + order_idx[:start]
         self._rr += 1
-        granted: dict[int, int] = {}      # job index -> raw tokens
-        for phase_cap in (share, None):
+        granted: dict[int, int] = {}      # job index -> tokens
+        for fair in (True, False):
             for i in order_idx:
-                job = jobs[i]
-                if leftover <= 0:
-                    break
-                cap = leftover if phase_cap is None else phase_cap
-                grant = min(job.remaining - granted.get(i, 0),
-                            self.chunk_tokens - granted.get(i, 0),
-                            cap, leftover)
-                if grant < job.remaining - granted.get(i, 0):
-                    grant = (grant // page) * page
-                if grant <= 0:
+                shapes = self.shapes_of(jobs[i].remaining)
+                have = granted.get(i, 0)
+                paid = self._charge(shapes, have)
+                room = leftover + paid
+                if fair:
+                    room = min(room, max(per_job, shapes[0]))
+                # Final grant: the remainder, charged the shape that
+                # covers it; otherwise exactly a shape.
+                grant = min(jobs[i].remaining, self._within(shapes, room))
+                if grant <= have:
                     continue
-                granted[i] = granted.get(i, 0) + grant
-                leftover -= grant
-        for i, job in enumerate(jobs):
-            total = granted.get(i, 0)
-            if total <= 0:
-                continue
-            if total < job.remaining:
-                # Non-final grant: quantize DOWN to whole pages so every
-                # later chunk starts page-aligned (chunk KV scatters
-                # page-wise; a ragged boundary would split a page across
-                # two dispatches).
-                total = (total // page) * page
-                if total <= 0:
-                    continue
-            plan.chunks.append((job.key, total))
+                granted[i] = grant
+                leftover -= self._charge(shapes, grant) - paid
+        plan.chunks.extend((job.key, granted[i])
+                           for i, job in enumerate(jobs) if i in granted)
         return plan

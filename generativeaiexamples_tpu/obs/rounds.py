@@ -160,7 +160,8 @@ class RoundRecord:
         "active_decodes", "plan_ms", "pool_used_pages",
         # dispatch (scheduler thread, filled until seal)
         "decode_slots", "spec_drafted", "verify_positions",
-        "prefill_tokens", "grants", "pages_touched", "hbm_bytes",
+        "prefill_tokens", "prefill_padded_tokens", "grants",
+        "pages_touched", "hbm_bytes",
         "kv_restore_pages", "blocked_on_pages", "kv_pages_skipped",
         "dispatch_ms", "modeled_ms", "t_dispatch_done",
         # execution (harvest thread)
@@ -195,6 +196,10 @@ class RoundRecord:
         self.spec_drafted = 0
         self.verify_positions = 0
         self.prefill_tokens = 0
+        # Tokens of the compiled shapes this round's chunks ran in (each
+        # grant padded to its bucket): prefill_tokens over this is how
+        # full the round's chunk programs were.
+        self.prefill_padded_tokens = 0
         self.grants: list[tuple[str, int]] = []
         self.pages_touched = 0
         self.hbm_bytes = 0
@@ -203,8 +208,9 @@ class RoundRecord:
         # are folded into hbm_bytes; the count is kept separately so
         # the round record shows restore work explicitly.
         self.kv_restore_pages = 0
-        # Requests the plan offered a chunk that _begin_prefill refused
-        # for want of pages this round (pool backpressure).
+        # Requests kept out for want of pages this round (pool
+        # backpressure): offered a chunk that _begin_prefill refused, or
+        # held back from the plan since the pool last refused them.
         self.blocked_on_pages = 0
         # Pages of the live contexts this round's decode steps did not
         # read because they lie behind a window layer's window (whole
@@ -262,6 +268,7 @@ class RoundRecord:
             "execution": {
                 "decode_slots": self.decode_slots,
                 "prefill_tokens": self.prefill_tokens,
+                "prefill_padded_tokens": self.prefill_padded_tokens,
                 "dispatch_ms": round(self.dispatch_ms, 3),
                 "blocked_on_pages": self.blocked_on_pages,
                 "harvest_wait_ms": round(self.harvest_wait_ms, 3),
@@ -341,6 +348,7 @@ class RoundRecorder:
 
     def seal(self, rec: RoundRecord, *, parts: int,
              prefill_tokens: int = 0,
+             prefill_padded_tokens: int = 0,
              grants: Optional[list] = None,
              modeled_ms: float = 0.0) -> None:
         """Close the dispatch half (scheduler thread): ``parts`` is how
@@ -349,6 +357,7 @@ class RoundRecorder:
         Finalizes immediately if the harvest thread already drained
         every part (it can outrun the scheduler on short rounds)."""
         rec.prefill_tokens = int(prefill_tokens)
+        rec.prefill_padded_tokens = int(prefill_padded_tokens)
         if grants:
             rec.grants = list(grants)
         rec.modeled_ms = float(modeled_ms)

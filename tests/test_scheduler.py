@@ -294,6 +294,241 @@ def test_plan_scarcity_rotation_bounds_single_page_starvation():
     assert "long" in first_page_owner     # the long prefill still moves
 
 
+# ------------------------------------------ grants in the device's shapes
+#
+# The engine hands the planner its chunk programs' shapes (the bucket
+# ladder); a scheduler built without one takes every page multiple as a
+# shape. One algorithm over three sets of shapes.
+
+BIG_PAGE = 128
+LADDERS = {"one-bucket": (512,), "shipped": (128, 512), "none": None}
+
+
+def ladder_sched(ladder, budget=512, chunk=None):
+    return TokenBudgetScheduler(
+        StepCostModel(decode_step_ms=2.0, prefill_ms_per_token=0.25),
+        page_size=BIG_PAGE, steps_per_round=8, round_budget_tokens=budget,
+        chunk_tokens=chunk, chunk_shapes=ladder,
+        max_one_shot_tokens=None if ladder else 512)
+
+
+def charge(sched, remaining, n):
+    """What a grant of ``n`` to a job with ``remaining`` left costs."""
+    return sched._charge(sched.shapes_of(remaining), n)
+
+
+def run_plans(sched, remaining, plans, *, decode_steps=0, active=0):
+    """Plan ``plans`` rounds over in-flight jobs {key: remaining},
+    applying each plan's grants; returns per plan [(key, grant,
+    remaining before the grant)]."""
+    out = []
+    for _ in range(plans):
+        jobs = [PrefillJob(key=k, remaining=r, seq=i, started=True)
+                for i, (k, r) in enumerate(remaining.items()) if r > 0]
+        plan = sched.plan_round(decode_steps=decode_steps,
+                                active_decodes=active, inflight=jobs)
+        out.append([(k, n, remaining[k]) for k, n in plan.chunks])
+        for k, n in plan.chunks:
+            remaining[k] -= n
+    return out
+
+
+def test_ladder_is_the_largest_grant_and_one_shot_cap():
+    sched = ladder_sched((128, 512), budget=4096)
+    assert sched.max_one_shot_tokens == 512 and sched.chunk_tokens == 512
+    # a ladder that starts at one page has no finer shapes under it
+    assert sched.shapes_of(4000) == sched.shapes_of(300) == (128, 512)
+    # one bucket: a job past it is granted whole buckets only; a job
+    # that fits one program keeps the page multiples under it
+    one = ladder_sched((512,))
+    assert one.shapes_of(513) == (512,)
+    assert one.shapes_of(512) == (128, 256, 384, 512)
+    assert [charge(one, 2000, n) for n in (1, 130, 512)] == [512] * 3
+    assert [charge(one, 300, n) for n in (1, 130, 300)] == [128, 256, 384]
+    # no ladder: every page multiple up to the chunk cap is a shape
+    loose = ladder_sched(None, budget=384)
+    assert loose.shapes_of(4000) == loose.shapes_of(100) == (128, 256, 384)
+
+
+def test_four_waiting_prompts_take_turns_at_one_whole_bucket():
+    """Budget 512, one 512 bucket, four 2000-token prompts: the parent
+    cut the budget into four 128-token pages, each run in a 512-shaped
+    program. Now ONE job a plan gets the whole bucket, and rotation
+    reaches every job within four plans."""
+    sched = ladder_sched((512,))
+    remaining = {k: 2000 for k in "abcd"}
+    chunks = run_plans(sched, remaining, 4)
+    assert all(len(c) == 1 and c[0][1] == 512 for c in chunks)
+    assert sorted(c[0][0] for c in chunks) == list("abcd")
+
+
+@pytest.mark.parametrize("ladder", list(LADDERS))
+@pytest.mark.parametrize("jobs,decodes", [(1, 0), (2, 3), (4, 1), (5, 16)])
+def test_every_nonfinal_grant_is_a_whole_shape(ladder, jobs, decodes):
+    sched = ladder_sched(LADDERS[ladder])
+    remaining = {f"j{i}": 700 + 391 * i for i in range(jobs)}
+    start = dict(remaining)
+    for chunks in run_plans(sched, remaining, 40, decode_steps=8,
+                            active=decodes):
+        assert chunks                       # liveness: work every plan
+        charged = 0
+        for key, n, before in chunks:
+            assert n > 0
+            if n < before:      # non-final: exactly one of its shapes
+                assert n in sched.shapes_of(before), (key, n, before)
+                if before > 512:
+                    assert n in (LADDERS[ladder] or range(128, 513, 128))
+            charged += charge(sched, before, n)
+        # the budget is charged the shapes, decode's cost first, with
+        # the floor of one smallest shape
+        assert charged <= max(512 - 8 * decodes, sched.shapes_of(4000)[0])
+        if not any(remaining.values()):
+            break
+    # every prompt is computed whole, tails included
+    assert not any(remaining.values()), (start, remaining)
+
+
+@pytest.mark.parametrize("ladder,grant", [("one-bucket", 512),
+                                          ("shipped", 128),
+                                          ("none", 384)])
+def test_decoding_batch_does_not_cut_a_lone_prefill_under_a_bucket(
+        ladder, grant):
+    """512 - 8 steps x 10 rows leaves 432: with one 512 bucket the floor
+    is the whole bucket (the parent page-quantised to 384 inside a 512
+    program); a ladder with a smaller shape grants that shape; without
+    a ladder the page-multiple meaning holds."""
+    sched = ladder_sched(LADDERS[ladder])
+    job = PrefillJob(key="j", remaining=2000, seq=0, started=True)
+    plan = sched.plan_round(decode_steps=8, active_decodes=10,
+                            inflight=[job])
+    assert plan.chunks == [("j", grant)]
+    # ...and a lone 500-token prompt is one program, not 384 + 116
+    if ladder != "none":
+        job = PrefillJob(key="j", remaining=500, seq=0)
+        plan = sched.plan_round(decode_steps=8, active_decodes=10,
+                                backlog=[job], max_new=1)
+        assert plan.chunks == [("j", 500 if ladder == "one-bucket"
+                                else 128)]
+
+
+def test_final_grant_keeps_its_tail_and_a_long_job_pays_its_bucket():
+    sched = ladder_sched((512,), budget=1024)
+    a = PrefillJob(key="a", remaining=130, seq=0, started=True)
+    b = PrefillJob(key="b", remaining=2000, seq=1, started=True)
+    c = PrefillJob(key="c", remaining=2000, seq=2, started=True)
+    plan = sched.plan_round(decode_steps=0, active_decodes=0,
+                            inflight=[a, b])
+    assert dict(plan.chunks) == {"a": 130, "b": 512}
+    # a long job is charged its bucket whatever it computes; the tail
+    # is charged its pages (256): two buckets do not pay for all three
+    plan = sched.plan_round(decode_steps=0, active_decodes=0,
+                            inflight=[b, c, a])
+    assert sorted(n for _, n in plan.chunks) == [130, 512]
+    b.remaining = c.remaining = 600     # 512 now, an 88-token tail next
+    plan = sched.plan_round(decode_steps=0, active_decodes=0,
+                            inflight=[b, c])
+    assert dict(plan.chunks) == {"b": 512, "c": 512}
+    # a ladder with a small shape cuts a tail to it rather than pad it
+    # to the next: 128 now, two tokens in a 128 program next
+    small = ladder_sched((128, 512))
+    plan = small.plan_round(decode_steps=0, active_decodes=0,
+                            inflight=[a, b])
+    assert dict(plan.chunks) == {"a": 128, "b": 128}
+    a.remaining = 100
+    plan = small.plan_round(decode_steps=0, active_decodes=0,
+                            inflight=[a, b])
+    assert dict(plan.chunks) == {"a": 100, "b": 128}
+
+
+@pytest.mark.parametrize("ladder", list(LADDERS))
+def test_short_prompt_behind_long_prefill_granted_within_len_jobs(ladder):
+    """A 2816-token prefill in flight, a short prompt arrives: its
+    grant comes within len(jobs) plans, not after the long one."""
+    sched = ladder_sched(LADDERS[ladder])
+    long_left = 2816
+    waited = 0
+    for _ in range(2):
+        long_job = PrefillJob(key="long", remaining=long_left, seq=0,
+                              started=True)
+        short = PrefillJob(key="short", remaining=90, seq=1)
+        plan = sched.plan_round(decode_steps=8, active_decodes=2,
+                                inflight=[long_job], backlog=[short],
+                                max_new=4)
+        grants = dict(plan.chunks)
+        long_left -= grants.get("long", 0)
+        if "short" in grants:
+            assert grants["short"] == 90
+            break
+        waited += 1
+    else:
+        pytest.fail("short prompt starved behind the long prefill")
+    assert waited < 2 and long_left > 0
+
+
+def test_backlog_job_without_a_grant_is_not_in_the_plan():
+    """Under scarcity a waiting backlog job that gets nothing this plan
+    takes no slot and no pages: it is simply absent from the chunks."""
+    sched = ladder_sched((512,))
+    inflight = PrefillJob(key="busy", remaining=2000, seq=0, started=True)
+    waiting = [PrefillJob(key=f"w{i}", remaining=1500, seq=1 + i)
+               for i in range(3)]
+    plan = sched.plan_round(decode_steps=0, active_decodes=0,
+                            inflight=[inflight], backlog=waiting,
+                            max_new=3)
+    assert len(plan.chunks) == 1 and plan.chunks[0][1] == 512
+
+
+def test_fair_share_is_the_largest_shape_under_an_equal_split():
+    # 8320 = 16 x 512 + 8 x 16: sixteen prompts and a decoding batch
+    # still leave every prompt a whole bucket
+    sched = ladder_sched((512,), budget=8320)
+    jobs = [PrefillJob(key=i, remaining=4000, seq=i, started=True)
+            for i in range(15)]
+    plan = sched.plan_round(decode_steps=8, active_decodes=1, inflight=jobs)
+    assert [n for _, n in plan.chunks] == [512] * 15
+    # the chunk cap bounds one job's grant a plan: the second pass does
+    # not hand a job a second bucket
+    plan = sched.plan_round(decode_steps=0, active_decodes=0,
+                            inflight=jobs[:3])
+    assert [n for _, n in plan.chunks] == [512] * 3
+
+
+def test_chunk_cap_under_the_smallest_shape_grants_that_shape():
+    # a cap finer than any compiled program would only be padding
+    sched = ladder_sched((512,), budget=512, chunk=128)
+    job = PrefillJob(key="j", remaining=2000, seq=0, started=True)
+    plan = sched.plan_round(decode_steps=8, active_decodes=4,
+                            inflight=[job])
+    assert plan.chunks == [("j", 512)]
+
+
+def test_prompts_that_fit_one_program_still_share_in_pages():
+    """Four 300-token prompts beside a decoding request, one 512
+    bucket: each gets a page, as before whole-shape grants. Kept for
+    the benchmark's warm-up, which reaches the chunk programs of small
+    windows (first, middle and final chunk of a SHORT prompt) only
+    through such shared grants (scheduler.shapes_of)."""
+    sched = ladder_sched((512,))
+    remaining = {k: 300 for k in "abcd"}
+    chunks = run_plans(sched, remaining, 3, decode_steps=8, active=1)
+    assert [sorted(n for _, n, _ in c) for c in chunks] \
+        == [[128] * 4, [128] * 4, [44] * 4]
+    # the tail of a long prompt is such a job too: among long jobs it
+    # takes its turn and finishes in one program
+    sched = ladder_sched((512,))
+    tail = PrefillJob(key="tail", remaining=300, seq=0, started=True)
+    longs = [PrefillJob(key=f"l{i}", remaining=2000, seq=1 + i,
+                        started=True) for i in range(3)]
+    seen = []
+    for _ in range(4):
+        plan = sched.plan_round(decode_steps=8, active_decodes=1,
+                                inflight=[tail] + longs)
+        assert len(plan.chunks) == 1
+        seen.append(plan.chunks[0])
+    assert ("tail", 300) in seen
+    assert sorted(k for k, _ in seen) == ["l0", "l1", "l2", "tail"]
+
+
 # ------------------------------------------------------- slack ordering
 
 
@@ -405,6 +640,107 @@ def test_engine_chunked_output_matches_one_shot():
     assert chunked.token_ids == one_shot.token_ids
 
 
+def test_engine_concurrent_prompts_fill_their_chunk_programs(tmp_path):
+    """Three multi-chunk prompts at once under a ONE-bucket ladder: the
+    planner hands the round's bucket to one prompt at a time, so the
+    chunk programs run full (the page-sized fair shares of before ran
+    each a quarter full), the tokens are those of each prompt served
+    alone, and the padded-token counter is the sum of what the
+    ``chunk_dispatch`` spans say each program was padded to."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    eng = _engine(max_slots=4, max_input_length=200, max_output_length=8,
+                  prefill_buckets=(64,), max_prefill_bucket=64,
+                  sched_round_budget_tokens=64, prefix_cache=False)
+    assert eng._buckets == (64,)
+    prompts = [[3 + (i * 5 + j) % 11 for i in range(n)]
+               for j, n in enumerate((150, 170, 131))]
+    sp = SamplingParams(max_tokens=6, top_k=1, ignore_eos=True)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    try:
+        together = [eng.submit(p, sp) for p in prompts]
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            eng.start()
+            for s in together:
+                s.text()
+        finally:
+            jax.profiler.stop_trace()
+        stats = eng.stats
+        alone = []
+        for p in prompts:
+            alone.append(eng.submit(p, sp))
+            alone[-1].text()
+    finally:
+        eng.stop()
+    assert [s.token_ids for s in together] == [s.token_ids for s in alone]
+    assert stats["sched_prefill_tokens"] == sum(map(len, prompts))
+    assert stats["sched_prefill_fill"] >= 0.7
+    path = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                  "*.xplane.pb"))[0]
+    spans = [dict(e.stats)
+             for plane in ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for e in line.events
+             if e.name == "chunk_dispatch"]
+    assert spans and all(s["padded"] == 64 for s in spans)
+    assert sum(s["padded"] for s in spans) \
+        == stats["sched_prefill_padded_tokens"]
+    assert sum(s["tokens"] for s in spans) == stats["sched_prefill_tokens"]
+    recs = [r for r in eng.rounds.records()
+            if r.engine_tag == eng.engine_tag]
+    assert sum(r.prefill_padded_tokens for r in recs) \
+        == eng.stats["sched_prefill_padded_tokens"]
+
+
+def test_engine_holds_back_a_prompt_the_pool_refused():
+    """The pool holds one request. The second prompt is offered to the
+    planner, refused for want of pages ONCE, and then held back until
+    the pool can give more: under one-bucket rotation a plan's only
+    grant must not go, round after round, to a prompt that cannot
+    start. The rounds it waits through still count as pool-blocked."""
+    # 150 in + 8 out = 158 tokens = 10 pages: a pool of 12 holds one
+    eng = _engine(max_slots=4, max_input_length=200, max_output_length=8,
+                  prefill_buckets=(64,), max_prefill_bucket=64,
+                  sched_round_budget_tokens=64, prefix_cache=False,
+                  kv_pool_tokens=12 * PAGE)
+    refusals = []
+    begin = eng._begin_prefill
+
+    def counting(req, rec=None):
+        ok = begin(req, rec)
+        if ok is False:
+            refusals.append(req.stream.request_id)
+        return ok
+
+    eng._begin_prefill = counting
+    sp = SamplingParams(max_tokens=8, top_k=1, ignore_eos=True)
+    prompts = [[3 + (i + j) % 7 for i in range(150)] for j in range(2)]
+    try:
+        streams = [eng.submit(p, sp) for p in prompts]
+        eng.start()
+        for s in streams:
+            s.text()
+        stats = eng.stats
+        recs = [r for r in eng.rounds.records()
+                if r.engine_tag == eng.engine_tag]
+    finally:
+        eng.stop()
+    assert all(len(s.token_ids) == 8 for s in streams)
+    assert refusals == [streams[1].request_id]
+    # the first prompt's three chunks and its decode rounds all ran
+    # while the second waited on the pool
+    assert stats["pool_blocked_rounds"] >= 3
+    assert stats["pool_blocked_rounds"] \
+        == sum(1 for r in recs if r.blocked_on_pages > 0)
+    assert stats["sched_prefill_fill"] >= 0.7
+
+
 def test_engine_decode_only_rounds_unchanged():
     """No prefill pending: the plan dispatches full steps_per_round
     rounds with a right-sized tail — exactly the pre-scheduler cadence
@@ -462,11 +798,13 @@ def test_engine_stats_expose_sched_gauges():
     try:
         stats = eng.stats
         for key in ("sched_round_budget_tokens", "sched_prefill_tokens",
+                    "sched_prefill_padded_tokens",
                     "sched_decode_tokens", "sched_interleaved_rounds",
-                    "sched_prefill_share"):
+                    "sched_prefill_share", "sched_prefill_fill"):
             assert key in stats
         assert stats["sched_round_budget_tokens"] >= PAGE
         assert stats["sched_prefill_share"] == 0.0
+        assert stats["sched_prefill_fill"] == 0.0
     finally:
         eng.stop()
 
