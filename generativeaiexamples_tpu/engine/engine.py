@@ -304,6 +304,26 @@ class _StaleLoop(Exception):
     whole _run() without touching the new generation's state."""
 
 
+def weight_bytes_of(params, model_cfg: LlamaConfig) -> tuple[int, int]:
+    """``(all, routed)``: the bytes of the parameter tree AS IT IS —
+    every stack, a shared expert, a gate matrix, whatever the tree holds
+    — and, of those, the dropless routed experts' (the stacks whose
+    layers have a router): a decode step streams the rest whole and of
+    these only the experts its rows reach (``_step_weight_bytes``).
+    Leaves need a shape and a dtype only."""
+    def nbytes(tree) -> int:
+        return sum(int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize
+                   for x in jax.tree.leaves(tree))
+
+    routed = 0
+    if model_cfg.num_experts and model_cfg.moe_impl == "dropless":
+        routed = sum(nbytes(params[stack][name])
+                     for stack, _, _ in model_cfg.layer_stacks
+                     if "router" in params[stack]
+                     for name in ("w_gate", "w_up", "w_down"))
+    return nbytes(params), routed
+
+
 @dataclass(frozen=True)
 class EngineConfig:
     """Engine sizing. Limits mirror the reference's engine defaults
@@ -847,8 +867,8 @@ class Engine:
         # Inputs of the per-round HBM-traffic estimate: weight bytes
         # streamed once per decode step, KV page bytes per touched page,
         # and the chip's peak bandwidth (0 on CPU — no roofline there).
-        self._param_bytes = sum(
-            int(x.nbytes) for x in jax.tree.leaves(self.params))
+        self._param_bytes, self._expert_bytes = weight_bytes_of(
+            self.params, self.model_cfg)
         # Per-layer kinds of the model (models/configs.py): the share of
         # its layers that attend a window (the decode KERNEL starts their
         # page loop at the window's first page; the gather path masks
@@ -859,12 +879,7 @@ class Engine:
         self._window_share = (
             sum(1 for w in mc.layer_windows if w) / mc.num_layers
             if self._use_kernel else 0.0)
-        self._moe_stats = bool(mc.num_experts
-                               and mc.moe_impl == "dropless")
-        self._expert_bytes = sum(
-            int(leaf.nbytes) for name in ("w_gate", "w_up", "w_down")
-            for leaf in jax.tree.leaves(self.params["layers"][name])
-        ) if self._moe_stats else 0
+        self._moe_stats = bool(self._expert_bytes)
         dev0 = self._devices()[0]
         self._hbm_peak = 0.0 if dev0.platform == "cpu" else peak_bw(dev0)
         # Model-vs-measured drift: EWMA of (round wall / modeled round

@@ -69,6 +69,49 @@ class LlamaConfig:
     #         the stream as it ENTERS the block, un-normed, before the
     #         attention; its logits are handed past it to the experts)
     router_input: str = "mlp_norm"
+    # An expert model whose blocks are not all alike (moe_impl "dropless"
+    # only; every default is the model above, whose programs it leaves
+    # as they are):
+    #   moe_intermediate_size: an expert's width where it is not the
+    #         dense width (0 = ``intermediate_size`` serves both)
+    #   num_dense_layers: the leading layers that keep a dense MLP of
+    #         width ``intermediate_size``. Their tree has another shape,
+    #         so they are a stack of their own, ``params["dense_layers"]``,
+    #         run before ``params["layers"]`` over the one KV pool
+    #   num_shared_experts: experts every token passes through, beside
+    #         the routed ones and un-weighted: one gated MLP of width
+    #         ``num_shared_experts`` x the expert width
+    #   router_score_func: "softmax" (top-k of the logits, softmax over
+    #         the chosen) | "sigmoid" (each expert scored on its own:
+    #         top-k of the scores, a chosen expert's weight its score)
+    #   router_norm_topk: sigmoid scores of the chosen divided by their
+    #         sum (softmax over the chosen sums to one by itself)
+    #   router_scale: the chosen weights times this
+    #   router_bias: "" | "selection" (a stored per-expert bias is added
+    #         to the scores for the CHOICE only; the weights stay the
+    #         un-biased scores) | "scores" (added to the scores, so it
+    #         moves choice and weight alike)
+    moe_intermediate_size: int = 0
+    num_dense_layers: int = 0
+    num_shared_experts: int = 0
+    router_score_func: str = "softmax"
+    router_norm_topk: bool = True
+    router_scale: float = 1.0
+    router_bias: str = ""
+    # Attention and block variants, each off by default:
+    #   qk_norm: RMSNorm over each head's ``head_dim`` values of q and k
+    #         (one weight vector each a layer, shared by the heads),
+    #         BEFORE the rotary embedding: the pool holds normed keys
+    #   attn_gate: the attention output times sigmoid(x W_z), x the
+    #         block's normed input, elementwise, before ``wo``
+    #   post_norms: a norm on each sub-block's OUTPUT (attention after
+    #         ``wo``, the MLP or experts) before it is added to the
+    #         stream: four norms a block
+    #   embed_scale: the embedding output times this
+    qk_norm: bool = False
+    attn_gate: bool = False
+    post_norms: bool = False
+    embed_scale: float = 1.0
     # How ``llama.init_params`` draws a random tree (tests, benchmarks;
     # served weights come from ``import_hf`` and ignore it):
     #   "fan_in": every matrix N(0, 1/fan_in), embedding rows of norm 1
@@ -77,7 +120,13 @@ class LlamaConfig:
     #         scaled by 1/sqrt(2L) as GPT-2 and Megatron draw them; ``wq``
     #         times 4. A stream a router can read un-normed without
     #         collapsing, and heads that choose keys, so that a wrong
-    #         window or rotary layer shows at the logits
+    #         window or rotary layer shows at the logits. The stream
+    #         is the embedding AFTER ``embed_scale``; a ``router_bias``
+    #         is the one that evens the load of router columns of
+    #         unequal reach (deviation 0.1); the norm weights that
+    #         ``qk_norm`` / ``post_norms`` add carry the gains the norms
+    #         take out of ``wq``, ``wo`` and ``w_down``, each a tenth
+    #         off its centre (``llama.init_params`` says why each)
     weight_init: str = "fan_in"
 
     def __post_init__(self):
@@ -95,6 +144,25 @@ class LlamaConfig:
             raise ValueError(f"unknown router_input {self.router_input!r}")
         if self.weight_init not in ("fan_in", "unit_stream"):
             raise ValueError(f"unknown weight_init {self.weight_init!r}")
+        if self.router_score_func not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"unknown router_score_func {self.router_score_func!r}")
+        if self.router_bias not in ("", "selection", "scores"):
+            raise ValueError(f"unknown router_bias {self.router_bias!r}")
+        routed = self.router_score_func == "sigmoid"
+        if not routed and (self.router_bias or self.router_scale != 1.0):
+            raise ValueError("router_bias and router_scale act on sigmoid "
+                             "scores (router_score_func 'sigmoid')")
+        varied = (routed or self.moe_intermediate_size
+                  or self.num_dense_layers or self.num_shared_experts)
+        if varied and not (self.num_experts
+                           and self.moe_impl == "dropless"):
+            raise ValueError(
+                "moe_intermediate_size, num_dense_layers, "
+                "num_shared_experts and sigmoid routing need experts "
+                "under moe_impl 'dropless'")
+        if not 0 <= self.num_dense_layers < max(self.num_layers, 1):
+            raise ValueError("num_dense_layers must leave an expert layer")
 
     def layer_pattern(self, pattern: tuple, default: int) -> tuple:
         """A 0/1 period repeated over the depth (``default`` where the
@@ -116,6 +184,20 @@ class LlamaConfig:
     @property
     def layer_rope(self) -> tuple:
         return self.layer_pattern(self.rope_layers, 1)
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def layer_stacks(self) -> tuple:
+        """The model's layer stacks in the order they run: ``(name in the
+        parameter tree, index of its first layer, layers)``. One stack,
+        ``layers``, but for an expert model with leading dense layers."""
+        n = self.num_dense_layers
+        if not n:
+            return (("layers", 0, self.num_layers),)
+        return (("dense_layers", 0, n), ("layers", n, self.num_layers - n))
 
     @property
     def q_dim(self) -> int:
@@ -170,6 +252,24 @@ SMALLTHINKER_21B_A3B = LlamaConfig(
     sliding_window=4096, window_layers=(0, 1, 1, 1),
     rope_layers=(0, 1, 1, 1), weight_init="unit_stream")
 
+# A 26B-total / 3B-active sparse model (arcee-ai Trinity-Mini config.json,
+# model_type afmoe): two dense layers then thirty of 128 narrow experts,
+# 8 a token by sigmoid scores with a selection bias, beside one shared
+# expert; gated attention with q/k norms and four norms a block; three
+# 2048-token window layers with rotary embedding then one global layer
+# without, eight times over; the embedding times sqrt(hidden).
+TRINITY_MINI = LlamaConfig(
+    vocab_size=200192, hidden_size=2048, intermediate_size=6144,
+    moe_intermediate_size=1024, num_layers=32, num_dense_layers=2,
+    num_heads=32, num_kv_heads=4, head_dim=128,
+    max_position_embeddings=131072, rope_theta=10000.0, rms_norm_eps=1e-5,
+    num_experts=128, num_experts_per_tok=8, num_shared_experts=1,
+    moe_impl="dropless", router_score_func="sigmoid",
+    router_norm_topk=True, router_scale=2.826, router_bias="selection",
+    sliding_window=2048, window_layers=(1, 1, 1, 0),
+    rope_layers=(1, 1, 1, 0), qk_norm=True, attn_gate=True,
+    post_norms=True, embed_scale=2048 ** 0.5, weight_init="unit_stream")
+
 # GPT-Next / Nemotron-8B (the reference's second served family:
 # ensemble_models/gptnext/, docs/rag/support_matrix.md:14 sizing;
 # nemotron_config.yaml deployment). Rotary attention, zero-centered
@@ -218,6 +318,7 @@ MODEL_REGISTRY: dict[str, LlamaConfig] = {
     "mixtral-8x7b-instruct": MIXTRAL_8X7B,
     "nemotron-8b-chat": NEMOTRON_8B,
     "smallthinker-21b-a3b-instruct": SMALLTHINKER_21B_A3B,
+    "trinity-mini": TRINITY_MINI,
     "gptnext-tiny": GPTNEXT_TINY,
     "llama-tiny": LLAMA_TINY,
     "golden-tiny": GOLDEN_TINY,

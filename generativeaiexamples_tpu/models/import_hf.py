@@ -37,6 +37,26 @@ _HF_LAYER_KEYS = {
     "mlp.down_proj.weight": ("w_down", True),
 }
 
+# A block with four norms, a gated QK-normed attention and a sigmoid router
+# beside shared experts (``model_type: afmoe``; cfg.post_norms). Where a
+# block has post-norms, ``post_attention_layernorm`` is what it says — the
+# norm on the attention's OUTPUT — and the MLP's input norm has a name of
+# its own. A leading dense layer's ``mlp.*_proj`` are the plain names above.
+_HF_POST_NORM_LAYER_KEYS = {
+    "post_attention_layernorm.weight": ("post_attn_norm", False),
+    "pre_mlp_layernorm.weight": ("mlp_norm", False),
+    "post_mlp_layernorm.weight": ("post_mlp_norm", False),
+    "self_attn.q_norm.weight": ("q_norm", False),
+    "self_attn.k_norm.weight": ("k_norm", False),
+    "self_attn.gate_proj.weight": ("wz", True),
+    "mlp.router.gate.weight": ("router", True),
+    "mlp.expert_bias": ("router_bias", False),
+    "mlp.shared_experts.gate_proj.weight": ("ws_gate", True),
+    "mlp.shared_experts.up_proj.weight": ("ws_up", True),
+    "mlp.shared_experts.down_proj.weight": ("ws_down", True),
+}
+_FLOAT32_LEAVES = ("router_bias",)      # a buffer the router adds in f32
+
 # Meta/fairscale checkpoint names (consolidated.*.pth). Values: (name, kind)
 # where kind marks the extra transform — "q"/"k" rows additionally need the
 # interleaved→half-split RoPE permutation to match ops.rope's HF convention.
@@ -69,6 +89,9 @@ _MOE_EXPERT_RE = re.compile(
     r"block_sparse_moe\.experts\.(\d+)\.w([123])\.weight")
 # Mixtral: w1=gate, w3=up, w2=down.
 _MOE_W_TO_NAME = {"1": "w_gate", "3": "w_up", "2": "w_down"}
+# ... and experts named as a dense MLP's projections (afmoe)
+_MOE_PROJ_RE = re.compile(
+    r"mlp\.experts\.(\d+)\.(gate|up|down)_proj\.weight")
 
 
 def detect_checkpoint_format(path: str) -> str:
@@ -190,9 +213,14 @@ def params_from_named_tensors(
         dtype: jnp.dtype = jnp.bfloat16) -> Params:
     """Assemble the stacked param tree from HF-named tensors.
 
-    Accepts names with or without the leading ``model.`` prefix.
+    Accepts names with or without the leading ``model.`` prefix. The
+    layers are split into the model's stacks (``cfg.layer_stacks``): the
+    leading dense layers of an expert model are a tree of their own.
     """
     L = cfg.num_layers
+    hf_keys = dict(_HF_LAYER_KEYS)
+    if cfg.post_norms:
+        hf_keys.update(_HF_POST_NORM_LAYER_KEYS)
     layer_acc: dict[str, list] = {}
     top: dict[str, Any] = {}
 
@@ -221,8 +249,8 @@ def params_from_named_tensors(
         if not m:
             continue  # rotary inv_freq buffers etc.
         idx, rest = int(m.group(1)), m.group(2)
-        if rest in _HF_LAYER_KEYS:
-            name, transpose = _HF_LAYER_KEYS[rest]
+        if rest in hf_keys:
+            name, transpose = hf_keys[rest]
             put_layer(name, idx, arr.T if transpose else arr)
             continue
         if rest in _META_LAYER_KEYS:
@@ -243,25 +271,42 @@ def params_from_named_tensors(
             put_layer(_MOE_W_TO_NAME[em.group(2)], idx, _to_numpy(raw).T,
                       extra=int(em.group(1)))
             continue
+        em = _MOE_PROJ_RE.match(rest)
+        if em:
+            put_layer("w_" + em.group(2), idx, _to_numpy(raw).T,
+                      extra=int(em.group(1)))
+            continue
 
-    missing = [k for k, v in layer_acc.items()
-               for i, x in enumerate(v) if x is None]
+    def stacked(first: int, n: int) -> tuple[dict, list]:
+        """The leaves the layers [first, first + n) hold, stacked, and
+        the names some of them lack."""
+        layers, missing = {}, []
+        for name, per_layer in layer_acc.items():
+            part = per_layer[first:first + n]
+            if all(x is None for x in part):
+                continue            # not a leaf of this stack's layers
+            if any(x is None or (isinstance(x, list)
+                                 and any(e is None for e in x))
+                   for x in part):
+                missing.append(name)
+                continue
+            if isinstance(part[0], list):   # MoE: [L][E] → (L,E,...)
+                part = [np.stack(e, axis=0) for e in part]
+            layers[name] = jnp.asarray(
+                np.stack(part, axis=0),
+                jnp.float32 if name in _FLOAT32_LEAVES else dtype)
+        return layers, missing
+
+    stacks = {name: stacked(first, n) for name, first, n in cfg.layer_stacks}
+    missing = [k for _, lacking in stacks.values() for k in lacking]
     if missing or "embed" not in top or "final_norm" not in top:
         raise ModelLoadError(
             f"incomplete checkpoint: missing embed/final_norm or layer "
             f"tensors ({sorted(set(missing))[:5]}...)")
 
-    layers = {}
-    for name, per_layer in layer_acc.items():
-        if isinstance(per_layer[0], list):  # MoE: [L][E] → (L,E,...)
-            stacked = np.stack([np.stack(e, axis=0) for e in per_layer], axis=0)
-        else:
-            stacked = np.stack(per_layer, axis=0)
-        layers[name] = jnp.asarray(stacked, dtype)
-
     params: Params = {
         "embed": jnp.asarray(top["embed"], dtype),
-        "layers": layers,
+        **{name: layers for name, (layers, _) in stacks.items()},
         "final_norm": jnp.asarray(top["final_norm"], dtype),
     }
     if "lm_head" in top:

@@ -30,6 +30,13 @@ Param tree (all projections stored input-major so forward is ``x @ W``):
     router: (L, D, E)  w_gate/w_up: (L, E, D, F)  w_down: (L, E, F, D)  [MoE]
   final_norm:  (D,)
   lm_head:     (D, V)
+Configured extras (models/configs.py; absent otherwise): ``wz`` (L, D,
+H*hd) the attention gate; ``q_norm`` / ``k_norm`` (L, hd);
+``post_attn_norm`` / ``post_mlp_norm`` (L, D); ``router_bias`` (L, E)
+float32; a shared expert ``ws_gate`` / ``ws_up`` (L, D, Fs), ``ws_down``
+(L, Fs, D). An expert model with leading dense layers holds them as a
+second stack, ``dense_layers`` (the same tree with a dense MLP), beside
+``layers``: ``cfg.layer_stacks`` names the stacks in the order they run.
 """
 
 from __future__ import annotations
@@ -58,15 +65,22 @@ from .configs import LlamaConfig
 #: the KV write), ``mlp`` (dense FFN) or ``moe_route`` + ``moe_experts``
 #: (parallel/moe.py); ``tail`` (final norm, lm_head tile stream,
 #: penalties, sampling) with ``tail_select`` inside it (top-k / top-p
-#: candidate handling: where the sort is). docs/observability.md lists
-#: them; the benchmark's scope reader keeps an equal tuple.
+#: candidate handling: where the sort is). A shared expert runs as
+#: ``mlp/moe_shared`` (a dense FFN inside ``mlp``, under a name of its
+#: own). docs/observability.md lists them; the benchmark's scope reader
+#: keeps an equal tuple.
 SCOPES = ("embed", "attn_proj", "attn", "mlp", "moe_route", "moe_experts",
           "tail", "tail_select")
 
 
-def _embed(params: "Params", tokens: jax.Array) -> jax.Array:
+def _embed(params: "Params", tokens: jax.Array,
+           scale: float = 1.0) -> jax.Array:
+    """Embedding rows times the model's ``embed_scale``."""
     with jax.named_scope("embed"):
-        return jnp.take(params["embed"], tokens, axis=0)
+        h = jnp.take(params["embed"], tokens, axis=0)
+        if scale != 1.0:
+            h = (h.astype(jnp.float32) * scale).astype(h.dtype)
+        return h
 
 
 def use_paged_kernel(cfg: LlamaConfig, page: int) -> bool:
@@ -94,7 +108,7 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
     """Random-init parameter tree (for tests/benchmarks; real weights come
     from ``import_hf``)."""
     k = iter(jax.random.split(key, 16))
-    D, F, L = cfg.hidden_size, cfg.intermediate_size, cfg.num_layers
+    D, F = cfg.hidden_size, cfg.intermediate_size
     H, KV, hd, V = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.vocab_size
 
     def norm(rng, shape, fan_in):
@@ -125,51 +139,128 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
     # 4-18 times over it (chip, PR 28; PERF.md section 6).
     stream_draw = cfg.weight_init == "unit_stream"
     q_gain = 16 if stream_draw else 1
-    resid = 2 * L if stream_draw else 1
+    resid = 2 * cfg.num_layers if stream_draw else 1
 
     # layernorm1p stores weights centered at zero (applied as 1 + w)
     norm_w = jnp.zeros if cfg.norm == "layernorm1p" else jnp.ones
-    layers: dict[str, jax.Array] = {
-        "attn_norm": norm_w((L, D), dtype),
-        "mlp_norm": norm_w((L, D), dtype),
-        "wq": norm(next(k), (L, D, H * hd), D / q_gain),
-        "wk": norm(next(k), (L, D, KV * hd), D),
-        "wv": norm(next(k), (L, D, KV * hd), D),
-        "wo": norm(next(k), (L, H * hd, D), H * hd * resid),
-    }
-    if cfg.norm == "layernorm1p":
-        layers["attn_norm_b"] = jnp.zeros((L, D), dtype)
-        layers["mlp_norm_b"] = jnp.zeros((L, D), dtype)
-    if cfg.attn_bias:
-        layers["bq"] = jnp.zeros((L, H * hd), dtype)
-        layers["bk"] = jnp.zeros((L, KV * hd), dtype)
-        layers["bv"] = jnp.zeros((L, KV * hd), dtype)
-        layers["bo"] = jnp.zeros((L, D), dtype)
-    if cfg.num_experts:
-        E = cfg.num_experts
-        layers.update({
-            "router": norm(next(k), (L, D, E), D),
-            "w_gate": norm(next(k), (L, E, D, F), D),
-            "w_up": norm(next(k), (L, E, D, F), D),
-            "w_down": norm(next(k), (L, E, F, D), F * resid),
-        })
-    elif cfg.mlp == "squared_relu":
-        # GPT-Next MLP: no gate projection
-        layers.update({
-            "w_up": norm(next(k), (L, D, F), D),
-            "w_down": norm(next(k), (L, F, D), F),
-        })
-        if cfg.mlp_bias:
-            layers["b_up"] = jnp.zeros((L, F), dtype)
-            layers["b_down"] = jnp.zeros((L, D), dtype)
-    else:
-        layers.update({
-            "w_gate": norm(next(k), (L, D, F), D),
-            "w_up": norm(next(k), (L, D, F), D),
-            "w_down": norm(next(k), (L, F, D), F),
-        })
+    (_, _, L), *leading = reversed(cfg.layer_stacks)
+    Fe = cfg.expert_width
+
+    def stack(k, L, experts):
+        """One stack's tree, its matrices drawn from ``k`` in an order
+        the committed trees depend on."""
+        layers: dict[str, jax.Array] = {
+            "attn_norm": norm_w((L, D), dtype),
+            "mlp_norm": norm_w((L, D), dtype),
+            "wq": norm(next(k), (L, D, H * hd), D / q_gain),
+            "wk": norm(next(k), (L, D, KV * hd), D),
+            "wv": norm(next(k), (L, D, KV * hd), D),
+            "wo": norm(next(k), (L, H * hd, D), H * hd * resid),
+        }
+        if cfg.norm == "layernorm1p":
+            layers["attn_norm_b"] = jnp.zeros((L, D), dtype)
+            layers["mlp_norm_b"] = jnp.zeros((L, D), dtype)
+        if cfg.attn_bias:
+            layers["bq"] = jnp.zeros((L, H * hd), dtype)
+            layers["bk"] = jnp.zeros((L, KV * hd), dtype)
+            layers["bv"] = jnp.zeros((L, KV * hd), dtype)
+            layers["bo"] = jnp.zeros((L, D), dtype)
+        if experts:
+            E = cfg.num_experts
+            layers.update({
+                "router": norm(next(k), (L, D, E), D),
+                "w_gate": norm(next(k), (L, E, D, Fe), D),
+                "w_up": norm(next(k), (L, E, D, Fe), D),
+                "w_down": norm(next(k), (L, E, Fe, D), Fe * resid),
+            })
+        elif cfg.mlp == "squared_relu":
+            # GPT-Next MLP: no gate projection
+            layers.update({
+                "w_up": norm(next(k), (L, D, F), D),
+                "w_down": norm(next(k), (L, F, D), F),
+            })
+            if cfg.mlp_bias:
+                layers["b_up"] = jnp.zeros((L, F), dtype)
+                layers["b_down"] = jnp.zeros((L, D), dtype)
+        else:
+            layers.update({
+                "w_gate": norm(next(k), (L, D, F), D),
+                "w_up": norm(next(k), (L, D, F), D),
+                "w_down": norm(next(k), (L, F, D), F),
+            })
+        return layers
+
+    def extras(k, layers):
+        """The leaves the configured variants add to a stack's tree,
+        from keys of their own (the draws above keep theirs), and the
+        router again where they re-draw it. What a norm's weight is drawn
+        around says what the norm does to the draw: a q/k norm takes the
+        gain out of ``wq``, so ``q_norm`` carries it (a query's scores
+        keep their deviation of 4); a post-norm rescales a sub-block's
+        output to its weight, so it carries the 1/sqrt(2L) that ``wo``
+        and ``w_down`` no longer can — and ``post_mlp_norm`` a fifth of
+        that: bf16 rounding of the stream swaps the last of a token's
+        sigmoid-scored experts for the next in about every second
+        layer (the chosen weigh nearly alike, so the swap is a whole
+        share), and under 1/sqrt(2L) every position's logits then read
+        0.14 off the float32 reference's (chip, PR 32); at a fifth a
+        swap moves a hundredth of the stream. Each weight a tenth off
+        its centre, so that a program without the norm differs in more
+        than scale.
+
+        The router's columns get unequal REACH (0.4 to 2.5 times the
+        fan-in deviation) and the selection bias is the one that evens
+        the load: an expert of long reach scores high when chosen, one
+        of short reach low, and ``router_bias`` lifts each to the same
+        cut, as a bias trained for balance does. So the bias is large
+        (deviation 0.1, where neighbours in rank lie 0.005 apart), the
+        biased and un-biased scores of the chosen differ by a tenth,
+        and a decode batch still reaches the experts a uniform draw
+        would (a bias drawn alone at 0.1 made some experts everybody's:
+        55 of the uniform 77 at 14 rows; chip, PR 32)."""
+        def near(centre, shape):
+            return (centre * (1.0 + 0.1 * jax.random.normal(
+                next(k), shape, jnp.float32))).astype(dtype)
+
+        L, experts = layers["wq"].shape[0], "router" in layers
+        out: dict[str, jax.Array] = {}
+        if cfg.attn_gate:
+            out["wz"] = norm(next(k), (L, D, H * hd), D)
+        if cfg.qk_norm:
+            out["q_norm"] = near(q_gain ** 0.5, (L, hd))
+            out["k_norm"] = near(1.0, (L, hd))
+        if cfg.post_norms:
+            for name, share in (("post_attn_norm", 1.0),
+                                ("post_mlp_norm", 0.2)):
+                out[name] = near(share * resid ** -0.5
+                                 - (cfg.norm == "layernorm1p"), (L, D))
+                if cfg.norm == "layernorm1p":
+                    out[name + "_b"] = jnp.zeros((L, D), dtype)
+        if experts and cfg.router_bias:
+            E = cfg.num_experts
+            reach = jnp.exp(jax.random.uniform(
+                next(k), (L, E), jnp.float32, jnp.log(0.4), jnp.log(2.5)))
+            # an expert's score where it enters a token's top k
+            cut = jax.nn.sigmoid(reach * jax.scipy.special.ndtri(
+                1.0 - cfg.num_experts_per_tok / E))
+            out["router_bias"] = jnp.mean(cut, -1, keepdims=True) - cut
+            out["router"] = (layers["router"].astype(jnp.float32)
+                             * reach[:, None, :]).astype(dtype)
+        if experts and cfg.num_shared_experts:
+            Fs = cfg.num_shared_experts * Fe
+            out.update({
+                "ws_gate": norm(next(k), (L, D, Fs), D),
+                "ws_up": norm(next(k), (L, D, Fs), D),
+                "ws_down": norm(next(k), (L, Fs, D), Fs * resid),
+            })
+        return out
+
+    kx = iter(jax.random.split(jax.random.fold_in(key, 1), 32))
+    layers = stack(k, L, bool(cfg.num_experts))
+    layers.update(extras(kx, layers))
     params: Params = {
-        "embed": norm(next(k), (V, D), 1 if stream_draw else D),
+        "embed": norm(next(k), (V, D),
+                      cfg.embed_scale ** 2 if stream_draw else D),
         "layers": layers,
         "final_norm": norm_w((D,), dtype),
     }
@@ -177,6 +268,9 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
         params["final_norm_b"] = jnp.zeros((D,), dtype)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = norm(next(k), (D, V), D)
+    for name, _, n in leading:          # the leading dense stack
+        params[name] = stack(kx, n, False)
+        params[name].update(extras(kx, params[name]))
     return params
 
 
@@ -257,36 +351,49 @@ def kernel_tp_compatible(cfg: LlamaConfig, mesh) -> bool:
             and (cfg.num_kv_heads // tp) > 0)
 
 
-def layer_kinds(cfg: LlamaConfig) -> dict[str, jax.Array]:
+def layer_kinds(cfg: LlamaConfig, first: int = 0,
+                n: Optional[int] = None) -> dict[str, jax.Array]:
     """The per-layer flags that ride the layer scan beside the stacked
-    weights: ``window`` (L,) int32, the keys a layer attends (0 = its
-    whole context), and ``rope`` (L,) bool. Empty for a model whose
-    layers are all of one kind: its programs take no extra input."""
+    weights: ``window`` (n,) int32, the keys a layer attends (0 = its
+    whole context), and ``rope`` (n,) bool, for the ``n`` layers from
+    layer ``first`` (default: all). Empty for a model whose layers are
+    all of one kind: its programs take no extra input."""
+    last = cfg.num_layers if n is None else first + n
     kinds: dict[str, jax.Array] = {}
     if any(cfg.layer_windows):
-        kinds["window"] = jnp.asarray(cfg.layer_windows, jnp.int32)
+        kinds["window"] = jnp.asarray(cfg.layer_windows[first:last],
+                                      jnp.int32)
     if not all(cfg.layer_rope):
-        kinds["rope"] = jnp.asarray(cfg.layer_rope, bool)
+        kinds["rope"] = jnp.asarray(cfg.layer_rope[first:last], bool)
     return kinds
 
 
-def scan_layers(params: Params, cfg: LlamaConfig
+def scan_layers(params: Params, cfg: LlamaConfig, stack: str = "layers"
                 ) -> tuple[dict[str, jax.Array], dict[str, jax.Array]]:
-    """What the one layer scan iterates over, and what it closes over:
-    ``(xs, held)``. ``xs`` is the stacked layer tree plus the per-layer
+    """What the one layer scan iterates over, and what it closes over,
+    for one of the model's stacks (``cfg.layer_stacks``): ``(xs,
+    held)``. ``xs`` is the stacked layer tree plus the per-layer
     kinds; a scan body rebuilds a layer's parameters as ``{**lp,
     **held}``. ``held`` is empty but for dropless experts: their
     (L, E, in, out) stacks stay OUT of the scan's sliced inputs and the
-    layer carries its ``layer_index``, so an expert's matrix is sliced
-    out of the whole stack where it is used (parallel/moe.py) — a scan
-    that slices a layer's experts first hands the inner block loop a
-    copy of the layer's whole slab."""
-    layers = dict(params["layers"])
+    layer carries its ``layer_index`` — its place in ITS stack, whatever
+    layer of the model it is — so an expert's matrix is sliced out of
+    the whole stack where it is used (parallel/moe.py) — a scan that
+    slices a layer's experts first hands the inner block loop a copy of
+    the layer's whole slab."""
+    first = next(f for name, f, _ in cfg.layer_stacks if name == stack)
+    return _scan_inputs(params[stack], cfg, first)
+
+
+def _scan_inputs(layers: dict[str, jax.Array], cfg: LlamaConfig, first: int):
+    layers = dict(layers)
+    n = jax.tree.leaves(layers)[0].shape[0]
     held: dict[str, jax.Array] = {}
-    if cfg.num_experts and cfg.moe_impl == "dropless":
-        held = {n: layers.pop(n) for n in ("w_gate", "w_up", "w_down")}
-        layers["layer_index"] = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-    layers.update(layer_kinds(cfg))
+    if "router" in layers and cfg.moe_impl == "dropless":
+        held = {name: layers.pop(name)
+                for name in ("w_gate", "w_up", "w_down")}
+        layers["layer_index"] = jnp.arange(n, dtype=jnp.int32)
+    layers.update(layer_kinds(cfg, first, n))
     return layers, held
 
 
@@ -294,13 +401,18 @@ def _run_stack(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
                positions: jax.Array, inv_freq: jax.Array,
                kv_valid_len: Optional[jax.Array], attend=None, *,
                state=None, xs: Optional[dict] = None,
-               row_mask: Optional[jax.Array] = None, stats: bool = False):
-    """The ONE scan over the layer stack; every forward is a use of it
-    and hands it only what differs. ``attend(q, k, v, lp, li, state) ->
-    (attn, out)`` is a layer's KV step (None: attention over the tokens
-    given); ``lp`` holds the layer's parameters, its kinds and its slice
-    of ``xs``, ``li`` is its index. Returns ``(h, out, touched)``, the
-    last each layer's ``experts_touched`` (L,) under ``stats``.
+               row_mask: Optional[jax.Array] = None, stats: bool = False,
+               first: int = 0):
+    """The ONE scan over a layer stack; every forward is a use of it
+    (through ``_run_model``, once a stack of the model) and hands it
+    only what differs. ``attend(q, k, v, lp, li, state) -> (attn, out)``
+    is a layer's KV step (None: attention over the tokens given); ``lp``
+    holds the layer's parameters, its kinds and its slice of ``xs``,
+    ``li`` is its index IN THE MODEL: the stack's layers are the model's
+    layers ``first``, ``first + 1``, ..., and that is where their kinds
+    are read, where the kernel appends and where the pool is read.
+    Returns ``(h, out, touched)``, the last each layer's
+    ``experts_touched`` (n,) under ``stats``.
 
     What the scan iterates over and what it closes over was decided on
     the chip, three times, and is written down here and in ``scan_layers``:
@@ -328,11 +440,7 @@ def _run_stack(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
     ``_write_pool``, the two readers (``_gathered_window``,
     ``_paged_prefix_attention``) and the kernel call.
     """
-    if jax.tree.leaves(layers)[0].shape[0] == cfg.num_layers:
-        stack, held = scan_layers({"layers": layers}, cfg)
-    else:                       # a pipeline stage's share of the depth
-        _refuse_kinds(cfg, "a partial layer stack")
-        stack, held = layers, {}
+    stack, held = _scan_inputs(layers, cfg, first)
     stack = {**stack, **(xs or {})}
     carried = state is not None
 
@@ -353,9 +461,39 @@ def _run_stack(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
 
     # the Pallas call takes its layer as a (1,) scalar-prefetch operand;
     # the held form indexes the flattened pool with a scalar
-    carry = (h, state, jnp.zeros((1,) if carried else (), jnp.int32))
-    (h, state, _), (out, touched) = jax.lax.scan(body, carry, stack)
+    li = jnp.zeros((1,) if carried else (), jnp.int32)
+    if first:
+        li = li + first
+    (h, state, _), (out, touched) = jax.lax.scan(body, (h, state, li), stack)
     return h, (state if carried else out), touched
+
+
+def _run_model(params: Params, cfg: LlamaConfig, h: jax.Array, *args,
+               state=None, xs: Optional[dict] = None, **kw):
+    """Every layer of the model: ``_run_stack`` over each of its stacks
+    in turn (``cfg.layer_stacks``: one, or the leading dense layers and
+    then the expert layers) inside the caller's one program, over ONE
+    pool — carried from stack to stack (``state``) or held by the
+    caller's ``attend`` while each stack's rows come out and are joined
+    along the layer axis. ``xs`` (L, ...) is cut to each stack's layers.
+    ``touched`` is that of the layers that have experts."""
+    stacks = cfg.layer_stacks
+    if len(stacks) == 1:
+        return _run_stack(params["layers"], cfg, h, *args, state=state,
+                          xs=xs, **kw)
+    outs, touched = [], None
+    for name, first, n in stacks:
+        part = xs and {k: v[first:first + n] for k, v in xs.items()}
+        h, out, t = _run_stack(params[name], cfg, h, *args, state=state,
+                               xs=part, first=first, **kw)
+        if state is not None:
+            state = out
+        outs.append(out)
+        if "router" in params[name]:
+            touched = t
+    if state is None:
+        state = jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *outs)
+    return h, state, touched
 
 
 def _write_pool(kv_cache: KVCache, new_k: jax.Array, new_v: jax.Array,
@@ -452,7 +590,7 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     from ..ops.paged_attention import paged_attention_decode
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
                                 cfg.rope_scaling_factor)
-    h = _embed(params, tokens)
+    h = _embed(params, tokens, cfg.embed_scale)
     pos_in_win = positions[:, 0]  # logical index of the current token
     # int8-KV pools: the kernel quantizes the appended row itself, so
     # the current token's K/V pass in compute dtype, not pool dtype.
@@ -492,8 +630,8 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
             block_table, pos_in_win, write_page, write_offset, *win)
         return attn[:, None], pool
 
-    h, cache, touched = _run_stack(
-        params["layers"], cfg, h, positions, inv_freq, kv_valid_len, attend,
+    h, cache, touched = _run_model(
+        params, cfg, h, positions, inv_freq, kv_valid_len, attend,
         state=kv_cache, row_mask=active, stats=stats)
     return _step_result(params, cfg, h, cache, touched, return_hidden,
                         stats)
@@ -552,9 +690,9 @@ def apply_verify_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
         return gqa_attention(q, window("k", k), window("v", v), positions,
                              kv_valid_len, window=lp.get("window")), (k, v)
 
-    h, (new_k, new_v), touched = _run_stack(
-        params["layers"], cfg, _embed(params, tokens), positions, inv_freq,
-        kv_valid_len, attend, row_mask=active, stats=stats)
+    h, (new_k, new_v), touched = _run_model(
+        params, cfg, _embed(params, tokens, cfg.embed_scale), positions,
+        inv_freq, kv_valid_len, attend, row_mask=active, stats=stats)
     cache = _write_pool(kv_cache, new_k, new_v, write_pages, write_offsets)
     return _step_result(params, cfg, h, cache, touched, return_hidden,
                         stats)
@@ -755,7 +893,7 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
         raise ValueError(f"chunk {C} not a page ({page}) multiple")
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
                                 cfg.rope_scaling_factor)
-    h = _embed(params, tokens)
+    h = _embed(params, tokens, cfg.embed_scale)
     start = positions[0, 0]  # absolute position of the chunk's first row
 
     def attend(q, k, v, lp, li, _):
@@ -770,8 +908,8 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
             cfg, window=lp.get("window"), layer=li)
         return attn, (k[0], v[0])
 
-    h, (new_k, new_v), _ = _run_stack(params["layers"], cfg, h, positions,
-                                      inv_freq, kv_valid_len, attend)
+    h, (new_k, new_v), _ = _run_model(params, cfg, h, positions, inv_freq,
+                                      kv_valid_len, attend)
     # new_k/new_v: (L, C, KV, hd), to the chunk's physical pages
     dest = jax.lax.dynamic_slice(block_table[0], (start_page_idx,),
                                  (C // page,))
@@ -876,11 +1014,14 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
     ``rope`` switches the rotary embedding off for a layer. ``row_mask``
     (B,) marks the rows that hold a sequence, for dropless experts;
     ``aux``, a dict, receives what a layer has to say beside its output
-    (``experts_touched``).
+    (``experts_touched``). What the configuration adds to a block
+    (``qk_norm``, ``attn_gate``, ``post_norms``, a shared expert) is read
+    from ``cfg``; whether the layer has experts from its tree.
     """
     B, S, _ = h.shape
     router_logits = None
-    if cfg.num_experts and cfg.router_input == "block_input":
+    experts = "router" in lp        # a leading dense layer has none
+    if experts and cfg.router_input == "block_input":
         # the router reads the stream as it enters the block
         with jax.named_scope("moe_route"):
             router_logits = _router_logits(h, lp)
@@ -892,6 +1033,8 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
         v = qmm(x, lp["wv"])
         if "bq" in lp:
             q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+        if cfg.attn_gate:
+            gate = jax.nn.sigmoid(qmm(x, lp["wz"]).astype(jnp.float32))
         # Keep the head split OUT of the matmuls. Without the barrier the
         # TPU compiler folds `reshape(B, S, H, hd)` into each dot and
         # emits a convolution over the head axis whose kernel is the
@@ -906,6 +1049,9 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
         q = q.reshape(B, S, cfg.num_heads, cfg.head_dim)
         k = k.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
         v = v.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
+        if cfg.qk_norm:         # before the rotation: the pool's keys
+            q = rmsnorm(q, lp["q_norm"], cfg.rms_norm_eps)
+            k = rmsnorm(k, lp["k_norm"], cfg.rms_norm_eps)
         if "rope" in lp:
             qr, kr = apply_rope(q, k, positions, inv_freq)
             q, k = jnp.where(lp["rope"], qr, q), jnp.where(lp["rope"], kr, k)
@@ -919,19 +1065,35 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
                                  window=window)
             new_cache = None
     with jax.named_scope("attn_proj"):
-        attn_out = qmm(attn.reshape(B, S, cfg.q_dim), lp["wo"])
+        attn = attn.reshape(B, S, cfg.q_dim)
+        if cfg.attn_gate:
+            attn = (attn * gate).astype(attn.dtype)
+        attn_out = qmm(attn, lp["wo"])
         if "bo" in lp:
             attn_out = attn_out + lp["bo"]
+        if cfg.post_norms:
+            attn_out = block_norm(attn_out, lp, "post_attn_norm", cfg)
         h = h + attn_out
-    if cfg.num_experts:
+    if experts:
         with jax.named_scope("moe_route"):
             x = block_norm(h, lp, "mlp_norm", cfg)
         mlp = _moe_mlp(x, lp, cfg, router_logits, row_mask, aux)
+        if cfg.num_shared_experts:
+            # every token's own, un-weighted: a dense MLP like any other
+            with jax.named_scope("mlp"), jax.named_scope("moe_shared"):
+                mlp = mlp + _dense_mlp(x, {
+                    n: lp["ws_" + n[2:]]
+                    for n in ("w_gate", "w_up", "w_down")}, cfg)
         with jax.named_scope("moe_experts"):
+            if cfg.post_norms:
+                mlp = block_norm(mlp, lp, "post_mlp_norm", cfg)
             return h + mlp, new_cache
     with jax.named_scope("mlp"):
         x = block_norm(h, lp, "mlp_norm", cfg)
-        return h + _dense_mlp(x, lp, cfg), new_cache
+        mlp = _dense_mlp(x, lp, cfg)
+        if cfg.post_norms:
+            mlp = block_norm(mlp, lp, "post_mlp_norm", cfg)
+        return h + mlp, new_cache
 
 
 def run_layers(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
@@ -939,6 +1101,9 @@ def run_layers(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
                kv_valid_len: Optional[jax.Array] = None) -> jax.Array:
     """Scan a (possibly partial) stacked layer stack over hidden states,
     no KV cache — the per-stage body for pipeline parallelism."""
+    if jax.tree.leaves(layers)[0].shape[0] != cfg.num_layers:
+        # a stage does not know which of the model's layers it holds
+        _refuse_kinds(cfg, "a partial layer stack")
     inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
                                 cfg.rope_scaling_factor)
     return _run_stack(layers, cfg, h, positions, inv_freq, kv_valid_len)[0]
@@ -1075,8 +1240,9 @@ def apply(params: Params, cfg: LlamaConfig, tokens: jax.Array,
             return gqa_attention(q, kc, vc, positions, kv_valid_len,
                                  window=lp.get("window")), (kc, vc)
 
-    h, new, _ = _run_stack(params["layers"], cfg, _embed(params, tokens),
-                           positions, inv_freq, kv_valid_len, attend, xs=xs)
+    h, new, _ = _run_model(
+        params, cfg, _embed(params, tokens, cfg.embed_scale), positions,
+        inv_freq, kv_valid_len, attend, xs=xs)
     new_cache = None if new is None else dict(zip("kv", new))
     if return_hidden:
         return unembed_norm(params, cfg, h), new_cache
@@ -1138,10 +1304,11 @@ def apply_sp(params: Params, cfg: LlamaConfig, tokens: jax.Array,
 def _refuse_kinds(cfg: LlamaConfig, fn_name: str) -> None:
     """Paths that scan the raw layer tree (ring attention, which has no
     window mask either; a pipeline stage's partial stack)."""
-    if layer_kinds(cfg) or (cfg.num_experts
-                            and cfg.moe_impl == "dropless"):
+    if layer_kinds(cfg) or cfg.embed_scale != 1.0 or (
+            cfg.num_experts and cfg.moe_impl == "dropless"):
         raise NotImplementedError(
-            f"{fn_name}: per-layer kinds and dropless experts are not "
+            f"{fn_name}: per-layer kinds, dropless experts (and so a "
+            f"second stack) and an embedding multiplier are not "
             f"supported here")
 
 

@@ -36,7 +36,13 @@ QTensor = dict[str, jax.Array]
 # Weights quantized by quantize_params; norms/embeddings stay high precision
 # (embed doubles as the tied lm_head input and is gather-bound, not
 # matmul-bound).
-_QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+# The matmul weights of a layer stack that quantize (``wz``: the
+# attention gate; ``ws_*``: a shared expert — dense matrices like the
+# rest). Routed expert stacks (L, E, K, N) stay as they are, and so do
+# the router, its bias and every norm.
+_QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "wz", "w_gate", "w_up",
+                     "w_down", "ws_gate", "ws_up", "ws_down")
+_LAYER_STACKS = ("layers", "dense_layers")
 
 
 def is_quantized(w: Any) -> bool:
@@ -264,14 +270,17 @@ def quantize_params(params: Any, mode: str = "int8",
         return quantize_tensor(w, 8 if mode == "int8" else 4)
 
     out = dict(params)
-    layers = dict(params["layers"])
-    for key in _QUANT_LAYER_KEYS:
-        # MoE expert tensors (L,E,K,N) keep full precision for now — the
-        # expert einsums contract differently than plain matmul.
-        if (key in layers and not is_quantized(layers[key])
-                and layers[key].ndim <= 3):
-            layers[key] = quant(layers[key])
-    out["layers"] = layers
+    for stack in _LAYER_STACKS:
+        if stack not in params:
+            continue
+        layers = dict(params[stack])
+        for key in _QUANT_LAYER_KEYS:
+            # MoE expert tensors (L,E,K,N) keep full precision for now —
+            # the expert einsums contract differently than plain matmul.
+            if (key in layers and not is_quantized(layers[key])
+                    and layers[key].ndim <= 3):
+                layers[key] = quant(layers[key])
+        out[stack] = layers
     if "lm_head" in out and not is_quantized(out["lm_head"]):
         out["lm_head"] = quant(out["lm_head"])
     return out

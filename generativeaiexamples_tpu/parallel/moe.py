@@ -177,14 +177,19 @@ def dropless_block_rows(n_tokens: int) -> int:
     return min(64, max(16, -(-n_tokens // 16) * 16))
 
 
-def route_sorted(router_logits: jax.Array, k: int, bm: int,
-                 row_mask: jax.Array | None = None) -> dict:
+def route_sorted(select: jax.Array, k: int, bm: int,
+                 row_mask: jax.Array | None = None,
+                 weigh: jax.Array | None = None) -> dict:
     """Top-k routing laid out for a grouped product: the T*k assignments
     sorted by expert, each expert's run padded to whole ``bm``-row blocks.
 
-    router_logits: (T, E). ``row_mask`` (T,) bool: rows that route
-    nowhere (idle decode slots) — they touch no expert. Returns
-      weight        (T, k) f32   softmax over the chosen k
+    Choice and weight need not come from one score: ``select`` (T, E)
+    decides which k experts a token gets, ``weigh`` (T, E) float32 what
+    each chosen one weighs, as given (``router_scores``). ``weigh``
+    None: the softmax over the chosen k of ``select``, the router's
+    logits. ``row_mask`` (T,) bool: rows that route nowhere (idle
+    decode slots) — they touch no expert. Returns
+      weight        (T, k) f32   of the chosen k
       token         (R,)         source token of each padded row
       valid         (R,) bool    padded rows that hold an assignment
       row_of        (T, k)       padded row of each assignment
@@ -193,10 +198,13 @@ def route_sorted(router_logits: jax.Array, k: int, bm: int,
       touched       ()           distinct experts with at least one row
     with R = NB * bm, NB = T*k // bm + E (static).
     """
-    T, E = router_logits.shape
+    T, E = select.shape
     A = T * k
-    w, idx = jax.lax.top_k(router_logits, k)                    # (T, k)
-    weight = jax.nn.softmax(w.astype(jnp.float32), axis=-1)
+    w, idx = jax.lax.top_k(select, k)                           # (T, k)
+    if weigh is None:
+        weight = jax.nn.softmax(w.astype(jnp.float32), axis=-1)
+    else:
+        weight = jnp.take_along_axis(weigh, idx, axis=1)
     expert = idx.reshape(A).astype(jnp.int32)                   # token-major
     # A counting sort, not ``argsort``: a claim's rank among its expert's
     # claims is a running count down its expert's column (token order is
@@ -231,6 +239,30 @@ def route_sorted(router_logits: jax.Array, k: int, bm: int,
             "row_of": jnp.where(claimed, row_of, 0).reshape(T, k),
             "block_expert": block_expert, "n_blocks": bend[-1],
             "touched": jnp.sum(counts > 0).astype(jnp.float32)}
+
+
+def router_scores(logits: jax.Array, lp: dict[str, jax.Array],
+                  cfg: LlamaConfig) -> tuple:
+    """``(select, weigh)`` for ``route_sorted`` from a router's logits
+    (T, E) float32. Softmax scoring: the logits, and None (the softmax
+    over the chosen). Sigmoid scoring: each expert's own score; the
+    stored ``router_bias`` moves the choice only ("selection") or the
+    scores themselves ("scores")."""
+    if cfg.router_score_func == "softmax":
+        return logits, None
+    scores = jax.nn.sigmoid(logits)
+    biased = scores + lp["router_bias"] if cfg.router_bias else scores
+    return biased, biased if cfg.router_bias == "scores" else scores
+
+
+def scale_chosen(weight: jax.Array, cfg: LlamaConfig) -> jax.Array:
+    """Sigmoid scores of the chosen k (T, k), normalised over them and
+    scaled as the configuration says; zero rows (idle) stay zero."""
+    if cfg.router_score_func == "softmax":
+        return weight
+    if cfg.router_norm_topk:
+        weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
+    return weight * cfg.router_scale
 
 
 def block_loop_ffn(x_pad: jax.Array, block_expert: jax.Array,
@@ -287,9 +319,12 @@ def dropless_moe_ffn(x: jax.Array, router_logits: jax.Array,
                             for n in ("w_gate", "w_up", "w_down"))
     li = jnp.asarray(lp.get("layer_index", 0), jnp.int32)
     with jax.named_scope("moe_route"):
+        select, weigh = router_scores(
+            router_logits.reshape(T, cfg.num_experts), lp, cfg)
         rt = route_sorted(
-            router_logits.reshape(T, cfg.num_experts), k, bm,
-            None if row_mask is None else jnp.repeat(row_mask, S))
+            select, k, bm,
+            None if row_mask is None else jnp.repeat(row_mask, S), weigh)
+        rt["weight"] = scale_chosen(rt["weight"], cfg)
         x_pad = jnp.where(rt["valid"][:, None], x_flat[rt["token"]], 0)
 
     ffn = (grouped_ffn.grouped_expert_ffn

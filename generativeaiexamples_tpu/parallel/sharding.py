@@ -49,47 +49,68 @@ def llama_param_specs(cfg: LlamaConfig, mesh: Mesh) -> Specs:
     kv_tp = tp if tp and cfg.num_kv_heads % mesh.shape["tp"] == 0 else None
     q_tp = tp if tp and cfg.num_heads % mesh.shape["tp"] == 0 else None
 
-    layers: Specs = {
-        "attn_norm": P(pp, None),
-        "mlp_norm": P(pp, None),
-        "wq": P(pp, None, q_tp),
-        "wk": P(pp, None, kv_tp),
-        "wv": P(pp, None, kv_tp),
-        "wo": P(pp, q_tp, None),
-    }
-    # GPT-Next/Nemotron extras (norm biases, projection biases): biases
-    # shard like their projection's output dim.
-    if cfg.norm == "layernorm1p":
-        layers["attn_norm_b"] = P(pp, None)
-        layers["mlp_norm_b"] = P(pp, None)
-    if cfg.attn_bias:
-        layers.update({"bq": P(pp, q_tp), "bk": P(pp, kv_tp),
-                       "bv": P(pp, kv_tp), "bo": P(pp, None)})
-    if cfg.num_experts:
-        layers.update({
-            "router": P(pp, None, None),
-            "w_gate": P(pp, ep, None, tp),
-            "w_up": P(pp, ep, None, tp),
-            "w_down": P(pp, ep, tp, None),
-        })
-    elif cfg.mlp == "squared_relu":
-        layers.update({
-            "w_up": P(pp, None, tp),
-            "w_down": P(pp, tp, None),
-        })
-        if cfg.mlp_bias:
-            layers.update({"b_up": P(pp, tp), "b_down": P(pp, None)})
-    else:
-        layers.update({
-            "w_gate": P(pp, None, tp),
-            "w_up": P(pp, None, tp),
-            "w_down": P(pp, tp, None),
-        })
+    def stack(experts: bool) -> Specs:
+        """One layer stack's specs: the expert stack, or a dense one."""
+        layers: Specs = {
+            "attn_norm": P(pp, None),
+            "mlp_norm": P(pp, None),
+            "wq": P(pp, None, q_tp),
+            "wk": P(pp, None, kv_tp),
+            "wv": P(pp, None, kv_tp),
+            "wo": P(pp, q_tp, None),
+        }
+        norms = ["attn_norm", "mlp_norm"]
+        if cfg.attn_gate:           # a column of the gate per attn column
+            layers["wz"] = P(pp, None, q_tp)
+        if cfg.qk_norm:             # (L, hd): shared by the heads
+            layers.update({"q_norm": P(pp, None), "k_norm": P(pp, None)})
+        if cfg.post_norms:
+            norms += ["post_attn_norm", "post_mlp_norm"]
+            layers.update({n: P(pp, None) for n in norms[2:]})
+        # GPT-Next/Nemotron extras (norm biases, projection biases):
+        # biases shard like their projection's output dim.
+        if cfg.norm == "layernorm1p":
+            layers.update({n + "_b": P(pp, None) for n in norms})
+        if cfg.attn_bias:
+            layers.update({"bq": P(pp, q_tp), "bk": P(pp, kv_tp),
+                           "bv": P(pp, kv_tp), "bo": P(pp, None)})
+        if experts:
+            layers.update({
+                "router": P(pp, None, None),
+                "w_gate": P(pp, ep, None, tp),
+                "w_up": P(pp, ep, None, tp),
+                "w_down": P(pp, ep, tp, None),
+            })
+            if cfg.router_bias:
+                layers["router_bias"] = P(pp, None)
+            if cfg.num_shared_experts:  # dense: every device's tokens
+                layers.update({
+                    "ws_gate": P(pp, None, tp),
+                    "ws_up": P(pp, None, tp),
+                    "ws_down": P(pp, tp, None),
+                })
+        elif cfg.mlp == "squared_relu":
+            layers.update({
+                "w_up": P(pp, None, tp),
+                "w_down": P(pp, tp, None),
+            })
+            if cfg.mlp_bias:
+                layers.update({"b_up": P(pp, tp), "b_down": P(pp, None)})
+        else:
+            layers.update({
+                "w_gate": P(pp, None, tp),
+                "w_up": P(pp, None, tp),
+                "w_down": P(pp, tp, None),
+            })
+        return layers
+
     specs: Specs = {
         "embed": P(tp, None),
-        "layers": layers,
+        "layers": stack(bool(cfg.num_experts)),
         "final_norm": P(None),
     }
+    if cfg.num_dense_layers:        # the leading dense stack
+        specs["dense_layers"] = stack(False)
     if cfg.norm == "layernorm1p":
         specs["final_norm_b"] = P(None)
     if not cfg.tie_word_embeddings:
