@@ -246,6 +246,13 @@ _STATS_TEMPLATE = {
     # layer streams a step). 0 for every other model.
     "experts_touched_sum": 0.0,
     "experts_touched_rounds": 0,
+    # The fused tail's candidate merge (ops/fused_sampler.py
+    # _merge_tile): the sum over SAMPLED decode rounds of the share, in
+    # percent, of a step's vocabulary tiles whose merge could not prove
+    # its pre-selection and sorted the whole tile, and the rounds that
+    # reported one. 0 for greedy rounds and the materialized tail.
+    "tail_resort_pct_sum": 0.0,
+    "tail_resort_pct_rounds": 0,
 }
 
 # The process's program build log (utils/compile_cache.py), read into
@@ -1025,12 +1032,14 @@ class Engine:
 
     def _tail_sample(self, params, ha, key, *, temp, top_k, top_p,
                      rep_pen, seen_words, banned_words, ban_tok, ban_hit,
-                     greedy: bool):
+                     greedy: bool, stats: bool = False):
         """One fused unembed+sample call over already-normed hidden rows
         ``ha`` (rows, D), routed to the single-chip tile stream or — on
         a tp mesh — the sharded stream whose per-chip carries merge with
         one small collective (ops/fused_sampler.py). Traced inside the
-        decode/verify round programs."""
+        decode/verify round programs. ``stats`` (sampled streams): also
+        the share of the tiles whose candidate merge sorted the tile
+        whole."""
         mcfg = self.model_cfg
         V = mcfg.vocab_size
         if self._tail_sharded:
@@ -1042,14 +1051,14 @@ class Engine:
                 V, hn=ha, key=key, temp=temp, top_k=top_k, top_p=top_p,
                 rep_pen=rep_pen, seen_words=seen_words,
                 banned_words=banned_words, ban_tok=ban_tok,
-                ban_hit=ban_hit, greedy=greedy)
+                ban_hit=ban_hit, greedy=greedy, stats=stats)
         return fused_unembed_sample(
             lambda t0, tile: llama.lm_head_tile(params, mcfg, ha, t0,
                                                 tile),
             V, key=key, temp=temp, top_k=top_k, top_p=top_p,
             rep_pen=rep_pen, seen_words=seen_words,
             banned_words=banned_words, ban_tok=ban_tok, ban_hit=ban_hit,
-            greedy=greedy)
+            greedy=greedy, stats=stats)
 
     def _tail_verify(self, params, ha, key, u, *, temp, top_k, top_p,
                      rep_pen, seen_words, banned_words, draft_ids,
@@ -1633,6 +1642,7 @@ class Engine:
         def make_round(window: int, steps: int, greedy: bool, ba: int):
             fused = self._fused_tail
             V = mcfg.vocab_size
+            stat_names = self._round_stat_names(greedy)
 
             def decode_round(params, state, key, act_idx):
                 """K decode steps fused in one dispatch; returns (K, B)
@@ -1651,8 +1661,14 @@ class Engine:
                 merge — see _tail_sample). The materialized tail remains
                 for ENGINE_FUSED_SAMPLER=0 / downgraded geometries and
                 as the parity oracle; the greedy variant of either tail
-                is a pure argmax (no vocab sort / no sampling noise)."""
+                is a pure argmax (no vocab sort / no sampling noise).
+
+                Where the program has scalars to report beside its
+                tokens (``_round_stat_names``) it returns ``(tokens,
+                {name: scalar})``, each the mean over the steps that had
+                a row to decode."""
                 def body(st, key_k):
+                    step_stats = {}
                     pos, active = st["pos"], st["active"]
                     page_of = jnp.take_along_axis(
                         st["table"], (pos // page)[:, None], axis=1)[:, 0]
@@ -1686,7 +1702,11 @@ class Engine:
                             rep_pen=st["rep_pen"][act_idx],
                             seen_words=st["seen"][act_idx],
                             banned_words=st["banned"][act_idx],
-                            ban_tok=tail, ban_hit=hit, greedy=greedy)
+                            ban_tok=tail, ban_hit=hit, greedy=greedy,
+                            stats=not greedy)
+                        if not greedy:
+                            tok_a, resort = tok_a
+                            step_stats["tail_resort_pct"] = 100.0 * resort
                         # padding indices (== B) drop on scatter; rows not
                         # in act_idx are inactive, so their (unused) token
                         # defaults to 0 and every update below masks on
@@ -1731,19 +1751,23 @@ class Engine:
                                              tok[:, None]], axis=1),
                             st["recent"]))
                     if aux:
-                        return new_st, (emitted, aux[0]["experts_touched"],
+                        step_stats["experts_touched"] = \
+                            aux[0]["experts_touched"]
+                    if step_stats:
+                        return new_st, (emitted, step_stats,
                                         jnp.any(active))
                     return new_st, emitted
 
                 state, toks = jax.lax.scan(body, state,
                                            jax.random.split(key, steps))
                 state = dict(state, cache=self._pin_cache(state["cache"]))
-                if self._moe_stats:
+                if stat_names:
                     # mean over the steps that had a row to decode
-                    toks, touched, live = toks
-                    n = jnp.sum(live)
-                    return state, (toks, jnp.sum(
-                        jnp.where(live, touched, 0.0)) / jnp.maximum(n, 1))
+                    toks, step_stats, live = toks
+                    n = jnp.maximum(jnp.sum(live), 1)
+                    return state, (toks, {
+                        name: jnp.sum(jnp.where(live, v, 0.0)) / n
+                        for name, v in step_stats.items()})
                 return state, toks
             return decode_round
 
@@ -1889,7 +1913,7 @@ class Engine:
                             jnp.repeat(state["temp"], S),
                             jnp.repeat(state["top_k"], S),
                             jnp.repeat(state["top_p"], S),
-                            draft_r, tile=choose_tile(V))
+                            draft_r, tile=choose_tile(V, sampled=True))
                     acc_g = acc_r.reshape(B, S)
                     out_g = out_r.reshape(B, S)
                 # Longest agreed prefix, then the correction/bonus token
@@ -1972,6 +1996,16 @@ class Engine:
         self._round_fns: dict[tuple[int, int, bool], object] = {}
         self._verify_fns: dict[tuple, object] = {}
         self._chunk_fns: dict[tuple, object] = {}
+
+    def _round_stat_names(self, greedy: bool) -> tuple[str, ...]:
+        """The scalars a decode round program returns beside its tokens
+        (``RoundRecord`` attributes): the experts a dropless model's
+        rows touched, and the fused tail's share of whole-sort tiles —
+        of a SAMPLED round only; a greedy round has no candidate merge
+        and returns nothing new."""
+        return ((("experts_touched",) if self._moe_stats else ())
+                + (("tail_resort_pct",)
+                   if self._fused_tail and not greedy else ()))
 
     def _round_fn(self, window: int, steps: int, greedy: bool, ba: int):
         key = (window, steps, greedy, ba)
@@ -3520,17 +3554,19 @@ class Engine:
                         if kind == "verify":
                             _, members, toks_dev, acc_dev, drafted, _ = item
                             accs = np.asarray(acc_dev)  # blocks off-thread
-                            touched = None
+                            round_stats = {}
                         else:
-                            _, members, toks_dev, touched, _ = item
+                            _, members, toks_dev, round_stats, _ = item
                             accs = drafted = None
                         # (K, B); blocks off-thread
                         toks = np.asarray(toks_dev)
-                        if touched is not None:
-                            touched = float(np.asarray(touched))
+                        if round_stats:
+                            round_stats = {k: float(np.asarray(v))
+                                           for k, v in round_stats.items()}
                             with self._stats_lock:
-                                self._stats["experts_touched_sum"] += touched
-                                self._stats["experts_touched_rounds"] += 1
+                                for k, v in round_stats.items():
+                                    self._stats[f"{k}_sum"] += v
+                                    self._stats[f"{k}_rounds"] += 1
                     wait = ph.seconds
                     self._bump("harvest_wait_ms", wait * 1e3)
                     self._bump("harvest_rounds")
@@ -3573,8 +3609,7 @@ class Engine:
                         rec, tokens=sum(emitted.values()),
                         spec_accepted=accepted,
                         harvest_wait_ms=wait * 1e3,
-                        emit_ms=ph.seconds * 1e3,
-                        experts_touched=touched or 0.0)
+                        emit_ms=ph.seconds * 1e3, **round_stats)
                     with self._pipe_lock:
                         # Guarded by the generation check just above: a
                         # worker disowned during the readback must not
@@ -4457,9 +4492,9 @@ class Engine:
         act[:len(members)] = sorted(members)
         new_state, toks = self._round_fn(window, steps, greedy, ba)(
             self.params, self._state, key, jnp.asarray(act))
-        touched = None
-        if self._moe_stats:
-            toks, touched = toks
+        round_stats = {}
+        if self._round_stat_names(greedy):
+            toks, round_stats = toks
         self._guard_live()  # reset() may have run while the round compiled
         self._state = new_state
         if self._fused_tail:
@@ -4473,8 +4508,8 @@ class Engine:
             # the round's tokens already on the host instead of paying a
             # blocking readback per round.
             toks.copy_to_host_async()
-            if touched is not None:
-                touched.copy_to_host_async()
+            for v in round_stats.values():
+                v.copy_to_host_async()
         except Exception:  # noqa: BLE001 — optional fast path
             pass
         if rec is not None:
@@ -4500,7 +4535,7 @@ class Engine:
             if depth > self._stats["dispatch_depth_peak"]:
                 self._stats["dispatch_depth_peak"] = depth
         self._assert_harvestable(toks)
-        self._harvest_q.put(("round", members, toks, touched, rec))
+        self._harvest_q.put(("round", members, toks, round_stats, rec))
         self._bump("decode_steps", steps)
 
     def _step_weight_bytes(self, rows: int) -> float:

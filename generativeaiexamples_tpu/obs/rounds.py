@@ -167,7 +167,7 @@ class RoundRecord:
         # execution (harvest thread)
         "harvest_wait_ms", "first_readback_ms", "emit_ms",
         "tokens_emitted", "first_tokens", "spec_accepted",
-        "experts_touched",
+        "experts_touched", "tail_resort_pct",
         # finalization
         "device_ms", "round_ms", "bw_util", "drift_ratio", "done",
         # bookkeeping
@@ -233,6 +233,11 @@ class RoundRecord:
         # of this round: a scalar the decode program returns beside its
         # tokens (harvest thread). 0 without dropless experts.
         self.experts_touched = 0.0
+        # Share, in percent, of a decode step's vocabulary tiles whose
+        # candidate merge sorted the whole tile (ops/fused_sampler.py
+        # _merge_tile), mean over the round's steps: a scalar a SAMPLED
+        # round's program returns (harvest thread). 0 for a greedy one.
+        self.tail_resort_pct = 0.0
         self.device_ms = 0.0
         self.round_ms = 0.0
         self.bw_util = 0.0
@@ -284,6 +289,7 @@ class RoundRecord:
                 "pages_touched": self.pages_touched,
                 "kv_pages_skipped": round(self.kv_pages_skipped, 2),
                 "experts_touched": round(self.experts_touched, 2),
+                "tail_resort_pct": round(self.tail_resort_pct, 2),
                 "kv_restore_pages": self.kv_restore_pages,
                 "hbm_bytes_est": self.hbm_bytes,
                 "bw_util": round(self.bw_util, 4),
@@ -377,7 +383,8 @@ class RoundRecorder:
                       tokens: int = 0, spec_accepted: int = 0,
                       harvest_wait_ms: float = 0.0,
                       emit_ms: float = 0.0,
-                      experts_touched: float = 0.0) -> None:
+                      experts_touched: float = 0.0,
+                      tail_resort_pct: float = 0.0) -> None:
         """One harvested device output of this round (harvest thread).
         The last part — once the scheduler has sealed the expected
         count — finalizes the record."""
@@ -389,6 +396,8 @@ class RoundRecorder:
         rec.emit_ms += float(emit_ms)
         if experts_touched:
             rec.experts_touched = float(experts_touched)
+        if tail_resort_pct:
+            rec.tail_resort_pct = float(tail_resort_pct)
         finalize = False
         with self._lock:
             rec._done_parts += 1

@@ -21,6 +21,16 @@ running state across tiles:
   from the candidate set alone, with an exact running logsumexp for the
   top-p mass. Full penalized logits never exist in any buffer.
 
+The candidate merge *selects before it sorts* (``_merge_tile``): a tile
+of thousands brings a handful of entrants, so each tile is reduced to
+the few largest elements of each of its strided buckets, only carry +
+winners are sorted, and one count — no element outside the winners
+reaches the new ``cand_k``-th value — proves the small sort lost
+nothing; where it fails the whole tile is sorted under a ``lax.cond``,
+as every tile was before. The carry is bit for bit the whole sort's
+either way, so every exactness statement below holds unchanged; the
+sampled streams report how often the whole sort ran (``stats``).
+
 Tensor-parallel serving (``fused_unembed_sample_tp`` /
 ``fused_verify_sample_tp``): the same stream runs SHARDED over the
 mesh's ``tp`` axis — each chip streams only its own vocab shard's
@@ -54,26 +64,43 @@ import jax.numpy as jnp
 from .sampling import MASK_BITS, NEG_INF, unpack_mask
 
 DEFAULT_TILE = 4096
+# The sampled and verify streams' tile. Wider than the greedy stream's:
+# a sampled tile pays a Gumbel field, a logsumexp, three running maxima
+# and a candidate merge on top of its slice of the head, and on a v5e
+# the 256000-column stream of 16 rows read 3.32 ms a step without any
+# merge at 64 tiles of 4000, 2.61 at 20 of 12800, 2.55 at 8 of 32000
+# (chip, PR 33); a (16, 16000) float32 transient is 1 MB.
+DEFAULT_SAMPLE_TILE = 16384
 DEFAULT_CAND_K = 64
+_LANES = 128      # a vector register's lanes: the bucket count's unit
+_PASSES = 4       # winners taken from each bucket of a tile
+_NO_ID = jnp.iinfo(jnp.int32).max
 
 
-def default_tile() -> int:
-    return int(os.environ.get("SAMPLER_TILE", str(DEFAULT_TILE)))
+def default_tile(sampled: bool = False) -> int:
+    """The tile target: ``SAMPLER_TILE`` where set (both streams), else
+    the greedy stream's default or the sampled streams' own."""
+    return int(os.environ.get("SAMPLER_TILE", str(
+        DEFAULT_SAMPLE_TILE if sampled else DEFAULT_TILE)))
 
 
 def default_cand_k() -> int:
     return int(os.environ.get("SAMPLER_CAND_K", str(DEFAULT_CAND_K)))
 
 
-def choose_tile(vocab_size: int, target: int | None = None) -> int:
-    """Largest divisor of ``vocab_size`` that is <= ``target`` and a
+def choose_tile(vocab_size: int, target: int | None = None, *,
+                sampled: bool = False) -> int:
+    """The vocabulary tile of a stream (``sampled``: the sampling and
+    verify streams, whose default target is their own — the noise is
+    laid out by tile, so an oracle takes the tile of the stream it
+    checks). Largest divisor of ``vocab_size`` that is <= ``target`` and a
     multiple of 32 (so each tile covers whole mask words and the word
     slice is a contiguous dynamic_slice, not a gather) — or, where that
     is under an eighth of the target, the smallest such divisor above
     it (see below). Falls back to a
     single whole-vocab tile (tiny or 32-indivisible vocabs only — real
     vocabs are 32-divisible and always admit a 32-aligned divisor)."""
-    target = max(1, min(target or default_tile(), vocab_size))
+    target = max(1, min(target or default_tile(sampled), vocab_size))
     if vocab_size % MASK_BITS == 0:
         for t in range(target - target % MASK_BITS, 0, -MASK_BITS):
             if vocab_size % t == 0:
@@ -142,6 +169,108 @@ def _penalize_tile(logits, t0, tile, *, seen_words, banned_words, rep_pen,
     return lf
 
 
+# ------------------------------------------------------- candidate merge
+#
+# The running top-``cand_k`` of the sampling and verify streams. The carry
+# ``(cv, ci, cp)`` holds the largest raw scaled values seen so far in
+# descending order with their vocabulary ids and Gumbel perturbations;
+# among equal values the carry comes before the tile and a lower id
+# before a higher one: the order of the oracle's stable argsort.
+
+
+def _full_merge(cv, ci, cp, scaled, idb, pert, cand_k: int):
+    """Sort the carry and the whole tile: ``lax.top_k`` over the
+    carry-first concatenation, stable, so the tie order is the
+    concatenation's. Exact by definition, and a sort of ``cand_k +
+    tile`` numbers a row to keep ``cand_k`` of them."""
+    av = jnp.concatenate([cv, scaled], axis=-1)
+    ai = jnp.concatenate([ci, idb], axis=-1)
+    ap = jnp.concatenate([cp, pert], axis=-1)
+    cv, sel = jax.lax.top_k(av, cand_k)
+    return (cv, jnp.take_along_axis(ai, sel, axis=-1),
+            jnp.take_along_axis(ap, sel, axis=-1))
+
+
+def select_plan(tile: int, cand_k: int) -> tuple[int, int] | None:
+    """``(buckets, passes)`` of the pre-selection for a tile of this
+    width, or ``None`` where the tile is no wider than a few ``cand_k``
+    and sorting it whole IS the small sort. From shapes known at trace
+    time only: whole lanes of buckets, at least two a candidate, so that
+    a stream's first tile (all ``cand_k`` entrants at once) seldom puts
+    more than ``_PASSES`` of them into one."""
+    buckets = -(-2 * cand_k // _LANES) * _LANES
+    small = cand_k + _PASSES * buckets
+    return (buckets, _PASSES) if tile >= 4 * small else None
+
+
+def _bucket_winner(a, b):
+    """The larger value of two (value, id, perturbation) triples, the
+    lower id among equals: the reducer of a bucket's maximum."""
+    (av, ai, ap), (bv, bi, bp) = a, b
+    first = (av > bv) | ((av == bv) & (ai < bi))
+    return (jnp.where(first, av, bv), jnp.where(first, ai, bi),
+            jnp.where(first, ap, bp))
+
+
+def _merge_tile(cv, ci, cp, scaled, idb, pert, cand_k: int):
+    """One tile into the carry: ``((cv, ci, cp), resorted)`` with
+    ``resorted`` a bool scalar, whether this tile took the whole sort.
+
+    *Select before sorting.* A tile of thousands brings a handful of
+    entrants (tile ``t`` of a stream about ``cand_k / t`` a row), so the
+    tile is first cut into ``buckets`` strided buckets (element ``j``
+    lies in bucket ``j % buckets``: a reduction over whole vregs, no
+    lane crosses another) and each bucket gives its ``passes`` largest
+    elements with their ids and perturbations; only the carry and those
+    winners are ordered, by a two-key sort on (value descending, id
+    ascending) — the carry's ids are all below the tile's, so this is
+    the carry-first order of :func:`_full_merge`. *The proof, one count:*
+    with ``thr2`` the new ``cand_k``-th value, the small sort lost
+    nothing iff no element of the tile outside the winners is
+    ``>= thr2`` (an equal one might have won the tie). Where any row
+    fails it — several entrants in one bucket: the first tiles of a
+    stream, where nearly everything enters, and ties — the whole batch
+    takes :func:`_full_merge` under a ``lax.cond``. Either way the carry
+    is bit for bit what :func:`_full_merge` alone leaves."""
+    R, tile = scaled.shape
+    plan = select_plan(tile, cand_k)
+    if plan is None:
+        return _full_merge(cv, ci, cp, scaled, idb, pert,
+                           cand_k), jnp.bool_(False)
+    buckets, passes = plan
+    # element j of the tile lies in bucket j % buckets; a ragged last
+    # stride is filled with what never wins
+    depth = -(-tile // buckets)
+    fill = ((0, 0), (0, depth * buckets - tile))
+    x, i3, p3 = (jnp.pad(a, fill, constant_values=c).reshape(
+        R, depth, buckets) for a, c in (
+            (scaled, -jnp.inf), (idb, _NO_ID), (pert, -jnp.inf)))
+    init = (jnp.float32(-jnp.inf), jnp.int32(_NO_ID),
+            jnp.float32(-jnp.inf))
+    won = []
+    for n in range(passes):
+        if n:   # take the last winner out of its bucket
+            x = jnp.where(i3 == won[-1][1][:, None, :], -jnp.inf, x)
+        won.append(jax.lax.reduce((x, i3, p3), init, _bucket_winner, (1,)))
+    wv = jnp.concatenate([w[0] for w in won], axis=-1)
+    # the negated value ascending is the value descending, and lax.sort
+    # holds -0.0 equal to 0.0 as top_k's comparison does
+    nv, ni, npert = jax.lax.sort(
+        (jnp.concatenate([-cv, -wv], axis=-1),
+         jnp.concatenate([ci] + [w[1] for w in won], axis=-1),
+         jnp.concatenate([cp] + [w[2] for w in won], axis=-1)),
+        dimension=1, num_keys=2)
+    fast = (-nv[:, :cand_k], ni[:, :cand_k], npert[:, :cand_k])
+    thr2 = fast[0][:, -1:]
+    lost = (jnp.sum(scaled >= thr2, axis=-1)
+            != jnp.sum(wv >= thr2, axis=-1))
+    resorted = jnp.any(lost)
+    return jax.lax.cond(
+        resorted,
+        lambda: _full_merge(cv, ci, cp, scaled, idb, pert, cand_k),
+        lambda: fast), resorted
+
+
 # --------------------------------------------------------- tile streams
 #
 # The scan bodies shared by the single-chip and tp-sharded paths. Each
@@ -178,13 +307,14 @@ def _greedy_stream(masked_tile, n_tiles: int, tile: int, B: int):
 def _sample_stream(masked_tile, noise_tile, key, tf, n_tiles: int,
                    tile: int, B: int, cand_k: int):
     """Sampling carry over the tile stream. Returns
-    ``(cv, ci, cp, lse, bpert, bpid, braw, brid)``: the top-``cand_k``
-    raw scaled values with ids + Gumbel perturbations, the running
-    logsumexp, the untruncated Gumbel-max winner, and the running greedy
-    argmax (for temp<=0 / top_k==1 rows of the batch)."""
+    ``(cv, ci, cp, lse, bpert, bpid, braw, brid, resort)``: the
+    top-``cand_k`` raw scaled values with ids + Gumbel perturbations, the
+    running logsumexp, the untruncated Gumbel-max winner, the running
+    greedy argmax (for temp<=0 / top_k==1 rows of the batch), and the
+    share of the tiles whose merge took the whole sort (0..1)."""
 
     def body(carry, t):
-        cv, ci, cp, lse, bpert, bpid, braw, brid = carry
+        cv, ci, cp, lse, bpert, bpid, braw, brid, n_resort = carry
         t0, lf = masked_tile(t)
         ids = t0 + jnp.arange(tile, dtype=jnp.int32)
         idb = jnp.broadcast_to(ids, lf.shape)
@@ -207,17 +337,12 @@ def _sample_stream(masked_tile, noise_tile, key, tf, n_tiles: int,
         ug = rb > braw
         braw, brid = jnp.where(ug, rb, braw), jnp.where(ug, ri, brid)
         # candidate merge: keep the top-cand_k raw scaled values seen so
-        # far, with their ids and Gumbel perturbations. Concatenating
-        # carry-first preserves ascending-id order among equal values —
-        # the same tie order as the oracle's stable argsort.
+        # far, with their ids and Gumbel perturbations
         with jax.named_scope("tail_select"):
-            av = jnp.concatenate([cv, scaled], axis=-1)
-            ai = jnp.concatenate([ci, idb], axis=-1)
-            ap = jnp.concatenate([cp, pert], axis=-1)
-            cv, sel = jax.lax.top_k(av, cand_k)
-            ci = jnp.take_along_axis(ai, sel, axis=-1)
-            cp = jnp.take_along_axis(ap, sel, axis=-1)
-        return (cv, ci, cp, lse, bpert, bpid, braw, brid), None
+            (cv, ci, cp), resorted = _merge_tile(cv, ci, cp, scaled, idb,
+                                                 pert, cand_k)
+        return (cv, ci, cp, lse, bpert, bpid, braw, brid,
+                n_resort + resorted), None
 
     init = (jnp.full((B, cand_k), -jnp.inf, jnp.float32),
             jnp.zeros((B, cand_k), jnp.int32),
@@ -226,10 +351,11 @@ def _sample_stream(masked_tile, noise_tile, key, tf, n_tiles: int,
             jnp.full((B,), -jnp.inf, jnp.float32),
             jnp.zeros((B,), jnp.int32),
             jnp.full((B,), -jnp.inf, jnp.float32),
-            jnp.zeros((B,), jnp.int32))
+            jnp.zeros((B,), jnp.int32),
+            jnp.int32(0))
     carry, _ = jax.lax.scan(body, init,
                             jnp.arange(n_tiles, dtype=jnp.int32))
-    return carry
+    return carry[:-1] + (carry[-1] / n_tiles,)
 
 
 def _verify_stream(masked_tile, noise_tile, key, tf, draft_ids,
@@ -271,15 +397,10 @@ def _verify_stream(masked_tile, noise_tile, key, tf, draft_ids,
                                  axis=1)[:, 0]
         un = nb > npert
         npert, npid = jnp.where(un, nb, npert), jnp.where(un, ni, npid)
-        # candidate merge (identical to the sampling stream: carry-first
-        # preserves the oracle's stable tie order)
+        # candidate merge (the sampling stream's)
         with jax.named_scope("tail_select"):
-            av = jnp.concatenate([cv, scaled], axis=-1)
-            ai = jnp.concatenate([ci, idb], axis=-1)
-            ap = jnp.concatenate([cp, pert], axis=-1)
-            cv, sel = jax.lax.top_k(av, cand_k)
-            ci = jnp.take_along_axis(ai, sel, axis=-1)
-            cp = jnp.take_along_axis(ap, sel, axis=-1)
+            (cv, ci, cp), _ = _merge_tile(cv, ci, cp, scaled, idb, pert,
+                                          cand_k)
         return (cv, ci, cp, lse, braw, brid, sd, sfound, npert, npid), None
 
     init = (jnp.full((R, cand_k), -jnp.inf, jnp.float32),
@@ -405,15 +526,15 @@ def _merge_lse(axis: str, lse):
     return jax.nn.logsumexp(jax.lax.all_gather(lse, axis), axis=0)
 
 
-def _shard_geometry(mesh, axis: str, vocab_size: int,
-                    tile: int | None) -> tuple[int, int, int]:
+def _shard_geometry(mesh, axis: str, vocab_size: int, tile: int | None,
+                    sampled: bool) -> tuple[int, int, int]:
     n_shards = int(mesh.shape[axis])
     if not tp_shardable(vocab_size, n_shards):
         raise ValueError(
             f"vocab_size={vocab_size} cannot shard over {axis}="
             f"{n_shards} in whole 32-token mask words")
     v_local = vocab_size // n_shards
-    t = choose_tile(v_local, tile)
+    t = choose_tile(v_local, tile, sampled=sampled)
     return n_shards, v_local, t
 
 
@@ -425,7 +546,7 @@ def fused_unembed_sample(tile_logits_fn, vocab_size: int, *, key, temp,
                          top_k, top_p, rep_pen, seen_words, banned_words,
                          ban_tok=None, ban_hit=None, greedy: bool = False,
                          tile: int | None = None,
-                         cand_k: int | None = None) -> jax.Array:
+                         cand_k: int | None = None, stats: bool = False):
     """Stream the vocab in tiles and sample without materializing it.
 
     tile_logits_fn(t0, tile) -> (B, tile) f32 raw logits for tokens
@@ -434,8 +555,11 @@ def fused_unembed_sample(tile_logits_fn, vocab_size: int, *, key, temp,
     the semantics of ``ops.sampling.sample`` applied to the penalized
     logits (greedy when ``greedy`` — trace-time, the engine's all-greedy
     round variant — no noise, no candidate carry, just a running argmax).
+    With ``stats`` (sampled streams only) returns ``(tokens, resort)``,
+    ``resort`` the share of the stream's tiles, 0..1, whose candidate
+    merge took the whole sort (:func:`_merge_tile`).
     """
-    tile = choose_tile(vocab_size, tile)
+    tile = choose_tile(vocab_size, tile, sampled=not greedy)
     cand_k = cand_k or default_cand_k()
     n_tiles = vocab_size // tile
     probe = jax.eval_shape(lambda: tile_logits_fn(jnp.int32(0), tile))
@@ -454,11 +578,12 @@ def fused_unembed_sample(tile_logits_fn, vocab_size: int, *, key, temp,
         return best_id
 
     tf = jnp.maximum(temp, 1e-6)[:, None]
-    cv, ci, cp, lse, _, bpid, _, brid = _sample_stream(
+    cv, ci, cp, lse, _, bpid, _, brid, resort = _sample_stream(
         masked_tile, lambda t: t, key, tf, n_tiles, tile, B, cand_k)
-    return _finalize_sample(cv, ci, cp, lse, bpid, brid, temp=temp,
-                            top_k=top_k, top_p=top_p,
-                            vocab_size=vocab_size, cand_k=cand_k)
+    tok = _finalize_sample(cv, ci, cp, lse, bpid, brid, temp=temp,
+                           top_k=top_k, top_p=top_p,
+                           vocab_size=vocab_size, cand_k=cand_k)
+    return (tok, resort) if stats else tok
 
 
 @jax.named_scope("tail")
@@ -467,7 +592,7 @@ def fused_unembed_sample_tp(mesh, axis: str, head_tree, head_specs,
                             temp, top_k, top_p, rep_pen, seen_words,
                             banned_words, ban_tok=None, ban_hit=None,
                             greedy: bool = False, tile: int | None = None,
-                            cand_k: int | None = None) -> jax.Array:
+                            cand_k: int | None = None, stats: bool = False):
     """:func:`fused_unembed_sample` with the vocab stream SHARDED over
     the mesh's ``axis``: each chip streams only its local lm_head
     shard's tiles and the per-shard carries merge with one small
@@ -481,11 +606,12 @@ def fused_unembed_sample_tp(mesh, axis: str, head_tree, head_specs,
     with a matching tile size the sharded stream is sample-exact against
     the single-chip stream and the materialized oracle. The returned
     (B,) tokens are replicated on every chip — harvest-safe by
-    construction."""
+    construction. ``stats`` as in :func:`fused_unembed_sample`: the mean
+    over the shards of each one's share of whole-sort tiles."""
     from jax.sharding import PartitionSpec as P
 
     n_shards, v_local, tile = _shard_geometry(mesh, axis, vocab_size,
-                                              tile)
+                                              tile, not greedy)
     cand_k = cand_k or default_cand_k()
     n_tiles = v_local // tile
     B = hn.shape[0]
@@ -513,22 +639,24 @@ def fused_unembed_sample_tp(mesh, axis: str, head_tree, head_specs,
             best, best_id = _greedy_stream(masked_tile, n_tiles, tile, B)
             _, win_id = _merge_running_max(axis, best, best_id)
             return win_id
-        cv, ci, cp, lse, bpert, bpid, braw, brid = _sample_stream(
+        cv, ci, cp, lse, bpert, bpid, braw, brid, resort = _sample_stream(
             masked_tile, lambda t: tile_base + t, key, tf, n_tiles, tile,
             B, cand_k)
         cv, ci, cp = _merge_candidates(axis, cv, ci, cp, cand_k)
         lse = _merge_lse(axis, lse)
         _, bpid = _merge_running_max(axis, bpert, bpid)
         _, brid = _merge_running_max(axis, braw, brid)
-        return _finalize_sample(cv, ci, cp, lse, bpid, brid, temp=temp,
-                                top_k=top_k, top_p=top_p,
-                                vocab_size=vocab_size, cand_k=cand_k)
+        tok = _finalize_sample(cv, ci, cp, lse, bpid, brid, temp=temp,
+                               top_k=top_k, top_p=top_p,
+                               vocab_size=vocab_size, cand_k=cand_k)
+        return (tok, jax.lax.pmean(resort, axis)) if stats else tok
 
     args = (head_tree, hn, temp, top_k, top_p, rep_pen, seen_words,
             banned_words) + ((ban_tok, ban_hit) if has_ban else ())
     in_specs = (head_specs,) + (P(),) * (len(args) - 1)
     return jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=P(), check_vma=False)(*args)
+                         out_specs=(P(), P()) if stats and not greedy
+                         else P(), check_vma=False)(*args)
 
 
 @jax.named_scope("tail")
@@ -569,7 +697,7 @@ def fused_verify_sample(tile_logits_fn, vocab_size: int, *, key, u, temp,
     :func:`verify_reference_tiled`; a draft outside the candidate set
     of a truncated row has p = 0 there (it cannot be in the kept set).
     """
-    tile = choose_tile(vocab_size, tile)
+    tile = choose_tile(vocab_size, tile, sampled=True)
     cand_k = cand_k or default_cand_k()
     n_tiles = vocab_size // tile
     probe = jax.eval_shape(lambda: tile_logits_fn(jnp.int32(0), tile))
@@ -610,7 +738,7 @@ def fused_verify_sample_tp(mesh, axis: str, head_tree, head_specs,
     from jax.sharding import PartitionSpec as P
 
     n_shards, v_local, tile = _shard_geometry(mesh, axis, vocab_size,
-                                              tile)
+                                              tile, True)
     cand_k = cand_k or default_cand_k()
     n_tiles = v_local // tile
     R = hn.shape[0]

@@ -475,3 +475,284 @@ def test_tp_shardable_geometry_rule():
     assert not tp_shardable(130, 2)      # 65 % 32 != 0
     assert not tp_shardable(320, 3)      # uneven split
     assert not tp_shardable(320, 1)      # single chip: not a tp stream
+
+
+# ------------------------------------------------ the candidate merge
+#
+# ``_merge_tile`` selects before it sorts (bucket winners, a small
+# two-key sort, one count that proves nothing was lost, the whole sort
+# where the count fails). Its carry must be the whole sort's, bit for bit.
+
+MERGE_ROWS, MERGE_TILE, MERGE_TILES = 4, 2500, 5    # a ragged last stride
+
+
+def _merge_case(case, cand_k):
+    """(values (n_tiles, R, tile) f32, what the case must show) for one
+    stream of tiles; ids ascend over the stream as the tile scans' do."""
+    R, T, N = MERGE_ROWS, MERGE_TILE, MERGE_TILES
+    from generativeaiexamples_tpu.ops.fused_sampler import select_plan
+    buckets, _ = select_plan(T, cand_k)
+    rng = np.random.default_rng(7)
+    flat = np.arange(N * T, dtype=np.float32)
+    if case == "random":
+        x = rng.standard_normal((N, R, T)).astype(np.float32)
+    elif case == "ascending":       # every element is over the carry's
+        x = np.broadcast_to(flat.reshape(N, 1, T), (N, R, T)).copy()
+    elif case == "descending":      # nothing enters after the first tile
+        x = np.broadcast_to(-flat.reshape(N, 1, T), (N, R, T)).copy()
+    elif case == "all_equal":
+        x = np.full((N, R, T), 1.25, np.float32)
+    elif case == "many_ties":       # five distinct values, signed zeros
+        x = rng.integers(-2, 3, (N, R, T)).astype(np.float32)
+        x[x == 0] = rng.choice(np.float32([0.0, -0.0]), (x == 0).sum())
+    elif case == "neg_inf_rows":
+        # a banned tile, banned rows, -inf logits, and a row with fewer
+        # finite values than the carry holds: an unfilled carry
+        x = rng.standard_normal((N, R, T)).astype(np.float32)
+        x[1] = NEG_INF
+        x[:, 0] = NEG_INF
+        x[:, 1] = -np.inf
+        x[:, 2] = -np.inf
+        x[2, 2, :cand_k // 2] = 1.0
+        x[::2, 3, ::3] = -np.inf
+    elif case == "one_bucket":
+        # in every tile the largest values fill ONE strided bucket, far
+        # more of them than a bucket hands over: the proof has to fail,
+        # and the whole sort has to repair it
+        x = rng.standard_normal((N, R, T)).astype(np.float32)
+        for n in range(N):
+            b = (5 * n + 3) % buckets
+            x[n, :, b::buckets] += 100.0 * (n + 1)
+    else:
+        raise AssertionError(case)
+    return jnp.asarray(x)
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(np.uint32) if a.dtype == np.float32 else a
+
+
+@pytest.mark.parametrize("cand_k", [8, 64])
+@pytest.mark.parametrize("case", [
+    "random", "ascending", "descending", "all_equal", "many_ties",
+    "neg_inf_rows", "one_bucket"])
+def test_merge_tile_is_the_whole_sort_bit_for_bit(case, cand_k):
+    """After EVERY tile the carry of the selecting merge — values, ids,
+    perturbations, order, the ties carry-first then by ascending id — is
+    the carry ``lax.top_k`` over carry + whole tile leaves."""
+    from generativeaiexamples_tpu.ops.fused_sampler import (
+        _full_merge, _merge_tile, select_plan)
+    R, T, N = MERGE_ROWS, MERGE_TILE, MERGE_TILES
+    assert select_plan(T, cand_k) is not None, "the case must pre-select"
+    x = _merge_case(case, cand_k)
+    pert = x + jax.random.gumbel(jax.random.key(3), x.shape, jnp.float32)
+    carry = (jnp.full((R, cand_k), -jnp.inf, jnp.float32),
+             jnp.zeros((R, cand_k), jnp.int32),
+             jnp.full((R, cand_k), -jnp.inf, jnp.float32))
+    merge = jax.jit(_merge_tile, static_argnums=6)
+    resorted = []
+    for n in range(N):
+        idb = jnp.broadcast_to(n * T + jnp.arange(T, dtype=jnp.int32),
+                               (R, T))
+        want = _full_merge(*carry, x[n], idb, pert[n], cand_k)
+        got, slow = merge(*carry, x[n], idb, pert[n], cand_k)
+        for name, g, w in zip(("values", "ids", "perturbations"), got,
+                              want):
+            np.testing.assert_array_equal(
+                _bits(g), _bits(w), err_msg=f"{case} tile {n}: {name}")
+        resorted.append(bool(slow))
+        carry = want
+    if case in ("all_equal", "one_bucket"):
+        assert all(resorted), resorted      # the proof fails: whole sort
+    if case in ("random", "descending"):
+        assert not all(resorted), resorted  # the small sort does serve
+
+
+@pytest.mark.parametrize("path", ["sample", "verify"])
+def test_real_shape_stream_is_exact_against_its_oracle(path):
+    """The shape the chip serves: 16 rows, a 256000-token vocabulary in
+    the sampled stream's own tile, ``cand_k`` 64, top_p 0.9 — through
+    the selecting merge (the shape pre-selects), the same tokens and the
+    same verdicts as the materialized oracle under the same key."""
+    from generativeaiexamples_tpu.ops.fused_sampler import (
+        fused_verify_sample, select_plan, verify_reference_tiled)
+    R, vocab, cand_k = 16, 256000, 64
+    tile = choose_tile(vocab, sampled=True)
+    assert tile % 128 == 0 and vocab // tile >= 8
+    assert select_plan(tile, cand_k) is not None
+    ks = jax.random.split(jax.random.key(33), 6)
+    # a chat model's head: a few dozen likely tokens over a flat floor,
+    # so that 0.9 of the mass lies inside the candidate carry
+    logits = jax.random.normal(ks[0], (R, vocab), jnp.float32)
+    hot = jax.random.randint(ks[1], (R, 40), 0, vocab)
+    logits = logits.at[jnp.arange(R)[:, None], hot].add(
+        jax.random.uniform(ks[2], (R, 40), minval=9.0, maxval=15.0))
+    seen = jnp.zeros((R, vocab), bool).at[
+        jnp.arange(R)[:, None], hot[:, :6]].set(True)
+    banned = jnp.zeros((R, vocab), bool).at[:, hot[0, 7]].set(True)
+    temp = jnp.full((R,), 0.7, jnp.float32).at[3].set(0.0)
+    top_k = jnp.zeros((R,), jnp.int32).at[5].set(20)
+    top_p = jnp.full((R,), 0.9, jnp.float32)
+    rep = jnp.full((R,), 1.15, jnp.float32)
+    pen = _oracle_penalize(logits, seen, banned, rep)
+    common = dict(key=ks[3], temp=temp, top_k=top_k, top_p=top_p,
+                  rep_pen=rep, seen_words=pack_mask(seen),
+                  banned_words=pack_mask(banned), cand_k=cand_k)
+    if path == "sample":
+        got, resort = jax.jit(lambda lg: fused_unembed_sample(
+            _tile_fn(lg), vocab, stats=True, **common))(logits)
+        want = sample_reference_tiled(pen, ks[3], temp, top_k, top_p,
+                                      tile)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        assert 0.0 <= float(resort) < 0.5   # the small sort does serve
+        return
+    # drafts: the likeliest token, a likely one, an unlikely one, none
+    drafts = jnp.where(jnp.arange(R) % 4 == 0, jnp.argmax(pen, -1),
+                       jnp.where(jnp.arange(R) % 4 == 1, hot[:, 9],
+                                 jnp.where(jnp.arange(R) % 4 == 2, 123,
+                                           -1))).astype(jnp.int32)
+    u = jax.random.uniform(ks[4], (R,))
+    acc, out = jax.jit(lambda lg: fused_verify_sample(
+        _tile_fn(lg), vocab, u=u, draft_ids=drafts, **common))(logits)
+    acc_w, out_w = verify_reference_tiled(pen, ks[3], u, temp, top_k,
+                                          top_p, drafts, tile=tile)
+    np.testing.assert_array_equal(np.asarray(acc), np.asarray(acc_w))
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(out_w))
+    assert bool(acc.any()) and not bool(acc.all())
+
+
+# ------------------- the round programs: what the tail holds, and counts
+
+
+def _walk_prims(jaxpr, out, scope=""):
+    """(primitive, scope path) of every equation, nested bodies
+    included: a body's name stack is relative to its equation's."""
+    for eqn in jaxpr.eqns:
+        path = f"{scope}/{eqn.source_info.name_stack}"
+        out.append((eqn.primitive.name, path))
+        for val in eqn.params.values():
+            for sub in _jaxprs_in(val):
+                _walk_prims(sub, out, path)
+
+
+@pytest.fixture(scope="module")
+def select_engine():
+    """A tiny engine whose vocabulary tiles are wide enough for the
+    merge to pre-select (two tiles of 2080 at ``cand_k`` 8), and whose
+    ``lm_head`` is all zeros: every logit ties with every other, the
+    proof fails in every tile, so a sampled round reads 100 %."""
+    from generativeaiexamples_tpu.engine import Engine, EngineConfig
+    from generativeaiexamples_tpu.models import llama
+    from generativeaiexamples_tpu.models.configs import LlamaConfig
+    from generativeaiexamples_tpu.models.tokenizer import ByteTokenizer
+    from generativeaiexamples_tpu.obs.rounds import RoundRecorder
+    from generativeaiexamples_tpu.ops.fused_sampler import select_plan
+
+    vocab, tile, cand_k = 4160, 2080, 8
+    assert select_plan(tile, cand_k) is not None
+    mp = pytest.MonkeyPatch()
+    mp.setenv("SAMPLER_TILE", str(tile))
+    mp.setenv("SAMPLER_CAND_K", str(cand_k))
+    cfg = LlamaConfig(vocab_size=vocab, hidden_size=64,
+                      intermediate_size=128, num_layers=2, num_heads=4,
+                      num_kv_heads=2, head_dim=16,
+                      max_position_embeddings=256,
+                      tie_word_embeddings=False)
+    params = llama.init_params(cfg, jax.random.key(0), dtype=jnp.float32)
+    params["lm_head"] = jnp.zeros_like(params["lm_head"])
+    eng = Engine(params, cfg, ByteTokenizer(), EngineConfig(
+        max_slots=4, max_input_length=64, max_output_length=32,
+        prefill_buckets=(16, 32, 64), dtype="float32", max_queue=8))
+    eng.rounds = RoundRecorder()
+    eng.start()
+    try:
+        yield eng, vocab, tile
+    finally:
+        eng.stop()
+        mp.undo()
+
+
+@pytest.mark.parametrize("greedy", [True, False], ids=["greedy", "sampled"])
+def test_round_tail_holds_a_sort_only_where_it_samples(select_engine,
+                                                       greedy):
+    """The greedy round's tail is a running argmax: no ``sort``,
+    ``top_k``, ``cond`` or ``while`` under scope ``tail``. The sampled
+    round's selection (the small sort and the ``cond`` around the whole
+    one) lies under ``tail_select``, and still no (rows, V) array
+    exists anywhere in it."""
+    eng, vocab, tile = select_engine
+    ba = 2
+    fn = eng._make_round(eng._windows[0], 2, greedy, ba)
+    jaxpr = jax.make_jaxpr(fn)(
+        eng.params, eng._state, jax.random.key(1),
+        jnp.zeros((ba,), jnp.int32)).jaxpr
+    prims = []
+    _walk_prims(jaxpr, prims)
+    in_tail = {p for p, path in prims if "/tail" in path}
+    assert in_tail, "the trace must see the tail"
+    heavy = {"sort", "top_k", "cond", "while"}
+    if greedy:
+        assert not in_tail & heavy, in_tail & heavy
+    else:
+        in_select = {p for p, path in prims if "tail_select" in path}
+        assert {"sort", "cond", "top_k"} <= in_select, in_select
+        # nothing of the selection escapes its scope
+        assert not {p for p, path in prims
+                    if p in heavy and "/tail" in path
+                    and "tail_select" not in path}
+    avals = []
+    _walk_avals(jaxpr, avals)
+    assert not [a.shape for a in avals
+                if getattr(a, "ndim", 0) >= 2 and a.shape[-1] == vocab]
+    assert any(getattr(a, "ndim", 0) >= 2 and a.shape[-1] == tile
+               for a in avals)
+
+
+def _serve(eng, sampling):
+    before = {r.round_id for r in eng.rounds.records()}
+    stream = eng.submit([5, 6, 7, 8], sampling)
+    for _ in stream:
+        pass
+    assert stream.finish_reason == "length"
+    return [r for r in eng.rounds.records()
+            if r.round_id not in before and r.decode_slots]
+
+
+def test_tail_resort_pct_is_absent_from_a_greedy_round(select_engine):
+    from generativeaiexamples_tpu.engine import SamplingParams
+    eng, _, _ = select_engine
+    assert eng._round_stat_names(True) == ()
+    before = eng.stats["tail_resort_pct_rounds"]
+    recs = _serve(eng, SamplingParams(max_tokens=5, top_k=1,
+                                      ignore_eos=True))
+    assert recs and all(r.tail_resort_pct == 0.0 for r in recs)
+    assert eng.stats["tail_resort_pct_rounds"] == before
+
+
+def test_tail_resort_pct_reaches_record_stats_and_metrics(select_engine):
+    """A sampled round's program returns the share of whole-sort tiles
+    beside its tokens: on the round record, in ``engine.stats`` (sum
+    and rounds) and as ``/metrics`` gauges."""
+    from generativeaiexamples_tpu.engine import SamplingParams
+    from generativeaiexamples_tpu.obs import metrics as obs_metrics
+    eng, _, _ = select_engine
+    assert eng._round_stat_names(False) == ("tail_resort_pct",)
+    s0, n0 = (eng.stats["tail_resort_pct_sum"],
+              eng.stats["tail_resort_pct_rounds"])
+    recs = _serve(eng, SamplingParams(max_tokens=5, temperature=0.8,
+                                      top_k=0, top_p=0.9,
+                                      ignore_eos=True))
+    # all-equal logits: the proof fails in both tiles of every step
+    assert recs and all(r.tail_resort_pct == pytest.approx(100.0)
+                        for r in recs)
+    assert recs[0].to_dict()["outcome"]["tail_resort_pct"] == 100.0
+    stats = eng.stats
+    assert stats["tail_resort_pct_rounds"] - n0 == len(recs)
+    assert stats["tail_resort_pct_sum"] - s0 == pytest.approx(
+        100.0 * len(recs))
+    reg = obs_metrics.Registry()
+    obs_metrics.record_engine_stats(stats, reg)
+    text = reg.render_prometheus()
+    assert "engine_tail_resort_pct_sum" in text
+    assert "engine_tail_resort_pct_rounds" in text
