@@ -62,6 +62,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 
 from ..models import llama
 from ..models.configs import LlamaConfig
+from ..models.kv_cache import kv_cache_of
 from ..models.tokenizer import Tokenizer
 from ..obs import flight as obs_flight
 from ..obs import rounds as obs_rounds
@@ -74,7 +75,7 @@ from ..ops.fused_sampler import (choose_tile, fused_unembed_sample,
 from ..ops.sampling import (apply_repetition_penalty, mask_words,
                             pack_mask, pack_mask_np, sample, seen_mask,
                             set_token_bits, unpack_mask)
-from ..parallel.sharding import (llama_param_specs, paged_kv_cache_spec,
+from ..parallel.sharding import (llama_param_specs,
                                  shard_params)
 from ..utils import compile_cache, faults
 from ..utils.errors import (ConfigError, EngineError, RoleMismatchError,
@@ -253,6 +254,12 @@ _STATS_TEMPLATE = {
     # reported one. 0 for greedy rounds and the materialized tail.
     "tail_resort_pct_sum": 0.0,
     "tail_resort_pct_rounds": 0,
+    # An expert share (models/configs.py ``experts_held``): the sum over
+    # decode rounds of the mean assignments a step's rows made to the
+    # experts this tree holds, a layer, and the rounds that reported one.
+    # 0 where the tree holds every expert.
+    "local_assignments_sum": 0.0,
+    "local_assignments_rounds": 0,
 }
 
 # The process's program build log (utils/compile_cache.py), read into
@@ -275,7 +282,8 @@ def engine_stat_keys() -> tuple[str, ...]:
                "sched_prefill_share", "sched_prefill_fill",
                "spec_acceptance_rate", "spec_tokens_per_step",
                "sched_cost_drift_ratio",
-               "kv_tier_host_pages", "kv_restore_hit_rate", "uptime_s")
+               "kv_tier_host_pages", "kv_restore_hit_rate",
+               "kv_bytes_per_token", "uptime_s")
             + _BUILD_LOG_KEYS
             + tuple(CacheStats().snapshot()) + ("prefix_cache_pages",))
 
@@ -675,6 +683,7 @@ class Engine:
         self._downgrades: list[dict] = []
         self._dtype = jnp.dtype(cfg.dtype)
         self._kv_quant = bool(cfg.kv_quant)
+        self._refuse_unsupported(model_cfg, cfg, mesh)
         B, page = cfg.max_slots, cfg.page_size
         self._pmax = _ceil_div(cfg.max_cache_len, page)
 
@@ -1130,6 +1139,46 @@ class Engine:
         state["cache"] = self._alloc_pool()
         return state
 
+    @staticmethod
+    def _refuse_unsupported(mcfg: LlamaConfig, cfg: "EngineConfig",
+                            mesh: Optional[Mesh]) -> None:
+        """What cannot take a latent pool (``kv_lora_rank``) or an expert
+        share (``experts_held``) yet says so by name, here, before
+        anything is built (docs/support-matrix.md lists them)."""
+        axes = dict(mesh.shape) if mesh is not None else {}
+        host = (os.environ.get("KV_HOST_POOL_TOKENS", "")
+                or cfg.kv_host_pool_tokens or 0)
+        role = (os.environ.get("ENGINE_ROLE", "") or cfg.role or "unified"
+                ).strip().lower()
+        refused = []
+        if mcfg.kv_lora_rank:
+            refused += [
+                (cfg.kv_quant, "an int8 KV pool (kv_quant): the latent "
+                 "row is key and value at once and has no scale plane"),
+                (int(axes.get("tp", 1)) > 1, "a tp mesh: the latent is "
+                 "common to all heads, so tp has nothing of the pool to "
+                 "split and the decode kernel no shard rule"),
+                (int(axes.get("sp", 1)) > 1, "an sp mesh (ring attention "
+                 "has no latent form)"),
+                (int(host) > 0, "the host KV tier (kv_host_pool_tokens / "
+                 "KV_HOST_POOL_TOKENS): engine/kv_tier.py keys and "
+                 "stacks host blobs on per-head K and V"),
+                (role != "unified", f"role {role!r}: prefill/decode "
+                 f"handoff ships kv_tier blobs"),
+            ]
+        if mcfg.experts_held:
+            refused += [
+                (int(axes.get("ep", 1)) > 1 or int(axes.get("tp", 1)) > 1,
+                 "an expert share under an ep or tp mesh: the share IS "
+                 "one device's experts"),
+            ]
+        for hit, why in refused:
+            if hit:
+                raise ConfigError(
+                    f"this model (kv_lora_rank={mcfg.kv_lora_rank}, "
+                    f"experts_held={mcfg.experts_held}) does not support "
+                    f"{why}")
+
     def _alloc_pool(self) -> dict:
         """Zeroed pool leaves, each born in its final sharding and
         (kernel path) row-major layout. The pool is sized to nearly all
@@ -1141,8 +1190,8 @@ class Engine:
             mcfg, self._n_pages, self.cfg.page_size, self._dtype,
             quantized=self._kv_quant))
         if mesh is not None:
-            specs = paged_kv_cache_spec(mcfg, mesh,
-                                        quantized=self._kv_quant)
+            specs = kv_cache_of(mcfg).pool_spec(mesh,
+                                                quantized=self._kv_quant)
             shardings = {k: NamedSharding(mesh, specs[k]) for k in leaves}
         else:
             dev = SingleDeviceSharding(self._devices()[0])
@@ -1180,12 +1229,8 @@ class Engine:
         quantization happens at insert, so sizing the prefill headroom
         with pooled bytes would under-reserve by ~2x in quant mode."""
         mcfg = self.model_cfg
-        if pooled and self._kv_quant:
-            # int8 K+V rows + one bf16 scale each (ops/kv_quant.py)
-            return (mcfg.num_layers * mcfg.num_kv_heads
-                    * 2 * (mcfg.head_dim + 2))
-        return (mcfg.num_layers * mcfg.num_kv_heads * mcfg.head_dim
-                * 2 * self._dtype.itemsize)
+        return mcfg.num_layers * kv_cache_of(mcfg).token_bytes(
+            self._dtype.itemsize, quantized=pooled and self._kv_quant)
 
     def _pool_shard_factor(self) -> int:
         """How many ways the page pool is actually split across devices —
@@ -1249,7 +1294,7 @@ class Engine:
         # (the 16-slot throughput collapse, VERDICT r3 weak #2).
         gather = 0 if self._use_kernel else (
             cfg.max_slots * self._pmax * cfg.page_size
-            * mcfg.num_kv_heads * mcfg.head_dim * 2 * self._dtype.itemsize)
+            * kv_cache_of(mcfg).token_bytes(self._dtype.itemsize))
         # int8-KV insert quantizes the bucket per-row; XLA sequences the
         # K and V transforms, so ~one bucket's f32 copy is live at once
         quant = bucket_cache if self._kv_quant else 0
@@ -1483,6 +1528,9 @@ class Engine:
         # tier turned into restored pages instead of recompute.
         tier = self._kv_tier
         out["kv_tier_host_pages"] = tier.store.pages if tier else 0
+        # what one cached token costs the pool, all layers: the
+        # configuration's cache object says (models/kv_cache.py)
+        out["kv_bytes_per_token"] = self._kv_bytes_per_token()
         lookups = out.get("prefix_cache_lookups", 0)
         out["kv_restore_hit_rate"] = (
             round(out["kv_tier_restore_hits"] / lookups, 4)
@@ -1505,7 +1553,7 @@ class Engine:
         page = cfg.page_size
         eos = int(self.tokenizer.eos_id)
         B = cfg.max_slots
-        L = mcfg.num_layers
+        kvc = kv_cache_of(mcfg)
 
         sp_mesh = (self.mesh is not None
                    and int(dict(self.mesh.shape).get("sp", 1)) > 1)
@@ -1554,7 +1602,7 @@ class Engine:
                 first_tok = sample(last, key, temp[None], top_k[None],
                                    top_p[None])[0]
             seen = pack_mask(seen[0].at[first_tok].set(True))  # (Wn,) u32
-            return cache["k"], cache["v"], first_tok, seen
+            return (*(cache[n] for n in kvc.leaves), first_tok, seen)
 
         def insert(state, k_new, v_new, slot, length, first_tok,
                    temp, top_k, top_p, rep_pen, seen, banned,
@@ -1563,35 +1611,8 @@ class Engine:
             slot. ``row``: (Pmax,) physical page per logical page, padded
             with 0 (trash) — bucket overhang beyond the allocated extent
             lands in the trash page."""
-            S = k_new.shape[2]
-            nb = S // page
-            dest = row[:nb]
-            cache = state["cache"]
-            # (L,1,S,KV,hd) -> (L, nb, KV, page, hd): pool layout keeps KV
-            # ahead of page (see llama.init_paged_kv_cache).
-            kp = k_new.reshape(L, nb, page, mcfg.num_kv_heads,
-                               mcfg.head_dim).swapaxes(2, 3)
-            vp = v_new.reshape(L, nb, page, mcfg.num_kv_heads,
-                               mcfg.head_dim).swapaxes(2, 3)
-            if self._kv_quant:
-                from ..ops.kv_quant import quantize_rows
-                kq, ks = quantize_rows(kp)   # scales: (L, nb, KV, page)
-                vq, vs = quantize_rows(vp)
-                cache = {
-                    "k": cache["k"].at[:, dest].set(kq),
-                    "v": cache["v"].at[:, dest].set(vq),
-                    "ks": cache["ks"].at[:, dest].set(
-                        ks.astype(cache["ks"].dtype)),
-                    "vs": cache["vs"].at[:, dest].set(
-                        vs.astype(cache["vs"].dtype)),
-                }
-            else:
-                cache = {
-                    "k": cache["k"].at[:, dest].set(
-                        kp.astype(cache["k"].dtype)),
-                    "v": cache["v"].at[:, dest].set(
-                        vp.astype(cache["v"].dtype)),
-                }
+            dest = row[:k_new.shape[2] // page]
+            cache = kvc.insert_pages(state["cache"], k_new, v_new, dest)
             # Device-side finish state: a slot whose first token already
             # ends it (eos, or max_tokens == 1) never activates.
             active = (remaining > 0) & ~((first_tok == eos) & eos_ok)
@@ -1750,9 +1771,8 @@ class Engine:
                             jnp.concatenate([st["recent"][:, 1:],
                                              tok[:, None]], axis=1),
                             st["recent"]))
-                    if aux:
-                        step_stats["experts_touched"] = \
-                            aux[0]["experts_touched"]
+                    if aux:     # the layers' scalars (llama.layer_stat_names)
+                        step_stats.update(aux[0])
                     if step_stats:
                         return new_st, (emitted, step_stats,
                                         jnp.any(active))
@@ -2003,7 +2023,8 @@ class Engine:
         rows touched, and the fused tail's share of whole-sort tiles —
         of a SAMPLED round only; a greedy round has no candidate merge
         and returns nothing new."""
-        return ((("experts_touched",) if self._moe_stats else ())
+        return ((llama.layer_stat_names(self.model_cfg)
+                 if self._moe_stats else ())
                 + (("tail_resort_pct",)
                    if self._fused_tail and not greedy else ()))
 
@@ -2076,7 +2097,7 @@ class Engine:
                 # executed. Its buffer is NOT part of the donated state
                 # dict — it survives the next dispatch, unlike any ref
                 # into the returned state (which donation invalidates).
-                marker = cache["k"][0, 0, 0, 0, 0]
+                marker = cache[kv_cache_of(mcfg).leaves[0]][0, 0, 0, 0, 0]
                 return dict(state,
                             cache=self._pin_cache(cache),
                             seen=self._chunk_seen(state, tokens, start,

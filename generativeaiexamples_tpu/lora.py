@@ -45,6 +45,11 @@ def init_lora(cfg: LlamaConfig, base_params: llama.Params, key: jax.Array,
               rank: int = 8, targets: Sequence[str] = DEFAULT_TARGETS,
               dtype: jnp.dtype = jnp.float32) -> LoraParams:
     """Zero-delta init: a ~ N(0, 1/K), b = 0 (the standard LoRA init)."""
+    if cfg.kv_lora_rank:
+        raise NotImplementedError(
+            "LoRA over a latent-attention model (kv_lora_rank): its "
+            "projections are already low-rank pairs and none of them is "
+            "a target here")
     lora: LoraParams = {}
     keys = jax.random.split(key, len(targets))
     for k_rng, name in zip(keys, targets):

@@ -10,6 +10,7 @@ only, examples/5_mins_rag_no_gpu/main.py:50 — here it is first-class).
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, replace
 
 
@@ -112,6 +113,43 @@ class LlamaConfig:
     attn_gate: bool = False
     post_norms: bool = False
     embed_scale: float = 1.0
+    # Latent attention (``kv_lora_rank`` > 0; every default is the model
+    # above): the block's input is projected to ONE latent row a token,
+    # ``kv_lora_rank`` values (normed) beside one rotary key part of
+    # ``qk_rope_head_dim`` values that all heads share, and that row is
+    # what the cache holds (models/kv_cache.py ``LatentKV``). A head's
+    # keys and values are the latent times ``wkv_b`` (``qk_nope_head_dim``
+    # + ``v_head_dim`` columns a head); queries come through a second
+    # low-rank pair, ``wq_a`` (``q_lora_rank``, normed) and ``wq_b``.
+    # ``head_dim`` is then qk_nope_head_dim + qk_rope_head_dim, of which
+    # only the rope part rotates, and ``num_kv_heads`` is 1: the one row.
+    #   rope_interleave: the rotary part's pairs are (2i, 2i+1), not
+    #         (i, i + half): the columns are taken apart first
+    #   rope_scaling_type "yarn": ``rope_scaling_factor`` stretches only
+    #         the frequencies that turn less than ``rope_beta_slow`` times
+    #         in ``rope_original_max`` positions, keeps those that turn
+    #         more than ``rope_beta_fast`` times and blends between
+    #         (ops/rope.py); ``rope_mscale_all_dim`` > 0 multiplies the
+    #         scores by (0.1 * it * ln(factor) + 1) ** 2
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False
+    rope_scaling_type: str = "linear"
+    rope_original_max: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # An expert share (``experts_held`` > 0; moe_impl "dropless"): this
+    # tree holds ``experts_held`` of the layer's ``num_experts`` experts,
+    # from expert ``experts_first`` on. The router keeps its
+    # ``num_experts`` columns and a token its ``num_experts_per_tok``;
+    # what falls on experts held elsewhere is dropped before the sort and
+    # the layer's output is the partial sum (parallel/moe.py).
+    experts_held: int = 0
+    experts_first: int = 0
     # How ``llama.init_params`` draws a random tree (tests, benchmarks;
     # served weights come from ``import_hf`` and ignore it):
     #   "fan_in": every matrix N(0, 1/fan_in), embedding rows of norm 1
@@ -163,6 +201,39 @@ class LlamaConfig:
                 "under moe_impl 'dropless'")
         if not 0 <= self.num_dense_layers < max(self.num_layers, 1):
             raise ValueError("num_dense_layers must leave an expert layer")
+        if self.rope_scaling_type not in ("linear", "yarn"):
+            raise ValueError(
+                f"unknown rope_scaling_type {self.rope_scaling_type!r}")
+        if self.kv_lora_rank:
+            if not (self.q_lora_rank and self.qk_nope_head_dim
+                    and self.qk_rope_head_dim and self.v_head_dim):
+                raise ValueError(
+                    "latent attention (kv_lora_rank) needs q_lora_rank, "
+                    "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+            if (self.head_dim != self.qk_nope_head_dim
+                    + self.qk_rope_head_dim or self.num_kv_heads != 1):
+                raise ValueError(
+                    "latent attention: head_dim is qk_nope_head_dim + "
+                    "qk_rope_head_dim and num_kv_heads is 1 (one latent "
+                    "row a token serves every head)")
+            if (self.sliding_window or self.rope_layers or self.qk_norm
+                    or self.attn_gate or self.attn_bias):
+                raise ValueError(
+                    "latent attention takes no window, rope pattern, q/k "
+                    "norm, output gate or bias")
+        elif self.rope_interleave:
+            raise ValueError("rope_interleave is the latent rotary part's")
+        if self.experts_held or self.experts_first:
+            if not (self.num_experts and self.moe_impl == "dropless"):
+                raise ValueError("an expert share (experts_held) needs "
+                                 "experts under moe_impl 'dropless'")
+            if not (0 < self.experts_held and 0 <= self.experts_first
+                    and self.experts_first + self.experts_held
+                    <= self.num_experts):
+                raise ValueError(
+                    f"expert share [{self.experts_first}, "
+                    f"{self.experts_first + self.experts_held}) lies "
+                    f"outside the layer's {self.num_experts} experts")
 
     def layer_pattern(self, pattern: tuple, default: int) -> tuple:
         """A 0/1 period repeated over the depth (``default`` where the
@@ -198,6 +269,22 @@ class LlamaConfig:
         if not n:
             return (("layers", 0, self.num_layers),)
         return (("dense_layers", 0, n), ("layers", n, self.num_layers - n))
+
+    @property
+    def held_experts(self) -> int:
+        """The expert matrices a layer's tree holds: all of them, or its
+        share."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def score_scale(self) -> float:
+        """What the attention scores are multiplied by: head_dim ** -0.5,
+        times YaRN's m ** 2 where the configuration has it."""
+        m = 1.0
+        if self.rope_mscale_all_dim and self.rope_scaling_factor > 1:
+            m = 0.1 * self.rope_mscale_all_dim * math.log(
+                self.rope_scaling_factor) + 1.0
+        return self.head_dim ** -0.5 * m * m
 
     @property
     def q_dim(self) -> int:
@@ -270,6 +357,28 @@ TRINITY_MINI = LlamaConfig(
     rope_layers=(1, 1, 1, 0), qk_norm=True, attn_gate=True,
     post_norms=True, embed_scale=2048 ** 0.5, weight_init="unit_stream")
 
+# A 1.04T-total / 32B-active sparse model (moonshotai Kimi-K2-Instruct
+# config.json, model_type kimi_k2; the equations are DeepseekV3's): one
+# dense layer then sixty of 384 SwiGLU experts, 8 a token by sigmoid
+# scores with a selection bias, beside one shared expert; latent
+# attention, 64 heads over one 512 + 64 wide cached row a token; YaRN
+# over the 64-wide rotary part. Whole here (a share of 1 of 1): a chip
+# of a deployment holds a share of the experts and of the layers
+# (``experts_held``; benchmarks/configs/kimi-k2-instruct.json).
+KIMI_K2 = LlamaConfig(
+    vocab_size=163840, hidden_size=7168, intermediate_size=18432,
+    moe_intermediate_size=2048, num_layers=61, num_dense_layers=1,
+    num_heads=64, num_kv_heads=1, head_dim=192,
+    max_position_embeddings=131072, rope_theta=50000.0, rms_norm_eps=1e-6,
+    num_experts=384, num_experts_per_tok=8, num_shared_experts=1,
+    moe_impl="dropless", router_score_func="sigmoid",
+    router_norm_topk=True, router_scale=2.827, router_bias="selection",
+    kv_lora_rank=512, q_lora_rank=1536, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128, rope_interleave=True,
+    rope_scaling_type="yarn", rope_scaling_factor=32.0,
+    rope_original_max=4096, rope_beta_fast=1.0, rope_beta_slow=1.0,
+    rope_mscale_all_dim=1.0, weight_init="unit_stream")
+
 # GPT-Next / Nemotron-8B (the reference's second served family:
 # ensemble_models/gptnext/, docs/rag/support_matrix.md:14 sizing;
 # nemotron_config.yaml deployment). Rotary attention, zero-centered
@@ -319,6 +428,7 @@ MODEL_REGISTRY: dict[str, LlamaConfig] = {
     "nemotron-8b-chat": NEMOTRON_8B,
     "smallthinker-21b-a3b-instruct": SMALLTHINKER_21B_A3B,
     "trinity-mini": TRINITY_MINI,
+    "kimi-k2-instruct": KIMI_K2,
     "gptnext-tiny": GPTNEXT_TINY,
     "llama-tiny": LLAMA_TINY,
     "golden-tiny": GOLDEN_TINY,

@@ -55,7 +55,34 @@ _HF_POST_NORM_LAYER_KEYS = {
     "mlp.shared_experts.up_proj.weight": ("ws_up", True),
     "mlp.shared_experts.down_proj.weight": ("ws_down", True),
 }
+# A latent-attention block (DeepseekV3's names; cfg.kv_lora_rank): two
+# low-rank pairs with a norm between, and a sigmoid router with a
+# selection bias beside shared experts. ``kv_b_proj`` is stored as its two
+# halves (``_split_kv_b``).
+_HF_LATENT_LAYER_KEYS = {
+    "self_attn.q_a_proj.weight": ("wq_a", True),
+    "self_attn.q_a_layernorm.weight": ("q_a_norm", False),
+    "self_attn.q_b_proj.weight": ("wq_b", True),
+    "self_attn.kv_a_proj_with_mqa.weight": ("wkv_a", True),
+    "self_attn.kv_a_layernorm.weight": ("kv_a_norm", False),
+    "mlp.gate.weight": ("router", True),
+    "mlp.gate.e_score_correction_bias": ("router_bias", False),
+    "mlp.shared_experts.gate_proj.weight": ("ws_gate", True),
+    "mlp.shared_experts.up_proj.weight": ("ws_up", True),
+    "mlp.shared_experts.down_proj.weight": ("ws_down", True),
+}
+_HF_KV_B = "self_attn.kv_b_proj.weight"
 _FLOAT32_LEAVES = ("router_bias",)      # a buffer the router adds in f32
+
+
+def _split_kv_b(w: np.ndarray, cfg: LlamaConfig) -> tuple:
+    """``kv_b_proj`` (H x (nope + v), R) as the tree holds it: ``wk_b``
+    (R, H x nope), every head's ``nope`` key columns, and ``wv_b`` (R,
+    H x v), its value columns — the absorbed decode reads each alone."""
+    H, nope, vd = cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+    w = w.T.reshape(w.shape[1], H, nope + vd)
+    return (w[..., :nope].reshape(w.shape[0], H * nope),
+            w[..., nope:].reshape(w.shape[0], H * vd))
 
 # Meta/fairscale checkpoint names (consolidated.*.pth). Values: (name, kind)
 # where kind marks the extra transform — "q"/"k" rows additionally need the
@@ -221,6 +248,10 @@ def params_from_named_tensors(
     hf_keys = dict(_HF_LAYER_KEYS)
     if cfg.post_norms:
         hf_keys.update(_HF_POST_NORM_LAYER_KEYS)
+    if cfg.kv_lora_rank:
+        hf_keys.update(_HF_LATENT_LAYER_KEYS)
+    # an expert share keeps the experts it holds, numbered from its first
+    first_expert, held = cfg.experts_first, cfg.held_experts
     layer_acc: dict[str, list] = {}
     top: dict[str, Any] = {}
 
@@ -228,9 +259,12 @@ def params_from_named_tensors(
         if extra is None:
             layer_acc.setdefault(name, [None] * L)[idx] = value
         else:  # MoE expert tensors: [layer][expert]
+            extra -= first_expert
+            if not 0 <= extra < held:
+                return              # an expert held elsewhere
             acc = layer_acc.setdefault(name, [None] * L)
             if acc[idx] is None:
-                acc[idx] = [None] * cfg.num_experts
+                acc[idx] = [None] * held
             acc[idx][extra] = value
 
     for key, raw in tensors:
@@ -265,6 +299,10 @@ def params_from_named_tensors(
             continue
         if rest == _HF_MOE_GATE:
             put_layer("router", idx, arr.T)
+            continue
+        if rest == _HF_KV_B and cfg.kv_lora_rank:
+            for name, half in zip(("wk_b", "wv_b"), _split_kv_b(arr, cfg)):
+                put_layer(name, idx, half)
             continue
         em = _MOE_EXPERT_RE.match(rest)
         if em:

@@ -1,0 +1,752 @@
+"""The KV cache behind ONE object, in two implementations.
+
+What the forwards of models/llama.py, the decode kernel call and the
+engine's sizing need of a cache is asked of the object that
+``kv_cache_of(cfg)`` returns, and of nothing else:
+
+- build it: the paged pool (``init_pool``) and the dense
+  absolute-position cache (``init_dense``);
+- size it: ``token_bytes`` a cached token a layer, ``page_size``;
+- write it: rows or whole pages (``write``), a dense cache's rows
+  (``put_dense``);
+- read it, which is attention: a slot's window gathered (``attend_window``:
+  the verify forward, and the decode step off the chip), a chunk's prefix
+  streamed block by block (``attend_prefix``), the tokens given alone or
+  the dense cache's rows (``attend_tokens``), and the Pallas decode kernel with the pool in the layer scan's carry
+  (``kernel_attend``);
+- shard it: ``pool_spec``.
+
+Every ``attend_*`` takes a layer's ``(q, k, v)`` as ``decoder_layer``
+hands them and returns the attention output in VALUE space,
+(B, S, H, v_head_dim), and what the layer leaves in the cache is ``(k,
+v)`` as handed:
+
+``HeadKV``  per-head keys and values. Pool ``{"k", "v"}: (L, N, KV, page,
+            hd)`` (+ ``"ks"/"vs"`` scale planes when int8); ``(k, v)`` are
+            (B, S, KV, hd).
+``LatentKV`` one latent row a token for all heads (``cfg.kv_lora_rank``).
+            ``(k, v)`` are the normed latent ``c`` (B, S, R) and the
+            rotated shared key part ``k_r`` (B, S, rope). Pool in TWO
+            leaves, ``"c": (L, N, 1, page, R)`` and ``"r": (L, N, 1,
+            rope, page)`` — the rotary part TRANSPOSED, positions on the
+            lanes: a 576-wide row is not a lane multiple (the chip pads
+            it to 640 in HBM and in VMEM), a 64-wide one is padded to
+            128, and (rope, page) is whole tiles AND the right-hand side
+            of the scores' matmul as it lies. A step reads each cached
+            row once and uses ``c`` as key and value: decode and verify
+            run ABSORBED (the query carried into the latent space through
+            ``wk_b``, ``wv_b`` applied after the sum); a chunk program
+            runs EXPANDED, its own tokens and each prefix block it reads
+            back taken through ``wk_b`` / ``wv_b`` (a 512-token chunk
+            does half the operations that way: one expansion a block
+            serves all its queries), so nothing of size prefix x heads x
+            head width exists at once.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from ..ops.attention import gqa_attention
+from ..ops.quant import matmul as qmm
+from .configs import LlamaConfig
+
+KVCache = dict[str, jax.Array]
+
+
+@functools.lru_cache(maxsize=None)
+def kv_cache_of(cfg: LlamaConfig):
+    """The configuration's cache object: what it says of its attention
+    decides, nothing else."""
+    return LatentKV(cfg) if cfg.kv_lora_rank else HeadKV(cfg)
+
+
+class HeadKV:
+    """Per-head K and V: ``{"k","v"}: (L, n_pages, KV, page, hd)``."""
+
+    leaves = ("k", "v")
+
+    def __init__(self, cfg: LlamaConfig):
+        self.cfg = cfg
+
+    # ---------------------------------------------------------------- build
+
+    def init_dense(self, batch: int, max_len: int,
+                   dtype: jnp.dtype = jnp.bfloat16) -> KVCache:
+        cfg = self.cfg
+        shape = (cfg.num_layers, batch, max_len, cfg.num_kv_heads,
+                 cfg.head_dim)
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+    def init_pool(self, n_pages: int, page_size: int,
+                  dtype: jnp.dtype = jnp.bfloat16,
+                  quantized: bool = False) -> KVCache:
+        cfg = self.cfg
+        shape = (cfg.num_layers, n_pages, cfg.num_kv_heads, page_size,
+                 cfg.head_dim)
+        if not quantized:
+            return {"k": jnp.zeros(shape, dtype),
+                    "v": jnp.zeros(shape, dtype)}
+        from ..ops.kv_quant import SCALE_DTYPE
+        return {"k": jnp.zeros(shape, jnp.int8),
+                "v": jnp.zeros(shape, jnp.int8),
+                "ks": jnp.zeros(shape[:4], SCALE_DTYPE),
+                "vs": jnp.zeros(shape[:4], SCALE_DTYPE)}
+
+    # ----------------------------------------------------------------- size
+
+    def token_bytes(self, itemsize: int, quantized: bool = False) -> int:
+        """Bytes a cached token a layer: K and V rows (int8 rows and one
+        bf16 scale each when ``quantized``: ops/kv_quant.py)."""
+        cfg = self.cfg
+        if quantized:
+            return cfg.num_kv_heads * 2 * (cfg.head_dim + 2)
+        return cfg.num_kv_heads * cfg.head_dim * 2 * itemsize
+
+    @staticmethod
+    def page_size(kv_cache: KVCache) -> int:
+        return kv_cache["k"].shape[3]
+
+    @staticmethod
+    def quantized(kv_cache: KVCache) -> bool:
+        """Whether a paged pool carries int8 rows + scale leaves."""
+        return "ks" in kv_cache
+
+    def kernel_supported(self, page: int) -> bool:
+        from ..ops.paged_attention import kernel_supported
+        cfg = self.cfg
+        return kernel_supported(page, cfg.num_heads, cfg.num_kv_heads,
+                                cfg.head_dim)
+
+    def pool_spec(self, mesh, quantized: bool = False) -> dict:
+        from ..parallel.sharding import paged_kv_cache_spec
+        return paged_kv_cache_spec(self.cfg, mesh, quantized)
+
+    # ---------------------------------------------------------------- write
+
+    def write(self, kv_cache: KVCache, new_k: jax.Array, new_v: jax.Array,
+              pages: jax.Array, offsets: Optional[jax.Array] = None
+              ) -> KVCache:
+        """The one place that writes rows into the paged pool and knows its
+        format: the layers' new K and V, stacked (L, ...) as the scan gave
+        them, are quantised when the pool has scale planes and written in
+        ONE scatter a leaf, after the scan. Two destinations:
+
+        - rows (``offsets`` given): new (L, B, S, KV, hd) to ``pages`` /
+          ``offsets`` (B, S), each token's physical page and row in it (a
+          decode step is S = 1): one (layer, flat row) index per (slot,
+          token, kv-head) over (N, KV, page) flattened. Indexed ``[:,
+          row]`` instead, the TPU compiler relayouts the WHOLE pool to
+          scatter and back (compile, PR 30).
+        - whole pages (``offsets`` None): a chunk's new (L, C, KV, hd), C
+          a page multiple, to its C / page physical ``pages``.
+        """
+        L, N, KV, page, _ = kv_cache["k"].shape
+        new = {"k": new_k, "v": new_v}
+        if self.quantized(kv_cache):
+            from ..ops.kv_quant import quantize_rows
+            new["k"], new["ks"] = quantize_rows(new_k)    # scales: (..., KV)
+            new["v"], new["vs"] = quantize_rows(new_v)
+        if offsets is None:
+            def put(pool, rows):
+                blocks = rows.reshape((L, -1, page) + rows.shape[2:])
+                return pool.at[:, pages].set(
+                    blocks.swapaxes(2, 3).astype(pool.dtype))
+        else:
+            flat_idx = ((pages[..., None] * KV + jnp.arange(KV)) * page
+                        + offsets[..., None])                   # (B, S, KV)
+            layer = jnp.arange(L)[:, None, None, None]
+
+            def put(pool, rows):
+                flat = pool.reshape((L, N * KV * page) + pool.shape[4:])
+                return flat.at[layer, flat_idx[None]].set(
+                    rows.astype(pool.dtype)).reshape(pool.shape)
+
+        with jax.named_scope("attn"):      # the KV write
+            return {name: put(kv_cache[name], rows)
+                    for name, rows in new.items()}
+
+    def insert_pages(self, kv_cache: KVCache, k_new: jax.Array,
+                     v_new: jax.Array, dest: jax.Array) -> KVCache:
+        """A prefilled bucket's dense rows (L, 1, S, KV, hd), S a page
+        multiple, into the S / page physical pages ``dest`` (the engine's
+        admission; bucket overhang past the slot's extent goes to the
+        trash page)."""
+        cfg = self.cfg
+        L, _, S = k_new.shape[:3]
+        page = self.page_size(kv_cache)
+        nb = S // page
+        # (L,1,S,KV,hd) -> (L, nb, KV, page, hd): pool layout keeps KV
+        # ahead of page (see llama.init_paged_kv_cache).
+        kp = k_new.reshape(L, nb, page, cfg.num_kv_heads,
+                           cfg.head_dim).swapaxes(2, 3)
+        vp = v_new.reshape(L, nb, page, cfg.num_kv_heads,
+                           cfg.head_dim).swapaxes(2, 3)
+        cache = kv_cache
+        if self.quantized(kv_cache):
+            from ..ops.kv_quant import quantize_rows
+            kq, ks = quantize_rows(kp)   # scales: (L, nb, KV, page)
+            vq, vs = quantize_rows(vp)
+            return {
+                "k": cache["k"].at[:, dest].set(kq),
+                "v": cache["v"].at[:, dest].set(vq),
+                "ks": cache["ks"].at[:, dest].set(
+                    ks.astype(cache["ks"].dtype)),
+                "vs": cache["vs"].at[:, dest].set(
+                    vs.astype(cache["vs"].dtype)),
+            }
+        return {
+            "k": cache["k"].at[:, dest].set(
+                kp.astype(cache["k"].dtype)),
+            "v": cache["v"].at[:, dest].set(
+                vp.astype(cache["v"].dtype)),
+        }
+
+    # ----------------------------------------------------------------- read
+
+    def window(self, kv_cache: KVCache, name: str, layer, block_table,
+               dtype):
+        """One layer's slot windows of leaf ``name`` ("k" or "v") gathered
+        from the WHOLE paged pool, (L, N, KV, page, hd) -> (B, P*page, KV,
+        hd), by (layer, page) in one step over the pool's flattened
+        leading axes (a ``pool[layer]`` first is a copy of the layer's
+        whole slab); int8 pages are dequantized via their per-row
+        scales."""
+        cfg = self.cfg
+        pool = kv_cache[name]
+        B, P = block_table.shape
+        pages = block_table + layer * pool.shape[1]
+        g = pool.reshape((-1,) + pool.shape[2:])[pages]  # (B,P,KV,page,hd)
+        if self.quantized(kv_cache):
+            from ..ops.kv_quant import dequantize_rows
+            scales = kv_cache[name + "s"]                   # (L, N, KV, page)
+            g = dequantize_rows(
+                g, scales.reshape((-1,) + scales.shape[2:])[pages], dtype)
+        return g.swapaxes(2, 3).reshape(B, P * pool.shape[3],
+                                        cfg.num_kv_heads, cfg.head_dim)
+
+    def attend_tokens(self, q, k, v, lp, positions, kv_valid_len):
+        """Attention over the keys given by absolute position: the
+        call's own tokens, or a dense cache's rows."""
+        return gqa_attention(q, k, v, positions, kv_valid_len,
+                             window=lp.get("window"))
+
+    def put_dense(self, lp, k, v, row_start):
+        """A layer's slices of the dense cache (``lp["cache_k"]`` /
+        ``lp["cache_v"]``: (B, T, KV, hd)) with this call's rows written
+        at their absolute positions (rows contiguous)."""
+        put = jax.vmap(
+            lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s, 0, 0)))
+        return put(lp["cache_k"], k, row_start), \
+            put(lp["cache_v"], v, row_start)
+
+    def attend_window(self, q, k, v, lp, kv_cache, layer, block_table,
+                      rows, positions, kv_valid_len):
+        """The verify forward's attention: the slot's window gathered,
+        the S current tokens joined in-register."""
+        def window(name, new):
+            g = self.window(kv_cache, name, layer, block_table, q.dtype)
+            # All S current tokens join the window in-register at their
+            # logical positions (their pool writes happen in the
+            # post-scan scatter); positions past the window drop on
+            # scatter — they can only belong to masked garbage rows.
+            return g.at[rows, positions].set(new.astype(g.dtype))
+
+        return gqa_attention(q, window("k", k), window("v", v), positions,
+                             kv_valid_len, window=lp.get("window"))
+
+    def attend_prefix(self, q, k, v, lp, kv_cache, block_table, start,
+                      kv_valid_len, layer):
+        """A chunk's attention: its prefix streamed from the pool block by
+        block, its own tokens in-register. B = 1."""
+        return _paged_prefix_attention(
+            q, k, v, kv_cache["k"], kv_cache["v"], kv_cache.get("ks"),
+            kv_cache.get("vs"), block_table, start, kv_valid_len,
+            self.page_size(kv_cache), self.cfg, window=lp.get("window"),
+            layer=layer)
+
+    def kernel_attend(self, kv_cache: KVCache, block_table, pos_in_win,
+                      write_page, write_offset, mesh, act_dtype):
+        """The decode step's ``attend`` over the Pallas kernel: attention
+        read and row append inside the kernel, the pool in the layer
+        scan's carry."""
+        from ..ops.paged_attention import paged_attention_decode
+        cfg = self.cfg
+        # int8-KV pools: the kernel quantizes the appended row itself, so
+        # the current token's K/V pass in compute dtype, not pool dtype.
+        dt = act_dtype if self.quantized(kv_cache) else kv_cache["k"].dtype
+        interp = jax.default_backend() != "tpu"
+
+        # ``win``: the layer's window as one more (1,) operand, only in a
+        # model that has window layers
+        def call_kernel(q, pool, ck, cv, li, tbl, lens, wp, off, *win):
+            attn, *leaves = paged_attention_decode(
+                q, pool["k"], pool["v"], tbl, lens, ck, cv, wp, off, li,
+                pool_ks=pool.get("ks"), pool_vs=pool.get("vs"),
+                interpret=interp, window=win[0] if win else None)
+            return attn, dict(zip(("k", "v", "ks", "vs"), leaves))
+
+        if mesh is not None and "tp" in mesh.shape:
+            # Pallas has no SPMD partitioning rule, so under a tp mesh the
+            # call is shard_mapped: each device runs the kernel on its own
+            # H/tp query heads and KV/tp pool shard — table/positions are
+            # replicated, and the append lands in the local shard. This is
+            # what keeps the v5e-8 TP serving config off the ~10x-slower
+            # gather path (VERDICT r3 weak #3).
+            from jax.sharding import PartitionSpec as P
+            heads = P(None, "tp", None)                     # q, ck, cv, attn
+            pool_specs = {
+                name: P(None, None, "tp", *(None,) * (leaf.ndim - 3))
+                for name, leaf in kv_cache.items()}
+            call_kernel = jax.shard_map(
+                call_kernel, mesh=mesh,
+                in_specs=(heads, pool_specs, heads, heads)
+                + (P(),) * (5 + any(cfg.layer_windows)),
+                out_specs=(heads, pool_specs), check_vma=False)
+
+        def attend(q, k, v, lp, li, pool):
+            win = (lp["window"][None],) if "window" in lp else ()
+            attn, pool = call_kernel(
+                q[:, 0], pool, k[:, 0].astype(dt), v[:, 0].astype(dt), li,
+                block_table, pos_in_win, write_page, write_offset, *win)
+            return attn[:, None], pool
+
+        return attend
+
+
+def _paged_prefix_attention(q, k_self, v_self, kc, vc, ksc, vsc,
+                            block_table, start, kv_valid_len, page: int,
+                            cfg: LlamaConfig, block_pages: int = 8,
+                            window: Optional[jax.Array] = None,
+                            layer: jax.Array | int = 0):
+    """Chunk queries attend [pooled prefix] + [their own chunk], with the
+    prefix STREAMED from the pool in ``block_pages``-page blocks under an
+    online softmax.
+
+    The former implementation gathered the whole window up front —
+    (1, P*page, KV, hd) per layer, ~4 GB per tensor at 16k tokens on 7B —
+    which capped chunked long-prompt serving far below the pool's own
+    capacity. Block streaming bounds the transient to one block's K/V
+    plus one (KV, G, C, block) score tile, independent of prefix length.
+
+    q:            (1, C, H, hd) post-rope queries (C = chunk length)
+    k/v_self:     (1, C, KV, hd) this chunk's post-rope K/V (NOT yet in
+                  the pool — the pool's rows for these positions are
+                  stale, so the self part computes in-register)
+    kc/vc:        (L, N, KV, page, hd) the WHOLE pool, or one layer's
+                  (N, KV, page, hd); int8 when ksc/vsc, the per-row
+                  scales of the same leading axes, are given
+    block_table:  (1, P) logical→physical window
+    start:        () int32 — absolute position of the chunk's first row
+                  (page-aligned); pool rows with logical position >=
+                  start are masked (stale/future)
+    kv_valid_len: (1,) int32 — start + valid tokens in this chunk
+    window:       () int32 or None — this layer's window in keys (0 =
+                  whole context): the query at position p attends keys
+                  p - window < j <= p, and prefix blocks wholly behind
+                  the FIRST query's window are skipped like those past
+                  the prefix
+    layer:        () int32 — the layer to read of a whole pool. A
+                  block's pages are gathered by (layer, page) in ONE
+                  step, over the pool's flattened leading axes: a
+                  ``pool[layer]`` first is invariant in the block loop,
+                  and XLA hoists it into a copy of the layer's whole
+                  slab (2 x 142 MB a layer of a 1088-page pool) to read
+                  eight pages of it
+    Returns (1, C, H, hd) in q.dtype.
+    """
+    B, C, H, hd = q.shape
+    KV = cfg.num_kv_heads
+    G = H // KV
+    scale = 1.0 / (hd ** 0.5)
+    P = block_table.shape[1]
+    nb = -(-P // block_pages)
+    tbl = jnp.pad(block_table[0], (0, nb * block_pages - P))
+    tbl = tbl + layer * kc.shape[-4]
+    kc, vc = (a.reshape((-1,) + a.shape[-3:]) for a in (kc, vc))
+    if ksc is not None:
+        ksc, vsc = (a.reshape((-1,) + a.shape[-2:]) for a in (ksc, vsc))
+    cd = q.dtype
+    # operands stay in storage dtype into the MXU with f32 accumulation
+    # (casting whole K/V blocks to f32 up front would double the
+    # prefix stream's HBM bytes — the anti-pattern ops/attention.py's
+    # chunked path documents avoiding); softmax state is f32.
+    qf = q[0].reshape(C, KV, G, hd)
+    tblk = block_pages * page
+    rel = jnp.arange(C, dtype=jnp.int32)
+    if window is not None:
+        # first key each query attends (0 where the layer has no window)
+        lo = jnp.where(window > 0, start + rel - window + 1, 0)  # (C,)
+
+    def online(carry, s, mask, vb):
+        """One online-softmax update. s: (KV, G, C, T) f32 scores,
+        mask (C, T) or (T,); explicit zeroing of masked probabilities —
+        relying on exp(-1e30 - m) underflow alone breaks the moment a
+        stale pool row is non-finite (NaN * 0 = NaN)."""
+        m, l, acc = carry
+        mb = jnp.broadcast_to(mask, s.shape[-2:])[None, None]
+        s = jnp.where(mb, s, -1e30)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(mb, jnp.exp(s - m_new[..., None]), 0.0)
+        l_new = l * alpha + jnp.sum(p, axis=-1)
+        acc_new = (acc * alpha[..., None]
+                   + jnp.einsum("kgct,tkh->kgch", p.astype(cd), vb,
+                                preferred_element_type=jnp.float32))
+        return m_new, l_new, acc_new
+
+    def dequant_block(pool, scales, pages):
+        g = pool[pages]                         # (bp, KV, page, hd)
+        if scales is not None:
+            from ..ops.kv_quant import dequantize_rows
+            g = dequantize_rows(g, scales[pages], cd)
+        return g.swapaxes(1, 2).reshape(tblk, KV, hd).astype(cd)
+
+    def block(carry, bi):
+        def live(carry):
+            pages = jax.lax.dynamic_slice(tbl, (bi * block_pages,),
+                                          (block_pages,))
+            kb = dequant_block(kc, ksc, pages)
+            vb = dequant_block(vc, vsc, pages)
+            t = bi * tblk + jnp.arange(tblk, dtype=jnp.int32)
+            s = jnp.einsum("ckgh,tkh->kgct", qf, kb,
+                           preferred_element_type=jnp.float32) * scale
+            # prefix rows only: pool rows at/past `start` are stale
+            # (this chunk's own rows land post-scan) — and every prefix
+            # row is causally visible to every chunk query (t < start)
+            mask = t < start
+            # ... and the V rows no query may read are zeroed, not only
+            # their probabilities: a block that holds the end of the
+            # prefix also holds pages past it — this chunk's own, stale,
+            # and past the extent the TRASH page, where the decode kernel
+            # parks idle slots' rows beside whatever its scratch held.
+            # One non-finite value there and 0 x NaN = NaN reaches every
+            # query of the chunk through the PV product (PERF.md section
+            # 7 row 1: a request answers with garbage from then on).
+            vb = jnp.where(mask[:, None, None], vb, 0)
+            if window is not None:
+                mask = mask[None, :] & (t[None, :] >= lo[:, None])
+            return online(carry, s, mask, vb)
+        # blocks wholly past the prefix would be gathered then fully
+        # masked — skip their HBM reads and matmuls at runtime; so
+        # would blocks wholly behind the first query's window
+        wanted = bi * tblk < start
+        if window is not None:
+            wanted = wanted & ((bi + 1) * tblk > lo[0])
+        return jax.lax.cond(wanted, live, lambda c: c, carry), None
+
+    m0 = jnp.full((KV, G, C), -1e30, jnp.float32)
+    l0 = jnp.zeros((KV, G, C), jnp.float32)
+    acc0 = jnp.zeros((KV, G, C, hd), jnp.float32)
+    (m, l, acc), _ = jax.lax.scan(
+        block, (m0, l0, acc0), jnp.arange(nb, dtype=jnp.int32))
+
+    # the chunk itself, ALSO in key blocks — a dense (KV, G, C, C) f32
+    # score tensor at C=2048 on 7B is 512 MB/layer, the transient the
+    # chunked-attention machinery exists to avoid
+    sb = min(C, 512)
+    while C % sb:
+        sb //= 2
+    ks, vs = k_self[0], v_self[0]               # (C, KV, hd)
+
+    def self_block(carry, si):
+        kb = jax.lax.dynamic_slice(ks, (si * sb, 0, 0), (sb, KV, hd))
+        vb = jax.lax.dynamic_slice(vs, (si * sb, 0, 0), (sb, KV, hd))
+        tloc = si * sb + jnp.arange(sb, dtype=jnp.int32)
+        s = jnp.einsum("ckgh,tkh->kgct", qf, kb,
+                       preferred_element_type=jnp.float32) * scale
+        ok = (tloc[None, :] <= rel[:, None]) \
+            & ((start + tloc) < kv_valid_len[0])[None, :]
+        if window is not None:
+            ok = ok & ((start + tloc)[None, :] >= lo[:, None])
+        return online(carry, s, ok, vb), None
+
+    (m, l, acc), _ = jax.lax.scan(
+        self_block, (m, l, acc), jnp.arange(C // sb, dtype=jnp.int32))
+    # valid queries attend at least themselves (l > 0); PADDED rows past
+    # kv_valid_len attend nothing — floor the denominator so they yield
+    # zeros, not NaNs that would trip debug tooling downstream
+    out = acc / jnp.maximum(l[..., None], 1e-30)
+    # (KV, G, C, hd) -> (1, C, H, hd)
+    return out.transpose(2, 0, 1, 3).reshape(1, C, H, hd).astype(q.dtype)
+
+
+class LatentKV:
+    """One latent row a token for all heads: ``{"c": (L, N, 1, page, R),
+    "r": (L, N, 1, rope, page)}`` (module docstring). bf16 or float32
+    rows only: an int8 latent pool is refused by name."""
+
+    leaves = ("c", "r")
+
+    def __init__(self, cfg: LlamaConfig):
+        self.cfg = cfg
+        self.R, self.rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        self.nope, self.vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+
+    # ---------------------------------------------------------------- build
+
+    def init_dense(self, batch: int, max_len: int,
+                   dtype: jnp.dtype = jnp.bfloat16) -> KVCache:
+        L = self.cfg.num_layers
+        return {"c": jnp.zeros((L, batch, max_len, self.R), dtype),
+                "r": jnp.zeros((L, batch, max_len, self.rope), dtype)}
+
+    def init_pool(self, n_pages: int, page_size: int,
+                  dtype: jnp.dtype = jnp.bfloat16,
+                  quantized: bool = False) -> KVCache:
+        if quantized:
+            raise NotImplementedError(
+                "an int8 KV pool over a latent cache (kv_lora_rank) is "
+                "not supported: the latent row is key and value at once "
+                "and has one scale plane too few")
+        L = self.cfg.num_layers
+        return {"c": jnp.zeros((L, n_pages, 1, page_size, self.R), dtype),
+                "r": jnp.zeros((L, n_pages, 1, self.rope, page_size),
+                               dtype)}
+
+    # ----------------------------------------------------------------- size
+
+    def token_bytes(self, itemsize: int, quantized: bool = False) -> int:
+        """Bytes a cached token a layer: the latent and the rotary key
+        part, once for all heads."""
+        if quantized:
+            raise NotImplementedError("int8 KV over a latent cache")
+        return (self.R + self.rope) * itemsize
+
+    @staticmethod
+    def page_size(kv_cache: KVCache) -> int:
+        return kv_cache["c"].shape[3]
+
+    @staticmethod
+    def quantized(kv_cache: KVCache) -> bool:
+        return False
+
+    def kernel_supported(self, page: int) -> bool:
+        from ..ops.latent_attention import kernel_supported
+        return kernel_supported(page, self.R, self.rope)
+
+    def pool_spec(self, mesh, quantized: bool = False) -> dict:
+        """Replicated: the latent is common to all heads, so a ``tp``
+        mesh has nothing of it to split (the engine refuses one by
+        name)."""
+        from jax.sharding import PartitionSpec as P
+        return dict.fromkeys(self.leaves, P())
+
+    # ---------------------------------------------------------------- write
+
+    def write(self, kv_cache: KVCache, new_c: jax.Array, new_r: jax.Array,
+              pages: jax.Array, offsets: Optional[jax.Array] = None
+              ) -> KVCache:
+        """``HeadKV.write`` for the latent leaves. Rows: new_c (L, B, S,
+        R) and new_r (L, B, S, rope) to ``pages`` / ``offsets`` (B, S) —
+        a latent row is one flat row of (N, page), its rotary part
+        ``rope`` single values of (N, rope, page), a lane each. Whole
+        pages: (L, C, R) and (L, C, rope), the latter turned."""
+        L, N, _, page, R = kv_cache["c"].shape
+        rope = self.rope
+        pc, pr = kv_cache["c"], kv_cache["r"]
+        with jax.named_scope("attn"):      # the KV write
+            if offsets is None:
+                cb = new_c.reshape(L, -1, 1, page, R)
+                rb = new_r.reshape(L, -1, 1, page, rope).swapaxes(3, 4)
+                return {"c": pc.at[:, pages].set(cb.astype(pc.dtype)),
+                        "r": pr.at[:, pages].set(rb.astype(pr.dtype))}
+            row = pages * page + offsets                        # (B, S)
+            lane = ((pages[..., None] * rope + jnp.arange(rope)) * page
+                    + offsets[..., None])                       # (B,S,rope)
+            layer = jnp.arange(L)[:, None, None]
+            c = pc.reshape(L, N * page, R).at[layer, row[None]].set(
+                new_c.astype(pc.dtype)).reshape(pc.shape)
+            r = pr.reshape(L, N * rope * page).at[
+                layer[..., None], lane[None]].set(
+                new_r.astype(pr.dtype)).reshape(pr.shape)
+            return {"c": c, "r": r}
+
+    def insert_pages(self, kv_cache: KVCache, c_new: jax.Array,
+                     r_new: jax.Array, dest: jax.Array) -> KVCache:
+        """``HeadKV.insert_pages``: dense rows (L, 1, S, R) and (L, 1, S,
+        rope) into the pages ``dest``."""
+        return self.write(kv_cache, c_new[:, 0], r_new[:, 0], dest)
+
+    # ----------------------------------------------------------------- read
+
+    def window(self, kv_cache: KVCache, layer, block_table):
+        """One layer's slot windows gathered out of the whole pool by
+        (layer, page): ``c`` (B, P*page, R) and ``k_r`` (B, P*page,
+        rope)."""
+        pc, pr = kv_cache["c"], kv_cache["r"]
+        B, P = block_table.shape
+        page = pc.shape[3]
+        pages = block_table + layer * pc.shape[1]
+        gc = pc.reshape((-1,) + pc.shape[3:])[pages]        # (B,P,page,R)
+        gr = pr.reshape((-1,) + pr.shape[3:])[pages]        # (B,P,rope,page)
+        return gc.reshape(B, P * page, self.R), \
+            gr.swapaxes(2, 3).reshape(B, P * page, self.rope)
+
+    def _split(self, q):
+        return q[..., :self.nope], q[..., self.nope:]
+
+    def attend_tokens(self, q, c, k_r, lp, positions, kv_valid_len):
+        """Expanded attention over the tokens given (B, T = S): every
+        head's keys and values from the latent through ``wk_b`` /
+        ``wv_b``."""
+        from ..ops.latent_attention import expanded_attention
+        B, T, _ = c.shape
+        H = self.cfg.num_heads
+        k_nope = qmm(c, lp["wk_b"]).reshape(B, T, H, self.nope)
+        v = qmm(c, lp["wv_b"]).reshape(B, T, H, self.vd)
+        qn, qr = self._split(q)
+        return expanded_attention(qn, qr, k_nope, k_r, v, positions,
+                                  kv_valid_len, self.cfg.score_scale)
+
+    def put_dense(self, lp, c, k_r, row_start):
+        put = jax.vmap(
+            lambda a, u, s: jax.lax.dynamic_update_slice(a, u, (s, 0)))
+        return put(lp["cache_c"], c, row_start), \
+            put(lp["cache_r"], k_r, row_start)
+
+    def absorb(self, qn, lp):
+        """The query carried into the latent space: q~_h = q_nope_h
+        W_UK,h^T, (B, S, H, nope) -> (B, S, H, R)."""
+        from ..ops.latent_attention import head_product
+        return head_product(qn, lp["wk_b"], self.cfg.num_heads,
+                            transposed=True)
+
+    def attend_window(self, q, c, k_r, lp, kv_cache, layer, block_table,
+                      rows, positions, kv_valid_len):
+        """Absorbed attention over the slot's gathered window with the S
+        current tokens joined in-register: each cached row is key and
+        value; ``wv_b`` after the sum."""
+        from ..ops.latent_attention import absorbed_attention, head_product
+        qn, qr = self._split(q)
+        gc, gr = self.window(kv_cache, layer, block_table)
+        gc = gc.astype(q.dtype).at[rows, positions].set(c.astype(q.dtype))
+        gr = gr.astype(q.dtype).at[rows, positions].set(
+            k_r.astype(q.dtype))
+        o_c = absorbed_attention(self.absorb(qn, lp), qr, gc, gr, positions,
+                                 kv_valid_len, self.cfg.score_scale)
+        return head_product(o_c, lp["wv_b"], self.cfg.num_heads)
+
+    def attend_prefix(self, q, c, k_r, lp, kv_cache, block_table, start,
+                      kv_valid_len, layer, block_pages: int = 4):
+        """A chunk's attention, EXPANDED: the chunk's own tokens and each
+        ``block_pages``-page block of the prefix it reads back from the
+        latent pool are taken through ``wk_b`` / ``wv_b`` (one expansion
+        a block serves all C x H queries), under one online softmax.
+        B = 1. Pool rows at or past ``start`` are stale or another
+        slot's: masked, and zeroed BEFORE the expansion (the trash page
+        may hold anything; ``HeadKV``'s reader says why)."""
+        cfg = self.cfg
+        _, C, H, _ = q.shape
+        nope, vd, rope, R = self.nope, self.vd, self.rope, self.R
+        pc, pr = kv_cache["c"], kv_cache["r"]
+        page = pc.shape[3]
+        scale = cfg.score_scale
+        P = block_table.shape[1]
+        nb = -(-P // block_pages)
+        tbl = jnp.pad(block_table[0], (0, nb * block_pages - P))
+        tbl = tbl + layer * pc.shape[1]
+        pc = pc.reshape((-1,) + pc.shape[3:])               # (L*N, page, R)
+        pr = pr.reshape((-1,) + pr.shape[3:])               # (L*N, rope, page)
+        cd = q.dtype
+        qn, qr = self._split(q[0])                          # (C, H, .)
+        tblk = block_pages * page
+        rel = jnp.arange(C, dtype=jnp.int32)
+
+        def expand(cb):
+            return (qmm(cb, lp["wk_b"]).reshape(-1, H, nope),
+                    qmm(cb, lp["wv_b"]).reshape(-1, H, vd))
+
+        def scores(kb, rb):
+            return (jnp.einsum("chj,thj->hct", qn, kb,
+                               preferred_element_type=jnp.float32)
+                    + jnp.einsum("chr,tr->hct", qr, rb,
+                                 preferred_element_type=jnp.float32)
+                    ) * scale
+
+        def online(carry, s, mask, vb):
+            m, l, acc = carry
+            mb = jnp.broadcast_to(mask, s.shape[-2:])[None]
+            s = jnp.where(mb, s, -1e30)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.where(mb, jnp.exp(s - m_new[..., None]), 0.0)
+            l_new = l * alpha + jnp.sum(p, axis=-1)
+            acc_new = (acc * alpha[..., None]
+                       + jnp.einsum("hct,thv->hcv", p.astype(cd), vb,
+                                    preferred_element_type=jnp.float32))
+            return m_new, l_new, acc_new
+
+        def block(carry, bi):
+            def live(carry):
+                pages = jax.lax.dynamic_slice(tbl, (bi * block_pages,),
+                                              (block_pages,))
+                t = bi * tblk + jnp.arange(tblk, dtype=jnp.int32)
+                mask = t < start
+                cb = jnp.where(mask[:, None],
+                               pc[pages].reshape(tblk, R).astype(cd), 0)
+                rb = jnp.where(
+                    mask[:, None],
+                    pr[pages].swapaxes(1, 2).reshape(tblk, rope).astype(cd),
+                    0)
+                kb, vb = expand(cb)
+                return online(carry, scores(kb, rb), mask, vb)
+            return jax.lax.cond(bi * tblk < start, live, lambda c: c,
+                                carry), None
+
+        m0 = jnp.full((H, C), -1e30, jnp.float32)
+        l0 = jnp.zeros((H, C), jnp.float32)
+        acc0 = jnp.zeros((H, C, vd), jnp.float32)
+        (m, l, acc), _ = jax.lax.scan(
+            block, (m0, l0, acc0), jnp.arange(nb, dtype=jnp.int32))
+
+        sb = min(C, 512)
+        while C % sb:
+            sb //= 2
+        ks, vs = expand(c[0])                               # (C, H, .)
+        rs = k_r[0]
+
+        def self_block(carry, si):
+            kb = jax.lax.dynamic_slice(ks, (si * sb, 0, 0), (sb, H, nope))
+            vb = jax.lax.dynamic_slice(vs, (si * sb, 0, 0), (sb, H, vd))
+            rb = jax.lax.dynamic_slice(rs, (si * sb, 0), (sb, rope))
+            tloc = si * sb + jnp.arange(sb, dtype=jnp.int32)
+            ok = (tloc[None, :] <= rel[:, None]) \
+                & ((start + tloc) < kv_valid_len[0])[None, :]
+            return online(carry, scores(kb, rb), ok, vb), None
+
+        (m, l, acc), _ = jax.lax.scan(
+            self_block, (m, l, acc), jnp.arange(C // sb, dtype=jnp.int32))
+        out = acc / jnp.maximum(l[..., None], 1e-30)        # (H, C, vd)
+        return out.transpose(1, 0, 2)[None].astype(q.dtype)
+
+    def kernel_attend(self, kv_cache: KVCache, block_table, pos_in_win,
+                      write_page, write_offset, mesh, act_dtype):
+        """The decode step's ``attend`` over the latent Pallas kernel
+        (ops/latent_attention.py): the absorbed query against the pool's
+        rows, the step's row appended inside the kernel, the pool in the
+        layer scan's carry; ``wv_b`` after."""
+        from ..ops.latent_attention import (head_product,
+                                            latent_attention_decode)
+        if mesh is not None and mesh.shape.get("tp", 1) > 1:
+            raise NotImplementedError(
+                "the latent decode kernel under a tp mesh is not supported")
+        cfg = self.cfg
+        dt = kv_cache["c"].dtype
+        interp = jax.default_backend() != "tpu"
+
+        def attend(q, c, k_r, lp, li, pool):
+            qn, qr = self._split(q[:, 0])                   # (B, H, .)
+            o_c, pc, pr = latent_attention_decode(
+                self.absorb(qn[:, None], lp)[:, 0], qr, pool["c"],
+                pool["r"], block_table, pos_in_win, c[:, 0].astype(dt),
+                k_r[:, 0].astype(dt), write_page, write_offset, li,
+                scale=cfg.score_scale, interpret=interp)
+            attn = head_product(o_c[:, None], lp["wv_b"], cfg.num_heads)
+            return attn, {"c": pc, "r": pr}
+
+        return attend

@@ -10,7 +10,8 @@ Design choices (TPU-first, not a port):
   along a leading L axis and the decoder scans over layers. One layer gets
   traced/compiled, not 32/40/80 — compile time stays flat with depth, and
   sharding rules are written once per leaf. ONE function runs that scan
-  (``_run_stack``) and one writes the paged pool (``_write_pool``); the
+  (``_run_stack``) and one object reads and writes the cache
+  (models/kv_cache.py: per-head K and V, or a latent row a token); the
   forwards (``apply*``, ``run_layers``) hand them only what differs.
 - **Absolute-position KV cache**: cache index == token position. Prefill and
   decode are the same function with different (tokens, positions) shapes; no
@@ -37,6 +38,12 @@ float32; a shared expert ``ws_gate`` / ``ws_up`` (L, D, Fs), ``ws_down``
 (L, Fs, D). An expert model with leading dense layers holds them as a
 second stack, ``dense_layers`` (the same tree with a dense MLP), beside
 ``layers``: ``cfg.layer_stacks`` names the stacks in the order they run.
+A latent-attention layer (``kv_lora_rank``) has, in place of wq / wk /
+wv: ``wq_a`` (L, D, Rq), ``q_a_norm`` (L, Rq), ``wq_b`` (L, Rq, H*hd),
+``wkv_a`` (L, D, R + rope), ``kv_a_norm`` (L, R), ``wk_b`` (L, R,
+H*nope), ``wv_b`` (L, R, H*v), and ``wo`` (L, H*v, D). Under an expert
+share (``experts_held``) the expert stacks hold the held experts only;
+the router keeps every column.
 """
 
 from __future__ import annotations
@@ -49,12 +56,14 @@ import os
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import gqa_attention
 from ..ops.quant import matmul as qmm
 from ..ops.quant import matmul_f32 as qmm_f32
 from ..ops.rmsnorm import layernorm1p, rmsnorm
-from ..ops.rope import apply_rope, rope_frequencies
+from ..ops.rope import (apply_rope, deinterleave, rope_frequencies,
+                        yarn_frequencies)
 from .configs import LlamaConfig
+from .kv_cache import (KVCache, _paged_prefix_attention,  # noqa: F401
+                       kv_cache_of)
 
 #: Stage names inside every device program (``jax.named_scope``: HLO
 #: metadata only — numerics, program count and jitted-function names are
@@ -92,15 +101,23 @@ def use_paged_kernel(cfg: LlamaConfig, page: int) -> bool:
     flag = os.environ.get("GENAI_TPU_PAGED_KERNEL", "auto")
     if flag == "0":
         return False
-    from ..ops.paged_attention import kernel_supported
-    ok = kernel_supported(page, cfg.num_heads, cfg.num_kv_heads,
-                          cfg.head_dim)
+    ok = kv_cache_of(cfg).kernel_supported(page)
     if flag == "1":
         return ok
     return ok and jax.default_backend() == "tpu"
 
 Params = dict[str, Any]
-KVCache = dict[str, jax.Array]  # {"k": (L,B,T,KV,hd), "v": (L,B,T,KV,hd)}
+
+
+def _inv_freq(cfg: LlamaConfig) -> jax.Array:
+    """The rotary part's inverse frequencies as the configuration scales
+    them: over the whole head, or a latent layer's ``qk_rope_head_dim``."""
+    dim = cfg.qk_rope_head_dim or cfg.head_dim
+    if cfg.rope_scaling_type == "yarn":
+        return yarn_frequencies(dim, cfg.rope_theta, cfg.rope_scaling_factor,
+                                cfg.rope_original_max, cfg.rope_beta_fast,
+                                cfg.rope_beta_slow)
+    return rope_frequencies(dim, cfg.rope_theta, cfg.rope_scaling_factor)
 
 
 def init_params(cfg: LlamaConfig, key: jax.Array,
@@ -152,11 +169,16 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
         layers: dict[str, jax.Array] = {
             "attn_norm": norm_w((L, D), dtype),
             "mlp_norm": norm_w((L, D), dtype),
-            "wq": norm(next(k), (L, D, H * hd), D / q_gain),
-            "wk": norm(next(k), (L, D, KV * hd), D),
-            "wv": norm(next(k), (L, D, KV * hd), D),
-            "wo": norm(next(k), (L, H * hd, D), H * hd * resid),
         }
+        if cfg.kv_lora_rank:
+            layers.update(latent(k, L))
+        else:
+            layers.update({
+                "wq": norm(next(k), (L, D, H * hd), D / q_gain),
+                "wk": norm(next(k), (L, D, KV * hd), D),
+                "wv": norm(next(k), (L, D, KV * hd), D),
+                "wo": norm(next(k), (L, H * hd, D), H * hd * resid),
+            })
         if cfg.norm == "layernorm1p":
             layers["attn_norm_b"] = jnp.zeros((L, D), dtype)
             layers["mlp_norm_b"] = jnp.zeros((L, D), dtype)
@@ -166,12 +188,14 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
             layers["bv"] = jnp.zeros((L, KV * hd), dtype)
             layers["bo"] = jnp.zeros((L, D), dtype)
         if experts:
-            E = cfg.num_experts
+            # the router's columns are the layer's experts; the matrices
+            # are those this tree holds (all of them, or its share)
+            E, Eh = cfg.num_experts, cfg.held_experts
             layers.update({
                 "router": norm(next(k), (L, D, E), D),
-                "w_gate": norm(next(k), (L, E, D, Fe), D),
-                "w_up": norm(next(k), (L, E, D, Fe), D),
-                "w_down": norm(next(k), (L, E, Fe, D), Fe * resid),
+                "w_gate": norm(next(k), (L, Eh, D, Fe), D),
+                "w_up": norm(next(k), (L, Eh, D, Fe), D),
+                "w_down": norm(next(k), (L, Eh, Fe, D), Fe * resid),
             })
         elif cfg.mlp == "squared_relu":
             # GPT-Next MLP: no gate projection
@@ -189,6 +213,29 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
                 "w_down": norm(next(k), (L, F, D), F),
             })
         return layers
+
+    def latent(k, L):
+        """A latent-attention layer's five projections. The two latent
+        norms take the gain out of ``wq_a`` and the latent columns of
+        ``wkv_a``, which are drawn at HALF the fan-in deviation so that a
+        program without a norm differs by more than the norm weights'
+        tenth (``extras``); the rotary key columns of ``wkv_a`` meet no
+        norm and keep theirs. ``wq_b`` carries the reach: a query's
+        scores deviate by 4 AFTER the configuration's score multiplier
+        (m ** 2 under YaRN), so leaving the multiplier out shows."""
+        R, Rq = cfg.kv_lora_rank, cfg.q_lora_rank
+        nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+        m2 = cfg.score_scale * hd ** 0.5
+        half = jnp.where(jnp.arange(R + cfg.qk_rope_head_dim) < R, 0.5, 1.0)
+        wkv_a = norm(next(k), (L, D, R + cfg.qk_rope_head_dim), D)
+        return {
+            "wq_a": norm(next(k), (L, D, Rq), 4 * D),
+            "wq_b": norm(next(k), (L, Rq, H * hd), Rq * m2 * m2 / q_gain),
+            "wkv_a": (wkv_a.astype(jnp.float32) * half).astype(dtype),
+            "wk_b": norm(next(k), (L, R, H * nope), R),
+            "wv_b": norm(next(k), (L, R, H * vd), R),
+            "wo": norm(next(k), (L, H * vd, D), H * vd * resid),
+        }
 
     def extras(k, layers):
         """The leaves the configured variants add to a stack's tree,
@@ -222,8 +269,11 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
             return (centre * (1.0 + 0.1 * jax.random.normal(
                 next(k), shape, jnp.float32))).astype(dtype)
 
-        L, experts = layers["wq"].shape[0], "router" in layers
+        L, experts = layers["attn_norm"].shape[0], "router" in layers
         out: dict[str, jax.Array] = {}
+        if cfg.kv_lora_rank:
+            out["q_a_norm"] = near(1.0, (L, cfg.q_lora_rank))
+            out["kv_a_norm"] = near(1.0, (L, cfg.kv_lora_rank))
         if cfg.attn_gate:
             out["wz"] = norm(next(k), (L, D, H * hd), D)
         if cfg.qk_norm:
@@ -276,14 +326,18 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
 
 def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int,
                   dtype: jnp.dtype = jnp.bfloat16) -> KVCache:
-    shape = (cfg.num_layers, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    """The dense absolute-position cache: ``{"k", "v"}: (L, B, T, KV,
+    hd)``, or a latent model's ``{"c", "r"}`` (models/kv_cache.py)."""
+    return kv_cache_of(cfg).init_dense(batch, max_len, dtype)
 
 
 def init_paged_kv_cache(cfg: LlamaConfig, n_pages: int, page_size: int,
                         dtype: jnp.dtype = jnp.bfloat16,
                         quantized: bool = False) -> KVCache:
-    """Block-pool KV cache: {"k","v"}: (L, n_pages, KV, page, hd).
+    """Block-pool KV cache: {"k","v"}: (L, n_pages, KV, page, hd), or a
+    latent model's two leaves; the configuration's cache object
+    (models/kv_cache.py) builds it and is the one that reads and writes
+    it.
 
     The pool is shared by all decode slots through per-slot block tables —
     the XLA-static equivalent of TRT-LLM's paged KV cache
@@ -301,40 +355,7 @@ def init_paged_kv_cache(cfg: LlamaConfig, n_pages: int, page_size: int,
     (ops/kv_quant.py), the lever toward the reference's batch-128 class
     capacity (reference: config.pbtxt.j2:29).
     """
-    shape = (cfg.num_layers, n_pages, cfg.num_kv_heads, page_size,
-             cfg.head_dim)
-    if not quantized:
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-    from ..ops.kv_quant import SCALE_DTYPE
-    return {"k": jnp.zeros(shape, jnp.int8),
-            "v": jnp.zeros(shape, jnp.int8),
-            "ks": jnp.zeros(shape[:4], SCALE_DTYPE),
-            "vs": jnp.zeros(shape[:4], SCALE_DTYPE)}
-
-
-def kv_cache_quantized(kv_cache: KVCache) -> bool:
-    """Whether a paged pool carries int8 rows + scale leaves."""
-    return "ks" in kv_cache
-
-
-def _gathered_window(kv_cache: KVCache, name: str, layer, block_table,
-                     cfg: LlamaConfig, dtype):
-    """One layer's slot windows of leaf ``name`` ("k" or "v") gathered
-    from the WHOLE paged pool, (L, N, KV, page, hd) -> (B, P*page, KV,
-    hd), by (layer, page) in one step over the pool's flattened leading
-    axes (a ``pool[layer]`` first is a copy of the layer's whole slab);
-    int8 pages are dequantized via their per-row scales."""
-    pool = kv_cache[name]
-    B, P = block_table.shape
-    pages = block_table + layer * pool.shape[1]
-    g = pool.reshape((-1,) + pool.shape[2:])[pages]  # (B, P, KV, page, hd)
-    if kv_cache_quantized(kv_cache):
-        from ..ops.kv_quant import dequantize_rows
-        scales = kv_cache[name + "s"]                   # (L, N, KV, page)
-        g = dequantize_rows(
-            g, scales.reshape((-1,) + scales.shape[2:])[pages], dtype)
-    return g.swapaxes(2, 3).reshape(B, P * pool.shape[3], cfg.num_kv_heads,
-                                    cfg.head_dim)
+    return kv_cache_of(cfg).init_pool(n_pages, page_size, dtype, quantized)
 
 
 def kernel_tp_compatible(cfg: LlamaConfig, mesh) -> bool:
@@ -366,6 +387,15 @@ def layer_kinds(cfg: LlamaConfig, first: int = 0,
     if not all(cfg.layer_rope):
         kinds["rope"] = jnp.asarray(cfg.layer_rope[first:last], bool)
     return kinds
+
+
+def layer_stat_names(cfg: LlamaConfig) -> tuple[str, ...]:
+    """The scalars a layer with dropless experts reports under ``stats``
+    (parallel/moe.py): the distinct experts its rows reached among those
+    it holds and, where it holds a share, the assignments that fell on
+    them."""
+    return ("experts_touched",) + (
+        ("local_assignments",) if cfg.experts_held else ())
 
 
 def scan_layers(params: Params, cfg: LlamaConfig, stack: str = "layers"
@@ -427,7 +457,8 @@ def _run_stack(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
       layers): every other forward that has a pool. The body closes
       over the pool WHOLE and reads it by (layer, page) over its
       flattened leading axes; a layer's new rows are scan outputs and
-      ONE write after the scan puts them in the pool (``_write_pool``).
+      ONE write after the scan puts them in the pool (the cache
+      object's ``write``).
       Handed to the scan as sliced inputs, each iteration's slice of the
       pool is a copy of the layer's whole K and V slab (PR 29: 2 x 142 MB
       a layer to attend eight pages), and as sliced-in / stacked-out it
@@ -436,9 +467,9 @@ def _run_stack(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
       (``scan_layers``; PR 28).
 
     A new layer kind adds its flag in ``layer_kinds`` and reads it in
-    ``decoder_layer`` or an ``attend``; a new cache format edits
-    ``_write_pool``, the two readers (``_gathered_window``,
-    ``_paged_prefix_attention``) and the kernel call.
+    ``decoder_layer`` or an ``attend``; a new cache format is a third
+    implementation of the cache object (models/kv_cache.py: its writer,
+    its readers and its kernel call).
     """
     stack, held = _scan_inputs(layers, cfg, first)
     stack = {**stack, **(xs or {})}
@@ -455,8 +486,9 @@ def _run_stack(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
             row_mask=row_mask, aux=aux)
         if carried:
             state, out = out, None
-        touched = None if aux is None else aux.get("experts_touched",
-                                                   jnp.float32(0.0))
+        touched = None if aux is None else {
+            name: aux.get(name, jnp.float32(0.0))
+            for name in layer_stat_names(cfg)}
         return (h, state, li + 1), (out, touched)
 
     # the Pallas call takes its layer as a (1,) scalar-prefetch operand;
@@ -496,54 +528,11 @@ def _run_model(params: Params, cfg: LlamaConfig, h: jax.Array, *args,
     return h, state, touched
 
 
-def _write_pool(kv_cache: KVCache, new_k: jax.Array, new_v: jax.Array,
-                pages: jax.Array, offsets: Optional[jax.Array] = None
-                ) -> KVCache:
-    """The one place that writes rows into the paged pool and knows its
-    format: the layers' new K and V, stacked (L, ...) as the scan gave
-    them, are quantised when the pool has scale planes and written in
-    ONE scatter a leaf, after the scan. Two destinations:
-
-    - rows (``offsets`` given): new (L, B, S, KV, hd) to ``pages`` /
-      ``offsets`` (B, S), each token's physical page and row in it (a
-      decode step is S = 1): one (layer, flat row) index per (slot,
-      token, kv-head) over (N, KV, page) flattened. Indexed ``[:, row]``
-      instead, the TPU compiler relayouts the WHOLE pool to scatter and
-      back (compile, PR 30).
-    - whole pages (``offsets`` None): a chunk's new (L, C, KV, hd), C a
-      page multiple, to its C / page physical ``pages``.
-    """
-    L, N, KV, page, _ = kv_cache["k"].shape
-    new = {"k": new_k, "v": new_v}
-    if kv_cache_quantized(kv_cache):
-        from ..ops.kv_quant import quantize_rows
-        new["k"], new["ks"] = quantize_rows(new_k)    # scales: (..., KV)
-        new["v"], new["vs"] = quantize_rows(new_v)
-    if offsets is None:
-        def put(pool, rows):
-            blocks = rows.reshape((L, -1, page) + rows.shape[2:])
-            return pool.at[:, pages].set(
-                blocks.swapaxes(2, 3).astype(pool.dtype))
-    else:
-        flat_idx = ((pages[..., None] * KV + jnp.arange(KV)) * page
-                    + offsets[..., None])                   # (B, S, KV)
-        layer = jnp.arange(L)[:, None, None, None]
-
-        def put(pool, rows):
-            flat = pool.reshape((L, N * KV * page) + pool.shape[4:])
-            return flat.at[layer, flat_idx[None]].set(
-                rows.astype(pool.dtype)).reshape(pool.shape)
-
-    with jax.named_scope("attn"):      # the KV write
-        return {name: put(kv_cache[name], rows)
-                for name, rows in new.items()}
-
-
 def _step_result(params: Params, cfg: LlamaConfig, h: jax.Array,
                  cache: KVCache, touched, return_hidden: bool, stats: bool):
     out = h if return_hidden else unembed(params, cfg, h)
     if stats:
-        return out, cache, {"experts_touched": jnp.mean(touched)}
+        return out, cache, {name: jnp.mean(t) for name, t in touched.items()}
     return out, cache
 
 
@@ -580,55 +569,19 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     scan's carry (``_run_stack``); False — the CPU, a mesh the kernel
     refuses — is the one-token case of ``apply_verify_paged``.
     """
+    kvc = kv_cache_of(cfg)
     if use_kernel is None:
-        use_kernel = use_paged_kernel(cfg, kv_cache["k"].shape[3])
+        use_kernel = use_paged_kernel(cfg, kvc.page_size(kv_cache))
     if not use_kernel:
         return apply_verify_paged(
             params, cfg, tokens, positions, kv_cache, block_table,
             kv_valid_len, write_page[:, None], write_offset[:, None],
             return_hidden, active=active, stats=stats)
-    from ..ops.paged_attention import paged_attention_decode
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
-                                cfg.rope_scaling_factor)
+    inv_freq = _inv_freq(cfg)
     h = _embed(params, tokens, cfg.embed_scale)
     pos_in_win = positions[:, 0]  # logical index of the current token
-    # int8-KV pools: the kernel quantizes the appended row itself, so
-    # the current token's K/V pass in compute dtype, not pool dtype.
-    dt = h.dtype if kv_cache_quantized(kv_cache) else kv_cache["k"].dtype
-    interp = jax.default_backend() != "tpu"
-
-    # ``win``: the layer's window as one more (1,) operand, only in a
-    # model that has window layers
-    def call_kernel(q, pool, ck, cv, li, tbl, lens, wp, off, *win):
-        attn, *leaves = paged_attention_decode(
-            q, pool["k"], pool["v"], tbl, lens, ck, cv, wp, off, li,
-            pool_ks=pool.get("ks"), pool_vs=pool.get("vs"),
-            interpret=interp, window=win[0] if win else None)
-        return attn, dict(zip(("k", "v", "ks", "vs"), leaves))
-
-    if mesh is not None and "tp" in mesh.shape:
-        # Pallas has no SPMD partitioning rule, so under a tp mesh the
-        # call is shard_mapped: each device runs the kernel on its own
-        # H/tp query heads and KV/tp pool shard — table/positions are
-        # replicated, and the append lands in the local shard. This is
-        # what keeps the v5e-8 TP serving config off the ~10x-slower
-        # gather path (VERDICT r3 weak #3).
-        from jax.sharding import PartitionSpec as P
-        heads = P(None, "tp", None)                     # q, ck, cv, attn
-        pool_specs = {name: P(None, None, "tp", *(None,) * (leaf.ndim - 3))
-                      for name, leaf in kv_cache.items()}
-        call_kernel = jax.shard_map(
-            call_kernel, mesh=mesh,
-            in_specs=(heads, pool_specs, heads, heads)
-            + (P(),) * (5 + any(cfg.layer_windows)),
-            out_specs=(heads, pool_specs), check_vma=False)
-
-    def attend(q, k, v, lp, li, pool):
-        win = (lp["window"][None],) if "window" in lp else ()
-        attn, pool = call_kernel(
-            q[:, 0], pool, k[:, 0].astype(dt), v[:, 0].astype(dt), li,
-            block_table, pos_in_win, write_page, write_offset, *win)
-        return attn[:, None], pool
+    attend = kvc.kernel_attend(kv_cache, block_table, pos_in_win,
+                               write_page, write_offset, mesh, h.dtype)
 
     h, cache, touched = _run_model(
         params, cfg, h, positions, inv_freq, kv_valid_len, attend,
@@ -673,186 +626,20 @@ def apply_verify_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     path on every backend, trading a gathered window per layer for the
     K+1 scoring positions. Pool held, rows out (``_run_stack``).
     """
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
-                                cfg.rope_scaling_factor)
+    inv_freq = _inv_freq(cfg)
+    kvc = kv_cache_of(cfg)
     rows = jnp.arange(tokens.shape[0])[:, None]
 
     def attend(q, k, v, lp, li, _):
-        def window(name, new):
-            g = _gathered_window(kv_cache, name, li, block_table, cfg,
-                                 q.dtype)
-            # All S current tokens join the window in-register at their
-            # logical positions (their pool writes happen in the
-            # post-scan scatter); positions past the window drop on
-            # scatter — they can only belong to masked garbage rows.
-            return g.at[rows, positions].set(new.astype(g.dtype))
-
-        return gqa_attention(q, window("k", k), window("v", v), positions,
-                             kv_valid_len, window=lp.get("window")), (k, v)
+        return kvc.attend_window(q, k, v, lp, kv_cache, li, block_table,
+                                 rows, positions, kv_valid_len), (k, v)
 
     h, (new_k, new_v), touched = _run_model(
         params, cfg, _embed(params, tokens, cfg.embed_scale), positions,
         inv_freq, kv_valid_len, attend, row_mask=active, stats=stats)
-    cache = _write_pool(kv_cache, new_k, new_v, write_pages, write_offsets)
+    cache = kvc.write(kv_cache, new_k, new_v, write_pages, write_offsets)
     return _step_result(params, cfg, h, cache, touched, return_hidden,
                         stats)
-
-
-def _paged_prefix_attention(q, k_self, v_self, kc, vc, ksc, vsc,
-                            block_table, start, kv_valid_len, page: int,
-                            cfg: LlamaConfig, block_pages: int = 8,
-                            window: Optional[jax.Array] = None,
-                            layer: jax.Array | int = 0):
-    """Chunk queries attend [pooled prefix] + [their own chunk], with the
-    prefix STREAMED from the pool in ``block_pages``-page blocks under an
-    online softmax.
-
-    The former implementation gathered the whole window up front —
-    (1, P*page, KV, hd) per layer, ~4 GB per tensor at 16k tokens on 7B —
-    which capped chunked long-prompt serving far below the pool's own
-    capacity. Block streaming bounds the transient to one block's K/V
-    plus one (KV, G, C, block) score tile, independent of prefix length.
-
-    q:            (1, C, H, hd) post-rope queries (C = chunk length)
-    k/v_self:     (1, C, KV, hd) this chunk's post-rope K/V (NOT yet in
-                  the pool — the pool's rows for these positions are
-                  stale, so the self part computes in-register)
-    kc/vc:        (L, N, KV, page, hd) the WHOLE pool, or one layer's
-                  (N, KV, page, hd); int8 when ksc/vsc, the per-row
-                  scales of the same leading axes, are given
-    block_table:  (1, P) logical→physical window
-    start:        () int32 — absolute position of the chunk's first row
-                  (page-aligned); pool rows with logical position >=
-                  start are masked (stale/future)
-    kv_valid_len: (1,) int32 — start + valid tokens in this chunk
-    window:       () int32 or None — this layer's window in keys (0 =
-                  whole context): the query at position p attends keys
-                  p - window < j <= p, and prefix blocks wholly behind
-                  the FIRST query's window are skipped like those past
-                  the prefix
-    layer:        () int32 — the layer to read of a whole pool. A
-                  block's pages are gathered by (layer, page) in ONE
-                  step, over the pool's flattened leading axes: a
-                  ``pool[layer]`` first is invariant in the block loop,
-                  and XLA hoists it into a copy of the layer's whole
-                  slab (2 x 142 MB a layer of a 1088-page pool) to read
-                  eight pages of it
-    Returns (1, C, H, hd) in q.dtype.
-    """
-    B, C, H, hd = q.shape
-    KV = cfg.num_kv_heads
-    G = H // KV
-    scale = 1.0 / (hd ** 0.5)
-    P = block_table.shape[1]
-    nb = -(-P // block_pages)
-    tbl = jnp.pad(block_table[0], (0, nb * block_pages - P))
-    tbl = tbl + layer * kc.shape[-4]
-    kc, vc = (a.reshape((-1,) + a.shape[-3:]) for a in (kc, vc))
-    if ksc is not None:
-        ksc, vsc = (a.reshape((-1,) + a.shape[-2:]) for a in (ksc, vsc))
-    cd = q.dtype
-    # operands stay in storage dtype into the MXU with f32 accumulation
-    # (casting whole K/V blocks to f32 up front would double the
-    # prefix stream's HBM bytes — the anti-pattern ops/attention.py's
-    # chunked path documents avoiding); softmax state is f32.
-    qf = q[0].reshape(C, KV, G, hd)
-    tblk = block_pages * page
-    rel = jnp.arange(C, dtype=jnp.int32)
-    if window is not None:
-        # first key each query attends (0 where the layer has no window)
-        lo = jnp.where(window > 0, start + rel - window + 1, 0)  # (C,)
-
-    def online(carry, s, mask, vb):
-        """One online-softmax update. s: (KV, G, C, T) f32 scores,
-        mask (C, T) or (T,); explicit zeroing of masked probabilities —
-        relying on exp(-1e30 - m) underflow alone breaks the moment a
-        stale pool row is non-finite (NaN * 0 = NaN)."""
-        m, l, acc = carry
-        mb = jnp.broadcast_to(mask, s.shape[-2:])[None, None]
-        s = jnp.where(mb, s, -1e30)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.where(mb, jnp.exp(s - m_new[..., None]), 0.0)
-        l_new = l * alpha + jnp.sum(p, axis=-1)
-        acc_new = (acc * alpha[..., None]
-                   + jnp.einsum("kgct,tkh->kgch", p.astype(cd), vb,
-                                preferred_element_type=jnp.float32))
-        return m_new, l_new, acc_new
-
-    def dequant_block(pool, scales, pages):
-        g = pool[pages]                         # (bp, KV, page, hd)
-        if scales is not None:
-            from ..ops.kv_quant import dequantize_rows
-            g = dequantize_rows(g, scales[pages], cd)
-        return g.swapaxes(1, 2).reshape(tblk, KV, hd).astype(cd)
-
-    def block(carry, bi):
-        def live(carry):
-            pages = jax.lax.dynamic_slice(tbl, (bi * block_pages,),
-                                          (block_pages,))
-            kb = dequant_block(kc, ksc, pages)
-            vb = dequant_block(vc, vsc, pages)
-            t = bi * tblk + jnp.arange(tblk, dtype=jnp.int32)
-            s = jnp.einsum("ckgh,tkh->kgct", qf, kb,
-                           preferred_element_type=jnp.float32) * scale
-            # prefix rows only: pool rows at/past `start` are stale
-            # (this chunk's own rows land post-scan) — and every prefix
-            # row is causally visible to every chunk query (t < start)
-            mask = t < start
-            # ... and the V rows no query may read are zeroed, not only
-            # their probabilities: a block that holds the end of the
-            # prefix also holds pages past it — this chunk's own, stale,
-            # and past the extent the TRASH page, where the decode kernel
-            # parks idle slots' rows beside whatever its scratch held.
-            # One non-finite value there and 0 x NaN = NaN reaches every
-            # query of the chunk through the PV product (PERF.md section
-            # 7 row 1: a request answers with garbage from then on).
-            vb = jnp.where(mask[:, None, None], vb, 0)
-            if window is not None:
-                mask = mask[None, :] & (t[None, :] >= lo[:, None])
-            return online(carry, s, mask, vb)
-        # blocks wholly past the prefix would be gathered then fully
-        # masked — skip their HBM reads and matmuls at runtime; so
-        # would blocks wholly behind the first query's window
-        wanted = bi * tblk < start
-        if window is not None:
-            wanted = wanted & ((bi + 1) * tblk > lo[0])
-        return jax.lax.cond(wanted, live, lambda c: c, carry), None
-
-    m0 = jnp.full((KV, G, C), -1e30, jnp.float32)
-    l0 = jnp.zeros((KV, G, C), jnp.float32)
-    acc0 = jnp.zeros((KV, G, C, hd), jnp.float32)
-    (m, l, acc), _ = jax.lax.scan(
-        block, (m0, l0, acc0), jnp.arange(nb, dtype=jnp.int32))
-
-    # the chunk itself, ALSO in key blocks — a dense (KV, G, C, C) f32
-    # score tensor at C=2048 on 7B is 512 MB/layer, the transient the
-    # chunked-attention machinery exists to avoid
-    sb = min(C, 512)
-    while C % sb:
-        sb //= 2
-    ks, vs = k_self[0], v_self[0]               # (C, KV, hd)
-
-    def self_block(carry, si):
-        kb = jax.lax.dynamic_slice(ks, (si * sb, 0, 0), (sb, KV, hd))
-        vb = jax.lax.dynamic_slice(vs, (si * sb, 0, 0), (sb, KV, hd))
-        tloc = si * sb + jnp.arange(sb, dtype=jnp.int32)
-        s = jnp.einsum("ckgh,tkh->kgct", qf, kb,
-                       preferred_element_type=jnp.float32) * scale
-        ok = (tloc[None, :] <= rel[:, None]) \
-            & ((start + tloc) < kv_valid_len[0])[None, :]
-        if window is not None:
-            ok = ok & ((start + tloc)[None, :] >= lo[:, None])
-        return online(carry, s, ok, vb), None
-
-    (m, l, acc), _ = jax.lax.scan(
-        self_block, (m, l, acc), jnp.arange(C // sb, dtype=jnp.int32))
-    # valid queries attend at least themselves (l > 0); PADDED rows past
-    # kv_valid_len attend nothing — floor the denominator so they yield
-    # zeros, not NaNs that would trip debug tooling downstream
-    out = acc / jnp.maximum(l[..., None], 1e-30)
-    # (KV, G, C, hd) -> (1, C, H, hd)
-    return out.transpose(2, 0, 1, 3).reshape(1, C, H, hd).astype(q.dtype)
 
 
 def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
@@ -888,11 +675,11 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     B, C = tokens.shape
     if B != 1:
         raise ValueError("apply_prefill_paged is single-request (B=1)")
-    page = kv_cache["k"].shape[3]  # (L, N, KV, page, hd)
+    kvc = kv_cache_of(cfg)
+    page = kvc.page_size(kv_cache)
     if C % page:
         raise ValueError(f"chunk {C} not a page ({page}) multiple")
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
-                                cfg.rope_scaling_factor)
+    inv_freq = _inv_freq(cfg)
     h = _embed(params, tokens, cfg.embed_scale)
     start = positions[0, 0]  # absolute position of the chunk's first row
 
@@ -902,10 +689,8 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
         # the one post-scan scatter. Never materializes the full
         # gathered window — prefix length does not bound this path's
         # memory.
-        attn = _paged_prefix_attention(
-            q, k, v, kv_cache["k"], kv_cache["v"], kv_cache.get("ks"),
-            kv_cache.get("vs"), block_table, start, kv_valid_len, page,
-            cfg, window=lp.get("window"), layer=li)
+        attn = kvc.attend_prefix(q, k, v, lp, kv_cache, block_table, start,
+                                 kv_valid_len, li)
         return attn, (k[0], v[0])
 
     h, (new_k, new_v), _ = _run_model(params, cfg, h, positions, inv_freq,
@@ -913,7 +698,7 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     # new_k/new_v: (L, C, KV, hd), to the chunk's physical pages
     dest = jax.lax.dynamic_slice(block_table[0], (start_page_idx,),
                                  (C // page,))
-    cache = _write_pool(kv_cache, new_k, new_v, dest)
+    cache = kvc.write(kv_cache, new_k, new_v, dest)
     if not with_logits:
         return h, cache
     return unembed(params, cfg, h), cache
@@ -965,7 +750,8 @@ def _moe_mlp(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
         if router_logits is None:
             with jax.named_scope("moe_route"):
                 router_logits = _router_logits(x, lp)
-        out, touched = dropless_moe_ffn(x, router_logits, lp, cfg, row_mask)
+        out, touched = dropless_moe_ffn(x, router_logits, lp, cfg, row_mask,
+                                        aux)
         if aux is not None:
             aux["experts_touched"] = touched
         return out
@@ -1025,47 +811,50 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
         # the router reads the stream as it enters the block
         with jax.named_scope("moe_route"):
             router_logits = _router_logits(h, lp)
-    window = lp.get("window")
     with jax.named_scope("attn_proj"):
         x = block_norm(h, lp, "attn_norm", cfg)
-        q = qmm(x, lp["wq"])
-        k = qmm(x, lp["wk"])
-        v = qmm(x, lp["wv"])
-        if "bq" in lp:
-            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-        if cfg.attn_gate:
-            gate = jax.nn.sigmoid(qmm(x, lp["wz"]).astype(jnp.float32))
-        # Keep the head split OUT of the matmuls. Without the barrier the
-        # TPU compiler folds `reshape(B, S, H, hd)` into each dot and
-        # emits a convolution over the head axis whose kernel is the
-        # weight viewed [K, H, hd] and wanted K-minor: the whole stacked
-        # weight is then transposed into a temporary once a program, and
-        # each layer's slice is copied out of it BEFORE its matmul
-        # instead of streaming through it. With it the three compile as
-        # wo / w_up / w_down do: one fusion that takes (stack, layer
-        # index, scale, x), the slice inside, the stack read in place
-        # (tests/test_chip_compile.py holds this on the compiled text).
-        q, k, v = jax.lax.optimization_barrier((q, k, v))
-        q = q.reshape(B, S, cfg.num_heads, cfg.head_dim)
-        k = k.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
-        v = v.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
-        if cfg.qk_norm:         # before the rotation: the pool's keys
-            q = rmsnorm(q, lp["q_norm"], cfg.rms_norm_eps)
-            k = rmsnorm(k, lp["k_norm"], cfg.rms_norm_eps)
-        if "rope" in lp:
-            qr, kr = apply_rope(q, k, positions, inv_freq)
-            q, k = jnp.where(lp["rope"], qr, q), jnp.where(lp["rope"], kr, k)
+        if cfg.kv_lora_rank:
+            q, k, v = _latent_qkv(x, lp, cfg, positions, inv_freq)
         else:
-            q, k = apply_rope(q, k, positions, inv_freq)
+            q = qmm(x, lp["wq"])
+            k = qmm(x, lp["wk"])
+            v = qmm(x, lp["wv"])
+            if "bq" in lp:
+                q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+            if cfg.attn_gate:
+                gate = jax.nn.sigmoid(qmm(x, lp["wz"]).astype(jnp.float32))
+            # Keep the head split OUT of the matmuls. Without the barrier the
+            # TPU compiler folds `reshape(B, S, H, hd)` into each dot and
+            # emits a convolution over the head axis whose kernel is the
+            # weight viewed [K, H, hd] and wanted K-minor: the whole stacked
+            # weight is then transposed into a temporary once a program, and
+            # each layer's slice is copied out of it BEFORE its matmul
+            # instead of streaming through it. With it the three compile as
+            # wo / w_up / w_down do: one fusion that takes (stack, layer
+            # index, scale, x), the slice inside, the stack read in place
+            # (tests/test_chip_compile.py holds this on the compiled text).
+            q, k, v = jax.lax.optimization_barrier((q, k, v))
+            q = q.reshape(B, S, cfg.num_heads, cfg.head_dim)
+            k = k.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
+            v = v.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
+            if cfg.qk_norm:         # before the rotation: the pool's keys
+                q = rmsnorm(q, lp["q_norm"], cfg.rms_norm_eps)
+                k = rmsnorm(k, lp["k_norm"], cfg.rms_norm_eps)
+            if "rope" in lp:
+                qr, kr = apply_rope(q, k, positions, inv_freq)
+                q, k = jnp.where(lp["rope"], qr, q), jnp.where(lp["rope"], kr, k)
+            else:
+                q, k = apply_rope(q, k, positions, inv_freq)
     with jax.named_scope("attn"):
         if attend is not None:
             attn, new_cache = attend(q, k, v)
         else:
-            attn = gqa_attention(q, k, v, positions, kv_valid_len,
-                                 window=window)
+            attn = kv_cache_of(cfg).attend_tokens(q, k, v, lp, positions,
+                                                  kv_valid_len)
             new_cache = None
     with jax.named_scope("attn_proj"):
-        attn = attn.reshape(B, S, cfg.q_dim)
+        attn = attn.reshape(
+            B, S, cfg.num_heads * (cfg.v_head_dim or cfg.head_dim))
         if cfg.attn_gate:
             attn = (attn * gate).astype(attn.dtype)
         attn_out = qmm(attn, lp["wo"])
@@ -1096,6 +885,31 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
         return h + mlp, new_cache
 
 
+def _latent_qkv(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
+                positions: jax.Array, inv_freq: jax.Array):
+    """A latent-attention layer's projections of its normed input ``x``
+    (B, S, D): ``(q, c, k_r)`` — the queries (B, S, H, nope + rope)
+    through the low-rank pair ``wq_a`` (normed) and ``wq_b``, their rope
+    part rotated; the normed latent (B, S, R); the ONE rotated key part
+    (B, S, rope) all heads share. ``(c, k_r)`` is what a token leaves in
+    the cache; how the heads' keys and values come out of it, expanded or
+    absorbed, is the cache object's (models/kv_cache.py)."""
+    B, S, _ = x.shape
+    R, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    q = qmm(rmsnorm(qmm(x, lp["wq_a"]), lp["q_a_norm"], cfg.rms_norm_eps),
+            lp["wq_b"])
+    kv = qmm(x, lp["wkv_a"])
+    # the head split stays out of the matmul (``decoder_layer`` says why)
+    q, kv = jax.lax.optimization_barrier((q, kv))
+    q = q.reshape(B, S, cfg.num_heads, cfg.head_dim)
+    c = rmsnorm(kv[..., :R], lp["kv_a_norm"], cfg.rms_norm_eps)
+    q_r, k_r = q[..., nope:], kv[..., None, R:]
+    if cfg.rope_interleave:     # pairs (2i, 2i+1) as published
+        q_r, k_r = deinterleave(q_r), deinterleave(k_r)
+    q_r, k_r = apply_rope(q_r, k_r, positions, inv_freq)
+    return jnp.concatenate([q[..., :nope], q_r], axis=-1), c, k_r[:, :, 0]
+
+
 def run_layers(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
                positions: jax.Array,
                kv_valid_len: Optional[jax.Array] = None) -> jax.Array:
@@ -1104,8 +918,7 @@ def run_layers(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
     if jax.tree.leaves(layers)[0].shape[0] != cfg.num_layers:
         # a stage does not know which of the model's layers it holds
         _refuse_kinds(cfg, "a partial layer stack")
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
-                                cfg.rope_scaling_factor)
+    inv_freq = _inv_freq(cfg)
     return _run_stack(layers, cfg, h, positions, inv_freq, kv_valid_len)[0]
 
 
@@ -1222,28 +1035,26 @@ def apply(params: Params, cfg: LlamaConfig, tokens: jax.Array,
                  causal masking only.
     Returns (logits (B,S,V) or hidden (B,S,D), updated cache or None).
     """
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
-                                cfg.rope_scaling_factor)
+    inv_freq = _inv_freq(cfg)
     attend = xs = None
     if kv_cache is not None:
         if kv_valid_len is None:
             kv_valid_len = positions[:, -1] + 1
         row_start = positions[:, 0]
-        # Write this chunk at its absolute positions (rows contiguous).
-        put = jax.vmap(
-            lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s, 0, 0)))
-        xs = {"cache_k": kv_cache["k"], "cache_v": kv_cache["v"]}
+        kvc = kv_cache_of(cfg)
+        xs = {"cache_" + n: kv_cache[n] for n in kvc.leaves}
 
         def attend(q, k, v, lp, li, _):
-            kc = put(lp["cache_k"], k, row_start)   # (B,T,KV,hd)
-            vc = put(lp["cache_v"], v, row_start)
-            return gqa_attention(q, kc, vc, positions, kv_valid_len,
-                                 window=lp.get("window")), (kc, vc)
+            # this chunk written at its absolute positions
+            kc, vc = kvc.put_dense(lp, k, v, row_start)     # (B,T,KV,hd)
+            return kvc.attend_tokens(q, kc, vc, lp, positions,
+                                     kv_valid_len), (kc, vc)
 
     h, new, _ = _run_model(
         params, cfg, _embed(params, tokens, cfg.embed_scale), positions,
         inv_freq, kv_valid_len, attend, xs=xs)
-    new_cache = None if new is None else dict(zip("kv", new))
+    new_cache = None if new is None else dict(
+        zip(kv_cache_of(cfg).leaves, new))
     if return_hidden:
         return unembed_norm(params, cfg, h), new_cache
     return unembed(params, cfg, h), new_cache
@@ -1279,8 +1090,7 @@ def apply_sp(params: Params, cfg: LlamaConfig, tokens: jax.Array,
 
     n_sp = validate_sp_mesh(mesh, tokens.shape[1], "apply_sp")
     _refuse_kinds(cfg, "apply_sp")
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
-                                cfg.rope_scaling_factor)
+    inv_freq = _inv_freq(cfg)
     dp = "dp" if int(mesh.shape.get("dp", 1)) > 1 else None
 
     def fwd(tokens_l, positions_l, params_l):
@@ -1304,11 +1114,12 @@ def apply_sp(params: Params, cfg: LlamaConfig, tokens: jax.Array,
 def _refuse_kinds(cfg: LlamaConfig, fn_name: str) -> None:
     """Paths that scan the raw layer tree (ring attention, which has no
     window mask either; a pipeline stage's partial stack)."""
-    if layer_kinds(cfg) or cfg.embed_scale != 1.0 or (
+    if layer_kinds(cfg) or cfg.embed_scale != 1.0 or cfg.kv_lora_rank or (
             cfg.num_experts and cfg.moe_impl == "dropless"):
         raise NotImplementedError(
             f"{fn_name}: per-layer kinds, dropless experts (and so a "
-            f"second stack) and an embedding multiplier are not "
+            f"second stack and an expert share), latent attention "
+            f"(kv_lora_rank) and an embedding multiplier are not "
             f"supported here")
 
 
@@ -1362,8 +1173,7 @@ def apply_prefill_sp(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     B, S = tokens.shape
     n_sp = validate_sp_mesh(mesh, S, "apply_prefill_sp")
     _refuse_kinds(cfg, "apply_prefill_sp")
-    inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta,
-                                cfg.rope_scaling_factor)
+    inv_freq = _inv_freq(cfg)
     # serving prefill is B=1: batch shards over dp only when divisible,
     # otherwise the dp groups replicate the (identical) work
     n_dp = int(mesh.shape.get("dp", 1))

@@ -167,7 +167,7 @@ class RoundRecord:
         # execution (harvest thread)
         "harvest_wait_ms", "first_readback_ms", "emit_ms",
         "tokens_emitted", "first_tokens", "spec_accepted",
-        "experts_touched", "tail_resort_pct",
+        "experts_touched", "tail_resort_pct", "local_assignments",
         # finalization
         "device_ms", "round_ms", "bw_util", "drift_ratio", "done",
         # bookkeeping
@@ -238,6 +238,12 @@ class RoundRecord:
         # _merge_tile), mean over the round's steps: a scalar a SAMPLED
         # round's program returns (harvest thread). 0 for a greedy one.
         self.tail_resort_pct = 0.0
+        # Assignments a decode step's rows made to the experts THIS tree
+        # holds (an expert share: models/configs.py ``experts_held``),
+        # mean over the expert layers and the round's steps: a scalar
+        # the decode program returns beside ``experts_touched``. 0
+        # where the tree holds every expert.
+        self.local_assignments = 0.0
         self.device_ms = 0.0
         self.round_ms = 0.0
         self.bw_util = 0.0
@@ -290,6 +296,7 @@ class RoundRecord:
                 "kv_pages_skipped": round(self.kv_pages_skipped, 2),
                 "experts_touched": round(self.experts_touched, 2),
                 "tail_resort_pct": round(self.tail_resort_pct, 2),
+                "local_assignments": round(self.local_assignments, 2),
                 "kv_restore_pages": self.kv_restore_pages,
                 "hbm_bytes_est": self.hbm_bytes,
                 "bw_util": round(self.bw_util, 4),
@@ -384,7 +391,8 @@ class RoundRecorder:
                       harvest_wait_ms: float = 0.0,
                       emit_ms: float = 0.0,
                       experts_touched: float = 0.0,
-                      tail_resort_pct: float = 0.0) -> None:
+                      tail_resort_pct: float = 0.0,
+                      local_assignments: float = 0.0) -> None:
         """One harvested device output of this round (harvest thread).
         The last part — once the scheduler has sealed the expected
         count — finalizes the record."""
@@ -398,6 +406,8 @@ class RoundRecorder:
             rec.experts_touched = float(experts_touched)
         if tail_resort_pct:
             rec.tail_resort_pct = float(tail_resort_pct)
+        if local_assignments:
+            rec.local_assignments = float(local_assignments)
         finalize = False
         with self._lock:
             rec._done_parts += 1

@@ -41,7 +41,9 @@ QTensor = dict[str, jax.Array]
 # rest). Routed expert stacks (L, E, K, N) stay as they are, and so do
 # the router, its bias and every norm.
 _QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "wz", "w_gate", "w_up",
-                     "w_down", "ws_gate", "ws_up", "ws_down")
+                     "w_down", "ws_gate", "ws_up", "ws_down",
+                     # latent attention's five (models/configs.py)
+                     "wq_a", "wq_b", "wkv_a", "wk_b", "wv_b")
 _LAYER_STACKS = ("layers", "dense_layers")
 
 
@@ -88,6 +90,18 @@ def _unpack4(q4: jax.Array) -> jax.Array:
 
 def _int_weights(w: QTensor) -> jax.Array:
     return _unpack4(w["q4"]) if "q4" in w else w["q"]
+
+
+def int_weights_and_scale(w: Union[jax.Array, QTensor]):
+    """``(weights, scale)`` of a raw or per-channel-quantized tensor for a
+    caller that contracts it its own way: the integers as stored (int4
+    unpacked) and the (..., N) column scales, or the raw array and
+    None."""
+    if not is_quantized(w):
+        return w, None
+    if is_grouped(w):
+        raise ValueError("group-wise quantization has no column scale")
+    return _int_weights(w), w["scale"]
 
 
 def quantize_tensor_grouped(w: jax.Array, group_size: int = 128) -> QTensor:

@@ -9,6 +9,8 @@ just different), which is what XLA wants: no data-dependent shapes.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -24,6 +26,43 @@ def rope_frequencies(head_dim: int, theta: float = 10000.0,
     exponents = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
     inv_freq = 1.0 / (theta ** exponents)
     return inv_freq / scaling_factor
+
+
+def yarn_frequencies(dim: int, theta: float, factor: float,
+                     original_max: int, beta_fast: float = 32.0,
+                     beta_slow: float = 1.0) -> jax.Array:
+    """YaRN's inverse frequencies over a rotary part of ``dim`` values,
+    shape (dim // 2,), float32 (Peng et al. 2023, "NTK-by-parts"; the
+    arithmetic is that of transformers' ``_compute_yarn_parameters``).
+
+    A frequency that turns more than ``beta_fast`` times within
+    ``original_max`` positions is kept; one that turns fewer than
+    ``beta_slow`` times is divided by ``factor`` (its positions
+    interpolated); between the two indices the blend is linear. The
+    score multiplier that goes with it is the configuration's
+    (``LlamaConfig.score_scale``), not a scale on cos and sin.
+    """
+    def index_of(turns: float) -> float:
+        # the (fractional) frequency index that turns ``turns`` times
+        return (dim * math.log(original_max / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(index_of(beta_fast)), 0)
+    high = min(math.ceil(index_of(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001                   # as published: no singularity
+    plain = rope_frequencies(dim, theta)
+    idx = jnp.arange(dim // 2, dtype=jnp.float32)
+    kept = 1.0 - jnp.clip((idx - low) / (high - low), 0.0, 1.0)
+    return plain / factor * (1.0 - kept) + plain * kept
+
+
+def deinterleave(x: jax.Array) -> jax.Array:
+    """Rotary pairs published as (2i, 2i+1) brought to the (i, i + half)
+    layout ``apply_rope`` rotates: the even columns, then the odd. Applied
+    to queries and keys alike, so their products are the published
+    ones."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
 
 
 def apply_rope(q: jax.Array, k: jax.Array, positions: jax.Array,

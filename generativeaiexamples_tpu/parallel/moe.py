@@ -179,7 +179,8 @@ def dropless_block_rows(n_tokens: int) -> int:
 
 def route_sorted(select: jax.Array, k: int, bm: int,
                  row_mask: jax.Array | None = None,
-                 weigh: jax.Array | None = None) -> dict:
+                 weigh: jax.Array | None = None,
+                 share: tuple[int, int] | None = None) -> dict:
     """Top-k routing laid out for a grouped product: the T*k assignments
     sorted by expert, each expert's run padded to whole ``bm``-row blocks.
 
@@ -196,7 +197,9 @@ def route_sorted(select: jax.Array, k: int, bm: int,
       block_expert  (NB,)        expert of each block
       n_blocks      ()           blocks that hold rows (the loop's bound)
       touched       ()           distinct experts with at least one row
-    with R = NB * bm, NB = T*k // bm + E (static).
+      assigned      ()           assignments that hold a row (``share``)
+      held          (T, k) bool  assignments to experts held here (``share``)
+    with R = NB * bm, NB = T*k // bm + E (static; E the count held).
     """
     T, E = select.shape
     A = T * k
@@ -206,17 +209,25 @@ def route_sorted(select: jax.Array, k: int, bm: int,
     else:
         weight = jnp.take_along_axis(weigh, idx, axis=1)
     expert = idx.reshape(A).astype(jnp.int32)                   # token-major
+    held = None
+    if share is not None:
+        first, E = share
+        expert = expert - first
+        held = (expert >= 0) & (expert < E)
+        expert = jnp.clip(expert, 0, E - 1)
     # A counting sort, not ``argsort``: a claim's rank among its expert's
     # claims is a running count down its expert's column (token order is
     # kept, as ``route_topk`` keeps it) — one cumsum where a sort, a
     # search and two scatters were (1.6 ms of a 13 ms decode step, 5 ms
     # of a 45 ms chunk; chip, PR 28).
     claims = jax.nn.one_hot(expert, E, dtype=jnp.int32)         # (A, E)
-    claimed = jnp.ones((A,), bool)
+    claimed = jnp.ones((A,), bool) if held is None else held
     if row_mask is not None:        # idle rows claim nothing
-        claimed = jnp.repeat(row_mask, k)
-        claims = claims * claimed[:, None].astype(jnp.int32)
+        claimed = jnp.repeat(row_mask, k) if held is None \
+            else held & jnp.repeat(row_mask, k)
         weight = weight * row_mask[:, None]
+    if row_mask is not None or held is not None:
+        claims = claims * claimed[:, None].astype(jnp.int32)
     rank = jnp.take_along_axis(jnp.cumsum(claims, axis=0) - claims,
                                expert[:, None], axis=1)[:, 0]
     counts = jnp.sum(claims, axis=0)                            # (E,)
@@ -234,11 +245,15 @@ def route_sorted(select: jax.Array, k: int, bm: int,
     src = jnp.full((NB * bm,), -1, jnp.int32).at[
         jnp.where(claimed, row_of, NB * bm)].set(
         jnp.arange(A, dtype=jnp.int32), mode="drop")
-    return {"weight": weight, "token": jnp.maximum(src, 0) // k,
-            "valid": src >= 0,
-            "row_of": jnp.where(claimed, row_of, 0).reshape(T, k),
-            "block_expert": block_expert, "n_blocks": bend[-1],
-            "touched": jnp.sum(counts > 0).astype(jnp.float32)}
+    rt = {"weight": weight, "token": jnp.maximum(src, 0) // k,
+          "valid": src >= 0,
+          "row_of": jnp.where(claimed, row_of, 0).reshape(T, k),
+          "block_expert": block_expert, "n_blocks": bend[-1],
+          "touched": jnp.sum(counts > 0).astype(jnp.float32)}
+    if held is not None:
+        rt["held"] = held.reshape(T, k)
+        rt["assigned"] = jnp.sum(counts).astype(jnp.float32)
+    return rt
 
 
 def router_scores(logits: jax.Array, lp: dict[str, jax.Array],
@@ -294,7 +309,8 @@ def block_loop_ffn(x_pad: jax.Array, block_expert: jax.Array,
 
 def dropless_moe_ffn(x: jax.Array, router_logits: jax.Array,
                      lp: dict[str, jax.Array], cfg: LlamaConfig,
-                     row_mask: jax.Array | None = None):
+                     row_mask: jax.Array | None = None,
+                     aux: dict | None = None):
     """Dropless sparse MoE layer: (B, S, D) -> ((B, S, D), touched).
 
     ``router_logits`` (B, S, E) are the caller's (they may come from
@@ -302,6 +318,16 @@ def dropless_moe_ffn(x: jax.Array, router_logits: jax.Array,
     experts whatever the batch, and a token's output does not depend on
     its neighbours. ``touched`` is the number of distinct experts the
     rows reached: the experts whose weights this call read.
+
+    Under an expert share (``cfg.experts_held``: ``lp`` holds the
+    matrices of experts ``experts_first`` .. + ``experts_held`` only) the
+    router still scores all ``num_experts`` and a token's k weights are
+    normalised over its k; what falls on experts held elsewhere takes no
+    row and no weight read here, and the output is the PARTIAL sum over
+    the held experts — what this chip adds before the exchange that
+    nothing here stands in for. ``touched`` then counts among the held,
+    and ``aux`` (a dict, if given) receives ``local_assignments``, the
+    assignments that fell on them.
 
     ``lp`` holds the layer's own (E, in, out) expert stacks or — where
     the caller keeps the stacks out of its layer scan and hands
@@ -321,10 +347,17 @@ def dropless_moe_ffn(x: jax.Array, router_logits: jax.Array,
     with jax.named_scope("moe_route"):
         select, weigh = router_scores(
             router_logits.reshape(T, cfg.num_experts), lp, cfg)
+        share = (cfg.experts_first, cfg.experts_held) \
+            if cfg.experts_held else None
         rt = route_sorted(
             select, k, bm,
-            None if row_mask is None else jnp.repeat(row_mask, S), weigh)
+            None if row_mask is None else jnp.repeat(row_mask, S), weigh,
+            share)
         rt["weight"] = scale_chosen(rt["weight"], cfg)
+        if share:       # after the k were normalised together
+            rt["weight"] = rt["weight"] * rt["held"]
+            if aux is not None:
+                aux["local_assignments"] = rt["assigned"]
         x_pad = jnp.where(rt["valid"][:, None], x_flat[rt["token"]], 0)
 
     ffn = (grouped_ffn.grouped_expert_ffn

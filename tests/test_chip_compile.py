@@ -130,6 +130,32 @@ def test_paged_attention_kernel_compiles(topo, batch, heads, kv_heads,
             else "paged_attn_decode") in text
 
 
+@pytest.mark.parametrize("batch", [32, 8], ids=["b32", "b8"])
+def test_latent_attention_kernel_compiles(topo, batch):
+    """The latent decode kernel at the published widths of the one
+    configuration that has a latent cache: 64 heads over a 512 + 64 row,
+    pages of 128, the rotary pool transposed."""
+    from generativeaiexamples_tpu.ops.latent_attention import (
+        kernel_supported, latent_attention_decode)
+    dev = SingleDeviceSharding(topo.devices[0])
+    H, R, rope = 64, 512, 64
+    assert kernel_supported(PAGE, R, rope)
+    L, N, W = 2, batch * 4 + 1, 8
+    bf = lambda *shape: sds(shape, jnp.bfloat16, dev)   # noqa: E731
+    i32 = lambda *shape: sds(shape, jnp.int32, dev)     # noqa: E731
+
+    def step(qc, qr, pc, pr, tbl, lens, cc, cr, wp, off, li):
+        return latent_attention_decode(qc, qr, pc, pr, tbl, lens, cc, cr,
+                                       wp, off, li, scale=0.13)
+
+    compiled = jax.jit(step, donate_argnums=(2, 3)).lower(
+        bf(batch, H, R), bf(batch, H, rope), bf(L, N, 1, PAGE, R),
+        bf(L, N, 1, rope, PAGE), i32(batch, W), i32(batch), bf(batch, R),
+        bf(batch, rope), i32(batch), i32(batch), i32(1)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "latent_attn_decode" in text
+
+
 @pytest.mark.parametrize("group", [0, 128], ids=["perchannel", "g128"])
 @pytest.mark.parametrize("K,N,M", [
     (4096, 11008, 8),           # gate/up projection, decode rows

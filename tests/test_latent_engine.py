@@ -1,0 +1,165 @@
+"""The engine end to end over a tiny latent model with an expert share
+(``Engine.submit``, the scheduler, the one paged pool, chunk programs,
+decode rounds, the fused tail), on the CPU: its greedy tokens are the
+plain forward's; a prefix-cache hit and a speculative verify round work
+over the latent pool (pages are pages); and everything that cannot take
+a latent pool or a share yet refuses it BY NAME when the engine is
+configured."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from generativeaiexamples_tpu.engine.engine import (Engine, EngineConfig,
+                                                    SamplingParams)
+from generativeaiexamples_tpu.models import llama
+from generativeaiexamples_tpu.models.tokenizer import ByteTokenizer
+from generativeaiexamples_tpu.obs.rounds import RoundRecorder
+from generativeaiexamples_tpu.utils.errors import ConfigError, EngineError
+
+from test_latent_attention import CFG as LATENT
+
+CFG = dataclasses.replace(LATENT, experts_held=4, experts_first=4)
+ENGINE = dict(max_slots=4, max_input_length=512, max_output_length=32,
+              prefill_buckets=(128,), max_prefill_bucket=128, page_size=128,
+              steps_per_round=4, kv_pool_tokens=None, dtype="float32")
+N_OUT = 10
+
+
+@pytest.fixture(scope="module")
+def params():
+    return llama.init_params(CFG, jax.random.key(3), dtype=jnp.float32)
+
+
+def prompt(n, seed):
+    return [int(t) for t in np.random.default_rng(seed).integers(3, 250, n)]
+
+
+def plain_greedy(params, ids, n):
+    """The plain forward's own greedy chain, no cache."""
+    ids = list(ids)
+    for _ in range(n):
+        logits, _ = llama.apply(params, CFG, jnp.asarray(ids)[None],
+                                jnp.arange(len(ids))[None])
+        ids.append(int(jnp.argmax(logits[0, -1])))
+    return ids[-n:]
+
+
+def serve(engine, ids, n=N_OUT):
+    s = engine.submit(ids, SamplingParams(max_tokens=n, temperature=0.0,
+                                          ignore_eos=True))
+    list(s)
+    assert s.finish_reason == "length"
+    return list(s.token_ids)
+
+
+def make_engine(params, **kw):
+    """An engine with a round recorder of its own: the process-wide ring
+    is other test files' too."""
+    eng = Engine(params, CFG, ByteTokenizer(), EngineConfig(**ENGINE, **kw))
+    eng.rounds = RoundRecorder(cap=512)
+    return eng
+
+
+@pytest.fixture(scope="module")
+def engine(params):
+    eng = make_engine(params)
+    eng.start()
+    yield eng
+    eng.stop()
+
+
+@pytest.mark.parametrize("n", [300, 50], ids=["three_chunks", "one_bucket"])
+def test_engine_tokens_are_the_plain_forwards(engine, params, n):
+    """300 tokens: three 128-token chunks, the later ones reading the
+    earlier back from the latent pool; 50: the bucket prefill (dense
+    latent cache, then whole pages inserted). Then decode rounds."""
+    ids = prompt(n, n)
+    assert serve(engine, ids) == plain_greedy(params, ids, N_OUT)
+
+
+def test_the_share_and_the_cache_are_counted(engine):
+    serve(engine, prompt(40, 7))
+    st = engine.stats
+    assert st["kv_bytes_per_token"] == CFG.num_layers * (128 + 16) * 4
+    assert st["local_assignments_rounds"] > 0
+    assert st["local_assignments_rounds"] == st["experts_touched_rounds"]
+    # one live row: each of its assignments that stays here is an expert
+    mean = st["local_assignments_sum"] / st["local_assignments_rounds"]
+    assert 0 < mean <= CFG.num_experts_per_tok
+    assert any(r.local_assignments > 0 for r in engine.rounds.records())
+
+
+def test_a_prefix_cache_hit_reads_the_latent_pool(engine, params):
+    ids = prompt(290, 11)
+    first = serve(engine, ids)
+    hits0 = engine.stats["prefix_cache_hit_tokens"]
+    again = serve(engine, ids)
+    assert engine.stats["prefix_cache_hit_tokens"] >= hits0 + 256
+    assert again == first == plain_greedy(params, ids, N_OUT)
+
+
+def test_speculative_verify_runs_over_the_latent_pool(params, monkeypatch):
+    # single tokens match: whatever the model says that the prompt holds
+    # too gives the prompt-lookup drafter something to propose
+    monkeypatch.setenv("SPEC_NGRAM_MIN", "1")
+    eng = make_engine(params, spec_decode=True, spec_max_draft_tokens=3)
+    eng.start()
+    try:
+        ids = list(range(3, 253)) + list(range(3, 200))
+        got = serve(eng, ids, 24)
+        assert eng.stats["spec_verify_rounds"] > 0
+        assert eng.stats["spec_draft_tokens"] > 0
+    finally:
+        eng.stop()
+    assert got == plain_greedy(params, ids, 24)
+
+
+def test_suspend_and_resume_refuse(engine):
+    """They ship host-tier blobs, and the tier refuses a latent pool."""
+    with pytest.raises(EngineError, match="tiering is disabled"):
+        engine.suspend_session(prompt(200, 1))
+    with pytest.raises(EngineError, match="tiering is disabled"):
+        engine.resume_session(b"")
+
+
+# --------------------------------------------- refused at configuration
+
+
+def refused(params, match, mesh=None, **kw):
+    with pytest.raises(ConfigError, match=match):
+        Engine(params, CFG, ByteTokenizer(),
+               EngineConfig(**{**ENGINE, **kw}), mesh=mesh)
+
+
+def test_an_int8_kv_pool_is_refused(params):
+    refused(params, "int8 KV pool", kv_quant="int8")
+
+
+def test_the_host_kv_tier_is_refused(params, monkeypatch):
+    refused(params, "host KV tier", kv_host_pool_tokens=4096)
+    monkeypatch.setenv("KV_HOST_POOL_TOKENS", "4096")
+    refused(params, "host KV tier")
+
+
+@pytest.mark.parametrize("role", ["prefill", "decode"])
+def test_prefill_decode_handoff_is_refused(params, role):
+    refused(params, "handoff", role=role)
+
+
+@pytest.mark.parametrize("axis", ["tp", "sp"])
+def test_a_tp_or_sp_mesh_is_refused(params, axis):
+    from jax.sharding import Mesh
+    devs = np.array(jax.devices()[:2])
+    refused(params, f"{axis} mesh",
+            mesh=Mesh(devs.reshape(1, 2), ("dp", axis)))
+
+
+def test_an_expert_share_under_an_ep_mesh_is_refused(params):
+    from jax.sharding import Mesh
+    devs = np.array(jax.devices()[:2])
+    refused(params, "expert share under an ep or tp mesh",
+            mesh=Mesh(devs.reshape(1, 2), ("dp", "ep")))
