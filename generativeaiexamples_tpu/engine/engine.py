@@ -754,6 +754,21 @@ class Engine:
                 f"decode kernel (heads {model_cfg.num_heads}/"
                 f"{model_cfg.num_kv_heads} must divide tp, pp must be 1)")
         self._pin_layouts = self._use_kernel
+        # A chunk program's attention as the Pallas chunk kernel
+        # (ops/chunk_attention.py), where the cache object has one
+        # (LatentKV): armed with the decode kernel, or a named downgrade.
+        has_prefix_kernel = hasattr(kv_cache_of(model_cfg),
+                                    "prefix_kernel_supported")
+        self._use_prefix_kernel = (
+            self._use_kernel and llama.use_prefix_kernel(model_cfg, page))
+        if (self._use_kernel and has_prefix_kernel
+                and not self._use_prefix_kernel):
+            self._note_downgrade(
+                "prefix_kernel", "jnp_blocks",
+                f"page {page}, key / value / rotary widths "
+                f"{model_cfg.qk_nope_head_dim} / {model_cfg.v_head_dim} / "
+                f"{model_cfg.qk_rope_head_dim}: the chunk kernel takes "
+                f"lane-width pages and keys, whole sublane tiles of values")
 
         # Page pool: physical page 0 is the trash page (never allocated);
         # the allocator hands out 1..n_pages-1.
@@ -2090,7 +2105,7 @@ class Engine:
                 _, cache = llama.apply_prefill_paged(
                     params, mcfg, tokens, positions, state["cache"],
                     row_win, valid[None], start // self.cfg.page_size,
-                    with_logits=False)
+                    with_logits=False, use_kernel=self._use_prefix_kernel)
                 # Round-telemetry completion marker: a scalar OUTPUT
                 # that data-depends on the chunk's paged prefill, so a
                 # host readback of it blocks until this program has
@@ -2130,7 +2145,7 @@ class Engine:
                 h, cache = llama.apply_prefill_paged(
                     params, mcfg, tokens, positions, state["cache"],
                     row_win, valid[None], start // self.cfg.page_size,
-                    with_logits=False)
+                    with_logits=False, use_kernel=self._use_prefix_kernel)
                 seen = self._chunk_seen(state, tokens, start, valid, slot,
                                         "seed" if seed else "accum",
                                         *seed0)

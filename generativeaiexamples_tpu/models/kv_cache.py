@@ -529,6 +529,12 @@ class LatentKV:
         from ..ops.latent_attention import kernel_supported
         return kernel_supported(page, self.R, self.rope)
 
+    def prefix_kernel_supported(self, page: int) -> bool:
+        """Whether ``attend_prefix`` can run the chunk kernel
+        (ops/chunk_attention.py) at this geometry."""
+        from ..ops.chunk_attention import kernel_supported
+        return kernel_supported(page, self.nope, self.vd, self.rope)
+
     def pool_spec(self, mesh, quantized: bool = False) -> dict:
         """Replicated: the latent is common to all heads, so a ``tp``
         mesh has nothing of it to split (the engine refuses one by
@@ -632,14 +638,23 @@ class LatentKV:
         return head_product(o_c, lp["wv_b"], self.cfg.num_heads)
 
     def attend_prefix(self, q, c, k_r, lp, kv_cache, block_table, start,
-                      kv_valid_len, layer, block_pages: int = 4):
+                      kv_valid_len, layer, block_pages: int = 4,
+                      use_kernel: bool = False):
         """A chunk's attention, EXPANDED: the chunk's own tokens and each
         ``block_pages``-page block of the prefix it reads back from the
         latent pool are taken through ``wk_b`` / ``wv_b`` (one expansion
         a block serves all C x H queries), under one online softmax.
         B = 1. Pool rows at or past ``start`` are stale or another
         slot's: masked, and zeroed BEFORE the expansion (the trash page
-        may hold anything; ``HeadKV``'s reader says why)."""
+        may hold anything; ``HeadKV``'s reader says why).
+
+        ``use_kernel``: a block's update of the softmax state — scores,
+        mask, maximum, exponent, sum, PV product — is the Pallas kernel
+        of ops/chunk_attention.py, the (H, T, C) float32 scores on the
+        chip; False is the same update as jnp operations (the CPU, and
+        what the kernel is held against). The block loop, the gather,
+        the zeroing and the expansion are the same either way."""
+        from ..ops import chunk_attention as ca
         cfg = self.cfg
         _, C, H, _ = q.shape
         nope, vd, rope, R = self.nope, self.vd, self.rope, self.R
@@ -653,33 +668,49 @@ class LatentKV:
         pc = pc.reshape((-1,) + pc.shape[3:])               # (L*N, page, R)
         pr = pr.reshape((-1,) + pr.shape[3:])               # (L*N, rope, page)
         cd = q.dtype
-        qn, qr = self._split(q[0])                          # (C, H, .)
         tblk = block_pages * page
         rel = jnp.arange(C, dtype=jnp.int32)
 
         def expand(cb):
-            return (qmm(cb, lp["wk_b"]).reshape(-1, H, nope),
-                    qmm(cb, lp["wv_b"]).reshape(-1, H, vd))
+            """Every head's keys (T, H * nope) and values (T, H * vd)."""
+            return qmm(cb, lp["wk_b"]), qmm(cb, lp["wv_b"])
 
-        def scores(kb, rb):
-            return (jnp.einsum("chj,thj->hct", qn, kb,
-                               preferred_element_type=jnp.float32)
-                    + jnp.einsum("chr,tr->hct", qr, rb,
-                                 preferred_element_type=jnp.float32)
-                    ) * scale
+        # One block folded into the carry. The kernel builds its mask from
+        # three positions (the block's first key, the limit past which keys
+        # are masked, ``causal``); the jnp form takes the mask made.
+        if use_kernel:
+            qh = q[0].transpose(1, 0, 2)                    # (H, C, .)
+            interp = jax.default_backend() != "tpu"
+            carry0 = ca.init_carry(H, C, vd)
 
-        def online(carry, s, mask, vb):
-            m, l, acc = carry
-            mb = jnp.broadcast_to(mask, s.shape[-2:])[None]
-            s = jnp.where(mb, s, -1e30)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.where(mb, jnp.exp(s - m_new[..., None]), 0.0)
-            l_new = l * alpha + jnp.sum(p, axis=-1)
-            acc_new = (acc * alpha[..., None]
-                       + jnp.einsum("hct,thv->hcv", p.astype(cd), vb,
-                                    preferred_element_type=jnp.float32))
-            return m_new, l_new, acc_new
+            def update(carry, kb, rb, vb, mask, k0, limit, causal):
+                return ca.chunk_attention_update(
+                    qh, kb, vb.T, carry, k0, limit, start, scale=scale,
+                    causal=causal, k_shared=rb, interpret=interp)
+        else:
+            qn, qr = self._split(q[0])                      # (C, H, .)
+            carry0 = (jnp.full((H, C), -1e30, jnp.float32),
+                      jnp.zeros((H, C), jnp.float32),
+                      jnp.zeros((H, C, vd), jnp.float32))
+
+            def update(carry, kb, rb, vb, mask, k0, limit, causal):
+                m, l, acc = carry
+                kb, vb = kb.reshape(-1, H, nope), vb.reshape(-1, H, vd)
+                s = (jnp.einsum("chj,thj->hct", qn, kb,
+                                preferred_element_type=jnp.float32)
+                     + jnp.einsum("chr,tr->hct", qr, rb,
+                                  preferred_element_type=jnp.float32)
+                     ) * scale
+                mb = jnp.broadcast_to(mask, s.shape[-2:])[None]
+                s = jnp.where(mb, s, -1e30)
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.where(mb, jnp.exp(s - m_new[..., None]), 0.0)
+                l_new = l * alpha + jnp.sum(p, axis=-1)
+                acc_new = (acc * alpha[..., None]
+                           + jnp.einsum("hct,thv->hcv", p.astype(cd), vb,
+                                        preferred_element_type=jnp.float32))
+                return m_new, l_new, acc_new
 
         def block(carry, bi):
             def live(carry):
@@ -694,33 +725,35 @@ class LatentKV:
                     pr[pages].swapaxes(1, 2).reshape(tblk, rope).astype(cd),
                     0)
                 kb, vb = expand(cb)
-                return online(carry, scores(kb, rb), mask, vb)
+                return update(carry, kb, rb, vb, mask, bi * tblk, start,
+                              False)
             return jax.lax.cond(bi * tblk < start, live, lambda c: c,
                                 carry), None
 
-        m0 = jnp.full((H, C), -1e30, jnp.float32)
-        l0 = jnp.zeros((H, C), jnp.float32)
-        acc0 = jnp.zeros((H, C, vd), jnp.float32)
-        (m, l, acc), _ = jax.lax.scan(
-            block, (m0, l0, acc0), jnp.arange(nb, dtype=jnp.int32))
+        carry, _ = jax.lax.scan(block, carry0,
+                                jnp.arange(nb, dtype=jnp.int32))
 
         sb = min(C, 512)
         while C % sb:
             sb //= 2
-        ks, vs = expand(c[0])                               # (C, H, .)
+        ks, vs = expand(c[0])                               # (C, H * .)
         rs = k_r[0]
 
         def self_block(carry, si):
-            kb = jax.lax.dynamic_slice(ks, (si * sb, 0, 0), (sb, H, nope))
-            vb = jax.lax.dynamic_slice(vs, (si * sb, 0, 0), (sb, H, vd))
+            kb = jax.lax.dynamic_slice(ks, (si * sb, 0), (sb, H * nope))
+            vb = jax.lax.dynamic_slice(vs, (si * sb, 0), (sb, H * vd))
             rb = jax.lax.dynamic_slice(rs, (si * sb, 0), (sb, rope))
             tloc = si * sb + jnp.arange(sb, dtype=jnp.int32)
             ok = (tloc[None, :] <= rel[:, None]) \
                 & ((start + tloc) < kv_valid_len[0])[None, :]
-            return online(carry, scores(kb, rb), ok, vb), None
+            return update(carry, kb, rb, vb, ok, start + si * sb,
+                          kv_valid_len[0], True), None
 
-        (m, l, acc), _ = jax.lax.scan(
-            self_block, (m, l, acc), jnp.arange(C // sb, dtype=jnp.int32))
+        carry, _ = jax.lax.scan(
+            self_block, carry, jnp.arange(C // sb, dtype=jnp.int32))
+        if use_kernel:
+            return ca.finish(carry, q.dtype)[None]
+        m, l, acc = carry
         out = acc / jnp.maximum(l[..., None], 1e-30)        # (H, C, vd)
         return out.transpose(1, 0, 2)[None].astype(q.dtype)
 

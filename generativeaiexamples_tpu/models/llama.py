@@ -106,6 +106,17 @@ def use_paged_kernel(cfg: LlamaConfig, page: int) -> bool:
         return ok
     return ok and jax.default_backend() == "tpu"
 
+
+def use_prefix_kernel(cfg: LlamaConfig, page: int) -> bool:
+    """Whether a chunk program's attention runs the Pallas kernel of
+    ops/chunk_attention.py: where the decode kernel runs
+    (``use_paged_kernel``) and the cache object has a chunk kernel for
+    this geometry (``LatentKV.prefix_kernel_supported``; ``HeadKV`` has
+    none)."""
+    supported = getattr(kv_cache_of(cfg), "prefix_kernel_supported", None)
+    return (supported is not None and supported(page)
+            and use_paged_kernel(cfg, page))
+
 Params = dict[str, Any]
 
 
@@ -647,6 +658,7 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
                         block_table: jax.Array, kv_valid_len: jax.Array,
                         start_page_idx: jax.Array, *,
                         with_logits: bool = False,
+                        use_kernel: Optional[bool] = None,
                         ) -> tuple[jax.Array, KVCache]:
     """One CHUNK of a long-prompt prefill over the paged KV pool (B=1).
 
@@ -667,6 +679,10 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     engine unembeds only the sampling position; ``with_logits=True``
     returns full (1, C, V) logits instead (a large transient at big
     vocab x chunk; only for callers that truly need every position).
+    ``use_kernel``: the engine decides (None = auto, as
+    ``apply_decode_paged``): True runs a block's softmax update as the
+    Pallas chunk kernel, where the cache object has one
+    (``use_prefix_kernel``).
 
     The pool is held, the rows come out (``_run_stack``): a layer's
     prefix blocks are gathered out of the whole pool by (layer, page),
@@ -679,6 +695,9 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     page = kvc.page_size(kv_cache)
     if C % page:
         raise ValueError(f"chunk {C} not a page ({page}) multiple")
+    if use_kernel is None:
+        use_kernel = use_prefix_kernel(cfg, page)
+    kernel = {"use_kernel": True} if use_kernel else {}
     inv_freq = _inv_freq(cfg)
     h = _embed(params, tokens, cfg.embed_scale)
     start = positions[0, 0]  # absolute position of the chunk's first row
@@ -690,7 +709,7 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
         # gathered window — prefix length does not bound this path's
         # memory.
         attn = kvc.attend_prefix(q, k, v, lp, kv_cache, block_table, start,
-                                 kv_valid_len, li)
+                                 kv_valid_len, li, **kernel)
         return attn, (k[0], v[0])
 
     h, (new_k, new_v), _ = _run_model(params, cfg, h, positions, inv_freq,

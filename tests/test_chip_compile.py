@@ -14,6 +14,7 @@ embed the INTERPRETED kernel, or skip it.
 
 import dataclasses
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -575,6 +576,55 @@ def test_chunk_program_reads_the_pool_in_place(topo, tpu_backend, model,
     slab = cache["k"].size * cache["k"].dtype.itemsize // layers
     assert m.temp_size_in_bytes < slab, (m.temp_size_in_bytes, slab)
     assert m.alias_size_in_bytes >= pool_bytes, m
+
+
+# ------------------------- where a latent chunk program keeps its scores
+
+
+@pytest.mark.parametrize("form", ["kernel", "jnp"])
+def test_latent_chunk_program_keeps_its_scores_in_the_kernel(
+        topo, tpu_backend, form):
+    """``kimi-k2-instruct``'s 512-token chunk program at published widths
+    (2 layers, one chip's 12 held experts, the cell's 2176-page pool and
+    68-page window) compiles for the described chip with the chunk kernel
+    in it — VMEM fits, no misaligned slice — and its compiled text holds
+    NO float32 array of heads x chunk x block = 64 x 512 x 512 (67 MB)
+    anywhere: as jnp blocks the same program holds over a hundred such
+    instructions (ISSUE 37: they were a quarter of the cell's device
+    time), which is what the ``jnp`` case shows the parse can see."""
+    from tools.dump_hlo import parse_hlo
+    cfg = dataclasses.replace(get_model_config("kimi-k2-instruct"),
+                              num_layers=2, experts_held=12, experts_first=0)
+    dev = SingleDeviceSharding(topo.devices[0])
+    cache = on(jax.eval_shape(
+        lambda: llama.init_paged_kv_cache(cfg, 2177, PAGE)), dev)
+    i32 = lambda *shape: sds(shape, jnp.int32, dev)  # noqa: E731
+    assert llama.use_prefix_kernel(cfg, PAGE)
+
+    def chunk(params, tok, pos, cache, tbl, valid, start):
+        return llama.apply_prefill_paged(params, cfg, tok, pos, cache, tbl,
+                                         valid, start,
+                                         use_kernel=form == "kernel")
+
+    compiled = jax.jit(chunk, donate_argnums=(3,)).lower(
+        on(param_shapes(cfg), dev), i32(1, 512), i32(1, 512), cache,
+        i32(1, 68), i32(1), i32()).compile()
+    assert_fits(compiled)
+    text = compiled.as_text()
+    tile = sorted((cfg.num_heads, 512, 512))
+    tiles = [i["name"] for ins in parse_hlo(text).values() for i in ins
+             if (m := re.match(r"f32\[([\d,]+)\]", i["shape"])) and sorted(
+                 int(d) for d in m.group(1).split(",") if d != "1") == tile]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if form == "jnp":
+        assert len(tiles) > 50 and "chunk_attn" not in text
+        return
+    assert tiles == []
+    # the prefix blocks' and the chunk's own update, both stacks
+    assert text.count('custom_call_target="tpu_custom_call"') >= 4
+    assert "%chunk_attn" in text
+    # 120.6 MB where the jnp blocks take 233.7 (compile, PR 37)
+    assert temp < 160 << 20, temp
 
 
 _POOL = "bf16[2,9,4,16,64]{4,3,2,1,0:T(8,128)(2,1)}"

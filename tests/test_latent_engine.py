@@ -163,3 +163,42 @@ def test_an_expert_share_under_an_ep_mesh_is_refused(params):
     devs = np.array(jax.devices()[:2])
     refused(params, "expert share under an ep or tp mesh",
             mesh=Mesh(devs.reshape(1, 2), ("dp", "ep")))
+
+
+# ------------------------------------------------- the chunk kernel armed
+
+
+def test_an_engine_that_cannot_arm_the_chunk_kernel_says_so(params,
+                                                            monkeypatch):
+    """With the decode kernel wanted (here by the environment: the CPU
+    does not want it by itself), heads 32 wide are no lane-aligned slice
+    of a key block: ONE downgrade, named ``prefix_kernel``."""
+    monkeypatch.setenv("GENAI_TPU_PAGED_KERNEL", "1")
+    eng = make_engine(params)
+    assert eng._use_kernel and not eng._use_prefix_kernel
+    assert [(d["feature"], d["fallback"]) for d in eng.downgrades] \
+        == [("prefix_kernel", "jnp_blocks")]
+    assert eng.stats["downgrades"] == 1
+
+
+def test_a_fully_armed_engine_reports_no_downgrade(monkeypatch):
+    """The published head's widths (128 | 64, values 128): both kernels
+    armed, interpreted here; a three-chunk prompt's tokens are the plain
+    forward's."""
+    monkeypatch.setenv("GENAI_TPU_PAGED_KERNEL", "1")
+    cfg = dataclasses.replace(CFG, num_heads=2, head_dim=192,
+                              qk_nope_head_dim=128, qk_rope_head_dim=64,
+                              v_head_dim=128)
+    p = llama.init_params(cfg, jax.random.key(5), dtype=jnp.float32)
+    eng = Engine(p, cfg, ByteTokenizer(), EngineConfig(**ENGINE))
+    assert eng._use_kernel and eng._use_prefix_kernel
+    assert eng.downgrades == [] and eng.stats["downgrades"] == 0
+    ids = prompt(300, 3)
+    with eng:
+        got = serve(eng, ids, 4)
+    want = list(ids)
+    for _ in range(4):
+        logits, _ = llama.apply(p, cfg, jnp.asarray(want)[None],
+                                jnp.arange(len(want))[None])
+        want.append(int(jnp.argmax(logits[0, -1])))
+    assert got == want[-4:]
