@@ -66,6 +66,7 @@ from ..models.kv_cache import kv_cache_of
 from ..models.tokenizer import Tokenizer
 from ..obs import flight as obs_flight
 from ..obs import rounds as obs_rounds
+from ..obs.metrics import observe_stage
 from ..obs.tracing import phase, record_stage
 from ..ops.fused_sampler import (choose_tile, fused_unembed_sample,
                                  fused_unembed_sample_tp,
@@ -484,6 +485,13 @@ class TokenStream:
         self.timeline: Optional[obs_flight.Timeline] = None
         self.owns_timeline = True
         self._flight: Optional[obs_flight.FlightRecorder] = None
+        # The request's place in its span tree (obs/flight.py Span): the
+        # root, the state it is in now (exactly one is open between
+        # submit and finish), and the newest engine round it was seen
+        # under — what the terminal transition stamps.
+        self.root: Optional[obs_flight.Span] = None
+        self.state: Optional[obs_flight.Span] = None
+        self.round_id = -1
         self._q: "queue.Queue[tuple[str, object]]" = queue.Queue()
         self._error: Optional[BaseException] = None
         self.finish_reason: Optional[str] = None
@@ -499,6 +507,21 @@ class TokenStream:
     def _put_chunk(self, text: str) -> None:
         if text:
             self._q.put(("chunk", text))
+
+    def _enter(self, name: str, round_id: int = -1,
+               t: Optional[float] = None, cause: Optional[str] = None,
+               n: int = 0, m: int = 0) -> None:
+        """One state transition of this request, stamped once by the
+        thread that makes it: the state it was in ends and ``name``
+        begins at ``t``, under round ``round_id``. A finished request
+        (its root closed by whichever thread ended it) moves no more."""
+        root = self.root
+        if root is None or root.t1 is not None:
+            return
+        self.round_id = round_id
+        self.state = self.timeline.enter(
+            self.state, name, time.monotonic() if t is None else t,
+            round_id, cause, n, m)
 
     def _record_done(self) -> None:
         """Retire the timeline on the FIRST terminal transition — every
@@ -1014,6 +1037,19 @@ class Engine:
         # the pool that refused one has not grown since (staged the same
         # way, for the round record's blocked_on_pages).
         self._held_on_pool = 0
+        # What waited on the last plan (staged the same way, for the
+        # round record): backlog requests by cause (slot, pages,
+        # budget) and in-flight prefills the plan granted nothing.
+        self._plan_waiting = (0, 0, 0, 0)
+        # Id of the newest round begun — the one being dispatched, on
+        # the scheduler thread: what a request's state stamps carry
+        # (obs/flight.py Span.round_id0/1).
+        self._round_seq = -1
+        # Requests ever pulled into the backlog, and the count up to
+        # which a plan without a free slot has stamped them ``slot``:
+        # a saturated engine re-stamps nothing until one more arrives.
+        self._pulled = 0
+        self._slot_stamped = -1
         # Active-row ladder for the fused tail: decode rounds gather the
         # armed slots into the smallest rung >= the live count, so the
         # unembed/sampling tail is sized to OCCUPANCY, not max_slots.
@@ -2627,9 +2663,14 @@ class Engine:
         stream = TokenStream(tl.request_id)
         stream.owns_timeline = owns
         tl.annotate(prompt_tokens=prompt_tokens, max_tokens=eff_max)
-        tl.event("engine_submit")
         stream.timeline = tl
         stream._flight = self.flight
+        # The span tree's root and first state both start at the
+        # stream's own submit stamp, so the states sum to finish -
+        # submit and to the stream's ttft exactly.
+        rid = self._round_seq
+        stream.root = tl.enter(None, "request", stream.submit_time, rid)
+        stream._enter("req_intake", rid, t=stream.submit_time)
         return stream
 
     def _resolve_deadline(self, stream: TokenStream,
@@ -2745,11 +2786,10 @@ class Engine:
         timeline is completed by the edge, which turns this exception
         into a structured 429."""
         self._bump("rejected_full")
-        tl = stream.timeline
-        if tl is not None:
-            tl.annotate(finish="rejected")
-            if stream.owns_timeline:
-                self.flight.complete(tl)
+        # the stream never reaches its caller: end it where every other
+        # stream ends, so its spans close and its reason is recorded
+        stream.finish_reason = "rejected"
+        self.flight.complete_stream(stream)
         raise SchedulerFullError(
             f"request queue full ({self.cfg.max_queue})") from None
 
@@ -3564,9 +3604,12 @@ class Engine:
                     wait = ph.seconds
                     self._bump("first_readback_ms", wait * 1e3)
                     self._bump("first_readbacks")
-                    tl = req.stream.timeline
-                    if tl is not None:   # lock-free ring append
-                        tl.stage("engine_first_readback", wait)
+                    st = req.stream
+                    if st.state is not None:
+                        # the blocking wait, inside req_first_token
+                        st.timeline.child(st.state, "req_readback", t0,
+                                          t0 + wait, rid)
+                        st.round_id = rid
                     if self._gen != gen:
                         return
                     emitted_first = not req.done
@@ -3609,6 +3652,9 @@ class Engine:
                     if self._gen != gen:
                         return
                     emitted: dict[int, int] = {}
+                    for req in members.values():
+                        # what a finish inside this round is stamped with
+                        req.stream.round_id = rid
                     with phase("engine_emit", round_id=rid,
                                tokens=int((toks[:, list(members)] >= 0)
                                           .sum())) as ph:
@@ -3634,9 +3680,11 @@ class Engine:
                         # recorder's token-path budget. Ring appends
                         # are lock-free.
                         for slot, n in emitted.items():
-                            tl = members[slot].stream.timeline
-                            if tl is not None:
-                                tl.event("decode_round", n)
+                            st = members[slot].stream
+                            if st.timeline is not None:
+                                st.timeline.event("decode_round", n)
+                                if st.state is not None:
+                                    st.state.m += 1   # req_decode's rounds
                     accepted = 0
                     if kind == "verify":
                         accepted = self._finish_verify(members, accs,
@@ -3703,9 +3751,13 @@ class Engine:
         moved = False
         while len(self._backlog) < self.cfg.max_queue:
             try:
-                self._backlog.append(self._pending.get_nowait())
+                entry = self._pending.get_nowait()
             except queue.Empty:
                 break
+            self._backlog.append(entry)
+            # req_intake ends: the wait's cause is the next plan's to say
+            entry[0].stream._enter("req_backlog", self._round_seq)
+            self._pulled += 1
             moved = True
         return moved
 
@@ -3725,10 +3777,8 @@ class Engine:
                 continue
             if req.deadline_t is not None and now > req.deadline_t:
                 self._bump("deadline_queue_drops")
-                tl = req.stream.timeline
-                if tl is not None:
-                    tl.stage("engine_admit_pickup",
-                             now - req.stream.submit_time)
+                # _finish closes the open req_backlog span: the queue
+                # wait of a dropped request reads from it like any other
                 req.stream._finish("deadline_queue")
                 did = True
                 continue
@@ -3799,7 +3849,17 @@ class Engine:
         backlog_jobs = []
         self._held_on_pool = 0
         now = time.monotonic()
-        if self._free_slots:
+        # What each backlog request waits on is said here, where it is
+        # known, and stamped on its req_backlog span when it changes.
+        n_slot = n_pages = 0
+        if not self._free_slots:
+            n_slot = len(self._backlog)
+            if n_slot and self._slot_stamped != self._pulled:
+                for req, _sp in self._backlog:
+                    self._wait_on(req, "slot", now)
+                self._slot_stamped = self._pulled
+        else:
+            self._slot_stamped = -1
             for req, _sp in self._backlog:
                 # Pre-admission estimate: the full prompt (a prefix-cache
                 # hit is only discovered at admission and can only SHRINK
@@ -3818,20 +3878,52 @@ class Engine:
             # more pages than it could then. A grant for a request that
             # cannot start is a whole chunk program taken from the
             # prefills in flight.
+            backlog_jobs = self._sched.order(backlog_jobs, now)
             if any(j.key.refused_avail is not None for j in backlog_jobs):
                 avail = self._pool_avail_pages()
-                backlog_jobs = self._sched.order(backlog_jobs, now)
                 for n, job in enumerate(backlog_jobs):
                     if job.key.refused_avail is not None \
                             and avail <= job.key.refused_avail:
                         self._held_on_pool = 1
+                        n_pages = len(backlog_jobs) - n
+                        for held in backlog_jobs[n:]:
+                            self._wait_on(held.key, "pages", now)
                         del backlog_jobs[n:]
                         break
-        return self._sched.plan_round(
+            # The planner is offered as many as can start (plan_round
+            # would cut the ordered list at max_new itself): the rest
+            # wait for a slot, not for the budget.
+            free = len(self._free_slots)
+            n_slot = max(0, len(backlog_jobs) - free)
+            for late in backlog_jobs[free:]:
+                self._wait_on(late.key, "slot", now)
+            del backlog_jobs[free:]
+        plan = self._sched.plan_round(
             decode_steps=steps, active_decodes=len(armed),
             inflight=inflight, backlog=backlog_jobs,
             now=now, max_new=len(self._free_slots),
             decode_cost_tokens=verify_cost)
+        granted = {id(key) for key, _ in plan.chunks}
+        n_budget = 0
+        for job in backlog_jobs:
+            if id(job.key) not in granted:
+                n_budget += 1
+                self._wait_on(job.key, "budget", now)
+        self._plan_waiting = (
+            n_slot, n_pages, n_budget,
+            sum(1 for j in inflight if id(j.key) not in granted))
+        return plan
+
+    def _wait_on(self, req: _Request, cause: str, t: float) -> None:
+        """Stamp what a backlog request waits on (scheduler thread): the
+        first plan after the pull names the open ``req_backlog`` span, a
+        CHANGE of cause opens a new one — one span a cause, not one a
+        round."""
+        st = req.stream
+        sp = st.state
+        if sp is not None and sp.cause != cause and sp.t1 is None \
+                and sp.name == "req_backlog":
+            st.state = st.timeline.recause(sp, cause, t, self._round_seq)
 
     def _any_draftable(self, armed) -> bool:
         """Cheap hint: could any armed slot propose >= 1 draft token
@@ -3917,6 +4009,12 @@ class Engine:
         # Requests the planner was not offered because the pool that
         # refused them has not grown since wait on pages this round too.
         rec.blocked_on_pages = self._held_on_pool
+        # What waited on this round when it was planned, and the decode
+        # rounds queued on the device ahead of its first program.
+        self._round_seq = rec.round_id
+        (rec.waiting_slot, rec.waiting_pages, rec.waiting_budget,
+         rec.prefill_ungranted) = self._plan_waiting
+        rec.queued_ahead = self._queued_rounds()
         try:
             with phase("engine_round", round_id=rec.round_id,
                        kind=rec.kind, t_mono_ns=time.monotonic_ns()):
@@ -3963,9 +4061,13 @@ class Engine:
         marker = None
         if plan.chunks:
             with phase("loop_admit", round_id=rid) as ph:
-                for key, grant in plan.chunks:
+                # a backlog request granted a chunk it could not start:
+                # why, for it and for the grants behind it
+                stopped_on = None
+                for at, (key, grant) in enumerate(plan.chunks):
                     req: _Request = key
                     if req.slot < 0 and not self._free_slots:
+                        stopped_on = "slot"
                         break
                     with phase("chunk_dispatch", round_id=rid,
                                request_id=req.stream.request_id,
@@ -3980,6 +4082,7 @@ class Engine:
                         continue
                     if not ok:         # pool backpressure: stop admitting
                         rec.blocked_on_pages += 1
+                        stopped_on = "pages"
                         break
                     self._guard_live()
                     if n:
@@ -3993,6 +4096,11 @@ class Engine:
                         # the weights once and writes its tokens' KV.
                         rec.hbm_bytes += self._param_bytes \
                             + n * self._kv_bytes_per_token()
+                if stopped_on is not None:
+                    now = time.monotonic()
+                    for key, _ in plan.chunks[at:]:
+                        if key.slot < 0:
+                            self._wait_on(key, stopped_on, now)
                 ph.record = bool(prefilled)
         if prefilled:
             self._bump("sched_prefill_tokens", prefilled)
@@ -4204,8 +4312,12 @@ class Engine:
                 st.hit_tokens += start_tok
 
         now = time.monotonic()
-        qwait = now - req.stream.submit_time
-        record_stage("engine_admit_pickup", qwait)
+        # The wait is over: req_backlog ends and req_prefill begins at
+        # this one stamp (the timeline's engine_admit_pickup is read
+        # off the spans; the stage histogram gets the same number).
+        req.stream._enter("req_prefill", self._round_seq, t=now,
+                          n=len(req.prompt_ids), m=start_tok)
+        observe_stage("engine_admit_pickup", now - req.stream.submit_time)
         if req.deadline_t is not None:
             # Slack at admission: the headroom left after the modeled
             # prefill of the UNCACHED suffix. Clamped at 0 — the
@@ -4218,10 +4330,8 @@ class Engine:
             record_stage("sched_slack", max(slack, 0.0))
         tl = req.stream.timeline
         if tl is not None:
-            # Scheduler-side timeline events: queue wait, the slot
-            # and pages this request occupies, and how much of the
-            # prompt the prefix cache already held.
-            tl.stage("engine_admit_pickup", qwait)
+            # The slot and pages this request occupies, and how much of
+            # the prompt the prefix cache already held.
             tl.annotate(slot=slot, pages_held=len(req.pages),
                         prefix_hit_tokens=start_tok)
         # Masks/tables were built at submit() on the caller's thread
@@ -4404,17 +4514,24 @@ class Engine:
                 marker = first_tok
             self._guard_live()
             self._state = new_state
-        dt = time.monotonic() - t_chunk
-        pf["dispatch_s"] += dt
-        tl = req.stream.timeline
-        if tl is not None:
-            # Host-side dispatch time of this chunk (the device work
-            # is async); one event per chunk.
-            tl.stage("engine_prefill_chunk", dt)
+        t_done = time.monotonic()
+        pf["dispatch_s"] += t_done - t_chunk
+        self._chunk_span(req, t_chunk, t_done, n, self._bucket_for(n))
         req.pf_pos += n
         if final:
             self._arm_slot(req, first_tok, rec)
         return n, marker
+
+    def _chunk_span(self, req: _Request, t0: float, t1: float,
+                    tokens: int, padded: int) -> None:
+        """One chunk's host dispatch (the device work is async) as a
+        ``req_chunk`` child of the request's ``req_prefill`` span; the
+        id of the round being dispatched joins it to the
+        ``engine_round`` and ``chunk_dispatch`` spans of a trace."""
+        st = req.stream
+        if st.state is not None:
+            st.timeline.child(st.state, "req_chunk", t0, t1,
+                              self._round_seq, tokens, padded)
 
     def _arm_slot(self, req: _Request, first_tok, rec=None) -> None:
         """Prefill complete: publish cache blocks, mark the slot armed
@@ -4426,13 +4543,11 @@ class Engine:
         token itself) to it."""
         pf = req.pf
         self._register_prefix(req, pf["hashes"], pf["k_use"])
-        record_stage("engine_admit_dispatch", pf["dispatch_s"])
-        tl = req.stream.timeline
-        if tl is not None:
-            # Cumulative host dispatch time across every chunk of this
-            # admission — the same meaning the one-dispatch path always
-            # had, now summed over the interleaved pieces.
-            tl.stage("engine_admit_dispatch", pf["dispatch_s"])
+        # Cumulative host dispatch time across every chunk of this
+        # admission (the timeline reads the same sum off its req_chunk
+        # spans); req_prefill ends and req_first_token begins.
+        observe_stage("engine_admit_dispatch", pf["dispatch_s"])
+        req.stream._enter("req_first_token", self._round_seq)
         try:
             # Start the device->host transfer of the first token now —
             # the harvest worker's np.asarray then finds the value
@@ -4474,7 +4589,9 @@ class Engine:
             req.greedy)
         self._guard_live()
         self._state = new_state
-        pf["dispatch_s"] += time.monotonic() - t0
+        t1 = time.monotonic()
+        pf["dispatch_s"] += t1 - t0
+        self._chunk_span(req, t0, t1, fused.spec.bucket, fused.spec.bucket)
         self._arm_slot(req, first_tok, rec)
         return fused.spec.bucket, first_tok
 
@@ -4709,12 +4826,13 @@ class Engine:
             req.stream.first_token_time = time.monotonic()
             ttft = req.stream.first_token_time - req.stream.submit_time
             # Once per request, not per token. The single authoritative
-            # engine_ttft record: timeline + stage histogram/collector
-            # (EngineLLM deliberately does not re-report it).
-            record_stage("engine_ttft", ttft)
-            tl = req.stream.timeline
-            if tl is not None:
-                tl.stage("engine_ttft", ttft)
+            # engine_ttft record (EngineLLM deliberately does not
+            # re-report it): req_first_token ends and req_decode begins
+            # at the stream's own first-token stamp, and the stage
+            # histogram gets the same number.
+            req.stream._enter("req_decode", req.stream.round_id,
+                              t=req.stream.first_token_time)
+            observe_stage("engine_ttft", ttft)
 
         finish: Optional[str] = None
         if token == self.tokenizer.eos_id and not req.params.ignore_eos:

@@ -8,9 +8,14 @@ request slow?* Every request entering the serving path gets
   the W3C ``traceparent`` trace-id) at the HTTP edge, minted otherwise,
   and threaded through the chains layer into ``Engine.submit()`` via a
   contextvar — no signature changes through ``BaseExample``;
-- a **timeline**: a preallocated per-request event ring recording queue
-  wait, admission dispatch, prefix-cache hit length, prefill chunks,
-  first token, per-round token counts, and the finish/cancel reason.
+- a **timeline**: the request's life as a SPAN TREE (``Timeline.spans``:
+  one state at every instant between submit and finish, each wait
+  stamped with its cause and the engine round it began and ended
+  under, one child per prefill chunk — append-only, outside the ring,
+  so a long request keeps every boundary), beside a preallocated event
+  ring for what happens once a round (per-round token counts, draft
+  acceptances) and the chains' stage durations, and the finish/cancel
+  reason.
 
 Concurrency contract (the token-path budget): timeline appends are O(1)
 slot writes into a preallocated ring, indexed by an atomic-under-GIL
@@ -138,8 +143,71 @@ def record_current_stage(name: str, seconds: float) -> None:
         tl.stage(name, seconds)
 
 
+#: States of a request inside the engine, in order: exactly one is open
+#: at every instant between ``submit`` and the finish (``request`` is
+#: their root; ``req_chunk`` and ``req_readback`` are children).
+REQUEST_STATES = ("req_intake", "req_backlog", "req_prefill",
+                  "req_first_token", "req_decode")
+#: What a ``req_backlog`` span may wait on: no free slot; the KV pool
+#: refused the admission (or it is held behind one the pool refused);
+#: offered to the round planner and given no grant.
+WAIT_CAUSES = ("slot", "pages", "budget")
+#: Spans one timeline keeps. The state boundaries of a request are at
+#: most 8 + its chunks; the cap only bounds what a timeline shared by
+#: many engine calls (agent chains) or a backlog whose cause flips every
+#: round may hold. Past it, chunk children and changes of cause are
+#: counted (``spans_dropped``), never a state boundary.
+SPAN_CAP = 512
+
+
+class Span:
+    """One node of a request's span tree, on ``time.monotonic``.
+
+    ``round_id0`` / ``round_id1``: the newest engine round begun when
+    the span was opened / closed (the round being dispatched or
+    harvested where the stamp is made inside one) — the link to
+    ``/debug/rounds`` and, through the ``engine_round`` span's
+    ``t_mono_ns``, to a profiler trace. ``cause``: what a
+    ``req_backlog`` span waits on (:data:`WAIT_CAUSES`); on the root,
+    the finish reason. ``n`` / ``m``: the span's two counts —
+    ``req_prefill`` prompt / prefix-hit tokens, ``req_chunk`` tokens /
+    padded tokens, ``req_decode`` tokens / rounds. One writer a
+    boundary: the thread that makes the transition stamps it once."""
+
+    __slots__ = ("name", "t0", "t1", "parent", "round_id0", "round_id1",
+                 "cause", "n", "m")
+
+    def __init__(self, name: str, t0: float, parent: Optional[str] = None,
+                 round_id: int = -1, cause: Optional[str] = None,
+                 n: int = 0, m: int = 0, t1: Optional[float] = None):
+        self.name = name
+        self.t0 = t0
+        self.t1 = t1
+        self.parent = parent
+        self.round_id0 = round_id
+        self.round_id1 = round_id if t1 is not None else -1
+        self.cause = cause
+        self.n = n
+        self.m = m
+
+    def close(self, t: float, round_id: int = -1) -> None:
+        """First close wins (a terminal transition may race the
+        thread that was about to make the next one)."""
+        if self.t1 is None:
+            self.round_id1 = round_id
+            self.t1 = t
+
+    @property
+    def seconds(self) -> Optional[float]:
+        return None if self.t1 is None else self.t1 - self.t0
+
+
 class Timeline:
-    """Event ring for one request.
+    """Span tree and event ring for one request.
+
+    ``spans`` is the request's life: an append-only list of
+    :class:`Span` (see :meth:`enter`), never overwritten. The ring holds
+    what recurs, and only it may wrap.
 
     Events are ``(seq, t_monotonic, name, value)`` tuples in a
     preallocated ring; value typing is by convention — ``float`` means a
@@ -151,7 +219,8 @@ class Timeline:
     """
 
     __slots__ = ("request_id", "t_start", "wall_start", "meta", "done",
-                 "otel_ctx", "deadline_t", "_events", "_cap", "_seq", "_n")
+                 "otel_ctx", "deadline_t", "spans", "spans_dropped",
+                 "_events", "_cap", "_seq", "_n")
 
     def __init__(self, request_id: str, event_cap: int = 64):
         self.request_id = request_id
@@ -168,6 +237,8 @@ class Timeline:
         # so the retrospective span replay parents engine stages INTO
         # the request's trace instead of emitting disconnected roots.
         self.otel_ctx: Any = None
+        self.spans: list[Span] = []     # append-only; list.append is atomic
+        self.spans_dropped = 0
         self._cap = max(8, int(event_cap))
         self._events: list = [None] * self._cap
         self._seq = itertools.count()   # next() is atomic under the GIL
@@ -190,6 +261,45 @@ class Timeline:
     def annotate(self, **fields: Any) -> None:
         self.meta.update(fields)
 
+    def enter(self, prev: Optional[Span], name: str, t: float,
+              round_id: int = -1, cause: Optional[str] = None,
+              n: int = 0, m: int = 0) -> Span:
+        """One transition of a request's state: close ``prev`` (the
+        state it was in, None at submit) and open ``name`` at the same
+        instant, so the states partition the request's life. The caller
+        keeps the returned span as the request's open state — the
+        timeline keeps none, because one timeline may serve several
+        engine calls (an agent chain's sub-queries)."""
+        if prev is not None:
+            prev.close(t, round_id)
+        sp = Span(name, t, "request" if name != "request" else None,
+                  round_id, cause, n, m)
+        self.spans.append(sp)
+        return sp
+
+    def child(self, parent: Span, name: str, t0: float, t1: float,
+              round_id: int = -1, n: int = 0, m: int = 0) -> None:
+        """A closed child of the open state ``parent`` (one prefill
+        chunk's host dispatch, the first token's readback)."""
+        if len(self.spans) >= SPAN_CAP:
+            self.spans_dropped += 1
+            return
+        self.spans.append(Span(name, t0, parent.name, round_id, None, n, m,
+                               t1=t1))
+
+    def recause(self, state: Span, cause: str, t: float,
+                round_id: int = -1) -> Span:
+        """A wait changed its cause: the first cause names the open
+        span, a different one opens a new span of the same name."""
+        if state.cause is None:
+            state.cause = cause
+        elif state.cause != cause:
+            if len(self.spans) >= SPAN_CAP:
+                self.spans_dropped += 1
+            else:
+                return self.enter(state, state.name, t, round_id, cause)
+        return state
+
     def set_deadline(self, ms: Optional[float]) -> None:
         """Arm this request's deadline, ``ms`` from its start (None/<=0
         clears). Recorded in meta so /debug/requests shows the budget a
@@ -203,10 +313,62 @@ class Timeline:
 
     # ------------------------------------------------------------ readers
 
+    def span_events(self) -> list[tuple]:
+        """The engine's stage events, rendered from the span tree under
+        the names operators know (``(-1, t, name, value)``, the ring's
+        tuple): ``engine_submit`` (the root's start),
+        ``engine_admit_pickup`` (submit to the first grant's dispatch, or
+        to the end of a request that died waiting),
+        ``engine_prefill_chunk`` (each chunk's host dispatch),
+        ``engine_admit_dispatch`` (their sum, at arming),
+        ``engine_first_readback`` (the harvest thread's blocking wait)
+        and ``engine_ttft`` (submit to the first token on the host)."""
+        out: list[tuple] = []
+        t_submit = chunks = 0.0
+        waited: Optional[Span] = None   # last state, while it is a wait
+
+        def pickup() -> None:
+            # the wait is over: at the first grant's dispatch, or where
+            # the request died in the queue (deadline drop, cancel) —
+            # that wait is evidence for admission control too
+            nonlocal waited
+            if waited is not None and waited.t1 is not None:
+                out.append((-1, waited.t1, "engine_admit_pickup",
+                            waited.t1 - t_submit))
+            waited = None
+
+        for sp in list(self.spans):
+            name = sp.name
+            if name == "request":
+                pickup()
+                t_submit, chunks = sp.t0, 0.0
+                out.append((-1, sp.t0, "engine_submit", None))
+            elif name in ("req_intake", "req_backlog"):
+                waited = sp
+            elif name == "req_prefill":
+                pickup()
+            elif name == "req_chunk":
+                chunks += sp.seconds
+                out.append((-1, sp.t1, "engine_prefill_chunk", sp.seconds))
+            elif name == "req_first_token":
+                out.append((-1, sp.t0, "engine_admit_dispatch", chunks))
+            elif name == "req_readback":
+                out.append((-1, sp.t1, "engine_first_readback",
+                            sp.seconds))
+            elif name == "req_decode":
+                out.append((-1, sp.t0, "engine_ttft", sp.t0 - t_submit))
+        pickup()
+        return out
+
     def events_snapshot(self) -> list[tuple]:
-        """Best-effort ordered copy of the ring's live events."""
+        """Best-effort ordered copy of the ring's live events, with the
+        stage events of the span tree (:meth:`span_events`) among them
+        in order of time."""
         items = [e for e in list(self._events) if e is not None]
         items.sort(key=lambda e: e[0])
+        if self.spans:
+            items.extend(self.span_events())
+            items.sort(key=lambda e: e[1])      # stable: ring order kept
         return items
 
     def stage_durations(self) -> dict[str, float]:
@@ -239,9 +401,30 @@ class Timeline:
             "age_ms": round((time.monotonic() - self.t_start) * 1e3, 1),
             "done": self.done,
             "meta": dict(self.meta),
+            "spans": self.spans_dict(),
+            "spans_dropped": self.spans_dropped,
             "events": events,
             "events_dropped": max(0, n - self._cap),
         }
+        return out
+
+    def spans_dict(self, t_ref: Optional[float] = None) -> list[dict]:
+        """The span tree, JSON-ready, times in ms from ``t_ref`` (the
+        timeline's start); an open span has ``t1_ms`` None."""
+        ref = self.t_start if t_ref is None else t_ref
+        out = []
+        for sp in list(self.spans):
+            d: dict[str, Any] = {
+                "span": sp.name, "parent": sp.parent,
+                "t0_ms": round((sp.t0 - ref) * 1e3, 3),
+                "t1_ms": (None if sp.t1 is None
+                          else round((sp.t1 - ref) * 1e3, 3)),
+                "round_id0": sp.round_id0, "round_id1": sp.round_id1}
+            if sp.cause is not None:
+                d["cause"] = sp.cause
+            if sp.n or sp.m:
+                d["n"], d["m"] = sp.n, sp.m
+            out.append(d)
         return out
 
 
@@ -343,9 +526,25 @@ class FlightRecorder:
         ``finish`` tracks the latest sub-call, and the request duration
         is left for ``complete()``'s whole-timeline fallback."""
         tl = getattr(stream, "timeline", None)
+        reason = stream.finish_reason or "unknown"
+        # The span tree's one terminal writer: whichever thread ends the
+        # stream (harvest finish, backlog cull, drain, fatal fan-out)
+        # closes the open state and the root at the same instant —
+        # before the done check, so an edge that retired the timeline
+        # first still leaves no span open.
+        t_end = stream.finish_time or time.monotonic()
+        rid = getattr(stream, "round_id", -1)
+        state = getattr(stream, "state", None)
+        root = getattr(stream, "root", None)
+        if state is not None and state.t1 is None:
+            if state.name == "req_decode":
+                state.n = len(stream.token_ids)
+            state.close(t_end, rid)
+        if root is not None and root.t1 is None:
+            root.cause = reason
+            root.close(t_end, rid)
         if tl is None or tl.done:
             return
-        reason = stream.finish_reason or "unknown"
         owns = getattr(stream, "owns_timeline", True)
         tl.meta["generated"] = (tl.meta.get("generated") or 0) \
             + len(stream.token_ids)

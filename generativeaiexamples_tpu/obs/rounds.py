@@ -158,6 +158,8 @@ class RoundRecord:
         "round_id", "engine_tag", "t_start", "wall_start", "kind",
         "budget_tokens", "decode_steps", "decode_cost_tokens",
         "active_decodes", "plan_ms", "pool_used_pages",
+        "waiting_slot", "waiting_pages", "waiting_budget",
+        "prefill_ungranted", "queued_ahead",
         # dispatch (scheduler thread, filled until seal)
         "decode_slots", "spec_drafted", "verify_positions",
         "prefill_tokens", "prefill_padded_tokens", "grants",
@@ -168,8 +170,9 @@ class RoundRecord:
         "harvest_wait_ms", "first_readback_ms", "emit_ms",
         "tokens_emitted", "first_tokens", "spec_accepted",
         "experts_touched", "tail_resort_pct", "local_assignments",
+        "t_parts",
         # finalization
-        "device_ms", "round_ms", "bw_util", "drift_ratio", "done",
+        "t_done", "device_ms", "round_ms", "bw_util", "drift_ratio", "done",
         # bookkeeping
         "_parts", "_done_parts", "_sealed", "_cb",
     )
@@ -192,6 +195,16 @@ class RoundRecord:
         # Pool occupancy when the round began: pages held by live
         # requests (total - free - evictable prefix-cache pages).
         self.pool_used_pages = 0
+        # What waited on this round when it was planned (scheduler
+        # thread, O(1) a round): backlog requests by the cause stamped
+        # on their req_backlog span (obs/flight.py WAIT_CAUSES),
+        # in-flight prefills the plan granted nothing, and the decode
+        # rounds queued on the device when its first program went out.
+        self.waiting_slot = 0
+        self.waiting_pages = 0
+        self.waiting_budget = 0
+        self.prefill_ungranted = 0
+        self.queued_ahead = 0
         self.decode_slots = 0
         self.spec_drafted = 0
         self.verify_positions = 0
@@ -244,6 +257,14 @@ class RoundRecord:
         # the decode program returns beside ``experts_touched``. 0
         # where the tree holds every expert.
         self.local_assignments = 0.0
+        # The harvest thread's stamp of each completed part, in the
+        # device's FIFO order (the decode output before the chunks'
+        # marker): where a round that dispatched both divides.
+        self.t_parts: list[float] = []
+        # Stamp of the LAST part — the round's completion on the
+        # program's own clock (t_start + round_ms says when the record
+        # was finalized, which the scheduler thread may do later).
+        self.t_done = 0.0
         self.device_ms = 0.0
         self.round_ms = 0.0
         self.bw_util = 0.0
@@ -270,6 +291,10 @@ class RoundRecord:
                 "active_decodes": self.active_decodes,
                 "plan_ms": round(self.plan_ms, 3),
                 "pool_used_pages": self.pool_used_pages,
+                "waiting_slot": self.waiting_slot,
+                "waiting_pages": self.waiting_pages,
+                "waiting_budget": self.waiting_budget,
+                "prefill_ungranted": self.prefill_ungranted,
                 "prefill_grants": [
                     {"request_id": rid, "tokens": n}
                     for rid, n in self.grants],
@@ -281,12 +306,15 @@ class RoundRecord:
                 "prefill_tokens": self.prefill_tokens,
                 "prefill_padded_tokens": self.prefill_padded_tokens,
                 "dispatch_ms": round(self.dispatch_ms, 3),
+                "queued_ahead": self.queued_ahead,
                 "blocked_on_pages": self.blocked_on_pages,
                 "harvest_wait_ms": round(self.harvest_wait_ms, 3),
                 "first_readback_ms": round(self.first_readback_ms, 3),
                 "emit_ms": round(self.emit_ms, 3),
                 "device_ms": round(self.device_ms, 3),
                 "round_ms": round(self.round_ms, 3),
+                "done_ms": (round((self.t_done - self.t_start) * 1e3, 3)
+                            if self.t_done else None),
             },
             "outcome": {
                 "tokens_emitted": self.tokens_emitted,
@@ -408,6 +436,7 @@ class RoundRecorder:
             rec.tail_resort_pct = float(tail_resort_pct)
         if local_assignments:
             rec.local_assignments = float(local_assignments)
+        rec.t_parts.append(time.monotonic())
         finalize = False
         with self._lock:
             rec._done_parts += 1
@@ -431,6 +460,7 @@ class RoundRecorder:
 
     def _finalize(self, rec: RoundRecord) -> None:
         now = time.monotonic()
+        rec.t_done = rec.t_parts[-1] if rec.t_parts else now
         rec.round_ms = (now - rec.t_start) * 1e3
         with self._lock:
             busy_from = max(rec.t_dispatch_done,

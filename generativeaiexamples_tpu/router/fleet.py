@@ -123,6 +123,10 @@ ROUTER_TIMELINE_SCHEMA: dict[str, list[str]] = {
     "age_ms": ["num"],
     "done": ["bool"],
     "meta": ["obj"],
+    # the engine's span tree (obs/flight.py Timeline.spans): the router
+    # runs no engine, so its timelines carry an empty one
+    "spans": ["list"],
+    "spans_dropped": ["int"],
     "events": ["list"],
     "events_dropped": ["int"],
 }

@@ -416,3 +416,226 @@ def test_span_replay_emits_engine_stage_spans(engine, monkeypatch):
     mine = [s for s in spans if s.attributes.get("request.id") == "span-1"]
     assert {"engine_admit_dispatch", "engine_ttft"} <= {s.name
                                                         for s in mine}
+
+
+# ------------------------------------------------------- the span tree
+
+from generativeaiexamples_tpu.obs.flight import (SPAN_CAP,  # noqa: E402
+                                                 WAIT_CAUSES, Span)
+
+
+def _life(tl, t=100.0, chunks=2, cause="slot"):
+    """A whole request stamped by hand: submit at ``t``, 10 ms of
+    intake, 40 ms waiting, ``chunks`` chunks of 5 ms 20 ms apart, 30 ms
+    to the first token (a 12 ms readback), 200 ms of decode."""
+    root = tl.enter(None, "request", t, 3)
+    st = tl.enter(None, "req_intake", t, 3)
+    st = tl.enter(st, "req_backlog", t + 0.010, 3, cause=cause)
+    st = tl.enter(st, "req_prefill", t + 0.050, 5, n=chunks * 16, m=16)
+    at = t + 0.050
+    for k in range(chunks):
+        tl.child(st, "req_chunk", at + 0.001, at + 0.006, 5 + k, 16, 16)
+        at += 0.020
+    st = tl.enter(st, "req_first_token", at, 5 + chunks - 1)
+    tl.child(st, "req_readback", at + 0.010, at + 0.022, 5 + chunks - 1)
+    st = tl.enter(st, "req_decode", at + 0.030, 5 + chunks - 1)
+    st.close(at + 0.230, 40)
+    root.cause = "length"
+    root.close(at + 0.230, 40)
+    return root
+
+
+def test_span_enter_closes_the_state_before_and_close_is_first_wins():
+    tl = Timeline("sp-1")
+    a = tl.enter(None, "req_intake", 1.0, 7)
+    b = tl.enter(a, "req_backlog", 1.5, 8, cause="pages")
+    assert (a.t0, a.t1, a.round_id0, a.round_id1) == (1.0, 1.5, 7, 8)
+    assert a.parent == "request" and a.seconds == 0.5
+    assert (b.t1, b.round_id1, b.seconds, b.cause) == (None, -1, None,
+                                                       "pages")
+    b.close(2.0, 9)
+    b.close(3.0, 11)                       # a terminal race: the first holds
+    assert (b.t1, b.round_id1) == (2.0, 9)
+    assert tl.enter(None, "request", 0.5).parent is None
+    assert [sp.name for sp in tl.spans] == ["req_intake", "req_backlog",
+                                            "request"]
+
+
+@pytest.mark.parametrize("first,then,spans", [
+    (None, "slot", 1),          # the first plan names the open span
+    ("slot", "slot", 1),        # the same cause again: nothing
+    ("slot", "pages", 2),       # a change of cause: a new span
+    ("budget", "slot", 2),
+])
+def test_recause_opens_a_span_only_on_a_change_of_cause(first, then, spans):
+    assert {"slot", "pages", "budget"} == set(WAIT_CAUSES)
+    tl = Timeline("sp-2")
+    st = tl.enter(None, "req_backlog", 1.0, 0, cause=first)
+    new = tl.recause(st, then, 2.0, 4)
+    assert len(tl.spans) == spans and new.cause == then
+    if spans == 2:
+        assert (st.t1, st.round_id1, new.t0, new.round_id0) == (2.0, 4,
+                                                                2.0, 4)
+    else:
+        assert new is st and st.t1 is None
+
+
+def test_span_cap_drops_children_and_recauses_never_a_state():
+    tl = Timeline("sp-3")
+    st = tl.enter(None, "req_prefill", 1.0)
+    for k in range(SPAN_CAP + 10):
+        tl.child(st, "req_chunk", 1.0 + k, 1.5 + k, k, 16, 16)
+    assert len(tl.spans) == SPAN_CAP and tl.spans_dropped == 11
+    wait = Span("req_backlog", 0.0, "request", cause="slot")
+    assert tl.recause(wait, "pages", 9.0) is wait and tl.spans_dropped == 12
+    nxt = tl.enter(st, "req_first_token", 2.0, 3)      # a boundary: kept
+    assert tl.spans[-1] is nxt and len(tl.spans) == SPAN_CAP + 1
+    assert tl.to_dict()["spans_dropped"] == 12
+
+
+@pytest.mark.parametrize("name,seconds", [
+    ("engine_admit_pickup", 0.050),
+    ("engine_prefill_chunk", 0.005),        # first occurrence wins
+    ("engine_admit_dispatch", 0.015),       # the three chunks' sum
+    ("engine_first_readback", 0.012),
+    ("engine_ttft", 0.140),                 # 50 + 3 x 20 + 30
+])
+def test_old_stage_names_render_from_the_spans(name, seconds):
+    tl = Timeline("sp-4")
+    _life(tl, chunks=3)
+    assert tl.stage_durations()[name] == pytest.approx(seconds)
+    view = tl.to_dict()
+    ev = next(e for e in view["events"] if e["event"] == name)
+    assert ev["dur_ms"] == pytest.approx(seconds * 1e3, abs=1e-3)
+    assert [e["event"] for e in view["events"]][0] == "engine_submit"
+
+
+def test_span_events_merge_with_the_ring_in_order_of_time():
+    tl = Timeline("sp-5", event_cap=8)
+    _life(tl, t=tl.t_start, chunks=1)
+    tl.event("decode_round", 8, t=tl.t_start + 0.15)
+    tl.event("llm", 0.3, t=tl.t_start + 1.0)   # a chain's stage, later
+    names = [e[2] for e in tl.events_snapshot()]
+    assert names.index("engine_ttft") < names.index("decode_round")
+    assert names[-1] == "llm" and names[0] == "engine_submit"
+    assert tl.stage_durations()["llm"] == 0.3
+
+
+def test_spans_dict_is_json_ready_and_relative():
+    tl = Timeline("sp-6")
+    _life(tl, t=tl.t_start + 1.0, chunks=2, cause="budget")
+    tl.enter(None, "req_intake", tl.t_start + 5.0, 50)   # still open
+    spans = json.loads(json.dumps(tl.to_dict()["spans"]))
+    assert [s["span"] for s in spans] == [
+        "request", "req_intake", "req_backlog", "req_prefill", "req_chunk",
+        "req_chunk", "req_first_token", "req_readback", "req_decode",
+        "req_intake"]
+    wait = spans[2]
+    assert (wait["cause"], wait["parent"], wait["round_id0"],
+            wait["round_id1"]) == ("budget", "request", 3, 5)
+    assert wait["t0_ms"] == pytest.approx(1010.0, abs=1e-3)
+    assert spans[0]["cause"] == "length" and spans[0]["parent"] is None
+    assert (spans[4]["n"], spans[4]["m"], spans[4]["parent"]) == (
+        16, 16, "req_prefill")
+    assert spans[-1]["t1_ms"] is None and "cause" not in spans[-1]
+
+
+def test_a_request_that_died_waiting_still_renders_its_queue_wait():
+    tl = Timeline("sp-7")
+    root = tl.enter(None, "request", 10.0)
+    st = tl.enter(None, "req_intake", 10.0)
+    st = tl.enter(st, "req_backlog", 10.01, cause="slot")
+    assert "engine_admit_pickup" not in tl.stage_durations()   # still open
+    st.close(12.5)
+    root.close(12.5)
+    assert tl.stage_durations() == {"engine_admit_pickup": 2.5}
+    # a second engine call on the same (adopted) timeline renders its own
+    _life(tl, t=20.0, chunks=1)
+    picks = [e[3] for e in tl.events_snapshot()
+             if e[2] == "engine_admit_pickup"]
+    assert picks == [2.5, pytest.approx(0.050)]
+
+
+def test_recent_stage_ms_counts_spans_and_seeded_ring_stages_alike():
+    rec = FlightRecorder(completed_cap=8)
+    a = rec.begin("a")
+    _life(a, t=a.t_start)
+    b = rec.begin("b")
+    b.stage("engine_admit_pickup", 0.150)          # a test's seed
+    rec.complete(a), rec.complete(b)
+    n, avg = rec.recent_stage_ms("engine_admit_pickup")
+    assert (n, avg) == (2, pytest.approx(100.0))
+
+
+def test_engine_span_tree_on_debug_requests_and_adopted_timelines(engine):
+    """Two engine calls on one adopted timeline leave two whole trees
+    end to end; /debug/requests' snapshot carries them."""
+    tl_edge = engine.flight.begin("tree-1")
+    token = flight.bind(tl_edge)
+    try:
+        for text in ("ab", "cd"):
+            engine.submit(
+                engine.tokenizer.encode(text),
+                SamplingParams(max_tokens=3, top_k=1, ignore_eos=True)
+            ).text()
+    finally:
+        flight.unbind(token)
+    engine.flight.complete(tl_edge)
+    snap = engine.flight.snapshot(limit=50)
+    view = next(t for t in snap["completed"] if t["request_id"] == "tree-1")
+    names = [s["span"] for s in view["spans"]]
+    assert names.count("request") == 2 and names.count("req_decode") == 2
+    assert all(s["t1_ms"] is not None for s in view["spans"])
+    roots = [s for s in view["spans"] if s["span"] == "request"]
+    assert roots[0]["t1_ms"] <= roots[1]["t0_ms"]
+    assert [s["cause"] for s in roots] == ["length", "length"]
+    assert view["spans_dropped"] == 0
+
+
+def test_rejected_submit_closes_its_spans(engine):
+    from generativeaiexamples_tpu.engine.engine import SchedulerFullError
+    ids = engine.tokenizer.encode("q")
+    sp = SamplingParams(max_tokens=16, top_k=1, ignore_eos=True)
+    streams, rejected = [], None
+    for i in range(64):
+        try:
+            streams.append(engine.submit(ids, sp, request_id=f"full-{i}"))
+        except SchedulerFullError:
+            rejected = engine.flight.find(f"full-{i}")
+            break
+    for s in streams:
+        s.text()
+    assert rejected is not None and rejected.done
+    assert [sp.name for sp in rejected.spans] == ["request", "req_intake"]
+    assert all(sp.t1 is not None for sp in rejected.spans)
+    assert rejected.spans[0].cause == "rejected"
+
+
+def test_span_replay_gives_every_old_stage_name(engine, monkeypatch):
+    from generativeaiexamples_tpu.obs import tracing
+    seen = []
+
+    class FakeSpan:
+        def end(self, end_time=None):
+            pass
+
+    class FakeTracer:
+        def start_span(self, name, context=None, start_time=None,
+                       attributes=None):
+            if (attributes or {}).get("request.id") == "replay-2":
+                seen.append((name, start_time))
+            return FakeSpan()
+
+    monkeypatch.setattr(tracing, "_enabled_override", True)
+    monkeypatch.setattr(tracing, "_tracer", FakeTracer())
+    engine.submit(engine.tokenizer.encode("sp"),
+                  SamplingParams(max_tokens=2, top_k=1, ignore_eos=True),
+                  request_id="replay-2").text()
+    deadline = time.monotonic() + 10
+    while time.monotonic() < deadline and not any(
+            n == "engine_ttft" for n, _ in seen):
+        time.sleep(0.02)
+    assert {"engine_admit_pickup", "engine_admit_dispatch",
+            "engine_prefill_chunk", "engine_first_readback",
+            "engine_ttft"} <= {n for n, _ in seen}
+    assert all(isinstance(t0, int) and t0 > 0 for _, t0 in seen)

@@ -546,3 +546,219 @@ def test_bench_rounds_snapshot_keys_pinned_by_schema():
     snap = bench.rounds_snapshot(_FakeEngine())
     schema = load_schema()
     assert set(snap) == set(schema["engine_rounds"])
+
+
+# ------------------------------------- a request's life as one span tree
+
+from generativeaiexamples_tpu.obs.flight import (FlightRecorder,  # noqa: E402
+                                                 REQUEST_STATES)
+
+GREEDY = dict(top_k=1, ignore_eos=True)
+
+
+def _wait_rounds_done(eng, timeout=20.0):
+    deadline = time.monotonic() + timeout
+    while (any(not r.done for r in eng.rounds.records())
+           and time.monotonic() < deadline):
+        time.sleep(0.01)
+
+
+def _states(stream):
+    return [sp for sp in stream.timeline.spans if sp.parent == "request"]
+
+
+def _assert_partition(stream):
+    """The states tile [submit, finish]: each begins where the last
+    ended, all closed, in the order of REQUEST_STATES."""
+    states = _states(stream)
+    root = stream.timeline.spans[0]
+    assert root.name == "request" and root.parent is None
+    assert root.t0 == stream.submit_time == states[0].t0
+    assert root.t1 == stream.finish_time == states[-1].t1
+    for a, b in zip(states, states[1:]):
+        assert a.t1 == b.t0
+        assert REQUEST_STATES.index(a.name) <= REQUEST_STATES.index(b.name)
+    assert all(sp.t1 is not None for sp in stream.timeline.spans)
+    assert sum(sp.t1 - sp.t0 for sp in states) == pytest.approx(
+        stream.finish_time - stream.submit_time, abs=1e-9)
+    return states
+
+
+@pytest.fixture(scope="module")
+def one_slot():
+    """One slot, one 16-token chunk shape: every wait is for the slot."""
+    eng = _engine(max_slots=1, prefill_buckets=(16,), max_prefill_bucket=16,
+                  sched_round_budget_tokens=64, prefix_cache=False)
+    eng.flight = FlightRecorder(completed_cap=64)
+    with eng:
+        yield eng
+
+
+@pytest.mark.parametrize("prompt,chunks", [(10, 1), (16, 1), (40, 3),
+                                           (64, 4)])
+def test_states_partition_the_life_of_a_request(one_slot, prompt, chunks):
+    eng = one_slot
+    s = eng.submit([7] * prompt, SamplingParams(max_tokens=9, **GREEDY))
+    s.text()
+    states = _assert_partition(s)
+    assert [sp.name for sp in states] == list(REQUEST_STATES)
+    by = {sp.name: sp for sp in states}
+    # the first four states ARE the stream's time to first token
+    assert by["req_decode"].t0 == s.first_token_time
+    assert (by["req_prefill"].n, by["req_prefill"].m) == (prompt, 0)
+    kids = [sp for sp in s.timeline.spans if sp.name == "req_chunk"]
+    assert len(kids) == chunks and all(k.parent == "req_prefill"
+                                       for k in kids)
+    assert sum(k.n for k in kids) == prompt
+    assert all(k.m == 16 for k in kids)             # the bucket it ran in
+    assert all(by["req_prefill"].t0 <= k.t0 <= k.t1 <= by["req_prefill"].t1
+               for k in kids)
+    # each chunk names the round that granted it
+    _wait_rounds_done(eng)
+    grants = {r.round_id: dict(r.grants) for r in eng.rounds.records()}
+    for k in kids:
+        assert grants[k.round_id0][s.request_id] == k.n
+    (rb,) = [sp for sp in s.timeline.spans if sp.name == "req_readback"]
+    assert rb.parent == "req_first_token"
+    assert by["req_first_token"].t0 <= rb.t0 <= rb.t1 \
+        <= by["req_first_token"].t1 + 1e-3
+    assert (by["req_decode"].n, by["req_decode"].m) == (9, 2)
+    assert s.timeline.spans[0].cause == "length"
+
+
+def test_no_free_slot_is_the_cause_slot(one_slot):
+    eng = one_slot
+    a = eng.submit([5] * 16, SamplingParams(max_tokens=16, **GREEDY))
+    b = eng.submit([6] * 16, SamplingParams(max_tokens=4, **GREEDY))
+    a.text(), b.text()
+    _assert_partition(a)
+    waits = [sp for sp in _assert_partition(b) if sp.name == "req_backlog"]
+    assert [w.cause for w in waits] == ["slot"]
+    # it waited for all of a's decode: until a's finish, near enough
+    assert waits[0].t1 >= a.finish_time
+    _wait_rounds_done(eng)
+    assert any(r.waiting_slot == 1 for r in eng.rounds.records())
+    assert all(r.waiting_pages == 0 and r.waiting_budget == 0
+               for r in eng.rounds.records())
+
+
+def test_cancel_and_deadline_drop_close_the_open_span(one_slot):
+    eng = one_slot
+    blocker = eng.submit([5] * 16, SamplingParams(max_tokens=16, **GREEDY))
+    dropped = eng.submit([6] * 16, SamplingParams(max_tokens=4, **GREEDY),
+                         deadline_t=time.monotonic() - 1.0)
+    cancelled = eng.submit([8] * 16, SamplingParams(max_tokens=4, **GREEDY))
+    cancelled.cancel()
+    for s in (blocker, dropped, cancelled):
+        s.text()
+    assert dropped.finish_reason == "deadline_queue"
+    assert cancelled.finish_reason == "cancelled"
+    for s in (dropped, cancelled):
+        states = _assert_partition(s)
+        assert [sp.name for sp in states][0] == "req_intake"
+        assert states[-1].name in ("req_intake", "req_backlog")
+        assert s.timeline.spans[0].cause == s.finish_reason
+        # the dropped request's queue wait reads from its spans, under
+        # the stage name the load shedders ask for
+        assert s.timeline.stage_durations()["engine_admit_pickup"] \
+            == pytest.approx(s.finish_time - s.submit_time, abs=1e-6)
+
+
+def test_pool_refusal_is_the_cause_pages_and_a_change_opens_a_span():
+    """Two slots and a pool of 7 pages: a (4 pages) and b (2 pages) run,
+    c (5 pages) first waits for a SLOT, then — b gone, a still holding
+    its four — for PAGES: two req_backlog spans, one a cause."""
+    eng = _engine(max_slots=2, max_output_length=48, prefill_buckets=(16, 64),
+                  kv_pool_tokens=7 * PAGE, sched_round_budget_tokens=128,
+                  prefix_cache=False)
+    eng.flight = FlightRecorder(completed_cap=16)
+    with eng:
+        a = eng.submit([5] * 16, SamplingParams(max_tokens=48, **GREEDY))
+        b = eng.submit([6] * 16, SamplingParams(max_tokens=8, **GREEDY))
+        c = eng.submit([7] * 64, SamplingParams(max_tokens=16, **GREEDY))
+        for s in (a, b, c):
+            s.text()
+        _wait_rounds_done(eng)
+    waits = [sp for sp in _assert_partition(c) if sp.name == "req_backlog"]
+    assert [w.cause for w in waits] == ["slot", "pages"]
+    assert waits[0].t1 == waits[1].t0
+    assert waits[1].round_id0 > waits[0].round_id0
+    recs = eng.rounds.records()
+    assert any(r.waiting_slot for r in recs)
+    assert any(r.waiting_pages or r.blocked_on_pages for r in recs)
+    view = recs[-1].to_dict()
+    assert {"waiting_slot", "waiting_pages", "waiting_budget",
+            "prefill_ungranted"} <= set(view["plan"])
+    assert {"queued_ahead", "done_ms"} <= set(view["execution"])
+
+
+def test_a_budget_of_one_chunk_is_the_cause_budget():
+    """Free slots for both, a round budget of one 16-token chunk: the
+    planner is offered both prompts and grants one."""
+    eng = _engine(max_slots=2, prefill_buckets=(16,), max_prefill_bucket=16,
+                  sched_round_budget_tokens=PAGE, prefix_cache=False)
+    eng.flight = FlightRecorder(completed_cap=16)
+    # both are queued before the loop starts, so its first plan sees both
+    a = eng.submit([5] * 48, SamplingParams(max_tokens=4, **GREEDY))
+    b = eng.submit([6] * 48, SamplingParams(max_tokens=4, **GREEDY))
+    with eng:
+        a.text(), b.text()
+        _wait_rounds_done(eng)
+    causes = [sp.cause for s in (a, b) for sp in _assert_partition(s)
+              if sp.name == "req_backlog"]
+    assert "budget" in causes and "slot" not in causes \
+        and "pages" not in causes
+    recs = eng.rounds.records()
+    assert any(r.waiting_budget == 1 for r in recs)
+    # a prompt mid-prefill that a plan passed over is counted too
+    assert any(r.prefill_ungranted for r in recs)
+    assert all(r.t_done >= r.t_start and len(r.t_parts) >= 1
+               for r in recs if r.done)
+
+
+def test_a_long_answer_keeps_every_boundary_while_the_ring_wraps():
+    """Nine chunks in, 600 tokens out at eight steps a round: the ring's
+    64 events wrap (75 decode_round events), the span tree keeps every
+    state boundary, and admission control still counts the request."""
+    from generativeaiexamples_tpu.models.tokenizer import ByteTokenizer
+    cfg = LlamaConfig(vocab_size=259 + 5, hidden_size=64,
+                      intermediate_size=128, num_layers=2, num_heads=4,
+                      num_kv_heads=2, head_dim=16,
+                      max_position_embeddings=1024)
+    eng = Engine(llama.init_params(cfg, jax.random.key(3), dtype=jnp.float32),
+                 cfg, ByteTokenizer(), EngineConfig(
+                     max_slots=1, max_input_length=144,
+                     max_output_length=600, prefill_buckets=(16,),
+                     max_prefill_bucket=16, sched_round_budget_tokens=64,
+                     dtype="float32", page_size=PAGE, kv_pool_tokens=None,
+                     steps_per_round=8, prefix_cache=False))
+    eng.rounds = RoundRecorder(cap=512)
+    eng.flight = FlightRecorder(completed_cap=16)
+    with eng:
+        s = eng.submit([7] * 144, SamplingParams(max_tokens=600, **GREEDY),
+                       request_id="long-1")
+        s.text()
+        _wait_rounds_done(eng)
+    tl = s.timeline
+    view = tl.to_dict()
+    assert view["events_dropped"] > 0 and view["spans_dropped"] == 0
+    states = _assert_partition(s)
+    assert [sp.name for sp in states] == list(REQUEST_STATES)
+    assert len([sp for sp in tl.spans if sp.name == "req_chunk"]) == 9
+    assert states[-1].n == 600 and states[-1].m >= 75
+    rendered = [e["event"] for e in view["events"]]
+    for name in ("engine_submit", "engine_admit_pickup",
+                 "engine_admit_dispatch", "engine_first_readback",
+                 "engine_ttft"):
+        assert name in rendered, name
+    assert rendered.count("engine_prefill_chunk") == 9
+    assert [d["span"] for d in view["spans"]][:3] == [
+        "request", "req_intake", "req_backlog"]
+    n, avg = eng.flight.recent_stage_ms("engine_admit_pickup")
+    assert n == 1 and avg == pytest.approx(
+        (states[2].t0 - s.submit_time) * 1e3, abs=1e-6)
+    durs = tl.stage_durations()
+    assert durs["engine_ttft"] == pytest.approx(
+        s.first_token_time - s.submit_time, abs=1e-9)
+    assert durs["engine_admit_dispatch"] == pytest.approx(
+        sum(sp.t1 - sp.t0 for sp in tl.spans if sp.name == "req_chunk"))
