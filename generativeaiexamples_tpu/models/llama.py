@@ -660,7 +660,8 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
                         with_logits: bool = False,
                         use_kernel: Optional[bool] = None,
                         ) -> tuple[jax.Array, KVCache]:
-    """One CHUNK of a long-prompt prefill over the paged KV pool (B=1).
+    """One CHUNK of a long-prompt prefill over the paged KV pool, of one
+    prompt (B = 1) or of B prompts at once, a row each.
 
     The piece that lets the engine serve prompts longer than any single
     prefill bucket: the prompt streams through in page-aligned chunks,
@@ -687,10 +688,24 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     The pool is held, the rows come out (``_run_stack``): a layer's
     prefix blocks are gathered out of the whole pool by (layer, page),
     and the chunk rides its own attention in-register.
+
+    B > 1 (the engine's grouped chunk program): tokens/positions (B, C),
+    block_table (B, P) at one width, kv_valid_len and start_page_idx
+    (B,), each row a different prompt at its own start. Everything but
+    attention runs over the B x C tokens together — so a layer's experts
+    are read once for all rows (dropless and dense models only: capacity
+    routing would drop by what is routed together) —, the cache object's
+    ``attend_prefix`` once a row with the row's own table, start and
+    length (blocks past a row's start are skipped, whatever the width),
+    and ONE write after the scan puts every row's pages at their own
+    destinations. Returns hidden states (B, C, D).
     """
     B, C = tokens.shape
-    if B != 1:
-        raise ValueError("apply_prefill_paged is single-request (B=1)")
+    if B != 1 and cfg.num_experts and cfg.moe_impl == "sparse":
+        raise ValueError(
+            "apply_prefill_paged over several rows and moe_impl 'sparse': "
+            "an expert's capacity is that of the tokens routed together, so "
+            "the rows of other prompts would change which assignments drop")
     kvc = kv_cache_of(cfg)
     page = kvc.page_size(kv_cache)
     if C % page:
@@ -712,11 +727,32 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
                                  kv_valid_len, li, **kernel)
         return attn, (k[0], v[0])
 
+    def attend_rows(q, k, v, lp, li, _):
+        def row(r):
+            q, k, v, table, start, valid = r
+            return kvc.attend_prefix(q[None], k[None], v[None], lp, kv_cache,
+                                     table[None], start, valid[None], li,
+                                     **kernel)[0]
+        attn = jax.lax.map(row, (q, k, v, block_table, positions[:, 0],
+                                 kv_valid_len))
+        return attn, (k, v)
+
     h, (new_k, new_v), _ = _run_model(params, cfg, h, positions, inv_freq,
-                                      kv_valid_len, attend)
+                                      kv_valid_len,
+                                      attend if B == 1 else attend_rows)
     # new_k/new_v: (L, C, KV, hd), to the chunk's physical pages
-    dest = jax.lax.dynamic_slice(block_table[0], (start_page_idx,),
-                                 (C // page,))
+    if B == 1:
+        dest = jax.lax.dynamic_slice(block_table[0], (start_page_idx,),
+                                     (C // page,))
+    else:
+        # (L, B, C, ...) row-major is (L, B * C, ...): every row's pages
+        # in one list, each row's own run of its table
+        new_k, new_v = (a.reshape((a.shape[0], B * C) + a.shape[3:])
+                        for a in (new_k, new_v))
+        dest = jnp.take_along_axis(
+            block_table, start_page_idx[:, None]
+            + jnp.arange(C // page, dtype=jnp.int32)[None], axis=1
+        ).reshape(-1)
     cache = kvc.write(kv_cache, new_k, new_v, dest)
     if not with_logits:
         return h, cache

@@ -358,15 +358,56 @@ def dropless_moe_ffn(x: jax.Array, router_logits: jax.Array,
             rt["weight"] = rt["weight"] * rt["held"]
             if aux is not None:
                 aux["local_assignments"] = rt["assigned"]
-        x_pad = jnp.where(rt["valid"][:, None], x_flat[rt["token"]], 0)
 
     ffn = (grouped_ffn.grouped_expert_ffn
            if grouped_ffn.use_kernel(D, w_gate.shape[-1], bm, x.dtype)
            else block_loop_ffn)
-    with jax.named_scope("moe_experts"):
-        y_pad = ffn(x_pad, rt["block_expert"], rt["n_blocks"], li, w_gate,
-                    w_up, w_down, bm=bm, relu=relu)
-    return _combine_sorted(y_pad, rt, x.dtype, (B, S, D))
+
+    NB = rt["block_expert"].shape[0]
+    few = NB if not share else cfg.experts_held - (
+        -4 * T * k * cfg.experts_held // (cfg.num_experts * bm))
+    if 2 * few > NB:
+        with jax.named_scope("moe_route"):
+            x_pad = jnp.where(rt["valid"][:, None], x_flat[rt["token"]], 0)
+        with jax.named_scope("moe_experts"):
+            y_pad = ffn(x_pad, rt["block_expert"], rt["n_blocks"], li,
+                        w_gate, w_up, w_down, bm=bm, relu=relu)
+        return _combine_sorted(y_pad, rt, x.dtype, (B, S, D))
+
+    # A thin share's layout is walked ``few`` blocks at a time. It is
+    # sized for every assignment falling on a held expert (T*k // bm + E
+    # blocks) and a share is expected 1 in num_experts / experts_held of
+    # them: gathered whole, a chunk program's 17152 padded rows, 97 % of
+    # them padding, were 15 % of it at D 7168 (chip, PR 39). The blocks
+    # that hold rows come first, so four times the expectation is one
+    # trip and a step whose rows did crowd onto the held experts takes
+    # more: nothing is dropped either way, and no trip holds more than
+    # ``few`` blocks of rows.
+    seg = few * bm
+    pad = -NB % few
+    valid, token = (jnp.pad(rt[n], (0, pad * bm)) for n in ("valid", "token"))
+    block_expert = jnp.pad(rt["block_expert"], (0, pad))
+    w = rt["weight"][..., None]                                 # (T, k, 1)
+
+    def segment(i, out):
+        at = lambda a, n: jax.lax.dynamic_slice_in_dim(a, i * n, n)
+        with jax.named_scope("moe_route"):
+            x_pad = jnp.where(at(valid, seg)[:, None],
+                              x_flat[at(token, seg)], 0)
+        with jax.named_scope("moe_experts"):
+            y_pad = ffn(x_pad, at(block_expert, few),
+                        jnp.clip(rt["n_blocks"] - i * few, 0, few), li,
+                        w_gate, w_up, w_down, bm=bm, relu=relu)
+        with jax.named_scope("moe_route"):
+            row = rt["row_of"] - i * seg
+            here = (w > 0) & ((row >= 0) & (row < seg))[..., None]
+            y = jnp.where(here, y_pad[jnp.clip(row, 0, seg - 1)].astype(
+                jnp.float32), 0.0)
+            return out + jnp.sum(y * w, axis=1)
+
+    out = jax.lax.fori_loop(0, -(-rt["n_blocks"] // few), segment,
+                            jnp.zeros((T, D), jnp.float32))
+    return out.astype(x.dtype).reshape(B, S, D), rt["touched"]
 
 
 def _combine_sorted(y_pad, rt, dtype, shape):

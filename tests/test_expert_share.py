@@ -106,6 +106,58 @@ def test_what_is_held_elsewhere_is_dropped_before_the_sort():
     assert float(rt_m["assigned"]) == held[np.asarray(mask)].sum()
 
 
+@pytest.mark.parametrize("crowded", [False, True],
+                         ids=["as_expected", "crowded"])
+def test_a_thin_share_gathers_only_the_blocks_that_can_hold_rows(crowded):
+    """2 of 64 experts held, 512 tokens: the layout has 34 blocks and
+    the expected load fills two, so the walk's one trip of six blocks
+    holds every row; when a selection bias sends EVERY token to the held
+    pair (16 blocks) it takes three. Both against a sum written out per
+    token: nothing is dropped either way."""
+    T, D, F, n, held, first = 512, 64, 32, 64, 2, 6
+    cfg = LlamaConfig(
+        vocab_size=64, hidden_size=D, intermediate_size=F,
+        moe_intermediate_size=F, num_layers=1, num_heads=4, num_kv_heads=2,
+        head_dim=16, num_experts=n, num_experts_per_tok=K,
+        moe_impl="dropless", router_score_func="sigmoid",
+        router_norm_topk=True, router_scale=2.5, router_bias="selection",
+        experts_held=held, experts_first=first, weight_init="unit_stream")
+    ks = jax.random.split(jax.random.key(5), 5)
+    x = jax.random.normal(ks[0], (1, T, D), jnp.float32)
+    logits = jax.random.normal(ks[1], (1, T, n), jnp.float32)
+    bias = jnp.zeros((n,)).at[first:first + held].set(
+        5.0 if crowded else 0.0)
+    lp = {"router_bias": bias,
+          "w_gate": jax.random.normal(ks[2], (held, D, F)) / 8,
+          "w_up": jax.random.normal(ks[3], (held, D, F)) / 8,
+          "w_down": jax.random.normal(ks[4], (held, F, D)) / 8}
+    aux = {}
+    got, touched = jax.jit(lambda x, lg, lp: moe.dropless_moe_ffn(
+        x, lg, lp, cfg, aux=aux))(x, logits, lp)
+    # the layout, and whether this draw fits one trip of six blocks
+    select, weigh = moe.router_scores(logits[0], lp, cfg)
+    rt = moe.route_sorted(select, K, 64, None, weigh, (first, held))
+    assert rt["block_expert"].shape[0] == 34
+    assert (int(rt["n_blocks"]) > 6) == crowded
+    # per token: its chosen experts' outputs, weighted over all K, the
+    # held ones kept
+    _, idx = jax.lax.top_k(select, K)
+    w = moe.scale_chosen(jnp.take_along_axis(weigh, idx, axis=1), cfg)
+    want = jnp.zeros((T, D))
+    for j in range(K):
+        e = idx[:, j] - first
+        here = (e >= 0) & (e < held)
+        e = jnp.clip(e, 0, held - 1)
+        gate = jax.nn.silu(jnp.einsum("td,tdf->tf", x[0], lp["w_gate"][e]))
+        up = jnp.einsum("td,tdf->tf", x[0], lp["w_up"][e])
+        y = jnp.einsum("tf,tfd->td", gate * up, lp["w_down"][e])
+        want = want + jnp.where(here[:, None], y * w[:, j:j + 1], 0.0)
+    assert float(jnp.max(jnp.abs(got[0] - want))) < 2e-5
+    assert float(touched) == held
+    assert float(rt["assigned"]) == (2 * T if crowded else
+                                     float(jnp.sum(rt["held"])))
+
+
 def test_tree_holds_the_share_and_the_router_every_column():
     cfg = share_of(WHOLE, 2)
     p = llama.init_params(cfg, jax.random.key(0), dtype=jnp.float32)

@@ -10,9 +10,10 @@ the ledger's ``breakdown.device_ops`` print (``copy.69``,
 
 The same pieces as ``benchmarks/rehearse_compile.py`` (which prints
 memory only): an engine built on the CPU with zero weights
-``--engine-layers`` deep, its 8-step decode round (greedy and sampled)
-and one 512-token chunk of the chunked prefill, lowered for the
-described chip. Writes ``<out>/<config>.<program>.hlo.txt`` and prints
+``--engine-layers`` deep, its 8-step decode round (greedy and sampled),
+one 512-token chunk of the chunked prefill and the chunk program of four
+prompts' rows, lowered for the described chip. Writes
+``<out>/<config>.<program>.hlo.txt`` and prints
 one JSON line: each program's temporaries, what :func:`weight_report`
 finds in its text and, as ``pool_copies``, what :func:`pool_report` does
 (instructions that copy a layer's slab of the KV pool, or a whole pool).
@@ -332,6 +333,14 @@ def engine_programs(config_name: str, engine_layers: int,
     yield ("chunk_extend_512", eng._chunk_extend_fn(window, "accum").lower(
         state, p_sds, sds((1, 512), jnp.int32), i32, i32, i32,
         sds((1, window), jnp.int32)).compile(), leaves)
+    # the chunk program of several prompts, at its largest rung (none
+    # under capacity routing)
+    for rows in eng._row_ladder[:1]:
+        vec = sds((rows,), jnp.int32)
+        yield (f"chunk_extend_rows{rows}", eng._chunk_rows_fn(rows).lower(
+            state, p_sds, sds((rows, 512), jnp.int32), vec, vec,
+            sds((rows, window), jnp.int32),
+            sds((rows,), jnp.bool_)).compile(), leaves)
 
 
 def main(argv=None) -> int:
