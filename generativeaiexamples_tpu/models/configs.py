@@ -150,6 +150,27 @@ class LlamaConfig:
     # the layer's output is the partial sum (parallel/moe.py).
     experts_held: int = 0
     experts_first: int = 0
+    # Learned sparse attention over the latent cache (``index_topk`` > 0;
+    # latent attention only): a query attends the ``index_topk`` cached
+    # tokens an INDEXER scores highest, not its whole context. A layer
+    # whose ``index_layers`` flag is 1 ("full") has the indexer:
+    # ``index_n_heads`` queries of ``index_head_dim`` values from the
+    # query latent, ONE key of that width a token from the block's normed
+    # input (LayerNorm; its first ``qk_rope_head_dim`` values rotated,
+    # pairs (2i, 2i+1) under ``index_rope_interleave``), head weights
+    # from the input; the score of a cached token is sum_j w_j
+    # relu(q_j . k), and the ``index_topk`` best causal positions (all of
+    # them up to that many; ties to the lower position) are the set the
+    # layer attends. Its keys are cached beside the latent rows
+    # (models/kv_cache.py ``SparseLatentKV``). A layer whose flag is 0
+    # ("shared") has no indexer and no such rows and attends the set of
+    # the nearest full layer below: layer 0 is full. ``index_layers`` is
+    # one 0/1 a layer, or a period repeated over the depth.
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_layers: tuple = ()
+    index_rope_interleave: bool = False
     # How ``llama.init_params`` draws a random tree (tests, benchmarks;
     # served weights come from ``import_hf`` and ignore it):
     #   "fan_in": every matrix N(0, 1/fan_in), embedding rows of norm 1
@@ -170,7 +191,7 @@ class LlamaConfig:
     def __post_init__(self):
         # JSON hands lists; a frozen dataclass used as a jit static and
         # an lru_cache key must hash
-        for name in ("window_layers", "rope_layers"):
+        for name in ("window_layers", "rope_layers", "index_layers"):
             object.__setattr__(self, name,
                                tuple(int(x) for x in getattr(self, name)))
         object.__setattr__(self, "sliding_window",
@@ -223,6 +244,22 @@ class LlamaConfig:
                     "norm, output gate or bias")
         elif self.rope_interleave:
             raise ValueError("rope_interleave is the latent rotary part's")
+        if self.index_topk:
+            if not (self.kv_lora_rank and self.index_n_heads > 0
+                    and self.index_head_dim >= self.qk_rope_head_dim):
+                raise ValueError(
+                    "learned sparse attention (index_topk) needs latent "
+                    "attention (kv_lora_rank), index_n_heads and an "
+                    "index_head_dim that holds the rotary part")
+            if not self.layer_index[0]:
+                raise ValueError(
+                    "index_layers: layer 0 has no full layer below it "
+                    "whose selection it could attend")
+        elif (self.index_n_heads or self.index_head_dim
+              or self.index_layers or self.index_rope_interleave):
+            raise ValueError("index_n_heads, index_head_dim, index_layers "
+                             "and index_rope_interleave are the indexer's "
+                             "(index_topk)")
         if self.experts_held or self.experts_first:
             if not (self.num_experts and self.moe_impl == "dropless"):
                 raise ValueError("an expert share (experts_held) needs "
@@ -255,6 +292,12 @@ class LlamaConfig:
     @property
     def layer_rope(self) -> tuple:
         return self.layer_pattern(self.rope_layers, 1)
+
+    @property
+    def layer_index(self) -> tuple:
+        """Per layer, 1 where it has the indexer ("full"), 0 where it
+        attends the selection of the nearest full layer below."""
+        return self.layer_pattern(self.index_layers, 1)
 
     @property
     def expert_width(self) -> int:
@@ -379,6 +422,29 @@ KIMI_K2 = LlamaConfig(
     rope_original_max=4096, rope_beta_fast=1.0, rope_beta_slow=1.0,
     rope_mscale_all_dim=1.0, weight_init="unit_stream")
 
+# A ~750B-total / ~40B-active sparse model (zai-org GLM-5.2 config.json,
+# model_type glm_moe_dsa): three dense layers then seventy-five of 256
+# SwiGLU experts, 8 a token by sigmoid scores with a selection bias,
+# beside one shared expert; latent attention, 64 heads of 192 + 64 over
+# one 512 + 64 wide cached row a token, values 256 wide, plain rotary
+# frequencies; and learned sparse attention: an indexer of 32 heads x 128
+# on layers 0, 1, 2 and every fourth from 6 on chooses the 2048 cached
+# tokens a query attends, the layers between attend the set of the full
+# layer below. The multi-token-prediction module is not built.
+GLM_5_2 = LlamaConfig(
+    vocab_size=154880, hidden_size=6144, intermediate_size=12288,
+    moe_intermediate_size=2048, num_layers=78, num_dense_layers=3,
+    num_heads=64, num_kv_heads=1, head_dim=256,
+    max_position_embeddings=1048576, rope_theta=8_000_000.0,
+    rms_norm_eps=1e-5, num_experts=256, num_experts_per_tok=8,
+    num_shared_experts=1, moe_impl="dropless", router_score_func="sigmoid",
+    router_norm_topk=True, router_scale=2.5, router_bias="selection",
+    kv_lora_rank=512, q_lora_rank=2048, qk_nope_head_dim=192,
+    qk_rope_head_dim=64, v_head_dim=256, rope_interleave=True,
+    index_topk=2048, index_n_heads=32, index_head_dim=128,
+    index_layers=tuple(int(i < 3 or (i - 2) % 4 == 0) for i in range(78)),
+    index_rope_interleave=True, weight_init="unit_stream")
+
 # GPT-Next / Nemotron-8B (the reference's second served family:
 # ensemble_models/gptnext/, docs/rag/support_matrix.md:14 sizing;
 # nemotron_config.yaml deployment). Rotary attention, zero-centered
@@ -429,6 +495,7 @@ MODEL_REGISTRY: dict[str, LlamaConfig] = {
     "smallthinker-21b-a3b-instruct": SMALLTHINKER_21B_A3B,
     "trinity-mini": TRINITY_MINI,
     "kimi-k2-instruct": KIMI_K2,
+    "glm-5.2": GLM_5_2,
     "gptnext-tiny": GPTNEXT_TINY,
     "llama-tiny": LLAMA_TINY,
     "golden-tiny": GOLDEN_TINY,

@@ -71,6 +71,16 @@ _HF_LATENT_LAYER_KEYS = {
     "mlp.shared_experts.up_proj.weight": ("ws_up", True),
     "mlp.shared_experts.down_proj.weight": ("ws_down", True),
 }
+# The indexer of learned sparse attention (cfg.index_topk; the published
+# DeepSeek-V3.2 / GLM names): on the FULL layers only (cfg.layer_index),
+# and stacked over those alone.
+_HF_INDEX_LAYER_KEYS = {
+    "self_attn.indexer.wq_b.weight": ("index_wq", True),
+    "self_attn.indexer.wk.weight": ("index_wk", True),
+    "self_attn.indexer.k_norm.weight": ("index_k_norm", False),
+    "self_attn.indexer.k_norm.bias": ("index_k_norm_b", False),
+    "self_attn.indexer.weights_proj.weight": ("index_wp", True),
+}
 _HF_KV_B = "self_attn.kv_b_proj.weight"
 _FLOAT32_LEAVES = ("router_bias",)      # a buffer the router adds in f32
 
@@ -250,6 +260,8 @@ def params_from_named_tensors(
         hf_keys.update(_HF_POST_NORM_LAYER_KEYS)
     if cfg.kv_lora_rank:
         hf_keys.update(_HF_LATENT_LAYER_KEYS)
+    if cfg.index_topk:
+        hf_keys.update(_HF_INDEX_LAYER_KEYS)
     # an expert share keeps the experts it holds, numbered from its first
     first_expert, held = cfg.experts_first, cfg.held_experts
     layer_acc: dict[str, list] = {}
@@ -321,7 +333,11 @@ def params_from_named_tensors(
         layers, missing = {}, []
         for name, per_layer in layer_acc.items():
             part = per_layer[first:first + n]
-            if all(x is None for x in part):
+            indexer = name.startswith("index_")
+            if indexer:     # the stack's full layers only, and all of them
+                part = [x for x, f in zip(
+                    part, cfg.layer_index[first:first + n]) if f]
+            if all(x is None for x in part) and not (indexer and part):
                 continue            # not a leaf of this stack's layers
             if any(x is None or (isinstance(x, list)
                                  and any(e is None for e in x))

@@ -1,4 +1,4 @@
-"""The KV cache behind ONE object, in two implementations.
+"""The KV cache behind ONE object, in three implementations.
 
 What the forwards of models/llama.py, the decode kernel call and the
 engine's sizing need of a cache is asked of the object that
@@ -41,6 +41,26 @@ v)`` as handed:
             does half the operations that way: one expansion a block
             serves all its queries), so nothing of size prefix x heads x
             head width exists at once.
+``SparseLatentKV`` ``LatentKV`` under learned sparse attention
+            (``cfg.index_topk``): a THIRD leaf, ``"i": (Lf, N, 1, page,
+            index_head_dim)``, the indexer's key a token on the Lf FULL
+            layers only (``cfg.layer_index``) — one block table and one
+            page id for all three, bytes a token that differ by layer
+            (``model_token_bytes``). What a layer leaves in the cache is
+            ``(c, k_r, k_i)``, the last zeros on a shared layer and never
+            written. A full layer scores the row's cached index keys
+            read through its block table and keeps the ``index_topk``
+            best (ops/sparse_index.py); the KEEP MASK over the keys is
+            returned beside the attention and handed to the layers above
+            (models/llama.py ``_run_stack`` carries it); every
+            ``attend_*`` takes the layer's ``index`` (projections, whether
+            it is full, the mask carried) and reads MASKED: all of the
+            row's cached rows, the unchosen keys out of the softmax.
+            Decode and verify gather the window and run absorbed (no
+            Pallas decode kernel: ``kernel_attend`` is None and the
+            decode step is the one-token verify forward on every
+            backend); a chunk runs ``LatentKV.attend_prefix`` with the
+            mask as one more operand of the chunk kernel.
 """
 
 from __future__ import annotations
@@ -62,6 +82,8 @@ KVCache = dict[str, jax.Array]
 def kv_cache_of(cfg: LlamaConfig):
     """The configuration's cache object: what it says of its attention
     decides, nothing else."""
+    if cfg.index_topk:
+        return SparseLatentKV(cfg)
     return LatentKV(cfg) if cfg.kv_lora_rank else HeadKV(cfg)
 
 
@@ -106,6 +128,11 @@ class HeadKV:
         if quantized:
             return cfg.num_kv_heads * 2 * (cfg.head_dim + 2)
         return cfg.num_kv_heads * cfg.head_dim * 2 * itemsize
+
+    def model_token_bytes(self, itemsize: int, quantized: bool = False
+                          ) -> int:
+        """Bytes a cached token, all layers."""
+        return self.cfg.num_layers * self.token_bytes(itemsize, quantized)
 
     @staticmethod
     def page_size(kv_cache: KVCache) -> int:
@@ -517,6 +544,8 @@ class LatentKV:
             raise NotImplementedError("int8 KV over a latent cache")
         return (self.R + self.rope) * itemsize
 
+    model_token_bytes = HeadKV.model_token_bytes
+
     @staticmethod
     def page_size(kv_cache: KVCache) -> int:
         return kv_cache["c"].shape[3]
@@ -531,9 +560,17 @@ class LatentKV:
 
     def prefix_kernel_supported(self, page: int) -> bool:
         """Whether ``attend_prefix`` can run the chunk kernel
-        (ops/chunk_attention.py) at this geometry."""
+        (ops/chunk_attention.py) at this geometry: a head's ``nope`` key
+        columns whole lanes beside the shared rotary part, or (192 + 64)
+        whole lanes WITH it, the rotary part then copied into every
+        head's keys (``_fold_shared``)."""
         from ..ops.chunk_attention import kernel_supported
-        return kernel_supported(page, self.nope, self.vd, self.rope)
+        return kernel_supported(page, self.nope, self.vd, self.rope) \
+            or kernel_supported(page, self.nope + self.rope, self.vd)
+
+    @property
+    def _fold_shared(self) -> bool:
+        return self.nope % 128 != 0
 
     def pool_spec(self, mesh, quantized: bool = False) -> dict:
         """Replicated: the latent is common to all heads, so a ``tp``
@@ -639,7 +676,7 @@ class LatentKV:
 
     def attend_prefix(self, q, c, k_r, lp, kv_cache, block_table, start,
                       kv_valid_len, layer, block_pages: int = 4,
-                      use_kernel: bool = False):
+                      use_kernel: bool = False, keep=None):
         """A chunk's attention, EXPANDED: the chunk's own tokens and each
         ``block_pages``-page block of the prefix it reads back from the
         latent pool are taken through ``wk_b`` / ``wv_b`` (one expansion
@@ -653,7 +690,13 @@ class LatentKV:
         of ops/chunk_attention.py, the (H, T, C) float32 scores on the
         chip; False is the same update as jnp operations (the CPU, and
         what the kernel is held against). The block loop, the gather,
-        the zeroing and the expansion are the same either way."""
+        the zeroing and the expansion are the same either way.
+
+        ``keep``: (prefix blocks' keys + C, C) bool or None, keys by
+        rows — a query (column) attends a key only where it is set, the
+        same for all heads (``SparseLatentKV``); the prefix part is
+        indexed like the padded block table, the last C rows are the
+        chunk's own keys."""
         from ..ops import chunk_attention as ca
         cfg = self.cfg
         _, C, H, _ = q.shape
@@ -683,18 +726,30 @@ class LatentKV:
             interp = jax.default_backend() != "tpu"
             carry0 = ca.init_carry(H, C, vd)
 
-            def update(carry, kb, rb, vb, mask, k0, limit, causal):
+            def update(carry, kb, rb, vb, mask, k0, limit, causal, kp=None):
+                more = {}
+                if self._fold_shared:   # [nope | rope] a head: whole lanes
+                    T = kb.shape[0]
+                    kb = jnp.concatenate(
+                        [kb.reshape(T, H, nope),
+                         jnp.broadcast_to(rb[:, None], (T, H, rope))],
+                        axis=-1).reshape(T, H * (nope + rope))
+                    rb = None
+                if kp is not None:
+                    more["keep"] = kp.astype(jnp.float32)
                 return ca.chunk_attention_update(
                     qh, kb, vb.T, carry, k0, limit, start, scale=scale,
-                    causal=causal, k_shared=rb, interpret=interp)
+                    causal=causal, k_shared=rb, interpret=interp, **more)
         else:
             qn, qr = self._split(q[0])                      # (C, H, .)
             carry0 = (jnp.full((H, C), -1e30, jnp.float32),
                       jnp.zeros((H, C), jnp.float32),
                       jnp.zeros((H, C, vd), jnp.float32))
 
-            def update(carry, kb, rb, vb, mask, k0, limit, causal):
+            def update(carry, kb, rb, vb, mask, k0, limit, causal, kp=None):
                 m, l, acc = carry
+                if kp is not None:
+                    mask = jnp.broadcast_to(mask, kp.shape[::-1]) & kp.T
                 kb, vb = kb.reshape(-1, H, nope), vb.reshape(-1, H, vd)
                 s = (jnp.einsum("chj,thj->hct", qn, kb,
                                 preferred_element_type=jnp.float32)
@@ -725,8 +780,10 @@ class LatentKV:
                     pr[pages].swapaxes(1, 2).reshape(tblk, rope).astype(cd),
                     0)
                 kb, vb = expand(cb)
+                kp = () if keep is None else (jax.lax.dynamic_slice(
+                    keep, (bi * tblk, 0), (tblk, C)),)
                 return update(carry, kb, rb, vb, mask, bi * tblk, start,
-                              False)
+                              False, *kp)
             return jax.lax.cond(bi * tblk < start, live, lambda c: c,
                                 carry), None
 
@@ -746,8 +803,10 @@ class LatentKV:
             tloc = si * sb + jnp.arange(sb, dtype=jnp.int32)
             ok = (tloc[None, :] <= rel[:, None]) \
                 & ((start + tloc) < kv_valid_len[0])[None, :]
+            kp = () if keep is None else (jax.lax.dynamic_slice(
+                keep, (nb * tblk + si * sb, 0), (sb, C)),)
             return update(carry, kb, rb, vb, ok, start + si * sb,
-                          kv_valid_len[0], True), None
+                          kv_valid_len[0], True, *kp), None
 
         carry, _ = jax.lax.scan(
             self_block, carry, jnp.arange(C // sb, dtype=jnp.int32))
@@ -783,3 +842,212 @@ class LatentKV:
             return attn, {"c": pc, "r": pr}
 
         return attend
+
+
+class SparseLatentKV(LatentKV):
+    """``LatentKV`` with an index cache and a masked read: ``{"c", "r"}``
+    as there, ``"i": (Lf, N, 1, page, index_head_dim)`` over the full
+    layers (module docstring)."""
+
+    leaves = ("c", "r", "i")
+    #: the decode step has no Pallas kernel: ``apply_decode_paged`` runs
+    #: the one-token verify forward, whose gathered window the engine's
+    #: headroom reserves (``token_bytes``)
+    kernel_attend = None
+
+    def __init__(self, cfg: LlamaConfig):
+        super().__init__(cfg)
+        self.K, self.di = cfg.index_topk, cfg.index_head_dim
+        #: the model's full layers, in order: a full layer's place here
+        #: is its layer of the ``"i"`` leaf
+        self.full = tuple(i for i, f in enumerate(cfg.layer_index) if f)
+
+    # ---------------------------------------------------------------- build
+
+    def init_dense(self, batch: int, max_len: int,
+                   dtype: jnp.dtype = jnp.bfloat16) -> KVCache:
+        """The dense cache keeps an index row on EVERY layer (zeros on a
+        shared one): its leaves are sliced a layer by the scan."""
+        L = self.cfg.num_layers
+        return dict(super().init_dense(batch, max_len, dtype),
+                    i=jnp.zeros((L, batch, max_len, self.di), dtype))
+
+    def init_pool(self, n_pages: int, page_size: int,
+                  dtype: jnp.dtype = jnp.bfloat16,
+                  quantized: bool = False) -> KVCache:
+        pool = super().init_pool(n_pages, page_size, dtype, quantized)
+        return dict(pool, i=jnp.zeros(
+            (len(self.full), n_pages, 1, page_size, self.di), dtype))
+
+    # ----------------------------------------------------------------- size
+
+    def token_bytes(self, itemsize: int, quantized: bool = False) -> int:
+        """Bytes a cached token on a FULL layer (a shared one has no
+        index key): what a layer's gathered window is sized by."""
+        return super().token_bytes(itemsize, quantized) + self.di * itemsize
+
+    def index_token_bytes(self, itemsize: int) -> int:
+        """Bytes a cached token's index keys take, all full layers."""
+        return len(self.full) * self.di * itemsize
+
+    def model_token_bytes(self, itemsize: int, quantized: bool = False
+                          ) -> int:
+        """Bytes a cached token, all layers: a latent row a layer, an
+        index key a full layer."""
+        return (self.cfg.num_layers
+                * LatentKV.token_bytes(self, itemsize, quantized)
+                + self.index_token_bytes(itemsize))
+
+    @staticmethod
+    def select_bytes(queries: int, keys: int) -> int:
+        """What a full layer's selection holds at once for a chunk of
+        ``queries`` over ``keys``: float32 scores, their sort keys, a
+        running count and the masks (``select``), 16 B a pair."""
+        return 16 * queries * keys
+
+    def kernel_supported(self, page: int) -> bool:
+        """The kernel this cache has is the chunk kernel."""
+        return self.prefix_kernel_supported(page)
+
+    # ---------------------------------------------------------------- write
+
+    def write(self, kv_cache: KVCache, new_c, new_r, new_i, pages,
+              offsets: Optional[jax.Array] = None) -> KVCache:
+        """``LatentKV.write`` and, in the same step, the full layers' index
+        keys: ``new_i`` comes stacked over ALL layers (L, ...) as the scan
+        gave it and the full layers' rows go to the same pages and
+        offsets of the ``"i"`` leaf."""
+        out = super().write(kv_cache, new_c, new_r, pages, offsets)
+        pi = kv_cache["i"]
+        Lf, N, _, page, di = pi.shape
+        rows = new_i[jnp.asarray(self.full)].astype(pi.dtype)
+        with jax.named_scope("attn_index"):     # the index key write
+            if offsets is None:
+                out["i"] = pi.at[:, pages].set(
+                    rows.reshape(Lf, -1, 1, page, di))
+            else:
+                row = pages * page + offsets                    # (B, S)
+                out["i"] = pi.reshape(Lf, N * page, di).at[
+                    jnp.arange(Lf)[:, None, None], row[None]].set(
+                    rows).reshape(pi.shape)
+        return out
+
+    def insert_pages(self, kv_cache: KVCache, c_new, r_new, i_new,
+                     dest) -> KVCache:
+        return self.write(kv_cache, c_new[:, 0], r_new[:, 0], i_new[:, 0],
+                          dest)
+
+    # ----------------------------------------------------------------- read
+
+    def index_window(self, kv_cache: KVCache, at, block_table):
+        """A full layer's slot windows of index keys, (B, P * page, di),
+        gathered by (its place among the full layers, page)."""
+        pi = kv_cache["i"]
+        B, P = block_table.shape
+        pages = block_table + at * pi.shape[1]
+        return pi.reshape((-1,) + pi.shape[3:])[pages].reshape(
+            B, P * pi.shape[3], self.di)
+
+    def select(self, index: dict, keys, valid: jax.Array) -> jax.Array:
+        """The layer's keep mask (B, S, T): on a full layer the
+        ``index_topk`` best of the ``valid`` (causal) keys by the
+        indexer's scores over ``keys()`` (B, T, di), on a shared layer
+        the mask carried from the full layer below — no score, no
+        top-k, no read of an index key. A window no longer than
+        ``index_topk`` keeps all that is valid without a score."""
+        from ..ops.sparse_index import index_scores, topk_keep
+        if valid.shape[-1] <= self.K:
+            return valid
+
+        def full(_):
+            with jax.named_scope("attn_index"):
+                scores = index_scores(index["q"], index["w"], keys())
+            with jax.named_scope("attn_select"):
+                return topk_keep(scores, valid, self.K)
+
+        return jax.lax.cond(index["full"], full, lambda _: index["keep"],
+                            None)
+
+    def attend_tokens(self, q, c, k_r, lp, positions, kv_valid_len, index):
+        """Expanded attention over the tokens given, each query over its
+        kept keys. Returns ``(attn, keep)``."""
+        from ..ops.latent_attention import _causal
+        from ..ops.sparse_index import expanded_masked
+        B, T, _ = c.shape
+        H = self.cfg.num_heads
+        valid = _causal(positions, kv_valid_len, T)[:, 0]
+        keys = index.get("keys", index["k"])
+        keep = self.select(index, lambda: keys.astype(index["q"].dtype),
+                           valid)
+        k_nope = qmm(c, lp["wk_b"]).reshape(B, T, H, self.nope)
+        v = qmm(c, lp["wv_b"]).reshape(B, T, H, self.vd)
+        qn, qr = self._split(q)
+        return expanded_masked(qn, qr, k_nope.astype(q.dtype),
+                               k_r.astype(q.dtype), v.astype(q.dtype), keep,
+                               self.cfg.score_scale), keep
+
+    def put_dense(self, lp, c, k_r, row_start, index):
+        put = jax.vmap(
+            lambda a, u, s: jax.lax.dynamic_update_slice(a, u, (s, 0)))
+        return (*super().put_dense(lp, c, k_r, row_start),
+                put(lp["cache_i"], index["k"].astype(lp["cache_i"].dtype),
+                    row_start))
+
+    def attend_window(self, q, c, k_r, lp, kv_cache, layer, block_table,
+                      rows, positions, kv_valid_len, index):
+        """``LatentKV.attend_window`` over the kept keys: the decode step
+        and the verify forward. Returns ``(attn, keep)``."""
+        from ..ops.latent_attention import _causal, head_product
+        from ..ops.sparse_index import absorbed_masked
+        qn, qr = self._split(q)
+        gc, gr = self.window(kv_cache, layer, block_table)
+        gc = gc.astype(q.dtype).at[rows, positions].set(c.astype(q.dtype))
+        gr = gr.astype(q.dtype).at[rows, positions].set(
+            k_r.astype(q.dtype))
+        valid = _causal(positions, kv_valid_len, gc.shape[1])[:, 0]
+
+        def keys():
+            gi = self.index_window(kv_cache, index["layer"], block_table)
+            return gi.astype(q.dtype).at[rows, positions].set(
+                index["k"].astype(q.dtype))
+
+        keep = self.select(index, keys, valid)
+        o_c = absorbed_masked(self.absorb(qn, lp), qr, gc, gr, keep,
+                              self.cfg.score_scale)
+        return head_product(o_c, lp["wv_b"], self.cfg.num_heads), keep
+
+    def prefix_keys(self, n_pages: int, page: int,
+                    block_pages: int = 4) -> int:
+        """The keys of a chunk's prefix as ``attend_prefix`` walks them:
+        the block table padded to whole blocks."""
+        return -(-n_pages // block_pages) * block_pages * page
+
+    def attend_prefix(self, q, c, k_r, lp, kv_cache, block_table, start,
+                      kv_valid_len, layer, index, block_pages: int = 4,
+                      use_kernel: bool = False):
+        """``LatentKV.attend_prefix`` over the kept keys; the mask is (C,
+        prefix keys + C), a row a query. B = 1. Returns ``(attn,
+        keep)``."""
+        _, C, _, _ = q.shape
+        P = block_table.shape[1]
+        page = self.page_size(kv_cache)
+        Tp = self.prefix_keys(P, page, block_pages)
+        t = jnp.arange(Tp, dtype=jnp.int32)
+        rel = jnp.arange(C, dtype=jnp.int32)
+        own = (rel[None, :] <= rel[:, None]) \
+            & ((start + rel) < kv_valid_len[0])[None, :]
+        valid = jnp.concatenate(
+            [jnp.broadcast_to(t[None, :] < start, (C, Tp)), own],
+            axis=1)[None]                                   # (1, C, Tp + C)
+
+        def keys():
+            tbl = jnp.pad(block_table, ((0, 0), (0, Tp // page - P)))
+            gi = self.index_window(kv_cache, index["layer"], tbl)
+            return jnp.concatenate(
+                [gi.astype(q.dtype), index["k"].astype(q.dtype)], axis=1)
+
+        keep = self.select(index, keys, valid)
+        attn = super().attend_prefix(
+            q, c, k_r, lp, kv_cache, block_table, start, kv_valid_len,
+            layer, block_pages, use_kernel, keep=keep[0].T)
+        return attn, keep

@@ -43,7 +43,12 @@ wv: ``wq_a`` (L, D, Rq), ``q_a_norm`` (L, Rq), ``wq_b`` (L, Rq, H*hd),
 ``wkv_a`` (L, D, R + rope), ``kv_a_norm`` (L, R), ``wk_b`` (L, R,
 H*nope), ``wv_b`` (L, R, H*v), and ``wo`` (L, H*v, D). Under an expert
 share (``experts_held``) the expert stacks hold the held experts only;
-the router keeps every column.
+the router keeps every column. A model with learned sparse attention
+(``index_topk``) has, in each stack, the indexer of its Lf FULL layers,
+stacked over those alone: ``index_wq`` (Lf, Rq, Hi*di), ``index_wk`` (Lf,
+D, di), ``index_k_norm`` / ``index_k_norm_b`` (Lf, di), ``index_wp`` (Lf,
+D, Hi); bf16, never quantized (the selection is what a precision step
+would move).
 """
 
 from __future__ import annotations
@@ -77,9 +82,14 @@ from .kv_cache import (KVCache, _paged_prefix_attention,  # noqa: F401
 #: candidate handling: where the sort is). A shared expert runs as
 #: ``mlp/moe_shared`` (a dense FFN inside ``mlp``, under a name of its
 #: own). docs/observability.md lists them; the benchmark's scope reader
-#: keeps an equal tuple.
+#: keeps an equal tuple. Learned sparse attention adds ``INDEX_SCOPES``:
+#: ``attn_index`` (the indexer's projections, beside ``attn_proj``; its
+#: key's write; and, inside ``attn``, the scores over the cached index
+#: keys) and ``attn_select`` (the exact top-k, inside ``attn``), both on
+#: full layers only.
 SCOPES = ("embed", "attn_proj", "attn", "mlp", "moe_route", "moe_experts",
           "tail", "tail_select")
+INDEX_SCOPES = ("attn_index", "attn_select")
 
 
 def _embed(params: "Params", tokens: jax.Array,
@@ -237,11 +247,23 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
         R, Rq = cfg.kv_lora_rank, cfg.q_lora_rank
         nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
         m2 = cfg.score_scale * hd ** 0.5
+        # Under a learned selection of keys (``index_topk``) a query's
+        # scores deviate by 2.4, not 4: a random indexer's choice is
+        # independent of what the heads attend, so where bf16 reorders
+        # two near-ties at the ``index_topk``-th place (~3 members of a
+        # 4608-token query's set a full layer; chip, PR 40) the swapped
+        # key may be one a head rests on. At 4 that moved the sound
+        # program's logits by 3.5-3.9 % of their scale at the MEDIAN
+        # position (1.0 % with the selection off on both sides); at 2.4
+        # by 1.4-1.7 %, while no selection, half of it, another layer's
+        # set or an indexer without its pairs still move every position
+        # by 10 % or more (PERF.md section 6).
+        gain = q_gain * 0.36 if cfg.index_topk else q_gain
         half = jnp.where(jnp.arange(R + cfg.qk_rope_head_dim) < R, 0.5, 1.0)
         wkv_a = norm(next(k), (L, D, R + cfg.qk_rope_head_dim), D)
         return {
             "wq_a": norm(next(k), (L, D, Rq), 4 * D),
-            "wq_b": norm(next(k), (L, Rq, H * hd), Rq * m2 * m2 / q_gain),
+            "wq_b": norm(next(k), (L, Rq, H * hd), Rq * m2 * m2 / gain),
             "wkv_a": (wkv_a.astype(jnp.float32) * half).astype(dtype),
             "wk_b": norm(next(k), (L, R, H * nope), R),
             "wv_b": norm(next(k), (L, R, H * vd), R),
@@ -316,6 +338,31 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
             })
         return out
 
+    def indexer(k, first, n):
+        """The indexer of a stack's full layers (``cfg.layer_index``),
+        stacked over those alone. Plain fan-in draws: a query's dot with
+        a key (both of unit entries after the norms) deviates by
+        sqrt(index_head_dim), half of them pass the relu, and the head
+        weights are the normed input's projection, of either sign — so a
+        token's score is a signed sum over the heads, the chosen set of a
+        context longer than ``index_topk`` is no prefix, suffix or
+        window of it, and dropping the rotation, the norm's bias (a
+        tenth) or a head's weight moves who is chosen."""
+        Lf = sum(cfg.layer_index[first:first + n])
+        Hi, di = cfg.index_n_heads, cfg.index_head_dim
+        if not Lf:
+            return {}
+        return {
+            "index_wq": norm(next(k), (Lf, cfg.q_lora_rank, Hi * di),
+                             cfg.q_lora_rank),
+            "index_wk": norm(next(k), (Lf, D, di), D),
+            "index_k_norm": (1.0 + 0.1 * jax.random.normal(
+                next(k), (Lf, di), jnp.float32)).astype(dtype),
+            "index_k_norm_b": (0.1 * jax.random.normal(
+                next(k), (Lf, di), jnp.float32)).astype(dtype),
+            "index_wp": norm(next(k), (Lf, D, Hi), D),
+        }
+
     kx = iter(jax.random.split(jax.random.fold_in(key, 1), 32))
     layers = stack(k, L, bool(cfg.num_experts))
     layers.update(extras(kx, layers))
@@ -332,6 +379,10 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
     for name, _, n in leading:          # the leading dense stack
         params[name] = stack(kx, n, False)
         params[name].update(extras(kx, params[name]))
+    if cfg.index_topk:
+        ki = iter(jax.random.split(jax.random.fold_in(key, 2), 16))
+        for name, first, n in cfg.layer_stacks:
+            params[name].update(indexer(ki, first, n))
     return params
 
 
@@ -397,6 +448,15 @@ def layer_kinds(cfg: LlamaConfig, first: int = 0,
                                       jnp.int32)
     if not all(cfg.layer_rope):
         kinds["rope"] = jnp.asarray(cfg.layer_rope[first:last], bool)
+    if cfg.index_topk:
+        # a full layer's place among the MODEL's full layers (its layer
+        # of the pool's index leaf), -1 on a shared layer; its place
+        # among its stack's (its row of the stacked indexer weights) is
+        # that less the full layers below the stack
+        flags = cfg.layer_index
+        kinds["index"] = jnp.asarray(
+            [sum(flags[:i]) if flags[i] else -1 for i in range(first, last)],
+            jnp.int32)
     return kinds
 
 
@@ -434,7 +494,13 @@ def _scan_inputs(layers: dict[str, jax.Array], cfg: LlamaConfig, first: int):
         held = {name: layers.pop(name)
                 for name in ("w_gate", "w_up", "w_down")}
         layers["layer_index"] = jnp.arange(n, dtype=jnp.int32)
+    # an indexer is stacked over its stack's FULL layers only: held, and
+    # sliced by the layer's place among them (``_index_project``)
+    held.update({name: layers.pop(name) for name in list(layers)
+                 if name.startswith("index_")})
     layers.update(layer_kinds(cfg, first, n))
+    if cfg.index_topk:
+        held["index_first"] = sum(cfg.layer_index[:first])
     return layers, held
 
 
@@ -443,7 +509,7 @@ def _run_stack(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
                kv_valid_len: Optional[jax.Array], attend=None, *,
                state=None, xs: Optional[dict] = None,
                row_mask: Optional[jax.Array] = None, stats: bool = False,
-               first: int = 0):
+               first: int = 0, selection: Optional[jax.Array] = None):
     """The ONE scan over a layer stack; every forward is a use of it
     (through ``_run_model``, once a stack of the model) and hands it
     only what differs. ``attend(q, k, v, lp, li, state) -> (attn, out)``
@@ -452,8 +518,17 @@ def _run_stack(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
     ``li`` is its index IN THE MODEL: the stack's layers are the model's
     layers ``first``, ``first + 1``, ..., and that is where their kinds
     are read, where the kernel appends and where the pool is read.
-    Returns ``(h, out, touched)``, the last each layer's
+    Returns ``(h, out, touched, selection)``, the third each layer's
     ``experts_touched`` (n,) under ``stats``.
+
+    ``selection`` (learned sparse attention; None otherwise, and then
+    nothing of it is in the program): the keep mask over the keys that a
+    FULL layer's attention selects and the shared layers above it
+    attend — the one value that leaves a layer's attention and enters
+    the next layer's. It rides the carry beside ``h``: a layer finds it
+    as ``lp["selection"]`` and its ``attend`` returns ``(attn, (out,
+    selection))``; the caller hands in the mask's shape (zeros) and gets
+    the last layer's back, for the next stack.
 
     What the scan iterates over and what it closes over was decided on
     the chip, three times, and is written down here and in ``scan_layers``:
@@ -487,48 +562,57 @@ def _run_stack(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
     carried = state is not None
 
     def body(carry, lp):
-        h, state, li = carry
+        h, state, li, sel = carry
         lp = {**lp, **held}
+        if sel is not None:
+            lp["selection"] = sel
         aux = {} if stats else None
         h, out = decoder_layer(
             h, lp, cfg, positions, inv_freq, kv_valid_len,
-            attend=attend and (lambda q, k, v: attend(q, k, v, lp, li,
-                                                       state)),
+            attend=attend and (lambda q, k, v, *index: attend(
+                q, k, v, lp, li, state, *index)),
             row_mask=row_mask, aux=aux)
+        if sel is not None:
+            out, sel = out
         if carried:
             state, out = out, None
         touched = None if aux is None else {
             name: aux.get(name, jnp.float32(0.0))
             for name in layer_stat_names(cfg)}
-        return (h, state, li + 1), (out, touched)
+        return (h, state, li + 1, sel), (out, touched)
 
     # the Pallas call takes its layer as a (1,) scalar-prefetch operand;
     # the held form indexes the flattened pool with a scalar
     li = jnp.zeros((1,) if carried else (), jnp.int32)
     if first:
         li = li + first
-    (h, state, _), (out, touched) = jax.lax.scan(body, (h, state, li), stack)
-    return h, (state if carried else out), touched
+    (h, state, _, selection), (out, touched) = jax.lax.scan(
+        body, (h, state, li, selection), stack)
+    return h, (state if carried else out), touched, selection
 
 
 def _run_model(params: Params, cfg: LlamaConfig, h: jax.Array, *args,
-               state=None, xs: Optional[dict] = None, **kw):
+               state=None, xs: Optional[dict] = None,
+               selection: Optional[jax.Array] = None, **kw):
     """Every layer of the model: ``_run_stack`` over each of its stacks
     in turn (``cfg.layer_stacks``: one, or the leading dense layers and
     then the expert layers) inside the caller's one program, over ONE
     pool — carried from stack to stack (``state``) or held by the
     caller's ``attend`` while each stack's rows come out and are joined
     along the layer axis. ``xs`` (L, ...) is cut to each stack's layers.
-    ``touched`` is that of the layers that have experts."""
+    ``touched`` is that of the layers that have experts. A ``selection``
+    (``_run_stack``) is handed from stack to stack: the first expert
+    layers attend the set of the last full dense layer."""
     stacks = cfg.layer_stacks
     if len(stacks) == 1:
         return _run_stack(params["layers"], cfg, h, *args, state=state,
-                          xs=xs, **kw)
+                          xs=xs, selection=selection, **kw)[:3]
     outs, touched = [], None
     for name, first, n in stacks:
         part = xs and {k: v[first:first + n] for k, v in xs.items()}
-        h, out, t = _run_stack(params[name], cfg, h, *args, state=state,
-                               xs=part, first=first, **kw)
+        h, out, t, selection = _run_stack(
+            params[name], cfg, h, *args, state=state, xs=part, first=first,
+            selection=selection, **kw)
         if state is not None:
             state = out
         outs.append(out)
@@ -537,6 +621,20 @@ def _run_model(params: Params, cfg: LlamaConfig, h: jax.Array, *args,
     if state is None:
         state = jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *outs)
     return h, state, touched
+
+
+def _kv_step(attn, rows: tuple, index: tuple, key=None):
+    """What a forward's ``attend`` returns: ``(attn, rows)``, the rows the
+    layer leaves in the cache. Under learned sparse attention (``index``
+    holds the layer's index projections) the cache object's reader gave
+    ``(attn, selection)``: the index key (``key``; the projections' own
+    by default) joins the rows and the selection rides beside them
+    (``_run_stack``)."""
+    if not index:
+        return attn, rows
+    attn, selection = attn
+    return attn, (rows + (index[0]["k"] if key is None else key,),
+                  selection)
 
 
 def _step_result(params: Params, cfg: LlamaConfig, h: jax.Array,
@@ -578,12 +676,13 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     ``use_kernel``: the engine decides (None = auto, for single-device
     callers). True is the Pallas kernel with the pool in the layer
     scan's carry (``_run_stack``); False — the CPU, a mesh the kernel
-    refuses — is the one-token case of ``apply_verify_paged``.
+    refuses, a cache object without a decode kernel (learned sparse
+    attention) — is the one-token case of ``apply_verify_paged``.
     """
     kvc = kv_cache_of(cfg)
     if use_kernel is None:
         use_kernel = use_paged_kernel(cfg, kvc.page_size(kv_cache))
-    if not use_kernel:
+    if not use_kernel or kvc.kernel_attend is None:
         return apply_verify_paged(
             params, cfg, tokens, positions, kv_cache, block_table,
             kv_valid_len, write_page[:, None], write_offset[:, None],
@@ -641,14 +740,21 @@ def apply_verify_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     kvc = kv_cache_of(cfg)
     rows = jnp.arange(tokens.shape[0])[:, None]
 
-    def attend(q, k, v, lp, li, _):
-        return kvc.attend_window(q, k, v, lp, kv_cache, li, block_table,
-                                 rows, positions, kv_valid_len), (k, v)
+    def attend(q, k, v, lp, li, _, *index):
+        return _kv_step(kvc.attend_window(
+            q, k, v, lp, kv_cache, li, block_table, rows, positions,
+            kv_valid_len, *index), (k, v), index)
 
-    h, (new_k, new_v), touched = _run_model(
+    selection = None
+    if cfg.index_topk:
+        selection = jnp.zeros(
+            tokens.shape + (block_table.shape[1]
+                            * kvc.page_size(kv_cache),), bool)
+    h, new, touched = _run_model(
         params, cfg, _embed(params, tokens, cfg.embed_scale), positions,
-        inv_freq, kv_valid_len, attend, row_mask=active, stats=stats)
-    cache = kvc.write(kv_cache, new_k, new_v, write_pages, write_offsets)
+        inv_freq, kv_valid_len, attend, row_mask=active, stats=stats,
+        selection=selection)
+    cache = kvc.write(kv_cache, *new, write_pages, write_offsets)
     return _step_result(params, cfg, h, cache, touched, return_hidden,
                         stats)
 
@@ -717,43 +823,56 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     h = _embed(params, tokens, cfg.embed_scale)
     start = positions[0, 0]  # absolute position of the chunk's first row
 
-    def attend(q, k, v, lp, li, _):
+    def attend(q, k, v, lp, li, _, *index):
         # prefix streamed from the pool block-by-block (online softmax)
         # + the chunk's own K/V in-register; the pool write happens in
         # the one post-scan scatter. Never materializes the full
         # gathered window — prefix length does not bound this path's
         # memory.
         attn = kvc.attend_prefix(q, k, v, lp, kv_cache, block_table, start,
-                                 kv_valid_len, li, **kernel)
-        return attn, (k[0], v[0])
+                                 kv_valid_len, li, *index, **kernel)
+        return _kv_step(attn, (k[0], v[0]), index,
+                        *(ix["k"][0] for ix in index))
 
-    def attend_rows(q, k, v, lp, li, _):
+    def attend_rows(q, k, v, lp, li, _, *index):
+        # what of ``index`` is a row's own: its projections and the mask
+        # carried; whether the layer selects and where its keys lie is
+        # the layer's
+        own = [{n: ix[n] for n in ("q", "k", "w", "keep")} for ix in index]
+
         def row(r):
-            q, k, v, table, start, valid = r
-            return kvc.attend_prefix(q[None], k[None], v[None], lp, kv_cache,
-                                     table[None], start, valid[None], li,
-                                     **kernel)[0]
+            q, k, v, table, start, valid, *own = r
+            out = kvc.attend_prefix(
+                q[None], k[None], v[None], lp, kv_cache, table[None], start,
+                valid[None], li, *(
+                    {**ix, **{n: a[None] for n, a in o.items()}}
+                    for ix, o in zip(index, own)), **kernel)
+            return (out[0][0], out[1][0]) if index else out[0]
         attn = jax.lax.map(row, (q, k, v, block_table, positions[:, 0],
-                                 kv_valid_len))
-        return attn, (k, v)
+                                 kv_valid_len, *own))
+        return _kv_step(attn, (k, v), index)
 
-    h, (new_k, new_v), _ = _run_model(params, cfg, h, positions, inv_freq,
-                                      kv_valid_len,
-                                      attend if B == 1 else attend_rows)
-    # new_k/new_v: (L, C, KV, hd), to the chunk's physical pages
+    selection = None
+    if cfg.index_topk:
+        selection = jnp.zeros((B, C, kvc.prefix_keys(
+            block_table.shape[1], page) + C), bool)
+    h, new, _ = _run_model(params, cfg, h, positions, inv_freq,
+                           kv_valid_len, attend if B == 1 else attend_rows,
+                           selection=selection)
+    # new: (L, C, KV, hd) a leaf, to the chunk's physical pages
     if B == 1:
         dest = jax.lax.dynamic_slice(block_table[0], (start_page_idx,),
                                      (C // page,))
     else:
         # (L, B, C, ...) row-major is (L, B * C, ...): every row's pages
         # in one list, each row's own run of its table
-        new_k, new_v = (a.reshape((a.shape[0], B * C) + a.shape[3:])
-                        for a in (new_k, new_v))
+        new = tuple(a.reshape((a.shape[0], B * C) + a.shape[3:])
+                    for a in new)
         dest = jnp.take_along_axis(
             block_table, start_page_idx[:, None]
             + jnp.arange(C // page, dtype=jnp.int32)[None], axis=1
         ).reshape(-1)
-    cache = kvc.write(kv_cache, new_k, new_v, dest)
+    cache = kvc.write(kv_cache, *new, dest)
     if not with_logits:
         return h, cache
     return unembed(params, cfg, h), cache
@@ -849,6 +968,9 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
     ``attend(q, k, v) -> (attn, out)`` is the whole KV step (cache read,
     write and attention; ``out`` is whatever the forward carries or
     collects); None attends the tokens given. Returns (h, out or None).
+    Under learned sparse attention (``cfg.index_topk``) the layer's index
+    projections go to ``attend`` as a fourth argument and ``out`` is
+    ``(out, selection)`` (``_run_stack``).
 
     Per-layer kinds ride in ``lp`` (``layer_kinds``): ``window`` masks the
     built-in attention (an ``attend`` closure reads it itself) and
@@ -868,7 +990,10 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
             router_logits = _router_logits(h, lp)
     with jax.named_scope("attn_proj"):
         x = block_norm(h, lp, "attn_norm", cfg)
-        if cfg.kv_lora_rank:
+        if cfg.index_topk:
+            q, k, v, c_q = _latent_qkv(x, lp, cfg, positions, inv_freq,
+                                       with_latent=True)
+        elif cfg.kv_lora_rank:
             q, k, v = _latent_qkv(x, lp, cfg, positions, inv_freq)
         else:
             q = qmm(x, lp["wq"])
@@ -900,13 +1025,20 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
                 q, k = jnp.where(lp["rope"], qr, q), jnp.where(lp["rope"], kr, k)
             else:
                 q, k = apply_rope(q, k, positions, inv_freq)
+    index = ()
+    if cfg.index_topk:
+        with jax.named_scope("attn_index"):
+            index = (_index_project(x, c_q, lp, cfg, positions, inv_freq),)
     with jax.named_scope("attn"):
         if attend is not None:
-            attn, new_cache = attend(q, k, v)
+            attn, new_cache = attend(q, k, v, *index)
         else:
             attn = kv_cache_of(cfg).attend_tokens(q, k, v, lp, positions,
-                                                  kv_valid_len)
+                                                  kv_valid_len, *index)
             new_cache = None
+            if index:       # the layer's selection, for the layers above
+                attn, keep = attn
+                new_cache = (None, keep)
     with jax.named_scope("attn_proj"):
         attn = attn.reshape(
             B, S, cfg.num_heads * (cfg.v_head_dim or cfg.head_dim))
@@ -941,18 +1073,20 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
 
 
 def _latent_qkv(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
-                positions: jax.Array, inv_freq: jax.Array):
+                positions: jax.Array, inv_freq: jax.Array,
+                with_latent: bool = False):
     """A latent-attention layer's projections of its normed input ``x``
     (B, S, D): ``(q, c, k_r)`` — the queries (B, S, H, nope + rope)
     through the low-rank pair ``wq_a`` (normed) and ``wq_b``, their rope
     part rotated; the normed latent (B, S, R); the ONE rotated key part
     (B, S, rope) all heads share. ``(c, k_r)`` is what a token leaves in
     the cache; how the heads' keys and values come out of it, expanded or
-    absorbed, is the cache object's (models/kv_cache.py)."""
+    absorbed, is the cache object's (models/kv_cache.py). ``with_latent``
+    appends the normed query latent (B, S, Rq), the indexer's input."""
     B, S, _ = x.shape
     R, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    q = qmm(rmsnorm(qmm(x, lp["wq_a"]), lp["q_a_norm"], cfg.rms_norm_eps),
-            lp["wq_b"])
+    c_q = rmsnorm(qmm(x, lp["wq_a"]), lp["q_a_norm"], cfg.rms_norm_eps)
+    q = qmm(c_q, lp["wq_b"])
     kv = qmm(x, lp["wkv_a"])
     # the head split stays out of the matmul (``decoder_layer`` says why)
     q, kv = jax.lax.optimization_barrier((q, kv))
@@ -962,7 +1096,69 @@ def _latent_qkv(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
     if cfg.rope_interleave:     # pairs (2i, 2i+1) as published
         q_r, k_r = deinterleave(q_r), deinterleave(k_r)
     q_r, k_r = apply_rope(q_r, k_r, positions, inv_freq)
-    return jnp.concatenate([q[..., :nope], q_r], axis=-1), c, k_r[:, :, 0]
+    out = jnp.concatenate([q[..., :nope], q_r], axis=-1), c, k_r[:, :, 0]
+    return out + (c_q,) if with_latent else out
+
+
+def _index_project(x: jax.Array, c_q: jax.Array, lp: dict[str, jax.Array],
+                   cfg: LlamaConfig, positions: jax.Array,
+                   inv_freq: jax.Array) -> dict:
+    """The indexer's projections of a layer (learned sparse attention),
+    computed on a FULL layer only — a shared layer takes the zeros of
+    the ``cond``'s other branch, which nothing reads:
+
+    - ``q`` (B, S, Hi, di): the query latent ``c_q`` (the main queries'
+      own) through ``index_wq``;
+    - ``k`` (B, S, di): the block's normed input ``x`` through
+      ``index_wk``, LayerNorm (weight and bias, eps 1e-6) — the row the
+      token leaves in the index cache;
+    - the first ``qk_rope_head_dim`` values of both rotated at the
+      token's position with the model's frequencies (pairs (2i, 2i+1)
+      under ``index_rope_interleave``);
+    - ``w`` (B, S, Hi) float32: ``x`` through ``index_wp``, times
+      Hi ** -0.5 * di ** -0.5.
+
+    With them: ``full`` (whether this layer selects), ``layer`` (its
+    layer of the pool's index leaf) and ``keep`` (the selection carried
+    from below): what the cache object's ``attend_*`` take as
+    ``index``."""
+    B, S, _ = x.shape
+    Hi, di, rope = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+    full = lp["index"] >= 0
+    at = jnp.maximum(lp["index"] - lp["index_first"], 0)
+
+    def weights(name):
+        return jax.lax.dynamic_index_in_dim(lp[name], at, 0, keepdims=False)
+
+    def project(_):
+        q = qmm(c_q, weights("index_wq")).reshape(B, S, Hi, di)
+        k = qmm(x, weights("index_wk")).astype(jnp.float32)
+        k = k - jnp.mean(k, axis=-1, keepdims=True)
+        k = k * jax.lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True) + 1e-6)
+        k = (k * weights("index_k_norm").astype(jnp.float32)
+             + weights("index_k_norm_b").astype(jnp.float32)
+             ).astype(x.dtype)[:, :, None]                  # (B, S, 1, di)
+        q_r, k_r = q[..., :rope], k[..., :rope]
+        if cfg.index_rope_interleave:
+            q_r, k_r = deinterleave(q_r), deinterleave(k_r)
+        q_r, k_r = apply_rope(q_r, k_r, positions, inv_freq)
+        w = qmm(x, weights("index_wp")).astype(jnp.float32) \
+            * (Hi ** -0.5 * di ** -0.5)
+        return (jnp.concatenate([q_r, q[..., rope:]], axis=-1),
+                jnp.concatenate([k_r, k[..., rope:]], axis=-1)[:, :, 0], w)
+
+    def nothing(_):
+        return (jnp.zeros((B, S, Hi, di), x.dtype),
+                jnp.zeros((B, S, di), x.dtype),
+                jnp.zeros((B, S, Hi), jnp.float32))
+
+    if "index_wq" not in lp:    # a stack without a full layer
+        full = jnp.bool_(False)
+        q, k, w = nothing(None)
+    else:
+        q, k, w = jax.lax.cond(full, project, nothing, None)
+    return {"q": q, "k": k, "w": w, "full": full,
+            "layer": jnp.maximum(lp["index"], 0), "keep": lp["selection"]}
 
 
 def run_layers(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
@@ -1091,23 +1287,29 @@ def apply(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     Returns (logits (B,S,V) or hidden (B,S,D), updated cache or None).
     """
     inv_freq = _inv_freq(cfg)
-    attend = xs = None
+    attend = xs = selection = None
+    kvc = kv_cache_of(cfg)
+    if cfg.index_topk:      # the keep mask's shape: queries x keys
+        keys = tokens.shape[1] if kv_cache is None \
+            else kv_cache[kvc.leaves[0]].shape[2]
+        selection = jnp.zeros(tokens.shape + (keys,), bool)
     if kv_cache is not None:
         if kv_valid_len is None:
             kv_valid_len = positions[:, -1] + 1
         row_start = positions[:, 0]
-        kvc = kv_cache_of(cfg)
         xs = {"cache_" + n: kv_cache[n] for n in kvc.leaves}
 
-        def attend(q, k, v, lp, li, _):
+        def attend(q, k, v, lp, li, _, *index):
             # this chunk written at its absolute positions
-            kc, vc = kvc.put_dense(lp, k, v, row_start)     # (B,T,KV,hd)
-            return kvc.attend_tokens(q, kc, vc, lp, positions,
-                                     kv_valid_len), (kc, vc)
+            new = kvc.put_dense(lp, k, v, row_start, *index)  # (B,T,KV,hd)
+            attn = kvc.attend_tokens(
+                q, new[0], new[1], lp, positions, kv_valid_len,
+                *({**ix, "keys": new[2]} for ix in index))
+            return _kv_step(attn, new[:2], index, *new[2:])
 
     h, new, _ = _run_model(
         params, cfg, _embed(params, tokens, cfg.embed_scale), positions,
-        inv_freq, kv_valid_len, attend, xs=xs)
+        inv_freq, kv_valid_len, attend, xs=xs, selection=selection)
     new_cache = None if new is None else dict(
         zip(kv_cache_of(cfg).leaves, new))
     if return_hidden:
@@ -1243,8 +1445,8 @@ def apply_prefill_sp(params: Params, cfg: LlamaConfig, tokens: jax.Array,
                                       axis_name="sp",
                                       axis_size=n_sp), (k, v)
 
-        h, (ks, vs), _ = _run_stack(params_l["layers"], cfg, h, positions_l,
-                                    inv_freq, None, attend)
+        h, (ks, vs), *_ = _run_stack(params_l["layers"], cfg, h,
+                                     positions_l, inv_freq, None, attend)
         # Last valid position's hidden state: the row lives on exactly
         # one sp shard — mask-select locally, then one psum makes it
         # replicated. (B, D) is tiny; the unembed runs on it outside.

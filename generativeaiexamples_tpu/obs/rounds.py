@@ -165,6 +165,7 @@ class RoundRecord:
         "prefill_tokens", "prefill_padded_tokens", "grants",
         "pages_touched", "hbm_bytes",
         "kv_restore_pages", "blocked_on_pages", "kv_pages_skipped",
+        "kv_rows_selected", "kv_rows_indexed", "kv_selected_pct",
         "dispatch_ms", "modeled_ms", "t_dispatch_done",
         # execution (harvest thread)
         "harvest_wait_ms", "first_readback_ms", "emit_ms",
@@ -230,6 +231,15 @@ class RoundRecord:
         # pages averaged over the layers, the unit of pages_touched;
         # from the plan, scheduler thread). 0 without window layers.
         self.kv_pages_skipped = 0.0
+        # Learned sparse attention (from the plan, scheduler thread):
+        # cached rows this round's decode steps' attention selected, a
+        # layer (each row's min(context, index_topk), over rows and
+        # steps); rows their indexer scored, a full layer (each row's
+        # context); and the first as a share of the second, in percent:
+        # what of a dense read the selection keeps. 0 without an indexer.
+        self.kv_rows_selected = 0
+        self.kv_rows_indexed = 0
+        self.kv_selected_pct = 0.0
         self.dispatch_ms = 0.0
         self.modeled_ms = 0.0
         self.t_dispatch_done = self.t_start
@@ -322,6 +332,9 @@ class RoundRecord:
                 "spec_accepted": self.spec_accepted,
                 "pages_touched": self.pages_touched,
                 "kv_pages_skipped": round(self.kv_pages_skipped, 2),
+                "kv_rows_selected": self.kv_rows_selected,
+                "kv_rows_indexed": self.kv_rows_indexed,
+                "kv_selected_pct": round(self.kv_selected_pct, 2),
                 "experts_touched": round(self.experts_touched, 2),
                 "tail_resort_pct": round(self.tail_resort_pct, 2),
                 "local_assignments": round(self.local_assignments, 2),

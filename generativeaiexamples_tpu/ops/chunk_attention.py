@@ -87,6 +87,7 @@ def _tile(n: int, want: int, unit: int) -> int:
 def chunk_attention_update(q: jax.Array, k: jax.Array, vt: jax.Array,
                            carry, k0, limit, q0, *, scale: float,
                            causal: bool, k_shared: jax.Array = None,
+                           keep: jax.Array = None,
                            interpret: bool = False):
     """One key block folded into the carry.
 
@@ -95,6 +96,11 @@ def chunk_attention_update(q: jax.Array, k: jax.Array, vt: jax.Array,
     k:        (T, H * dk)      the block's keys, heads along the lanes
     vt:       (H * dv, T)      its values, TRANSPOSED (keys on the lanes)
     k_shared: (T, ds) or None  a key part all heads share
+    keep:     (T, C) or None   float32, > 0 where the query (column) may
+                               attend the key (row) at all: a per-query
+                               key set, the same for all heads (learned
+                               sparse attention), ANDed with the mask
+                               the positions give
     carry:    (m, l, acc)      as ``init_carry``
     k0:       () int32         position of the block's first key
     limit:    () int32         keys at or past it are masked
@@ -125,8 +131,9 @@ def chunk_attention_update(q: jax.Array, k: jax.Array, vt: jax.Array,
 
     def kernel(pos_ref, q_ref, k_ref, *refs):
         ks_ref = refs[0] if ds else None
+        keep_ref = refs[1 if ds else 0] if keep is not None else None
         vt_ref, m_ref, l_ref, acc_ref, mo_ref, lo_ref, acco_ref = \
-            refs[1 if ds else 0:]
+            refs[bool(ds) + (keep is not None):]
         k_first, k_limit = pos_ref[0], pos_ref[1]
         q_first = pos_ref[2] + pl.program_id(1) * cq
         live = k_first < k_limit
@@ -140,6 +147,8 @@ def chunk_attention_update(q: jax.Array, k: jax.Array, vt: jax.Array,
             if causal:
                 valid = valid & (kpos <= q_first + jax.lax.broadcasted_iota(
                     jnp.int32, (T, cq), 1))
+            if keep is not None:
+                valid = valid & (keep_ref[...] > 0.0)
             for j in range(hg):
                 kj = k_ref[:, j * dk:(j + 1) * dk]
                 if ds:
@@ -173,6 +182,7 @@ def chunk_attention_update(q: jax.Array, k: jax.Array, vt: jax.Array,
     state = [pl.BlockSpec((hg, 1, cq), heads), pl.BlockSpec((hg, 1, cq), heads),
              pl.BlockSpec((hg, dv, cq), heads)]
     shared = [] if k_shared is None else [k_shared]
+    kept = [] if keep is None else [keep]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,       # (k0, limit, q0)
         grid=(H // hg, C // cq),
@@ -181,12 +191,14 @@ def chunk_attention_update(q: jax.Array, k: jax.Array, vt: jax.Array,
             pl.BlockSpec((T, hg * dk), lambda g, qi, *_: (0, g)),
             *[pl.BlockSpec((T, ds), lambda g, qi, *_: (0, 0))
               for _ in shared],
+            *[pl.BlockSpec((T, cq), lambda g, qi, *_: (0, qi))
+              for _ in kept],
             pl.BlockSpec((hg * dv, T), lambda g, qi, *_: (g, 0)),
             *state,
         ],
         out_specs=state,
     )
-    n_in = 4 + len(shared)           # operands before the carry
+    n_in = 4 + len(shared) + len(kept)   # operands before the carry
     pos = jnp.stack([jnp.asarray(x, jnp.int32).reshape(())
                      for x in (k0, limit, q0)])
     return tuple(pl.pallas_call(
@@ -200,4 +212,4 @@ def chunk_attention_update(q: jax.Array, k: jax.Array, vt: jax.Array,
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="chunk_attn",
-    )(pos, q, k, *shared, vt, m, l, acc))
+    )(pos, q, k, *shared, *kept, vt, m, l, acc))

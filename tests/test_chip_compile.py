@@ -14,6 +14,7 @@ embed the INTERPRETED kernel, or skip it.
 
 import dataclasses
 import os
+import math
 import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -630,6 +631,79 @@ def test_latent_chunk_program_keeps_its_scores_in_the_kernel(
     # 37), 75.8 since a share's padded expert layout is walked in short
     # segments; four prompts' rows: 394.1 (compile, PR 39)
     assert temp < (160 << 20 if rows == 1 else 448 << 20), temp
+
+
+def sparse_cfg():
+    """``glm-5.2`` at published widths, three layers deep: the dense
+    layer (full) and two expert layers (shared, full), one chip's 8 held
+    experts."""
+    return dataclasses.replace(
+        get_model_config("glm-5.2"), num_layers=3, num_dense_layers=1,
+        index_layers=(1, 0, 1), experts_held=8, experts_first=0)
+
+
+@pytest.mark.parametrize("rows", [1, 4])
+def test_sparse_latent_chunk_program_compiles(topo, tpu_backend, rows):
+    """A 512-token chunk program under learned sparse attention at the
+    cell's sizes (2080-page pool, 130-page window): the chunk kernel takes
+    the keep mask as an operand and a head's 192 + 64 key columns folded
+    to 256 whole lanes; the full layers' (512 x 17152) index scores are
+    built a head at a time — no float32 array of index heads x chunk x
+    keys exists — and the program fits beside the pool."""
+    from tools.dump_hlo import parse_hlo
+    cfg = sparse_cfg()
+    dev = SingleDeviceSharding(topo.devices[0])
+    cache = on(jax.eval_shape(
+        lambda: llama.init_paged_kv_cache(cfg, 2081, PAGE)), dev)
+    assert set(cache) == {"c", "r", "i"} and cache["i"].shape[0] == 2
+    i32 = lambda *shape: sds(shape, jnp.int32, dev)  # noqa: E731
+    assert llama.use_prefix_kernel(cfg, PAGE)
+
+    def chunk(params, tok, pos, cache, tbl, valid, start):
+        return llama.apply_prefill_paged(params, cfg, tok, pos, cache, tbl,
+                                         valid, start, use_kernel=True)
+
+    compiled = jax.jit(chunk, donate_argnums=(3,)).lower(
+        on(param_shapes(cfg), dev), i32(rows, 512), i32(rows, 512), cache,
+        i32(rows, 130), i32(rows), i32(rows) if rows > 1 else i32()).compile()
+    assert_fits(compiled)
+    text = compiled.as_text()
+    assert "%chunk_attn" in text
+    keys = 132 * PAGE + 512                 # the table padded to blocks
+    big = [i["name"] for ins in parse_hlo(text).values() for i in ins
+           if (m := re.match(r"f32\[([\d,]+)\]", i["shape"]))
+           and math.prod(int(d) for d in m.group(1).split(","))
+           >= 32 * 512 * keys]
+    assert big == []
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (640 << 20 if rows == 1 else 1024 << 20), temp
+
+
+def test_sparse_latent_decode_step_compiles(topo, tpu_backend):
+    """The decode step of this pool is the one-token verify forward on the
+    chip too (no Pallas decode kernel): 16 rows over a 130-page window,
+    the window gathered, the top 2048 of 16640 selected, the masked
+    absorbed read — and ONE pool: the write after the scan is in place."""
+    cfg = sparse_cfg()
+    dev = SingleDeviceSharding(topo.devices[0])
+    cache = on(jax.eval_shape(
+        lambda: llama.init_paged_kv_cache(cfg, 2081, PAGE)), dev)
+    i32 = lambda *shape: sds(shape, jnp.int32, dev)  # noqa: E731
+    B = 16
+
+    def step(params, tok, pos, cache, tbl, wp, off):
+        return llama.apply_decode_paged(params, cfg, tok, pos, cache, tbl,
+                                        pos[:, 0] + 1, wp, off,
+                                        use_kernel=True, return_hidden=True)
+
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        on(param_shapes(cfg), dev), i32(B, 1), i32(B, 1), cache,
+        i32(B, 130), i32(B), i32(B)).compile()
+    assert_fits(compiled)
+    pool = sum(math.prod(x.shape) * 2 for x in cache.values())
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= pool            # donated, not copied
+    assert m.temp_size_in_bytes < 1536 << 20, m.temp_size_in_bytes
 
 
 _POOL = "bf16[2,9,4,16,64]{4,3,2,1,0:T(8,128)(2,1)}"

@@ -175,3 +175,48 @@ def test_a_share_lies_inside_the_layers_experts():
         dataclasses.replace(WHOLE, experts_held=8, experts_first=12)
     with pytest.raises(ValueError, match="needs experts"):
         LlamaConfig(experts_held=2)
+
+
+@pytest.mark.parametrize("T", [13, 48], ids=["decode_rows", "chunk_rows"])
+def test_the_shares_add_up_at_a_router_of_256_columns(T):
+    """The sizes of the widest router served as a share: 256 columns, 8 a
+    token, x 2.5, 32 shares of 8 — what one chip of 32 holds. Thirteen
+    rows reach few of a share's eight experts, and the sum over the
+    shares is still the uncut layer's."""
+    n, k, shares = 256, 8, 32
+    whole = dataclasses.replace(WHOLE, num_experts=n, num_experts_per_tok=k)
+    params = llama.init_params(whole, jax.random.key(2), dtype=jnp.float32)
+    held = n // shares
+    h = jax.random.normal(jax.random.key(T), (1, T, 64), jnp.float32)
+    pos = jnp.arange(T)[None]
+
+    def cut(i):
+        cfg = dataclasses.replace(whole, experts_held=held,
+                                  experts_first=i * held)
+        lp = jax.tree.map(lambda a: a[1], params["layers"])
+        lp.update({name: lp[name][i * held:(i + 1) * held]
+                   for name in ("w_gate", "w_up", "w_down")})
+        return cfg, lp
+
+    parts, assigned, touched = [], 0.0, []
+    for i in range(shares):
+        cfg, lp = cut(i)
+        aux = {}
+        parts.append(llama.decoder_layer(h, lp, cfg, pos, INV, None,
+                                         aux=aux)[0])
+        assigned += float(aux["local_assignments"])
+        touched.append(float(aux["experts_touched"]))
+    cfg, lp = cut(0)
+    lp.update({name: jnp.zeros_like(lp[name])
+               for name in ("w_gate", "w_up", "w_down")})
+    rest = llama.decoder_layer(h, lp, cfg, pos, INV, None)[0]
+    aux = {}
+    uncut = llama.decoder_layer(
+        h, jax.tree.map(lambda a: a[1], params["layers"]), whole, pos, INV,
+        None, aux=aux)[0]
+    total = rest + sum(p - rest for p in parts)
+    assert float(jnp.max(jnp.abs(total - uncut))) < 5e-5
+    assert assigned == T * k and sum(touched) == float(aux["experts_touched"])
+    # a share of 8 is thinly reached at decode: 8 (1 - (31/32)^13) = 2.7
+    if T == 13:
+        assert 1.0 < sum(touched) / shares < 4.5
