@@ -254,6 +254,10 @@ _STATS_TEMPLATE = {
     # the dispatch plan. 0 for a model without an indexer.
     "kv_rows_selected": 0,
     "kv_rows_indexed": 0,
+    # ... and the cached rows their attention STREAMED out of HBM, a
+    # layer: over the decode kernel each live row's context in whole
+    # blocks of the kernel's pages, gathered every slot's whole window.
+    "kv_rows_read": 0,
     # Dropless experts (moe_impl "dropless"): the sum over decode rounds
     # of the mean distinct experts a layer's rows reached in a step, and
     # the rounds that reported one (their ratio is the mean experts a
@@ -1367,15 +1371,19 @@ class Engine:
         # the Pallas kernel streams pages through VMEM and never
         # materializes it — reserving for it there starves the pool
         # (the 16-slot throughput collapse, VERDICT r3 weak #2).
-        # — but a cache object without a decode kernel (learned sparse
-        # attention) gathers it wherever it runs, and its chunk programs
-        # hold a full layer's index scores and their selection
+        # Learned sparse attention: a chunk program holds a full layer's
+        # index scores and their selection, and the decode step over the
+        # kernel still gathers the slots' windows of INDEX keys and
+        # scores them (off the kernel they are in ``token_bytes``).
         kvc = kv_cache_of(mcfg)
-        gathers = not self._use_kernel or kvc.kernel_attend is None
-        gather = cfg.max_slots * self._pmax * cfg.page_size \
-            * kvc.token_bytes(self._dtype.itemsize) if gathers else 0
+        slots, keys = cfg.max_slots, self._pmax * cfg.page_size
+        itemsize = self._dtype.itemsize
+        gather = 0 if self._use_kernel \
+            else slots * keys * kvc.token_bytes(itemsize)
         if mcfg.index_topk:
-            gather += kvc.select_bytes(S, self._pmax * cfg.page_size + S)
+            gather += kvc.select_bytes(S, keys + S)
+            if self._use_kernel:
+                gather += kvc.index_window_bytes(slots, keys, itemsize)
         # int8-KV insert quantizes the bucket per-row; XLA sequences the
         # K and V transforms, so ~one bucket's f32 copy is live at once
         quant = bucket_cache if self._kv_quant else 0
@@ -4863,11 +4871,20 @@ class Engine:
                 ctx = [r.proj_pos + 1 for r in members.values()]
                 indexed = sum(ctx)
                 selected = sum(min(c, K) for c in ctx)
+                # ... and what the read streamed to attend them: the
+                # kernel walks a row's cached pages in whole blocks, the
+                # gathered form takes every slot's whole window
+                read = kv_cache_of(self.model_cfg).kernel_rows_read(
+                    ctx, page) if self._use_kernel else B * window * page
                 rec.kv_rows_selected += selected * steps
                 rec.kv_rows_indexed += indexed * steps
+                rec.kv_rows_read += read * steps
                 rec.kv_selected_pct = 100.0 * selected / max(indexed, 1)
+                rec.kv_read_per_selected = (
+                    rec.kv_rows_read / max(rec.kv_rows_selected, 1))
                 self._bump("kv_rows_selected", selected * steps)
                 self._bump("kv_rows_indexed", indexed * steps)
+                self._bump("kv_rows_read", read * steps)
         for req in members.values():
             req.proj_pos = min(req.proj_pos + steps, req.extent)
         with self._pipe_lock:

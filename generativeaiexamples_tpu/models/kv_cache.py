@@ -56,11 +56,15 @@ v)`` as handed:
             ``attend_*`` takes the layer's ``index`` (projections, whether
             it is full, the mask carried) and reads MASKED: all of the
             row's cached rows, the unchosen keys out of the softmax.
-            Decode and verify gather the window and run absorbed (no
-            Pallas decode kernel: ``kernel_attend`` is None and the
-            decode step is the one-token verify forward on every
-            backend); a chunk runs ``LatentKV.attend_prefix`` with the
-            mask as one more operand of the chunk kernel.
+            The decode step runs the latent decode kernel with the mask
+            as one more operand (``kernel_attend``: each slot's live
+            pages streamed as ``LatentKV``'s are, the pool and the mask
+            in the layer scan's carry; only the full layers' windows of
+            index keys are gathered, to be scored); the verify forward,
+            and the decode step off the chip, gather the window and run
+            absorbed (``attend_window``); a chunk runs
+            ``LatentKV.attend_prefix`` with the mask as one more operand
+            of the chunk kernel.
 """
 
 from __future__ import annotations
@@ -850,10 +854,6 @@ class SparseLatentKV(LatentKV):
     layers (module docstring)."""
 
     leaves = ("c", "r", "i")
-    #: the decode step has no Pallas kernel: ``apply_decode_paged`` runs
-    #: the one-token verify forward, whose gathered window the engine's
-    #: headroom reserves (``token_bytes``)
-    kernel_attend = None
 
     def __init__(self, cfg: LlamaConfig):
         super().__init__(cfg)
@@ -905,9 +905,20 @@ class SparseLatentKV(LatentKV):
         running count and the masks (``select``), 16 B a pair."""
         return 16 * queries * keys
 
+    def index_window_bytes(self, slots: int, keys: int, itemsize: int
+                           ) -> int:
+        """What the decode step over the kernel still gathers on a full
+        layer: every slot's window of index keys (``index_window``) and
+        the scores of all index heads over it at once
+        (ops/sparse_index.py ``index_scores``)."""
+        return slots * keys * (self.di * itemsize
+                               + 4 * self.cfg.index_n_heads)
+
     def kernel_supported(self, page: int) -> bool:
-        """The kernel this cache has is the chunk kernel."""
-        return self.prefix_kernel_supported(page)
+        """Both kernels this cache has: the latent decode kernel and the
+        chunk kernel."""
+        return super().kernel_supported(page) \
+            and self.prefix_kernel_supported(page)
 
     # ---------------------------------------------------------------- write
 
@@ -947,6 +958,22 @@ class SparseLatentKV(LatentKV):
         pages = block_table + at * pi.shape[1]
         return pi.reshape((-1,) + pi.shape[3:])[pages].reshape(
             B, P * pi.shape[3], self.di)
+
+    def window_keys(self, kv_cache: KVCache, index: dict, block_table,
+                    rows, positions, dtype) -> jax.Array:
+        """``index_window`` of the layer, the current tokens' own keys set
+        at their positions (they are not in the pool yet)."""
+        gi = self.index_window(kv_cache, index["layer"], block_table)
+        return gi.astype(dtype).at[rows, positions].set(
+            index["k"].astype(dtype))
+
+    def kernel_rows_read(self, contexts, page: int) -> int:
+        """Cached rows ONE decode step over the kernel streams a layer for
+        live rows of ``contexts`` tokens (the current one not cached
+        yet): the kernel walks a row's pages in whole blocks."""
+        from ..ops.latent_attention import _BLOCK_PAGES
+        block = _BLOCK_PAGES * page
+        return sum(-(-(c - 1) // block) for c in contexts) * block
 
     def select(self, index: dict, keys, valid: jax.Array) -> jax.Array:
         """The layer's keep mask (B, S, T): on a full layer the
@@ -1005,16 +1032,68 @@ class SparseLatentKV(LatentKV):
         gr = gr.astype(q.dtype).at[rows, positions].set(
             k_r.astype(q.dtype))
         valid = _causal(positions, kv_valid_len, gc.shape[1])[:, 0]
-
-        def keys():
-            gi = self.index_window(kv_cache, index["layer"], block_table)
-            return gi.astype(q.dtype).at[rows, positions].set(
-                index["k"].astype(q.dtype))
-
-        keep = self.select(index, keys, valid)
+        keep = self.select(index, lambda: self.window_keys(
+            kv_cache, index, block_table, rows, positions, q.dtype), valid)
         o_c = absorbed_masked(self.absorb(qn, lp), qr, gc, gr, keep,
                               self.cfg.score_scale)
         return head_product(o_c, lp["wv_b"], self.cfg.num_heads), keep
+
+    def kernel_attend(self, kv_cache: KVCache, block_table, pos_in_win,
+                      write_page, write_offset, mesh, act_dtype):
+        """``LatentKV.kernel_attend`` over the kept keys: the layer's keep
+        mask is one more operand of the latent decode kernel, which
+        streams each slot's live pages as before — no gathered window of
+        latent rows. A full layer scores the slot windows of index keys
+        read from the pool in the carry (the step's own key set at its
+        position, as ``attend_window`` has it) and selects; a shared
+        layer hands on the mask carried. The step's index key goes to
+        the ``"i"`` leaf of the carried pool where the kernel puts the
+        latent row — B rows scattered in place, to the trash page on a
+        shared layer (no ``cond`` around the leaf: a branch that passes
+        it through costs a copy of it). ``attend`` returns ``(attn,
+        (pool, keep))`` (models/llama.py ``_run_stack``). A window no
+        longer than ``index_topk`` keeps every causal key: the kernel
+        without a mask, the mask carried untouched."""
+        from ..ops.latent_attention import (
+            head_product, latent_attention_decode_jit as
+            latent_attention_decode)
+        if mesh is not None and mesh.shape.get("tp", 1) > 1:
+            raise NotImplementedError(
+                "the latent decode kernel under a tp mesh is not supported")
+        cfg = self.cfg
+        dt = kv_cache["c"].dtype
+        interp = jax.default_backend() != "tpu"
+        B, W = block_table.shape
+        Lf, N, _, page, di = kv_cache["i"].shape
+        rows, pos = jnp.arange(B)[:, None], pos_in_win[:, None]
+        # causal: the cached rows and the current token's own place
+        valid = (jnp.arange(W * page, dtype=jnp.int32)[None, None]
+                 <= pos_in_win[:, None, None])              # (B, 1, T)
+        scored = W * page > self.K
+
+        def attend(q, c, k_r, lp, li, pool, index):
+            qn, qr = self._split(q[:, 0])                   # (B, H, .)
+            keep, mask = index["keep"], {}
+            if scored:
+                keep = self.select(index, lambda: self.window_keys(
+                    pool, index, block_table, rows, pos, q.dtype), valid)
+                mask = dict(keep=keep[:, 0],
+                            cur_keep=keep[rows, 0, pos][:, 0])
+            o_c, pc, pr = latent_attention_decode(
+                self.absorb(qn[:, None], lp)[:, 0], qr, pool["c"],
+                pool["r"], block_table, pos_in_win, c[:, 0].astype(dt),
+                k_r[:, 0].astype(dt), write_page, write_offset, li,
+                scale=cfg.score_scale, interpret=interp, **mask)
+            with jax.named_scope("attn_index"):     # the index key write
+                at = (index["layer"] * N
+                      + jnp.where(index["full"], write_page, 0)) * page \
+                    + write_offset
+                pi = pool["i"].reshape(Lf * N * page, di).at[at].set(
+                    index["k"][:, 0].astype(dt)).reshape(pool["i"].shape)
+            attn = head_product(o_c[:, None], lp["wv_b"], cfg.num_heads)
+            return attn, ({"c": pc, "r": pr, "i": pi}, keep)
+
+        return attend
 
     def prefix_keys(self, n_pages: int, page: int,
                     block_pages: int = 4) -> int:

@@ -675,14 +675,15 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
 
     ``use_kernel``: the engine decides (None = auto, for single-device
     callers). True is the Pallas kernel with the pool in the layer
-    scan's carry (``_run_stack``); False — the CPU, a mesh the kernel
-    refuses, a cache object without a decode kernel (learned sparse
-    attention) — is the one-token case of ``apply_verify_paged``.
+    scan's carry (``_run_stack``) — under learned sparse attention with
+    the layer's keep mask as one more operand of the kernel and the mask
+    in the carry beside the pool; False — the CPU, a mesh the kernel
+    refuses — is the one-token case of ``apply_verify_paged``.
     """
     kvc = kv_cache_of(cfg)
     if use_kernel is None:
         use_kernel = use_paged_kernel(cfg, kvc.page_size(kv_cache))
-    if not use_kernel or kvc.kernel_attend is None:
+    if not use_kernel:
         return apply_verify_paged(
             params, cfg, tokens, positions, kv_cache, block_table,
             kv_valid_len, write_page[:, None], write_offset[:, None],
@@ -695,9 +696,21 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
 
     h, cache, touched = _run_model(
         params, cfg, h, positions, inv_freq, kv_valid_len, attend,
-        state=kv_cache, row_mask=active, stats=stats)
+        state=kv_cache, row_mask=active, stats=stats,
+        selection=_no_selection(cfg, tokens, kv_cache, block_table))
     return _step_result(params, cfg, h, cache, touched, return_hidden,
                         stats)
+
+
+def _no_selection(cfg: LlamaConfig, tokens: jax.Array, kv_cache: KVCache,
+                  block_table: jax.Array) -> Optional[jax.Array]:
+    """The keep mask a forward over a slot window hands its first layer
+    (learned sparse attention; None otherwise): (B, S, window keys)
+    zeros, which the first layer, a full one, replaces."""
+    if not cfg.index_topk:
+        return None
+    page = kv_cache_of(cfg).page_size(kv_cache)
+    return jnp.zeros(tokens.shape + (block_table.shape[1] * page,), bool)
 
 
 def apply_verify_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
@@ -745,15 +758,10 @@ def apply_verify_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
             q, k, v, lp, kv_cache, li, block_table, rows, positions,
             kv_valid_len, *index), (k, v), index)
 
-    selection = None
-    if cfg.index_topk:
-        selection = jnp.zeros(
-            tokens.shape + (block_table.shape[1]
-                            * kvc.page_size(kv_cache),), bool)
     h, new, touched = _run_model(
         params, cfg, _embed(params, tokens, cfg.embed_scale), positions,
         inv_freq, kv_valid_len, attend, row_mask=active, stats=stats,
-        selection=selection)
+        selection=_no_selection(cfg, tokens, kv_cache, block_table))
     cache = kvc.write(kv_cache, *new, write_pages, write_offsets)
     return _step_result(params, cfg, h, cache, touched, return_hidden,
                         stats)

@@ -166,6 +166,7 @@ class RoundRecord:
         "pages_touched", "hbm_bytes",
         "kv_restore_pages", "blocked_on_pages", "kv_pages_skipped",
         "kv_rows_selected", "kv_rows_indexed", "kv_selected_pct",
+        "kv_rows_read", "kv_read_per_selected",
         "dispatch_ms", "modeled_ms", "t_dispatch_done",
         # execution (harvest thread)
         "harvest_wait_ms", "first_readback_ms", "emit_ms",
@@ -240,6 +241,13 @@ class RoundRecord:
         self.kv_rows_selected = 0
         self.kv_rows_indexed = 0
         self.kv_selected_pct = 0.0
+        # ... and the cached rows their attention STREAMED out of HBM, a
+        # layer: over the decode kernel each live row's context rounded
+        # up to whole blocks of the kernel's pages, gathered every
+        # slot's whole window; and that over the rows selected: which
+        # form ran, and how far a masked read is from a gathered one.
+        self.kv_rows_read = 0
+        self.kv_read_per_selected = 0.0
         self.dispatch_ms = 0.0
         self.modeled_ms = 0.0
         self.t_dispatch_done = self.t_start
@@ -335,6 +343,8 @@ class RoundRecord:
                 "kv_rows_selected": self.kv_rows_selected,
                 "kv_rows_indexed": self.kv_rows_indexed,
                 "kv_selected_pct": round(self.kv_selected_pct, 2),
+                "kv_rows_read": self.kv_rows_read,
+                "kv_read_per_selected": round(self.kv_read_per_selected, 2),
                 "experts_touched": round(self.experts_touched, 2),
                 "tail_resort_pct": round(self.tail_resort_pct, 2),
                 "local_assignments": round(self.local_assignments, 2),

@@ -86,6 +86,16 @@ def head_product(x: jax.Array, w, num_heads: int,
     return y.astype(x.dtype)
 
 
+def _column(row: jax.Array) -> jax.Array:
+    """Inside the decode kernel: a (1, T) bool row, positions on the
+    lanes, as a (T, 1) column, positions on the sublanes — what zeroes
+    the ROWS of a streamed block. Through a float32 transpose of whole
+    tiles: the chip's compiler refuses a reshape across the lane axis
+    and the transpose of a boolean."""
+    T = row.shape[1]
+    return jnp.broadcast_to(row.astype(jnp.float32), (8, T)).T[:, :1] != 0
+
+
 def _causal(positions, kv_valid_len, T: int):
     """(B, 1, S, T) bool: the key at cache index t is visible to the
     query at absolute position p iff t <= p and t < kv_valid_len."""
@@ -145,7 +155,9 @@ def latent_attention_decode(q_c: jax.Array, q_r: jax.Array,
                             write_page: jax.Array, write_offset: jax.Array,
                             layer: jax.Array, *, scale: float,
                             interpret: bool = False,
-                            block_pages: int = _BLOCK_PAGES):
+                            block_pages: int = _BLOCK_PAGES,
+                            keep: jax.Array | None = None,
+                            cur_keep: jax.Array | None = None):
     """Absorbed decode attention + row append over the latent pool, one
     query token a slot.
 
@@ -161,7 +173,24 @@ def latent_attention_decode(q_c: jax.Array, q_r: jax.Array,
     cur_c/cur_r:  (B, R) / (B, rope)   the current token's row, pool dtype
     write_page/write_offset: (B,)      where it goes (page 0 = trash)
     layer:        (1,) int32
-    Returns (o_c (B, H, R) in q_c.dtype, pool_c, pool_r).
+    keep:         (B, W * page) bool   optional (learned sparse attention:
+                                       ops/sparse_index.py): a slot's
+                                       query attends the cached row at
+                                       logical position t only where set
+                                       (AND t < its length) — one more
+                                       operand, every live page still
+                                       streamed. A row the mask drops is
+                                       zeroed before the sum, not only
+                                       its probability, as
+                                       ``absorbed_masked`` has it
+    cur_keep:     (B,) bool            with ``keep``: whether the current
+                                       token is among the kept (it
+                                       competes for its place like any
+                                       causal position); folded in after
+                                       the loop only then
+    Returns (o_c (B, H, R) in q_c.dtype, pool_c, pool_r). Without a mask
+    nothing of it is traced: the program is what it was
+    (tests/test_latent_program_pins.py).
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -172,11 +201,16 @@ def latent_attention_decode(q_c: jax.Array, q_r: jax.Array,
     Gs = group_size(B)
     PB = block_pages
     T = PB * page
+    masked = keep is not None
 
-    def kernel(tbl_ref, len_ref, wp_ref, off_ref, l_ref,
-               qc_ref, qr_ref, c_hbm, r_hbm, cc_ref, cr_ref, crt_ref,
-               out_ref, opc_ref, opr_ref,
-               cbuf, rbuf, accs, ms, ls, stc, strp, crw, rrw, sem, rw_sem):
+    def kernel(tbl_ref, len_ref, wp_ref, off_ref, l_ref, *refs):
+        if masked:      # the current token's bit, a scalar a slot
+            ck_ref, *refs = refs
+        qc_ref, qr_ref, c_hbm, r_hbm, cc_ref, cr_ref, crt_ref, *refs = refs
+        if masked:      # (Gs, blocks, T) int32: a block's bits, a row
+            kp_ref, *refs = refs
+        (out_ref, opc_ref, opr_ref, cbuf, rbuf, accs, ms, ls, stc, strp,
+         crw, rrw, sem, rw_sem) = refs
         gi = pl.program_id(0)
         li = l_ref[0]
         b0 = gi * Gs
@@ -254,6 +288,9 @@ def latent_attention_decode(q_c: jax.Array, q_r: jax.Array,
             t0 = wb * T
             valid = (t0 + jax.lax.broadcasted_iota(
                 jnp.int32, (1, T), 1)) < length
+            if masked:
+                kept = kp_ref[sidx, pl.ds(wb, 1), :] != 0        # (1, T)
+                valid = valid & kept
             s = jnp.where(valid, s * scale, NEG)
             m = ms[sidx]
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -265,6 +302,8 @@ def latent_attention_decode(q_c: jax.Array, q_r: jax.Array,
             # zeroed (stale rows of the last page, repeated pages)
             live = (t0 + jax.lax.broadcasted_iota(
                 jnp.int32, (T, 1), 0)) < length
+            if masked:
+                live = live & _column(kept)
             pv = jnp.dot(p.astype(cp.dtype), jnp.where(live, cp, 0),
                          preferred_element_type=jnp.float32)     # (H, R)
             accs[sidx] = accs[sidx] * alpha + pv
@@ -302,10 +341,21 @@ def latent_attention_decode(q_c: jax.Array, q_r: jax.Array,
                                axis=-1, keepdims=True)) * scale  # (H, 1)
             m = ms[i]
             m2 = jnp.maximum(m, s_cur)
+            if masked:
+                # the current token competes for its place like any other
+                # causal position: folded in only if the mask keeps it (a
+                # slot that keeps nothing at all, an idle one, gets zeros)
+                own = ck_ref[b] != 0
+                m2 = jnp.where(own, m2, m)
             a = jnp.exp(m - m2)
             bta = jnp.exp(s_cur - m2)
+            if masked:
+                bta = jnp.where(own, bta, 0.0)
             out = accs[i] * a + ccf * bta
-            out_ref[i] = (out / (ls[i] * a + bta)).astype(out_ref.dtype)
+            norm = ls[i] * a + bta
+            if masked:
+                norm = jnp.maximum(norm, 1e-30)
+            out_ref[i] = (out / norm).astype(out_ref.dtype)
 
             off = off_ref[b]
             tile0 = (off // _TILE) * _TILE
@@ -333,18 +383,35 @@ def latent_attention_decode(q_c: jax.Array, q_r: jax.Array,
     def rows(g, *_):
         return (g, 0, 0)
 
+    scalars = (block_table, lengths, write_page, write_offset, layer)
+    operands = (q_c, q_r, pool_c, pool_r, cur_c[:, None], cur_r[:, None],
+                cur_r[:, :, None])
+    in_specs = [
+        pl.BlockSpec((Gs, H, R), rows),
+        pl.BlockSpec((Gs, H, rope), rows),
+        pl.BlockSpec(memory_space=pl.ANY),      # latent pool stays in HBM
+        pl.BlockSpec(memory_space=pl.ANY),      # rotary pool likewise
+        pl.BlockSpec((Gs, 1, R), rows),
+        pl.BlockSpec((Gs, 1, rope), rows),
+        pl.BlockSpec((Gs, rope, 1), rows),
+    ]
+    if masked:
+        # the mask cut to the kernel's blocks, a row of T bits a block
+        # (whole sublane tiles of blocks): int32, a block's row read at a
+        # dynamic sublane
+        W = block_table.shape[1]
+        nb = -(-W // PB)
+        nb += -nb % 8
+        bits = jnp.pad(keep.astype(jnp.int32),
+                       ((0, 0), (0, nb * T - W * page))).reshape(B, nb, T)
+        scalars += (cur_keep.astype(jnp.int32),)
+        operands += (bits,)
+        in_specs.append(pl.BlockSpec((Gs, nb, T), rows))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,       # table, lengths, write page/offset, layer
+        # table, lengths, write page/offset, layer (, the current bit)
+        num_scalar_prefetch=len(scalars),
         grid=(B // Gs,),
-        in_specs=[
-            pl.BlockSpec((Gs, H, R), rows),
-            pl.BlockSpec((Gs, H, rope), rows),
-            pl.BlockSpec(memory_space=pl.ANY),      # latent pool stays in HBM
-            pl.BlockSpec(memory_space=pl.ANY),      # rotary pool likewise
-            pl.BlockSpec((Gs, 1, R), rows),
-            pl.BlockSpec((Gs, 1, rope), rows),
-            pl.BlockSpec((Gs, rope, 1), rows),
-        ],
+        in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((Gs, H, R), rows),
             pl.BlockSpec(memory_space=pl.ANY),
@@ -373,14 +440,23 @@ def latent_attention_decode(q_c: jax.Array, q_r: jax.Array,
             jax.ShapeDtypeStruct(pool_r.shape, pool_r.dtype),
         ],
         # operands: tbl=0, lens=1, wp=2, off=3, layer=4, q_c=5, q_r=6,
-        # pool_c=7, pool_r=8, ...
-        input_output_aliases={7: 1, 8: 2},
+        # pool_c=7, pool_r=8, ... (one later under a mask)
+        input_output_aliases={len(scalars) + 2: 1, len(scalars) + 3: 2},
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="latent_attn_decode",
-    )(block_table, lengths, write_page, write_offset, layer, q_c, q_r,
-      pool_c, pool_r, cur_c[:, None], cur_r[:, None], cur_r[:, :, None])
+    )(*scalars, *operands)
+
+
+#: The kernel call under ``jax.jit``: ONE trace of the kernel body serves
+#: every decode program of a process and both stacks of a model (traced
+#: anew it is ~0.8 s of set-up a decode program; ops/chunk_attention.py
+#: does the same). ``LatentKV``'s unmasked call stays bare: its lowered
+#: programs are pinned to the byte (tests/test_latent_program_pins.py).
+latent_attention_decode_jit = jax.jit(
+    latent_attention_decode,
+    static_argnames=("scale", "interpret", "block_pages"))
 
 
 def latent_attention_decode_reference(q_c, q_r, pool_c, pool_r, block_table,

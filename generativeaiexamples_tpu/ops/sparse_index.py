@@ -12,9 +12,11 @@ above (models/llama.py ``_run_stack``) and meets the attention: the
 reads here are MASKED — every cached row of the context is read and the
 unchosen keys are masked out of the softmax, the same numbers as a
 gather of the chosen rows at the dense read's bytes and operations
-(``absorbed_masked`` for decode and verify, ``expanded_masked`` for the
-tokens given; a chunk's prefix goes through ops/chunk_attention.py with
-the mask as one more operand).
+(``absorbed_masked`` for the verify forward and the decode step off the
+chip, ``expanded_masked`` for the tokens given; the decode step on the
+chip and a chunk's prefix go through the Pallas kernels of
+ops/latent_attention.py and ops/chunk_attention.py with the mask as one
+more operand).
 
 The selection is EXACT and by token: ``topk_keep`` finds the k-th
 largest score of a row by bisection over the scores' bit patterns — 32

@@ -132,28 +132,36 @@ def test_paged_attention_kernel_compiles(topo, batch, heads, kv_heads,
             else "paged_attn_decode") in text
 
 
-@pytest.mark.parametrize("batch", [32, 8], ids=["b32", "b8"])
-def test_latent_attention_kernel_compiles(topo, batch):
-    """The latent decode kernel at the published widths of the one
-    configuration that has a latent cache: 64 heads over a 512 + 64 row,
-    pages of 128, the rotary pool transposed."""
+@pytest.mark.parametrize("batch,W,masked", [
+    (32, 8, False), (8, 8, False), (16, 130, True)],
+    ids=["b32", "b8", "b16-keep-mask"])
+def test_latent_attention_kernel_compiles(topo, batch, W, masked):
+    """The latent decode kernel at the published widths of the
+    configurations that have a latent cache: 64 heads over a 512 + 64
+    row, pages of 128, the rotary pool transposed; and with the keep mask
+    of learned sparse attention as an operand over the 130-page window of
+    that cell (a block's bits read at a dynamic sublane, turned to a
+    column through a float32 transpose)."""
     from generativeaiexamples_tpu.ops.latent_attention import (
         kernel_supported, latent_attention_decode)
     dev = SingleDeviceSharding(topo.devices[0])
     H, R, rope = 64, 512, 64
     assert kernel_supported(PAGE, R, rope)
-    L, N, W = 2, batch * 4 + 1, 8
+    L, N = 2, batch * 4 + 1
     bf = lambda *shape: sds(shape, jnp.bfloat16, dev)   # noqa: E731
     i32 = lambda *shape: sds(shape, jnp.int32, dev)     # noqa: E731
+    mask = (sds((batch, W * PAGE), jnp.bool_, dev),
+            sds((batch,), jnp.bool_, dev)) if masked else ()
 
-    def step(qc, qr, pc, pr, tbl, lens, cc, cr, wp, off, li):
+    def step(qc, qr, pc, pr, tbl, lens, cc, cr, wp, off, li, *mask):
+        more = dict(zip(("keep", "cur_keep"), mask))
         return latent_attention_decode(qc, qr, pc, pr, tbl, lens, cc, cr,
-                                       wp, off, li, scale=0.13)
+                                       wp, off, li, scale=0.13, **more)
 
     compiled = jax.jit(step, donate_argnums=(2, 3)).lower(
         bf(batch, H, R), bf(batch, H, rope), bf(L, N, 1, PAGE, R),
         bf(L, N, 1, rope, PAGE), i32(batch, W), i32(batch), bf(batch, R),
-        bf(batch, rope), i32(batch), i32(batch), i32(1)).compile()
+        bf(batch, rope), i32(batch), i32(batch), i32(1), *mask).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "latent_attn_decode" in text
 
@@ -680,10 +688,14 @@ def test_sparse_latent_chunk_program_compiles(topo, tpu_backend, rows):
 
 
 def test_sparse_latent_decode_step_compiles(topo, tpu_backend):
-    """The decode step of this pool is the one-token verify forward on the
-    chip too (no Pallas decode kernel): 16 rows over a 130-page window,
-    the window gathered, the top 2048 of 16640 selected, the masked
-    absorbed read — and ONE pool: the write after the scan is in place."""
+    """The decode step of this pool at the cell's sizes, 16 rows over a
+    130-page window: the full layers' index window gathered and the top
+    2048 of 16640 selected, the read the latent decode kernel with the
+    keep mask as an operand, the pool in the layer scan's carry — ONE
+    pool, no copy of a leaf (the index key's scatter is in place) and no
+    gathered window of latent rows: the program's temporaries are a
+    tenth of what the gathered read held."""
+    from tools.dump_hlo import parse_hlo, pool_report
     cfg = sparse_cfg()
     dev = SingleDeviceSharding(topo.devices[0])
     cache = on(jax.eval_shape(
@@ -703,7 +715,18 @@ def test_sparse_latent_decode_step_compiles(topo, tpu_backend):
     pool = sum(math.prod(x.shape) * 2 for x in cache.values())
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= pool            # donated, not copied
-    assert m.temp_size_in_bytes < 1536 << 20, m.temp_size_in_bytes
+    assert m.temp_size_in_bytes < 192 << 20, m.temp_size_in_bytes
+    text = compiled.as_text()
+    # the kernel under the scope the benchmark's stage reader sums
+    assert re.search(r'op_name="[^"]*/attn/[^"]*latent_attn_decode', text)
+    assert pool_report(text, [(v.dtype.name, v.shape)
+                              for v in cache.values()]) == []
+    # no array of slots x window rows x a latent row (16 x 16640 x 512)
+    window = sorted((B, 130 * PAGE, cfg.kv_lora_rank))
+    wide = [i["name"] for ins in parse_hlo(text).values() for i in ins
+            if (m := re.match(r"\w+\[([\d,]+)\]", i["shape"])) and sorted(
+                int(d) for d in m.group(1).split(",") if d != "1") == window]
+    assert wide == []
 
 
 _POOL = "bf16[2,9,4,16,64]{4,3,2,1,0:T(8,128)(2,1)}"

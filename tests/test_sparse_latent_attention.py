@@ -8,10 +8,14 @@ the full layer below, across the stack boundary; selection off =
 latent rows AND index keys back, a chunk program of four prompts, decode
 and verify — against the reference's one full pass, with a NaN-filled
 trash page; the chunk kernel with its mask operand against the jnp form;
-the cache object's three leaves; the published names through import_hf;
-and what is refused."""
+the latent decode kernel with its mask operand (interpreted) against
+``absorbed_masked`` over the same pool and mask, the decode step over it
+against the gathered form, the verify forward and the full pass, and the
+three rows it appends; the cache object's three leaves; the published
+names through import_hf; and what is refused."""
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -293,11 +297,18 @@ def test_chunked_prefill_reads_rows_and_index_keys_back(built, prefilled):
     assert float(jnp.abs(pool["i"][:, 1:4]).min()) > 0
 
 
-def test_decode_through_the_pool_matches_the_one_full_pass(built, prefilled):
+FORMS = pytest.mark.parametrize("form", ["gathered", "kernel"])
+
+
+@pytest.mark.parametrize("form", ["gathered", "kernel", "alternating"])
+def test_decode_through_the_pool_matches_the_one_full_pass(built, prefilled,
+                                                           form):
     """Logits, not tokens: expanded masked chunks, then absorbed masked
     decode over the rows and index keys they left, against the
-    reference's pass without a cache. ``use_kernel`` changes nothing:
-    this cache has no decode kernel."""
+    reference's pass without a cache — the window gathered (the one-token
+    verify forward), the latent decode kernel with the keep mask as an
+    operand (interpreted), and the two taking turns over ONE pool: each
+    reads the three rows the other appended."""
     p, ids, pos, want = built
     pool, table, _ = prefilled
     with jax.default_matmul_precision("highest"):
@@ -305,14 +316,19 @@ def test_decode_through_the_pool_matches_the_one_full_pass(built, prefilled):
             logits, pool = decode_j(
                 p, CFG, ids[:, t:t + 1], pos[:, t:t + 1], pool, table,
                 jnp.array([t + 1]), table[0, t // PAGE][None],
-                jnp.array([t % PAGE]), use_kernel=t % 2 == 0)
+                jnp.array([t % PAGE]),
+                use_kernel={"gathered": False, "kernel": True,
+                            "alternating": t % 2 == 0}[form])
             assert bool(jnp.all(jnp.isfinite(logits)))
             assert err(logits[0, 0], want[t]) < 5e-5, t
 
 
-def test_the_decode_step_is_the_verify_forwards_first_row(built, prefilled):
+@FORMS
+def test_the_decode_step_is_the_verify_forwards_first_row(built, prefilled,
+                                                          form):
     """One query a row and three: the same masked read, so a decode step's
-    logits are those of the verify forward's first position."""
+    logits are those of the verify forward's first position — gathered
+    as the verify forward itself, or through the kernel."""
     p, ids, pos, _ = built
     pool, table, _ = prefilled
     t = N_PRE
@@ -320,12 +336,66 @@ def test_the_decode_step_is_the_verify_forwards_first_row(built, prefilled):
         one, _ = decode_j(
             p, CFG, ids[:, t:t + 1], pos[:, t:t + 1], pool, table,
             jnp.array([t + 1]), table[0, t // PAGE][None],
-            jnp.array([t % PAGE]))
+            jnp.array([t % PAGE]), use_kernel=form == "kernel")
         three, _ = verify_j(
             p, CFG, ids[:, t:t + 3], pos[:, t:t + 3], pool, table,
             jnp.array([t + 3]), jnp.array([[4, 4, 4]]),
             jnp.array([[0, 1, 2]]))
     assert err(one[0, 0], three[0, 0]) < 2e-5
+
+
+def test_the_kernel_step_appends_the_rows_where_write_puts_them(built,
+                                                                prefilled):
+    """Two slots, the second idle: the kernel step leaves in the pool
+    what the gathered step's ONE ``write`` leaves — a latent row and its
+    rotary lane on every layer, an index key on the full layers, at the
+    slot's page and offset and nowhere else but the trash page."""
+    p, ids, pos, _ = built
+    pool, table, _ = prefilled
+    t = N_PRE
+    table = jnp.concatenate([table, jnp.zeros_like(table)])
+
+    def step(pool, t, use_kernel):
+        tok = jnp.concatenate([ids[:, t:t + 1], jnp.zeros((1, 1), ids.dtype)])
+        return decode_j(p, CFG, tok, jnp.array([[t], [0]]), pool, table,
+                        jnp.array([t + 1, 1]), jnp.array([4, 0]),
+                        jnp.array([t % PAGE, 0]), use_kernel=use_kernel)[1]
+
+    with jax.default_matmul_precision("highest"):
+        pool = step(pool, t, False)     # the page begun: row 0 of page 4
+        want, got = step(pool, t + 1, False), step(pool, t + 1, True)
+    assert set(got) == {"c", "r", "i"}
+    for name in got:
+        g, w, old = got[name][:, 1:], want[name][:, 1:], pool[name][:, 1:]
+        assert err(g, w) < 2e-5, name
+        moved = np.argwhere(np.asarray(g != old))
+        # every layer of the leaf; page 4 (3 without the trash page);
+        # its second row / lane
+        assert set(moved[:, 0]) == set(range(g.shape[0])), name
+        assert set(moved[:, 1]) == {3}, name
+        assert set(moved[:, 4 if name == "r" else 3]) == {1}, name
+
+
+def test_a_window_no_longer_than_index_topk_runs_without_a_mask(built):
+    """One page of context under ``index_topk`` 160: every causal key is
+    kept, so the kernel step scores nothing and takes no mask — the same
+    logits as the gathered step, and no index score in the program."""
+    p, ids, pos, want = built
+    pool = nan_trash(llama.init_paged_kv_cache(CFG, 3, PAGE, jnp.float32))
+    table = jnp.array([[1]])
+    n = 100
+    with jax.default_matmul_precision("highest"):
+        _, pool = prefill_j(p, CFG, jnp.pad(ids[:, :n], ((0, 0), (0, 28))),
+                            pos[:, :PAGE], pool, table, jnp.array([n]),
+                            jnp.int32(0))
+        args = (ids[:, n:n + 1], pos[:, n:n + 1], pool, table,
+                jnp.array([n + 1]), jnp.array([1]), jnp.array([n]))
+        a, _ = decode_j(p, CFG, *args, use_kernel=False)
+        b, _ = decode_j(p, CFG, *args, use_kernel=True)
+    assert err(a, b) < 2e-5 and err(b[0, 0], want[n]) < 5e-5
+    text = decode_j.lower(p, CFG, *args, use_kernel=True).as_text(
+        debug_info=True)
+    assert "attn_select" not in text and "latent_attn_decode" in text
 
 
 def test_verify_forward_over_the_sparse_pool(built, prefilled):
@@ -407,6 +477,78 @@ def test_int8_tree_in_bf16_follows_the_reference(built):
 # ------------------------------------------------------------ the kernel
 
 
+def _decode_kernel_case(case):
+    """A pool of float32 rows, four slots over a five-page window, and a
+    keep mask a slot (its last bit the current token's)."""
+    B, H, R, rope, W, N = 4, 4, 128, 32, 5, 12
+    ks = jax.random.split(jax.random.key(11), 8)
+    pc = jax.random.normal(ks[0], (2, N, 1, PAGE, R))
+    pr = jax.random.normal(ks[1], (2, N, 1, rope, PAGE))
+    pc, pr = pc.at[:, 0].set(jnp.nan), pr.at[:, 0].set(jnp.nan)
+    qc = jax.random.normal(ks[2], (B, H, R))
+    qr = jax.random.normal(ks[3], (B, H, rope))
+    cc = jax.random.normal(ks[4], (B, R))
+    cr = jax.random.normal(ks[5], (B, rope))
+    tbl = jnp.array([[1, 2, 3, 4, 5], [6, 7, 0, 0, 0], [0, 0, 0, 0, 0],
+                     [8, 9, 10, 11, 0]])
+    # a full last page, a short row, an IDLE slot, a row that ends a block
+    lens = jnp.array([600, 130, 0, 512 if case == "lengths" else 511])
+    t = jnp.arange(W * PAGE)[None]
+    causal = t <= lens[:, None]
+    keep = {
+        "random": jax.random.uniform(ks[6], causal.shape) < 0.3,
+        "drops_current": t < lens[:, None] - 3,
+        "one_key": t == (lens[:, None] // 2),
+        "lengths": jax.random.uniform(ks[7], causal.shape) < 0.5,
+        "all_kept": causal,
+    }[case] & causal
+    if case == "random":        # the current token kept on some rows
+        keep = keep.at[jnp.arange(B), lens].set(
+            jnp.array([True, False, True, True]))
+    return qc, qr, pc, pr, tbl, lens, cc, cr, keep
+
+
+@pytest.mark.parametrize("case", ["random", "drops_current", "one_key",
+                                  "lengths", "all_kept"])
+def test_decode_kernel_with_a_keep_mask_is_absorbed_masked(case):
+    """The latent decode kernel with the mask as an operand (interpreted)
+    against ``absorbed_masked`` over the gathered window, the current row
+    set at its position: a random mask, one that drops the current token,
+    one kept key, rows of different lengths beside an idle slot, and the
+    causal mask itself, which is the kernel WITHOUT a mask. NaN in the
+    trash page and past nothing: rows no query keeps are zeroed."""
+    from generativeaiexamples_tpu.ops.latent_attention import (
+        latent_attention_decode)
+    qc, qr, pc, pr, tbl, lens, cc, cr, keep = _decode_kernel_case(case)
+    B, W = tbl.shape
+    rows = jnp.arange(B)
+    active = np.asarray(lens) > 0
+    wp = jnp.where(lens > 0, tbl[rows, lens // PAGE], 0)
+    args = (qc, qr, pc, pr, tbl, lens, cc, cr, wp, lens % PAGE,
+            jnp.array([1]))
+    with jax.default_matmul_precision("highest"):
+        got, npc, npr = latent_attention_decode(
+            *args, scale=0.2, interpret=True, keep=keep,
+            cur_keep=keep[rows, lens])
+        gc = pc[1][tbl][:, :, 0].reshape(B, W * PAGE, -1)
+        gr = pr[1][tbl][:, :, 0].swapaxes(2, 3).reshape(B, W * PAGE, -1)
+        want = si.absorbed_masked(
+            qc[:, None], qr[:, None], gc.at[rows, lens].set(cc),
+            gr.at[rows, lens].set(cr), keep[:, None], 0.2)[:, 0]
+        assert bool(jnp.all(jnp.isfinite(got)))
+        # the idle slot too: its own row if the mask keeps it, else zeros
+        assert err(got, want) < 2e-5
+        assert case != "drops_current" or not bool(jnp.any(got[~active]))
+        # the rows appended, mask or none
+        assert np.array_equal(npc[1, wp, 0, lens % PAGE][active], cc[active])
+        assert np.array_equal(npr[1, wp, 0, :, lens % PAGE][active],
+                              cr[active])
+        if case == "all_kept":
+            plain, _, _ = latent_attention_decode(*args, scale=0.2,
+                                                  interpret=True)
+            assert err(got[active], plain[active]) < 2e-6
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_chunk_kernel_with_a_keep_mask_is_the_jnp_update(causal):
     H, C, Tk, dk, dv = 4, 128, 256, 128, 48
@@ -440,7 +582,9 @@ def test_chunk_kernel_with_a_keep_mask_is_the_jnp_update(causal):
 
 def test_kernel_geometry():
     kvc = kv_cache_of(MODEL_REGISTRY["glm-5.2"])
-    assert isinstance(kvc, SparseLatentKV) and kvc.kernel_attend is None
+    assert isinstance(kvc, SparseLatentKV)
+    # a decode kernel of its own, not the latent cache's unmasked one
+    assert kvc.kernel_attend.__func__ is not LatentKV.kernel_attend
     # 192 key columns a head are not whole lanes; 192 + 64 are
     assert not ca.kernel_supported(128, 192, 256, 64)
     assert kvc.prefix_kernel_supported(128) and kvc.kernel_supported(128)
@@ -646,20 +790,25 @@ def test_the_new_scopes_are_in_the_programs(built):
     z = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
     pool = llama.init_paged_kv_cache(CFG, 5, PAGE, jnp.float32)
 
-    def step(p, pool, tok, pos, table, wp, off):
+    def step(p, pool, tok, pos, table, wp, off, use_kernel=False):
         return decode_j(p, CFG, tok, pos, pool, table,
-                                        pos[:, 0] + 1, wp, off)
+                                        pos[:, 0] + 1, wp, off,
+                                        use_kernel=use_kernel)
 
     def chunk(p, pool, tok, pos, table):
         return prefill_j(p, CFG, tok, pos, pool, table,
                                          pos[:, -1] + 1, jnp.int32(0))
     # four pages: above index_topk, or no score would be computed at all
-    texts = [jax.jit(step).lower(p, pool, z(2, 1), z(2, 1), z(2, 4), z(2),
-                                 z(2)).as_text(debug_info=True),
-             jax.jit(chunk).lower(p, pool, z(1, PAGE), z(1, PAGE),
+    texts = [jax.jit(step, static_argnums=(7,)).lower(
+                 p, pool, z(2, 1), z(2, 1), z(2, 4), z(2), z(2),
+                 kernel).as_text(debug_info=True) for kernel in (False, True)]
+    # the step over the decode kernel: the (jitted) kernel call under
+    # ``attn`` too — a compiled program's op names carry the prefix into
+    # the call (tests/test_chip_compile.py)
+    assert re.search(r'"attn/jit\(latent_attention_decode\)"', texts[1])
+    texts += [jax.jit(chunk).lower(p, pool, z(1, PAGE), z(1, PAGE),
                                   z(1, 4)).as_text(debug_info=True)]
     assert llama.INDEX_SCOPES == ("attn_index", "attn_select")
-    import re
     for text in texts:
         # the scores and the top-k inside ``attn`` (under the full
         # layer's cond), the projections beside ``attn_proj``

@@ -4,10 +4,16 @@ with its index leaf, chunk programs, decode rounds, the fused tail), on
 the CPU, at contexts ABOVE its ``index_topk``: its greedy tokens are the
 plain forward's; a prefix-cache hit serves latent rows AND index keys
 (pages are pages); a speculative verify round works over the pool; the
-selection's counters count; and everything that cannot take this pool
-refuses it BY NAME when the engine is configured."""
+selection's counters count; the decode rounds over the latent decode
+kernel with the keep mask as an operand (interpreted) serve the same
+tokens, count what they stream, and reserve no gathered window; and
+everything that cannot take this pool refuses it BY NAME when the engine
+is configured."""
 
 import dataclasses
+import json
+import os
+import types
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +23,7 @@ import pytest
 from generativeaiexamples_tpu.engine.engine import (Engine, EngineConfig,
                                                     SamplingParams)
 from generativeaiexamples_tpu.models import llama
+from generativeaiexamples_tpu.models.kv_cache import kv_cache_of
 from generativeaiexamples_tpu.models.tokenizer import ByteTokenizer
 from generativeaiexamples_tpu.obs.rounds import RoundRecorder
 from generativeaiexamples_tpu.utils.errors import ConfigError, EngineError
@@ -107,6 +114,105 @@ def test_the_selection_and_the_cache_are_counted(engine):
     assert recs and all(45 < r.kv_selected_pct < 55 for r in recs[-2:])
     assert "kv_rows_indexed" in recs[0].to_dict()["outcome"]
     assert st["local_assignments_rounds"] > 0
+
+
+# ---------------------------------------- decode rounds over the kernel
+
+
+@pytest.fixture(scope="module")
+def kernel_engine(params):
+    """The same engine with the decode kernel wanted (by the environment:
+    the CPU does not want it by itself), interpreted: the latent decode
+    kernel with the keep mask as an operand, the pool in the carry."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("GENAI_TPU_PAGED_KERNEL", "1")
+        eng = make_engine(params)
+    assert eng._use_kernel and eng._use_prefix_kernel
+    assert eng.downgrades == []
+    eng.start()
+    yield eng
+    eng.stop()
+
+
+def test_engine_tokens_through_the_kernel_are_the_plain_forwards(
+        kernel_engine, params):
+    """Three chunks through the chunk kernel, then decode rounds whose
+    attention is the masked decode kernel: 160 of ~300 kept."""
+    ids = prompt(300, 300)
+    assert serve(kernel_engine, ids) == plain_greedy(params, ids, N_OUT)
+
+
+@pytest.mark.parametrize("form", ["gathered", "kernel"])
+def test_the_rows_a_step_streams_are_counted(engine, kernel_engine, form):
+    """One live row of ~305 tokens, 160 selected a step: gathered, every
+    slot's window at the round's rung (4 slots x 4 pages); over the
+    kernel the row's cached context in whole blocks of four pages (one
+    block)."""
+    eng = engine if form == "gathered" else kernel_engine
+    before = dict(eng.stats)
+    serve(eng, prompt(300, 7))
+    st = eng.stats
+    steps = st["decode_steps"] - before["decode_steps"]
+    read = st["kv_rows_read"] - before["kv_rows_read"]
+    selected = st["kv_rows_selected"] - before["kv_rows_selected"]
+    want = 4 * 4 * 128 if form == "gathered" else 4 * 128
+    assert read == want * steps and selected == TOPK * steps
+    recs = [r for r in eng.rounds.records() if r.kv_rows_selected]
+    assert recs and all(r.kv_read_per_selected == want / TOPK
+                        for r in recs[-2:])
+    assert recs[-1].to_dict()["outcome"]["kv_read_per_selected"] \
+        == round(want / TOPK, 2)
+
+
+def test_the_headroom_holds_the_index_window_not_sixteen_windows(
+        engine, kernel_engine):
+    """Over the kernel the decode step gathers no window of latent rows:
+    the reserve drops every slot's gathered window and keeps the full
+    layers' index window and its scores (all index heads at once)."""
+    kvc = kv_cache_of(CFG)
+    keys = 4 * 5 * 128                  # slots x window pages x page
+    window = keys * kvc.token_bytes(4)
+    index = keys * (64 * 4 + 4 * CFG.index_n_heads)
+    assert kvc.index_window_bytes(4, 5 * 128, 4) == index < window
+    assert engine._headroom_bytes() - kernel_engine._headroom_bytes() \
+        == window - index
+    # what stays is there under both forms: the chunk's selection
+    chunk = kvc.select_bytes(128, 5 * 128 + 128)
+    assert kernel_engine._headroom_bytes() > index + chunk
+
+
+def test_the_read_per_selected_metric_names_its_reader_and_its_cell():
+    """``sparse_read_per_selected``: a data file over a reader the
+    benchmark has; on a program without the counter it reads nothing and
+    does not raise."""
+    from benchmarks.harness.spec import Spec
+    from benchmarks.readers import decode_round_fields
+    spec = Spec()
+    name = "sparse_read_per_selected"
+    entry = next(m for m in spec.doc["per_layer"] if m["name"] == name)
+    assert entry == spec.doc["per_layer"][-1]           # appended
+    assert entry["workloads"] == ["glm-5.2.long-context-mixed-16"]
+    assert (entry["moves"], entry["layer"], entry["unit"], entry["source"],
+            entry["better"]) == ("out_tok_per_s", "kernels", "count",
+                                 "program_counter", "lower")
+    metric = spec.layer_metric(name)
+    assert metric["reader"] == "decode_round_fields"
+    assert metric["args"] == {"field": "kv_read_per_selected",
+                              "per": "round"}
+    with open(os.path.join(os.path.dirname(spec.path), "benchmarks",
+                           "layer_metrics", name + ".json")) as f:
+        assert {k: entry[k] for k in ("unit", "better", "source", "layer")} \
+            == {k: v for k, v in json.load(f).items() if k in entry}
+    rec = types.SimpleNamespace
+    ctx = rec(rounds=[
+        rec(decode_slots=0, decode_steps=0, kv_read_per_selected=0.0),
+        rec(decode_slots=5, decode_steps=8, kv_read_per_selected=4.0),
+        rec(decode_slots=4, decode_steps=8, kv_read_per_selected=4.5)])
+    assert decode_round_fields.read(ctx, **metric["args"]) \
+        == pytest.approx(4.25)
+    old = rec(rounds=[rec(decode_slots=3, decode_steps=8,
+                          kv_selected_pct=25.0)])
+    assert decode_round_fields.read(old, **metric["args"]) is None
 
 
 def test_a_prefix_cache_hit_serves_rows_and_index_keys(engine, params):
