@@ -277,6 +277,12 @@ _STATS_TEMPLATE = {
     # 0 where the tree holds every expert.
     "local_assignments_sum": 0.0,
     "local_assignments_rounds": 0,
+    # Hyper-connections (models/configs.py ``hc_mult``): the sum over
+    # decode rounds of how far the rows of a layer's write-back matrices
+    # were from summing to 1 (ops/hyper_connection.py ``row_defect``),
+    # and the rounds that reported one. 0 on the plain residual path.
+    "hc_row_defect_sum": 0.0,
+    "hc_row_defect_rounds": 0,
 }
 
 # The process's program build log (utils/compile_cache.py), read into
@@ -956,13 +962,13 @@ class Engine:
         # its layers that attend a window (the decode KERNEL starts their
         # page loop at the window's first page; the gather path masks
         # and skips nothing), and whether its experts are dropless (the
-        # decode program then returns the experts its rows touched, and
-        # a step streams those experts' weights, not all).
+        # decode program then returns the experts its rows touched (and
+        # hyper-connected streams' row defect); a step streams those).
         mc = self.model_cfg
         self._window_share = (
             sum(1 for w in mc.layer_windows if w) / mc.num_layers
             if self._use_kernel else 0.0)
-        self._moe_stats = bool(self._expert_bytes)
+        self._layer_stats = bool(self._expert_bytes) or bool(mc.hc_mult)
         dev0 = self._devices()[0]
         self._hbm_peak = 0.0 if dev0.platform == "cpu" else peak_bw(dev0)
         # Model-vs-measured drift: EWMA of (round wall / modeled round
@@ -1222,9 +1228,10 @@ class Engine:
     @staticmethod
     def _refuse_unsupported(mcfg: LlamaConfig, cfg: "EngineConfig",
                             mesh: Optional[Mesh]) -> None:
-        """What cannot take a latent pool (``kv_lora_rank``) or an expert
-        share (``experts_held``) yet says so by name, here, before
-        anything is built (docs/support-matrix.md lists them)."""
+        """What cannot take a latent pool (``kv_lora_rank``), an expert
+        share (``experts_held``) or hyper-connection streams
+        (``hc_mult``) yet says so by name, here, before anything is
+        built (docs/support-matrix.md lists them)."""
         axes = dict(mesh.shape) if mesh is not None else {}
         host = (os.environ.get("KV_HOST_POOL_TOKENS", "")
                 or cfg.kv_host_pool_tokens or 0)
@@ -1252,12 +1259,18 @@ class Engine:
                  "an expert share under an ep or tp mesh: the share IS "
                  "one device's experts"),
             ]
+        if mcfg.hc_mult:
+            refused += [
+                (int(axes.get("sp", 1)) > 1, "hyper-connection streams "
+                 "under an sp mesh: the ring-attention forwards scan the "
+                 "raw layer tree and neither widen nor sum the stream"),
+            ]
         for hit, why in refused:
             if hit:
                 raise ConfigError(
                     f"this model (kv_lora_rank={mcfg.kv_lora_rank}, "
-                    f"experts_held={mcfg.experts_held}) does not support "
-                    f"{why}")
+                    f"experts_held={mcfg.experts_held}, "
+                    f"hc_mult={mcfg.hc_mult}) does not support {why}")
 
     def _alloc_pool(self) -> dict:
         """Zeroed pool leaves, each born in its final sharding and
@@ -1367,6 +1380,14 @@ class Engine:
         bucket_cache = S * self._kv_bytes_per_token(pooled=False)
         logits = S * mcfg.vocab_size * 4
         acts = S * mcfg.hidden_size * 64
+        if mcfg.hc_mult:
+            # Hyper-connections: the stream of the widest chunk program
+            # (its rows x the bucket) is ``hc_mult`` copies wide, and a
+            # sublayer's mix holds the stream it reads, the one it
+            # writes and a float32 copy of one beside them.
+            rows = max(self._row_ladder, default=1)
+            acts += rows * S * mcfg.hc_mult * mcfg.hidden_size \
+                * (2 * self._dtype.itemsize + 4)
         # The gathered page window only exists on the jnp fallback path;
         # the Pallas kernel streams pages through VMEM and never
         # materializes it — reserving for it there starves the pool
@@ -1792,9 +1813,9 @@ class Engine:
                     # (pos -> 0) streams nothing, so dead slots cost no HBM.
                     eff_pos = jnp.where(active, pos, 0)
                     # dropless experts: idle slots touch no expert, and
-                    # the step says how many its rows reached (``aux``)
+                    # the step returns llama.layer_stat_names (``aux``)
                     moe = (dict(active=active, stats=True)
-                           if self._moe_stats else {})
+                           if self._layer_stats else {})
                     net, cache, *aux = llama.apply_decode_paged(
                         params, mcfg, st["last_token"][:, None],
                         eff_pos[:, None], st["cache"], st["table"][:, :window],
@@ -2117,7 +2138,7 @@ class Engine:
         of a SAMPLED round only; a greedy round has no candidate merge
         and returns nothing new."""
         return ((llama.layer_stat_names(self.model_cfg)
-                 if self._moe_stats else ())
+                 if self._layer_stats else ())
                 + (("tail_resort_pct",)
                    if self._fused_tail and not greedy else ()))
 

@@ -50,6 +50,12 @@ def init_lora(cfg: LlamaConfig, base_params: llama.Params, key: jax.Array,
             "LoRA over a latent-attention model (kv_lora_rank): its "
             "projections are already low-rank pairs and none of them is "
             "a target here")
+    if cfg.hc_mult:
+        raise NotImplementedError(
+            f"LoRA over hyper-connection streams (hc_mult={cfg.hc_mult}): "
+            f"the train step merges deltas into a tree whose mapping "
+            f"weights (hc_*_phi / alpha / b) are no target, and no "
+            f"adapter was ever trained against a mixed stream here")
     lora: LoraParams = {}
     keys = jax.random.split(key, len(targets))
     for k_rng, name in zip(keys, targets):

@@ -171,6 +171,22 @@ class LlamaConfig:
     index_head_dim: int = 0
     index_layers: tuple = ()
     index_rope_interleave: bool = False
+    # Manifold-constrained hyper-connections (``hc_mult`` > 0; 0 is the
+    # plain residual path, whose programs it leaves as they are): the
+    # stream between blocks is ``hc_mult`` copies wide, (B, S, hc_mult x
+    # hidden_size), and each sublayer (attention, MLP or experts) reads a
+    # learned mixture of the copies and writes back through a doubly
+    # stochastic hc_mult x hc_mult matrix (ops/hyper_connection.py has
+    # the equations):
+    #   hc_sinkhorn_iters: row-then-column normalisations that make it
+    #   hc_eps: added under the stream's RMS statistic and to every row
+    #         and column sum
+    #   hc_res_clamp: the matrix's logits are clamped to +- this before
+    #         the exponential
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
     # How ``llama.init_params`` draws a random tree (tests, benchmarks;
     # served weights come from ``import_hf`` and ignore it):
     #   "fan_in": every matrix N(0, 1/fan_in), embedding rows of norm 1
@@ -186,6 +202,12 @@ class LlamaConfig:
     #         ``qk_norm`` / ``post_norms`` add carry the gains the norms
     #         take out of ``wq``, ``wo`` and ``w_down``, each a tenth
     #         off its centre (``llama.init_params`` says why each)
+    #   "unit_stream_thin_experts": "unit_stream" with the ROUTED
+    #         experts' ``w_down`` at a fifth of that scale (the shared
+    #         expert keeps it) — for a tree that holds EVERY expert of
+    #         a layer, where each near-tie bf16 flips between a token's
+    #         last chosen expert and the next shows at the logits in
+    #         full (a held share hides most of them)
     weight_init: str = "fan_in"
 
     def __post_init__(self):
@@ -201,7 +223,8 @@ class LlamaConfig:
                              "use 0 (none) or >= 2")
         if self.router_input not in ("mlp_norm", "block_input"):
             raise ValueError(f"unknown router_input {self.router_input!r}")
-        if self.weight_init not in ("fan_in", "unit_stream"):
+        if self.weight_init not in ("fan_in", "unit_stream",
+                                    "unit_stream_thin_experts"):
             raise ValueError(f"unknown weight_init {self.weight_init!r}")
         if self.router_score_func not in ("softmax", "sigmoid"):
             raise ValueError(
@@ -260,6 +283,19 @@ class LlamaConfig:
             raise ValueError("index_n_heads, index_head_dim, index_layers "
                              "and index_rope_interleave are the indexer's "
                              "(index_topk)")
+        if self.hc_mult < 0 or self.hc_mult == 1:
+            raise ValueError("hc_mult is 0 (the plain residual path) or "
+                             "the number of streams, at least 2")
+        if self.hc_mult and self.router_input == "block_input":
+            raise ValueError("hyper-connections (hc_mult): a block's input "
+                             "is hc_mult streams, which a router of "
+                             "router_input 'block_input' cannot read")
+        if self.hc_mult and not (self.hc_sinkhorn_iters >= 1
+                                 and self.hc_eps > 0
+                                 and self.hc_res_clamp > 0):
+            raise ValueError("hyper-connections (hc_mult) need "
+                             "hc_sinkhorn_iters >= 1 and positive hc_eps "
+                             "and hc_res_clamp")
         if self.experts_held or self.experts_first:
             if not (self.num_experts and self.moe_impl == "dropless"):
                 raise ValueError("an expert share (experts_held) needs "
@@ -445,6 +481,29 @@ GLM_5_2 = LlamaConfig(
     index_layers=tuple(int(i < 3 or (i - 2) % 4 == 0) for i in range(78)),
     index_rope_interleave=True, weight_init="unit_stream")
 
+# A 29B-total / 4B-active sparse model (XingChen-AGI Xing4.0-29B-A4B
+# config.json, model_type xing4_0): the DeepseekV3 block — two dense
+# layers then thirty-eight of 64 SwiGLU experts, 4 a token by sigmoid
+# scores with a selection bias, beside one shared expert; latent
+# attention, 32 heads of 128 + 64 over one 512 + 64 wide cached row a
+# token; YaRN factor 64 — on a residual path of four streams mixed by
+# manifold-constrained hyper-connections (ops/hyper_connection.py). The
+# multi-token-prediction module is not built.
+XING4_0_29B_A4B = LlamaConfig(
+    vocab_size=131072, hidden_size=3584, intermediate_size=9216,
+    moe_intermediate_size=1024, num_layers=40, num_dense_layers=2,
+    num_heads=32, num_kv_heads=1, head_dim=192,
+    max_position_embeddings=262144, rope_theta=10000.0, rms_norm_eps=1e-6,
+    num_experts=64, num_experts_per_tok=4, num_shared_experts=1,
+    moe_impl="dropless", router_score_func="sigmoid",
+    router_norm_topk=True, router_scale=2.0, router_bias="selection",
+    kv_lora_rank=512, q_lora_rank=768, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128, rope_interleave=True,
+    rope_scaling_type="yarn", rope_scaling_factor=64.0,
+    rope_original_max=4096, rope_beta_fast=32.0, rope_beta_slow=1.0,
+    rope_mscale_all_dim=1.0, hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6,
+    hc_res_clamp=30.0, weight_init="unit_stream_thin_experts")
+
 # GPT-Next / Nemotron-8B (the reference's second served family:
 # ensemble_models/gptnext/, docs/rag/support_matrix.md:14 sizing;
 # nemotron_config.yaml deployment). Rotary attention, zero-centered
@@ -496,6 +555,7 @@ MODEL_REGISTRY: dict[str, LlamaConfig] = {
     "trinity-mini": TRINITY_MINI,
     "kimi-k2-instruct": KIMI_K2,
     "glm-5.2": GLM_5_2,
+    "xing4.0-29b-a4b": XING4_0_29B_A4B,
     "gptnext-tiny": GPTNEXT_TINY,
     "llama-tiny": LLAMA_TINY,
     "golden-tiny": GOLDEN_TINY,

@@ -81,8 +81,21 @@ _HF_INDEX_LAYER_KEYS = {
     "self_attn.indexer.k_norm.bias": ("index_k_norm_b", False),
     "self_attn.indexer.weights_proj.weight": ("index_wp", True),
 }
+# The mapping weights of hyper-connections (cfg.hc_mult), a set for each
+# of a block's two sublayers. ASSUMED names: no ``xing4_0`` checkpoint or
+# modelling code was at hand (benchmarks/configs/xing4.0-29b-a4b.json
+# ``assumed.weight_names``); this table is the one place to change.
+# ``phi`` is stored (n + n + n^2, n x hidden) like every projection.
+_HF_HC_LAYER_KEYS = {
+    f"{hf}_hc.{leaf}": (f"hc_{part}_{name}", leaf.endswith(".weight"))
+    for hf, part in (("attn", "attn"), ("mlp", "mlp"))
+    for leaf, name in (("phi.weight", "phi"), ("alpha", "alpha"),
+                       ("bias", "b"))
+}
 _HF_KV_B = "self_attn.kv_b_proj.weight"
-_FLOAT32_LEAVES = ("router_bias",)      # a buffer the router adds in f32
+# buffers and coefficients the program reads in float32
+_FLOAT32_LEAVES = ("router_bias", "hc_attn_alpha", "hc_attn_b",
+                   "hc_mlp_alpha", "hc_mlp_b")
 
 
 def _split_kv_b(w: np.ndarray, cfg: LlamaConfig) -> tuple:
@@ -262,6 +275,8 @@ def params_from_named_tensors(
         hf_keys.update(_HF_LATENT_LAYER_KEYS)
     if cfg.index_topk:
         hf_keys.update(_HF_INDEX_LAYER_KEYS)
+    if cfg.hc_mult:
+        hf_keys.update(_HF_HC_LAYER_KEYS)
     # an expert share keeps the experts it holds, numbered from its first
     first_expert, held = cfg.experts_first, cfg.held_experts
     layer_acc: dict[str, list] = {}

@@ -61,6 +61,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from ..ops import hyper_connection as hc
 from ..ops.quant import matmul as qmm
 from ..ops.quant import matmul_f32 as qmm_f32
 from ..ops.rmsnorm import layernorm1p, rmsnorm
@@ -90,6 +91,11 @@ from .kv_cache import (KVCache, _paged_prefix_attention,  # noqa: F401
 SCOPES = ("embed", "attn_proj", "attn", "mlp", "moe_route", "moe_experts",
           "tail", "tail_select")
 INDEX_SCOPES = ("attn_index", "attn_select")
+#: Hyper-connections (``cfg.hc_mult``) add two, OUTSIDE the stages above:
+#: ``hc_pre`` (a sublayer's coefficients and its input, the mix of the
+#: streams; and the model's first stream) and ``hc_post`` (the stream
+#: written back; and the sum that ends it) — ops/hyper_connection.py.
+HC_SCOPES = ("hc_pre", "hc_post")
 
 
 def _embed(params: "Params", tokens: jax.Array,
@@ -175,9 +181,23 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
     # the wrong window, or rotary where none belongs, reads within the
     # sound error of a 4608-token prompt's logits; with (3) it reads
     # 4-18 times over it (chip, PR 28; PERF.md section 6).
-    stream_draw = cfg.weight_init == "unit_stream"
+    stream_draw = cfg.weight_init in ("unit_stream",
+                                      "unit_stream_thin_experts")
     q_gain = 16 if stream_draw else 1
     resid = 2 * cfg.num_layers if stream_draw else 1
+    # (4) "unit_stream_thin_experts": the ROUTED experts' ``w_down`` at
+    # a fifth of (2), as ``post_mlp_norm`` below and for its reason.
+    # Where a tree holds EVERY expert of a layer, each near-tie that
+    # bf16 rounding flips between a token's last chosen sigmoid-scored
+    # expert and the next shows in full (a share of 12 in 384 hides 31
+    # of 32): at (2) alone the SOUND xing4.0-29b-a4b read 0.028 at the
+    # median position and 0.30 of 272 positions over 0.05 of the float32
+    # reference (chip, PR 42, seed 4242100001; kimi-k2-instruct's share
+    # reads 0.011 / 0.01-0.02). The shared expert keeps (2); a fourth
+    # chosen expert left out still reads 0.046, five times the sound
+    # median (three seeds; its faults file, PERF.md section 6).
+    routed = resid * (25 if cfg.weight_init == "unit_stream_thin_experts"
+                      else 1)
 
     # layernorm1p stores weights centered at zero (applied as 1 + w)
     norm_w = jnp.zeros if cfg.norm == "layernorm1p" else jnp.ones
@@ -216,7 +236,7 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
                 "router": norm(next(k), (L, D, E), D),
                 "w_gate": norm(next(k), (L, Eh, D, Fe), D),
                 "w_up": norm(next(k), (L, Eh, D, Fe), D),
-                "w_down": norm(next(k), (L, Eh, Fe, D), Fe * resid),
+                "w_down": norm(next(k), (L, Eh, Fe, D), Fe * routed),
             })
         elif cfg.mlp == "squared_relu":
             # GPT-Next MLP: no gate projection
@@ -363,6 +383,38 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
             "index_wp": norm(next(k), (Lf, D, Hi), D),
         }
 
+    def hyper(k, L):
+        """The mapping weights of a stack's two sublayers (``cfg.hc_mult``
+        streams), drawn so that no mapping is near its trivial value —
+        a program that loses part of the mechanism then differs at the
+        logits: ``phi`` at the fan-in deviation, so a token's projections
+        of its normed stream deviate by 1; ``alpha`` (1, 1, 0.3), each a
+        tenth off: the input moves the logits of ``H_pre`` and ``H_post``
+        by as much as their biases do and every entry of ``H_res`` by a
+        third (a fresh model's 0.01 would leave the mappings static);
+        ``b_pre`` of deviation 1 (``H_pre`` unequal over the streams),
+        ``b_post`` of 0.5 around 0 (``H_post`` around its 1); ``b_res``
+        1.5 on the diagonal, deviation 0.3 — a matrix that keeps three
+        fifths of a stream and moves the rest, whose rows after ONE
+        normalisation pair are off by a tenth and after the twenty the
+        configuration states by under 1e-5 at every token (the floor is
+        ``hc_eps``; a wider draw leaves single tokens at 1e-3)."""
+        n, m = cfg.hc_mult, cfg.hc_mult * (cfg.hc_mult + 2)
+        spread = jnp.where(jnp.arange(m) < n, 1.0,
+                           jnp.where(jnp.arange(m) < 2 * n, 0.5, 0.3))
+        out = {}
+        for part in ("attn", "mlp"):
+            bias = jax.random.normal(next(k), (L, m), jnp.float32) * spread
+            out.update({
+                f"hc_{part}_phi": norm(next(k), (L, n * D, m), n * D),
+                f"hc_{part}_alpha": jnp.asarray([1.0, 1.0, 0.3]) * (
+                    1.0 + 0.1 * jax.random.normal(next(k), (L, 3),
+                                                  jnp.float32)),
+                f"hc_{part}_b": bias.at[:, 2 * n:].add(
+                    1.5 * jnp.eye(n, dtype=jnp.float32).reshape(-1)),
+            })
+        return out
+
     kx = iter(jax.random.split(jax.random.fold_in(key, 1), 32))
     layers = stack(k, L, bool(cfg.num_experts))
     layers.update(extras(kx, layers))
@@ -383,6 +435,10 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
         ki = iter(jax.random.split(jax.random.fold_in(key, 2), 16))
         for name, first, n in cfg.layer_stacks:
             params[name].update(indexer(ki, first, n))
+    if cfg.hc_mult:
+        kh = iter(jax.random.split(jax.random.fold_in(key, 3), 16))
+        for name, _, n in cfg.layer_stacks:
+            params[name].update(hyper(kh, n))
     return params
 
 
@@ -461,12 +517,15 @@ def layer_kinds(cfg: LlamaConfig, first: int = 0,
 
 
 def layer_stat_names(cfg: LlamaConfig) -> tuple[str, ...]:
-    """The scalars a layer with dropless experts reports under ``stats``
-    (parallel/moe.py): the distinct experts its rows reached among those
+    """The scalars a layer reports under ``stats``: with dropless experts
+    (parallel/moe.py) the distinct experts its rows reached among those
     it holds and, where it holds a share, the assignments that fell on
-    them."""
+    them; under hyper-connections ``hc_row_defect``, how far the rows of
+    its two write-back matrices are from summing to 1
+    (ops/hyper_connection.py ``row_defect``)."""
     return ("experts_touched",) + (
-        ("local_assignments",) if cfg.experts_held else ())
+        ("local_assignments",) if cfg.experts_held else ()) + (
+        ("hc_row_defect",) if cfg.hc_mult else ())
 
 
 def scan_layers(params: Params, cfg: LlamaConfig, stack: str = "layers"
@@ -600,26 +659,37 @@ def _run_model(params: Params, cfg: LlamaConfig, h: jax.Array, *args,
     pool — carried from stack to stack (``state``) or held by the
     caller's ``attend`` while each stack's rows come out and are joined
     along the layer axis. ``xs`` (L, ...) is cut to each stack's layers.
-    ``touched`` is that of the layers that have experts. A ``selection``
+    ``touched`` (every ``layer_stat_names`` scalar) is that of the layers
+    that have experts. A ``selection``
     (``_run_stack``) is handed from stack to stack: the first expert
     layers attend the set of the last full dense layer."""
     stacks = cfg.layer_stacks
+    if cfg.hc_mult:
+        # the stream is ``hc_mult`` copies wide between the embedding and
+        # the final norm and nowhere else: every forward hands in and
+        # gets back (B, S, D)
+        h = hc.expand(h, cfg.hc_mult)
     if len(stacks) == 1:
-        return _run_stack(params["layers"], cfg, h, *args, state=state,
-                          xs=xs, selection=selection, **kw)[:3]
-    outs, touched = [], None
-    for name, first, n in stacks:
-        part = xs and {k: v[first:first + n] for k, v in xs.items()}
-        h, out, t, selection = _run_stack(
-            params[name], cfg, h, *args, state=state, xs=part, first=first,
-            selection=selection, **kw)
-        if state is not None:
-            state = out
-        outs.append(out)
-        if "router" in params[name]:
-            touched = t
-    if state is None:
-        state = jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *outs)
+        h, state, touched = _run_stack(
+            params["layers"], cfg, h, *args, state=state, xs=xs,
+            selection=selection, **kw)[:3]
+    else:
+        outs, touched = [], None
+        for name, first, n in stacks:
+            part = xs and {k: v[first:first + n] for k, v in xs.items()}
+            h, out, t, selection = _run_stack(
+                params[name], cfg, h, *args, state=state, xs=part,
+                first=first, selection=selection, **kw)
+            if state is not None:
+                state = out
+            outs.append(out)
+            if "router" in params[name]:
+                touched = t
+        if state is None:
+            state = jax.tree.map(lambda *a: jnp.concatenate(a, axis=0),
+                                 *outs)
+    if cfg.hc_mult:
+        h = hc.collapse(h, cfg.hc_mult)
     return h, state, touched
 
 
@@ -988,10 +1058,22 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
     (``experts_touched``). What the configuration adds to a block
     (``qk_norm``, ``attn_gate``, ``post_norms``, a shared expert) is read
     from ``cfg``; whether the layer has experts from its tree.
+
+    Under hyper-connections (``cfg.hc_mult``) ``h`` is the wide stream
+    (B, S, hc_mult x D) and the two ``h + out`` below are each a read
+    (``hc_pre``: the sublayer's input is a mix of the streams) and a
+    write (``hc_post``) of ops/hyper_connection.py, with the sublayer
+    between them as it is; ``aux`` receives ``hc_row_defect``.
     """
     B, S, _ = h.shape
     router_logits = None
     experts = "router" in lp        # a leading dense layer has none
+    hyper = _hc_args(cfg)
+    if hyper:
+        stream = h
+        h, h_post, h_res = hc.hc_pre(stream, _hc_weights(lp, "attn"),
+                                     **hyper)
+        defect = None if aux is None else hc.row_defect(h_res)
     if experts and cfg.router_input == "block_input":
         # the router reads the stream as it enters the block
         with jax.named_scope("moe_route"):
@@ -1057,7 +1139,14 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
             attn_out = attn_out + lp["bo"]
         if cfg.post_norms:
             attn_out = block_norm(attn_out, lp, "post_attn_norm", cfg)
-        h = h + attn_out
+        if not hyper:
+            h = h + attn_out
+    if hyper:
+        stream = hc.hc_post(stream, attn_out, h_post, h_res)
+        h, h_post, h_res = hc.hc_pre(stream, _hc_weights(lp, "mlp"),
+                                     **hyper)
+        if aux is not None:
+            aux["hc_row_defect"] = 0.5 * (defect + hc.row_defect(h_res))
     if experts:
         with jax.named_scope("moe_route"):
             x = block_norm(h, lp, "mlp_norm", cfg)
@@ -1071,13 +1160,32 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
         with jax.named_scope("moe_experts"):
             if cfg.post_norms:
                 mlp = block_norm(mlp, lp, "post_mlp_norm", cfg)
-            return h + mlp, new_cache
+            if not hyper:
+                return h + mlp, new_cache
+        return hc.hc_post(stream, mlp, h_post, h_res), new_cache
     with jax.named_scope("mlp"):
         x = block_norm(h, lp, "mlp_norm", cfg)
         mlp = _dense_mlp(x, lp, cfg)
         if cfg.post_norms:
             mlp = block_norm(mlp, lp, "post_mlp_norm", cfg)
-        return h + mlp, new_cache
+        if not hyper:
+            return h + mlp, new_cache
+    return hc.hc_post(stream, mlp, h_post, h_res), new_cache
+
+
+def _hc_args(cfg: LlamaConfig) -> dict:
+    """What ``hc.hc_pre`` takes of the configuration; empty on the plain
+    residual path."""
+    if not cfg.hc_mult:
+        return {}
+    return {"n": cfg.hc_mult, "iters": cfg.hc_sinkhorn_iters,
+            "eps": cfg.hc_eps, "clamp": cfg.hc_res_clamp}
+
+
+def _hc_weights(lp: dict[str, jax.Array], part: str) -> tuple:
+    """A sublayer's (``attn`` / ``mlp``) mapping weights out of a layer's
+    tree: ``(phi, alpha, b)``."""
+    return tuple(lp[f"hc_{part}_{name}"] for name in ("phi", "alpha", "b"))
 
 
 def _latent_qkv(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
@@ -1174,9 +1282,10 @@ def run_layers(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
                kv_valid_len: Optional[jax.Array] = None) -> jax.Array:
     """Scan a (possibly partial) stacked layer stack over hidden states,
     no KV cache — the per-stage body for pipeline parallelism."""
-    if jax.tree.leaves(layers)[0].shape[0] != cfg.num_layers:
-        # a stage does not know which of the model's layers it holds
-        _refuse_kinds(cfg, "a partial layer stack")
+    if cfg.hc_mult or jax.tree.leaves(layers)[0].shape[0] != cfg.num_layers:
+        # a stage does not know which of the model's layers it holds,
+        # and what crosses a stage under hyper-connections is n streams
+        _refuse_kinds(cfg, "a pipeline stage's layer stack")
     inv_freq = _inv_freq(cfg)
     return _run_stack(layers, cfg, h, positions, inv_freq, kv_valid_len)[0]
 
@@ -1379,6 +1488,13 @@ def apply_sp(params: Params, cfg: LlamaConfig, tokens: jax.Array,
 def _refuse_kinds(cfg: LlamaConfig, fn_name: str) -> None:
     """Paths that scan the raw layer tree (ring attention, which has no
     window mask either; a pipeline stage's partial stack)."""
+    if cfg.hc_mult:
+        raise NotImplementedError(
+            f"{fn_name}: hyper-connections (hc_mult={cfg.hc_mult}) are not "
+            f"supported here: the stream between blocks is hc_mult copies "
+            f"wide, widened after the embedding and summed before the "
+            f"final norm by the forwards that run every stack "
+            f"(_run_model)")
     if layer_kinds(cfg) or cfg.embed_scale != 1.0 or cfg.kv_lora_rank or (
             cfg.num_experts and cfg.moe_impl == "dropless"):
         raise NotImplementedError(

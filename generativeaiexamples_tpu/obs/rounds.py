@@ -172,7 +172,7 @@ class RoundRecord:
         "harvest_wait_ms", "first_readback_ms", "emit_ms",
         "tokens_emitted", "first_tokens", "spec_accepted",
         "experts_touched", "tail_resort_pct", "local_assignments",
-        "t_parts",
+        "hc_row_defect", "t_parts",
         # finalization
         "t_done", "device_ms", "round_ms", "bw_util", "drift_ratio", "done",
         # bookkeeping
@@ -275,6 +275,13 @@ class RoundRecord:
         # the decode program returns beside ``experts_touched``. 0
         # where the tree holds every expert.
         self.local_assignments = 0.0
+        # How far the rows of a layer's hyper-connection write-back
+        # matrices were from summing to 1 (ops/hyper_connection.py
+        # ``row_defect``), mean over the expert layers, their two
+        # sublayers and the round's steps: a scalar the decode program
+        # returns. ~1e-6 (``hc_eps``) where the coefficients are float32
+        # and every normalisation ran; 0 on the plain residual path.
+        self.hc_row_defect = 0.0
         # The harvest thread's stamp of each completed part, in the
         # device's FIFO order (the decode output before the chunks'
         # marker): where a round that dispatched both divides.
@@ -348,6 +355,7 @@ class RoundRecord:
                 "experts_touched": round(self.experts_touched, 2),
                 "tail_resort_pct": round(self.tail_resort_pct, 2),
                 "local_assignments": round(self.local_assignments, 2),
+                "hc_row_defect": self.hc_row_defect,
                 "kv_restore_pages": self.kv_restore_pages,
                 "hbm_bytes_est": self.hbm_bytes,
                 "bw_util": round(self.bw_util, 4),
@@ -443,7 +451,8 @@ class RoundRecorder:
                       emit_ms: float = 0.0,
                       experts_touched: float = 0.0,
                       tail_resort_pct: float = 0.0,
-                      local_assignments: float = 0.0) -> None:
+                      local_assignments: float = 0.0,
+                      hc_row_defect: float = 0.0) -> None:
         """One harvested device output of this round (harvest thread).
         The last part — once the scheduler has sealed the expected
         count — finalizes the record."""
@@ -459,6 +468,8 @@ class RoundRecorder:
             rec.tail_resort_pct = float(tail_resort_pct)
         if local_assignments:
             rec.local_assignments = float(local_assignments)
+        if hc_row_defect:
+            rec.hc_row_defect = float(hc_row_defect)
         rec.t_parts.append(time.monotonic())
         finalize = False
         with self._lock:

@@ -729,6 +729,92 @@ def test_sparse_latent_decode_step_compiles(topo, tpu_backend):
     assert wide == []
 
 
+# ------------------------------------- hyper-connected residual streams
+
+
+@pytest.mark.parametrize("T", [1, 16, 512, 1536, 2048])
+def test_sinkhorn_kernel_compiles(topo, T):
+    """``hc_sinkhorn`` at every token count the cell's programs have (a
+    decode round of one row and of sixteen, a chunk, the check's one-shot
+    prompt, four prompts' rows): Mosaic takes the layout (16, T / 128,
+    128) and the unrolled twenty pairs."""
+    from generativeaiexamples_tpu.ops import hyper_connection as hc
+    dev = SingleDeviceSharding(topo.devices[0])
+    compiled = hc.sinkhorn_kernel.lower(
+        sds((16, T), jnp.float32, dev), n=4, iters=20, eps=1e-6,
+        clamp=30.0).compile()
+    assert "hc_sinkhorn" in compiled.as_text()
+
+
+def xing_cfg():
+    return dataclasses.replace(get_model_config("xing4.0-29b-a4b"),
+                               num_layers=3, num_dense_layers=1)
+
+
+@pytest.mark.parametrize("rows", [1, 4])
+def test_hyper_connected_chunk_program_compiles(topo, tpu_backend, rows):
+    """``xing4.0-29b-a4b``'s 512-token chunk program at published widths
+    (1 dense + 2 expert layers, all 64 experts, the cell's 28-page
+    window), one prompt and four: the chain from a sublayer's logits to
+    ``H_res`` is ONE kernel under the scope ``hc_pre`` — not the four
+    fusions a normalisation pair it is in jax.numpy —, and the four
+    prompts' program holds its streams inside the engine's reserve."""
+    from tools.dump_hlo import parse_hlo
+    cfg = xing_cfg()
+    dev = SingleDeviceSharding(topo.devices[0])
+    cache = on(jax.eval_shape(
+        lambda: llama.init_paged_kv_cache(cfg, 449, PAGE)), dev)
+    i32 = lambda *shape: sds(shape, jnp.int32, dev)  # noqa: E731
+
+    def chunk(params, tok, pos, cache, tbl, valid, start):
+        return llama.apply_prefill_paged(params, cfg, tok, pos, cache, tbl,
+                                         valid, start, use_kernel=True)
+
+    compiled = jax.jit(chunk, donate_argnums=(3,)).lower(
+        on(param_shapes(cfg), dev), i32(rows, 512), i32(rows, 512), cache,
+        i32(rows, 28), i32(rows), i32(rows) if rows > 1 else i32()).compile()
+    assert_fits(compiled)
+    text = compiled.as_text()
+    assert re.search(r'op_name="[^"]*/hc_pre/[^"]*hc_sinkhorn', text)
+    ins = [i for c in parse_hlo(text).values() for i in c]
+    scoped = [i for i in ins if re.search(r'op_name="[^"]*/hc_(pre|post)/',
+                                          i["attrs"])]
+    # two sublayers' chains in each of the two stacks' loop bodies: a
+    # handful of fusions each, where the jnp chain had 80 a sublayer
+    assert 0 < sum(i["op"] == "fusion" for i in scoped) < 120
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (128 << 20 if rows == 1 else 448 << 20), temp
+
+
+def test_hyper_connected_decode_step_compiles(topo, tpu_backend):
+    """The decode step at the cell's 16 rows over the latent kernel, the
+    pool in the layer scan's carry and donated, ``hc_row_defect`` among
+    its results."""
+    cfg = xing_cfg()
+    dev = SingleDeviceSharding(topo.devices[0])
+    cache = on(jax.eval_shape(
+        lambda: llama.init_paged_kv_cache(cfg, 449, PAGE)), dev)
+    i32 = lambda *shape: sds(shape, jnp.int32, dev)  # noqa: E731
+    B = 16
+
+    def step(params, tok, pos, cache, tbl, wp, off, active):
+        return llama.apply_decode_paged(params, cfg, tok, pos, cache, tbl,
+                                        pos[:, 0] + 1, wp, off,
+                                        use_kernel=True, return_hidden=True,
+                                        active=active, stats=True)
+
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        on(param_shapes(cfg), dev), i32(B, 1), i32(B, 1), cache, i32(B, 28),
+        i32(B), i32(B), sds((B,), jnp.bool_, dev)).compile()
+    assert_fits(compiled)
+    m = compiled.memory_analysis()
+    pool = sum(math.prod(x.shape) * 2 for x in cache.values())
+    assert m.alias_size_in_bytes >= pool            # donated, not copied
+    text = compiled.as_text()
+    assert re.search(r'op_name="[^"]*/hc_pre/[^"]*hc_sinkhorn', text)
+    assert re.search(r'op_name="[^"]*/attn/[^"]*latent_attn_decode', text)
+
+
 _POOL = "bf16[2,9,4,16,64]{4,3,2,1,0:T(8,128)(2,1)}"
 _SLAB = "bf16[9,4,16,64]{3,2,1,0:T(8,128)(2,1)}"
 _BLOCK = "bf16[8,4,16,64]{3,2,1,0:T(8,128)(2,1)}"
