@@ -1353,9 +1353,14 @@ def lm_head_tile(params: Params, cfg: LlamaConfig, hn: jax.Array,
     Works for every lm_head storage the repo serves — tied embedding
     (V, D), raw (D, V), and quantized dicts (int8/int4/grouped, whose
     packing runs along the reduction axis, so an output-axis slice stays
-    a valid QTensor for ops.quant.matmul_f32). Inside a tile scan the
-    slice reads each weight byte exactly once per full vocab pass — the
-    same HBM traffic as one materialized unembed, with no (B, V) output."""
+    a valid QTensor for ops.quant.matmul_f32). Inside a tile scan — the
+    sampled, verify and tp-sharded streams, and the greedy one over an
+    int4 or grouped head or off the TPU — the slice reads each weight
+    byte exactly once per full vocab pass: the same HBM traffic as one
+    materialized unembed, with no (B, V) output. The greedy tail over a
+    per-column int8, raw or tied head on a TPU does not come here: it is
+    one Pallas kernel over the whole stored head
+    (ops/head_argmax.py ``greedy_head_argmax``)."""
     head = params.get("lm_head")
     if head is None:
         e = jax.lax.dynamic_slice_in_dim(params["embed"], t0, tile, axis=0)

@@ -13,7 +13,13 @@ running state across tiles:
 - repetition penalty and bad-words masks are applied per tile, read from
   uint32 *bitfield* masks (``ops/sampling.py pack_mask``: 1 bit per
   token, sliced per tile — no (B, V) bool ever exists);
-- greedy is a running argmax;
+- greedy is a running argmax (``_greedy_stream``) — HERE for int4 and
+  grouped heads, the tp-sharded stream and backends other than the TPU;
+  over a per-column int8, raw or tied head on a TPU the engine's greedy
+  tail is ``ops/head_argmax.py greedy_head_argmax`` instead: ONE Pallas
+  kernel that streams the stored head through VMEM once with the
+  penalties and the running argmax under the weight stream, the same
+  tokens;
 - sampling uses the Gumbel-max formulation (``argmax(scaled + gumbel)``
   == categorical) with per-tile noise keyed by ``fold_in(key, tile)``,
   plus a running top-``cand_k`` of raw scaled values (the Gumbel-top-k
@@ -64,7 +70,7 @@ import jax.numpy as jnp
 from .sampling import MASK_BITS, NEG_INF, unpack_mask
 
 DEFAULT_TILE = 4096
-# The sampled and verify streams' tile. Wider than the greedy stream's:
+# The sampled and verify streams' tile. Wider than the greedy scan's:
 # a sampled tile pays a Gumbel field, a logsumexp, three running maxima
 # and a candidate merge on top of its slice of the head, and on a v5e
 # the 256000-column stream of 16 rows read 3.32 ms a step without any

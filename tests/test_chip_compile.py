@@ -227,6 +227,40 @@ def test_fused_sampler_tail_256k_vocab_compiles(topo, greedy):
     assert compiled.memory_analysis().temp_size_in_bytes < (32 << 20)
 
 
+@pytest.mark.parametrize("D,V,rows,storage", [
+    (2048, 200192, 16, "int8"),     # trinity-mini: 98 blocks of 2048
+    (7168, 163840, 32, "int8"),     # kimi-k2-instruct: 320 blocks of 512
+    (4096, 256000, 1, "int8"),      # a first token's one row
+    (2560, 151936, 5, "tied"),      # the (V, D) embedding, NT blocks
+    (4096, 32000, 16, "raw"),
+])
+def test_greedy_head_argmax_kernel_compiles(topo, D, V, rows, storage):
+    """The greedy tail's kernel (ops/head_argmax.py) at the widths the
+    cells serve, every operand it can take: a few MB a block in VMEM, no
+    (rows, V) array in HBM."""
+    from generativeaiexamples_tpu.ops.head_argmax import greedy_head_argmax
+    from generativeaiexamples_tpu.ops.sampling import mask_words
+    dev = SingleDeviceSharding(topo.devices[0])
+    tree = {"int8": {"lm_head": {"q": sds((D, V), jnp.int8, dev),
+                                 "scale": sds((V,), jnp.float32, dev)}},
+            "tied": {"embed": sds((V, D), jnp.bfloat16, dev)},
+            "raw": {"lm_head": sds((D, V), jnp.bfloat16, dev)}}[storage]
+    words = sds((rows, mask_words(V)), jnp.uint32, dev)
+
+    def tail(hn, tree, rep, seen, banned, ban_tok, ban_hit):
+        return greedy_head_argmax(hn, tree, V, rep_pen=rep, seen_words=seen,
+                                  banned_words=banned, ban_tok=ban_tok,
+                                  ban_hit=ban_hit)
+
+    compiled = jax.jit(tail).lower(
+        sds((rows, D), jnp.bfloat16, dev), tree,
+        sds((rows,), jnp.float32, dev), words, words,
+        sds((rows, 7), jnp.int32, dev),
+        sds((rows, 7), jnp.bool_, dev)).compile()
+    assert '"greedy_head_argmax"' in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < (4 << 20)
+
+
 # ------------------------------------------------- engine step programs
 
 
@@ -248,6 +282,7 @@ def engine(topo):
         prefill_buckets=(512, 1024), max_prefill_bucket=1024,
         kv_pool_tokens=8 * 1024, steps_per_round=8))
     assert eng._use_kernel and eng._fused_tail and not eng.downgrades
+    assert eng._tail_kernel     # a per-column int8 head: the kernel's
     yield eng
     mp.undo()
 
@@ -293,6 +328,8 @@ def test_decode_round_program_compiles(engine, topo, tpu_backend):
                         sds((B,), jnp.int32, dev)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    # the greedy tail is the head kernel, once a step
+    assert '"greedy_head_argmax"' in text
     assert_fits(compiled)
     # the pool is donated and aliased in place: no second pool in temps
     pool_bytes = sum(v.nbytes for v in engine._state["cache"].values())

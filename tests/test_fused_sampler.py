@@ -756,3 +756,223 @@ def test_tail_resort_pct_reaches_record_stats_and_metrics(select_engine):
     text = reg.render_prometheus()
     assert "engine_tail_resort_pct_sum" in text
     assert "engine_tail_resort_pct_rounds" in text
+
+
+# --------------------- the greedy tail as one kernel (ops/head_argmax.py)
+
+
+def _head_tree(storage, head):
+    """The unembedding's leaves as ``lm_head_subtree`` hands them over,
+    in one of the storages the kernel takes."""
+    from generativeaiexamples_tpu.ops.quant import quantize_tensor
+    if storage == "tied":
+        return {"embed": head.T}
+    if storage == "raw":
+        return {"lm_head": head}
+    return {"lm_head": quantize_tensor(head, bits=8)}
+
+
+@pytest.mark.parametrize("rows,ban_rows", [(1, False), (5, True),
+                                           (16, False), (32, True)])
+@pytest.mark.parametrize("storage", ["int8", "int8_pre", "raw", "tied"])
+def test_head_kernel_is_the_scan_token_for_token(storage, rows, ban_rows):
+    """``greedy_head_argmax`` (interpreted) against the tile scan over
+    ``lm_head_tile``: the same tokens over every head storage it takes,
+    1 to 32 rows, a vocabulary of whole lanes but not of whole blocks
+    (128 x 11 in blocks of 256: the last block is half past the end),
+    repetition penalties over seen bits, banned bits as ``(W,)`` and as
+    ``(B, W)``, sequence bans — and an exact tie planted across two
+    blocks, where the lowest id wins."""
+    from generativeaiexamples_tpu.models import llama
+    from generativeaiexamples_tpu.ops import head_argmax
+    D, vocab, block = 64, 128 * 11, 256
+    ks = jax.random.split(jax.random.key(rows), 8)
+    hn = jax.random.normal(ks[1], (rows, D), jnp.float32)
+    head = jax.random.normal(ks[0], (D, vocab), jnp.float32)
+    # row 0's best two columns, bit for bit the same, in blocks 1 and 4
+    lo, hi = 300, 1100
+    col = 10.0 * hn[0] / jnp.linalg.norm(hn[0])
+    head = head.at[:, lo].set(col).at[:, hi].set(col)
+    tree = _head_tree(storage.split("_")[0], head)
+    rows_in = hn
+    if storage == "int8_pre":   # the smoothing scale folds into the rows
+        pre = 1.0 + jax.random.uniform(ks[7], (D,))
+        tree = {"lm_head": dict(tree["lm_head"], pre_scale=pre)}
+        rows_in = hn * pre
+    assert head_argmax.supported(tree)
+    clear = jnp.ones((vocab,), bool).at[jnp.asarray([lo, hi])].set(False)
+    seen = jax.random.bernoulli(ks[2], 0.3, (rows, vocab)) & clear
+    banned = jax.random.bernoulli(
+        ks[3], 0.05, (rows, vocab) if ban_rows else (vocab,)) & clear
+    ban_tok = jax.random.randint(ks[5], (rows, 7), 0, lo)
+    kw = dict(rep_pen=1.0 + jax.random.uniform(ks[4], (rows,)),
+              seen_words=pack_mask(seen), banned_words=pack_mask(banned),
+              ban_tok=ban_tok,
+              ban_hit=jax.random.bernoulli(ks[6], 0.5, (rows, 7)))
+    got = head_argmax.greedy_head_argmax(hn, tree, vocab, block=block,
+                                         interpret=True, **kw)
+    scan_tree = {k: ({n: a for n, a in v.items() if n != "pre_scale"}
+                     if isinstance(v, dict) else v)
+                 for k, v in tree.items()}
+    want = fused_unembed_sample(
+        lambda t0, tile: llama.lm_head_tile(scan_tree, None, rows_in, t0,
+                                            tile),
+        vocab, key=None, temp=None, top_k=None, top_p=None, greedy=True,
+        tile=TILE, **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert int(got[0]) == lo        # the tie's lowest id, not block 4's
+    assert len(set(np.asarray(got).tolist())) > 1 or rows == 1
+
+
+def test_head_kernel_block_follows_the_heads_shape():
+    """The block is read off the shapes: a few MB of the stored head, a
+    power of two of lanes, at most one window of mask words."""
+    from generativeaiexamples_tpu.ops.head_argmax import block_width
+    assert [block_width(d, 1) for d in (2048, 2560, 3584, 4096, 6144,
+                                        7168)] == [
+        2048, 1024, 1024, 1024, 512, 512]
+    assert block_width(4096, 2) == 512      # a raw bf16 head
+    assert block_width(64, 4) == 4096 and block_width(1 << 16, 2) == 128
+
+
+@pytest.fixture
+def armed(monkeypatch):
+    """``jax.default_backend() == "tpu"`` for the gates, as
+    tests/test_chip_compile.py steers them: what an engine on the chip
+    decides, traced here and never run."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _tiny_engine(head=None, mesh=None):
+    from generativeaiexamples_tpu.engine import Engine, EngineConfig
+    from generativeaiexamples_tpu.models import llama
+    from generativeaiexamples_tpu.models.configs import LlamaConfig
+    from generativeaiexamples_tpu.models.tokenizer import ByteTokenizer
+    from generativeaiexamples_tpu.ops.quant import (quantize_tensor,
+                                                    quantize_tensor_grouped)
+    cfg = LlamaConfig(vocab_size=512, hidden_size=64, intermediate_size=128,
+                      num_layers=1, num_heads=4, num_kv_heads=2,
+                      head_dim=16, max_position_embeddings=128,
+                      tie_word_embeddings=False)
+    params = llama.init_params(cfg, jax.random.key(0), dtype=jnp.float32)
+    if head == "int8":
+        params["lm_head"] = quantize_tensor(params["lm_head"], bits=8)
+    elif head == "int4":
+        params["lm_head"] = quantize_tensor(params["lm_head"], bits=4)
+    elif head == "int4_grouped":
+        params["lm_head"] = quantize_tensor_grouped(params["lm_head"],
+                                                    group_size=32)
+    return Engine(params, cfg, ByteTokenizer(), EngineConfig(
+        max_slots=2, max_input_length=64, max_output_length=16,
+        prefill_buckets=(32,), max_prefill_bucket=32, page_size=16,
+        dtype="float32", max_queue=4), mesh=mesh)
+
+
+def _greedy_round_prims(eng):
+    fn = eng._make_round(eng._windows[0], 2, True, 2)
+    jaxpr = jax.make_jaxpr(fn)(eng.params, eng._state, jax.random.key(1),
+                               jnp.zeros((2,), jnp.int32)).jaxpr
+    prims = []
+    _walk_prims(jaxpr, prims)
+    return {p for p, path in prims if "/tail" in path}
+
+
+@pytest.mark.parametrize("head,kernel", [
+    ("int8", True), (None, True), ("int4", False), ("int4_grouped", False),
+    ("tp", False)])
+def test_which_greedy_tails_take_the_head_kernel(armed, head, kernel):
+    """On a TPU the greedy round over a per-column int8 or raw head
+    holds ONE ``pallas_call`` under scope ``tail`` and no scan; int4 and
+    grouped heads and the tp-sharded stream keep the scan, and say so in
+    ``stats["tail_kernel"]`` — which is no downgrade."""
+    mesh = None
+    if head == "tp":
+        from jax.sharding import Mesh
+        mesh = Mesh(np.asarray(jax.devices()[:2]), ("tp",))
+    eng = _tiny_engine(None if head == "tp" else head, mesh)
+    try:
+        assert eng._tail_kernel is kernel
+        assert eng.stats["tail_kernel"] == int(kernel)
+        assert eng.stats["downgrades"] == 0
+        in_tail = _greedy_round_prims(eng)
+        assert ("pallas_call" in in_tail) is kernel, in_tail
+        assert ("scan" in in_tail) is not kernel, in_tail
+    finally:
+        eng.stop()
+
+
+def test_sampled_round_lowers_as_before_the_head_kernel(select_engine):
+    """The sampled stream is not the kernel's: its lowered text at a toy
+    shape is the one the tree before ``ops/head_argmax.py`` gave (the
+    digest was taken on the parent commit), and an engine's sampled
+    round lowers to the same text whether its greedy tails take the
+    kernel or not."""
+    import hashlib
+    from generativeaiexamples_tpu.models import llama
+    from generativeaiexamples_tpu.ops.quant import quantize_tensor
+    D, vocab, R = 64, 1408, 4
+    tree = {"lm_head": quantize_tensor(jnp.ones((D, vocab), jnp.float32))}
+
+    def tail(tree, hn, key, temp, top_k, top_p, rep, seen, banned, ban_tok,
+             ban_hit):
+        return fused_unembed_sample(
+            lambda t0, tile: llama.lm_head_tile(tree, None, hn, t0, tile),
+            vocab, key=key, temp=temp, top_k=top_k, top_p=top_p,
+            rep_pen=rep, seen_words=seen, banned_words=banned,
+            ban_tok=ban_tok, ban_hit=ban_hit, tile=352, cand_k=8,
+            stats=True)
+
+    f = jnp.ones((R,), jnp.float32)
+    words = jnp.zeros((R, mask_words(vocab)), jnp.uint32)
+    text = jax.jit(tail).lower(
+        tree, jnp.ones((R, D)), jax.random.key(0), f,
+        jnp.zeros((R,), jnp.int32), f, f, words, words,
+        jnp.zeros((R, 7), jnp.int32), jnp.zeros((R, 7), bool)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "e8c38e58bbcb1251733475a843712b78f43a84629b94d9b185c0b77766fa7199")
+
+    eng, _, _ = select_engine
+    args = (eng.params, eng._state, jax.random.key(1),
+            jnp.zeros((2,), jnp.int32))
+    texts = []
+    for on in (False, True):
+        eng._tail_kernel = on
+        try:
+            texts.append(jax.jit(eng._make_round(
+                eng._windows[0], 2, False, 2)).lower(*args).as_text())
+        finally:
+            eng._tail_kernel = False
+    assert texts[0] == texts[1]
+
+
+def test_engine_serves_the_same_tokens_over_the_head_kernel(monkeypatch):
+    """End to end, the kernel interpreted: an engine whose greedy tails
+    take ``greedy_head_argmax`` — the one-shot admission's first token,
+    the final chunk's, and every decode round — serves the tokens the
+    scan's engine serves, under a repetition penalty and a banned
+    word."""
+    import functools
+    from generativeaiexamples_tpu.engine import SamplingParams
+    from generativeaiexamples_tpu.ops import head_argmax
+    monkeypatch.setattr(
+        head_argmax, "greedy_head_argmax", functools.partial(
+            head_argmax.greedy_head_argmax, interpret=True))
+    sampling = SamplingParams(max_tokens=6, top_k=1, ignore_eos=True,
+                              repetition_penalty=1.3, bad_words=["a"])
+    prompts = [[5, 6, 7, 8], list(range(3, 43))]   # one shot; two chunks
+    served = {}
+    for kernel in (False, True):
+        eng = _tiny_engine("int8")
+        eng._tail_kernel = kernel       # read when a program is traced
+        eng.start()
+        try:
+            assert eng.stats["tail_kernel"] == int(kernel)
+            streams = [eng.submit(p, sampling) for p in prompts]
+            for stream in streams:
+                for _ in stream:
+                    pass
+            served[kernel] = [stream.token_ids for stream in streams]
+        finally:
+            eng.stop()
+    assert served[True] == served[False]
+    assert all(len(t) == 6 for t in served[True])
