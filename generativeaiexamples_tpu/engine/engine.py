@@ -72,7 +72,8 @@ from ..ops import head_argmax
 from ..ops.fused_sampler import (choose_tile, fused_unembed_sample,
                                  fused_unembed_sample_tp,
                                  fused_verify_sample,
-                                 fused_verify_sample_tp, tp_shardable,
+                                 fused_verify_sample_tp,
+                                 head_kernel_sample, tp_shardable,
                                  verify_reference_tiled)
 from ..ops.sampling import (apply_repetition_penalty, mask_words,
                             pack_mask, pack_mask_np, sample, seen_mask,
@@ -230,10 +231,13 @@ _STATS_TEMPLATE = {
     # potential, which used to be a silent comment-only fallback.
     # (Mirrored as the ``engine_downgrades`` gauge.)
     "downgrades": 0,
-    # 1 where this engine's GREEDY tails run the head-streaming kernel
-    # (ops/head_argmax.py: a per-column int8, raw or tied head, off-mesh,
-    # on a TPU), 0 where they run the tile scan. Static per engine.
+    # 1 where this engine's decode tails, greedy AND sampled, run the
+    # head-streaming kernels (ops/head_argmax.py: a per-column int8, raw
+    # or tied head, off-mesh, on a TPU), 0 where they run the tile scan.
+    # Static per engine. ``tail_kernel_rounds``: the decode rounds
+    # dispatched whose tail ran one (every one of an armed engine's).
     "tail_kernel": 0,
+    "tail_kernel_rounds": 0,
     # Times prewarm() had to shrink the auto-sized KV pool because the
     # worst-case request did not fit (each one also logs an
     # ``engine_pool_shrink`` event). 0 on a healthy build: > 0 means
@@ -1057,9 +1061,10 @@ class Engine:
                     f"vocab_size={model_cfg.vocab_size} does not split "
                     f"over tp={tp_size} into whole 32-token mask words")
         self._fused_tail = want_fused
-        # A greedy tail over a head the kernel takes is ONE pass of
-        # ops/head_argmax.py (off-mesh, on a TPU). A head it does not
-        # take keeps the tile scan: today's path, no downgrade.
+        # A decode tail over a head the kernels take, greedy or
+        # sampled, is ONE pass of ops/head_argmax.py (off-mesh, on a
+        # TPU). A head they do not take keeps the tile scan: today's
+        # path, no downgrade.
         self._tail_kernel = (want_fused and mesh is None
                              and head_argmax.armed(
                                  llama.lm_head_subtree(self.params)))
@@ -1140,13 +1145,14 @@ class Engine:
                      rep_pen, seen_words, banned_words, ban_tok, ban_hit,
                      greedy: bool, stats: bool = False):
         """One fused unembed+sample call over already-normed hidden rows
-        ``ha`` (rows, D), routed to the single-chip tile stream (a
-        greedy one the head kernel where :attr:`_tail_kernel` holds) or
-        — on a tp mesh — the sharded stream whose per-chip carries merge
-        with one small collective (ops/fused_sampler.py). Traced inside
-        the decode/verify round programs. ``stats`` (sampled streams): also
-        the share of the tiles whose candidate merge sorted the tile
-        whole."""
+        ``ha`` (rows, D), routed to the head kernels where
+        :attr:`_tail_kernel` holds (``greedy_head_argmax``, or
+        ``head_kernel_sample`` for a sampled tail), else to the
+        single-chip tile stream or — on a tp mesh — the sharded stream
+        whose per-chip carries merge with one small collective
+        (ops/fused_sampler.py). Traced inside the decode/verify round
+        programs. ``stats`` (sampled streams): also the share of the
+        tiles whose candidate merge sorted the tile whole."""
         mcfg = self.model_cfg
         V = mcfg.vocab_size
         if self._tail_sharded:
@@ -1159,11 +1165,16 @@ class Engine:
                 rep_pen=rep_pen, seen_words=seen_words,
                 banned_words=banned_words, ban_tok=ban_tok,
                 ban_hit=ban_hit, greedy=greedy, stats=stats)
-        if greedy and self._tail_kernel:
-            return head_argmax.greedy_head_argmax(
-                ha, llama.lm_head_subtree(params), V, rep_pen=rep_pen,
-                seen_words=seen_words, banned_words=banned_words,
-                ban_tok=ban_tok, ban_hit=ban_hit)
+        if self._tail_kernel:
+            masks = dict(rep_pen=rep_pen, seen_words=seen_words,
+                         banned_words=banned_words, ban_tok=ban_tok,
+                         ban_hit=ban_hit)
+            if greedy:
+                return head_argmax.greedy_head_argmax(
+                    ha, llama.lm_head_subtree(params), V, **masks)
+            return head_kernel_sample(
+                ha, llama.lm_head_subtree(params), V, key=key, temp=temp,
+                top_k=top_k, top_p=top_p, stats=stats, **masks)
         return fused_unembed_sample(
             lambda t0, tile: llama.lm_head_tile(params, mcfg, ha, t0,
                                                 tile),
@@ -4909,6 +4920,8 @@ class Engine:
             # masquerading as a full-occupancy fused engine.
             self._bump("sampler_rows_sampled", ba * steps)
             self._bump("sampler_rows_skipped", (B - ba) * steps)
+        if self._tail_kernel:
+            self._bump("tail_kernel_rounds")
         try:
             # Async host copy: the harvest worker's np.asarray then finds
             # the round's tokens already on the host instead of paying a
@@ -5047,6 +5060,8 @@ class Engine:
             if self._fused_tail:
                 self._bump("sampler_rows_sampled", ba * S)
                 self._bump("sampler_rows_skipped", (B - ba) * S)
+            if self._tail_kernel and greedy:    # a sampled verify: the scan
+                self._bump("tail_kernel_rounds")
             try:
                 toks.copy_to_host_async()
                 acc.copy_to_host_async()

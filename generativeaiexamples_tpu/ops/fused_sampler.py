@@ -13,19 +13,30 @@ running state across tiles:
 - repetition penalty and bad-words masks are applied per tile, read from
   uint32 *bitfield* masks (``ops/sampling.py pack_mask``: 1 bit per
   token, sliced per tile — no (B, V) bool ever exists);
-- greedy is a running argmax (``_greedy_stream``) — HERE for int4 and
-  grouped heads, the tp-sharded stream and backends other than the TPU;
-  over a per-column int8, raw or tied head on a TPU the engine's greedy
-  tail is ``ops/head_argmax.py greedy_head_argmax`` instead: ONE Pallas
-  kernel that streams the stored head through VMEM once with the
-  penalties and the running argmax under the weight stream, the same
-  tokens;
+- greedy is a running argmax (``_greedy_stream``);
 - sampling uses the Gumbel-max formulation (``argmax(scaled + gumbel)``
   == categorical) with per-tile noise keyed by ``fold_in(key, tile)``,
   plus a running top-``cand_k`` of raw scaled values (the Gumbel-top-k
   carry) so top-k / top-p truncation can be resolved AFTER the stream
   from the candidate set alone, with an exact running logsumexp for the
   top-p mass. Full penalized logits never exist in any buffer.
+
+Which stream runs where. The SCAN of head slices above (``_greedy_stream``,
+``_sample_stream``, ``_verify_stream``) serves int4 and grouped heads,
+the tp-sharded streams, the verify stream of speculative decoding and
+every backend but the TPU. Over a head ``head_argmax.supported`` takes (a
+per-column int8, raw or tied head), off-mesh, on a TPU, the engine's
+decode tails are ONE Pallas pass over the stored head instead
+(``ops/head_argmax.py``): greedy ``greedy_head_argmax`` — penalties and
+the running argmax under the weight stream, the same tokens — and
+sampled :func:`head_kernel_sample` — ``sampled_head_stream`` carries the
+logsumexp, the Gumbel-max winner and the greedy winner under the weight
+stream and writes the rung's penalised scaled logits once, as ONE
+``(rows, V)`` float32 array (1.6 % of a 256k int8 head's bytes at 16
+rows), over whose tiles the candidate merge below then runs. Its noise
+is one field keyed by (key, row, token id) (:func:`row_gumbel`), so its
+tokens for a seed are another realisation of the same distribution than
+the scan's; its oracle is :func:`sample_reference_rows`.
 
 The candidate merge *selects before it sorts* (``_merge_tile``): a tile
 of thousands brings a handful of entrants, so each tile is reduced to
@@ -53,7 +64,8 @@ on ANY chip; the collective payload is O(B·cand_k), not O(B·V).
 Exactness: greedy, pure temperature sampling (no truncation), and any
 top-k/top-p whose kept prefix fits in ``cand_k`` candidates are
 *sample-exact* against :func:`sample_reference_tiled` (the materialized
-penalize-then-sample oracle sharing the same per-tile noise layout) —
+penalize-then-sample oracle sharing the same per-tile noise layout; the
+kernel tail against :func:`sample_reference_rows`, likewise) —
 pinned by tier-1 tests, sharded paths included (the tp stream consumes
 the same per-tile Gumbel field, indexed by GLOBAL tile number). A top-p
 set wider than ``cand_k`` tokens is truncated at ``cand_k`` (vLLM-style
@@ -67,6 +79,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from . import head_argmax
 from .sampling import MASK_BITS, NEG_INF, unpack_mask
 
 DEFAULT_TILE = 4096
@@ -592,6 +605,79 @@ def fused_unembed_sample(tile_logits_fn, vocab_size: int, *, key, temp,
     return (tok, resort) if stats else tok
 
 
+def _candidate_stream(scaled, noise, n_tiles: int, tile: int, R: int,
+                      cand_k: int):
+    """The candidate carry of :func:`_sample_stream` over tiles of the
+    ``scaled`` array the head kernel wrote (and the noise it added):
+    ``(cv, ci, cp, resort)``, bit for bit the carry of a stream that
+    made each tile from the head."""
+
+    def body(carry, t):
+        cv, ci, cp, n_resort = carry
+        t0 = t * tile
+        sc = jax.lax.dynamic_slice(scaled, (0, t0), (R, tile))
+        g = jax.lax.dynamic_slice(noise, (0, t0), (R, tile))
+        idb = jnp.broadcast_to(t0 + jnp.arange(tile, dtype=jnp.int32),
+                               sc.shape)
+        with jax.named_scope("tail_select"):
+            (cv, ci, cp), resorted = _merge_tile(cv, ci, cp, sc, idb,
+                                                 sc + g, cand_k)
+        return (cv, ci, cp, n_resort + resorted), None
+
+    init = (jnp.full((R, cand_k), -jnp.inf, jnp.float32),
+            jnp.zeros((R, cand_k), jnp.int32),
+            jnp.full((R, cand_k), -jnp.inf, jnp.float32),
+            jnp.int32(0))
+    (cv, ci, cp, n_resort), _ = jax.lax.scan(
+        body, init, jnp.arange(n_tiles, dtype=jnp.int32))
+    return cv, ci, cp, n_resort / n_tiles
+
+
+def row_gumbel(key, rows: int, vocab_size: int) -> jax.Array:
+    """The (rows, V) Gumbel field of the head kernel's sampled tail: row
+    ``r`` is ``gumbel(fold_in(key, r), (V,))`` — a function of the key,
+    the row and the token id alone, whatever the rows are padded to and
+    however wide a block is."""
+    return jax.vmap(lambda r: jax.random.gumbel(
+        jax.random.fold_in(key, r), (vocab_size,), jnp.float32))(
+            jnp.arange(rows, dtype=jnp.int32))
+
+
+@jax.named_scope("tail")
+def head_kernel_sample(hn, head_tree, vocab_size: int, *, key, temp, top_k,
+                       top_p, rep_pen, seen_words, banned_words,
+                       ban_tok=None, ban_hit=None, tile: int | None = None,
+                       cand_k: int | None = None, stats: bool = False,
+                       block: int | None = None, interpret: bool = False):
+    """:func:`fused_unembed_sample` (sampled) over a head
+    ``head_argmax.supported`` takes, the head streamed through VMEM ONCE:
+    ``ops/head_argmax.py sampled_head_stream`` leaves the logsumexp, the
+    Gumbel-max and greedy winners, and the penalised scaled logits of
+    the already-normed rows ``hn`` (rows, D) as one (rows, V) float32
+    array; the select-before-sort candidate merge then runs over tiles
+    of THAT array (:func:`_candidate_stream`), and the truncation is
+    resolved from the carry as ever. The noise is :func:`row_gumbel`,
+    drawn before the kernel and streamed in beside the head's blocks;
+    sample-exact against :func:`sample_reference_rows`. ``stats``: also
+    the share of the selection's tiles that took the whole sort."""
+    R = hn.shape[0]
+    tile = choose_tile(vocab_size, tile, sampled=True)
+    cand_k = cand_k or default_cand_k()
+    # drawn for the rows there are, laid out in the rows the kernel holds
+    noise = jnp.pad(row_gumbel(key, R, vocab_size), (
+        (0, head_argmax.padded_rows(R, hn.dtype.itemsize) - R), (0, 0)))
+    scaled, lse, bpid, brid = head_argmax.sampled_head_stream(
+        hn, head_tree, vocab_size, noise=noise, temp=temp, rep_pen=rep_pen,
+        seen_words=seen_words, banned_words=banned_words, ban_tok=ban_tok,
+        ban_hit=ban_hit, block=block, interpret=interpret)
+    cv, ci, cp, resort = _candidate_stream(
+        scaled, noise, vocab_size // tile, tile, R, cand_k)
+    tok = _finalize_sample(cv, ci, cp, lse, bpid, brid, temp=temp,
+                           top_k=top_k, top_p=top_p,
+                           vocab_size=vocab_size, cand_k=cand_k)
+    return (tok, resort) if stats else tok
+
+
 @jax.named_scope("tail")
 def fused_unembed_sample_tp(mesh, axis: str, head_tree, head_specs,
                             local_tile_fn, vocab_size: int, *, hn, key,
@@ -857,11 +943,28 @@ def tiled_gumbel(key, batch: int, vocab_size: int, tile: int) -> jax.Array:
 
 def sample_reference_tiled(logits, key, temp, top_k, top_p,
                            tile: int) -> jax.Array:
-    """Materialized penalize-then-sample oracle with the fused sampler's
-    noise layout: full (B, V) logits, stable descending sort, top-k /
-    top-p prefix keep, argmax over kept Gumbel-perturbed values. The
-    fused path must produce IDENTICAL tokens for the same key whenever
-    the kept prefix fits in its candidate carry (tier-1 pinned)."""
+    """Materialized penalize-then-sample oracle with the tile scan's
+    noise layout (:func:`tiled_gumbel`): the scan must produce IDENTICAL
+    tokens for the same key whenever the kept prefix fits in its
+    candidate carry (tier-1 pinned)."""
+    B, V = logits.shape
+    return _sample_reference(logits, tiled_gumbel(key, B, V, tile), temp,
+                             top_k, top_p)
+
+
+def sample_reference_rows(logits, key, temp, top_k, top_p) -> jax.Array:
+    """The same oracle with the head kernel's noise layout
+    (:func:`row_gumbel`): what :func:`head_kernel_sample` must return
+    token for token."""
+    B, V = logits.shape
+    return _sample_reference(logits, row_gumbel(key, B, V), temp, top_k,
+                             top_p)
+
+
+def _sample_reference(logits, noise, temp, top_k, top_p) -> jax.Array:
+    """Full (B, V) penalized logits and their (B, V) Gumbel field in:
+    stable descending sort, top-k / top-p prefix keep, argmax over kept
+    Gumbel-perturbed values."""
     B, V = logits.shape
     lf = logits.astype(jnp.float32)
     greedy_ids = jnp.argmax(lf, axis=-1).astype(jnp.int32)
@@ -881,7 +984,7 @@ def sample_reference_tiled(logits, key, temp, top_k, top_p,
     keep_p = jnp.zeros_like(keep).at[
         jnp.arange(B)[:, None], sort_idx
     ].set(sorted_keep_p)
-    pert = scaled + tiled_gumbel(key, B, V, tile)
+    pert = scaled + noise
     masked = jnp.where(keep & keep_p, pert, -jnp.inf)
     sampled = jnp.argmax(masked, axis=-1).astype(jnp.int32)
     is_greedy = (temp <= 0) | (top_k == 1)

@@ -261,6 +261,44 @@ def test_greedy_head_argmax_kernel_compiles(topo, D, V, rows, storage):
     assert compiled.memory_analysis().temp_size_in_bytes < (4 << 20)
 
 
+@pytest.mark.parametrize("D,V,rows,storage", [
+    (4096, 256000, 4, "int8"),      # nemotron-8b-chat: chat-steady's rung
+    (4096, 256000, 16, "int8"),     # ... and its widest
+    (2560, 151936, 5, "tied"),      # the (V, D) embedding, NT blocks
+])
+def test_sampled_head_kernel_compiles(topo, D, V, rows, storage):
+    """The sampled tail over the head kernel (ops/fused_sampler.py
+    ``head_kernel_sample``) at served widths: ONE Mosaic program streams
+    the head, and what it leaves in HBM is the noise it read and the
+    ``scaled`` array it wrote, a few (16, V) float32 arrays — no second
+    pass over the head, nothing the size of a head tile."""
+    from generativeaiexamples_tpu.ops.fused_sampler import head_kernel_sample
+    from generativeaiexamples_tpu.ops.sampling import mask_words
+    dev = SingleDeviceSharding(topo.devices[0])
+    tree = {"int8": {"lm_head": {"q": sds((D, V), jnp.int8, dev),
+                                 "scale": sds((V,), jnp.float32, dev)}},
+            "tied": {"embed": sds((V, D), jnp.bfloat16, dev)}}[storage]
+    words = sds((rows, mask_words(V)), jnp.uint32, dev)
+    f32 = sds((rows,), jnp.float32, dev)
+
+    def tail(hn, tree, key, temp, top_k, top_p, rep, seen, banned, ban_tok,
+             ban_hit):
+        return head_kernel_sample(
+            hn, tree, V, key=key, temp=temp, top_k=top_k, top_p=top_p,
+            rep_pen=rep, seen_words=seen, banned_words=banned,
+            ban_tok=ban_tok, ban_hit=ban_hit, stats=True)
+
+    compiled = jax.jit(tail).lower(
+        sds((rows, D), jnp.bfloat16, dev), tree,
+        jax.eval_shape(lambda: jax.random.key(0)), f32,
+        sds((rows,), jnp.int32, dev), f32, f32, words, words,
+        sds((rows, 7), jnp.int32, dev),
+        sds((rows, 7), jnp.bool_, dev)).compile()
+    text = compiled.as_text()
+    assert text.count('"sampled_head_stream"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * 16 * V * 4
+
+
 # ------------------------------------------------- engine step programs
 
 
@@ -334,6 +372,21 @@ def test_decode_round_program_compiles(engine, topo, tpu_backend):
     # the pool is donated and aliased in place: no second pool in temps
     pool_bytes = sum(v.nbytes for v in engine._state["cache"].values())
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
+def test_sampled_decode_round_program_compiles(engine, topo, tpu_backend):
+    """The SAMPLED round of the same engine: its tail is the sampled
+    head kernel, once a step, and no scan of head slices."""
+    params, state, dev = engine_args(engine, topo)
+    ba = 4
+    fn = engine._round_fn(engine._pmax, 8, False, ba)
+    compiled = fn.lower(params, state,
+                        jax.eval_shape(lambda: jax.random.key(0)),
+                        sds((ba,), jnp.int32, dev)).compile()
+    text = compiled.as_text()
+    assert '"sampled_head_stream"' in text
+    assert '"greedy_head_argmax"' not in text
+    assert_fits(compiled)
 
 
 def test_chunked_prefill_program_compiles(engine, topo, tpu_backend):

@@ -868,23 +868,51 @@ def _tiny_engine(head=None, mesh=None):
         dtype="float32", max_queue=4), mesh=mesh)
 
 
-def _greedy_round_prims(eng):
-    fn = eng._make_round(eng._windows[0], 2, True, 2)
+def _round_tail(eng, greedy):
+    """``(primitives under scope tail, primitives that read the head)``
+    of a decode round of ``eng``."""
+    fn = eng._make_round(eng._windows[0], 2, greedy, 2)
     jaxpr = jax.make_jaxpr(fn)(eng.params, eng._state, jax.random.key(1),
                                jnp.zeros((2,), jnp.int32)).jaxpr
     prims = []
     _walk_prims(jaxpr, prims)
-    return {p for p, path in prims if "/tail" in path}
+    head = eng.params["lm_head"]
+    head_shape = (head["q"] if isinstance(head, dict) and "q" in head
+                  else jax.tree.leaves(head)[0]).shape
+    readers = []
+    _walk_readers(jaxpr, head_shape, readers)
+    return {p for p, path in prims if "/tail" in path}, set(readers)
 
 
+def _walk_readers(jaxpr, shape, out, scope=""):
+    """Primitives under scope ``tail`` with an operand of ``shape``,
+    nested bodies included (a kernel's own body is the kernel's)."""
+    for eqn in jaxpr.eqns:
+        path = f"{scope}/{eqn.source_info.name_stack}"
+        if "/tail" in path and any(
+                getattr(v.aval, "shape", None) == shape
+                for v in eqn.invars):
+            out.append(eqn.primitive.name)
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for val in eqn.params.values():
+            for sub in _jaxprs_in(val):
+                _walk_readers(sub, shape, out, path)
+
+
+@pytest.mark.parametrize("greedy", [True, False], ids=["greedy", "sampled"])
 @pytest.mark.parametrize("head,kernel", [
     ("int8", True), (None, True), ("int4", False), ("int4_grouped", False),
     ("tp", False)])
-def test_which_greedy_tails_take_the_head_kernel(armed, head, kernel):
-    """On a TPU the greedy round over a per-column int8 or raw head
-    holds ONE ``pallas_call`` under scope ``tail`` and no scan; int4 and
-    grouped heads and the tp-sharded stream keep the scan, and say so in
-    ``stats["tail_kernel"]`` — which is no downgrade."""
+def test_which_greedy_tails_take_the_head_kernel(armed, head, kernel,
+                                                 greedy):
+    """On a TPU a decode round over a per-column int8 or raw head,
+    greedy or sampled, holds ONE ``pallas_call`` under scope ``tail``
+    and nothing else reads the head there (the sampled round's remaining
+    scan is the selection's, over the array the kernel wrote); int4 and
+    grouped heads and the tp-sharded stream keep the scan of head
+    slices, and say so in ``stats["tail_kernel"]`` — which is no
+    downgrade."""
     mesh = None
     if head == "tp":
         from jax.sharding import Mesh
@@ -894,19 +922,25 @@ def test_which_greedy_tails_take_the_head_kernel(armed, head, kernel):
         assert eng._tail_kernel is kernel
         assert eng.stats["tail_kernel"] == int(kernel)
         assert eng.stats["downgrades"] == 0
-        in_tail = _greedy_round_prims(eng)
+        in_tail, readers = _round_tail(eng, greedy)
         assert ("pallas_call" in in_tail) is kernel, in_tail
-        assert ("scan" in in_tail) is not kernel, in_tail
+        if kernel:
+            assert ("scan" in in_tail) is not greedy, in_tail
+            assert "pallas_call" in readers and "scan" not in readers
+        else:
+            assert "scan" in in_tail and "pallas_call" not in readers
     finally:
         eng.stop()
 
 
 def test_sampled_round_lowers_as_before_the_head_kernel(select_engine):
-    """The sampled stream is not the kernel's: its lowered text at a toy
-    shape is the one the tree before ``ops/head_argmax.py`` gave (the
-    digest was taken on the parent commit), and an engine's sampled
-    round lowers to the same text whether its greedy tails take the
-    kernel or not."""
+    """The sampled SCAN is what it was: its lowered text at a toy shape
+    is the one the tree before ``ops/head_argmax.py`` gave (the digest
+    was taken on PR 43's parent commit; int4 / grouped / tp / verify
+    streams and every backend but the TPU still run it). An engine's
+    sampled round lowers to that scan with ``_tail_kernel`` off — a scan
+    over the head under scope ``tail``, no ``pallas_call`` — and with it
+    on holds one ``pallas_call`` there, the only reader of the head."""
     import hashlib
     from generativeaiexamples_tpu.models import llama
     from generativeaiexamples_tpu.ops.quant import quantize_tensor
@@ -932,17 +966,16 @@ def test_sampled_round_lowers_as_before_the_head_kernel(select_engine):
         "e8c38e58bbcb1251733475a843712b78f43a84629b94d9b185c0b77766fa7199")
 
     eng, _, _ = select_engine
-    args = (eng.params, eng._state, jax.random.key(1),
-            jnp.zeros((2,), jnp.int32))
-    texts = []
-    for on in (False, True):
-        eng._tail_kernel = on
-        try:
-            texts.append(jax.jit(eng._make_round(
-                eng._windows[0], 2, False, 2)).lower(*args).as_text())
-        finally:
-            eng._tail_kernel = False
-    assert texts[0] == texts[1]
+    assert not eng._tail_kernel         # the CPU: the scan serves
+    in_tail, readers = _round_tail(eng, False)
+    assert "pallas_call" not in in_tail and "scan" in readers
+    eng._tail_kernel = True
+    try:
+        in_tail, readers = _round_tail(eng, False)
+    finally:
+        eng._tail_kernel = False
+    assert "pallas_call" in in_tail
+    assert readers == {"pallas_call"}, readers
 
 
 def test_engine_serves_the_same_tokens_over_the_head_kernel(monkeypatch):
