@@ -749,7 +749,7 @@ def run_multichip_sweep(params, model_cfg, tokenizer, rungs, *,
                 # Which tail actually served: the whole point of the
                 # sweep is that a mesh rung reads "fused_tp", not
                 # "materialized".
-                "tail": ("fused_tp" if engine._tail_sharded
+                "tail": ("fused_tp" if engine.programs.tail.kind == "sharded"
                          else "fused" if engine._fused_tail
                          else "materialized"),
                 "engine_downgrades": int(stats["downgrades"]),

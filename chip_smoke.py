@@ -278,7 +278,7 @@ def engine_report(engine) -> dict:
     return {
         "kernel_path": bool(engine._use_kernel),
         "fused_tail": bool(engine._fused_tail),
-        "tail": ("fused_tp" if engine._tail_sharded
+        "tail": ("fused_tp" if engine.programs.tail.kind == "sharded"
                  else "fused" if engine._fused_tail else "materialized"),
         "downgrades": stats["downgrades"],
         "pool_shrinks": stats["pool_shrinks"],

@@ -51,12 +51,11 @@ import queue
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Iterator, Optional, Sequence, Union
+from typing import Iterator, NamedTuple, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.layout import Format, Layout, with_layout_constraint
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
@@ -68,16 +67,7 @@ from ..obs import flight as obs_flight
 from ..obs import rounds as obs_rounds
 from ..obs.metrics import observe_stage
 from ..obs.tracing import phase, record_stage
-from ..ops import head_argmax
-from ..ops.fused_sampler import (choose_tile, fused_unembed_sample,
-                                 fused_unembed_sample_tp,
-                                 fused_verify_sample,
-                                 fused_verify_sample_tp,
-                                 head_kernel_sample, tp_shardable,
-                                 verify_reference_tiled)
-from ..ops.sampling import (apply_repetition_penalty, mask_words,
-                            pack_mask, pack_mask_np, sample, seen_mask,
-                            set_token_bits, unpack_mask)
+from ..ops.sampling import pack_mask_np
 from ..parallel.sharding import (llama_param_specs,
                                  shard_params)
 from ..utils import compile_cache, faults
@@ -86,9 +76,10 @@ from ..utils.errors import (ConfigError, EngineError, RoleMismatchError,
 from ..utils.hbm import peak_bw
 from ..utils.logging import get_logger, log_event
 from . import kv_tier as kv_tier_mod
+from . import programs
 from . import resume as engine_resume
 from .detokenizer import IncrementalDetokenizer, StopWordTrap
-from .kv_tier import BlockRecord, KVTier
+from .kv_tier import KVTier
 from .prefix_cache import PrefixCache, hash_blocks, usable_prefix_tokens
 from .sampling_params import SamplingParams
 from .scheduler import (OnlineCalibrator, PrefillJob, StepCostModel,
@@ -320,11 +311,6 @@ def engine_stat_keys() -> tuple[str, ...]:
             + tuple(CacheStats().snapshot()) + ("prefix_cache_pages",))
 
 
-def _row_major(ndim: int) -> Layout:
-    """Concrete row-major device layout for an ``ndim``-D pool leaf."""
-    return Layout(major_to_minor=tuple(range(ndim)))
-
-
 @functools.lru_cache(maxsize=64)
 def _zeros_program(shape: tuple, dtype, placement):
     """Jitted ``zeros`` born at ``placement`` (a sharding, or a Format
@@ -362,12 +348,9 @@ def weight_bytes_of(params, model_cfg: LlamaConfig) -> tuple[int, int]:
         return sum(int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize
                    for x in jax.tree.leaves(tree))
 
-    routed = 0
-    if model_cfg.num_experts and model_cfg.moe_impl == "dropless":
-        routed = sum(nbytes(params[stack][name])
-                     for stack, _, _ in model_cfg.layer_stacks
-                     if "router" in params[stack]
-                     for name in ("w_gate", "w_up", "w_down"))
+    routed = sum(nbytes(params[stack][name])
+                 for stack in programs.routed_stacks(params, model_cfg)
+                 for name in ("w_gate", "w_up", "w_down"))
     return nbytes(params), routed
 
 
@@ -712,15 +695,22 @@ class _Request:
         return self.stream.finish_reason is not None
 
 
+class _Chunk(NamedTuple):
+    """A grant cut into a request's next chunk (``Engine._next_chunk``)."""
+    n: int              # tokens it computes: whole pages unless final
+    padded: int         # the bucket they are padded to (0 where n is)
+    final: bool         # reaches the prompt's end: arms the slot
+    mode: str           # one-shot | first | middle | final (the span's)
+    seen: str           # seen-mask handling: seed | replace | accum
+    joins_rows: bool    # may run beside other prompts' in one program
+
+
 class Engine:
     """Continuous-batching engine over one model + mesh."""
 
-    # Device-side multi-token bad-words table shape: up to MAX_BAD_SEQS
-    # sequences per request, each up to MAX_BAD_LEN tokens. Static caps so
-    # the decode round's match is a fixed (B, W, L) compare — growing them
-    # recompiles, it does not reallocate per request.
-    MAX_BAD_SEQS = 8
-    MAX_BAD_LEN = 8
+    # The device-side bad-words table's static caps (engine/programs.py).
+    MAX_BAD_SEQS = programs.MAX_BAD_SEQS
+    MAX_BAD_LEN = programs.MAX_BAD_LEN
 
     def __init__(self, params: llama.Params, model_cfg: LlamaConfig,
                  tokenizer: Tokenizer, cfg: EngineConfig = EngineConfig(),
@@ -761,18 +751,9 @@ class Engine:
             {page_up(min(b, cap)) for b in cfg.prefill_buckets}
             | {page_up(cap)}))
 
-        # The rows a chunk program of several prompts may carry, largest
-        # first (_chunk_rows_fn). None under capacity routing: an expert's
-        # capacity is that of the tokens routed together, so other
-        # prompts' rows would change which assignments drop — a different
-        # result. Dense and dropless layers are row-independent. One rung:
-        # the program runs on an engine with no stream decoding
-        # (_execute_plan_inner), where a burst's plans hold many such
-        # grants and a rung of two would be met by the odd leftover only
-        # — a program that a warm-up cannot count on having built.
-        self._row_ladder = () if (
-            model_cfg.num_experts and model_cfg.moe_impl == "sparse") \
-            else (4,)
+        # the rows a chunk program of several prompts may carry
+        # (_execute_plan_inner holds such grants back for company)
+        self._row_ladder = programs.row_ladder(model_cfg)
 
         # pp>1 serving is a validated REJECTION, not a silent fallback:
         # every decode round runs all layers in ONE program, so pipeline
@@ -802,40 +783,32 @@ class Engine:
                 except ValueError as exc:
                     raise ConfigError(str(exc)) from exc
 
-        # The Pallas decode kernel has no SPMD partitioning rule, so mesh
-        # serving shard_maps it over tp when the head counts divide
-        # (models/llama.py:kernel_tp_compatible) and otherwise falls back
-        # to the jnp gather path. When the kernel is in play the pool
-        # layout is pinned row-major — without pinning, XLA keeps the
-        # pre-transpose physical layout and inserts a full-pool relayout
-        # copy (2x pool HBM) inside every decode round. Decided BEFORE
-        # pool sizing: the auto sizer's headroom reserve depends on
-        # whether the gather window ever materializes.
-        kernel_wanted = llama.use_paged_kernel(model_cfg, page)
-        self._use_kernel = (kernel_wanted
-                            and llama.kernel_tp_compatible(model_cfg, mesh))
-        if kernel_wanted and not self._use_kernel:
-            self._note_downgrade(
-                "paged_kernel", "jnp_gather",
-                f"mesh {dict(mesh.shape)} cannot shard_map the Pallas "
-                f"decode kernel (heads {model_cfg.num_heads}/"
-                f"{model_cfg.num_kv_heads} must divide tp, pp must be 1)")
-        self._pin_layouts = self._use_kernel
-        # A chunk program's attention as the Pallas chunk kernel
-        # (ops/chunk_attention.py), where the cache object has one
-        # (LatentKV): armed with the decode kernel, or a named downgrade.
-        has_prefix_kernel = hasattr(kv_cache_of(model_cfg),
-                                    "prefix_kernel_supported")
-        self._use_prefix_kernel = (
-            self._use_kernel and llama.use_prefix_kernel(model_cfg, page))
-        if (self._use_kernel and has_prefix_kernel
-                and not self._use_prefix_kernel):
-            self._note_downgrade(
-                "prefix_kernel", "jnp_blocks",
-                f"page {page}, key / value / rotary widths "
-                f"{model_cfg.qk_nope_head_dim} / {model_cfg.v_head_dim} / "
-                f"{model_cfg.qk_rope_head_dim}: the chunk kernel takes "
-                f"lane-width pages and keys, whole sublane tiles of values")
+        # What every device program is built from (engine/programs.py):
+        # the kernel gates and the tail, resolved once, a gate below the
+        # hardware's potential as a named downgrade — BEFORE pool
+        # sizing: the auto sizer's headroom reserve depends on whether
+        # the decode kernel's gather window ever materializes.
+        # Speculative decoding (engine/spec_decode.py): host-side
+        # prompt-lookup drafting + a batched verify round scoring
+        # S = max_draft + 1 positions per slot in ONE model step. Runs
+        # on single-chip AND tp-sharded engines: the verify tail rides
+        # the same tail as the decode round, with identical
+        # greedy-token / rejection-sampling distribution guarantees
+        # (parity re-pinned on a sharded engine). ENGINE_SPEC_DECODE=0
+        # restores the exact plain path.
+        self._spec: Optional[SpecConfig] = None
+        if spec_enabled(cfg.spec_decode):
+            self._spec = SpecConfig.resolve(cfg.spec_max_draft_tokens)
+        self._spec_S = (self._spec.max_draft_tokens + 1) if self._spec \
+            else 0
+        spec = programs.ProgramSpec.resolve(
+            self.params, model_cfg, page_size=page, max_slots=B,
+            pmax=self._pmax, dtype=self._dtype, mesh=mesh,
+            eos_id=int(tokenizer.eos_id), spec_S=self._spec_S)
+        for downgrade in spec.downgrades:
+            self._note_downgrade(*downgrade)
+        self.programs = programs.Programs(spec)
+        self._use_kernel = spec.use_kernel
 
         # Page pool: physical page 0 is the trash page (never allocated);
         # the allocator hands out 1..n_pages-1.
@@ -977,7 +950,6 @@ class Engine:
         self._window_share = (
             sum(1 for w in mc.layer_windows if w) / mc.num_layers
             if self._use_kernel else 0.0)
-        self._layer_stats = bool(self._expert_bytes) or bool(mc.hc_mult)
         dev0 = self._devices()[0]
         self._hbm_peak = 0.0 if dev0.platform == "cpu" else peak_bw(dev0)
         # Model-vs-measured drift: EWMA of (round wall / modeled round
@@ -1032,55 +1004,6 @@ class Engine:
         # Decode-attention page windows: power-of-two ladder up to the max.
         self._windows = _pow2_ladder(self._pmax)
 
-        # Fused vocab-tiled unembed+sampling tail (ops/fused_sampler.py).
-        # Under a tp mesh the lm_head shards over the vocab axis, so the
-        # tail runs SHARDED (fused_unembed_sample_tp): each chip streams
-        # its own vocab shard's 32-aligned tiles, folds penalties/masks
-        # locally, and the running argmax / Gumbel-top-k candidate carry
-        # + logsumexp merge with one small (B, cand_k) cross-chip
-        # collective at the end — (B, V) still never materializes on ANY
-        # chip (re-pinned by the sharded jaxpr memory proof). Geometries
-        # whose vocab cannot split into whole 32-token mask words per
-        # shard downgrade to the materialized tail — observably, via
-        # _note_downgrade, never as a silent comment-only fallback.
-        # ENGINE_FUSED_SAMPLER=0 forces the materialized tail anywhere
-        # (it doubles as the parity oracle in tests).
-        want_fused = os.environ.get("ENGINE_FUSED_SAMPLER", "1") != "0"
-        tp_size = (int(dict(mesh.shape).get("tp", 1))
-                   if mesh is not None else 1)
-        self._tail_sharded = False
-        self._head_specs = None
-        if want_fused and tp_size > 1:
-            if tp_shardable(model_cfg.vocab_size, tp_size):
-                self._tail_sharded = True
-                self._head_specs = llama.lm_head_specs(self.params, mesh)
-            else:
-                want_fused = False
-                self._note_downgrade(
-                    "fused_sampler", "materialized_tail",
-                    f"vocab_size={model_cfg.vocab_size} does not split "
-                    f"over tp={tp_size} into whole 32-token mask words")
-        self._fused_tail = want_fused
-        # A decode tail over a head the kernels take, greedy or
-        # sampled, is ONE pass of ops/head_argmax.py (off-mesh, on a
-        # TPU). A head they do not take keeps the tile scan: today's
-        # path, no downgrade.
-        self._tail_kernel = (want_fused and mesh is None
-                             and head_argmax.armed(
-                                 llama.lm_head_subtree(self.params)))
-        # Speculative decoding (engine/spec_decode.py): host-side
-        # prompt-lookup drafting + a batched verify round scoring
-        # S = max_draft + 1 positions per slot in ONE model step. Runs
-        # on single-chip AND tp-sharded engines: the verify tail rides
-        # the same fused (sharded) or materialized sampler path as the
-        # decode tail, with identical greedy-token / rejection-sampling
-        # distribution guarantees (parity re-pinned on a sharded
-        # engine). ENGINE_SPEC_DECODE=0 restores the exact plain path.
-        self._spec: Optional[SpecConfig] = None
-        if spec_enabled(cfg.spec_decode):
-            self._spec = SpecConfig.resolve(cfg.spec_max_draft_tokens)
-        self._spec_S = (self._spec.max_draft_tokens + 1) if self._spec \
-            else 0
         # Draft plan staged between _plan_round and _execute_plan
         # (serve-loop thread only): {slot: [draft token ids]}.
         self._draft_plan: Optional[dict] = None
@@ -1101,24 +1024,6 @@ class Engine:
         # a saturated engine re-stamps nothing until one more arrives.
         self._pulled = 0
         self._slot_stamped = -1
-        # Active-row ladder for the fused tail: decode rounds gather the
-        # armed slots into the smallest rung >= the live count, so the
-        # unembed/sampling tail is sized to OCCUPANCY, not max_slots.
-        # Two rungs only — {1, B} — on purpose: every rung multiplies
-        # the decode-round compile ladder (each (window, steps, greedy)
-        # variant recompiles per rung, seconds of serve-loop stall per
-        # crossing on a real model), while the tail's cost is dominated
-        # by the row-count-INDEPENDENT lm_head tile stream, so the
-        # single-stream rung captures nearly all the win. prewarm()
-        # compiles both rungs through the real serving path.
-        self._ba_ladder = (1, B) if B > 1 else (1,)
-
-        self._build_jitted()
-
-    def _ba_for(self, n: int) -> int:
-        """Smallest active-row rung covering ``n`` armed slots."""
-        n = max(1, n)
-        return next(b for b in self._ba_ladder if b >= n)
 
     def _note_downgrade(self, feature: str, fallback: str,
                         reason: str) -> None:
@@ -1139,127 +1044,15 @@ class Engine:
         """Construction-time feature downgrades (copies)."""
         return [dict(d) for d in self._downgrades]
 
-    # -------------------------------------------------- fused tail dispatch
-
-    def _tail_sample(self, params, ha, key, *, temp, top_k, top_p,
-                     rep_pen, seen_words, banned_words, ban_tok, ban_hit,
-                     greedy: bool, stats: bool = False):
-        """One fused unembed+sample call over already-normed hidden rows
-        ``ha`` (rows, D), routed to the head kernels where
-        :attr:`_tail_kernel` holds (``greedy_head_argmax``, or
-        ``head_kernel_sample`` for a sampled tail), else to the
-        single-chip tile stream or — on a tp mesh — the sharded stream
-        whose per-chip carries merge with one small collective
-        (ops/fused_sampler.py). Traced inside the decode/verify round
-        programs. ``stats`` (sampled streams): also the share of the
-        tiles whose candidate merge sorted the tile whole."""
-        mcfg = self.model_cfg
-        V = mcfg.vocab_size
-        if self._tail_sharded:
-            return fused_unembed_sample_tp(
-                self.mesh, "tp", llama.lm_head_subtree(params),
-                self._head_specs,
-                lambda head, rows, t0, tile: llama.lm_head_tile(
-                    head, mcfg, rows, t0, tile),
-                V, hn=ha, key=key, temp=temp, top_k=top_k, top_p=top_p,
-                rep_pen=rep_pen, seen_words=seen_words,
-                banned_words=banned_words, ban_tok=ban_tok,
-                ban_hit=ban_hit, greedy=greedy, stats=stats)
-        if self._tail_kernel:
-            masks = dict(rep_pen=rep_pen, seen_words=seen_words,
-                         banned_words=banned_words, ban_tok=ban_tok,
-                         ban_hit=ban_hit)
-            if greedy:
-                return head_argmax.greedy_head_argmax(
-                    ha, llama.lm_head_subtree(params), V, **masks)
-            return head_kernel_sample(
-                ha, llama.lm_head_subtree(params), V, key=key, temp=temp,
-                top_k=top_k, top_p=top_p, stats=stats, **masks)
-        return fused_unembed_sample(
-            lambda t0, tile: llama.lm_head_tile(params, mcfg, ha, t0,
-                                                tile),
-            V, key=key, temp=temp, top_k=top_k, top_p=top_p,
-            rep_pen=rep_pen, seen_words=seen_words,
-            banned_words=banned_words, ban_tok=ban_tok, ban_hit=ban_hit,
-            greedy=greedy, stats=stats)
-
-    def _tail_first(self, params, hn, rep_pen, seen_words, banned_words):
-        """A greedy request's FIRST token from its sampling position's
-        normed row ``hn`` (1, D), over the head kernel: the admission
-        programs' tail where :attr:`_tail_kernel` holds (one row of the
-        decode round's own greedy tail; no sequence ban can have matched
-        before a token is out)."""
-        return head_argmax.greedy_head_argmax(
-            hn, llama.lm_head_subtree(params), self.model_cfg.vocab_size,
-            rep_pen=rep_pen[None], seen_words=seen_words,
-            banned_words=banned_words)[0]
-
-    def _tail_verify(self, params, ha, key, u, *, temp, top_k, top_p,
-                     rep_pen, seen_words, banned_words, draft_ids,
-                     ban_tok, ban_hit):
-        """One fused verification call (rejection-sampling verdicts per
-        scored row) — same single-chip/sharded routing as
-        :meth:`_tail_sample`."""
-        mcfg = self.model_cfg
-        V = mcfg.vocab_size
-        if self._tail_sharded:
-            return fused_verify_sample_tp(
-                self.mesh, "tp", llama.lm_head_subtree(params),
-                self._head_specs,
-                lambda head, rows, t0, tile: llama.lm_head_tile(
-                    head, mcfg, rows, t0, tile),
-                V, hn=ha, key=key, u=u, temp=temp, top_k=top_k,
-                top_p=top_p, rep_pen=rep_pen, seen_words=seen_words,
-                banned_words=banned_words, draft_ids=draft_ids,
-                ban_tok=ban_tok, ban_hit=ban_hit)
-        return fused_verify_sample(
-            lambda t0, tile: llama.lm_head_tile(params, mcfg, ha, t0,
-                                                tile),
-            V, key=key, u=u, temp=temp, top_k=top_k, top_p=top_p,
-            rep_pen=rep_pen, seen_words=seen_words,
-            banned_words=banned_words, draft_ids=draft_ids,
-            ban_tok=ban_tok, ban_hit=ban_hit)
-
     def _init_device_state(self) -> dict:
         """Fresh device-side scheduler state (cache pool + slot arrays).
         Used at construction and by ``reset()`` after an abandoned loop —
         donated buffers from a wedged thread are unusable, so recovery
         means rebuilding, not reusing."""
-        B = self.cfg.max_slots
-        mcfg, mesh = self.model_cfg, self.mesh
-        # Distinct arrays per field: donated jit args must not alias.
-        state = {
-            "table": jnp.zeros((B, self._pmax), jnp.int32),
-            "pos": jnp.zeros((B,), jnp.int32),
-            "last_token": jnp.zeros((B,), jnp.int32),
-            "active": jnp.zeros((B,), bool),
-            "remaining": jnp.zeros((B,), jnp.int32),
-            "eos_ok": jnp.zeros((B,), bool),
-            "temp": jnp.zeros((B,), jnp.float32),
-            "top_k": jnp.zeros((B,), jnp.int32),
-            "top_p": jnp.zeros((B,), jnp.float32),
-            "rep_pen": jnp.ones((B,), jnp.float32),
-            # Seen/banned vocab masks as uint32 BITFIELDS (32 tokens per
-            # word, ops/sampling.py pack_mask): 1 bit per token instead
-            # of a byte-bool — 8x less mask state and per-step mask
-            # traffic, and the fused sampler slices whole words per
-            # vocab tile.
-            "seen": jnp.zeros((B, mask_words(mcfg.vocab_size)),
-                              jnp.uint32),
-            "banned": jnp.zeros((B, mask_words(mcfg.vocab_size)),
-                                jnp.uint32),
-            # Multi-token bad-words: per-slot sequence table (padded with
-            # -1), per-sequence lengths, and a ring of the last L-1
-            # generated tokens the match runs against. -1 padding can never
-            # equal a real token id, so "not enough history yet" needs no
-            # separate mask.
-            "bad_seq": jnp.full((B, self.MAX_BAD_SEQS, self.MAX_BAD_LEN),
-                                -1, jnp.int32),
-            "bad_len": jnp.zeros((B, self.MAX_BAD_SEQS), jnp.int32),
-            "recent": jnp.full((B, self.MAX_BAD_LEN - 1), -1, jnp.int32),
-        }
-        if mesh is not None:
-            state = {k: jax.device_put(v, NamedSharding(mesh, P()))
+        state = programs.slot_state(self.model_cfg.vocab_size,
+                                    self.cfg.max_slots, self._pmax)
+        if self.mesh is not None:
+            state = {k: jax.device_put(v, NamedSharding(self.mesh, P()))
                      for k, v in state.items()}
         state["cache"] = self._alloc_pool()
         return state
@@ -1330,27 +1123,9 @@ class Engine:
             shardings = dict.fromkeys(leaves, dev)
         return {k: _zeros_program(
                     leaf.shape, leaf.dtype,
-                    self._cache_placement(shardings[k], leaf.ndim))()
+                    programs.cache_placement(shardings[k], leaf.ndim,
+                                             self._use_kernel))()
                 for k, leaf in leaves.items()}
-
-    # ------------------------------------------------------------- layouts
-
-    def _cache_placement(self, sharding, ndim: int = 5):
-        """device_put target for pool leaves: row-major-pinned when the
-        Pallas kernel is in play, plain sharding otherwise. Scale pools
-        (int8-KV mode) are 4D; their layout pins row-major too."""
-        if not self._pin_layouts:
-            return sharding
-        return Format(_row_major(ndim), sharding)
-
-    def _pin_cache(self, cache):
-        """Constrain pool leaves to row-major inside a jitted program so
-        every producer hands the next program (and Pallas) the same
-        physical layout — no inter-program relayout copies."""
-        if not self._pin_layouts:
-            return cache
-        return {k: with_layout_constraint(v, _row_major(v.ndim))
-                for k, v in cache.items()}
 
     # -------------------------------------------------------------- sizing
 
@@ -1565,7 +1340,7 @@ class Engine:
             # multi-slot round so the first real occupancy crossing
             # doesn't pay that compile on the serve loop mid-traffic.
             dummies = 1
-            if self.cfg.max_slots > 1 and self._fused_tail:
+            if self.cfg.max_slots > 1 and self.programs.tail.gathers_rows:
                 pair = [self.submit(
                     ids[:min(16, len(ids))], _SP(
                         max_tokens=self.cfg.steps_per_round + 1,
@@ -1658,7 +1433,7 @@ class Engine:
         # Construction-time feature downgrades — derived from the list
         # (written once at build, before any reader exists).
         out["downgrades"] = len(self._downgrades)
-        out["tail_kernel"] = int(self._tail_kernel)
+        out["tail_kernel"] = int(self.programs.tail.kernel)
         # Model-vs-measured drift over completed rounds: 1.0 = the
         # step-cost model predicts round time; >1 = rounds run slower
         # than planned (regression, or a stale artifact prior); 0.0
@@ -1700,694 +1475,28 @@ class Engine:
         with self._stats_lock:
             self._stats[key] += n
 
-    # ------------------------------------------------------------------ jit
+    # ------------------------------------------------------ device programs
+    # live in engine/programs.py (``self.programs``). These are what
+    # BENCHMARK.json's files still read off an engine by its private
+    # name (ROADMAP D13): read-only forwards, nothing else.
 
-    def _build_jitted(self) -> None:
-        cfg, mcfg = self.cfg, self.model_cfg
-        page = cfg.page_size
-        eos = int(self.tokenizer.eos_id)
-        B = cfg.max_slots
-        kvc = kv_cache_of(mcfg)
+    @property
+    def _fused_tail(self) -> bool:
+        return self.programs.tail.kind != "materialised"
 
-        sp_mesh = (self.mesh is not None
-                   and int(dict(self.mesh.shape).get("sp", 1)) > 1)
+    @property
+    def _round_fns(self) -> dict:
+        return self.programs.round_fns
 
-        def prefill(params, tokens, length, temp, top_k, top_p, rep_pen,
-                    banned, key, greedy: bool):
-            """tokens: (1, S_bucket); returns (k,v) for the bucket, the
-            sampled first token, and the prompt's seen-token mask as a
-            (Wn,) uint32 bitfield. ``banned``: (Wn,) uint32 bad-words
-            bitfield (unpacked transiently here — admission runs once
-            per request; the per-STEP decode path never unpacks).
-            ``greedy`` is a trace-time flag: the greedy variant is a
-            pure argmax — no vocab sort on the TTFT-critical path.
-
-            Under a dp×sp mesh the forward is the RING-ATTENTION prefill
-            (llama.apply_prefill_sp): bucket activations shard over sp,
-            so prompts beyond one device's activation budget admit as a
-            single exact prefill — sp serving, not just sp scoring
-            (VERDICT r4 weak #9)."""
-            S = tokens.shape[1]
-            positions = jnp.arange(S, dtype=jnp.int32)[None, :]
-            # a greedy first token over the head kernel takes the
-            # sampling position's normed row: no (S, V) logits, no
-            # unpacked mask (never under a mesh)
-            head_kernel = greedy and self._tail_kernel
-            if sp_mesh:
-                k_new, v_new, last = llama.apply_prefill_sp(
-                    params, mcfg, tokens, positions, self.mesh, length)
-                # (L, 1, S, KV, hd) matches the dense cache layout below
-                cache = {"k": k_new, "v": v_new}
-                last = last[0]  # (V,)
-            else:
-                cache = llama.init_kv_cache(mcfg, 1, S, self._dtype)
-                out, cache = llama.apply(params, mcfg, tokens,
-                                         positions, cache,
-                                         kv_valid_len=length[None],
-                                         return_hidden=head_kernel)
-                last = jnp.take_along_axis(
-                    out,
-                    (length - 1)[None, None, None].astype(jnp.int32),
-                    axis=1)[0, 0]  # (V,) logits, or the normed row (D,)
-            seen = seen_mask(tokens, length[None], mcfg.vocab_size)  # (1, V)
-            if head_kernel:
-                first_tok = self._tail_first(
-                    params, last[None], rep_pen, pack_mask(seen), banned)
-            else:
-                last = apply_repetition_penalty(last[None, :], seen,
-                                                rep_pen[None])
-                last = jnp.where(
-                    unpack_mask(banned, mcfg.vocab_size)[None, :],
-                    -1e30, last)
-                if greedy:
-                    first_tok = jnp.argmax(last[0].astype(jnp.float32)
-                                           ).astype(jnp.int32)
-                else:
-                    first_tok = sample(last, key, temp[None], top_k[None],
-                                       top_p[None])[0]
-            seen = pack_mask(seen[0].at[first_tok].set(True))  # (Wn,) u32
-            return (*(cache[n] for n in kvc.leaves), first_tok, seen)
-
-        def insert(state, new, slot, length, first_tok,
-                   temp, top_k, top_p, rep_pen, seen, banned,
-                   bad_seq, bad_len, row, remaining, eos_ok):
-            """Scatter a prefilled bucket (``new``: the cache object's
-            leaves) into the slot's pages and arm the slot. ``row``: (Pmax,) physical page per logical page, padded
-            with 0 (trash) — bucket overhang beyond the allocated extent
-            lands in the trash page."""
-            dest = row[:new[0].shape[2] // page]
-            cache = kvc.insert_pages(state["cache"], *new, dest)
-            # Device-side finish state: a slot whose first token already
-            # ends it (eos, or max_tokens == 1) never activates.
-            active = (remaining > 0) & ~((first_tok == eos) & eos_ok)
-            return {
-                "cache": self._pin_cache(cache),
-                "table": state["table"].at[slot].set(row),
-                "pos": state["pos"].at[slot].set(length),
-                "last_token": state["last_token"].at[slot].set(first_tok),
-                "active": state["active"].at[slot].set(active),
-                "remaining": state["remaining"].at[slot].set(remaining),
-                "eos_ok": state["eos_ok"].at[slot].set(eos_ok),
-                "temp": state["temp"].at[slot].set(temp),
-                "top_k": state["top_k"].at[slot].set(top_k),
-                "top_p": state["top_p"].at[slot].set(top_p),
-                "rep_pen": state["rep_pen"].at[slot].set(rep_pen),
-                "seen": state["seen"].at[slot].set(seen),
-                "banned": state["banned"].at[slot].set(banned),
-                "bad_seq": state["bad_seq"].at[slot].set(bad_seq),
-                "bad_len": state["bad_len"].at[slot].set(bad_len),
-                # Sequence matching runs over *generated* tokens only (the
-                # reference bans output occurrences): fresh ring, seeded
-                # with the first sampled token.
-                "recent": state["recent"].at[slot].set(
-                    jnp.full((self.MAX_BAD_LEN - 1,), -1, jnp.int32)
-                    .at[-1].set(first_tok)),
-            }
-
-        def bad_seq_hits(seq, blen, recent):
-            """Multi-token bad-words: a sequence of length l is banned by
-            masking its LAST token whenever the l-1 most recent generated
-            tokens equal its prefix. Returns (hit (R, W) bool,
-            tail (R, W) int32) — the compare is (R, W, L) int32, noise
-            next to the vocab work around it."""
-            R, W_, Lb = seq.shape
-            slen = recent.shape[1]
-            j = jnp.arange(Lb, dtype=jnp.int32)
-            # seq position j aligns with ring index Lb - l + j
-            gi = jnp.clip(Lb - blen[..., None] + j, 0, slen - 1)
-            hist = jnp.take_along_axis(
-                jnp.broadcast_to(recent[:, None, :], (R, W_, slen)),
-                gi, axis=2)
-            need = j[None, None, :] < (blen[..., None] - 1)
-            hit = ((hist == seq) | ~need).all(-1) & (blen >= 2)
-            tail = jnp.take_along_axis(
-                seq, jnp.maximum(blen - 1, 0)[..., None], axis=2)[..., 0]
-            return hit, tail
-
-        def make_round(window: int, steps: int, greedy: bool, ba: int):
-            fused = self._fused_tail
-            V = mcfg.vocab_size
-            stat_names = self._round_stat_names(greedy)
-
-            def decode_round(params, state, key, act_idx):
-                """K decode steps fused in one dispatch; returns (K, B)
-                tokens with -1 for slots inactive at step entry. eos and
-                length termination happen on-device (``active`` drops), so
-                the host only needs one transfer per round.
-
-                ``act_idx``: (ba,) armed-slot indices, padded with B
-                (out of bounds: gathers clamp to a throwaway row, token
-                scatters drop). The FUSED tail gathers those rows and
-                runs the vocab-tiled unembed+sampler on (ba, …) shapes
-                only — a half-empty engine no longer unembeds max_slots
-                rows — and never materializes (B, V) penalized logits or
-                bool masks (ops/fused_sampler.py; under a tp mesh the
-                tile stream is SHARDED per chip with one small carry
-                merge — see _tail_sample). The materialized tail remains
-                for ENGINE_FUSED_SAMPLER=0 / downgraded geometries and
-                as the parity oracle; the greedy variant of either tail
-                is a pure argmax (no vocab sort / no sampling noise).
-
-                Where the program has scalars to report beside its
-                tokens (``_round_stat_names``) it returns ``(tokens,
-                {name: scalar})``, each the mean over the steps that had
-                a row to decode."""
-                def body(st, key_k):
-                    step_stats = {}
-                    pos, active = st["pos"], st["active"]
-                    page_of = jnp.take_along_axis(
-                        st["table"], (pos // page)[:, None], axis=1)[:, 0]
-                    wp = jnp.where(active, page_of, 0)  # inactive -> trash
-                    # Masked positions: the kernel's per-slot dynamic page
-                    # loop trips ceil(pos/page) times — an inactive slot
-                    # (pos -> 0) streams nothing, so dead slots cost no HBM.
-                    eff_pos = jnp.where(active, pos, 0)
-                    # dropless experts: idle slots touch no expert, and
-                    # the step returns llama.layer_stat_names (``aux``)
-                    moe = (dict(active=active, stats=True)
-                           if self._layer_stats else {})
-                    net, cache, *aux = llama.apply_decode_paged(
-                        params, mcfg, st["last_token"][:, None],
-                        eff_pos[:, None], st["cache"], st["table"][:, :window],
-                        pos + 1, wp, eff_pos % page,
-                        use_kernel=self._use_kernel, mesh=self.mesh,
-                        return_hidden=fused, **moe)
-                    if fused:
-                        hn = llama.unembed_norm(params, mcfg,
-                                                net[:, 0])       # (B, D)
-                        ha = hn[act_idx]                         # (ba, D)
-                        hit, tail = bad_seq_hits(st["bad_seq"][act_idx],
-                                                 st["bad_len"][act_idx],
-                                                 st["recent"][act_idx])
-                        tok_a = self._tail_sample(
-                            params, ha, key_k,
-                            temp=st["temp"][act_idx],
-                            top_k=st["top_k"][act_idx],
-                            top_p=st["top_p"][act_idx],
-                            rep_pen=st["rep_pen"][act_idx],
-                            seen_words=st["seen"][act_idx],
-                            banned_words=st["banned"][act_idx],
-                            ban_tok=tail, ban_hit=hit, greedy=greedy,
-                            stats=not greedy)
-                        if not greedy:
-                            tok_a, resort = tok_a
-                            step_stats["tail_resort_pct"] = 100.0 * resort
-                        # padding indices (== B) drop on scatter; rows not
-                        # in act_idx are inactive, so their (unused) token
-                        # defaults to 0 and every update below masks on
-                        # ``active``.
-                        tok = jnp.zeros((B,), jnp.int32).at[
-                            act_idx].set(tok_a)
-                    else:
-                        penalized = apply_repetition_penalty(
-                            net[:, 0], unpack_mask(st["seen"], V),
-                            st["rep_pen"])
-                        penalized = jnp.where(unpack_mask(st["banned"], V),
-                                              -1e30, penalized)
-                        hit, tail = bad_seq_hits(st["bad_seq"],
-                                                 st["bad_len"],
-                                                 st["recent"])
-                        penalized = penalized.at[
-                            jnp.arange(B)[:, None],
-                            jnp.where(hit, tail, 0)].min(
-                            jnp.where(hit, -1e30, jnp.inf).astype(
-                                penalized.dtype))
-                        if greedy:
-                            tok = jnp.argmax(penalized.astype(jnp.float32),
-                                             axis=-1).astype(jnp.int32)
-                        else:
-                            tok = sample(penalized, key_k, st["temp"],
-                                         st["top_k"], st["top_p"])
-                    emitted = jnp.where(active, tok, -1)
-                    remaining = jnp.where(active, st["remaining"] - 1,
-                                          st["remaining"])
-                    finished = active & (((tok == eos) & st["eos_ok"])
-                                         | (remaining <= 0))
-                    new_st = dict(
-                        st, cache=cache,
-                        pos=jnp.where(active, pos + 1, pos),
-                        last_token=jnp.where(active, tok, st["last_token"]),
-                        active=active & ~finished,
-                        remaining=remaining,
-                        seen=set_token_bits(st["seen"], tok, active),
-                        recent=jnp.where(
-                            active[:, None],
-                            jnp.concatenate([st["recent"][:, 1:],
-                                             tok[:, None]], axis=1),
-                            st["recent"]))
-                    if aux:     # the layers' scalars (llama.layer_stat_names)
-                        step_stats.update(aux[0])
-                    if step_stats:
-                        return new_st, (emitted, step_stats,
-                                        jnp.any(active))
-                    return new_st, emitted
-
-                state, toks = jax.lax.scan(body, state,
-                                           jax.random.split(key, steps))
-                state = dict(state, cache=self._pin_cache(state["cache"]))
-                if stat_names:
-                    # mean over the steps that had a row to decode
-                    toks, step_stats, live = toks
-                    n = jnp.maximum(jnp.sum(live), 1)
-                    return state, (toks, {
-                        name: jnp.sum(jnp.where(live, v, 0.0)) / n
-                        for name, v in step_stats.items()})
-                return state, toks
-            return decode_round
-
-        def make_verify(window: int, greedy: bool, ba: int):
-            """One speculative VERIFY round: score S = max_draft + 1
-            positions per slot (the last accepted token + up to S-1
-            prompt-lookup drafts) through one multi-token paged forward
-            (llama.apply_verify_paged), run the vocab-tiled sampler on
-            every scored row, and accept on-device — emitting, per
-            active slot, the longest agreed draft prefix plus one
-            correction/bonus token. Exactness: greedy keeps a draft iff
-            it equals the row's argmax (token-identical to sequential
-            decode); temperature>0 rows use exact rejection sampling
-            (fused_verify_sample), so the output DISTRIBUTION matches
-            the non-speculative sampler. Rollback is free: ``pos``
-            advances only past consumed inputs, so rejected drafts'
-            K/V rows are dead weight the next step overwrites — pages
-            never advance past the last accepted token.
-
-            Returns (state, ((S, B) emitted tokens with -1 padding —
-            the classic round grid shape, so the harvest loop is
-            shared — and (B,) accepted-draft counts for stats and the
-            adaptive-K controllers))."""
-            fused = self._fused_tail
-            V = mcfg.vocab_size
-            S = self._spec_S
-            slen = self.MAX_BAD_LEN - 1
-
-            def verify_round(params, state, key, act_idx, drafts, n_draft):
-                pos, active = state["pos"], state["active"]
-                offs = jnp.arange(S, dtype=jnp.int32)
-                eff_pos = jnp.where(active, pos, 0)
-                positions = eff_pos[:, None] + offs[None, :]      # (B, S)
-                tokens = jnp.concatenate(
-                    [state["last_token"][:, None], drafts], axis=1)
-                # Writes: inactive slots and rows past the slot's draft
-                # count land in the trash page.
-                write_ok = active[:, None] \
-                    & (offs[None, :] <= n_draft[:, None])
-                page_idx = jnp.clip(positions // page, 0, self._pmax - 1)
-                page_of = jnp.take_along_axis(state["table"], page_idx,
-                                              axis=1)
-                wp = jnp.where(write_ok, page_of, 0)
-                net, cache = llama.apply_verify_paged(
-                    params, mcfg, tokens, positions, state["cache"],
-                    state["table"][:, :window], eff_pos + S, wp,
-                    positions % page, return_hidden=fused)
-                # Per-position sampler state: the seen mask / recent
-                # ring row j would carry after accepting drafts 0..j-1 —
-                # exactly the sequential path's (rows are only consumed
-                # when every preceding draft was accepted).
-                seen_list = [state["seen"]]
-                recent_list = [state["recent"]]
-                for j in range(1, S):
-                    d = drafts[:, j - 1]
-                    on = active & (j <= n_draft)
-                    seen_list.append(set_token_bits(seen_list[-1], d, on))
-                    recent_list.append(jnp.where(
-                        on[:, None],
-                        jnp.concatenate([recent_list[-1][:, 1:],
-                                         d[:, None]], axis=1),
-                        recent_list[-1]))
-                seen_pos = jnp.stack(seen_list, axis=1)      # (B, S, Wn)
-                recent_pos = jnp.stack(recent_list, axis=1)  # (B, S, sl)
-                # Row j verifies draft j (the token at input j+1); -1 on
-                # the bonus row (j == n_draft) and padding rows.
-                drafts_ext = jnp.concatenate(
-                    [drafts, jnp.full((B, 1), -1, jnp.int32)], axis=1)
-                draft_grid = jnp.where(offs[None, :] < n_draft[:, None],
-                                       drafts_ext, -1)
-                key_g = jax.random.fold_in(key, 0)
-                key_u = jax.random.fold_in(key, 1)
-                if fused:
-                    hn = llama.unembed_norm(params, mcfg, net)  # (B,S,D)
-                    ha = hn[act_idx].reshape(ba * S, -1)
-                    hit, tail = bad_seq_hits(
-                        jnp.repeat(state["bad_seq"][act_idx], S, axis=0),
-                        jnp.repeat(state["bad_len"][act_idx], S, axis=0),
-                        recent_pos[act_idx].reshape(ba * S, slen))
-                    temp_r = jnp.repeat(state["temp"][act_idx], S)
-                    tk_r = jnp.repeat(state["top_k"][act_idx], S)
-                    tp_r = jnp.repeat(state["top_p"][act_idx], S)
-                    rp_r = jnp.repeat(state["rep_pen"][act_idx], S)
-                    seen_r = seen_pos[act_idx].reshape(ba * S, -1)
-                    ban_r = jnp.repeat(state["banned"][act_idx], S,
-                                       axis=0)
-                    draft_r = draft_grid[act_idx].reshape(ba * S)
-
-                    if greedy:
-                        tgt = self._tail_sample(
-                            params, ha, key_g, temp=temp_r,
-                            top_k=tk_r, top_p=tp_r, rep_pen=rp_r,
-                            seen_words=seen_r, banned_words=ban_r,
-                            ban_tok=tail, ban_hit=hit, greedy=True)
-                        acc_r, out_r = draft_r == tgt, tgt
-                    else:
-                        u = jax.random.uniform(key_u, (ba * S,))
-                        acc_r, out_r = self._tail_verify(
-                            params, ha, key_g, u, temp=temp_r,
-                            top_k=tk_r, top_p=tp_r, rep_pen=rp_r,
-                            seen_words=seen_r, banned_words=ban_r,
-                            draft_ids=draft_r, ban_tok=tail, ban_hit=hit)
-                    # padding indices (== B) drop on scatter
-                    acc_g = jnp.zeros((B, S), bool).at[act_idx].set(
-                        acc_r.reshape(ba, S))
-                    out_g = jnp.zeros((B, S), jnp.int32).at[act_idx].set(
-                        out_r.reshape(ba, S))
-                else:
-                    # Materialized tail (ENGINE_FUSED_SAMPLER=0): same
-                    # verdict rule from full (B*S, V) penalized logits.
-                    # Greedy verdicts are identical to the fused tail
-                    # at any occupancy; sampled verdicts share the
-                    # per-tile noise layout but index rows B*S-wide
-                    # where the fused tail indexes its act_idx-gathered
-                    # ba*S rows — identical draws only at FULL
-                    # occupancy (act_idx == arange(B)); elsewhere the
-                    # tails are distribution-identical, not
-                    # sample-identical.
-                    lf = net.reshape(B * S, V)
-                    pen = apply_repetition_penalty(
-                        lf, unpack_mask(seen_pos.reshape(B * S, -1), V),
-                        jnp.repeat(state["rep_pen"], S))
-                    pen = jnp.where(
-                        unpack_mask(jnp.repeat(state["banned"], S,
-                                               axis=0), V),
-                        -1e30, pen)
-                    hit, tail = bad_seq_hits(
-                        jnp.repeat(state["bad_seq"], S, axis=0),
-                        jnp.repeat(state["bad_len"], S, axis=0),
-                        recent_pos.reshape(B * S, slen))
-                    pen = pen.at[jnp.arange(B * S)[:, None],
-                                 jnp.where(hit, tail, 0)].min(
-                        jnp.where(hit, -1e30, jnp.inf).astype(pen.dtype))
-                    draft_r = draft_grid.reshape(B * S)
-                    if greedy:
-                        tgt = jnp.argmax(pen.astype(jnp.float32),
-                                         axis=-1).astype(jnp.int32)
-                        acc_r, out_r = draft_r == tgt, tgt
-                    else:
-                        u = jax.random.uniform(key_u, (B * S,))
-                        acc_r, out_r = verify_reference_tiled(
-                            pen, key_g, u,
-                            jnp.repeat(state["temp"], S),
-                            jnp.repeat(state["top_k"], S),
-                            jnp.repeat(state["top_p"], S),
-                            draft_r, tile=choose_tile(V, sampled=True))
-                    acc_g = acc_r.reshape(B, S)
-                    out_g = out_r.reshape(B, S)
-                # Longest agreed prefix, then the correction/bonus token
-                # from its first disagreeing (or bonus) row.
-                valid_draft = offs[None, :] < n_draft[:, None]
-                chain = jnp.cumprod(
-                    (acc_g & valid_draft).astype(jnp.int32), axis=1)
-                a = chain.sum(axis=1)        # (B,) accepted draft count
-                corr = jnp.take_along_axis(out_g, a[:, None], axis=1)
-                e = jnp.where(offs[None, :] < a[:, None], drafts_ext,
-                              corr)
-                # eos / length termination INSIDE the burst, mirroring
-                # the sequential device rule: the terminal token itself
-                # is emitted, nothing after it is.
-                rem0 = state["remaining"]
-                is_eos = (e == eos) & state["eos_ok"][:, None]
-                stop_j = is_eos \
-                    | ((rem0[:, None] - (offs[None, :] + 1)) <= 0)
-                no_stop_before = jnp.cumprod(jnp.concatenate(
-                    [jnp.ones((B, 1), jnp.int32),
-                     (~stop_j[:, :-1]).astype(jnp.int32)], axis=1),
-                    axis=1)
-                emit = ((offs[None, :] <= a[:, None])
-                        & (no_stop_before > 0) & active[:, None])
-                m = emit.sum(axis=1)
-                last_tok = jnp.take_along_axis(
-                    e, jnp.maximum(m - 1, 0)[:, None], axis=1)[:, 0]
-                finished = active & jnp.any(emit & stop_j, axis=1)
-                seen = state["seen"]
-                recent = state["recent"]
-                for j in range(S):
-                    on = emit[:, j]
-                    seen = set_token_bits(seen, e[:, j], on)
-                    recent = jnp.where(
-                        on[:, None],
-                        jnp.concatenate([recent[:, 1:], e[:, j:j + 1]],
-                                        axis=1),
-                        recent)
-                new_state = dict(
-                    state,
-                    cache=self._pin_cache(cache),
-                    # pos advances past CONSUMED inputs only — the
-                    # rewind invariant: never past the last accepted
-                    # token (+1 for the input that produced it).
-                    pos=jnp.where(active, pos + m, pos),
-                    last_token=jnp.where(active, last_tok,
-                                         state["last_token"]),
-                    active=active & ~finished,
-                    remaining=jnp.where(active, rem0 - m, rem0),
-                    seen=seen, recent=recent)
-                return new_state, (jnp.where(emit, e, -1).T,
-                                   jnp.where(active, a, 0)
-                                   .astype(jnp.int32))
-            return verify_round
-
-        def release(state, slot):
-            return dict(state, active=state["active"].at[slot].set(False))
-
-        def prefill_insert(state, params, tokens, length, slot, row,
-                           temp, top_k, top_p, rep_pen, banned, bad_seq,
-                           bad_len, key, remaining, eos_ok, greedy: bool):
-            """Admission as ONE dispatch: prefill + sample + scatter into
-            the slot's pages. Separate prefill/insert programs put two
-            program boundaries (and a bucket-KV hand-off) on the
-            TTFT-critical path."""
-            *new, first_tok, seen = prefill(
-                params, tokens, length, temp, top_k, top_p, rep_pen,
-                banned, key, greedy)
-            new_state = insert(state, new, slot, length, first_tok,
-                               temp, top_k, top_p, rep_pen, seen, banned,
-                               bad_seq, bad_len, row, remaining, eos_ok)
-            return new_state, first_tok
-
-        self._prefill_insert = jax.jit(prefill_insert, static_argnums=(16,),
-                                       donate_argnums=(0,))
-        self._prefill_insert_raw = prefill_insert  # for fused-RAG composition
-        self._release = jax.jit(release, donate_argnums=(0,))
-        self._make_round = make_round
-        self._make_verify = make_verify
-        self._round_fns: dict[tuple[int, int, bool], object] = {}
-        self._verify_fns: dict[tuple, object] = {}
-        self._chunk_fns: dict[tuple, object] = {}
-
-    def _round_stat_names(self, greedy: bool) -> tuple[str, ...]:
-        """The scalars a decode round program returns beside its tokens
-        (``RoundRecord`` attributes): the experts a dropless model's
-        rows touched, and the fused tail's share of whole-sort tiles —
-        of a SAMPLED round only; a greedy round has no candidate merge
-        and returns nothing new."""
-        return ((llama.layer_stat_names(self.model_cfg)
-                 if self._layer_stats else ())
-                + (("tail_resort_pct",)
-                   if self._fused_tail and not greedy else ()))
+    @property
+    def _chunk_fns(self) -> dict:
+        return self.programs.chunk_fns
 
     def _round_fn(self, window: int, steps: int, greedy: bool, ba: int):
-        key = (window, steps, greedy, ba)
-        fn = self._round_fns.get(key)
-        if fn is None:
-            fn = jax.jit(self._make_round(window, steps, greedy, ba),
-                         donate_argnums=(1,))
-            self._round_fns[key] = fn
-        return fn
-
-    def _verify_fn(self, window: int, greedy: bool, ba: int):
-        key = (window, greedy, ba)
-        fn = self._verify_fns.get(key)
-        if fn is None:
-            fn = jax.jit(self._make_verify(window, greedy, ba),
-                         donate_argnums=(1,))
-            self._verify_fns[key] = fn
-        return fn
-
-    # --------------------------------------------- long-prompt admission
-
-    def _chunk_seen(self, state, tokens, start, valid, slot, mode: str,
-                    seen0=None):
-        """Accumulate the slot's seen-token mask chunk by chunk (the
-        repetition-penalty state the one-shot prefill computes in one
-        go). ``mode``: "replace" (chunk 0 of a cold chunked admission —
-        drop the previous occupant's stale mask), "accum" (OR into the
-        slot's mask), or "seed" (chunk 0 of a prefix-cache hit: OR into
-        ``seen0``, the host-built PACKED mask over the cached prefix
-        tokens the chunks never revisit). All forms are uint32 bitfields
-        (ops/sampling.py pack_mask); OR on packed words == OR on the
-        bool masks they encode."""
-        C = tokens.shape[1]
-        in_chunk = jnp.clip(valid - start, 0, C)
-        chunk_seen = pack_mask(seen_mask(tokens, in_chunk[None],
-                                         self.model_cfg.vocab_size)[0])
-        if mode == "accum":
-            chunk_seen = state["seen"][slot] | chunk_seen
-        elif mode == "seed":
-            chunk_seen = seen0 | chunk_seen
-        return state["seen"].at[slot].set(chunk_seen)
+        return self.programs.round_fn(window, steps, greedy, ba)
 
     def _chunk_extend_fn(self, window: int, mode: str):
-        """Jitted ONE-CHUNK paged prefill: the chunk's KV lands in the
-        slot's pool pages and its attention reads the whole prefix back
-        from the pool (models/llama.py apply_prefill_paged) — used both
-        for longer-than-any-bucket prompts and for prefix-cache hits,
-        whose first chunk starts at the first uncached token. Non-final
-        chunks skip the vocab projection entirely. ``mode`` is the seen
-        handling (_chunk_seen); "seed" variants take the prefix mask as
-        an extra arg so the TTFT path stays a single dispatch per chunk."""
-        key = ("extend", window, mode)
-        fn = self._chunk_fns.get(key)
-        if fn is None:
-            mcfg = self.model_cfg
-
-            def extend(state, params, tokens, start, valid, slot, row_win,
-                       *seed):
-                C = tokens.shape[1]
-                positions = (start + jnp.arange(C, dtype=jnp.int32))[None, :]
-                _, cache = llama.apply_prefill_paged(
-                    params, mcfg, tokens, positions, state["cache"],
-                    row_win, valid[None], start // self.cfg.page_size,
-                    with_logits=False, use_kernel=self._use_prefix_kernel)
-                # Round-telemetry completion marker: a scalar OUTPUT
-                # that data-depends on the chunk's paged prefill, so a
-                # host readback of it blocks until this program has
-                # executed. Its buffer is NOT part of the donated state
-                # dict — it survives the next dispatch, unlike any ref
-                # into the returned state (which donation invalidates).
-                marker = cache[kv_cache_of(mcfg).leaves[0]][0, 0, 0, 0, 0]
-                return dict(state,
-                            cache=self._pin_cache(cache),
-                            seen=self._chunk_seen(state, tokens, start,
-                                                  valid, slot, mode,
-                                                  *seed)), marker
-
-            fn = jax.jit(extend, donate_argnums=(0,))
-            self._chunk_fns[key] = fn
-        return fn
-
-    def _chunk_rows_fn(self, rows: int):
-        """Jitted chunk program of SEVERAL prompts: ``rows`` whole
-        largest-bucket non-final chunks, a row a prompt, each at its own
-        start in its own slot (models/llama.py apply_prefill_paged over
-        B rows: the weights — a layer's experts above all — are read
-        once for all rows, attention runs a row at a time). Every row's
-        block table comes at the slot's full width (``_pmax``): blocks
-        past a row's start are skipped at run time, so neither a
-        member's window nor its seen handling is part of the key —
-        ``fresh`` (rows,) bool is ``_chunk_seen``'s "replace" (a cold
-        prompt's first chunk) against "accum", as data."""
-        key = ("extend_rows", rows)
-        fn = self._chunk_fns.get(key)
-        if fn is None:
-            mcfg = self.model_cfg
-
-            def extend(state, params, tokens, start, slot, tables, fresh):
-                C = tokens.shape[1]
-                positions = start[:, None] + jnp.arange(C, dtype=jnp.int32)
-                _, cache = llama.apply_prefill_paged(
-                    params, mcfg, tokens, positions, state["cache"],
-                    tables, start + C, start // self.cfg.page_size,
-                    with_logits=False, use_kernel=self._use_prefix_kernel)
-                # the completion marker of _chunk_extend_fn
-                marker = cache[kv_cache_of(mcfg).leaves[0]][0, 0, 0, 0, 0]
-                chunk_seen = pack_mask(seen_mask(
-                    tokens, jnp.full((rows,), C, jnp.int32),
-                    mcfg.vocab_size))
-                seen = jnp.where(fresh[:, None], chunk_seen,
-                                 state["seen"][slot] | chunk_seen)
-                return dict(state, cache=self._pin_cache(cache),
-                            seen=state["seen"].at[slot].set(seen)), marker
-
-            fn = jax.jit(extend, donate_argnums=(0,))
-            self._chunk_fns[key] = fn
-        return fn
-
-    def _chunk_final_fn(self, window: int, greedy: bool, seed: bool):
-        """The LAST chunk: paged prefill + first-token sample + slot
-        arming in one dispatch — insert()'s non-cache half (the chunk
-        loop already scattered all prompt KV). Only the sampling
-        position is unembedded, not the whole chunk. ``seed``: this is
-        ALSO the first chunk (single-chunk prefix-cache hit), so the
-        seen mask seeds from the host-built prefix mask instead of the
-        slot's accumulated one."""
-        key = ("final", window, greedy, seed)
-        fn = self._chunk_fns.get(key)
-        if fn is None:
-            mcfg = self.model_cfg
-            eos = int(self.tokenizer.eos_id)
-
-            def final(state, params, tokens, start, valid, slot, row,
-                      row_win, temp, top_k, top_p, rep_pen, banned,
-                      bad_seq, bad_len, key_, remaining, eos_ok, *seed0):
-                C = tokens.shape[1]
-                positions = (start + jnp.arange(C, dtype=jnp.int32))[None, :]
-                h, cache = llama.apply_prefill_paged(
-                    params, mcfg, tokens, positions, state["cache"],
-                    row_win, valid[None], start // self.cfg.page_size,
-                    with_logits=False, use_kernel=self._use_prefix_kernel)
-                seen = self._chunk_seen(state, tokens, start, valid, slot,
-                                        "seed" if seed else "accum",
-                                        *seed0)
-                idx = jnp.clip(valid - start - 1, 0, C - 1)
-                h_last = jnp.take_along_axis(
-                    h, idx[None, None, None].astype(jnp.int32), axis=1)
-                # Admission runs once per request — unpacking the packed
-                # masks transiently here is fine; the per-STEP decode
-                # path never unpacks.
-                V = mcfg.vocab_size
-                if greedy and self._tail_kernel:
-                    first_tok = self._tail_first(
-                        params, llama.unembed_norm(params, mcfg,
-                                                   h_last)[0],
-                        rep_pen, seen[slot][None], banned)
-                else:
-                    last = llama.unembed(params, mcfg, h_last)[0, 0]  # (V,)
-                    last = apply_repetition_penalty(
-                        last[None, :], unpack_mask(seen[slot], V)[None, :],
-                        rep_pen[None])
-                    last = jnp.where(unpack_mask(banned, V)[None, :],
-                                     -1e30, last)
-                    if greedy:
-                        first_tok = jnp.argmax(
-                            last[0].astype(jnp.float32)).astype(jnp.int32)
-                    else:
-                        first_tok = sample(last, key_, temp[None],
-                                           top_k[None], top_p[None])[0]
-                active = (remaining > 0) & ~((first_tok == eos) & eos_ok)
-                length = valid
-                return dict(
-                    state,
-                    cache=self._pin_cache(cache),
-                    table=state["table"].at[slot].set(row),
-                    pos=state["pos"].at[slot].set(length),
-                    last_token=state["last_token"].at[slot].set(first_tok),
-                    active=state["active"].at[slot].set(active),
-                    remaining=state["remaining"].at[slot].set(remaining),
-                    eos_ok=state["eos_ok"].at[slot].set(eos_ok),
-                    temp=state["temp"].at[slot].set(temp),
-                    top_k=state["top_k"].at[slot].set(top_k),
-                    top_p=state["top_p"].at[slot].set(top_p),
-                    rep_pen=state["rep_pen"].at[slot].set(rep_pen),
-                    seen=seen.at[jnp.asarray(slot)].set(
-                        set_token_bits(seen[slot][None], first_tok[None],
-                                       jnp.ones((1,), bool))[0]),
-                    banned=state["banned"].at[slot].set(banned),
-                    bad_seq=state["bad_seq"].at[slot].set(bad_seq),
-                    bad_len=state["bad_len"].at[slot].set(bad_len),
-                    recent=state["recent"].at[slot].set(
-                        jnp.full((self.MAX_BAD_LEN - 1,), -1, jnp.int32)
-                        .at[-1].set(first_tok))), first_tok
-
-            fn = jax.jit(final, donate_argnums=(0,))
-            self._chunk_fns[key] = fn
-        return fn
+        return self.programs.chunk_extend_fn(window, mode)
 
     # ------------------------------------------------------------- lifecycle
 
@@ -2626,7 +1735,7 @@ class Engine:
         # the free list is about to hand to the next occupant.
         for slot in list(self._slots):
             # device-side deactivate: safe here, the loop thread is joined
-            self._state = self._release(self._state, jnp.int32(slot))
+            self._state = self.programs.release(self._state, jnp.int32(slot))
         # Slot/page bookkeeping for streams the harvest worker already
         # finished but the scheduler never got to retire.
         while True:
@@ -2766,7 +1875,7 @@ class Engine:
                       greedy: bool):
             tokens, length, top_ids = fused.assemble(
                 enc_params, corpus, q_enc, q_llm, q_llm_len)
-            new_state, first = self._prefill_insert_raw(
+            new_state, first = self.programs.prefill_insert_raw(
                 state, params, tokens[None, :], length, slot, row, temp,
                 top_k, top_p, rep_pen, banned, bad_seq, bad_len, key,
                 remaining, eos_ok, greedy)
@@ -3177,7 +2286,8 @@ class Engine:
             def scatter(state, arrays, idx):
                 cache = {k: v.at[:, idx].set(arrays[k].astype(v.dtype))
                          for k, v in state["cache"].items()}
-                return dict(state, cache=self._pin_cache(cache))
+                return dict(state,
+                            cache=self.programs.spec.pin_cache(cache))
 
             self._gather_fn = jax.jit(gather)
             self._scatter_fn = jax.jit(scatter, donate_argnums=(0,))
@@ -3682,7 +2792,7 @@ class Engine:
                 # after a liveness re-check so a thread disowned mid-call
                 # can't clobber the rebuilt generation.
                 self._guard_live()
-                new_state = self._release(self._state, jnp.int32(req.slot))
+                new_state = self.programs.release(self._state, jnp.int32(req.slot))
                 self._guard_live()
                 self._state = new_state
             self._retire(req, finish)
@@ -4288,11 +3398,12 @@ class Engine:
                         break
                     ok = True
                     if rows_ok and req.slot < 0 \
-                            and self._joins_rows(req, grant):
+                            and self._next_chunk(req, grant).joins_rows:
                         # admitted apart from any program's span: the
                         # program it joins is not this request's alone
                         ok = self._begin_prefill(req, rec)
-                    if ok and rows_ok and self._joins_rows(req, grant):
+                    if ok and rows_ok \
+                            and self._next_chunk(req, grant).joins_rows:
                         if not self._prefill_aborted(req):
                             held.append(req)
                             if len(held) == self._row_ladder[0]:
@@ -4421,31 +3532,46 @@ class Engine:
             logger.debug("round completion accounting failed",
                          exc_info=True)
 
-    def _chunk_shape(self, req: _Request, grant: int) -> dict:
-        """Arguments of a ``chunk_dispatch`` span: the tokens this grant
-        will compute, the bucket they are padded to, and which chunk
-        program runs (``one-shot`` = the fused prefill+insert, ``first``
-        / ``middle`` = extend, ``final``; ``rows`` = the prompts the
-        program carries: 1 here, ``_execute_plan_inner`` names the
-        program of several itself, mode ``rows``). For a request not yet
-        admitted this is the plan's view, taken before the prefix
-        lookup: a prefix-cache hit shrinks the real chunk (the round
-        record's grants are exact)."""
+    def _next_chunk(self, req: _Request, grant: int) -> _Chunk:
+        """How ``grant`` tokens become ``req``'s next chunk: worked out
+        ONCE, here, for the span's arguments (``_chunk_shape``), the
+        choice of the program of several (``joins_rows``) and the
+        dispatch (``_advance_prefill``). For a request not yet admitted
+        this is the plan's view, taken before the prefix lookup: a
+        prefix-cache hit, found only then, shrinks the real chunk and
+        may seed it (the round record's grants are exact)."""
         if req.rag is not None:
             bucket = self._fused_rag.spec.bucket
-            return {"tokens": bucket, "padded": bucket, "mode": "one-shot",
-                    "rows": 1}
-        total, pos = len(req.prompt_ids), req.pf_pos
-        first = req.pf is None or pos == req.pf["start_tok"]
-        n = min(grant, total - pos, self._buckets[-1])
+            return _Chunk(bucket, bucket, True, "one-shot", "replace", False)
+        total, pos, pf = len(req.prompt_ids), req.pf_pos, req.pf
+        top = self._buckets[-1]
+        n = min(grant, total - pos, top)
         final = pos + n >= total
         if not final:
             n = (n // self.cfg.page_size) * self.cfg.page_size
-        mode = ("one-shot" if final and pos == 0
-                and total <= self._buckets[-1]
+        first = pf is None or pos == pf["start_tok"]
+        # the first chunk after a prefix-cache hit: its seen mask is
+        # seeded from the host's over the cached prefix
+        seeding = pf is not None and first and pf["seed"] is not None
+        mode = ("one-shot" if final and pos == 0 and total <= top
                 else "final" if final else "first" if first else "middle")
-        return {"tokens": n, "padded": self._bucket_for(n) if n > 0 else 0,
-                "mode": mode, "rows": 1}
+        return _Chunk(
+            n, self._bucket_for(n) if n > 0 else 0, final, mode,
+            "seed" if seeding else "replace" if pos == 0 else "accum",
+            # a whole largest bucket, not the prompt's last chunk, not
+            # seeded: what engages the program of several
+            # (programs.make_extend_rows) is then the plan itself, two
+            # such grants
+            bool(self._row_ladder) and grant >= top and total - pos > top
+            and not seeding)
+
+    def _chunk_shape(self, req: _Request, grant: int) -> dict:
+        """Arguments of a ``chunk_dispatch`` span (``rows`` = the
+        prompts the program carries: 1 here, ``_execute_plan_inner``
+        names the program of several itself, mode ``rows``)."""
+        chunk = self._next_chunk(req, grant)
+        return {"tokens": chunk.n, "padded": chunk.padded,
+                "mode": chunk.mode, "rows": 1}
 
     def _begin_prefill(self, req: _Request, rec=None):
         """Admission half 1: allocate the slot and pages, take prefix-
@@ -4642,26 +3768,11 @@ class Engine:
             return True
         return False
 
-    def _joins_rows(self, req: _Request, grant: int) -> bool:
-        """Whether this grant may run beside other prompts' in one
-        program (``_chunk_rows_fn``): a whole largest bucket, not the
-        prompt's last chunk, not the first after a prefix-cache hit
-        (its seen mask is seeded from the host). What engages the
-        program of several is then the plan itself: two such grants."""
-        if not self._row_ladder or req.rag is not None:
-            return False
-        C, pf = self._buckets[-1], req.pf
-        if grant < C or len(req.prompt_ids) - req.pf_pos <= C:
-            return False
-        # before admission the plan's view: a prefix-cache hit, found
-        # only then, may leave less than a bucket or a seeded chunk
-        return pf is None or not (pf["seed"] is not None
-                                  and req.pf_pos == pf["start_tok"])
-
     def _advance_prefill_rows(self, members: list):
         """``_advance_prefill`` for several prompts at once: each
         member's next whole largest-bucket chunk, non-final, in ONE
-        program (``_chunk_rows_fn``). Returns the completion marker."""
+        program (``programs.make_extend_rows``). Returns the completion
+        marker."""
         C = self._buckets[-1]
         faults.inject("engine.dispatch")  # chaos: slow/failed prefill
         t_chunk = time.monotonic()
@@ -4669,7 +3780,7 @@ class Engine:
                            for r in members], np.int32)
         start = np.asarray([r.pf_pos for r in members], np.int32)
         self._guard_live()
-        new_state, marker = self._chunk_rows_fn(len(members))(
+        new_state, marker = self.programs.chunk_rows_fn(len(members))(
             self._state, self.params, jnp.asarray(toks), jnp.asarray(start),
             jnp.asarray(np.asarray([r.slot for r in members], np.int32)),
             jnp.asarray(np.stack([r.pf["row"] for r in members])),
@@ -4683,12 +3794,6 @@ class Engine:
             req.pf_pos += C
         return marker
 
-    def _chunk_pad(self, n: int) -> int:
-        """Compiled shape for an ``n``-token chunk: the smallest prefill
-        bucket that covers it — chunk programs reuse the bucket ladder's
-        shapes, so interleaving adds no new compile geometries."""
-        return self._bucket_for(n)
-
     def _advance_prefill(self, req: _Request, grant: int,
                          rec=None) -> tuple[int, Optional[object]]:
         """Admission half 2, run once per round plan: dispatch ONE
@@ -4701,90 +3806,63 @@ class Engine:
         prompts whose whole extent fits the grant keep the ONE-dispatch
         fused prefill+insert path — the TTFT-critical case is still a
         single program."""
-        sp = req.params
         if req.rag is not None:
             return self._dispatch_rag(req, rec)
         pf = req.pf
         if self._prefill_aborted(req):
             return 0, None
-        total = len(req.prompt_ids)
-        page = self.cfg.page_size
-        n = min(grant, total - req.pf_pos, self._buckets[-1])
-        final = req.pf_pos + n >= total
-        if not final:
-            n = (n // page) * page
-            if n <= 0:
-                return 0, None
+        chunk = self._next_chunk(req, grant)
+        n, final = chunk.n, chunk.final
+        if n <= 0:
+            return 0, None
         faults.inject("engine.dispatch")  # chaos: slow/failed prefill
         t_chunk = time.monotonic()
-        key = pf["key"]
-        if final and req.pf_pos == 0 and total <= self._buckets[-1]:
+        ids = req.prompt_ids[req.pf_pos:req.pf_pos + n] \
+            + [0] * (chunk.padded - n)
+        toks = jnp.asarray(np.asarray(ids, np.int32)[None, :])
+        self._guard_live()
+        if chunk.mode == "one-shot":
             # Whole cold prompt in one grant: the classic fused
             # prefill+sample+insert dispatch (one program boundary on
-            # the TTFT path — see _build_jitted).
-            bucket = self._bucket_for(total)
-            ids = req.prompt_ids + [0] * (bucket - total)
-            tokens = jnp.asarray(np.asarray(ids, np.int32)[None, :])
-            self._guard_live()
-            new_state, first_tok = self._prefill_insert(
-                self._state, self.params, tokens, jnp.int32(total),
+            # the TTFT path — see programs.make_prefill_insert).
+            new_state, first_tok = self.programs.prefill_insert(
+                self._state, self.params, toks, jnp.int32(n),
                 jnp.int32(req.slot), jnp.asarray(pf["row"]),
-                jnp.float32(sp.temperature), jnp.int32(sp.top_k),
-                jnp.float32(sp.top_p), jnp.float32(sp.repetition_penalty),
-                pf["banned"], pf["bad_seq"], pf["bad_len"], key,
-                jnp.int32(req.eff_max - 1), jnp.bool_(not sp.ignore_eos),
-                req.greedy)
-            self._guard_live()
-            self._state = new_state
+                *self._sampling_args(req), req.greedy)
             marker = first_tok
         else:
-            C = self._chunk_pad(n)
-            chunk = req.prompt_ids[req.pf_pos:req.pf_pos + n] \
-                + [0] * (C - n)
-            toks = jnp.asarray(np.asarray(chunk, np.int32)[None, :])
-            start = jnp.int32(req.pf_pos)
-            valid = jnp.int32(req.pf_pos + n)
-            seeding = (req.pf_pos == pf["start_tok"]
-                       and pf["seed"] is not None)
-            self._guard_live()
+            args = (self._state, self.params, toks, jnp.int32(req.pf_pos),
+                    jnp.int32(req.pf_pos + n), jnp.int32(req.slot))
+            seed = (pf["seed"],) if chunk.seen == "seed" else ()
             if not final:
-                if seeding:
-                    new_state, marker = self._chunk_extend_fn(
-                        pf["window"], "seed")(
-                        self._state, self.params, toks, start, valid,
-                        jnp.int32(req.slot), pf["row_win"], pf["seed"])
-                else:
-                    mode = ("replace"
-                            if req.pf_pos == 0 and pf["start_tok"] == 0
-                            else "accum")
-                    new_state, marker = self._chunk_extend_fn(
-                        pf["window"], mode)(
-                        self._state, self.params, toks, start, valid,
-                        jnp.int32(req.slot), pf["row_win"])
+                new_state, marker = self.programs.chunk_extend_fn(
+                    pf["window"], chunk.seen)(*args, pf["row_win"], *seed)
                 first_tok = None
             else:
-                args = (self._state, self.params, toks, start, valid,
-                        jnp.int32(req.slot), jnp.asarray(pf["row"]),
-                        pf["row_win"], jnp.float32(sp.temperature),
-                        jnp.int32(sp.top_k), jnp.float32(sp.top_p),
-                        jnp.float32(sp.repetition_penalty), pf["banned"],
-                        pf["bad_seq"], pf["bad_len"], key,
-                        jnp.int32(req.eff_max - 1),
-                        jnp.bool_(not sp.ignore_eos))
-                if seeding:
-                    args = args + (pf["seed"],)
-                new_state, first_tok = self._chunk_final_fn(
-                    pf["window"], req.greedy, seeding)(*args)
+                new_state, first_tok = self.programs.chunk_final_fn(
+                    pf["window"], req.greedy, chunk.seen == "seed")(
+                    *args, jnp.asarray(pf["row"]), pf["row_win"],
+                    *self._sampling_args(req), *seed)
                 marker = first_tok
-            self._guard_live()
-            self._state = new_state
+        self._guard_live()
+        self._state = new_state
         t_done = time.monotonic()
         pf["dispatch_s"] += t_done - t_chunk
-        self._chunk_span(req, t_chunk, t_done, n, self._bucket_for(n))
+        self._chunk_span(req, t_chunk, t_done, n, chunk.padded)
         req.pf_pos += n
         if final:
             self._arm_slot(req, first_tok, rec)
         return n, marker
+
+    @staticmethod
+    def _sampling_args(req: _Request) -> tuple:
+        """The sampling state an admission program arms a slot with, in
+        the order ``prefill_insert`` and ``final`` take it."""
+        sp, pf = req.params, req.pf
+        return (jnp.float32(sp.temperature), jnp.int32(sp.top_k),
+                jnp.float32(sp.top_p), jnp.float32(sp.repetition_penalty),
+                pf["banned"], pf["bad_seq"], pf["bad_len"], pf["key"],
+                jnp.int32(req.eff_max - 1), jnp.bool_(not sp.ignore_eos))
 
     def _chunk_span(self, req: _Request, t0: float, t1: float,
                     tokens: int, padded: int) -> None:
@@ -4832,7 +3910,6 @@ class Engine:
         charges the whole assembled bucket against the round budget (a
         grant can't split an on-device assembly). Returns ``(tokens,
         completion marker)`` like ``_advance_prefill``."""
-        sp = req.params
         pf = req.pf
         faults.inject("engine.dispatch")  # chaos: slow/failed prefill
         t0 = time.monotonic()
@@ -4844,13 +3921,7 @@ class Engine:
             self._state, self.params, fused.enc_params,
             fused.corpus, jnp.asarray(q_enc), jnp.asarray(q_llm),
             jnp.int32(q_len), jnp.int32(req.slot),
-            jnp.asarray(pf["row"]),
-            jnp.float32(sp.temperature), jnp.int32(sp.top_k),
-            jnp.float32(sp.top_p),
-            jnp.float32(sp.repetition_penalty), pf["banned"],
-            pf["bad_seq"], pf["bad_len"], pf["key"],
-            jnp.int32(req.eff_max - 1), jnp.bool_(not sp.ignore_eos),
-            req.greedy)
+            jnp.asarray(pf["row"]), *self._sampling_args(req), req.greedy)
         self._guard_live()
         self._state = new_state
         t1 = time.monotonic()
@@ -4891,8 +3962,7 @@ class Engine:
         # indices == max_slots: gathers clamp, scatters drop). The
         # materialized tail (ENGINE_FUSED_SAMPLER=0 / downgraded
         # geometry) always runs full-width.
-        B = self.cfg.max_slots
-        ba = self._ba_for(len(members)) if self._fused_tail else B
+        ba = self.programs.ba_for(len(members))
         with phase("loop_dispatch",
                    round_id=-1 if rec is None else rec.round_id,
                    steps=steps, rows=len(members), ba=ba):
@@ -4907,21 +3977,14 @@ class Engine:
         key = jax.random.fold_in(self._base_key, next(self._step_counter))
         act = np.full((ba,), B, np.int32)
         act[:len(members)] = sorted(members)
-        new_state, toks = self._round_fn(window, steps, greedy, ba)(
+        new_state, toks = self.programs.round_fn(window, steps, greedy, ba)(
             self.params, self._state, key, jnp.asarray(act))
         round_stats = {}
-        if self._round_stat_names(greedy):
+        if self.programs.spec.round_stat_names(greedy):
             toks, round_stats = toks
         self._guard_live()  # reset() may have run while the round compiled
         self._state = new_state
-        if self._fused_tail:
-            # Documented as fused-tail occupancy (observability.md):
-            # materialized-tail runs leave both at 0 rather than
-            # masquerading as a full-occupancy fused engine.
-            self._bump("sampler_rows_sampled", ba * steps)
-            self._bump("sampler_rows_skipped", (B - ba) * steps)
-        if self._tail_kernel:
-            self._bump("tail_kernel_rounds")
+        self._count_tail(ba, steps, self.programs.tail.kernel)
         try:
             # Async host copy: the harvest worker's np.asarray then finds
             # the round's tokens already on the host instead of paying a
@@ -4966,15 +4029,36 @@ class Engine:
                 self._bump("kv_rows_read", read * steps)
         for req in members.values():
             req.proj_pos = min(req.proj_pos + steps, req.extent)
+        self._count_inflight()
+        self._assert_harvestable(toks)
+        # counted BEFORE the hand-off: the harvest worker can end a
+        # stream the moment it has the round, and whoever reads the
+        # stats then must find the round that ended it
+        self._bump("decode_steps", steps)
+        self._harvest_q.put(("round", members, toks, round_stats, rec))
+
+    def _count_tail(self, ba: int, rows_per_slot: int,
+                    kernel: bool) -> None:
+        """A dispatched round's tail: the rows it sampled and the slots'
+        rows its compaction skipped — fused-tail occupancy
+        (observability.md): the materialised tail leaves both at 0
+        rather than masquerading as a full-occupancy fused engine — and
+        whether a head kernel ran it."""
+        if self.programs.tail.gathers_rows:
+            self._bump("sampler_rows_sampled", ba * rows_per_slot)
+            self._bump("sampler_rows_skipped",
+                       (self.cfg.max_slots - ba) * rows_per_slot)
+        if kernel:
+            self._bump("tail_kernel_rounds")
+
+    def _count_inflight(self) -> None:
+        """One more round rides the device queue ahead of harvest."""
         with self._pipe_lock:
             self._inflight_rounds += 1
             depth = self._inflight_rounds
         with self._stats_lock:
             if depth > self._stats["dispatch_depth_peak"]:
                 self._stats["dispatch_depth_peak"] = depth
-        self._assert_harvestable(toks)
-        self._harvest_q.put(("round", members, toks, round_stats, rec))
-        self._bump("decode_steps", steps)
 
     def _step_weight_bytes(self, rows: int) -> float:
         """Weight bytes one decode step of ``rows`` rows streams: all of
@@ -5027,7 +4111,7 @@ class Engine:
                    for r in members.values())
         window = self._window_for(_ceil_div(need, page))
         greedy = all(r.greedy for r in members.values())
-        ba = self._ba_for(len(members)) if self._fused_tail else B
+        ba = self.programs.ba_for(len(members))
         act = np.full((ba,), B, np.int32)
         act[:len(members)] = sorted(members)
         draft_np = np.zeros((B, S - 1), np.int32)
@@ -5043,7 +4127,8 @@ class Engine:
                    steps=1, rows=len(members), ba=ba):
             key = jax.random.fold_in(self._base_key, next(self._step_counter))
             t0 = time.monotonic()
-            new_state, (toks, acc) = self._verify_fn(window, greedy, ba)(
+            new_state, (toks, acc) = self.programs.verify_fn(
+                window, greedy, ba)(
                 self.params, self._state, key, jnp.asarray(act),
                 jnp.asarray(draft_np), jnp.asarray(n_np))
             self._guard_live()  # reset() may have run while the round compiled
@@ -5057,11 +4142,8 @@ class Engine:
                 tl = req.stream.timeline
                 if tl is not None:
                     tl.stage("engine_verify", dt)
-            if self._fused_tail:
-                self._bump("sampler_rows_sampled", ba * S)
-                self._bump("sampler_rows_skipped", (B - ba) * S)
-            if self._tail_kernel and greedy:    # a sampled verify: the scan
-                self._bump("tail_kernel_rounds")
+            # a sampled verify: the scan
+            self._count_tail(ba, S, self.programs.tail.kernel and greedy)
             try:
                 toks.copy_to_host_async()
                 acc.copy_to_host_async()
@@ -5082,16 +4164,11 @@ class Engine:
                     + pages_per_step * page * self._kv_bytes_per_token())
             for req in members.values():
                 req.proj_pos = min(req.proj_pos + S, req.extent)
-            with self._pipe_lock:
-                self._inflight_rounds += 1
-                depth = self._inflight_rounds
-            with self._stats_lock:
-                if depth > self._stats["dispatch_depth_peak"]:
-                    self._stats["dispatch_depth_peak"] = depth
+            self._count_inflight()
             self._assert_harvestable(toks, acc)
-            self._harvest_q.put(("verify", members, toks, acc, drafted, rec))
-            self._bump("decode_steps")
+            self._bump("decode_steps")      # before the hand-off, as above
             self._bump("spec_verify_rounds")
+            self._harvest_q.put(("verify", members, toks, acc, drafted, rec))
         return True
 
     def _emit_token(self, req: _Request, token: int) -> None:

@@ -320,7 +320,7 @@ def engine(topo):
         prefill_buckets=(512, 1024), max_prefill_bucket=1024,
         kv_pool_tokens=8 * 1024, steps_per_round=8))
     assert eng._use_kernel and eng._fused_tail and not eng.downgrades
-    assert eng._tail_kernel     # a per-column int8 head: the kernel's
+    assert eng.programs.tail.kernel     # a per-column int8 head: the kernel's
     yield eng
     mp.undo()
 
@@ -344,7 +344,7 @@ def test_prefill_bucket_program_compiles(engine, topo, tpu_backend):
     i32 = sds((), jnp.int32, dev)
     f32 = sds((), jnp.float32, dev)
     from generativeaiexamples_tpu.ops.sampling import mask_words
-    compiled = engine._prefill_insert.lower(
+    compiled = engine.programs.prefill_insert.lower(
         state, params, sds((1, S), jnp.int32, dev), i32, i32,
         sds((engine._pmax,), jnp.int32, dev), f32, i32, f32, f32,
         sds((mask_words(CFG.vocab_size),), jnp.uint32, dev),

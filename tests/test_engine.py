@@ -376,7 +376,7 @@ def test_crash_during_prefill_fails_stream():
     def boom(*a, **k):
         raise RuntimeError("synthetic prefill crash")
 
-    eng._prefill_insert = boom
+    eng.programs.prefill_insert = boom
     with eng:
         stream = eng.submit(eng.tokenizer.encode("doomed"),
                             SamplingParams(max_tokens=4))

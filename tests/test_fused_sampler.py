@@ -9,6 +9,8 @@ truncation prefix fits the candidate carry — pinned here under fixed
 keys, mixed greedy/sampling rows, repetition penalties, bitfield bans
 and multi-token sequence bans."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -16,6 +18,7 @@ import jax
 import jax.extend.core
 import jax.numpy as jnp
 
+from generativeaiexamples_tpu.engine import programs
 from generativeaiexamples_tpu.ops.fused_sampler import (
     choose_tile, fused_unembed_sample, sample_reference_tiled)
 from generativeaiexamples_tpu.ops.sampling import (
@@ -223,7 +226,8 @@ def test_decode_round_never_materializes_vocab(monkeypatch):
     try:
         assert eng._fused_tail, "fused tail must be the default off-mesh"
         ba = 2
-        fn = eng._make_round(eng._windows[0], 2, False, ba)
+        fn = programs.make_round(eng.programs.spec, eng._windows[0], 2,
+                                 False, ba)
         jaxpr = jax.make_jaxpr(fn)(
             eng.params, eng._state, jax.random.key(1),
             jnp.zeros((ba,), jnp.int32)).jaxpr
@@ -683,7 +687,8 @@ def test_round_tail_holds_a_sort_only_where_it_samples(select_engine,
     exists anywhere in it."""
     eng, vocab, tile = select_engine
     ba = 2
-    fn = eng._make_round(eng._windows[0], 2, greedy, ba)
+    fn = programs.make_round(eng.programs.spec, eng._windows[0], 2, greedy,
+                             ba)
     jaxpr = jax.make_jaxpr(fn)(
         eng.params, eng._state, jax.random.key(1),
         jnp.zeros((ba,), jnp.int32)).jaxpr
@@ -715,6 +720,11 @@ def _serve(eng, sampling):
     for _ in stream:
         pass
     assert stream.finish_reason == "length"
+    # settle: the harvest worker ends the stream BEFORE it completes the
+    # round's record (its outcome, ``tail_resort_pct`` among it);
+    # stop() joins it, with the round it holds finished
+    eng.stop()
+    eng.start()
     return [r for r in eng.rounds.records()
             if r.round_id not in before and r.decode_slots]
 
@@ -722,7 +732,7 @@ def _serve(eng, sampling):
 def test_tail_resort_pct_is_absent_from_a_greedy_round(select_engine):
     from generativeaiexamples_tpu.engine import SamplingParams
     eng, _, _ = select_engine
-    assert eng._round_stat_names(True) == ()
+    assert eng.programs.spec.round_stat_names(True) == ()
     before = eng.stats["tail_resort_pct_rounds"]
     recs = _serve(eng, SamplingParams(max_tokens=5, top_k=1,
                                       ignore_eos=True))
@@ -737,7 +747,8 @@ def test_tail_resort_pct_reaches_record_stats_and_metrics(select_engine):
     from generativeaiexamples_tpu.engine import SamplingParams
     from generativeaiexamples_tpu.obs import metrics as obs_metrics
     eng, _, _ = select_engine
-    assert eng._round_stat_names(False) == ("tail_resort_pct",)
+    assert eng.programs.spec.round_stat_names(False) == (
+        "tail_resort_pct",)
     s0, n0 = (eng.stats["tail_resort_pct_sum"],
               eng.stats["tail_resort_pct_rounds"])
     recs = _serve(eng, SamplingParams(max_tokens=5, temperature=0.8,
@@ -868,10 +879,18 @@ def _tiny_engine(head=None, mesh=None):
         dtype="float32", max_queue=4), mesh=mesh)
 
 
-def _round_tail(eng, greedy):
+def _with_tail(eng, kind, **kernels):
+    """``eng``'s program spec under a tail of the kind the test means
+    (``scan`` or ``kernel``), its kernels as given."""
+    return dataclasses.replace(eng.programs.spec, tail=programs.Tail(
+        kind, eng.model_cfg, **kernels))
+
+
+def _round_tail(eng, greedy, spec=None):
     """``(primitives under scope tail, primitives that read the head)``
-    of a decode round of ``eng``."""
-    fn = eng._make_round(eng._windows[0], 2, greedy, 2)
+    of a decode round of ``eng`` (built from ``spec``, where given)."""
+    fn = programs.make_round(spec or eng.programs.spec, eng._windows[0],
+                             2, greedy, 2)
     jaxpr = jax.make_jaxpr(fn)(eng.params, eng._state, jax.random.key(1),
                                jnp.zeros((2,), jnp.int32)).jaxpr
     prims = []
@@ -919,7 +938,7 @@ def test_which_greedy_tails_take_the_head_kernel(armed, head, kernel,
         mesh = Mesh(np.asarray(jax.devices()[:2]), ("tp",))
     eng = _tiny_engine(None if head == "tp" else head, mesh)
     try:
-        assert eng._tail_kernel is kernel
+        assert eng.programs.tail.kernel is kernel
         assert eng.stats["tail_kernel"] == int(kernel)
         assert eng.stats["downgrades"] == 0
         in_tail, readers = _round_tail(eng, greedy)
@@ -938,9 +957,10 @@ def test_sampled_round_lowers_as_before_the_head_kernel(select_engine):
     is the one the tree before ``ops/head_argmax.py`` gave (the digest
     was taken on PR 43's parent commit; int4 / grouped / tp / verify
     streams and every backend but the TPU still run it). An engine's
-    sampled round lowers to that scan with ``_tail_kernel`` off — a scan
-    over the head under scope ``tail``, no ``pallas_call`` — and with it
-    on holds one ``pallas_call`` there, the only reader of the head."""
+    sampled round lowers to that scan under a ``scan`` tail — a scan
+    over the head under scope ``tail``, no ``pallas_call`` — and under a
+    ``kernel`` tail holds one ``pallas_call`` there, the only reader of
+    the head."""
     import hashlib
     from generativeaiexamples_tpu.models import llama
     from generativeaiexamples_tpu.ops.quant import quantize_tensor
@@ -966,19 +986,15 @@ def test_sampled_round_lowers_as_before_the_head_kernel(select_engine):
         "e8c38e58bbcb1251733475a843712b78f43a84629b94d9b185c0b77766fa7199")
 
     eng, _, _ = select_engine
-    assert not eng._tail_kernel         # the CPU: the scan serves
+    assert eng.programs.tail.kind == "scan"     # the CPU: the scan serves
     in_tail, readers = _round_tail(eng, False)
     assert "pallas_call" not in in_tail and "scan" in readers
-    eng._tail_kernel = True
-    try:
-        in_tail, readers = _round_tail(eng, False)
-    finally:
-        eng._tail_kernel = False
+    in_tail, readers = _round_tail(eng, False, _with_tail(eng, "kernel"))
     assert "pallas_call" in in_tail
     assert readers == {"pallas_call"}, readers
 
 
-def test_engine_serves_the_same_tokens_over_the_head_kernel(monkeypatch):
+def test_engine_serves_the_same_tokens_over_the_head_kernel():
     """End to end, the kernel interpreted: an engine whose greedy tails
     take ``greedy_head_argmax`` — the one-shot admission's first token,
     the final chunk's, and every decode round — serves the tokens the
@@ -987,16 +1003,16 @@ def test_engine_serves_the_same_tokens_over_the_head_kernel(monkeypatch):
     import functools
     from generativeaiexamples_tpu.engine import SamplingParams
     from generativeaiexamples_tpu.ops import head_argmax
-    monkeypatch.setattr(
-        head_argmax, "greedy_head_argmax", functools.partial(
-            head_argmax.greedy_head_argmax, interpret=True))
     sampling = SamplingParams(max_tokens=6, top_k=1, ignore_eos=True,
                               repetition_penalty=1.3, bad_words=["a"])
     prompts = [[5, 6, 7, 8], list(range(3, 43))]   # one shot; two chunks
     served = {}
     for kernel in (False, True):
         eng = _tiny_engine("int8")
-        eng._tail_kernel = kernel       # read when a program is traced
+        if kernel:      # the engine's programs, over the tail meant
+            eng.programs = programs.Programs(_with_tail(
+                eng, "kernel", greedy_kernel=functools.partial(
+                    head_argmax.greedy_head_argmax, interpret=True)))
         eng.start()
         try:
             assert eng.stats["tail_kernel"] == int(kernel)

@@ -7,6 +7,7 @@ serves the oracle's tokens over it — and the greedy kernel, whose helpers
 it shares, still lowers to the Mosaic program the parent commit's did."""
 
 import base64
+import dataclasses
 import functools
 import hashlib
 import json
@@ -209,16 +210,14 @@ def test_sampled_kernel_follows_the_softmax(top_k):
         np.abs(freq - p).max())
 
 
-def test_engine_serves_the_oracles_tokens_over_the_sampled_kernel(
-        monkeypatch):
+def test_engine_serves_the_oracles_tokens_over_the_sampled_kernel():
     """End to end, the kernel interpreted: an engine whose sampled decode
     rounds take ``head_kernel_sample`` serves the tokens of the same
     engine with the noise-matched materialised oracle as its tail, under
     a temperature, top-k with top-p (a kept prefix the carry holds), a
     repetition penalty and a banned word — and counts every decode round
     in ``tail_kernel_rounds``."""
-    from generativeaiexamples_tpu.engine import SamplingParams
-    from generativeaiexamples_tpu.engine import engine as engine_mod
+    from generativeaiexamples_tpu.engine import SamplingParams, programs
     from generativeaiexamples_tpu.ops.fused_sampler import _penalize_tile
 
     def oracle_tail(hn, head_tree, vocab, *, key, temp, top_k, top_p,
@@ -235,25 +234,31 @@ def test_engine_serves_the_oracles_tokens_over_the_sampled_kernel(
     prompts = [[5, 6, 7, 8], list(range(3, 43))]
     served, rounds = {}, {}
     for tail in ("kernel", "oracle"):
-        monkeypatch.setattr(
-            engine_mod, "head_kernel_sample",
-            functools.partial(head_kernel_sample, interpret=True)
-            if tail == "kernel" else oracle_tail)
         eng = _tiny_engine("int8")
-        eng._tail_kernel = True         # read when a program is traced
+        # the engine's programs, over the tail meant
+        eng.programs = programs.Programs(dataclasses.replace(
+            eng.programs.spec, tail=programs.Tail(
+                "kernel", eng.model_cfg, sample_kernel=functools.partial(
+                    head_kernel_sample, interpret=True)
+                if tail == "kernel" else oracle_tail)))
+        # both prompts wait BEFORE the loop starts, so its first pull
+        # admits them in one plan: a round's key is the engine's step
+        # counter, which admissions share, so a sampled stream's tokens
+        # follow from how the loop interleaved the two (ROADMAP D17) —
+        # here it must interleave them the same way for both tails
+        streams = [eng.submit(p, sampling) for p in prompts]
         eng.start()
         try:
-            streams = [eng.submit(p, sampling) for p in prompts]
             for stream in streams:
                 for _ in stream:
                     pass
-            served[tail] = [stream.token_ids for stream in streams]
-            stats = eng.stats
-            rounds[tail] = stats["tail_kernel_rounds"]
-            assert stats["tail_kernel"] == 1 and stats["downgrades"] == 0
-            assert stats["tail_resort_pct_rounds"] == rounds[tail] > 0
         finally:
             eng.stop()
+        served[tail] = [stream.token_ids for stream in streams]
+        stats = eng.stats       # settled: stop() joined loop and harvest
+        rounds[tail] = stats["tail_kernel_rounds"]
+        assert stats["tail_kernel"] == 1 and stats["downgrades"] == 0
+        assert stats["tail_resort_pct_rounds"] == rounds[tail] > 0
     assert served["kernel"] == served["oracle"]
     assert all(len(t) == 6 for t in served["kernel"])
     assert len({tuple(t) for t in served["kernel"]}) == 2

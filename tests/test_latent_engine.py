@@ -175,7 +175,7 @@ def test_an_engine_that_cannot_arm_the_chunk_kernel_says_so(params,
     of a key block: ONE downgrade, named ``prefix_kernel``."""
     monkeypatch.setenv("GENAI_TPU_PAGED_KERNEL", "1")
     eng = make_engine(params)
-    assert eng._use_kernel and not eng._use_prefix_kernel
+    assert eng._use_kernel and not eng.programs.spec.use_prefix_kernel
     assert [(d["feature"], d["fallback"]) for d in eng.downgrades] \
         == [("prefix_kernel", "jnp_blocks")]
     assert eng.stats["downgrades"] == 1
@@ -191,7 +191,7 @@ def test_a_fully_armed_engine_reports_no_downgrade(monkeypatch):
                               v_head_dim=128)
     p = llama.init_params(cfg, jax.random.key(5), dtype=jnp.float32)
     eng = Engine(p, cfg, ByteTokenizer(), EngineConfig(**ENGINE))
-    assert eng._use_kernel and eng._use_prefix_kernel
+    assert eng._use_kernel and eng.programs.spec.use_prefix_kernel
     assert eng.downgrades == [] and eng.stats["downgrades"] == 0
     ids = prompt(300, 3)
     with eng:

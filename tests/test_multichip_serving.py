@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from generativeaiexamples_tpu.engine import (Engine, EngineConfig,
                                              SamplingParams)
+from generativeaiexamples_tpu.engine import programs
 from generativeaiexamples_tpu.models import llama
 from generativeaiexamples_tpu.models.configs import LlamaConfig
 from generativeaiexamples_tpu.models.tokenizer import ByteTokenizer
@@ -87,7 +88,7 @@ def test_tp2_engine_token_identical_with_fused_sampler_and_spec(params):
         ref_stats = single.stats
 
     with Engine(params, CFG, tok, ecfg, mesh=_mesh(2)) as sharded:
-        assert sharded._fused_tail and sharded._tail_sharded
+        assert sharded.programs.tail.kind == "sharded"
         assert sharded._spec is not None, "spec must arm under a mesh"
         got = _chat_run(sharded, tok)
         stats = sharded.stats
@@ -124,7 +125,7 @@ def test_tp2_sharded_fused_vs_materialized_tail_parity(params,
 
     monkeypatch.delenv("ENGINE_FUSED_SAMPLER")
     with Engine(params, CFG, tok, ecfg, mesh=_mesh(2)) as fused:
-        assert fused._tail_sharded
+        assert fused.programs.tail.kind == "sharded"
         got = fused.submit(prompt, sp)
         got.text()
     assert got.token_ids == ref.token_ids
@@ -171,9 +172,10 @@ def test_sharded_rounds_never_materialize_vocab(params):
                               **ECFG),
                  mesh=_mesh(2))
     try:
-        assert eng._tail_sharded
+        assert eng.programs.tail.kind == "sharded"
         ba = 1
-        fn = eng._make_round(eng._windows[0], 2, False, ba)
+        fn = programs.make_round(eng.programs.spec, eng._windows[0], 2,
+                                 False, ba)
         jaxpr = jax.make_jaxpr(fn)(
             eng.params, eng._state, jax.random.key(1),
             jnp.zeros((ba,), jnp.int32)).jaxpr
@@ -187,7 +189,8 @@ def test_sharded_rounds_never_materialize_vocab(params):
 
         S = eng._spec_S
         B = eng.cfg.max_slots
-        vfn = eng._make_verify(eng._windows[0], False, ba)
+        vfn = programs.make_verify(eng.programs.spec, eng._windows[0],
+                                   False, ba)
         vjaxpr = jax.make_jaxpr(vfn)(
             eng.params, eng._state, jax.random.key(2),
             jnp.zeros((ba,), jnp.int32),
@@ -215,7 +218,7 @@ def test_unshardable_vocab_downgrades_observably(params, caplog):
         eng = Engine(params, CFG, tok, EngineConfig(**ECFG),
                      mesh=_mesh(4))
     try:
-        assert not eng._fused_tail and not eng._tail_sharded
+        assert eng.programs.tail.kind == "materialised"
         assert eng.stats["downgrades"] >= 1
         feats = [d["feature"] for d in eng.downgrades]
         assert "fused_sampler" in feats
@@ -243,7 +246,7 @@ def test_tp2_sampled_decode_serves_on_sharded_tail(params):
     tok = ByteTokenizer()
     with Engine(params, CFG, tok, EngineConfig(**ECFG),
                 mesh=_mesh(2)) as eng:
-        assert eng._tail_sharded
+        assert eng.programs.tail.kind == "sharded"
         s = eng.submit(tok.encode("sampled sharded tail"),
                        SamplingParams(max_tokens=8, temperature=0.9,
                                       top_k=12, top_p=0.9,

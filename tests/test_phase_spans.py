@@ -152,12 +152,13 @@ def traced(tmp_path_factory):
             return fn(*a)
         return call
 
-    for attr, name in (("_round_fn", "round"), ("_chunk_extend_fn", "extend"),
-                       ("_chunk_final_fn", "final")):
-        orig = getattr(eng, attr)
-        setattr(eng, attr, lambda *a, _o=orig, _n=name: recording(
+    progs = eng.programs        # where the loop takes its programs from
+    for attr, name in (("round_fn", "round"), ("chunk_extend_fn", "extend"),
+                       ("chunk_final_fn", "final")):
+        orig = getattr(progs, attr)
+        setattr(progs, attr, lambda *a, _o=orig, _n=name: recording(
             _o(*a), _n))
-    eng._prefill_insert = recording(eng._prefill_insert, "prefill_insert")
+    progs.prefill_insert = recording(progs.prefill_insert, "prefill_insert")
     eng.start()
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0

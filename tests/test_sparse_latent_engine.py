@@ -127,7 +127,7 @@ def kernel_engine(params):
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("GENAI_TPU_PAGED_KERNEL", "1")
         eng = make_engine(params)
-    assert eng._use_kernel and eng._use_prefix_kernel
+    assert eng._use_kernel and eng.programs.spec.use_prefix_kernel
     assert eng.downgrades == []
     eng.start()
     yield eng

@@ -30,6 +30,7 @@ import jax.extend.core
 import jax.numpy as jnp
 
 from generativeaiexamples_tpu.engine import Engine, EngineConfig, SamplingParams
+from generativeaiexamples_tpu.engine import programs
 from generativeaiexamples_tpu.engine.detokenizer import StopWordTrap
 from generativeaiexamples_tpu.engine.scheduler import StepCostModel
 from generativeaiexamples_tpu.engine.spec_decode import (
@@ -483,7 +484,8 @@ def test_verify_round_never_materializes_vocab(monkeypatch):
         assert eng._fused_tail and eng._spec is not None
         ba = 2
         S = eng._spec_S
-        fn = eng._make_verify(eng._windows[0], False, ba)
+        fn = programs.make_verify(eng.programs.spec, eng._windows[0],
+                                  False, ba)
         jaxpr = jax.make_jaxpr(fn)(
             eng.params, eng._state, jax.random.key(1),
             jnp.zeros((ba,), jnp.int32),

@@ -8,11 +8,13 @@ the ledger's ``breakdown.device_ops`` print (``copy.69``,
     JAX_PLATFORMS=cpu python tools/dump_hlo.py <config> \
         [--engine-layers N] [--pool-tokens T] [--out DIR]
 
-The same pieces as ``benchmarks/rehearse_compile.py`` (which prints
-memory only): an engine built on the CPU with zero weights
-``--engine-layers`` deep, its 8-step decode round (greedy and sampled),
-one 512-token chunk of the chunked prefill and the chunk program of four
-prompts' rows, lowered for the described chip. Writes
+The programs of ``benchmarks/rehearse_compile.py`` (which prints memory
+only) and the rest of the serving path's, built from
+``engine/programs.py`` and SHAPES — no engine, no weights —
+``--engine-layers`` deep: the 8-step decode round (greedy and sampled),
+a sampled verify round, one 512-token chunk of the chunked prefill, the
+chunk program of four prompts' rows, a final chunk and the one-shot
+admission, lowered for the described chip. Writes
 ``<out>/<config>.<program>.hlo.txt`` and prints
 one JSON line: each program's temporaries, what :func:`weight_report`
 finds in its text and, as ``pool_copies``, what :func:`pool_report` does
@@ -272,18 +274,22 @@ def pool_report(text: str, leaves: list[tuple[str, tuple]]) -> list[str]:
 
 def engine_programs(config_name: str, engine_layers: int,
                     pool_tokens: int = 16 * 1024):
-    """Yield (program name, compiled, pool leaves) for the decode rounds
-    and one chunk program of ``benchmarks/configs/<config_name>.json``;
-    the leaves are :func:`pool_report`'s (dtype name, shape) pairs."""
+    """Yield (program name, compiled, pool leaves) for every program
+    kind of ``benchmarks/configs/<config_name>.json`` — the decode rounds
+    (greedy and sampled), a sampled verify round, one chunk, the chunk
+    of several prompts' rows, a final chunk and the one-shot admission —
+    built from ``engine/programs.py`` and shapes alone: no engine, no
+    array. The leaves are :func:`pool_report`'s (dtype name, shape)
+    pairs."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
-    from jax.experimental.layout import Format, Layout
     from jax.sharding import SingleDeviceSharding
 
     from benchmarks.harness import spec, system
     from generativeaiexamples_tpu.models import llama
     from generativeaiexamples_tpu.ops.quant import quantize_params
+    from generativeaiexamples_tpu.ops.sampling import mask_words
 
     jax.config.update("jax_enable_compilation_cache", False)
     config = spec.load_json(os.path.join(spec.HERE, "configs",
@@ -302,45 +308,67 @@ def engine_programs(config_name: str, engine_layers: int,
         return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
 
     jax.default_backend = lambda: "tpu"        # arm the kernel gates
-    from generativeaiexamples_tpu.engine import Engine, EngineConfig
+    from generativeaiexamples_tpu.engine import EngineConfig, programs
+    from generativeaiexamples_tpu.engine.spec_decode import SpecConfig
     from generativeaiexamples_tpu.models.tokenizer import ByteTokenizer
 
     def make(k):
         p = llama.init_params(cfg, k, dtype=jnp.bfloat16)
         return quantize_params(p, quant) if quant else p
 
-    shapes = jax.eval_shape(make, jax.random.key(0))
-    params = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
-    e = dict(config["engine"])
-    e["kv_pool_tokens"] = pool_tokens
-    eng = Engine(params, cfg, ByteTokenizer(), EngineConfig(**e))
-    state = on({k: v for k, v in eng._state.items() if k != "cache"})
+    p_sds = on(jax.eval_shape(make, jax.random.key(0)))
+    ecfg = EngineConfig(**dict(config["engine"], kv_pool_tokens=pool_tokens))
+    B, page = ecfg.max_slots, ecfg.page_size
+    pmax = -(-ecfg.max_cache_len // page)
+    S = SpecConfig.resolve().max_draft_tokens + 1
+    progs = programs.Programs(programs.ProgramSpec.resolve(
+        p_sds, cfg, page_size=page, max_slots=B, pmax=pmax,
+        dtype=ecfg.dtype, mesh=None, eos_id=int(ByteTokenizer().eos_id),
+        spec_S=S))
+    n_pages = 1 + min(B * pmax, max(pmax, -(-pool_tokens // page)))
+    state = on(jax.eval_shape(
+        lambda: programs.slot_state(cfg.vocab_size, B, pmax)))
+    pool = jax.eval_shape(lambda: llama.init_paged_kv_cache(
+        cfg, n_pages, page, progs.spec.dtype,
+        quantized=bool(ecfg.kv_quant)))
     state["cache"] = {
-        k: sds(v.shape, v.dtype, Format(
-            Layout(major_to_minor=tuple(range(v.ndim))), dev))
-        for k, v in eng._state["cache"].items()}
-    leaves = [(v.dtype.name, v.shape) for v in eng._state["cache"].values()]
-    p_sds = on(eng.params)
+        k: sds(v.shape, v.dtype, programs.cache_placement(
+            dev, v.ndim, progs.spec.use_kernel)) for k, v in pool.items()}
+    leaves = [(v.dtype.name, v.shape) for v in pool.values()]
     key = jax.eval_shape(lambda: jax.random.key(0))
-    B = eng.cfg.max_slots
-    for greedy in (True, False):
-        fn = eng._round_fn(eng._pmax, 8, greedy, B)
-        yield (f"decode_round_{'greedy' if greedy else 'sampled'}",
-               fn.lower(p_sds, state, key,
-                        sds((B,), jnp.int32)).compile(), leaves)
-    window = eng._pmax
-    i32 = sds((), jnp.int32)
-    yield ("chunk_extend_512", eng._chunk_extend_fn(window, "accum").lower(
-        state, p_sds, sds((1, 512), jnp.int32), i32, i32, i32,
-        sds((1, window), jnp.int32)).compile(), leaves)
+    i32, f32 = sds((), jnp.int32), sds((), jnp.float32)
+    slots, window = sds((B,), jnp.int32), pmax
+    chunk = (state, p_sds, sds((1, 512), jnp.int32), i32, i32, i32)
+    # the sampling state an admission program arms a slot with
+    arming = (f32, i32, f32, f32,
+              sds((mask_words(cfg.vocab_size),), jnp.uint32),
+              sds((programs.MAX_BAD_SEQS, programs.MAX_BAD_LEN), jnp.int32),
+              sds((programs.MAX_BAD_SEQS,), jnp.int32), key, i32,
+              sds((), jnp.bool_))
+    walk = [(f"decode_round_{name}", progs.round_fn(pmax, 8, greedy, B),
+             (p_sds, state, key, slots))
+            for greedy, name in ((True, "greedy"), (False, "sampled"))]
+    walk += [
+        ("verify_round_sampled", progs.verify_fn(window, False, B),
+         (p_sds, state, key, slots, sds((B, S - 1), jnp.int32), slots)),
+        ("chunk_extend_512", progs.chunk_extend_fn(window, "accum"),
+         (*chunk, sds((1, window), jnp.int32)))]
     # the chunk program of several prompts, at its largest rung (none
     # under capacity routing)
-    for rows in eng._row_ladder[:1]:
+    for rows in programs.row_ladder(cfg)[:1]:
         vec = sds((rows,), jnp.int32)
-        yield (f"chunk_extend_rows{rows}", eng._chunk_rows_fn(rows).lower(
-            state, p_sds, sds((rows, 512), jnp.int32), vec, vec,
-            sds((rows, window), jnp.int32),
-            sds((rows,), jnp.bool_)).compile(), leaves)
+        walk.append((f"chunk_extend_rows{rows}", progs.chunk_rows_fn(rows),
+                     (state, p_sds, sds((rows, 512), jnp.int32), vec, vec,
+                      sds((rows, window), jnp.int32),
+                      sds((rows,), jnp.bool_))))
+    walk += [
+        ("chunk_final_512", progs.chunk_final_fn(window, True, False),
+         (*chunk, sds((pmax,), jnp.int32), sds((1, window), jnp.int32),
+          *arming)),
+        ("prefill_insert_512", progs.prefill_insert,
+         (*chunk[:3], i32, i32, sds((pmax,), jnp.int32), *arming, True))]
+    for name, fn, args in walk:
+        yield name, fn.lower(*args).compile(), leaves
 
 
 def main(argv=None) -> int:
