@@ -306,7 +306,8 @@ def engine_stat_keys() -> tuple[str, ...]:
                "spec_acceptance_rate", "spec_tokens_per_step",
                "sched_cost_drift_ratio",
                "kv_tier_host_pages", "kv_restore_hit_rate",
-               "kv_bytes_per_token", "index_bytes_per_token", "uptime_s")
+               "kv_bytes_per_token", "index_bytes_per_token",
+               "state_bytes", "slot_bytes", "prefix_cache_off", "uptime_s")
             + _BUILD_LOG_KEYS
             + tuple(CacheStats().snapshot()) + ("prefix_cache_pages",))
 
@@ -816,8 +817,15 @@ class Engine:
         self._free_pages = list(range(1, self._n_pages))
         # Shared-prefix page reuse over the pool above. Mutated only on
         # the serve-loop thread; reset() swaps in a fresh instance.
-        self._prefix_cache = (PrefixCache(page) if cfg.prefix_cache
-                              else None)
+        # ... but not beside a recurrent state: a hit skips the chunks
+        # that would have computed it (a snapshot a block is not kept),
+        # so the cache is OFF for such a model, whatever the
+        # configuration asks, and says so (``stats["prefix_cache_off"]``)
+        self._prefix_cache_off = bool(cfg.prefix_cache
+                                      and model_cfg.recurrent)
+        self._prefix_cache = (
+            PrefixCache(page)
+            if cfg.prefix_cache and not self._prefix_cache_off else None)
         # Tiered KV store (engine/kv_tier.py): env beats config beats
         # the disabled default — with 0 the tier object never exists
         # and every tier code path below is skipped, preserving the
@@ -1061,48 +1069,68 @@ class Engine:
     def _refuse_unsupported(mcfg: LlamaConfig, cfg: "EngineConfig",
                             mesh: Optional[Mesh]) -> None:
         """What cannot take a latent pool (``kv_lora_rank``), an expert
-        share (``experts_held``) or hyper-connection streams
-        (``hc_mult``) yet says so by name, here, before anything is
-        built (docs/support-matrix.md lists them)."""
-        axes = dict(mesh.shape) if mesh is not None else {}
+        share (``experts_held``), hyper-connection streams (``hc_mult``)
+        or a recurrent state (``full_attention_interval``) yet says so by
+        name, here, before anything is built (docs/support-matrix.md
+        lists them)."""
+        axes = {k: int(v) for k, v in
+                (dict(mesh.shape) if mesh is not None else {}).items()}
         host = (os.environ.get("KV_HOST_POOL_TOKENS", "")
                 or cfg.kv_host_pool_tokens or 0)
         role = (os.environ.get("ENGINE_ROLE", "") or cfg.role or "unified"
                 ).strip().lower()
-        refused = []
+        tier = [
+            (int(host) > 0, "the host KV tier (kv_host_pool_tokens / "
+             "KV_HOST_POOL_TOKENS): engine/kv_tier.py keys and stacks host "
+             "blobs on per-head K and V pages"),
+            (role != "unified", f"role {role!r}: prefill/decode handoff "
+             f"ships kv_tier blobs"),
+        ]
+        refused: dict[str, list] = {}
         if mcfg.kv_lora_rank:
-            refused += [
+            refused[f"a latent KV pool (kv_lora_rank={mcfg.kv_lora_rank})"] \
+                = [
                 (cfg.kv_quant, "an int8 KV pool (kv_quant): the latent "
                  "row is key and value at once and has no scale plane"),
-                (int(axes.get("tp", 1)) > 1, "a tp mesh: the latent is "
+                (axes.get("tp", 1) > 1, "a tp mesh: the latent is "
                  "common to all heads, so tp has nothing of the pool to "
                  "split and the decode kernel no shard rule"),
-                (int(axes.get("sp", 1)) > 1, "an sp mesh (ring attention "
+                (axes.get("sp", 1) > 1, "an sp mesh (ring attention "
                  "has no latent form)"),
-                (int(host) > 0, "the host KV tier (kv_host_pool_tokens / "
-                 "KV_HOST_POOL_TOKENS): engine/kv_tier.py keys and "
-                 "stacks host blobs on per-head K and V"),
-                (role != "unified", f"role {role!r}: prefill/decode "
-                 f"handoff ships kv_tier blobs"),
-            ]
+            ] + tier
         if mcfg.experts_held:
-            refused += [
-                (int(axes.get("ep", 1)) > 1 or int(axes.get("tp", 1)) > 1,
+            refused[f"an expert share (experts_held={mcfg.experts_held})"] \
+                = [
+                (axes.get("ep", 1) > 1 or axes.get("tp", 1) > 1,
                  "an expert share under an ep or tp mesh: the share IS "
                  "one device's experts"),
             ]
         if mcfg.hc_mult:
-            refused += [
-                (int(axes.get("sp", 1)) > 1, "hyper-connection streams "
+            refused[f"hyper-connection streams (hc_mult={mcfg.hc_mult})"] = [
+                (axes.get("sp", 1) > 1, "hyper-connection streams "
                  "under an sp mesh: the ring-attention forwards scan the "
                  "raw layer tree and neither widen nor sum the stream"),
             ]
-        for hit, why in refused:
-            if hit:
-                raise ConfigError(
-                    f"this model (kv_lora_rank={mcfg.kv_lora_rank}, "
-                    f"experts_held={mcfg.experts_held}, "
-                    f"hc_mult={mcfg.hc_mult}) does not support {why}")
+        if mcfg.recurrent:
+            refused["a recurrent state (full_attention_interval="
+                    f"{mcfg.full_attention_interval})"] = [
+                (any(n > 1 for n in axes.values()), f"a mesh ({axes}): the "
+                 f"state a slot has no sharding over tp, sp, ep or pp, and "
+                 f"ring attention and the pipeline scan the raw layer tree"),
+                (cfg.kv_quant, "an int8 KV pool (kv_quant) beside the "
+                 "float32 state"),
+                (spec_enabled(cfg.spec_decode), "speculative decoding "
+                 "(spec_decode / ENGINE_SPEC_DECODE): a rejected draft is "
+                 "rolled back by length, and a state that has consumed it "
+                 "cannot be"),
+            ] + [(hit, why + " and knows no state a slot: suspend, resume "
+                  "and handoff would lose it") for hit, why in tier]
+        for mechanism, cases in refused.items():
+            for hit, why in cases:
+                if hit:
+                    raise ConfigError(
+                        f"{mechanism} refuses: this model does not "
+                        f"support {why}")
 
     def _alloc_pool(self) -> dict:
         """Zeroed pool leaves, each born in its final sharding and
@@ -1111,9 +1139,11 @@ class Engine:
         default layout and re-placing it afterwards would hold two
         pools for the length of the copy."""
         mcfg, mesh = self.model_cfg, self.mesh
+        slots = ({"slots": self.cfg.max_slots}
+                 if mcfg.recurrent else {})
         leaves = jax.eval_shape(lambda: llama.init_paged_kv_cache(
             mcfg, self._n_pages, self.cfg.page_size, self._dtype,
-            quantized=self._kv_quant))
+            quantized=self._kv_quant, **slots))
         if mesh is not None:
             specs = kv_cache_of(mcfg).pool_spec(mesh,
                                                 quantized=self._kv_quant)
@@ -1137,6 +1167,14 @@ class Engine:
         with pooled bytes would under-reserve by ~2x in quant mode."""
         return kv_cache_of(self.model_cfg).model_token_bytes(
             self._dtype.itemsize, quantized=pooled and self._kv_quant)
+
+    def _slot_bytes(self) -> int:
+        """Bytes a sequence costs whatever its length: a recurrent
+        model's state and convolution tail (models/kv_cache.py
+        ``RecurrentKV.slot_bytes``); 0 for every other model."""
+        if not self.model_cfg.recurrent:
+            return 0
+        return kv_cache_of(self.model_cfg).slot_bytes(self._dtype.itemsize)
 
     def _pool_shard_factor(self) -> int:
         """How many ways the page pool is actually split across devices —
@@ -1219,6 +1257,19 @@ class Engine:
             gather += kvc.select_bytes(S, keys + S)
             if self._use_kernel:
                 gather += kvc.index_window_bytes(slots, keys, itemsize)
+        if mcfg.recurrent:
+            # A recurrent state is allocated for every slot beside the
+            # pages, whatever they hold: reserved here, BEFORE the pool
+            # is sized from what is left. And the widest chunk program
+            # holds a recurrent layer's q, k, v in float32 twice over
+            # (as the convolution leaves them and cut into blocks) and
+            # its blocks' 64 x 64 products.
+            rows = max(self._row_ladder, default=1)
+            heads = mcfg.linear_num_value_heads
+            acts += rows * S * heads * 4 * (
+                4 * mcfg.linear_key_head_dim
+                + 4 * mcfg.linear_value_head_dim + 6 * 64)
+            gather += cfg.max_slots * self._slot_bytes()
         # int8-KV insert quantizes the bucket per-row; XLA sequences the
         # K and V transforms, so ~one bucket's f32 copy is live at once
         quant = bucket_cache if self._kv_quant else 0
@@ -1460,6 +1511,12 @@ class Engine:
         out["index_bytes_per_token"] = (
             kv_cache_of(self.model_cfg).index_token_bytes(
                 self._dtype.itemsize) if self.model_cfg.index_topk else 0)
+        # recurrent layers: what a sequence costs whatever its length,
+        # what that reserved for all slots before the pool was sized,
+        # and that the prefix cache is off for such a model
+        out["slot_bytes"] = self._slot_bytes()
+        out["state_bytes"] = self.cfg.max_slots * out["slot_bytes"]
+        out["prefix_cache_off"] = int(self._prefix_cache_off)
         lookups = out.get("prefix_cache_lookups", 0)
         out["kv_restore_hit_rate"] = (
             round(out["kv_tier_restore_hits"] / lookups, 4)

@@ -517,6 +517,16 @@ def arm(spec: ProgramSpec, state, slot, *, cache, row, length, first_tok,
     }
 
 
+def _slots(spec: ProgramSpec, slot) -> dict:
+    """Where a program's rows keep their recurrent state: the keyword a
+    model with recurrent layers adds to the forwards and to the cache's
+    page insert (nothing for any other model, whose programs stay as
+    they are)."""
+    if not spec.model_cfg.recurrent:
+        return {}
+    return {"slots": jnp.reshape(slot, (-1,))}
+
+
 def make_prefill_insert(spec: ProgramSpec):
     """The un-jitted one-shot admission (``enable_fused_rag`` composes
     it after its on-device retrieval)."""
@@ -569,7 +579,8 @@ def make_prefill_insert(spec: ProgramSpec):
         seen = pack_mask(seen[0].at[first_tok].set(True))  # (Wn,) u32
         new = [cache[n] for n in kvc.leaves]
         dest = row[:new[0].shape[2] // page]
-        cache = kvc.insert_pages(state["cache"], *new, dest)
+        cache = kvc.insert_pages(state["cache"], *new, dest,
+                                 **_slots(spec, slot))
         return arm(spec, state, slot, cache=cache, row=row, length=length,
                    first_tok=first_tok, temp=temp, top_k=top_k,
                    top_p=top_p, rep_pen=rep_pen, seen=state["seen"],
@@ -617,9 +628,12 @@ def make_round(spec: ProgramSpec, window: int, steps: int, greedy: bool,
             # (pos -> 0) streams nothing, so dead slots cost no HBM.
             eff_pos = jnp.where(active, pos, 0)
             # dropless experts: idle slots touch no expert, and
-            # the step returns llama.layer_stat_names (``aux``)
+            # the step returns llama.layer_stat_names (``aux``); a
+            # recurrent state: idle slots leave theirs as it is
             moe = (dict(active=active, stats=True)
-                   if spec.layer_stats else {})
+                   if spec.layer_stats else
+                   dict(active=active) if mcfg.recurrent
+                   else {})
             net, cache, *aux = llama.apply_decode_paged(
                 params, mcfg, st["last_token"][:, None],
                 eff_pos[:, None], st["cache"], st["table"][:, :window],
@@ -888,7 +902,8 @@ def make_extend(spec: ProgramSpec, mode: str):
         _, cache = llama.apply_prefill_paged(
             params, mcfg, tokens, positions, state["cache"],
             row_win, valid[None], start // spec.page_size,
-            with_logits=False, use_kernel=spec.use_prefix_kernel)
+            with_logits=False, use_kernel=spec.use_prefix_kernel,
+            **_slots(spec, slot))
         marker = _completion_marker(spec, cache)
         return dict(state,
                     cache=spec.pin_cache(cache),
@@ -915,7 +930,8 @@ def make_extend_rows(spec: ProgramSpec, rows: int):
         _, cache = llama.apply_prefill_paged(
             params, mcfg, tokens, positions, state["cache"],
             tables, start + C, start // spec.page_size,
-            with_logits=False, use_kernel=spec.use_prefix_kernel)
+            with_logits=False, use_kernel=spec.use_prefix_kernel,
+            **_slots(spec, slot))
         marker = _completion_marker(spec, cache)
         chunk = pack_mask(seen_mask(
             tokens, jnp.full((rows,), C, jnp.int32),
@@ -944,7 +960,8 @@ def make_final(spec: ProgramSpec, greedy: bool, seed: bool):
         h, cache = llama.apply_prefill_paged(
             params, mcfg, tokens, positions, state["cache"],
             row_win, valid[None], start // spec.page_size,
-            with_logits=False, use_kernel=spec.use_prefix_kernel)
+            with_logits=False, use_kernel=spec.use_prefix_kernel,
+            **_slots(spec, slot))
         seen = chunk_seen(spec, state, tokens, start, valid, slot,
                           "seed" if seed else "accum", *seed0)
         idx = jnp.clip(valid - start - 1, 0, C - 1)

@@ -56,6 +56,13 @@ def init_lora(cfg: LlamaConfig, base_params: llama.Params, key: jax.Array,
             f"the train step merges deltas into a tree whose mapping "
             f"weights (hc_*_phi / alpha / b) are no target, and no "
             f"adapter was ever trained against a mixed stream here")
+    if cfg.recurrent:
+        raise NotImplementedError(
+            f"LoRA over a model with recurrent layers "
+            f"(full_attention_interval={cfg.full_attention_interval}): the "
+            f"targets are stacked over the attention layers only, which "
+            f"the merge and the train step do not know, and the recurrent "
+            f"mixer's projections (gdn_*) are no target")
     lora: LoraParams = {}
     keys = jax.random.split(key, len(targets))
     for k_rng, name in zip(keys, targets):

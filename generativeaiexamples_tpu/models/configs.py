@@ -187,6 +187,33 @@ class LlamaConfig:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_res_clamp: float = 30.0
+    # Recurrent layers beside attention layers (``full_attention_interval``
+    # > 0; 0 is the model above, whose programs it leaves as they are):
+    # layer ``i`` is an attention layer when ``(i + 1) %
+    # full_attention_interval == 0`` and a GATED DELTA-RULE layer
+    # otherwise (ops/gated_delta.py has the equations): its token mixer
+    # keeps no row a token but ONE state a sequence, ``linear_num_value_heads``
+    # matrices of ``linear_key_head_dim`` x ``linear_value_head_dim`` in
+    # float32, and the last ``linear_conv_kernel_dim - 1`` inputs of a
+    # depthwise causal convolution over its q, k and v channels
+    # (``linear_num_key_heads`` heads of q and k, each serving
+    # ``linear_num_value_heads / linear_num_key_heads`` value heads). The
+    # cache is models/kv_cache.py ``RecurrentKV``: pages for the attention
+    # layers only, a state a SLOT for the others. ``num_layers`` is whole
+    # periods. Two keys beside them:
+    #   partial_rotary_factor: the share of an attention head's values the
+    #         rotary embedding turns, from the first on (pairs (i, i +
+    #         half of THAT part)); the rest pass
+    #   shared_expert_gate: the shared expert's output times
+    #         sigmoid(x . w), ``w`` one vector a layer (``ws_gate_w``)
+    full_attention_interval: int = 0
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 0
+    partial_rotary_factor: float = 1.0
+    shared_expert_gate: bool = False
     # How ``llama.init_params`` draws a random tree (tests, benchmarks;
     # served weights come from ``import_hf`` and ignore it):
     #   "fan_in": every matrix N(0, 1/fan_in), embedding rows of norm 1
@@ -296,6 +323,48 @@ class LlamaConfig:
             raise ValueError("hyper-connections (hc_mult) need "
                              "hc_sinkhorn_iters >= 1 and positive hc_eps "
                              "and hc_res_clamp")
+        if self.full_attention_interval:
+            n = self.full_attention_interval
+            sizes = (self.linear_num_key_heads, self.linear_num_value_heads,
+                     self.linear_key_head_dim, self.linear_value_head_dim)
+            if n < 2 or self.num_layers % n:
+                raise ValueError(
+                    "full_attention_interval is 0 (every layer attends) or "
+                    "a period of at least 2 that divides num_layers")
+            if (min(sizes) <= 0 or self.linear_conv_kernel_dim < 2
+                    or self.linear_num_value_heads
+                    % self.linear_num_key_heads):
+                raise ValueError(
+                    "recurrent layers (full_attention_interval) need the "
+                    "five linear_* sizes, a convolution of at least 2 taps "
+                    "and value heads a multiple of the key heads")
+            if (self.kv_lora_rank or self.num_dense_layers or self.hc_mult
+                    or self.sliding_window or self.rope_layers
+                    or self.norm != "rmsnorm" or self.attn_bias
+                    or self.router_input != "mlp_norm"
+                    or (self.num_experts and self.moe_impl != "dropless")):
+                raise ValueError(
+                    "recurrent layers (full_attention_interval) run beside "
+                    "plain per-head attention in ONE stack: no latent "
+                    "attention, leading dense layers, hyper-connections, "
+                    "window or rope pattern, layernorm1p, attention bias, "
+                    "block_input router or capacity routing")
+        elif (self.linear_num_key_heads or self.linear_num_value_heads
+              or self.linear_key_head_dim or self.linear_value_head_dim
+              or self.linear_conv_kernel_dim):
+            raise ValueError("the linear_* sizes are the recurrent layers' "
+                             "(full_attention_interval)")
+        if not 0.0 < self.partial_rotary_factor <= 1.0 or (
+                self.partial_rotary_factor != 1.0 and (
+                    self.kv_lora_rank
+                    or int(self.head_dim * self.partial_rotary_factor) % 2)):
+            raise ValueError(
+                "partial_rotary_factor is in (0, 1], turns an even number "
+                "of a per-head attention's values and is not the latent "
+                "rotary part's (qk_rope_head_dim)")
+        if self.shared_expert_gate and not self.num_shared_experts:
+            raise ValueError("shared_expert_gate needs a shared expert "
+                             "(num_shared_experts)")
         if self.experts_held or self.experts_first:
             if not (self.num_experts and self.moe_impl == "dropless"):
                 raise ValueError("an expert share (experts_held) needs "
@@ -334,6 +403,33 @@ class LlamaConfig:
         """Per layer, 1 where it has the indexer ("full"), 0 where it
         attends the selection of the nearest full layer below."""
         return self.layer_pattern(self.index_layers, 1)
+
+    @property
+    def recurrent(self) -> bool:
+        """Some layers' token mixer is the recurrence: a sequence holds
+        a state a slot beside its pages (``full_attention_interval``)."""
+        return self.full_attention_interval > 0
+
+    @property
+    def layer_full(self) -> tuple:
+        """Per layer, 1 where its token mixer is attention, 0 where it is
+        the recurrence (``full_attention_interval``)."""
+        n = self.full_attention_interval
+        return tuple(int(not n or (i + 1) % n == 0)
+                     for i in range(self.num_layers))
+
+    @property
+    def rotary_dim(self) -> int:
+        """The values of an attention head the rotary embedding turns."""
+        return self.qk_rope_head_dim or int(
+            self.head_dim * self.partial_rotary_factor)
+
+    @property
+    def linear_channels(self) -> int:
+        """The channels a recurrent layer's convolution runs over: q, k
+        and v of every head."""
+        return (2 * self.linear_num_key_heads * self.linear_key_head_dim
+                + self.linear_num_value_heads * self.linear_value_head_dim)
 
     @property
     def expert_width(self) -> int:
@@ -509,6 +605,26 @@ XING4_0_29B_A4B = LlamaConfig(
 # nemotron_config.yaml deployment). Rotary attention, zero-centered
 # LayerNorm, squared-ReLU non-gated MLP, untied embeddings, 256k
 # SentencePiece vocab.
+# An 80B-total / 3B-active hybrid (Qwen/Qwen3-Next-80B-A3B-Instruct
+# config.json, model_type qwen3_next): 48 layers in periods of three
+# gated delta-rule layers (16 key / 32 value heads of 128, a 4-tap
+# convolution) and one gated attention layer (16 query / 2 KV heads of
+# 256, a quarter of each head rotated, q/k norm); every layer 512 SwiGLU
+# experts of width 512, 10 a token by softmax, beside one shared expert
+# under a sigmoid gate of its own. The multi-token-prediction module is
+# not built.
+QWEN3_NEXT_80B_A3B = LlamaConfig(
+    vocab_size=151936, hidden_size=2048, intermediate_size=5120,
+    moe_intermediate_size=512, num_layers=48, num_heads=16, num_kv_heads=2,
+    head_dim=256, max_position_embeddings=262144, rope_theta=1e7,
+    rms_norm_eps=1e-6, num_experts=512, num_experts_per_tok=10,
+    num_shared_experts=1, shared_expert_gate=True, moe_impl="dropless",
+    qk_norm=True, attn_gate=True, partial_rotary_factor=0.25,
+    full_attention_interval=4, linear_num_key_heads=16,
+    linear_num_value_heads=32, linear_key_head_dim=128,
+    linear_value_head_dim=128, linear_conv_kernel_dim=4,
+    weight_init="unit_stream")
+
 NEMOTRON_8B = LlamaConfig(vocab_size=256000, hidden_size=4096,
                           intermediate_size=16384, num_layers=32,
                           num_heads=32, num_kv_heads=32, head_dim=128,
@@ -556,6 +672,7 @@ MODEL_REGISTRY: dict[str, LlamaConfig] = {
     "kimi-k2-instruct": KIMI_K2,
     "glm-5.2": GLM_5_2,
     "xing4.0-29b-a4b": XING4_0_29B_A4B,
+    "qwen3-next-80b-a3b-instruct": QWEN3_NEXT_80B_A3B,
     "gptnext-tiny": GPTNEXT_TINY,
     "llama-tiny": LLAMA_TINY,
     "golden-tiny": GOLDEN_TINY,

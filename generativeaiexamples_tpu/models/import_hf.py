@@ -23,7 +23,7 @@ import numpy as np
 
 from ..utils.errors import ModelLoadError, UnsupportedFormatError
 from .configs import LlamaConfig
-from .llama import Params
+from .llama import ATTENTION_WEIGHTS, Params
 
 _HF_LAYER_KEYS = {
     "input_layernorm.weight": ("attn_norm", False),
@@ -92,10 +92,63 @@ _HF_HC_LAYER_KEYS = {
     for leaf, name in (("phi.weight", "phi"), ("alpha", "alpha"),
                        ("bias", "b"))
 }
+# A model whose layers are not all attention (``model_type: qwen3_next``;
+# cfg.full_attention_interval): an attention layer's ``q_proj`` holds the
+# output gate too (``_split_q_gate``), a recurrent layer has
+# ``linear_attn.*`` in its place (``in_proj_qkvz`` / ``in_proj_ba``
+# published grouped by key head: ``_ungroup_qkvz`` / ``_ungroup_ba``),
+# and every layer routed experts beside a gated shared one. Every norm
+# weight but ``linear_attn.norm`` is published zero-centred and stored
+# as ``1 + w`` (``_ZERO_CENTRED``), so the program's RMSNorm stays as it
+# is.
+_HF_RECURRENT_LAYER_KEYS = {
+    "self_attn.q_norm.weight": ("q_norm", False),
+    "self_attn.k_norm.weight": ("k_norm", False),
+    "linear_attn.dt_bias": ("gdn_dt_bias", False),
+    "linear_attn.A_log": ("gdn_A_log", False),
+    "linear_attn.norm.weight": ("gdn_norm", False),
+    "linear_attn.out_proj.weight": ("gdn_wout", True),
+    "mlp.gate.weight": ("router", True),
+    "mlp.shared_expert.gate_proj.weight": ("ws_gate", True),
+    "mlp.shared_expert.up_proj.weight": ("ws_up", True),
+    "mlp.shared_expert.down_proj.weight": ("ws_down", True),
+}
+_ZERO_CENTRED = ("attn_norm", "mlp_norm", "q_norm", "k_norm")
 _HF_KV_B = "self_attn.kv_b_proj.weight"
 # buffers and coefficients the program reads in float32
 _FLOAT32_LEAVES = ("router_bias", "hc_attn_alpha", "hc_attn_b",
-                   "hc_mlp_alpha", "hc_mlp_b")
+                   "hc_mlp_alpha", "hc_mlp_b", "gdn_A_log", "gdn_dt_bias")
+
+
+def _split_q_gate(w: np.ndarray, cfg: LlamaConfig) -> tuple:
+    """``q_proj`` (H x 2 hd, D), a head's queries then its gate, as the
+    tree holds it: ``wq`` and ``wz`` (D, H x hd) each (cfg.attn_gate as
+    it is)."""
+    H, hd = cfg.num_heads, cfg.head_dim
+    w = w.T.reshape(w.shape[1], H, 2, hd)
+    return w[:, :, 0].reshape(-1, H * hd), w[:, :, 1].reshape(-1, H * hd)
+
+
+def _ungroup_qkvz(w: np.ndarray, cfg: LlamaConfig) -> np.ndarray:
+    """``in_proj_qkvz`` (out, D), published a key head at a time — its q,
+    its k, its value heads' v, their z — as the tree holds it: (D, [q of
+    every head | k | v | z])."""
+    Hk, r = cfg.linear_num_key_heads, \
+        cfg.linear_num_value_heads // cfg.linear_num_key_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    w = w.T.reshape(w.shape[1], Hk, 2 * dk + 2 * r * dv)
+    cuts = np.cumsum([dk, dk, r * dv])
+    return np.concatenate([part.reshape(w.shape[0], -1)
+                           for part in np.split(w, cuts, axis=-1)], axis=-1)
+
+
+def _ungroup_ba(w: np.ndarray, cfg: LlamaConfig) -> np.ndarray:
+    """``in_proj_ba`` (2 Hv, D), a key head's b's then its a's, as the
+    tree holds it: (D, [b of every value head | a])."""
+    Hk = cfg.linear_num_key_heads
+    w = w.T.reshape(w.shape[1], Hk, 2, -1)
+    return np.concatenate([w[:, :, 0].reshape(w.shape[0], -1),
+                           w[:, :, 1].reshape(w.shape[0], -1)], axis=-1)
 
 
 def _split_kv_b(w: np.ndarray, cfg: LlamaConfig) -> tuple:
@@ -258,6 +311,18 @@ def _to_numpy(t: Any) -> np.ndarray:
     return np.asarray(t)
 
 
+_RECURRENT_SPLITS = {
+    "self_attn.q_proj.weight": lambda w, cfg: dict(
+        zip(("wq", "wz"), _split_q_gate(w, cfg))),
+    "linear_attn.in_proj_qkvz.weight": lambda w, cfg: {
+        "gdn_wqkvz": _ungroup_qkvz(w, cfg)},
+    "linear_attn.in_proj_ba.weight": lambda w, cfg: {
+        "gdn_wba": _ungroup_ba(w, cfg)},
+    "linear_attn.conv1d.weight": lambda w, cfg: {"gdn_conv": w[:, 0, :]},
+    "mlp.shared_expert_gate.weight": lambda w, cfg: {"ws_gate_w": w[0]},
+}
+
+
 def params_from_named_tensors(
         tensors: Iterator[tuple[str, Any]], cfg: LlamaConfig,
         dtype: jnp.dtype = jnp.bfloat16) -> Params:
@@ -277,6 +342,9 @@ def params_from_named_tensors(
         hf_keys.update(_HF_INDEX_LAYER_KEYS)
     if cfg.hc_mult:
         hf_keys.update(_HF_HC_LAYER_KEYS)
+    recurrent = cfg.recurrent
+    if recurrent:
+        hf_keys.update(_HF_RECURRENT_LAYER_KEYS)
     # an expert share keeps the experts it holds, numbered from its first
     first_expert, held = cfg.experts_first, cfg.held_experts
     layer_acc: dict[str, list] = {}
@@ -301,7 +369,7 @@ def params_from_named_tensors(
             top["embed"] = arr
             continue
         if key == "norm.weight":
-            top["final_norm"] = arr
+            top["final_norm"] = arr + 1 if recurrent else arr
             continue
         if key in ("lm_head.weight", "output.weight"):
             top["lm_head"] = arr.T
@@ -310,8 +378,14 @@ def params_from_named_tensors(
         if not m:
             continue  # rotary inv_freq buffers etc.
         idx, rest = int(m.group(1)), m.group(2)
+        if recurrent and rest in _RECURRENT_SPLITS:
+            for name, part in _RECURRENT_SPLITS[rest](arr, cfg).items():
+                put_layer(name, idx, part)
+            continue
         if rest in hf_keys:
             name, transpose = hf_keys[rest]
+            if recurrent and name in _ZERO_CENTRED:
+                arr = arr + 1
             put_layer(name, idx, arr.T if transpose else arr)
             continue
         if rest in _META_LAYER_KEYS:
@@ -352,6 +426,11 @@ def params_from_named_tensors(
             if indexer:     # the stack's full layers only, and all of them
                 part = [x for x, f in zip(
                     part, cfg.layer_index[first:first + n]) if f]
+            elif recurrent and (name.startswith("gdn_")
+                                or name in ATTENTION_WEIGHTS):
+                # a mixer's leaves over the layers of its kind alone
+                part = [x for x, f in zip(part, cfg.layer_full)
+                        if f == (name in ATTENTION_WEIGHTS)]
             if all(x is None for x in part) and not (indexer and part):
                 continue            # not a leaf of this stack's layers
             if any(x is None or (isinstance(x, list)

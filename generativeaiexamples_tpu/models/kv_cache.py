@@ -1,4 +1,4 @@
-"""The KV cache behind ONE object, in three implementations.
+"""The KV cache behind ONE object, in four implementations.
 
 What the forwards of models/llama.py, the decode kernel call and the
 engine's sizing need of a cache is asked of the object that
@@ -65,11 +65,34 @@ v)`` as handed:
             absorbed (``attend_window``); a chunk runs
             ``LatentKV.attend_prefix`` with the mask as one more operand
             of the chunk kernel.
+``RecurrentKV`` ``HeadKV`` for a model whose layers are not all attention
+            (``cfg.full_attention_interval``): ``{"k", "v"}`` over the Lf
+            ATTENTION layers only, and two leaves that are not pages, one
+            entry a SLOT whatever the sequence's length: ``"s": (Lg,
+            slots, Hv, dk, dv)``, the Lg recurrent layers' delta-rule
+            state (float32, always), and ``"conv": (Lg, slots,
+            (K - 1) * channels)``, the last K - 1 inputs of their
+            convolution, a slot's as ONE row: as (K - 1, channels) the
+            chip pads 3 rows to 4 and its compiler asks for another
+            layout than the buffers have (a program loaded from the
+            compile cache then refuses them: PERF.md section 6). The
+            forwards hand the attention layers ``HeadKV``'s readers with
+            the layer's place among the attention layers, and the
+            recurrent layers ``recur``: the rows' state read by slot at
+            the layer's place among ITS kind (zeros where the row's
+            sequence starts) and written back there. A state does not
+            forgive what rows past a length forgive: tokens past a
+            chunk's valid length, idle rows of a decode round and a
+            finished row's surplus steps are masked out of it
+            (models/llama.py ``_gdn_mixer``). ``slots`` is a keyword of
+            every forward and of ``init_pool``; its default is one
+            sequence a row, row ``b`` in slot ``b``.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -88,6 +111,8 @@ def kv_cache_of(cfg: LlamaConfig):
     decides, nothing else."""
     if cfg.index_topk:
         return SparseLatentKV(cfg)
+    if cfg.recurrent:
+        return RecurrentKV(cfg)
     return LatentKV(cfg) if cfg.kv_lora_rank else HeadKV(cfg)
 
 
@@ -347,6 +372,210 @@ class HeadKV:
             return attn[:, None], pool
 
         return attend
+
+
+class RecurrentKV(HeadKV):
+    """``HeadKV`` over the attention layers, a state a slot for the
+    recurrent ones (module docstring)."""
+
+    leaves = ("k", "v", "s", "conv")
+
+    def __init__(self, cfg: LlamaConfig):
+        super().__init__(cfg)
+        self.period = cfg.full_attention_interval
+        self.n_full = sum(cfg.layer_full)
+        self.n_recurrent = cfg.num_layers - self.n_full
+
+    def _state_shapes(self, slots: int) -> tuple[tuple, tuple]:
+        cfg = self.cfg
+        return ((self.n_recurrent, slots, cfg.linear_num_value_heads,
+                 cfg.linear_key_head_dim, cfg.linear_value_head_dim),
+                (self.n_recurrent, slots,
+                 (cfg.linear_conv_kernel_dim - 1) * cfg.linear_channels))
+
+    def _state(self, slots: int, dtype) -> KVCache:
+        s, conv = self._state_shapes(slots)
+        return {"s": jnp.zeros(s, jnp.float32),
+                "conv": jnp.zeros(conv, dtype)}
+
+    @staticmethod
+    def _as_stored(name: str, rows: jax.Array, leaf: jax.Array) -> jax.Array:
+        """Rows of a state leaf in its dtype, a tail's (K - 1, channels)
+        as the one row the leaf keeps."""
+        if name == "conv":
+            rows = rows.reshape(rows.shape[:-2] + (-1,))
+        return rows.astype(leaf.dtype)
+
+    # ---------------------------------------------------------------- build
+
+    def init_dense(self, batch: int, max_len: int,
+                   dtype: jnp.dtype = jnp.bfloat16) -> KVCache:
+        cfg = self.cfg
+        shape = (self.n_full, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
+        state = self._state(batch, dtype)
+        # a dense cache's layers ride the scan whole: its tail stays
+        # (K - 1, channels) a row, as the mixer takes and leaves it
+        state["conv"] = state["conv"].reshape(
+            state["conv"].shape[:2] + (-1, cfg.linear_channels))
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+                **state}
+
+    def init_pool(self, n_pages: int, page_size: int,
+                  dtype: jnp.dtype = jnp.bfloat16,
+                  quantized: bool = False, slots: int = 1) -> KVCache:
+        if quantized:
+            raise NotImplementedError(
+                "an int8 KV pool beside a recurrent state is not supported")
+        cfg = self.cfg
+        shape = (self.n_full, n_pages, cfg.num_kv_heads, page_size,
+                 cfg.head_dim)
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+                **self._state(slots, dtype)}
+
+    # ----------------------------------------------------------------- size
+
+    def model_token_bytes(self, itemsize: int, quantized: bool = False
+                          ) -> int:
+        """Bytes a cached token: rows on the attention layers only."""
+        return self.n_full * self.token_bytes(itemsize, quantized)
+
+    def slot_bytes(self, itemsize: int) -> int:
+        """Bytes a sequence costs whatever its length: the recurrent
+        layers' state and convolution tail."""
+        s, conv = self._state_shapes(1)
+        return math.prod(s) * 4 + math.prod(conv) * itemsize
+
+    def pool_spec(self, mesh, quantized: bool = False) -> dict:
+        raise NotImplementedError(
+            "a recurrent state has no sharding over a mesh")
+
+    # ------------------------------------------------------- attention layers
+
+    def attend_window(self, q, k, v, lp, kv_cache, layer, *args):
+        return super().attend_window(q, k, v, lp, kv_cache,
+                                     layer // self.period, *args)
+
+    def attend_prefix(self, q, k, v, lp, kv_cache, block_table, start,
+                      kv_valid_len, layer):
+        return super().attend_prefix(q, k, v, lp, kv_cache, block_table,
+                                     start, kv_valid_len,
+                                     layer // self.period)
+
+    def kernel_attend(self, kv_cache: KVCache, *args):
+        inner = super().kernel_attend(kv_cache, *args)
+
+        def attend(q, k, v, lp, li, pool):
+            attn, new = inner(q, k, v, lp, li // self.period, pool)
+            return attn, {**pool, **new}
+
+        return attend
+
+    # ------------------------------------------------------ recurrent layers
+
+    @staticmethod
+    def from_zeros(rows: jax.Array, fresh: jax.Array) -> jax.Array:
+        """The rows' state (or tail) with zeros where a row's sequence
+        STARTS (``fresh`` (B,) bool), whatever was there."""
+        return jnp.where(fresh.reshape((-1,) + (1,) * (rows.ndim - 1)),
+                         jnp.zeros((), rows.dtype), rows)
+
+    def recur(self, kv_cache: Optional[KVCache], slots, fresh,
+              carried: bool = False, kernel: bool = False):
+        """``(load, store, step)`` for a forward's recurrent layers:
+        ``load(lg, state, lp)`` gives the rows' ``(S, conv tail)``
+        entering recurrent layer ``lg`` — read from the pool by ``slots``
+        ((B,), None: row ``b`` is slot ``b`` and the rows are all the
+        slots), zeros on the rows that are ``fresh`` ((B,) bool or None:
+        whose sequence starts here, whatever the slot held) —;
+        ``store(lg, state, s, conv)`` puts what they leave back into a
+        CARRIED pool (``carried``: the pool rides the layer scan,
+        ``state``; else None: the forward collects the rows and
+        ``write`` puts them). ``kernel`` (a carried pool, the rows all
+        the slots): the decode step runs as the Pallas kernel over the
+        WHOLE state leaf in place — ``step(lg, state, q, k, v, g, beta,
+        active) -> (o, state)``; ``load`` then hands no ``S`` (None) and
+        ``store`` takes none."""
+        def row(leaf, lg):
+            if slots is None:
+                a = jax.lax.dynamic_index_in_dim(leaf, lg, 0, False)
+            else:
+                # by (layer, slot) in ONE step over the flattened
+                # leading axes: a ``leaf[lg]`` first is a copy of
+                # the layer's whole slab (67 MB of state at 32
+                # slots) to read one row of it (``HeadKV.window``)
+                a = leaf.reshape((-1,) + leaf.shape[2:])[
+                    lg * leaf.shape[1] + slots]
+            return a if fresh is None else self.from_zeros(a, fresh)
+
+        def load(lg, state, lp):
+            pool = state if carried else kv_cache
+            tail = row(pool["conv"], lg)
+            return (None if kernel else row(pool["s"], lg),
+                    tail.reshape(tail.shape[0], -1,
+                                 self.cfg.linear_channels))
+
+        def store(lg, state, s, conv):
+            new = {}
+            for name, rows in (("s", s), ("conv", conv)):
+                if rows is None:
+                    continue
+                leaf = state[name]
+                rows = self._as_stored(name, rows, leaf)
+                if slots is None:
+                    new[name] = jax.lax.dynamic_update_index_in_dim(
+                        leaf, rows, lg, 0)
+                else:
+                    new[name] = leaf.at[lg, slots].set(rows)
+            return {**state, **new}
+
+        def step(lg, state, q, k, v, g, beta, active):
+            from ..ops.gated_delta import gated_delta_step_kernel
+            o, s = gated_delta_step_kernel(
+                q, k, v, g, beta, active, state["s"], lg,
+                interpret=jax.default_backend() != "tpu")
+            return o, {**state, "s": s}
+
+        return (load, store if carried else None,
+                step if kernel else None)
+
+    def step_kernel_supported(self) -> bool:
+        from ..ops.gated_delta import step_kernel_supported
+        cfg = self.cfg
+        return step_kernel_supported(cfg.linear_num_value_heads,
+                                     cfg.linear_key_head_dim,
+                                     cfg.linear_value_head_dim)
+
+    # ---------------------------------------------------------------- write
+
+    def write(self, kv_cache: KVCache, new_k, new_v, new_s, new_conv,
+              pages, offsets: Optional[jax.Array] = None,
+              slots: Optional[jax.Array] = None) -> KVCache:
+        """``HeadKV.write`` of the attention layers' rows and, in the same
+        step, the recurrent layers' state of the rows' ``slots`` ((B,);
+        None: the rows are all the slots, in order): ``new_s`` (Lg, B,
+        Hv, dk, dv) and ``new_conv`` (Lg, B, K - 1, channels) as the scan
+        stacked them."""
+        out = super().write({n: kv_cache[n] for n in ("k", "v")}, new_k,
+                            new_v, pages, offsets)
+        with jax.named_scope("gdn_state"):
+            for name, rows in (("s", new_s), ("conv", new_conv)):
+                leaf = kv_cache[name]
+                rows = self._as_stored(name, rows, leaf)
+                at = jnp.arange(rows.shape[1]) if slots is None else slots
+                out[name] = leaf.at[:, at].set(rows)
+        return out
+
+    def insert_pages(self, kv_cache: KVCache, k_new, v_new, s_new, conv_new,
+                     dest, slots: Optional[jax.Array] = None) -> KVCache:
+        """``HeadKV.insert_pages`` of a prefilled bucket's rows, and the
+        ONE sequence's state into its slot (``slots`` (1,); None: 0)."""
+        out = super().insert_pages({n: kv_cache[n] for n in ("k", "v")},
+                                   k_new, v_new, dest)
+        at = jnp.zeros((1,), jnp.int32) if slots is None else slots
+        for name, rows in (("s", s_new), ("conv", conv_new)):
+            out[name] = kv_cache[name].at[:, at].set(
+                self._as_stored(name, rows, kv_cache[name]))
+        return out
 
 
 def _paged_prefix_attention(q, k_self, v_self, kc, vc, ksc, vsc,

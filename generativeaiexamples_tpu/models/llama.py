@@ -61,12 +61,13 @@ import os
 import jax
 import jax.numpy as jnp
 
+from ..ops import gated_delta as gd
 from ..ops import hyper_connection as hc
 from ..ops.quant import matmul as qmm
 from ..ops.quant import matmul_f32 as qmm_f32
 from ..ops.rmsnorm import layernorm1p, rmsnorm
-from ..ops.rope import (apply_rope, deinterleave, rope_frequencies,
-                        yarn_frequencies)
+from ..ops.rope import (apply_rope, apply_rope_partial, deinterleave,
+                        rope_frequencies, yarn_frequencies)
 from .configs import LlamaConfig
 from .kv_cache import (KVCache, _paged_prefix_attention,  # noqa: F401
                        kv_cache_of)
@@ -96,6 +97,14 @@ INDEX_SCOPES = ("attn_index", "attn_select")
 #: streams; and the model's first stream) and ``hc_post`` (the stream
 #: written back; and the sum that ends it) — ops/hyper_connection.py.
 HC_SCOPES = ("hc_pre", "hc_post")
+#: A recurrent layer (``cfg.full_attention_interval``) runs, in place of
+#: ``attn_proj`` and ``attn``: ``gdn_proj`` (its norm, the two
+#: in-projections, the gated output norm and the output projection),
+#: ``gdn_conv`` (the causal convolution and its tail), and the recurrence
+#: as ``gdn_step`` (one token a row: the decode step) or ``gdn_scan``
+#: (the chunked form: every other forward) — ops/gated_delta.py; the
+#: state's write after a scan is ``gdn_state``.
+GDN_SCOPES = ("gdn_proj", "gdn_conv", "gdn_step", "gdn_scan", "gdn_state")
 
 
 def _embed(params: "Params", tokens: jax.Array,
@@ -139,7 +148,7 @@ Params = dict[str, Any]
 def _inv_freq(cfg: LlamaConfig) -> jax.Array:
     """The rotary part's inverse frequencies as the configuration scales
     them: over the whole head, or a latent layer's ``qk_rope_head_dim``."""
-    dim = cfg.qk_rope_head_dim or cfg.head_dim
+    dim = cfg.rotary_dim
     if cfg.rope_scaling_type == "yarn":
         return yarn_frequencies(dim, cfg.rope_theta, cfg.rope_scaling_factor,
                                 cfg.rope_original_max, cfg.rope_beta_fast,
@@ -204,6 +213,11 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
     (_, _, L), *leading = reversed(cfg.layer_stacks)
     Fe = cfg.expert_width
 
+    def attention_layers(L):
+        """The layers of a stack of ``L`` whose mixer is attention: all,
+        but beside recurrent layers (one stack then)."""
+        return sum(cfg.layer_full) if cfg.recurrent else L
+
     def stack(k, L, experts):
         """One stack's tree, its matrices drawn from ``k`` in an order
         the committed trees depend on."""
@@ -211,14 +225,15 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
             "attn_norm": norm_w((L, D), dtype),
             "mlp_norm": norm_w((L, D), dtype),
         }
+        A = attention_layers(L)
         if cfg.kv_lora_rank:
             layers.update(latent(k, L))
         else:
             layers.update({
-                "wq": norm(next(k), (L, D, H * hd), D / q_gain),
-                "wk": norm(next(k), (L, D, KV * hd), D),
-                "wv": norm(next(k), (L, D, KV * hd), D),
-                "wo": norm(next(k), (L, H * hd, D), H * hd * resid),
+                "wq": norm(next(k), (A, D, H * hd), D / q_gain),
+                "wk": norm(next(k), (A, D, KV * hd), D),
+                "wv": norm(next(k), (A, D, KV * hd), D),
+                "wo": norm(next(k), (A, H * hd, D), H * hd * resid),
             })
         if cfg.norm == "layernorm1p":
             layers["attn_norm_b"] = jnp.zeros((L, D), dtype)
@@ -323,15 +338,16 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
                 next(k), shape, jnp.float32))).astype(dtype)
 
         L, experts = layers["attn_norm"].shape[0], "router" in layers
+        A = attention_layers(L)
         out: dict[str, jax.Array] = {}
         if cfg.kv_lora_rank:
             out["q_a_norm"] = near(1.0, (L, cfg.q_lora_rank))
             out["kv_a_norm"] = near(1.0, (L, cfg.kv_lora_rank))
         if cfg.attn_gate:
-            out["wz"] = norm(next(k), (L, D, H * hd), D)
+            out["wz"] = norm(next(k), (A, D, H * hd), D)
         if cfg.qk_norm:
-            out["q_norm"] = near(q_gain ** 0.5, (L, hd))
-            out["k_norm"] = near(1.0, (L, hd))
+            out["q_norm"] = near(q_gain ** 0.5, (A, hd))
+            out["k_norm"] = near(1.0, (A, hd))
         if cfg.post_norms:
             for name, share in (("post_attn_norm", 1.0),
                                 ("post_mlp_norm", 0.2)):
@@ -415,6 +431,48 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
             })
         return out
 
+    def recurrent(k, L):
+        """The recurrent layers' leaves (``cfg.full_attention_interval``),
+        stacked over those alone, drawn so that what a sequence carries
+        MATTERS at the logits — a program that dropped the state or the
+        convolution tail between two chunks, or let padding into them,
+        must differ by more than rounding:
+
+        - ``gdn_A_log`` / ``gdn_dt_bias``: a head's state halves in 16 to
+          4096 tokens, log-uniform over the heads (``g = -exp(A_log)
+          softplus(a + dt_bias)`` at ``a = 0`` is ln 2 over that; under
+          the published initialiser's ``A ~ U(0, 16)`` a state forgets
+          within a few tokens). ``A_log`` itself deviates by 0.3, so a
+          program without it is off by a third;
+        - ``gdn_wba``: the ``b`` columns at 1.2 times the fan-in
+          deviation, so ``beta = sigmoid(b)`` spreads over (0.1, 0.9);
+          the ``a`` columns at half of it: a token moves its own decay
+          by a factor of e^0.5 either way;
+        - ``gdn_conv``: every tap of deviation 0.5 — the three inputs
+          behind a token weigh together more than its own;
+        - ``gdn_norm``: the gated output norm's weight, applied as it is,
+          a tenth off 1; ``gdn_wout`` writes to the stream, scaled as
+          ``wo`` is."""
+        Hv, dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
+        Ch, K = cfg.linear_channels, cfg.linear_conv_kernel_dim
+        wba = norm(next(k), (L, D, 2 * Hv), D).astype(jnp.float32) \
+            * jnp.where(jnp.arange(2 * Hv) < Hv, 1.2, 0.5)
+        a_log = 0.3 * jax.random.normal(next(k), (L, Hv), jnp.float32)
+        half_life = jnp.exp(jax.random.uniform(
+            next(k), (L, Hv), jnp.float32, jnp.log(16.0), jnp.log(4096.0)))
+        rate = jnp.log(2.0) / half_life / jnp.exp(a_log)
+        return {
+            "gdn_wqkvz": norm(next(k), (L, D, Ch + Hv * dv), D),
+            "gdn_wba": wba.astype(dtype),
+            "gdn_conv": (0.5 * jax.random.normal(
+                next(k), (L, Ch, K), jnp.float32)).astype(dtype),
+            "gdn_A_log": a_log,
+            "gdn_dt_bias": jnp.log(jnp.expm1(rate)),    # softplus^-1
+            "gdn_norm": (1.0 + 0.1 * jax.random.normal(
+                next(k), (L, dv), jnp.float32)).astype(dtype),
+            "gdn_wout": norm(next(k), (L, Hv * dv, D), Hv * dv * resid),
+        }
+
     kx = iter(jax.random.split(jax.random.fold_in(key, 1), 32))
     layers = stack(k, L, bool(cfg.num_experts))
     layers.update(extras(kx, layers))
@@ -439,6 +497,17 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
         kh = iter(jax.random.split(jax.random.fold_in(key, 3), 16))
         for name, _, n in cfg.layer_stacks:
             params[name].update(hyper(kh, n))
+    if cfg.recurrent:
+        kr = iter(jax.random.split(jax.random.fold_in(key, 4), 8))
+        layers.update(recurrent(kr, L - sum(cfg.layer_full)))
+    if cfg.shared_expert_gate:
+        # the shared expert's gate reads the normed stream (unit
+        # entries): a pre-activation of deviation 1.5, so the gate lies
+        # anywhere in (0.05, 0.95) and a program without it is off by
+        # about half the shared expert
+        layers["ws_gate_w"] = (1.5 * jax.random.normal(
+            jax.random.fold_in(key, 5), (L, D), jnp.float32)
+            * D ** -0.5).astype(dtype)
     return params
 
 
@@ -451,7 +520,8 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int,
 
 def init_paged_kv_cache(cfg: LlamaConfig, n_pages: int, page_size: int,
                         dtype: jnp.dtype = jnp.bfloat16,
-                        quantized: bool = False) -> KVCache:
+                        quantized: bool = False,
+                        slots: Optional[int] = None) -> KVCache:
     """Block-pool KV cache: {"k","v"}: (L, n_pages, KV, page, hd), or a
     latent model's two leaves; the configuration's cache object
     (models/kv_cache.py) builds it and is the one that reads and writes
@@ -472,8 +542,14 @@ def init_paged_kv_cache(cfg: LlamaConfig, n_pages: int, page_size: int,
     shaped (L, n_pages, KV, page) — half the HBM bytes per cached token
     (ops/kv_quant.py), the lever toward the reference's batch-128 class
     capacity (reference: config.pbtxt.j2:29).
+
+    ``slots``: the sequences a model with recurrent layers keeps a state
+    for beside the pages (``RecurrentKV``; default one). No other cache
+    takes it.
     """
-    return kv_cache_of(cfg).init_pool(n_pages, page_size, dtype, quantized)
+    extra = {} if slots is None else {"slots": slots}
+    return kv_cache_of(cfg).init_pool(n_pages, page_size, dtype, quantized,
+                                      **extra)
 
 
 def kernel_tp_compatible(cfg: LlamaConfig, mesh) -> bool:
@@ -563,12 +639,76 @@ def _scan_inputs(layers: dict[str, jax.Array], cfg: LlamaConfig, first: int):
     return layers, held
 
 
+#: The leaves of a stack that only its ATTENTION layers have, where not
+#: every layer attends (``cfg.full_attention_interval``): stacked over
+#: those alone, as the recurrent layers' ``gdn_*`` are over theirs.
+ATTENTION_WEIGHTS = ("wq", "wk", "wv", "wo", "wz", "q_norm", "k_norm")
+_ATTENTION_LEAVES = ATTENTION_WEIGHTS + ("cache_k", "cache_v")  # dense cache
+_RECURRENT_CACHE = ("cache_s", "cache_conv")
+
+
+def _by_period(stack: dict, period: int) -> tuple[dict, dict]:
+    """A stack's scan inputs regrouped so that ONE scan step is one
+    period of ``period`` layers — ``period - 1`` recurrent layers, then
+    an attention layer: ``(scanned, leaves)``. Two weight trees in one
+    stack, and no program computes both mixers for a layer: which one a
+    layer runs is its place in the period, known when the program is
+    traced.
+
+    ``leaves`` — ``{"common": what every layer has, stacked (L, ...);
+    "recurrent": (Lg, ...); "attention": (Lf, ...)}`` — are HELD, not
+    scanned, and a layer's slice is taken where it is used, by its place
+    among its kind (``_period_layer``): scanned as (P, period, ...) a
+    layer's matrix is a second, static index into the step's slab, and
+    the chip's compiler copies the slab's layer out before the matmul
+    (33 MB a recurrent layer a decode step: 0.6 ms of a 12 ms step;
+    chip, PR 46) where a dynamic slice of the whole stack is read in
+    place, as the experts' stacks are (``scan_layers``). Only a dense
+    cache's slices (``cache_*``) ride the scan."""
+    scanned: dict = {}
+    leaves: dict = {"common": {}, "recurrent": {}, "attention": {}}
+    for name, leaf in stack.items():
+        if name in _RECURRENT_CACHE:
+            scanned[name] = leaf.reshape((-1, period - 1) + leaf.shape[1:])
+        elif name.startswith("cache_"):
+            scanned[name] = leaf
+        elif name in _ATTENTION_LEAVES:
+            leaves["attention"][name] = leaf
+        elif name.startswith("gdn_"):
+            leaves["recurrent"][name] = leaf
+        else:
+            leaves["common"][name] = leaf
+    n = jax.tree.leaves(leaves["common"])[0].shape[0]
+    scanned["period"] = jnp.arange(n // period, dtype=jnp.int32)
+    return scanned, leaves
+
+
+def _period_layer(lp: dict, leaves: dict, j, period: int) -> dict:
+    """A layer of the period a scan step runs (``_by_period``): its
+    slices of the held stacks, and of a dense cache's. ``j`` traced: the
+    ``j``-th recurrent layer; None: the attention layer that ends the
+    period."""
+    def at(tree, index):
+        return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+            a, index, 0, keepdims=False), tree)
+
+    p = lp["period"]
+    if j is None:
+        own = at(leaves["attention"], p)
+        own.update({n: a for n, a in lp.items() if n in _ATTENTION_LEAVES})
+        return {**at(leaves["common"], (p + 1) * period - 1), **own}
+    own = at(leaves["recurrent"], p * (period - 1) + j)
+    own.update(at({n: lp[n] for n in _RECURRENT_CACHE if n in lp}, j))
+    return {**at(leaves["common"], p * period + j), **own}
+
+
 def _run_stack(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
                positions: jax.Array, inv_freq: jax.Array,
                kv_valid_len: Optional[jax.Array], attend=None, *,
                state=None, xs: Optional[dict] = None,
                row_mask: Optional[jax.Array] = None, stats: bool = False,
-               first: int = 0, selection: Optional[jax.Array] = None):
+               first: int = 0, selection: Optional[jax.Array] = None,
+               recur=None):
     """The ONE scan over a layer stack; every forward is a use of it
     (through ``_run_model``, once a stack of the model) and hands it
     only what differs. ``attend(q, k, v, lp, li, state) -> (attn, out)``
@@ -615,13 +755,21 @@ def _run_stack(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
     ``decoder_layer`` or an ``attend``; a new cache format is a third
     implementation of the cache object (models/kv_cache.py: its writer,
     its readers and its kernel call).
+
+    Where not every layer attends (``cfg.full_attention_interval``) a
+    scan step is one PERIOD of layers (``_by_period``), the recurrent
+    ones first, each run by the same ``one`` below. ``recur(x, lp, li,
+    state) -> (mixed, out)`` is a recurrent layer's step as ``attend`` is
+    an attention layer's (None: a sequence from zeros, nothing kept);
+    ``out`` stacks ``(k, v, s, conv)``, the first two over the attention
+    layers and the last two over the recurrent ones.
     """
     stack, held = _scan_inputs(layers, cfg, first)
     stack = {**stack, **(xs or {})}
     carried = state is not None
+    period = cfg.full_attention_interval
 
-    def body(carry, lp):
-        h, state, li, sel = carry
+    def one(h, state, li, sel, lp):
         lp = {**lp, **held}
         if sel is not None:
             lp["selection"] = sel
@@ -630,7 +778,8 @@ def _run_stack(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
             h, lp, cfg, positions, inv_freq, kv_valid_len,
             attend=attend and (lambda q, k, v, *index: attend(
                 q, k, v, lp, li, state, *index)),
-            row_mask=row_mask, aux=aux)
+            row_mask=row_mask, aux=aux,
+            recur=recur and (lambda x: recur(x, lp, li, state)))
         if sel is not None:
             out, sel = out
         if carried:
@@ -638,7 +787,37 @@ def _run_stack(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
         touched = None if aux is None else {
             name: aux.get(name, jnp.float32(0.0))
             for name in layer_stat_names(cfg)}
-        return (h, state, li + 1, sel), (out, touched)
+        return h, state, sel, out, touched
+
+    def body(carry, lp):
+        h, state, li, sel = carry
+        if not period:
+            h, state, sel, out, touched = one(h, state, li, sel, lp)
+            return (h, state, li + 1, sel), (out, touched)
+        # the recurrent layers are one body too, scanned inside the
+        # step: a period traced layer by layer is twice the program (137
+        # of them overran the chip's compile cache and every start was
+        # cold, 640 s; chip, PR 46)
+        def recurrent(carry, j):
+            h, state = carry
+            h, state, _, out, touched = one(
+                h, state, li + j, None, _period_layer(lp, leaves, j, period))
+            return (h, state), (out, touched)
+
+        (h, state), (rows, stat) = jax.lax.scan(
+            recurrent, (h, state), jnp.arange(period - 1, dtype=jnp.int32))
+        h, state, sel, out, touched = one(
+            h, state, li + period - 1, sel,
+            _period_layer(lp, leaves, None, period))
+        if out is not None:     # the attention layer's rows, then the
+            out = out + rows    # recurrent layers' states and tails
+        if stats:
+            stat = jax.tree.map(lambda a, b: jnp.concatenate([a, b[None]]),
+                                stat, touched)
+        return (h, state, li + period, sel), (out, stat if stats else None)
+
+    if period:
+        stack, leaves = _by_period(stack, period)
 
     # the Pallas call takes its layer as a (1,) scalar-prefetch operand;
     # the held form indexes the flattened pool with a scalar
@@ -647,6 +826,10 @@ def _run_stack(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
         li = li + first
     (h, state, _, selection), (out, touched) = jax.lax.scan(
         body, (h, state, li, selection), stack)
+    if period and out is not None:
+        # (P, period - 1, ...) -> the recurrent layers in order
+        out = out[:2] + tuple(a.reshape((-1,) + a.shape[2:])
+                              for a in out[2:])
     return h, (state if carried else out), touched, selection
 
 
@@ -723,8 +906,13 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
                        mesh=None, return_hidden: bool = False,
                        active: Optional[jax.Array] = None,
                        stats: bool = False,
+                       slots: Optional[jax.Array] = None,
                        ) -> tuple[jax.Array, KVCache]:
     """Single-token decode step over the paged KV pool.
+
+    ``slots`` (B,): where the rows' recurrent state lies (a model with
+    recurrent layers; None: row ``b`` is slot ``b``). Such a model's idle
+    rows (``active`` False) leave their slot's state as it is.
 
     ``active`` (B,) bool: the rows that hold a sequence. Only dropless
     experts read it (an idle slot then touches no expert); every other
@@ -757,7 +945,7 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
         return apply_verify_paged(
             params, cfg, tokens, positions, kv_cache, block_table,
             kv_valid_len, write_page[:, None], write_offset[:, None],
-            return_hidden, active=active, stats=stats)
+            return_hidden, active=active, stats=stats, slots=slots)
     inv_freq = _inv_freq(cfg)
     h = _embed(params, tokens, cfg.embed_scale)
     pos_in_win = positions[:, 0]  # logical index of the current token
@@ -767,9 +955,26 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     h, cache, touched = _run_model(
         params, cfg, h, positions, inv_freq, kv_valid_len, attend,
         state=kv_cache, row_mask=active, stats=stats,
-        selection=_no_selection(cfg, tokens, kv_cache, block_table))
+        selection=_no_selection(cfg, tokens, kv_cache, block_table),
+        **_recurrent_step(cfg, kvc, None, slots, active, carried=True))
     return _step_result(params, cfg, h, cache, touched, return_hidden,
                         stats)
+
+
+def _recurrent_step(cfg: LlamaConfig, kvc, kv_cache: Optional[KVCache],
+                    slots, active, carried: bool = False) -> dict:
+    """``_run_stack``'s ``recur`` for one token a row over the rows'
+    slots (nothing for a model without recurrent layers): every row
+    continues its sequence, idle rows touch nothing. Over a carried pool
+    whose rows are all its slots the step is the Pallas kernel over the
+    state leaf, where it takes the geometry."""
+    if not cfg.recurrent:
+        return {}
+    n_valid = None if active is None else active.astype(jnp.int32)
+    kernel = carried and slots is None and kvc.step_kernel_supported()
+    return {"recur": _gdn_recur(
+        cfg, *kvc.recur(kv_cache, slots, None, carried, kernel),
+        n_valid=n_valid)}
 
 
 def _no_selection(cfg: LlamaConfig, tokens: jax.Array, kv_cache: KVCache,
@@ -790,6 +995,7 @@ def apply_verify_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
                        return_hidden: bool = False, *,
                        active: Optional[jax.Array] = None,
                        stats: bool = False,
+                       slots: Optional[jax.Array] = None,
                        ) -> tuple[jax.Array, KVCache]:
     """Multi-token decode step over the paged KV pool: the speculative-
     decoding VERIFICATION forward (engine/spec_decode.py), and at S = 1
@@ -822,6 +1028,11 @@ def apply_verify_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     inv_freq = _inv_freq(cfg)
     kvc = kv_cache_of(cfg)
     rows = jnp.arange(tokens.shape[0])[:, None]
+    if cfg.recurrent and tokens.shape[1] != 1:
+        raise NotImplementedError(
+            "apply_verify_paged over several tokens a row and recurrent "
+            "layers (full_attention_interval): a rejected draft is rolled "
+            "back by length, and a state that has consumed it cannot be")
 
     def attend(q, k, v, lp, li, _, *index):
         return _kv_step(kvc.attend_window(
@@ -831,8 +1042,10 @@ def apply_verify_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     h, new, touched = _run_model(
         params, cfg, _embed(params, tokens, cfg.embed_scale), positions,
         inv_freq, kv_valid_len, attend, row_mask=active, stats=stats,
-        selection=_no_selection(cfg, tokens, kv_cache, block_table))
-    cache = kvc.write(kv_cache, *new, write_pages, write_offsets)
+        selection=_no_selection(cfg, tokens, kv_cache, block_table),
+        **_recurrent_step(cfg, kvc, kv_cache, slots, active))
+    where = {"slots": slots} if cfg.recurrent else {}
+    cache = kvc.write(kv_cache, *new, write_pages, write_offsets, **where)
     return _step_result(params, cfg, h, cache, touched, return_hidden,
                         stats)
 
@@ -843,9 +1056,15 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
                         start_page_idx: jax.Array, *,
                         with_logits: bool = False,
                         use_kernel: Optional[bool] = None,
+                        slots: Optional[jax.Array] = None,
                         ) -> tuple[jax.Array, KVCache]:
     """One CHUNK of a long-prompt prefill over the paged KV pool, of one
     prompt (B = 1) or of B prompts at once, a row each.
+
+    ``slots`` (B,): where the rows' recurrent state lies (a model with
+    recurrent layers; None: row ``b`` is slot ``b``). A row whose chunk
+    starts at position 0 starts from zeros whatever its slot held, and
+    the tokens past ``kv_valid_len`` leave the state alone.
 
     The piece that lets the engine serve prompts longer than any single
     prefill bucket: the prompt streams through in page-aligned chunks,
@@ -934,9 +1153,17 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     if cfg.index_topk:
         selection = jnp.zeros((B, C, kvc.prefix_keys(
             block_table.shape[1], page) + C), bool)
+    recur = {}
+    if cfg.recurrent:
+        recur["recur"] = _gdn_recur(
+            cfg, *kvc.recur(kv_cache, slots, positions[:, 0] == 0),
+            n_valid=kv_valid_len - positions[:, 0])
     h, new, _ = _run_model(params, cfg, h, positions, inv_freq,
                            kv_valid_len, attend if B == 1 else attend_rows,
-                           selection=selection)
+                           selection=selection, **recur)
+    state = ()
+    if cfg.recurrent:
+        new, state, recur = new[:2], new[2:], {"slots": slots}
     # new: (L, C, KV, hd) a leaf, to the chunk's physical pages
     if B == 1:
         dest = jax.lax.dynamic_slice(block_table[0], (start_page_idx,),
@@ -950,7 +1177,7 @@ def apply_prefill_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
             block_table, start_page_idx[:, None]
             + jnp.arange(C // page, dtype=jnp.int32)[None], axis=1
         ).reshape(-1)
-    cache = kvc.write(kv_cache, *new, dest)
+    cache = kvc.write(kv_cache, *new, *state, dest, **recur)
     if not with_logits:
         return h, cache
     return unembed(params, cfg, h), cache
@@ -1039,9 +1266,14 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
                   positions: jax.Array, inv_freq: jax.Array,
                   kv_valid_len: Optional[jax.Array],
                   attend=None, row_mask: Optional[jax.Array] = None,
-                  aux: Optional[dict] = None):
+                  aux: Optional[dict] = None, recur=None):
     """One transformer block. The single source of layer math: every
     forward reaches it through ``_run_stack``.
+
+    A layer whose tree holds the recurrent mixer's leaves (``gdn_*``)
+    runs ``_gdn_mixer`` in place of attention, through ``recur(x) ->
+    (mixed, out)`` (None: a sequence from zeros, nothing kept), and the
+    same experts after it.
 
     ``attend(q, k, v) -> (attn, out)`` is the whole KV step (cache read,
     write and attention; ``out`` is whatever the forward carries or
@@ -1078,69 +1310,83 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
         # the router reads the stream as it enters the block
         with jax.named_scope("moe_route"):
             router_logits = _router_logits(h, lp)
-    with jax.named_scope("attn_proj"):
-        x = block_norm(h, lp, "attn_norm", cfg)
-        if cfg.index_topk:
-            q, k, v, c_q = _latent_qkv(x, lp, cfg, positions, inv_freq,
-                                       with_latent=True)
-        elif cfg.kv_lora_rank:
-            q, k, v = _latent_qkv(x, lp, cfg, positions, inv_freq)
+    if "gdn_wqkvz" in lp:
+        with jax.named_scope("gdn_proj"):
+            x = block_norm(h, lp, "attn_norm", cfg)
+        if recur is None:
+            mixed, new_cache = _gdn_mixer(x, lp, cfg)[0], None
         else:
-            q = qmm(x, lp["wq"])
-            k = qmm(x, lp["wk"])
-            v = qmm(x, lp["wv"])
-            if "bq" in lp:
-                q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-            if cfg.attn_gate:
-                gate = jax.nn.sigmoid(qmm(x, lp["wz"]).astype(jnp.float32))
-            # Keep the head split OUT of the matmuls. Without the barrier the
-            # TPU compiler folds `reshape(B, S, H, hd)` into each dot and
-            # emits a convolution over the head axis whose kernel is the
-            # weight viewed [K, H, hd] and wanted K-minor: the whole stacked
-            # weight is then transposed into a temporary once a program, and
-            # each layer's slice is copied out of it BEFORE its matmul
-            # instead of streaming through it. With it the three compile as
-            # wo / w_up / w_down do: one fusion that takes (stack, layer
-            # index, scale, x), the slice inside, the stack read in place
-            # (tests/test_chip_compile.py holds this on the compiled text).
-            q, k, v = jax.lax.optimization_barrier((q, k, v))
-            q = q.reshape(B, S, cfg.num_heads, cfg.head_dim)
-            k = k.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
-            v = v.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
-            if cfg.qk_norm:         # before the rotation: the pool's keys
-                q = rmsnorm(q, lp["q_norm"], cfg.rms_norm_eps)
-                k = rmsnorm(k, lp["k_norm"], cfg.rms_norm_eps)
-            if "rope" in lp:
-                qr, kr = apply_rope(q, k, positions, inv_freq)
-                q, k = jnp.where(lp["rope"], qr, q), jnp.where(lp["rope"], kr, k)
+            mixed, new_cache = recur(x)
+        h = h + mixed
+    else:
+        with jax.named_scope("attn_proj"):
+            x = block_norm(h, lp, "attn_norm", cfg)
+            if cfg.index_topk:
+                q, k, v, c_q = _latent_qkv(x, lp, cfg, positions, inv_freq,
+                                           with_latent=True)
+            elif cfg.kv_lora_rank:
+                q, k, v = _latent_qkv(x, lp, cfg, positions, inv_freq)
             else:
-                q, k = apply_rope(q, k, positions, inv_freq)
-    index = ()
-    if cfg.index_topk:
-        with jax.named_scope("attn_index"):
-            index = (_index_project(x, c_q, lp, cfg, positions, inv_freq),)
-    with jax.named_scope("attn"):
-        if attend is not None:
-            attn, new_cache = attend(q, k, v, *index)
-        else:
-            attn = kv_cache_of(cfg).attend_tokens(q, k, v, lp, positions,
-                                                  kv_valid_len, *index)
-            new_cache = None
-            if index:       # the layer's selection, for the layers above
-                attn, keep = attn
-                new_cache = (None, keep)
-    with jax.named_scope("attn_proj"):
-        attn = attn.reshape(
-            B, S, cfg.num_heads * (cfg.v_head_dim or cfg.head_dim))
-        if cfg.attn_gate:
-            attn = (attn * gate).astype(attn.dtype)
-        attn_out = qmm(attn, lp["wo"])
-        if "bo" in lp:
-            attn_out = attn_out + lp["bo"]
-        if cfg.post_norms:
-            attn_out = block_norm(attn_out, lp, "post_attn_norm", cfg)
-        if not hyper:
-            h = h + attn_out
+                q = qmm(x, lp["wq"])
+                k = qmm(x, lp["wk"])
+                v = qmm(x, lp["wv"])
+                if "bq" in lp:
+                    q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+                if cfg.attn_gate:
+                    gate = jax.nn.sigmoid(
+                        qmm(x, lp["wz"]).astype(jnp.float32))
+                # Keep the head split OUT of the matmuls. Without the
+                # barrier the TPU compiler folds `reshape(B, S, H, hd)` into
+                # each dot and emits a convolution over the head axis whose
+                # kernel is the weight viewed [K, H, hd] and wanted K-minor:
+                # the whole stacked weight is then transposed into a
+                # temporary once a program, and each layer's slice is copied
+                # out of it BEFORE its matmul instead of streaming through
+                # it. With it the three compile as wo / w_up / w_down do: one
+                # fusion that takes (stack, layer index, scale, x), the slice
+                # inside, the stack read in place (tests/test_chip_compile.py
+                # holds this on the compiled text).
+                q, k, v = jax.lax.optimization_barrier((q, k, v))
+                q = q.reshape(B, S, cfg.num_heads, cfg.head_dim)
+                k = k.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
+                v = v.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
+                if cfg.qk_norm:         # before the rotation: the pool's keys
+                    q = rmsnorm(q, lp["q_norm"], cfg.rms_norm_eps)
+                    k = rmsnorm(k, lp["k_norm"], cfg.rms_norm_eps)
+                if "rope" in lp:
+                    qr, kr = apply_rope(q, k, positions, inv_freq)
+                    q = jnp.where(lp["rope"], qr, q)
+                    k = jnp.where(lp["rope"], kr, k)
+                elif cfg.partial_rotary_factor != 1.0:
+                    q, k = apply_rope_partial(q, k, positions, inv_freq)
+                else:
+                    q, k = apply_rope(q, k, positions, inv_freq)
+        index = ()
+        if cfg.index_topk:
+            with jax.named_scope("attn_index"):
+                index = (_index_project(x, c_q, lp, cfg, positions, inv_freq),)
+        with jax.named_scope("attn"):
+            if attend is not None:
+                attn, new_cache = attend(q, k, v, *index)
+            else:
+                attn = kv_cache_of(cfg).attend_tokens(q, k, v, lp, positions,
+                                                      kv_valid_len, *index)
+                new_cache = None
+                if index:       # the layer's selection, for the layers above
+                    attn, keep = attn
+                    new_cache = (None, keep)
+        with jax.named_scope("attn_proj"):
+            attn = attn.reshape(
+                B, S, cfg.num_heads * (cfg.v_head_dim or cfg.head_dim))
+            if cfg.attn_gate:
+                attn = (attn * gate).astype(attn.dtype)
+            attn_out = qmm(attn, lp["wo"])
+            if "bo" in lp:
+                attn_out = attn_out + lp["bo"]
+            if cfg.post_norms:
+                attn_out = block_norm(attn_out, lp, "post_attn_norm", cfg)
+            if not hyper:
+                h = h + attn_out
     if hyper:
         stream = hc.hc_post(stream, attn_out, h_post, h_res)
         h, h_post, h_res = hc.hc_pre(stream, _hc_weights(lp, "mlp"),
@@ -1152,11 +1398,20 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
             x = block_norm(h, lp, "mlp_norm", cfg)
         mlp = _moe_mlp(x, lp, cfg, router_logits, row_mask, aux)
         if cfg.num_shared_experts:
-            # every token's own, un-weighted: a dense MLP like any other
+            # every token's own — un-weighted, or under a sigmoid gate
+            # of its own (``shared_expert_gate``): a dense MLP like any
+            # other
             with jax.named_scope("mlp"), jax.named_scope("moe_shared"):
-                mlp = mlp + _dense_mlp(x, {
+                shared = _dense_mlp(x, {
                     n: lp["ws_" + n[2:]]
                     for n in ("w_gate", "w_up", "w_down")}, cfg)
+                if cfg.shared_expert_gate:
+                    gate = jax.nn.sigmoid(jnp.sum(
+                        x.astype(jnp.float32)
+                        * lp["ws_gate_w"].astype(jnp.float32),
+                        axis=-1, keepdims=True))
+                    shared = (shared * gate).astype(shared.dtype)
+                mlp = mlp + shared
         with jax.named_scope("moe_experts"):
             if cfg.post_norms:
                 mlp = block_norm(mlp, lp, "post_mlp_norm", cfg)
@@ -1171,6 +1426,108 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
         if not hyper:
             return h + mlp, new_cache
     return hc.hc_post(stream, mlp, h_post, h_res), new_cache
+
+
+def _gdn_mixer(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
+               state: Optional[jax.Array] = None,
+               tail: Optional[jax.Array] = None,
+               n_valid: Optional[jax.Array] = None, step_kernel=None):
+    """A recurrent layer's token mixer over its normed input ``x`` (B, S,
+    D): the gated delta rule (ops/gated_delta.py) behind a causal
+    convolution, its output normed a head, gated and projected. Returns
+    ``(mixed (B, S, D), state, tail)``.
+
+    ``state`` (B, Hv, dk, dv) and ``tail`` (B, K - 1, channels) are what
+    the rows' sequences carried in (None: zeros, a sequence's start).
+    ``n_valid`` (B,) is how many of the S tokens belong to the row's
+    sequence: the tokens past it — the padding of a chunk's grant, an
+    idle row of a decode round (0 of its 1) — decay nothing and write
+    nothing (``g = 0``, ``beta = 0``), and the convolution's new tail
+    ends at the last valid one. A paged cache forgives garbage rows past
+    a length; a state does not. S == 1 is the decode step
+    (``gated_delta_step``; ``step_kernel(q, k, v, g, beta, active) ->
+    (o, state)`` in its place where the cache object runs it as the
+    kernel over its own leaf: ``state`` is then None and what comes back
+    is the cache's), anything longer the chunked scan."""
+    B, S, _ = x.shape
+    Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    Ch, f32 = cfg.linear_channels, jnp.float32
+    if tail is None:        # a sequence's start
+        state = jnp.zeros((B, Hv, dk, dv), f32)
+        tail = jnp.zeros((B, cfg.linear_conv_kernel_dim - 1, Ch), x.dtype)
+    with jax.named_scope("gdn_proj"):
+        qkvz = qmm(x, lp["gdn_wqkvz"])
+        ba = qmm(x, lp["gdn_wba"]).astype(f32)
+        # the head split stays out of the matmul (``decoder_layer``)
+        qkvz, ba = jax.lax.optimization_barrier((qkvz, ba))
+        u, z = qkvz[..., :Ch], qkvz[..., Ch:]
+    with jax.named_scope("gdn_conv"):
+        u, tail = gd.causal_conv(u, tail, lp["gdn_conv"], n_valid)
+    step = S == 1
+    with jax.named_scope("gdn_step" if step else "gdn_scan"):
+        q = u[..., :Hk * dk].reshape(B, S, Hk, dk)
+        k = u[..., Hk * dk:2 * Hk * dk].reshape(B, S, Hk, dk)
+        v = u[..., 2 * Hk * dk:].reshape(B, S, Hv, dv)
+        # a key head serves Hv / Hk consecutive value heads
+        q = jnp.repeat(gd.l2norm(q) * dk ** -0.5, Hv // Hk, axis=2)
+        k = jnp.repeat(gd.l2norm(k), Hv // Hk, axis=2)
+        beta = jax.nn.sigmoid(ba[..., :Hv])
+        g = -jnp.exp(lp["gdn_A_log"].astype(f32)) * jax.nn.softplus(
+            ba[..., Hv:] + lp["gdn_dt_bias"].astype(f32))
+        if n_valid is not None:
+            valid = (jnp.arange(S)[None, :] < n_valid[:, None])[..., None]
+            beta, g = jnp.where(valid, beta, 0.0), jnp.where(valid, g, 0.0)
+        if step and step_kernel is not None:
+            o, new = step_kernel(
+                q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                jnp.ones((B,), bool) if n_valid is None else n_valid > 0)
+            o = o[:, None]
+        elif step:
+            o, new = gd.gated_delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                         beta[:, 0], state)
+            o = o[:, None]
+            if n_valid is not None:     # an idle row's state as it was,
+                # bit for bit and whatever it holds
+                new = jnp.where((n_valid > 0)[:, None, None, None], new,
+                                state)
+        else:
+            o, new = gd.gated_delta_chunked(q, k, v, g, beta, state)
+    with jax.named_scope("gdn_proj"):
+        # the norm over a head's values, its weight applied as it is,
+        # times silu(z); heads joined, projected back to the stream
+        of = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                               + cfg.rms_norm_eps)
+        y = of * lp["gdn_norm"].astype(f32) * jax.nn.silu(
+            z.reshape(B, S, Hv, dv).astype(f32))
+        mixed = qmm(y.reshape(B, S, Hv * dv).astype(x.dtype), lp["gdn_wout"])
+    return mixed, new, tail
+
+
+def _gdn_recur(cfg: LlamaConfig, load, store=None, step=None,
+               n_valid: Optional[jax.Array] = None):
+    """A forward's ``recur`` (``_run_stack``) over the cache object's
+    ``(load, store, step)`` (models/kv_cache.py ``RecurrentKV.recur``).
+    The state's read and write are the recurrence's own and carry its
+    scope."""
+    def recur(x, lp, li, state):
+        li = li.reshape(())
+        lg = li - li // cfg.full_attention_interval
+        scope = "gdn_step" if x.shape[1] == 1 else "gdn_scan"
+        with jax.named_scope(scope):
+            s0, tail0 = load(lg, state, lp)
+        if step is not None:        # the kernel steps the carried leaf
+            kernel = lambda *a: step(lg, state, *a)         # noqa: E731
+            mixed, state, tail = _gdn_mixer(x, lp, cfg, None, tail0,
+                                            n_valid, kernel)
+            s = None
+        else:
+            mixed, s, tail = _gdn_mixer(x, lp, cfg, s0, tail0, n_valid)
+        if store is None:
+            return mixed, (s, tail)
+        with jax.named_scope(scope):
+            return mixed, store(lg, state, s, tail)
+    return recur
 
 
 def _hc_args(cfg: LlamaConfig) -> dict:
@@ -1282,7 +1639,8 @@ def run_layers(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
                kv_valid_len: Optional[jax.Array] = None) -> jax.Array:
     """Scan a (possibly partial) stacked layer stack over hidden states,
     no KV cache — the per-stage body for pipeline parallelism."""
-    if cfg.hc_mult or jax.tree.leaves(layers)[0].shape[0] != cfg.num_layers:
+    if cfg.hc_mult or cfg.recurrent or jax.tree.leaves(
+            layers)[0].shape[0] != cfg.num_layers:
         # a stage does not know which of the model's layers it holds,
         # and what crosses a stage under hyper-connections is n streams
         _refuse_kinds(cfg, "a pipeline stage's layer stack")
@@ -1410,6 +1768,7 @@ def apply(params: Params, cfg: LlamaConfig, tokens: jax.Array,
     """
     inv_freq = _inv_freq(cfg)
     attend = xs = selection = None
+    recur: dict = {}
     kvc = kv_cache_of(cfg)
     if cfg.index_topk:      # the keep mask's shape: queries x keys
         keys = tokens.shape[1] if kv_cache is None \
@@ -1420,6 +1779,15 @@ def apply(params: Params, cfg: LlamaConfig, tokens: jax.Array,
             kv_valid_len = positions[:, -1] + 1
         row_start = positions[:, 0]
         xs = {"cache_" + n: kv_cache[n] for n in kvc.leaves}
+        if cfg.recurrent:
+            # a layer's slices of the dense cache ride the scan: the
+            # rows' state as the sequence so far left it (zeros at its
+            # start), out again as a scan output
+            recur["recur"] = _gdn_recur(
+                cfg, lambda lg, _, lp: tuple(
+                    kvc.from_zeros(lp[name], row_start == 0)
+                    for name in _RECURRENT_CACHE),
+                n_valid=kv_valid_len - row_start)
 
         def attend(q, k, v, lp, li, _, *index):
             # this chunk written at its absolute positions
@@ -1431,7 +1799,7 @@ def apply(params: Params, cfg: LlamaConfig, tokens: jax.Array,
 
     h, new, _ = _run_model(
         params, cfg, _embed(params, tokens, cfg.embed_scale), positions,
-        inv_freq, kv_valid_len, attend, xs=xs, selection=selection)
+        inv_freq, kv_valid_len, attend, xs=xs, selection=selection, **recur)
     new_cache = None if new is None else dict(
         zip(kv_cache_of(cfg).leaves, new))
     if return_hidden:
@@ -1500,6 +1868,13 @@ def _refuse_kinds(cfg: LlamaConfig, fn_name: str) -> None:
             f"wide, widened after the embedding and summed before the "
             f"final norm by the forwards that run every stack "
             f"(_run_model)")
+    if cfg.recurrent:
+        raise NotImplementedError(
+            f"{fn_name}: recurrent layers (full_attention_interval="
+            f"{cfg.full_attention_interval}) are not supported here: a "
+            f"sequence's state is neither passed around a ring nor handed "
+            f"from a pipeline stage that does not know which layers it "
+            f"holds")
     if layer_kinds(cfg) or cfg.embed_scale != 1.0 or cfg.kv_lora_rank or (
             cfg.num_experts and cfg.moe_impl == "dropless"):
         raise NotImplementedError(

@@ -43,7 +43,10 @@ QTensor = dict[str, jax.Array]
 _QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "wz", "w_gate", "w_up",
                      "w_down", "ws_gate", "ws_up", "ws_down",
                      # latent attention's five (models/configs.py)
-                     "wq_a", "wq_b", "wkv_a", "wk_b", "wv_b")
+                     "wq_a", "wq_b", "wkv_a", "wk_b", "wv_b",
+                     # a recurrent layer's two wide ones (its ``gdn_wba``,
+                     # 64 columns that set decay and write strength, stays)
+                     "gdn_wqkvz", "gdn_wout")
 _LAYER_STACKS = ("layers", "dense_layers")
 
 

@@ -85,3 +85,14 @@ def apply_rope(q: jax.Array, k: jax.Array, positions: jax.Array,
         return out.astype(x.dtype)
 
     return rot(q), rot(k)
+
+
+def apply_rope_partial(q: jax.Array, k: jax.Array, positions: jax.Array,
+                       inv_freq: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``apply_rope`` over the FIRST ``2 * len(inv_freq)`` values of each
+    head (pairs (i, i + half) of that part); the values past them pass
+    unrotated (a ``partial_rotary_factor`` below 1)."""
+    rot = 2 * inv_freq.shape[-1]
+    qr, kr = apply_rope(q[..., :rot], k[..., :rot], positions, inv_freq)
+    return (jnp.concatenate([qr, q[..., rot:]], axis=-1),
+            jnp.concatenate([kr, k[..., rot:]], axis=-1))

@@ -328,9 +328,13 @@ def engine_programs(config_name: str, engine_layers: int,
     n_pages = 1 + min(B * pmax, max(pmax, -(-pool_tokens // page)))
     state = on(jax.eval_shape(
         lambda: programs.slot_state(cfg.vocab_size, B, pmax)))
+    # a recurrent state is a slot's, beside the pages; a verify round
+    # cannot roll one back (the engine refuses it by name)
+    recurrent = cfg.recurrent
     pool = jax.eval_shape(lambda: llama.init_paged_kv_cache(
         cfg, n_pages, page, progs.spec.dtype,
-        quantized=bool(ecfg.kv_quant)))
+        quantized=bool(ecfg.kv_quant),
+        **({"slots": B} if recurrent else {})))
     state["cache"] = {
         k: sds(v.shape, v.dtype, programs.cache_placement(
             dev, v.ndim, progs.spec.use_kernel)) for k, v in pool.items()}
@@ -350,7 +354,8 @@ def engine_programs(config_name: str, engine_layers: int,
             for greedy, name in ((True, "greedy"), (False, "sampled"))]
     walk += [
         ("verify_round_sampled", progs.verify_fn(window, False, B),
-         (p_sds, state, key, slots, sds((B, S - 1), jnp.int32), slots)),
+         (p_sds, state, key, slots, sds((B, S - 1), jnp.int32), slots))
+    ] * (not recurrent) + [
         ("chunk_extend_512", progs.chunk_extend_fn(window, "accum"),
          (*chunk, sds((1, window), jnp.int32)))]
     # the chunk program of several prompts, at its largest rung (none
