@@ -229,6 +229,16 @@ _STATS_TEMPLATE = {
     # dispatched whose tail ran one (every one of an armed engine's).
     "tail_kernel": 0,
     "tail_kernel_rounds": 0,
+    # 1 where the chunk programs' recurrence (a model with recurrent
+    # layers: ops/gated_delta.py) runs the chunked scan as ONE Pallas
+    # kernel (a TPU, whole 64-token blocks, 128-lane heads in pairs), 0
+    # where it runs the XLA stages or there is no recurrent layer.
+    # Static per engine; a TPU engine with recurrent layers that reads 0
+    # also counts a downgrade. ``scan_kernel_chunks``: the chunk
+    # programs dispatched whose recurrence ran it (every one of an
+    # armed engine's: ``sched_chunk_programs``).
+    "scan_kernel": 0,
+    "scan_kernel_chunks": 0,
     # Times prewarm() had to shrink the auto-sized KV pool because the
     # worst-case request did not fit (each one also logs an
     # ``engine_pool_shrink`` event). 0 on a healthy build: > 0 means
@@ -1485,6 +1495,7 @@ class Engine:
         # (written once at build, before any reader exists).
         out["downgrades"] = len(self._downgrades)
         out["tail_kernel"] = int(self.programs.tail.kernel)
+        out["scan_kernel"] = int(self.programs.spec.scan_kernel)
         # Model-vs-measured drift over completed rounds: 1.0 = the
         # step-cost model predicts round time; >1 = rounds run slower
         # than planned (regression, or a stale artifact prior); 0.0
@@ -3486,6 +3497,8 @@ class Engine:
             self._bump("sched_prefill_tokens", prefilled)
             self._bump("sched_prefill_padded_tokens", padded)
             self._bump("sched_chunk_programs", programs)
+            if self.programs.spec.scan_kernel:
+                self._bump("scan_kernel_chunks", programs)
             if decoded:
                 self._bump("sched_interleaved_rounds")
         parts = int(decoded)

@@ -31,7 +31,7 @@ from jax.sharding import Mesh
 from ..models import llama
 from ..models.configs import LlamaConfig
 from ..models.kv_cache import kv_cache_of
-from ..ops import head_argmax
+from ..ops import gated_delta, head_argmax
 from ..ops.fused_sampler import (choose_tile, fused_unembed_sample,
                                  fused_unembed_sample_tp,
                                  fused_verify_sample,
@@ -381,6 +381,10 @@ class ProgramSpec:
     # the decode program returns the layers' scalars
     # (llama.layer_stat_names): dropless experts, hyper-connections
     layer_stats: bool
+    # a recurrent layer's chunked scan as the Pallas kernel
+    # (ops/gated_delta.py) in every chunk program: the mixer reads it
+    # off a chunk's shapes, and every chunk is whole pages
+    scan_kernel: bool
     tail: Tail
     # (feature, fallback, reason) of every gate that resolved below the
     # hardware's potential, the tail's among them, in order
@@ -419,6 +423,20 @@ class ProgramSpec:
                 f"{model_cfg.qk_nope_head_dim} / {model_cfg.v_head_dim} / "
                 f"{model_cfg.qk_rope_head_dim}: the chunk kernel takes "
                 f"lane-width pages and keys, whole sublane tiles of values"))
+        scan_kernel = False
+        if model_cfg.recurrent:
+            scan = (page_size, model_cfg.linear_num_key_heads,
+                    model_cfg.linear_num_value_heads,
+                    model_cfg.linear_key_head_dim,
+                    model_cfg.linear_value_head_dim)
+            scan_kernel = gated_delta.scan_kernel_armed(*scan)
+            if not scan_kernel and jax.default_backend() == "tpu":
+                downgrades.append((
+                    "scan_kernel", "xla_chunked",
+                    f"page {scan[0]}, key / value heads {scan[1]} / "
+                    f"{scan[2]} of {scan[3]} / {scan[4]}: the scan kernel "
+                    f"takes whole 64-token blocks and 128-lane heads, two "
+                    f"value heads a key head, in whole groups"))
         tail = resolve_tail(params, model_cfg, mesh)
         if tail.downgrade:
             downgrades.append(tail.downgrade)
@@ -429,7 +447,8 @@ class ProgramSpec:
             use_prefix_kernel=use_prefix_kernel,
             layer_stats=(bool(routed_stacks(params, model_cfg))
                          or bool(model_cfg.hc_mult)),
-            tail=tail, downgrades=tuple(downgrades))
+            scan_kernel=scan_kernel, tail=tail,
+            downgrades=tuple(downgrades))
 
     def pin_cache(self, cache):
         """Constrain pool leaves to row-major inside a jitted program so

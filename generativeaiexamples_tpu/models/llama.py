@@ -102,8 +102,10 @@ HC_SCOPES = ("hc_pre", "hc_post")
 #: in-projections, the gated output norm and the output projection),
 #: ``gdn_conv`` (the causal convolution and its tail), and the recurrence
 #: as ``gdn_step`` (one token a row: the decode step) or ``gdn_scan``
-#: (the chunked form: every other forward) — ops/gated_delta.py; the
-#: state's write after a scan is ``gdn_state``.
+#: (the chunked form: every other forward; the kernel
+#: ``gated_delta_scan`` where it takes the shapes, else the XLA stages)
+#: — ops/gated_delta.py; the state's write after a scan is
+#: ``gdn_state``.
 GDN_SCOPES = ("gdn_proj", "gdn_conv", "gdn_step", "gdn_scan", "gdn_state")
 
 
@@ -1448,7 +1450,9 @@ def _gdn_mixer(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
     (``gated_delta_step``; ``step_kernel(q, k, v, g, beta, active) ->
     (o, state)`` in its place where the cache object runs it as the
     kernel over its own leaf: ``state`` is then None and what comes back
-    is the cache's), anything longer the chunked scan."""
+    is the cache's), anything longer the chunked scan: ONE Pallas kernel
+    where ``gd.scan_kernel_armed`` says so of the shapes (a TPU, whole
+    64-token blocks, 128-lane heads in pairs), else the XLA form."""
     B, S, _ = x.shape
     Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
@@ -1465,13 +1469,17 @@ def _gdn_mixer(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
     with jax.named_scope("gdn_conv"):
         u, tail = gd.causal_conv(u, tail, lp["gdn_conv"], n_valid)
     step = S == 1
+    # the scan as the kernel, where it takes the shapes here
+    scan_kernel = not step and gd.scan_kernel_armed(S, Hk, Hv, dk, dv)
     with jax.named_scope("gdn_step" if step else "gdn_scan"):
         q = u[..., :Hk * dk].reshape(B, S, Hk, dk)
         k = u[..., Hk * dk:2 * Hk * dk].reshape(B, S, Hk, dk)
         v = u[..., 2 * Hk * dk:].reshape(B, S, Hv, dv)
-        # a key head serves Hv / Hk consecutive value heads
-        q = jnp.repeat(gd.l2norm(q) * dk ** -0.5, Hv // Hk, axis=2)
-        k = jnp.repeat(gd.l2norm(k), Hv // Hk, axis=2)
+        q, k = gd.l2norm(q) * dk ** -0.5, gd.l2norm(k)
+        if not scan_kernel:
+            # a key head serves Hv / Hk consecutive value heads (the
+            # kernel reads q and k by key head)
+            q, k = (jnp.repeat(a, Hv // Hk, axis=2) for a in (q, k))
         beta = jax.nn.sigmoid(ba[..., :Hv])
         g = -jnp.exp(lp["gdn_A_log"].astype(f32)) * jax.nn.softplus(
             ba[..., Hv:] + lp["gdn_dt_bias"].astype(f32))
@@ -1491,6 +1499,12 @@ def _gdn_mixer(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
                 # bit for bit and whatever it holds
                 new = jnp.where((n_valid > 0)[:, None, None, None], new,
                                 state)
+        elif scan_kernel:
+            # v read where the convolution left it, no slice in between
+            o, new = gd.gated_delta_chunked_kernel(
+                q.reshape(B, S, Hk * dk), k.reshape(B, S, Hk * dk), u, g,
+                beta, state, v_at=2 * Hk * dk)
+            o = o.reshape(B, S, Hv, dv)
         else:
             o, new = gd.gated_delta_chunked(q, k, v, g, beta, state)
     with jax.named_scope("gdn_proj"):

@@ -9,11 +9,13 @@ caller), value ``v``, log-decay ``g <= 0`` and write strength ``beta``:
     S <- S + k d^T                      corrected (the delta rule)
     o  = S^T q
 
-Three forms of the same recurrence, all taking and returning float32
-``q, k`` (B, T, H, dk), ``v`` (B, T, H, dv), ``g, beta`` (B, T, H) and a
-state (B, H, dk, dv), computed in float32 and handed back in the dtype
-it came in (the cache's is float32, always: a test hands in bf16 to
-show what that would cost):
+Four forms of the same recurrence, the first three taking and returning
+float32 ``q, k`` (B, T, H, dk), ``v`` (B, T, H, dv), ``g, beta`` (B, T,
+H) and a state (B, H, dk, dv), computed in float32 and handed back in
+the dtype it came in (the cache's is float32, always: a test hands in
+bf16 to show what that would cost). The recurrence token by token is
+what the chunked form is held to, the chunked form what its kernel is
+held to, each at 2e-5 of the largest value (tests/test_gated_delta.py):
 
 - :func:`gated_delta_step`: one token a row, the decode step. The work
   is the state read once and written once (2 x 64 KiB a head at 128 x
@@ -34,7 +36,17 @@ show what that would cost):
   ``S0`` is computed for all blocks at once; a short scan over the
   blocks carries the state. A token with ``g = 0`` and ``beta = 0``
   leaves the state as it found it: that is how the caller pads (a ragged
-  last block here, the tokens past a chunk's valid length there).
+  last block here, the tokens past a chunk's valid length there). A
+  dozen float32 XLA stages whose operands and results cross HBM: the
+  kernel's oracle, the CPU's path and the ragged lengths'.
+- :func:`gated_delta_chunked_kernel`: the chunked form as ONE Pallas
+  kernel, on a TPU wherever the shapes allow
+  (:func:`scan_kernel_armed`): the blocks of a (row, group of heads) in
+  order, the group's states in VMEM, a block's decays, ``k k^T``,
+  inverse, corrections and state update never leaving the chip, the
+  operands read as the mixer has them — (B, T, heads x width), q and k
+  by KEY head. The same arithmetic: float32 operands at HIGHEST
+  precision, the inverse by substitution and merges.
 
 :func:`causal_conv` is the depthwise causal convolution in front of it,
 whose cache is the last ``K - 1`` inputs of a sequence.
@@ -42,6 +54,7 @@ whose cache is the last ``K - 1`` inputs of a sequence.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -275,3 +288,291 @@ def gated_delta_chunked(q, k, v, g, beta, state, block: int = 64):
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 2), 1, 3).reshape(
         B, nb * c, H, o.shape[-1])
     return o[:, :T], state
+
+
+_SCAN_BLOCK = 64    # tokens a block of the scan kernel, as the XLA form's
+_SCAN_PAIRS = 2     # key heads, each with its two value heads, a grid step
+
+
+def scan_kernel_supported(tokens: int, key_heads: int, value_heads: int,
+                          dk: int, dv: int) -> bool:
+    """Whether :func:`gated_delta_chunked_kernel` takes these shapes:
+    whole blocks of tokens, a head a whole number of 128-lane slices,
+    two value heads a key head, whole groups of key heads."""
+    return (tokens > 0 and tokens % _SCAN_BLOCK == 0
+            and dk % 128 == 0 and dv % 128 == 0
+            and value_heads == 2 * key_heads
+            and key_heads % _SCAN_PAIRS == 0)
+
+
+def scan_kernel_armed(tokens: int, key_heads: int, value_heads: int,
+                      dk: int, dv: int) -> bool:
+    """Whether a scan over these shapes runs the kernel HERE: on a TPU,
+    where it takes them. Everywhere else :func:`gated_delta_chunked`
+    runs (the CPU's path; a test hands the mixer the interpreted kernel
+    by pointing this name at :func:`scan_kernel_supported`)."""
+    return jax.default_backend() == "tpu" and scan_kernel_supported(
+        tokens, key_heads, value_heads, dk, dv)
+
+
+def _dot(a, b, dims=((1,), (0,))):
+    """A float32 product at the accuracy ``Precision.HIGHEST`` gives."""
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _spread(x, ones):
+    """``x @ ones`` for a 0 / 1 matrix, EXACT in three bf16 passes: the
+    three bf16 terms of a float32 sum to it, and each meets one 1."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    hi = x.astype(bf16)
+    r = x - hi.astype(f32)
+    mid = r.astype(bf16)
+    lo = (r - mid.astype(f32)).astype(bf16)
+    return sum(jnp.dot(t, ones, preferred_element_type=f32)
+               for t in (hi, mid, lo))
+
+
+def gated_delta_chunked_kernel(q, k, v, g, beta, state, *, v_at: int = 0,
+                               interpret: Optional[bool] = None):
+    """:func:`gated_delta_chunked` as ONE Pallas kernel, over the
+    operands as the mixer has them: ``q, k`` (B, T, Hk * dk) by KEY head
+    (value heads ``2j, 2j + 1`` share key head ``j``: no ``repeat``),
+    ``v`` (B, T, Hv * dv) — or a wider array that holds the values from
+    lane ``v_at`` on (the convolution's whole output: a slice of it
+    would be a copy in front of the kernel) —, ``g, beta`` (B, T, Hv),
+    ``state`` (B, Hv, dk, dv). Returns ``(o (B, T, Hv * dv) float32,
+    state)``.
+
+    Grid (row, group of ``_SCAN_PAIRS`` key heads, block of 64 tokens),
+    the blocks in order, the group's states float32 in VMEM from the
+    first block to the last. A key head's pair of value heads is worked
+    on side by side: a block's token-by-token matrices of both lie on
+    128 lanes of a tile — ``[q; k] k^T`` is one product a key head —
+    and, block-diagonal in a (128, 128) tile, multiply both heads' token
+    rows stacked on the sublanes; the group's pairs lie side by side in
+    turn, so everything elementwise is written once for the group and
+    only the products are a pair's or a head's. ``(I + a)^-1`` as
+    :func:`_unit_lower_inverse` computes it: the 16-row diagonal blocks
+    of every head of the group at once by forward substitution (15
+    steps; row ``i``'s coefficients spread over the lanes beforehand, so
+    a step is a multiply and a sum down the sublanes), then the two
+    levels of merges as ``X - (X a_off) X``. Every product takes float32
+    operands at HIGHEST precision; what only moves numbers (a head's
+    column of ``g`` to the lanes that want it, a coefficient over its
+    block's lanes) is a product with a 0 / 1 matrix, exact in three bf16
+    passes.
+
+    The call is jitted and the body keeps to few and ``lax`` forms: a
+    warm start traces and lowers some sixty chunk programs that hold the
+    kernel, and pays for the body once a shape and once a program — a
+    first form, unrolled over four key heads with ``jnp.where`` and
+    ``//`` in it, made a warm start 24 s longer (PERF.md section 6, PR
+    47)."""
+    Hv = g.shape[-1]
+    if not scan_kernel_supported(g.shape[1], Hv // 2, Hv, *state.shape[-2:]):
+        raise ValueError(
+            f"no scan kernel for T={g.shape[1]}, heads {Hv // 2} / {Hv}, "
+            f"widths {state.shape[-2:]}")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _scan_kernel(q, k, v, g, beta, state, v_at=v_at,
+                        interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("v_at", "interpret"))
+def _scan_kernel(q, k, v, g, beta, state, *, v_at: int, interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, Hv = g.shape
+    dk, dv = state.shape[-2:]
+    Hk, c, P, s16 = Hv // 2, _SCAN_BLOCK, _SCAN_PAIRS, _SOLVE
+    nb, f32 = T // c, jnp.float32
+    if v_at % (2 * P * dv):     # no whole block of a group's values
+        v, v_at = v[..., v_at:v_at + Hv * dv], 0
+    v_block = v_at // (2 * P * dv)
+
+    def iota(shape, axis):
+        return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+    def keep(mask, x, other=0.0):
+        # (not ``jnp.where``, nor ``//`` and ``%`` below: each is a jitted
+        # function the kernel's lowering traces anew, in every program)
+        return jax.lax.select(mask, jnp.broadcast_to(x, mask.shape),
+                              jnp.full(mask.shape, other, x.dtype))
+
+    b16, b32, bc_ = (n.bit_length() - 1 for n in (s16, 2 * s16, c))
+
+    def kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s_in_ref, o_ref,
+               s_out_ref, s_scr):
+        t = pl.program_id(2)
+
+        @pl.when(t == 0)
+        def _():
+            s_scr[...] = s_in_ref[0].astype(f32)
+
+        # (c, W): rows a token, lanes (key head, value head of its pair,
+        # token) — every pair of the group side by side
+        W = P * 2 * c
+        row, lane = iota((c, W), 0), iota((c, W), 1)
+        col = lane & (c - 1)
+        below, above, diag = row > col, row < col, row == col
+        same16 = row >> b16 == col >> b16
+        offs = ((s16, (row >> b32 == col >> b32)
+                 & (row >> b16 > col >> b16)),
+                (2 * s16, row >> b32 > col >> b32))
+        # (2c, W): a pair's two heads block-diagonal in its 2c lanes
+        own = iota((2 * c, W), 0) >> bc_ == (iota((2 * c, W), 1) >> bc_) & 1
+        sub, ln = iota((s16, W), 0), iota((s16, W), 1) & (s16 - 1)
+        ones = (iota((2 * c, 2 * c), 0) >> b16
+                == iota((2 * c, 2 * c), 1) >> b16).astype(jnp.bfloat16)
+
+        def pairs(x):       # (., W) or (., P * d) -> its P lane slices
+            n = x.shape[1] // P
+            return [x[:, p * n:(p + 1) * n] for p in range(P)]
+
+        def both(x2):       # (c, W) side by side -> (2c, W) diagonal
+            return keep(own, jnp.concatenate([x2, x2], axis=0))
+
+        def along(x2):      # (c, W) column-spread -> its (1, W) row
+            return jnp.sum(keep(diag, x2), axis=0, keepdims=True)
+
+        # the running sum of g inside the block, every head at once; a
+        # head's column spread over the lanes that want it by a 0 / 1
+        # matrix (exact)
+        tri = (iota((c, c), 0) >= iota((c, c), 1)).astype(f32)
+        g_run, betas = _dot(tri, g_ref[0]), beta_ref[0]     # (c, Hv)
+        first = 2 * P * pl.program_id(1)    # the group's first value head
+
+        def wants(width, at):   # (Hv, width): lane l wants head at(l)
+            lanes = iota((Hv, width), 1)
+            return iota((Hv, width), 0) == first + at(lanes)
+
+        def of_pair(d, e):      # head e of the pair whose d lanes l is in
+            return lambda l: 2 * jax.lax.div(l, jnp.full_like(l, d)) + e
+
+        widths = (W,) + (P * dk,) * 2 + (P * dv,) * 2 * (dv != dk)
+        spreads = _spread(
+            jnp.concatenate([g_run, betas], axis=0),
+            jnp.concatenate(
+                [wants(W, lambda l: l >> bc_)] + [wants(
+                    P * d, of_pair(d, e)) for d in (dk, dv)[:1 + (dv != dk)]
+                    for e in (0, 1)], axis=1).astype(jnp.bfloat16))
+        at, parts = 0, []
+        for n in widths:
+            parts.append(spreads[:, at:at + n])
+            at += n
+        G, bt = parts[0][:c], parts[0][c:]                  # (c, W)
+        # (2c, P * d): rows (head of the pair, token)
+        Gs, bs = (jnp.concatenate([parts[1][r], parts[2][r]], axis=0)
+                  for r in (slice(0, c), slice(c, 2 * c)))
+        bv = bs if dv == dk else jnp.concatenate(
+            [parts[3][c:], parts[4][c:]], axis=0)
+
+        diff = G - along(G)                         # G_row - G_lane
+        decay = jnp.exp(keep(row >= col, diff, -jnp.inf))
+        q, k = q_ref[0], k_ref[0]                   # (c, P * dk)
+        # [q; k] k^T once a key head, for both its value heads
+        prod = [_dot(jnp.concatenate([qh, kh], axis=0),
+                     jnp.concatenate([kh, kh], axis=0), ((1,), (1,)))
+                for qh, kh in zip(pairs(q), pairs(k))]
+        qk = jnp.concatenate([x[:c] for x in prod], axis=1)
+        kk = jnp.concatenate([x[c:] for x in prod], axis=1)
+        a = keep(below, bt * kk * decay)
+        # a^T: k k^T is symmetric, so the same three factors with row
+        # and lane exchanged
+        a_t = keep(above, along(bt) * kk * jnp.exp(
+            keep(above, -diff, -jnp.inf)))
+        diag_t = keep(same16, a_t)
+        # the diagonal blocks' inverses, every head of the group at
+        # once: (16, W) rows j of a block, lanes (pair, head, block, l)
+        packed = sum(diag_t[m * s16:(m + 1) * s16] for m in range(c // s16))
+        # row i's coefficients a_ij over their block's lanes
+        steps = (s16 - 1) * s16
+        coeff = jnp.concatenate([_spread(x, ones) for x in pairs(keep(
+            (iota((steps, W), 0) >> b16) + 1
+            == iota((steps, W), 1) & (s16 - 1),
+            jnp.concatenate([packed] * (s16 - 1), axis=0)))],
+            axis=1)                                 # (15 * 16, W)
+        eye = (sub == ln).astype(f32)
+        x = eye
+        for i in range(1, s16):
+            new = eye[i:i + 1] - jnp.sum(
+                coeff[(i - 1) * s16:i * s16] * x, axis=0, keepdims=True)
+            x = jax.lax.select(sub == i, jnp.broadcast_to(new, x.shape), x)
+        inv = both(keep(same16, jnp.concatenate([x] * (c // s16), axis=0)))
+        # a level: X - (X a_off) X, whose only rows that change are the
+        # second block's of each pair of n-row blocks
+        for n, off in offs:
+            odd = [slice(r, r + n) for r in range(n, 2 * c, 2 * n)]
+            low = jnp.concatenate([
+                _dot(_dot(lhs, mid), rhs) for lhs, mid, rhs in zip(
+                    pairs(jnp.concatenate([inv[r] for r in odd], axis=0)),
+                    pairs(both(keep(off, a))), pairs(inv))], axis=1)
+            rows = []
+            for m, r in enumerate(odd):
+                rows += [inv[r.start - n:r.start],
+                         inv[r] - low[m * n:(m + 1) * n]]
+            inv = jnp.concatenate(rows, axis=0)
+        # token rows of a pair's two heads stacked: (2c, P * d)
+        k2, q2 = (jnp.concatenate([x_, x_], axis=0) for x_ in (k, q))
+        v = v_ref[0]                                # lanes (pair, head, dv)
+        vs = jnp.concatenate([jnp.concatenate(
+            [v[:, (2 * p + e) * dv:(2 * p + e + 1) * dv] for p in range(P)],
+            axis=1) for e in (0, 1)], axis=0)
+        last = jnp.concatenate([jnp.broadcast_to(
+            Gs[e * c + c - 1:(e + 1) * c], (c, P * dk)) for e in (0, 1)],
+            axis=0)
+        # a head's whole decay over the block, a (1, P * dv) row
+        whole = [parts[-2 + e][c - 1:c] for e in (0, 1)]
+        grow = jnp.exp(Gs)
+        kb, vb, q_in = k2 * (bs * grow), vs * bv, q2 * grow
+        k_out = k2 * jnp.exp(last - Gs)
+        mix = pairs(both(qk * decay))
+        for p, (inv_p, kb_p, vb_p, q_p, k_p) in enumerate(zip(
+                pairs(inv), pairs(kb), pairs(vb), pairs(q_in),
+                pairs(k_out))):
+            wu = _dot(inv_p, jnp.concatenate([kb_p, vb_p], axis=1))
+            w, u = wu[:, :dk], wu[:, dk:]
+            d, qs = [], []
+            for e in (0, 1):
+                rows = slice(e * c, (e + 1) * c)
+                ws = _dot(jnp.concatenate([w[rows], q_p[rows]], axis=0),
+                          s_scr[2 * p + e])
+                d.append(u[rows] - ws[:c])
+                qs.append(ws[c:])
+            o = jnp.concatenate(qs, axis=0) + _dot(
+                mix[p], jnp.concatenate(d, axis=0))
+            for e in (0, 1):
+                rows = slice(e * c, (e + 1) * c)
+                h = 2 * p + e
+                o_ref[0, :, h * dv:(h + 1) * dv] = o[rows]
+                s_scr[h] = jnp.exp(whole[e][:, p * dv:(p + 1) * dv]) \
+                    * s_scr[h] + _dot(k_p[rows], d[e], ((0,), (0,)))
+
+        @pl.when(t == nb - 1)
+        def _():
+            s_out_ref[0] = s_scr[...].astype(s_out_ref.dtype)
+
+    def tokens(width, at=0):
+        return pl.BlockSpec((1, c, width), lambda b, h, t: (b, t, at + h))
+
+    whole = pl.BlockSpec((1, c, Hv), lambda b, h, t: (b, t, 0))
+    states = pl.BlockSpec((1, 2 * P, dk, dv), lambda b, h, t: (b, h, 0, 0))
+    o, state = pl.pallas_call(
+        kernel,
+        grid=(B, Hk // P, nb),
+        in_specs=[tokens(P * dk), tokens(P * dk),
+                  tokens(2 * P * dv, v_block), whole, whole, states],
+        out_specs=[tokens(2 * P * dv), states],
+        out_shape=[jax.ShapeDtypeStruct((B, T, Hv * dv), f32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        scratch_shapes=[pltpu.VMEM((2 * P, dk, dv), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="gated_delta_scan",
+    )(q.astype(f32), k.astype(f32), v.astype(f32), g.astype(f32),
+      beta.astype(f32), state)
+    return o, state

@@ -1051,3 +1051,28 @@ def test_gated_delta_step_kernel_compiles(topo, state):
     assert "gated_delta_step" in text and "tpu_custom_call" in text
     # no second copy of the 0.6 GB leaf beside the donated one
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("rows", [1, 4])
+def test_gated_delta_scan_kernel_compiles(topo, rows):
+    """The chunked scan of a gated delta-rule layer as ONE kernel at
+    qwen3-next-80b-a3b-instruct's widths (a 512-token chunk of one
+    prompt and of four, 16 key / 32 value heads of 128 x 128): Mosaic
+    takes the float32 products at HIGHEST precision, the contraction
+    over tokens in the state's update and the 64-lane halves of a pair's
+    tile; nothing but the operands and results is on the program's
+    books (a block's temporaries stay in VMEM)."""
+    from generativeaiexamples_tpu.ops.gated_delta import (
+        gated_delta_chunked_kernel, scan_kernel_supported)
+    dev = SingleDeviceSharding(topo.devices[0])
+    T, Hk, Hv, dk, dv = 512, 16, 32, 128, 128
+    assert scan_kernel_supported(T, Hk, Hv, dk, dv)
+    f32 = jnp.float32
+    args = (sds((rows, T, Hk * dk), f32, dev), sds((rows, T, Hk * dk), f32, dev),
+            sds((rows, T, Hv * dv), f32, dev), sds((rows, T, Hv), f32, dev),
+            sds((rows, T, Hv), f32, dev), sds((rows, Hv, dk, dv), f32, dev))
+    compiled = jax.jit(lambda *a: gated_delta_chunked_kernel(
+        *a, interpret=False)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "gated_delta_scan" in text and "tpu_custom_call" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

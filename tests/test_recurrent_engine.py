@@ -223,3 +223,68 @@ def test_the_refusal_names_the_mechanism_that_refused():
     msg = str(err.value)
     assert msg.startswith("a latent KV pool (kv_lora_rank=")
     assert "experts_held" not in msg and "hc_mult" not in msg
+
+
+# ------------------------------------------- the chunked scan as a kernel
+
+
+def _served_by(params, cfg, prompts, n=6):
+    """Greedy tokens, the slots' final state and the settled stats of an
+    engine over ``cfg`` serving ``prompts`` one after the other."""
+    eng = Engine(params, cfg, ByteTokenizer(), EngineConfig(**{
+        **ENGINE, "page_size": 64, "prefill_buckets": (64,),
+        "max_prefill_bucket": 64}))
+    eng.start()
+    try:
+        tokens = [serve(eng, ids, n) for ids in prompts]
+    finally:
+        eng.stop()
+    return tokens, np.asarray(eng._state["cache"]["s"]), eng.stats
+
+
+def test_chunk_programs_over_the_scan_kernel_serve_the_xla_forms_tokens(
+        monkeypatch):
+    """A tiny model at widths the scan kernel takes (128-lane heads, a
+    whole group of key heads with two value heads each, pages of whole
+    blocks): its chunk programs with the kernel (interpreted here, armed
+    as a TPU arms it) serve the greedy tokens and leave the state of the
+    same engine over the XLA form — a prompt of several chunks, the last
+    padded, and a one-bucket prompt — and count every chunk program."""
+    from generativeaiexamples_tpu.ops import gated_delta as gd
+    cfg = dataclasses.replace(
+        CFG, linear_key_head_dim=128, linear_value_head_dim=128,
+        linear_num_key_heads=gd._SCAN_PAIRS,
+        linear_num_value_heads=2 * gd._SCAN_PAIRS)
+    p = llama.init_params(cfg, jax.random.key(3), dtype=jnp.float32)
+    prompts = [prompt(200, 21), prompt(40, 22)]
+    want, want_s, plain = _served_by(p, cfg, prompts)
+    assert plain["scan_kernel"] == 0 == plain["scan_kernel_chunks"]
+    assert plain["downgrades"] == 0 and plain["sched_chunk_programs"] > 0
+    monkeypatch.setattr(gd, "scan_kernel_armed", gd.scan_kernel_supported)
+    traced, kernel = [], gd.gated_delta_chunked_kernel
+    monkeypatch.setattr(gd, "gated_delta_chunked_kernel",
+                        lambda *a, **kw: traced.append(a[0].shape)
+                        or kernel(*a, **kw))
+    got, got_s, stats = _served_by(p, cfg, prompts)
+    assert got == want and (1, 64, 128 * gd._SCAN_PAIRS) in traced
+    assert np.abs(got_s - want_s).max() <= 2e-5 * np.abs(want_s).max()
+    assert stats["scan_kernel"] == 1 and stats["downgrades"] == 0
+    assert stats["scan_kernel_chunks"] == stats["sched_chunk_programs"] \
+        == plain["sched_chunk_programs"]
+
+
+def test_a_tpu_engine_that_cannot_take_the_scan_kernel_says_so(
+        params, monkeypatch):
+    """16-lane heads on a "TPU": the chunk programs keep the XLA form
+    and the engine records ONE downgrade, by name; a model without
+    recurrent layers records none."""
+    from test_layer_kinds_moe import TINY
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = make_engine(params)
+    assert [d["feature"] for d in eng.downgrades] == ["scan_kernel"]
+    assert eng.downgrades[0]["fallback"] == "xla_chunked"
+    assert eng.stats["scan_kernel"] == 0 and eng.stats["downgrades"] == 1
+    cfg = TINY["mixtral"]
+    p = llama.init_params(cfg, jax.random.key(0), dtype=jnp.float32)
+    plain = Engine(p, cfg, ByteTokenizer(), EngineConfig(**ENGINE))
+    assert plain.downgrades == [] and plain.stats["scan_kernel"] == 0
