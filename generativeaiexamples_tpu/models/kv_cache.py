@@ -792,11 +792,12 @@ class LatentKV:
         return kernel_supported(page, self.R, self.rope)
 
     def prefix_kernel_supported(self, page: int) -> bool:
-        """Whether ``attend_prefix`` can run the chunk kernel
+        """Whether ``attend_prefix`` can run the chunk kernels
         (ops/chunk_attention.py) at this geometry: a head's ``nope`` key
         columns whole lanes beside the shared rotary part, or (192 + 64)
         whole lanes WITH it, the rotary part then copied into every
-        head's keys (``_fold_shared``)."""
+        head's keys of the chunk's own block (``_fold_shared``; the
+        prefix kernel joins it to a head's keys in VMEM either way)."""
         from ..ops.chunk_attention import kernel_supported
         return kernel_supported(page, self.nope, self.vd, self.rope) \
             or kernel_supported(page, self.nope + self.rope, self.vd)
@@ -918,12 +919,18 @@ class LatentKV:
         slot's: masked, and zeroed BEFORE the expansion (the trash page
         may hold anything; ``HeadKV``'s reader says why).
 
-        ``use_kernel``: a block's update of the softmax state — scores,
-        mask, maximum, exponent, sum, PV product — is the Pallas kernel
-        of ops/chunk_attention.py, the (H, T, C) float32 scores on the
-        chip; False is the same update as jnp operations (the CPU, and
-        what the kernel is held against). The block loop, the gather,
-        the zeroing and the expansion are the same either way.
+        ``use_kernel``: the whole prefix is ONE call of the Pallas kernel
+        ``chunk_attention_prefix`` (ops/chunk_attention.py) — the walk of
+        the block table, the pages' fetch, the zeroing, the expansion of
+        a head group's keys and values and the softmax state are the
+        kernel's, nothing of a block crosses HBM but its latent rows and
+        its rows of ``keep`` — and the chunk's own tokens, expanded here
+        by XLA, are folded into the carry it returns by
+        ``chunk_attention_update``, the (H, T, C) float32 scores on the
+        chip. False is the same attention as jnp operations, a ``scan``
+        over the prefix blocks with the gather, the zeroing and the
+        expansion XLA's (the CPU, and what the kernels are held
+        against).
 
         ``keep``: (prefix blocks' keys + C, C) bool or None, keys by
         rows — a query (column) attends a key only where it is set, the
@@ -951,13 +958,20 @@ class LatentKV:
             """Every head's keys (T, H * nope) and values (T, H * vd)."""
             return qmm(cb, lp["wk_b"]), qmm(cb, lp["wv_b"])
 
-        # One block folded into the carry. The kernel builds its mask from
-        # three positions (the block's first key, the limit past which keys
-        # are masked, ``causal``); the jnp form takes the mask made.
+        # ``update``: one block of expanded keys and values folded into the
+        # carry. The kernel builds its mask from three positions (the
+        # block's first key, the limit past which keys are masked,
+        # ``causal``); the jnp form takes the mask made.
         if use_kernel:
             qh = q[0].transpose(1, 0, 2)                    # (H, C, .)
             interp = jax.default_backend() != "tpu"
-            carry0 = ca.init_carry(H, C, vd)
+            # the prefix needs no ``update``: one kernel walks the table,
+            # zeroes, expands and keeps the carry for all its blocks
+            carry = ca.chunk_attention_prefix(
+                qh, lp["wk_b"], lp["wv_b"], pc, pr, tbl, start, scale=scale,
+                block_pages=block_pages, interpret=interp,
+                keep=None if keep is None
+                else keep[:nb * tblk].astype(jnp.float32))
 
             def update(carry, kb, rb, vb, mask, k0, limit, causal, kp=None):
                 more = {}
@@ -975,9 +989,6 @@ class LatentKV:
                     causal=causal, k_shared=rb, interpret=interp, **more)
         else:
             qn, qr = self._split(q[0])                      # (C, H, .)
-            carry0 = (jnp.full((H, C), -1e30, jnp.float32),
-                      jnp.zeros((H, C), jnp.float32),
-                      jnp.zeros((H, C, vd), jnp.float32))
 
             def update(carry, kb, rb, vb, mask, k0, limit, causal, kp=None):
                 m, l, acc = carry
@@ -1000,28 +1011,32 @@ class LatentKV:
                                         preferred_element_type=jnp.float32))
                 return m_new, l_new, acc_new
 
-        def block(carry, bi):
-            def live(carry):
-                pages = jax.lax.dynamic_slice(tbl, (bi * block_pages,),
-                                              (block_pages,))
-                t = bi * tblk + jnp.arange(tblk, dtype=jnp.int32)
-                mask = t < start
-                cb = jnp.where(mask[:, None],
-                               pc[pages].reshape(tblk, R).astype(cd), 0)
-                rb = jnp.where(
-                    mask[:, None],
-                    pr[pages].swapaxes(1, 2).reshape(tblk, rope).astype(cd),
-                    0)
-                kb, vb = expand(cb)
-                kp = () if keep is None else (jax.lax.dynamic_slice(
-                    keep, (bi * tblk, 0), (tblk, C)),)
-                return update(carry, kb, rb, vb, mask, bi * tblk, start,
-                              False, *kp)
-            return jax.lax.cond(bi * tblk < start, live, lambda c: c,
-                                carry), None
+            def block(carry, bi):
+                def live(carry):
+                    pages = jax.lax.dynamic_slice(tbl, (bi * block_pages,),
+                                                  (block_pages,))
+                    t = bi * tblk + jnp.arange(tblk, dtype=jnp.int32)
+                    mask = t < start
+                    cb = jnp.where(
+                        mask[:, None],
+                        pc[pages].reshape(tblk, R).astype(cd), 0)
+                    rb = jnp.where(
+                        mask[:, None],
+                        pr[pages].swapaxes(1, 2).reshape(tblk, rope)
+                        .astype(cd), 0)
+                    kb, vb = expand(cb)
+                    kp = () if keep is None else (jax.lax.dynamic_slice(
+                        keep, (bi * tblk, 0), (tblk, C)),)
+                    return update(carry, kb, rb, vb, mask, bi * tblk, start,
+                                  False, *kp)
+                return jax.lax.cond(bi * tblk < start, live, lambda c: c,
+                                    carry), None
 
-        carry, _ = jax.lax.scan(block, carry0,
-                                jnp.arange(nb, dtype=jnp.int32))
+            carry, _ = jax.lax.scan(
+                block, (jnp.full((H, C), -1e30, jnp.float32),
+                        jnp.zeros((H, C), jnp.float32),
+                        jnp.zeros((H, C, vd), jnp.float32)),
+                jnp.arange(nb, dtype=jnp.int32))
 
         sb = min(C, 512)
         while C % sb:

@@ -722,13 +722,23 @@ def test_latent_chunk_program_keeps_its_scores_in_the_kernel(
         assert len(tiles) > 50 and "chunk_attn" not in text
         return
     assert tiles == []
-    # the prefix blocks' and the chunk's own update, both stacks
+    # the prefix kernel and the chunk's own update, both stacks
     assert text.count('custom_call_target="tpu_custom_call"') >= 4
     assert "%chunk_attn" in text
+    assert_prefix_is_one_kernel(text)
     # one prompt: 120.6 MB where the jnp blocks took 233.7 (compile, PR
     # 37), 75.8 since a share's padded expert layout is walked in short
     # segments; four prompts' rows: 394.1 (compile, PR 39)
     assert temp < (160 << 20 if rows == 1 else 448 << 20), temp
+
+
+def assert_prefix_is_one_kernel(text):
+    """A latent chunk program's prefix is the kernel ``chunk_attn_prefix``
+    (PR 48) under the scope ``prefill_attn_ms_per_ktok`` sums, and no
+    chunk kernel sits in the branch of a ``cond`` any more: that branch
+    was a live block's step of the scan over the prefix."""
+    assert re.search(r'op_name="[^"]*/attn/[^"]*chunk_attn_prefix/', text)
+    assert not re.search(r'op_name="[^"]*/cond/[^"]*chunk_attn', text)
 
 
 def sparse_cfg():
@@ -767,6 +777,7 @@ def test_sparse_latent_chunk_program_compiles(topo, tpu_backend, rows):
     assert_fits(compiled)
     text = compiled.as_text()
     assert "%chunk_attn" in text
+    assert_prefix_is_one_kernel(text)
     keys = 132 * PAGE + 512                 # the table padded to blocks
     big = [i["name"] for ins in parse_hlo(text).values() for i in ins
            if (m := re.match(r"f32\[([\d,]+)\]", i["shape"]))

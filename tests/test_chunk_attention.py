@@ -1,11 +1,16 @@
-"""The chunk kernel (ops/chunk_attention.py) on the CPU, interpreted:
-one update against a few lines of jnp; ``LatentKV.attend_prefix`` with the
-kernel against its jnp blocks over prefix lengths, padded tables, padded
-query rows, NaN wherever no query may read, unequal key and value widths,
-float32 and bf16 rows; and the whole ``apply_prefill_paged`` of a tiny
-latent configuration both ways against the plain reference's one pass."""
+"""The chunk kernels (ops/chunk_attention.py) on the CPU, interpreted:
+one update against a few lines of jnp; the prefix kernel against the scan
+of such updates it replaced, to the bit; ``LatentKV.attend_prefix`` with
+the kernels against its jnp blocks over prefix lengths, padded tables,
+padded query rows, NaN wherever no query may read, unequal key and value
+widths, float32 and bf16 rows; and the whole ``apply_prefill_paged`` of a
+tiny latent configuration both ways against the plain reference's one
+pass."""
 
 import dataclasses
+import json
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +22,7 @@ from generativeaiexamples_tpu.models import llama
 from generativeaiexamples_tpu.models.configs import MODEL_REGISTRY
 from generativeaiexamples_tpu.models.kv_cache import LatentKV, kv_cache_of
 from generativeaiexamples_tpu.ops import chunk_attention as ca
+from generativeaiexamples_tpu.ops.quant import matmul as qmm, quantize_tensor
 
 from test_latent_attention import CFG, PAGE  # every width a quarter lane
 
@@ -102,6 +108,157 @@ def test_kernel_geometry():
                                    128) is False          # the CPU
     # per-head K and V have no chunk kernel: nothing to want
     assert not llama.use_prefix_kernel(MODEL_REGISTRY["trinity-mini"], 128)
+
+
+# ------------------------------------- every prefix block in one kernel
+
+
+def scan_of_updates(q, wk_b, wv_b, pc, pr, tbl, start, keep, form, PB=2):
+    """What ``attend_prefix`` ran a prefix through before the prefix
+    kernel: a block's pages gathered, the rows at or past ``start``
+    zeroed, ``wk_b`` / ``wv_b`` by ``matmul``, and ONE
+    ``chunk_attention_update`` a live block. ``form``: how the rotary
+    part meets the keys — beside them (``shared``) or copied into every
+    head's (``folded``)."""
+    H, C, dq = q.shape
+    R, rope = pc.shape[2], pr.shape[1]
+    nope, T, cd = dq - rope, PB * PAGE, q.dtype
+    carry = ca.init_carry(H, C, jax.eval_shape(qmm, pc[0], wv_b).shape[1]
+                          // H)
+    for bi in range(tbl.shape[0] // PB):
+        if bi * T >= start:
+            continue
+        pages = tbl[bi * PB:(bi + 1) * PB]
+        mask = (bi * T + jnp.arange(T) < start)[:, None]
+        cb = jnp.where(mask, pc[pages].reshape(T, R).astype(cd), 0)
+        rb = jnp.where(mask, pr[pages].swapaxes(1, 2).reshape(T, rope)
+                       .astype(cd), 0)
+        kb, vb = qmm(cb, wk_b), qmm(cb, wv_b)
+        if form == "folded":
+            kb = jnp.concatenate(
+                [kb.reshape(T, H, nope),
+                 jnp.broadcast_to(rb[:, None], (T, H, rope))],
+                axis=-1).reshape(T, H * dq)
+            rb = None
+        more = {} if keep is None else {"keep": keep[bi * T:(bi + 1) * T]}
+        carry = ca.chunk_attention_update(
+            q, kb, vb.T, carry, bi * T, start, start, scale=0.1,
+            causal=False, k_shared=rb, interpret=True, **more)
+    return carry
+
+
+def prefix_case(geometry, dtype, storage, masked, dirty=None, start=None):
+    """Operands of one prefix walk: a pool of two layers (the table is
+    offset into the second), seven pages padded to four blocks of two by
+    the trash page; with ``dirty``, every pool row at or past ``start``
+    and the whole trash page hold it."""
+    nope, rope, dv, _ = GEOMETRY[geometry]
+    H, C, R, n_pages = 4, 128, 128, 12
+    ks = jax.random.split(jax.random.key(nope + dv), 6)
+    pc = jax.random.normal(ks[0], (2 * n_pages, PAGE, R), dtype)
+    pr = jax.random.normal(ks[1], (2 * n_pages, rope, PAGE), dtype)
+    table = TABLE + [0]
+    if dirty is not None:
+        pos = np.full((2 * n_pages, PAGE), 1 << 30)
+        for i, pg in enumerate(TABLE):
+            pos[n_pages + pg] = i * PAGE + np.arange(PAGE)
+        stale = jnp.asarray(pos >= start)
+        pc = jnp.where(stale[:, :, None], dirty, pc)
+        pr = jnp.where(stale[:, None, :], dirty, pr)
+    wk = jax.random.normal(ks[2], (R, H * nope), dtype) * R ** -.5
+    wv = jax.random.normal(ks[3], (R, H * dv), dtype) * R ** -.5
+    if storage == "int8":
+        wk, wv = quantize_tensor(wk), quantize_tensor(wv)
+    q = jax.random.normal(ks[4], (H, C, nope + rope), dtype)
+    keep = None
+    if masked:
+        keep = jax.random.uniform(ks[5], (len(table) * PAGE, C)) < 0.3
+        # query 5 keeps nothing at all, query 7 nothing of the first block
+        keep = keep.at[:, 5].set(False).at[:2 * PAGE, 7].set(False)
+        keep = keep.astype(jnp.float32)
+    return q, wk, wv, pc, pr, jnp.asarray(table, jnp.int32) + n_pages, keep
+
+
+#: nope, rope, value width, and how the replaced scan handed the rotary
+#: part to its kernel: the three forms that run on the chip
+GEOMETRY = {"192+64_folded": (192, 64, 256, "folded"),
+            "128+64_shared": (128, 64, 128, "shared"),
+            "192_plain_values_128": (128, 64, 128, "folded")}
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "keep"])
+@pytest.mark.parametrize("storage", ["int8", "raw"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("geometry", sorted(GEOMETRY))
+def test_prefix_kernel_is_the_scan_of_updates_to_the_bit(geometry, dtype,
+                                                         storage, masked):
+    """No prefix, part of a block, blocks and a part, and a prefix that
+    reaches into the block the trash page pads: ``m``, ``l`` and ``acc``
+    of ``chunk_attention_prefix`` equal the scan's bit for bit."""
+    *ops, keep = prefix_case(geometry, dtype, storage, masked)
+    for start in (0, 128, 640, 896):
+        want = scan_of_updates(*ops, start, keep, GEOMETRY[geometry][3])
+        got = ca.chunk_attention_prefix(
+            *ops, jnp.int32(start), scale=0.1, block_pages=2, keep=keep,
+            interpret=True)
+        for g, w in zip(got, want):
+            assert g.shape == w.shape and g.dtype == w.dtype
+            assert np.array_equal(np.asarray(g), np.asarray(w)), start
+    if masked:      # the query that keeps nothing saw no key
+        m, l, acc = got
+        assert float(l[:, 0, 5].max()) == 0 and not bool(acc[:, :, 5].any())
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "keep"])
+def test_prefix_kernel_reads_nothing_at_or_past_start(masked):
+    """NaN in every pool row at or past ``start`` — the rest of the last
+    live block's pages, the chunk's own stale pages — and in the whole
+    trash page: every output finite, and equal to the clean pool's."""
+    for start in (0, 200, 896):
+        *ops, keep = prefix_case("128+64_shared", jnp.bfloat16, "int8",
+                                 masked, dirty=jnp.nan, start=start)
+        got = ca.chunk_attention_prefix(
+            *ops, jnp.int32(start), scale=0.1, block_pages=2, keep=keep,
+            interpret=True)
+        *ops, keep = prefix_case("128+64_shared", jnp.bfloat16, "int8",
+                                 masked, dirty=0.0, start=start)
+        clean = ca.chunk_attention_prefix(
+            *ops, jnp.int32(start), scale=0.1, block_pages=2, keep=keep,
+            interpret=True)
+        for g, c in zip(got, clean):
+            assert bool(jnp.all(jnp.isfinite(g)))
+            assert np.array_equal(np.asarray(g), np.asarray(c))
+
+
+def test_prefix_kernel_lies_under_the_attn_scope():
+    """In a compiled chunk program every operation of the prefix kernel
+    carries the scope the benchmark's ``prefill_attn_ms_per_ktok`` sums
+    (its data file's regular expression, over the scope path a trace
+    rebuilds from ``op_name``)."""
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "layer_metrics",
+                           "prefill_attn_ms_per_ktok.json")) as f:
+        scope = re.compile(json.load(f)["args"]["scope"])
+    p = jax.eval_shape(lambda k: llama.init_params(CFG, k, jnp.float32),
+                       jax.random.key(0))
+    pool = jax.eval_shape(
+        lambda: llama.init_paged_kv_cache(CFG, 6, PAGE, jnp.float32))
+    z = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+
+    def chunk(p, pool, tok, pos, table, sp):
+        return llama.apply_prefill_paged(p, CFG, tok, pos, pool, table,
+                                         pos[:, -1] + 1, sp, use_kernel=True)
+
+    text = jax.jit(chunk).lower(p, pool, z(1, PAGE), z(1, PAGE), z(1, 4),
+                                z()).compile().as_text()
+    # (a reduction's own little computation is named from the kernel
+    # down; everything that runs is named from the program down)
+    names = [n for n in re.findall(r'op_name="([^"]*)"', text)
+             if n.startswith("jit(chunk)") and "chunk_attn_prefix" in n]
+    assert len(names) > 50
+    for n in names:
+        assert scope.search(n[:n.index("chunk_attn_prefix")].rstrip("/")), n
 
 
 # ----------------------------------------- under LatentKV.attend_prefix

@@ -7,7 +7,12 @@ three. Computed on PR 40's PARENT (commit 7c1cd04) and unchanged by PR 40
 that is None here, an optional mask operand of the chunk kernel, leaves
 handed to the pool writer as a tuple): a change that moves one of these
 moves a cell. Re-pin only on purpose (a new JAX re-words the text: re-pin
-from one commit)."""
+from one commit). RE-PINNED ON PURPOSE at PR 48, both from that PR's one
+commit: ``("kimi-k2-instruct", "chunk_kernel")`` and ``("kimi-k2-instruct",
+"rows_kernel")`` — under ``use_kernel`` a latent chunk program's prefix is
+ONE call of ``chunk_attention_prefix`` where it was a ``scan`` of gathers,
+expansions and per-block updates. The other nine, ``chunk_jnp`` among
+them, are the hashes they were: no other program moved."""
 
 import hashlib
 import json
@@ -27,9 +32,9 @@ PINS = {
     ("kimi-k2-instruct", "chunk_jnp"):
         "1b1a43f1d9237be71fb3cf2e0062a53a41ac3a28110efb07ec2b6495d1baee1a",
     ("kimi-k2-instruct", "chunk_kernel"):
-        "1e39c62399bcefe7decbec4c516d06f1c8d8b4b5333e40ee1480e317f4333806",
+        "24a2c31e3fd46159abd6f1e84b17a200454e14acd2f1ef1e10699c946e44da1a",
     ("kimi-k2-instruct", "rows_kernel"):
-        "7a2ce6e72e79637411c29702f15f1ec76ee4881fbfd8a7dcdf1cbeea8791646e",
+        "40edeb650b035b5e4626c2121615f9f3dfea95167f013b2cbf325edf858ba0c3",
     ("kimi-k2-instruct", "step_kernel"):
         "49ab8437bc55dd5c1315c4292b6f69d616a1c16f17ceb1ed62bc1bea74956046",
     ("kimi-k2-instruct", "verify"):
