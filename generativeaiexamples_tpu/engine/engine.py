@@ -293,6 +293,11 @@ _STATS_TEMPLATE = {
     # and the rounds that reported one. 0 on the plain residual path.
     "hc_row_defect_sum": 0.0,
     "hc_row_defect_rounds": 0,
+    # under a router limited to groups and an expert share: the share
+    # (%) of a decode round's live rows whose kept groups include a held
+    # one (RoundRecord.route_groups_held_pct), summed over the rounds
+    "route_groups_held_pct_sum": 0.0,
+    "route_groups_held_pct_rounds": 0,
 }
 
 # The process's program build log (utils/compile_cache.py), read into
@@ -1276,9 +1281,13 @@ class Engine:
             # its blocks' 64 x 64 products.
             rows = max(self._row_ladder, default=1)
             heads = mcfg.linear_num_value_heads
+            # (a decay a channel: the running decays and the keys and
+            # queries that carry them into a block's products, too)
             acts += rows * S * heads * 4 * (
                 4 * mcfg.linear_key_head_dim
-                + 4 * mcfg.linear_value_head_dim + 6 * 64)
+                + 4 * mcfg.linear_value_head_dim + 6 * 64
+                + 12 * mcfg.linear_key_head_dim
+                * (mcfg.linear_decay == "channel"))
             gather += cfg.max_slots * self._slot_bytes()
         # int8-KV insert quantizes the bucket per-row; XLA sequences the
         # K and V transforms, so ~one bucket's f32 copy is live at once

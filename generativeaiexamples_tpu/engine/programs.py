@@ -424,7 +424,9 @@ class ProgramSpec:
                 f"{model_cfg.qk_rope_head_dim}: the chunk kernel takes "
                 f"lane-width pages and keys, whole sublane tiles of values"))
         scan_kernel = False
-        if model_cfg.recurrent:
+        # (a decay a channel has no scan kernel to arm or fall short of:
+        # its chunked form is XLA's, ops/gated_delta.py ``kda_chunked``)
+        if model_cfg.recurrent and model_cfg.linear_decay == "head":
             scan = (page_size, model_cfg.linear_num_key_heads,
                     model_cfg.linear_num_value_heads,
                     model_cfg.linear_key_head_dim,

@@ -92,6 +92,13 @@ class LlamaConfig:
     #         to the scores for the CHOICE only; the weights stay the
     #         un-biased scores) | "scores" (added to the scores, so it
     #         moves choice and weight alike)
+    #   n_group / topk_group: the choice LIMITED to groups (sigmoid
+    #         scoring): the experts are ``n_group`` groups of consecutive
+    #         experts, a group is scored by the sum of its two largest
+    #         (biased) scores, the ``topk_group`` best groups stay and the
+    #         token's experts are chosen among theirs — so a token
+    #         crosses to at most ``topk_group`` devices of a deployment
+    #         that holds a group a device. 1 / 1 limits nothing
     moe_intermediate_size: int = 0
     num_dense_layers: int = 0
     num_shared_experts: int = 0
@@ -99,18 +106,21 @@ class LlamaConfig:
     router_norm_topk: bool = True
     router_scale: float = 1.0
     router_bias: str = ""
+    n_group: int = 1
+    topk_group: int = 1
     # Attention and block variants, each off by default:
     #   qk_norm: RMSNorm over each head's ``head_dim`` values of q and k
     #         (one weight vector each a layer, shared by the heads),
     #         BEFORE the rotary embedding: the pool holds normed keys
     #   attn_gate: the attention output times sigmoid(x W_z), x the
-    #         block's normed input, elementwise, before ``wo``
+    #         block's normed input, elementwise, before ``wo``; "head"
+    #         (latent attention's): ONE gate a head, ``wz_head`` (D, H)
     #   post_norms: a norm on each sub-block's OUTPUT (attention after
     #         ``wo``, the MLP or experts) before it is added to the
     #         stream: four norms a block
     #   embed_scale: the embedding output times this
     qk_norm: bool = False
-    attn_gate: bool = False
+    attn_gate: bool | str = False
     post_norms: bool = False
     embed_scale: float = 1.0
     # Latent attention (``kv_lora_rank`` > 0; every default is the model
@@ -120,7 +130,8 @@ class LlamaConfig:
     # what the cache holds (models/kv_cache.py ``LatentKV``). A head's
     # keys and values are the latent times ``wkv_b`` (``qk_nope_head_dim``
     # + ``v_head_dim`` columns a head); queries come through a second
-    # low-rank pair, ``wq_a`` (``q_lora_rank``, normed) and ``wq_b``.
+    # low-rank pair, ``wq_a`` (``q_lora_rank``, normed) and ``wq_b`` — or,
+    # where ``q_lora_rank`` is 0, through ONE matrix ``wq`` and no norm.
     # ``head_dim`` is then qk_nope_head_dim + qk_rope_head_dim, of which
     # only the rope part rotates, and ``num_kv_heads`` is 1: the one row.
     #   rope_interleave: the rotary part's pairs are (2i, 2i+1), not
@@ -199,8 +210,23 @@ class LlamaConfig:
     # (``linear_num_key_heads`` heads of q and k, each serving
     # ``linear_num_value_heads / linear_num_key_heads`` value heads). The
     # cache is models/kv_cache.py ``RecurrentKV``: pages for the attention
-    # layers only, a state a SLOT for the others. ``num_layers`` is whole
-    # periods. Two keys beside them:
+    # layers only, a state a SLOT for the others — pages of per-head K
+    # and V, or latent rows where the attention layers are latent
+    # (``kv_lora_rank``). The period is counted in the model's layer
+    # indices whatever the stacks: leading dense layers
+    # (``num_dense_layers``) are recurrent or attention layers by their
+    # index like any other, and the expert stack may begin inside a
+    # period. Keys beside them:
+    #   linear_decay: "head" (the gated delta rule: ONE log-decay a head
+    #         a token, ``g = -exp(A_log) softplus(a + dt_bias)``; q, k, v
+    #         and the output gate z one projection, decay and write
+    #         strength another) | "channel" (Kimi Delta Attention: a
+    #         log-decay a CHANNEL of a head's keys, ``g =
+    #         linear_decay_floor * sigmoid(exp(A_log) (a + dt_bias))`` in
+    #         (``linear_decay_floor``, 0); the state's rows decay each at
+    #         its own rate; a decay projection and an output gate as wide
+    #         as the heads, the gate a sigmoid; as many key as value
+    #         heads)
     #   partial_rotary_factor: the share of an attention head's values the
     #         rotary embedding turns, from the first on (pairs (i, i +
     #         half of THAT part)); the rest pass
@@ -212,6 +238,8 @@ class LlamaConfig:
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 0
+    linear_decay: str = "head"
+    linear_decay_floor: float = 0.0
     partial_rotary_factor: float = 1.0
     shared_expert_gate: bool = False
     # How ``llama.init_params`` draws a random tree (tests, benchmarks;
@@ -275,11 +303,29 @@ class LlamaConfig:
         if self.rope_scaling_type not in ("linear", "yarn"):
             raise ValueError(
                 f"unknown rope_scaling_type {self.rope_scaling_type!r}")
+        if self.n_group != 1 or self.topk_group != 1:
+            if not (routed and self.n_group >= 1
+                    and self.num_experts % self.n_group == 0
+                    and self.num_experts // self.n_group >= 2
+                    and 1 <= self.topk_group <= self.n_group
+                    and self.num_experts_per_tok <= self.topk_group
+                    * (self.num_experts // self.n_group)):
+                raise ValueError(
+                    "a router limited to groups (n_group, topk_group) "
+                    "scores by sigmoid, cuts num_experts into n_group "
+                    "groups of at least 2 and keeps topk_group of them, "
+                    "which hold a token's num_experts_per_tok")
+        if self.attn_gate not in (False, True, "head") or (
+                (self.attn_gate == "head") != bool(
+                    self.attn_gate and self.kv_lora_rank)):
+            raise ValueError(
+                "attn_gate is false, true (per-head attention: a gate a "
+                "value) or \"head\" (latent attention: a gate a head)")
         if self.kv_lora_rank:
-            if not (self.q_lora_rank and self.qk_nope_head_dim
+            if not (self.qk_nope_head_dim
                     and self.qk_rope_head_dim and self.v_head_dim):
                 raise ValueError(
-                    "latent attention (kv_lora_rank) needs q_lora_rank, "
+                    "latent attention (kv_lora_rank) needs "
                     "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
             if (self.head_dim != self.qk_nope_head_dim
                     + self.qk_rope_head_dim or self.num_kv_heads != 1):
@@ -288,19 +334,21 @@ class LlamaConfig:
                     "qk_rope_head_dim and num_kv_heads is 1 (one latent "
                     "row a token serves every head)")
             if (self.sliding_window or self.rope_layers or self.qk_norm
-                    or self.attn_gate or self.attn_bias):
+                    or self.attn_bias):
                 raise ValueError(
                     "latent attention takes no window, rope pattern, q/k "
-                    "norm, output gate or bias")
+                    "norm or bias")
         elif self.rope_interleave:
             raise ValueError("rope_interleave is the latent rotary part's")
         if self.index_topk:
-            if not (self.kv_lora_rank and self.index_n_heads > 0
+            if not (self.kv_lora_rank and self.q_lora_rank
+                    and self.index_n_heads > 0
                     and self.index_head_dim >= self.qk_rope_head_dim):
                 raise ValueError(
                     "learned sparse attention (index_topk) needs latent "
-                    "attention (kv_lora_rank), index_n_heads and an "
-                    "index_head_dim that holds the rotary part")
+                    "attention (kv_lora_rank) with a query latent "
+                    "(q_lora_rank), index_n_heads and an index_head_dim "
+                    "that holds the rotary part")
             if not self.layer_index[0]:
                 raise ValueError(
                     "index_layers: layer 0 has no full layer below it "
@@ -327,10 +375,10 @@ class LlamaConfig:
             n = self.full_attention_interval
             sizes = (self.linear_num_key_heads, self.linear_num_value_heads,
                      self.linear_key_head_dim, self.linear_value_head_dim)
-            if n < 2 or self.num_layers % n:
+            if n < 2 or self.num_layers < n:
                 raise ValueError(
                     "full_attention_interval is 0 (every layer attends) or "
-                    "a period of at least 2 that divides num_layers")
+                    "a period of at least 2 that num_layers holds once")
             if (min(sizes) <= 0 or self.linear_conv_kernel_dim < 2
                     or self.linear_num_value_heads
                     % self.linear_num_key_heads):
@@ -338,21 +386,34 @@ class LlamaConfig:
                     "recurrent layers (full_attention_interval) need the "
                     "five linear_* sizes, a convolution of at least 2 taps "
                     "and value heads a multiple of the key heads")
-            if (self.kv_lora_rank or self.num_dense_layers or self.hc_mult
+            if (self.index_topk or self.hc_mult
                     or self.sliding_window or self.rope_layers
                     or self.norm != "rmsnorm" or self.attn_bias
                     or self.router_input != "mlp_norm"
                     or (self.num_experts and self.moe_impl != "dropless")):
                 raise ValueError(
                     "recurrent layers (full_attention_interval) run beside "
-                    "plain per-head attention in ONE stack: no latent "
-                    "attention, leading dense layers, hyper-connections, "
+                    "per-head or latent attention: no learned sparse "
+                    "attention, hyper-connections, "
                     "window or rope pattern, layernorm1p, attention bias, "
                     "block_input router or capacity routing")
+            if self.linear_decay not in ("head", "channel") or (
+                    self.linear_decay == "channel" and not (
+                        self.linear_decay_floor < 0
+                        and self.linear_num_key_heads
+                        == self.linear_num_value_heads)) or (
+                    self.linear_decay == "head"
+                    and self.linear_decay_floor):
+                raise ValueError(
+                    "linear_decay is \"head\" or \"channel\"; a decay a "
+                    "channel has a negative linear_decay_floor (its "
+                    "log-decay's lower bound) and as many key as value "
+                    "heads")
         elif (self.linear_num_key_heads or self.linear_num_value_heads
               or self.linear_key_head_dim or self.linear_value_head_dim
-              or self.linear_conv_kernel_dim):
-            raise ValueError("the linear_* sizes are the recurrent layers' "
+              or self.linear_conv_kernel_dim or self.linear_decay != "head"
+              or self.linear_decay_floor):
+            raise ValueError("the linear_* keys are the recurrent layers' "
                              "(full_attention_interval)")
         if not 0.0 < self.partial_rotary_factor <= 1.0 or (
                 self.partial_rotary_factor != 1.0 and (
@@ -417,6 +478,12 @@ class LlamaConfig:
         n = self.full_attention_interval
         return tuple(int(not n or (i + 1) % n == 0)
                      for i in range(self.num_layers))
+
+    @property
+    def recurrent_scope(self) -> str:
+        """What a recurrent layer's leaves (``gdn_*`` / ``kda_*``) and
+        stage names start with: the member of the family it runs."""
+        return "kda" if self.linear_decay == "channel" else "gdn"
 
     @property
     def rotary_dim(self) -> int:
@@ -625,6 +692,34 @@ QWEN3_NEXT_80B_A3B = LlamaConfig(
     linear_value_head_dim=128, linear_conv_kernel_dim=4,
     weight_init="unit_stream")
 
+# A ~125B-total / ~5.5B-active hybrid (inclusionAI Ling-3.0-flash
+# config.json, model_type bailing_hybrid): 42 layers in groups of five
+# Kimi-Delta-Attention layers (32 heads of 128 x 128, a log-decay a
+# CHANNEL of a head's keys bounded below by -5, a 4-tap convolution) and
+# one latent attention layer (32 heads of 128 + 64 over one 512 + 64
+# wide cached row a token, ONE query matrix, a sigmoid gate a head); two
+# leading dense layers (KDA mixers, a dense MLP), then 512 SwiGLU experts
+# of width 768, 8 a token by sigmoid scores with a selection bias,
+# chosen within 4 of 8 groups, beside one shared expert. The
+# multi-token-prediction module and the activation limits of layers
+# 34-41 are not built (models/import_hf.py ``bailing_hybrid_config``
+# refuses the latter by name).
+LING_3_0_FLASH = LlamaConfig(
+    vocab_size=157184, hidden_size=2560, intermediate_size=6144,
+    moe_intermediate_size=768, num_layers=42, num_dense_layers=2,
+    num_heads=32, num_kv_heads=1, head_dim=192,
+    max_position_embeddings=262144, rope_theta=6e6, rms_norm_eps=1e-6,
+    num_experts=512, num_experts_per_tok=8, num_shared_experts=1,
+    moe_impl="dropless", router_score_func="sigmoid",
+    router_norm_topk=True, router_scale=2.5, router_bias="selection",
+    n_group=8, topk_group=4, kv_lora_rank=512, q_lora_rank=0,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    rope_interleave=True, attn_gate="head", full_attention_interval=6,
+    linear_num_key_heads=32, linear_num_value_heads=32,
+    linear_key_head_dim=128, linear_value_head_dim=128,
+    linear_conv_kernel_dim=4, linear_decay="channel",
+    linear_decay_floor=-5.0, weight_init="unit_stream")
+
 NEMOTRON_8B = LlamaConfig(vocab_size=256000, hidden_size=4096,
                           intermediate_size=16384, num_layers=32,
                           num_heads=32, num_kv_heads=32, head_dim=128,
@@ -673,6 +768,7 @@ MODEL_REGISTRY: dict[str, LlamaConfig] = {
     "glm-5.2": GLM_5_2,
     "xing4.0-29b-a4b": XING4_0_29B_A4B,
     "qwen3-next-80b-a3b-instruct": QWEN3_NEXT_80B_A3B,
+    "ling-3.0-flash": LING_3_0_FLASH,
     "gptnext-tiny": GPTNEXT_TINY,
     "llama-tiny": LLAMA_TINY,
     "golden-tiny": GOLDEN_TINY,

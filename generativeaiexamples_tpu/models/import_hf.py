@@ -23,7 +23,7 @@ import numpy as np
 
 from ..utils.errors import ModelLoadError, UnsupportedFormatError
 from .configs import LlamaConfig
-from .llama import ATTENTION_WEIGHTS, Params
+from .llama import ATTENTION_WEIGHTS, RECURRENT_PREFIXES, Params
 
 _HF_LAYER_KEYS = {
     "input_layernorm.weight": ("attn_norm", False),
@@ -114,10 +114,37 @@ _HF_RECURRENT_LAYER_KEYS = {
     "mlp.shared_expert.down_proj.weight": ("ws_down", True),
 }
 _ZERO_CENTRED = ("attn_norm", "mlp_norm", "q_norm", "k_norm")
+# A model whose recurrent layers decay a channel at its own rate beside
+# latent attention layers (``model_type: bailing_hybrid``;
+# cfg.linear_decay "channel"). A KDA layer's leaves, by ``fla``'s
+# ``KimiDeltaAttention`` names without the low-rank pairs
+# (``no_kda_lora``): q, k and v and their three convolutions are
+# published apart and stored side by side (``_KDA_JOINED``). A latent
+# layer has DeepseekV3's names with ONE ``q_proj`` and a gate a head
+# (``g_proj``: (heads, D)). ASSUMED names: no checkpoint or modelling
+# code was at hand (benchmarks/configs/ling-3.0-flash.json
+# ``assumed.weight_names``); these tables are the one place to change.
+# ``q_proj`` and ``g_proj`` name a leaf of either kind: the layer's index
+# says which (``cfg.layer_full``).
+_HF_KDA_LAYER_KEYS = {
+    "self_attn.f_proj.weight": ("kda_wf", True),
+    "self_attn.g_proj.weight": ("kda_wg", True),
+    "self_attn.b_proj.weight": ("kda_wb", True),
+    "self_attn.A_log": ("kda_A_log", False),
+    "self_attn.dt_bias": ("kda_dt_bias", False),
+    "self_attn.o_norm.weight": ("kda_norm", False),
+    "self_attn.o_proj.weight": ("kda_wout", True),
+}
+_KDA_JOINED = {     # the tree's leaf: its published parts, in order
+    "kda_wqkv": tuple(f"self_attn.{n}_proj.weight" for n in "qkv"),
+    "kda_conv": tuple(f"self_attn.{n}_conv1d.weight" for n in "qkv"),
+}
+_HF_LATENT_GATE = "self_attn.g_proj.weight"
 _HF_KV_B = "self_attn.kv_b_proj.weight"
 # buffers and coefficients the program reads in float32
 _FLOAT32_LEAVES = ("router_bias", "hc_attn_alpha", "hc_attn_b",
-                   "hc_mlp_alpha", "hc_mlp_b", "gdn_A_log", "gdn_dt_bias")
+                   "hc_mlp_alpha", "hc_mlp_b", "gdn_A_log", "gdn_dt_bias",
+                   "kda_A_log", "kda_dt_bias")
 
 
 def _split_q_gate(w: np.ndarray, cfg: LlamaConfig) -> tuple:
@@ -343,8 +370,13 @@ def params_from_named_tensors(
     if cfg.hc_mult:
         hf_keys.update(_HF_HC_LAYER_KEYS)
     recurrent = cfg.recurrent
-    if recurrent:
+    channel = cfg.linear_decay == "channel"
+    # the published conventions of the model whose decay is a head's:
+    # grouped projections, zero-centred norms
+    grouped = recurrent and not channel
+    if grouped:
         hf_keys.update(_HF_RECURRENT_LAYER_KEYS)
+    kda_parts: dict[tuple, np.ndarray] = {}
     # an expert share keeps the experts it holds, numbered from its first
     first_expert, held = cfg.experts_first, cfg.held_experts
     layer_acc: dict[str, list] = {}
@@ -369,7 +401,7 @@ def params_from_named_tensors(
             top["embed"] = arr
             continue
         if key == "norm.weight":
-            top["final_norm"] = arr + 1 if recurrent else arr
+            top["final_norm"] = arr + 1 if grouped else arr
             continue
         if key in ("lm_head.weight", "output.weight"):
             top["lm_head"] = arr.T
@@ -378,13 +410,33 @@ def params_from_named_tensors(
         if not m:
             continue  # rotary inv_freq buffers etc.
         idx, rest = int(m.group(1)), m.group(2)
-        if recurrent and rest in _RECURRENT_SPLITS:
+        if grouped and rest in _RECURRENT_SPLITS:
             for name, part in _RECURRENT_SPLITS[rest](arr, cfg).items():
                 put_layer(name, idx, part)
             continue
+        if channel and not cfg.layer_full[idx]:     # a KDA layer's own
+            if rest in _HF_KDA_LAYER_KEYS:
+                name, transpose = _HF_KDA_LAYER_KEYS[rest]
+                # (``A_log`` is published (1, 1, heads, 1))
+                put_layer(name, idx, arr.T if transpose else arr.reshape(
+                    -1) if name in _FLOAT32_LEAVES else arr)
+                continue
+            joined = next((n for n, parts in _KDA_JOINED.items()
+                           if rest in parts), None)
+            if joined:
+                kda_parts[idx, rest] = arr
+                parts = [kda_parts.get((idx, r)) for r in _KDA_JOINED[joined]]
+                if all(x is not None for x in parts):
+                    put_layer(joined, idx, np.concatenate(
+                        [x[:, 0, :] for x in parts]) if joined == "kda_conv"
+                        else np.concatenate(parts).T)
+                continue
+        elif channel and rest == _HF_LATENT_GATE:
+            put_layer("wz_head", idx, arr.T)
+            continue
         if rest in hf_keys:
             name, transpose = hf_keys[rest]
-            if recurrent and name in _ZERO_CENTRED:
+            if grouped and name in _ZERO_CENTRED:
                 arr = arr + 1
             put_layer(name, idx, arr.T if transpose else arr)
             continue
@@ -426,10 +478,11 @@ def params_from_named_tensors(
             if indexer:     # the stack's full layers only, and all of them
                 part = [x for x, f in zip(
                     part, cfg.layer_index[first:first + n]) if f]
-            elif recurrent and (name.startswith("gdn_")
+            elif recurrent and (name.startswith(RECURRENT_PREFIXES)
                                 or name in ATTENTION_WEIGHTS):
                 # a mixer's leaves over the layers of its kind alone
-                part = [x for x, f in zip(part, cfg.layer_full)
+                part = [x for x, f in zip(part,
+                                          cfg.layer_full[first:first + n])
                         if f == (name in ATTENTION_WEIGHTS)]
             if all(x is None for x in part) and not (indexer and part):
                 continue            # not a leaf of this stack's layers
@@ -463,6 +516,71 @@ def params_from_named_tensors(
         raise ModelLoadError("checkpoint has no lm_head and config does not "
                              "tie word embeddings")
     return params
+
+
+def bailing_hybrid_config(hf: dict, *, num_layers: int | None = None,
+                          experts_held: int = 0, experts_first: int = 0,
+                          **more) -> LlamaConfig:
+    """The ``LlamaConfig`` of a published ``bailing_hybrid``
+    ``config.json`` (``hf``, its keys as published), cut to the first
+    ``num_layers`` layers and to an expert share where asked. What a
+    key's NAME alone decides is listed in
+    benchmarks/configs/ling-3.0-flash.json ``assumed``. Refuses BY NAME
+    what the program has no form for: an activation limit
+    (``expert_swiglu_limit_list`` / ``share_expert_swiglu_limit_list``)
+    that is not 0 on a layer held — the clamp's form is not published
+    with the key —, KDA's low-rank pairs, and the switches the published
+    model leaves off."""
+    L = int(num_layers or hf["num_hidden_layers"])
+    for name in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
+        hit = [i for i, x in enumerate((hf.get(name) or [])[:L]) if x]
+        if hit:
+            raise ModelLoadError(
+                f"{name}: a non-zero activation limit on layers {hit} of "
+                f"the {L} held is not supported (the clamp's form is not "
+                f"given by the key)")
+    off = ("use_kda_lora", "value_norm", "up_proj_norm", "use_nGPT",
+           "scale_router_input", "use_mla_nope", "use_bias", "use_qkv_bias")
+    on = [k for k in off if hf.get(k)] + [
+        k for k in ("no_kda_lora", "kda_safe_gate", "linear_silu",
+                    "use_qk_norm", "norm_topk_prob",
+                    "moe_router_enable_expert_bias") if not hf.get(k, True)]
+    if on or hf.get("q_lora_rank") or hf.get("rope_scaling") or hf.get(
+            "gated_attention_proj_granularity_type") != "head_wise":
+        raise ModelLoadError(
+            f"bailing_hybrid: {on or 'q_lora_rank / rope_scaling / gate'} "
+            f"states a variant of the block that is not supported")
+    heads = hf.get("num_kv_heads_for_linear_attn") or hf["num_attention_heads"]
+    nope, rope = hf["qk_nope_head_dim"], hf["qk_rope_head_dim"]
+    return LlamaConfig(
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        moe_intermediate_size=hf["moe_intermediate_size"], num_layers=L,
+        num_dense_layers=hf["first_k_dense_replace"],
+        num_heads=hf["num_attention_heads"], num_kv_heads=1,
+        head_dim=nope + rope,
+        max_position_embeddings=hf["max_position_embeddings"],
+        rope_theta=float(hf["rope_theta"]), rms_norm_eps=hf["rms_norm_eps"],
+        num_experts=hf["num_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        num_shared_experts=hf["num_shared_experts"],
+        experts_held=experts_held, experts_first=experts_first,
+        moe_impl="dropless", router_score_func="sigmoid",
+        router_norm_topk=True,
+        router_scale=float(hf["routed_scaling_factor"]),
+        router_bias="selection", n_group=hf["n_group"],
+        topk_group=hf["topk_group"], kv_lora_rank=hf["kv_lora_rank"],
+        q_lora_rank=0, qk_nope_head_dim=nope, qk_rope_head_dim=rope,
+        v_head_dim=hf["v_head_dim"],
+        rope_interleave=bool(hf["rope_interleave"]), attn_gate="head",
+        full_attention_interval=hf["layer_group_size"],
+        linear_num_key_heads=heads, linear_num_value_heads=heads,
+        linear_key_head_dim=hf["head_dim"],
+        linear_value_head_dim=hf["head_dim"],
+        linear_conv_kernel_dim=hf["short_conv_kernel_size"],
+        linear_decay="channel",
+        linear_decay_floor=float(hf["kda_lower_bound"]),
+        tie_word_embeddings=bool(hf["tie_word_embeddings"]), **more)
 
 
 def load_checkpoint(path: str, cfg: LlamaConfig,

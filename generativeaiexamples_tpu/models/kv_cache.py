@@ -1,4 +1,5 @@
-"""The KV cache behind ONE object, in four implementations.
+"""The KV cache behind ONE object, in four implementations and one
+composition.
 
 What the forwards of models/llama.py, the decode kernel call and the
 engine's sizing need of a cache is asked of the object that
@@ -65,8 +66,11 @@ v)`` as handed:
             absorbed (``attend_window``); a chunk runs
             ``LatentKV.attend_prefix`` with the mask as one more operand
             of the chunk kernel.
-``RecurrentKV`` ``HeadKV`` for a model whose layers are not all attention
-            (``cfg.full_attention_interval``): ``{"k", "v"}`` over the Lf
+``RecurrentKV`` the cache of a model whose layers are not all attention
+            (``cfg.full_attention_interval``), by COMPOSITION: a paged
+            part — ``HeadKV``'s ``{"k", "v"}``, or ``LatentKV``'s ``{"c",
+            "r"}`` where the attention layers are latent
+            (``cfg.kv_lora_rank``), each what it is alone — over the Lf
             ATTENTION layers only, and two leaves that are not pages, one
             entry a SLOT whatever the sequence's length: ``"s": (Lg,
             slots, Hv, dk, dv)``, the Lg recurrent layers' delta-rule
@@ -76,15 +80,15 @@ v)`` as handed:
             chip pads 3 rows to 4 and its compiler asks for another
             layout than the buffers have (a program loaded from the
             compile cache then refuses them: PERF.md section 6). The
-            forwards hand the attention layers ``HeadKV``'s readers with
-            the layer's place among the attention layers, and the
+            forwards hand the attention layers the paged part's readers
+            with the layer's place among the attention layers, and the
             recurrent layers ``recur``: the rows' state read by slot at
             the layer's place among ITS kind (zeros where the row's
             sequence starts) and written back there. A state does not
             forgive what rows past a length forgive: tokens past a
             chunk's valid length, idle rows of a decode round and a
             finished row's surplus steps are masked out of it
-            (models/llama.py ``_gdn_mixer``). ``slots`` is a keyword of
+            (models/llama.py ``_gdn_mixer``, ``_kda_mixer``). ``slots`` is a keyword of
             every forward and of ``init_pool``; its default is one
             sequence a row, row ``b`` in slot ``b``.
 """
@@ -108,7 +112,7 @@ KVCache = dict[str, jax.Array]
 @functools.lru_cache(maxsize=None)
 def kv_cache_of(cfg: LlamaConfig):
     """The configuration's cache object: what it says of its attention
-    decides, nothing else."""
+    decides, and whether every layer attends; nothing else."""
     if cfg.index_topk:
         return SparseLatentKV(cfg)
     if cfg.recurrent:
@@ -121,15 +125,19 @@ class HeadKV:
 
     leaves = ("k", "v")
 
-    def __init__(self, cfg: LlamaConfig):
+    def __init__(self, cfg: LlamaConfig, layers: Optional[int] = None):
+        """``layers``: the layers that keep rows (all of them; the
+        attention layers where ``RecurrentKV`` holds this as its paged
+        part)."""
         self.cfg = cfg
+        self.n_layers = cfg.num_layers if layers is None else layers
 
     # ---------------------------------------------------------------- build
 
     def init_dense(self, batch: int, max_len: int,
                    dtype: jnp.dtype = jnp.bfloat16) -> KVCache:
         cfg = self.cfg
-        shape = (cfg.num_layers, batch, max_len, cfg.num_kv_heads,
+        shape = (self.n_layers, batch, max_len, cfg.num_kv_heads,
                  cfg.head_dim)
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
@@ -137,7 +145,7 @@ class HeadKV:
                   dtype: jnp.dtype = jnp.bfloat16,
                   quantized: bool = False) -> KVCache:
         cfg = self.cfg
-        shape = (cfg.num_layers, n_pages, cfg.num_kv_heads, page_size,
+        shape = (self.n_layers, n_pages, cfg.num_kv_heads, page_size,
                  cfg.head_dim)
         if not quantized:
             return {"k": jnp.zeros(shape, dtype),
@@ -160,8 +168,8 @@ class HeadKV:
 
     def model_token_bytes(self, itemsize: int, quantized: bool = False
                           ) -> int:
-        """Bytes a cached token, all layers."""
-        return self.cfg.num_layers * self.token_bytes(itemsize, quantized)
+        """Bytes a cached token, all the layers that keep rows."""
+        return self.n_layers * self.token_bytes(itemsize, quantized)
 
     @staticmethod
     def page_size(kv_cache: KVCache) -> int:
@@ -374,17 +382,31 @@ class HeadKV:
         return attend
 
 
-class RecurrentKV(HeadKV):
-    """``HeadKV`` over the attention layers, a state a slot for the
-    recurrent ones (module docstring)."""
-
-    leaves = ("k", "v", "s", "conv")
+class RecurrentKV:
+    """A paged part over the attention layers, a state a slot for the
+    recurrent ones (module docstring). What is asked of the cache and is
+    not a state's — a token's bytes, the page size, whether a kernel
+    takes the geometry, the attention over given tokens, a dense cache's
+    rows — is the paged part's, as it answers alone."""
 
     def __init__(self, cfg: LlamaConfig):
-        super().__init__(cfg)
+        self.cfg = cfg
         self.period = cfg.full_attention_interval
         self.n_full = sum(cfg.layer_full)
         self.n_recurrent = cfg.num_layers - self.n_full
+        paged = LatentKV if cfg.kv_lora_rank else HeadKV
+        self.paged = paged(cfg, layers=self.n_full)
+        self.leaves = self.paged.leaves + ("s", "conv")
+        self.scope = cfg.recurrent_scope + "_state"
+
+    def __getattr__(self, name):
+        # only what this class does not define: the paged part's
+        if name == "paged":
+            raise AttributeError(name)
+        return getattr(self.paged, name)
+
+    def _paged(self, kv_cache: KVCache) -> KVCache:
+        return {n: kv_cache[n] for n in self.paged.leaves}
 
     def _state_shapes(self, slots: int) -> tuple[tuple, tuple]:
         cfg = self.cfg
@@ -410,15 +432,12 @@ class RecurrentKV(HeadKV):
 
     def init_dense(self, batch: int, max_len: int,
                    dtype: jnp.dtype = jnp.bfloat16) -> KVCache:
-        cfg = self.cfg
-        shape = (self.n_full, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
         state = self._state(batch, dtype)
         # a dense cache's layers ride the scan whole: its tail stays
         # (K - 1, channels) a row, as the mixer takes and leaves it
         state["conv"] = state["conv"].reshape(
-            state["conv"].shape[:2] + (-1, cfg.linear_channels))
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
-                **state}
+            state["conv"].shape[:2] + (-1, self.cfg.linear_channels))
+        return {**self.paged.init_dense(batch, max_len, dtype), **state}
 
     def init_pool(self, n_pages: int, page_size: int,
                   dtype: jnp.dtype = jnp.bfloat16,
@@ -426,18 +445,10 @@ class RecurrentKV(HeadKV):
         if quantized:
             raise NotImplementedError(
                 "an int8 KV pool beside a recurrent state is not supported")
-        cfg = self.cfg
-        shape = (self.n_full, n_pages, cfg.num_kv_heads, page_size,
-                 cfg.head_dim)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+        return {**self.paged.init_pool(n_pages, page_size, dtype),
                 **self._state(slots, dtype)}
 
     # ----------------------------------------------------------------- size
-
-    def model_token_bytes(self, itemsize: int, quantized: bool = False
-                          ) -> int:
-        """Bytes a cached token: rows on the attention layers only."""
-        return self.n_full * self.token_bytes(itemsize, quantized)
 
     def slot_bytes(self, itemsize: int) -> int:
         """Bytes a sequence costs whatever its length: the recurrent
@@ -452,17 +463,17 @@ class RecurrentKV(HeadKV):
     # ------------------------------------------------------- attention layers
 
     def attend_window(self, q, k, v, lp, kv_cache, layer, *args):
-        return super().attend_window(q, k, v, lp, kv_cache,
-                                     layer // self.period, *args)
+        return self.paged.attend_window(q, k, v, lp, kv_cache,
+                                        layer // self.period, *args)
 
     def attend_prefix(self, q, k, v, lp, kv_cache, block_table, start,
-                      kv_valid_len, layer):
-        return super().attend_prefix(q, k, v, lp, kv_cache, block_table,
-                                     start, kv_valid_len,
-                                     layer // self.period)
+                      kv_valid_len, layer, **kernel):
+        return self.paged.attend_prefix(q, k, v, lp, kv_cache, block_table,
+                                        start, kv_valid_len,
+                                        layer // self.period, **kernel)
 
     def kernel_attend(self, kv_cache: KVCache, *args):
-        inner = super().kernel_attend(kv_cache, *args)
+        inner = self.paged.kernel_attend(kv_cache, *args)
 
         def attend(q, k, v, lp, li, pool):
             attn, new = inner(q, k, v, lp, li // self.period, pool)
@@ -550,14 +561,14 @@ class RecurrentKV(HeadKV):
     def write(self, kv_cache: KVCache, new_k, new_v, new_s, new_conv,
               pages, offsets: Optional[jax.Array] = None,
               slots: Optional[jax.Array] = None) -> KVCache:
-        """``HeadKV.write`` of the attention layers' rows and, in the same
-        step, the recurrent layers' state of the rows' ``slots`` ((B,);
-        None: the rows are all the slots, in order): ``new_s`` (Lg, B,
-        Hv, dk, dv) and ``new_conv`` (Lg, B, K - 1, channels) as the scan
-        stacked them."""
-        out = super().write({n: kv_cache[n] for n in ("k", "v")}, new_k,
-                            new_v, pages, offsets)
-        with jax.named_scope("gdn_state"):
+        """The paged part's ``write`` of the attention layers' rows and,
+        in the same step, the recurrent layers' state of the rows'
+        ``slots`` ((B,); None: the rows are all the slots, in order):
+        ``new_s`` (Lg, B, Hv, dk, dv) and ``new_conv`` (Lg, B, K - 1,
+        channels) as the scan stacked them."""
+        out = self.paged.write(self._paged(kv_cache), new_k, new_v, pages,
+                               offsets)
+        with jax.named_scope(self.scope):
             for name, rows in (("s", new_s), ("conv", new_conv)):
                 leaf = kv_cache[name]
                 rows = self._as_stored(name, rows, leaf)
@@ -567,10 +578,11 @@ class RecurrentKV(HeadKV):
 
     def insert_pages(self, kv_cache: KVCache, k_new, v_new, s_new, conv_new,
                      dest, slots: Optional[jax.Array] = None) -> KVCache:
-        """``HeadKV.insert_pages`` of a prefilled bucket's rows, and the
-        ONE sequence's state into its slot (``slots`` (1,); None: 0)."""
-        out = super().insert_pages({n: kv_cache[n] for n in ("k", "v")},
-                                   k_new, v_new, dest)
+        """The paged part's ``insert_pages`` of a prefilled bucket's
+        rows, and the ONE sequence's state into its slot (``slots``
+        (1,); None: 0)."""
+        out = self.paged.insert_pages(self._paged(kv_cache), k_new, v_new,
+                                      dest)
         at = jnp.zeros((1,), jnp.int32) if slots is None else slots
         for name, rows in (("s", s_new), ("conv", conv_new)):
             out[name] = kv_cache[name].at[:, at].set(
@@ -742,8 +754,9 @@ class LatentKV:
 
     leaves = ("c", "r")
 
-    def __init__(self, cfg: LlamaConfig):
+    def __init__(self, cfg: LlamaConfig, layers: Optional[int] = None):
         self.cfg = cfg
+        self.n_layers = cfg.num_layers if layers is None else layers
         self.R, self.rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
         self.nope, self.vd = cfg.qk_nope_head_dim, cfg.v_head_dim
 
@@ -751,7 +764,7 @@ class LatentKV:
 
     def init_dense(self, batch: int, max_len: int,
                    dtype: jnp.dtype = jnp.bfloat16) -> KVCache:
-        L = self.cfg.num_layers
+        L = self.n_layers
         return {"c": jnp.zeros((L, batch, max_len, self.R), dtype),
                 "r": jnp.zeros((L, batch, max_len, self.rope), dtype)}
 
@@ -763,7 +776,7 @@ class LatentKV:
                 "an int8 KV pool over a latent cache (kv_lora_rank) is "
                 "not supported: the latent row is key and value at once "
                 "and has one scale plane too few")
-        L = self.cfg.num_layers
+        L = self.n_layers
         return {"c": jnp.zeros((L, n_pages, 1, page_size, self.R), dtype),
                 "r": jnp.zeros((L, n_pages, 1, self.rope, page_size),
                                dtype)}

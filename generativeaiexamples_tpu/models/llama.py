@@ -48,7 +48,16 @@ the router keeps every column. A model with learned sparse attention
 stacked over those alone: ``index_wq`` (Lf, Rq, Hi*di), ``index_wk`` (Lf,
 D, di), ``index_k_norm`` / ``index_k_norm_b`` (Lf, di), ``index_wp`` (Lf,
 D, Hi); bf16, never quantized (the selection is what a precision step
-would move).
+would move). Where not every layer attends (``full_attention_interval``)
+a stack holds its attention layers' leaves stacked over those alone
+(``ATTENTION_WEIGHTS``: per-head attention's, or latent attention's with
+``wq`` (Lf, D, H*hd) alone where ``q_lora_rank`` is 0 and ``wz_head``
+(Lf, D, H) under ``attn_gate`` "head") and its recurrent layers' over
+theirs: ``gdn_*`` (a decay a head) or ``kda_*`` (a decay a channel:
+``kda_wqkv`` (Lg, D, channels), ``kda_wf`` (Lg, D, H*dk), ``kda_wg`` (Lg,
+D, H*dv), ``kda_wb`` (Lg, D, H), ``kda_conv`` (Lg, channels, K),
+``kda_A_log`` (Lg, H), ``kda_dt_bias`` (Lg, H*dk), ``kda_norm`` (Lg, dv),
+``kda_wout`` (Lg, H*dv, D)).
 """
 
 from __future__ import annotations
@@ -107,6 +116,10 @@ HC_SCOPES = ("hc_pre", "hc_post")
 #: — ops/gated_delta.py; the state's write after a scan is
 #: ``gdn_state``.
 GDN_SCOPES = ("gdn_proj", "gdn_conv", "gdn_step", "gdn_scan", "gdn_state")
+#: The same five stages where the recurrence decays a channel at its own
+#: rate (``cfg.linear_decay`` "channel": ``_kda_mixer``; the decode
+#: step's kernel is ``kda_delta_step``).
+KDA_SCOPES = ("kda_proj", "kda_conv", "kda_step", "kda_scan", "kda_state")
 
 
 def _embed(params: "Params", tokens: jax.Array,
@@ -215,21 +228,21 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
     (_, _, L), *leading = reversed(cfg.layer_stacks)
     Fe = cfg.expert_width
 
-    def attention_layers(L):
-        """The layers of a stack of ``L`` whose mixer is attention: all,
-        but beside recurrent layers (one stack then)."""
-        return sum(cfg.layer_full) if cfg.recurrent else L
-
-    def stack(k, L, experts):
-        """One stack's tree, its matrices drawn from ``k`` in an order
-        the committed trees depend on."""
+    def stack(k, L, experts, first=0):
+        """One stack's tree (the model's layers ``first`` .. ``first +
+        L``), its matrices drawn from ``k`` in an order the committed
+        trees depend on. ``A``: its layers whose mixer is attention —
+        all, but beside recurrent layers; a stack without one has no
+        attention leaf."""
         layers: dict[str, jax.Array] = {
             "attn_norm": norm_w((L, D), dtype),
             "mlp_norm": norm_w((L, D), dtype),
         }
-        A = attention_layers(L)
-        if cfg.kv_lora_rank:
-            layers.update(latent(k, L))
+        A = sum(cfg.layer_full[first:first + L])
+        if not A:
+            pass
+        elif cfg.kv_lora_rank:
+            layers.update(latent(k, A))
         else:
             layers.update({
                 "wq": norm(next(k), (A, D, H * hd), D / q_gain),
@@ -273,7 +286,9 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
         return layers
 
     def latent(k, L):
-        """A latent-attention layer's five projections. The two latent
+        """A latent-attention layer's five projections (four where the
+        queries come through ONE matrix: ``q_lora_rank`` 0; ``wq`` then
+        carries what ``wq_b`` does, over a fan-in of D). The two latent
         norms take the gain out of ``wq_a`` and the latent columns of
         ``wkv_a``, which are drawn at HALF the fan-in deviation so that a
         program without a norm differs by more than the norm weights'
@@ -298,9 +313,13 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
         gain = q_gain * 0.36 if cfg.index_topk else q_gain
         half = jnp.where(jnp.arange(R + cfg.qk_rope_head_dim) < R, 0.5, 1.0)
         wkv_a = norm(next(k), (L, D, R + cfg.qk_rope_head_dim), D)
-        return {
+        queries = {
             "wq_a": norm(next(k), (L, D, Rq), 4 * D),
             "wq_b": norm(next(k), (L, Rq, H * hd), Rq * m2 * m2 / gain),
+        } if Rq else {
+            "wq": norm(next(k), (L, D, H * hd), D * m2 * m2 / gain)}
+        return {
+            **queries,
             "wkv_a": (wkv_a.astype(jnp.float32) * half).astype(dtype),
             "wk_b": norm(next(k), (L, R, H * nope), R),
             "wv_b": norm(next(k), (L, R, H * vd), R),
@@ -340,14 +359,19 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
                 next(k), shape, jnp.float32))).astype(dtype)
 
         L, experts = layers["attn_norm"].shape[0], "router" in layers
-        A = attention_layers(L)
+        A = layers["wo"].shape[0] if "wo" in layers else 0
         out: dict[str, jax.Array] = {}
-        if cfg.kv_lora_rank:
-            out["q_a_norm"] = near(1.0, (L, cfg.q_lora_rank))
-            out["kv_a_norm"] = near(1.0, (L, cfg.kv_lora_rank))
-        if cfg.attn_gate:
+        if cfg.kv_lora_rank and A:
+            if cfg.q_lora_rank:
+                out["q_a_norm"] = near(1.0, (A, cfg.q_lora_rank))
+            out["kv_a_norm"] = near(1.0, (A, cfg.kv_lora_rank))
+        if cfg.attn_gate == "head" and A:
+            # a gate a head, its pre-activation of deviation 1 over the
+            # normed stream: a program without it is off by half
+            out["wz_head"] = norm(next(k), (A, D, H), D)
+        elif cfg.attn_gate and A:
             out["wz"] = norm(next(k), (A, D, H * hd), D)
-        if cfg.qk_norm:
+        if cfg.qk_norm and A:
             out["q_norm"] = near(q_gain ** 0.5, (A, hd))
             out["k_norm"] = near(1.0, (A, hd))
         if cfg.post_norms:
@@ -475,8 +499,49 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
             "gdn_wout": norm(next(k), (L, Hv * dv, D), Hv * dv * resid),
         }
 
+    def kda(k, L):
+        """The leaves of ``L`` layers whose recurrence decays a CHANNEL
+        at its own rate (``cfg.linear_decay`` "channel"), drawn as
+        ``recurrent`` draws and for its reasons, with one more: the
+        decays must differ ACROSS the channels of one head, or a program
+        that averaged a head's decay into one scalar would pass.
+
+        - ``kda_A_log`` (a head) / ``kda_dt_bias`` (a channel): at ``a =
+          0`` a channel's state halves in 16 to 4096 tokens, log-uniform
+          over the CHANNELS (``g = floor sigmoid(exp(A_log) dt_bias)``
+          is ln 2 over that); ``A_log`` deviates by 0.3;
+        - ``kda_wf``: the decay's projection at half the fan-in
+          deviation, ``kda_wb`` at 1.2 times it (``beta`` over (0.1,
+          0.9)); both act through float32;
+        - ``kda_wg``: the output gate ``sigmoid(x W_g)``, a value a
+          value, pre-activations of deviation 1;
+        - ``kda_conv``, ``kda_norm``, ``kda_wout``: as ``gdn_*``."""
+        Hv, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+                      cfg.linear_value_head_dim)
+        Ch, K = cfg.linear_channels, cfg.linear_conv_kernel_dim
+        a_log = 0.3 * jax.random.normal(next(k), (L, Hv), jnp.float32)
+        half_life = jnp.exp(jax.random.uniform(
+            next(k), (L, Hv, dk), jnp.float32, jnp.log(16.0),
+            jnp.log(4096.0)))
+        share = jnp.log(2.0) / half_life / -cfg.linear_decay_floor
+        return {
+            "kda_wqkv": norm(next(k), (L, D, Ch), D),
+            "kda_wf": norm(next(k), (L, D, Hv * dk), 4 * D),
+            "kda_wg": norm(next(k), (L, D, Hv * dv), D),
+            "kda_wb": norm(next(k), (L, D, Hv), D / 1.44),
+            "kda_conv": (0.5 * jax.random.normal(
+                next(k), (L, Ch, K), jnp.float32)).astype(dtype),
+            "kda_A_log": a_log,
+            # sigmoid^-1 of the decay's share of the floor, over exp(A_log)
+            "kda_dt_bias": (jnp.log(share / (1.0 - share))
+                            / jnp.exp(a_log)[..., None]).reshape(L, Hv * dk),
+            "kda_norm": (1.0 + 0.1 * jax.random.normal(
+                next(k), (L, dv), jnp.float32)).astype(dtype),
+            "kda_wout": norm(next(k), (L, Hv * dv, D), Hv * dv * resid),
+        }
+
     kx = iter(jax.random.split(jax.random.fold_in(key, 1), 32))
-    layers = stack(k, L, bool(cfg.num_experts))
+    layers = stack(k, L, bool(cfg.num_experts), cfg.num_dense_layers)
     layers.update(extras(kx, layers))
     params: Params = {
         "embed": norm(next(k), (V, D),
@@ -488,8 +553,8 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
         params["final_norm_b"] = jnp.zeros((D,), dtype)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = norm(next(k), (D, V), D)
-    for name, _, n in leading:          # the leading dense stack
-        params[name] = stack(kx, n, False)
+    for name, first, n in leading:      # the leading dense stack
+        params[name] = stack(kx, n, False, first)
         params[name].update(extras(kx, params[name]))
     if cfg.index_topk:
         ki = iter(jax.random.split(jax.random.fold_in(key, 2), 16))
@@ -500,8 +565,16 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
         for name, _, n in cfg.layer_stacks:
             params[name].update(hyper(kh, n))
     if cfg.recurrent:
-        kr = iter(jax.random.split(jax.random.fold_in(key, 4), 8))
-        layers.update(recurrent(kr, L - sum(cfg.layer_full)))
+        # a stack's recurrent layers, stacked over those alone (eight
+        # keys a stack of a decay a head: the committed trees' draw)
+        channel = cfg.linear_decay == "channel"
+        kr = iter(jax.random.split(
+            jax.random.fold_in(key, 6 if channel else 4),
+            (16 if channel else 8) * len(cfg.layer_stacks)))
+        for name, first, n in cfg.layer_stacks:
+            Lg = n - sum(cfg.layer_full[first:first + n])
+            if Lg:
+                params[name].update((kda if channel else recurrent)(kr, Lg))
     if cfg.shared_expert_gate:
         # the shared expert's gate reads the normed stream (unit
         # entries): a pre-activation of deviation 1.5, so the gate lies
@@ -598,11 +671,14 @@ def layer_stat_names(cfg: LlamaConfig) -> tuple[str, ...]:
     """The scalars a layer reports under ``stats``: with dropless experts
     (parallel/moe.py) the distinct experts its rows reached among those
     it holds and, where it holds a share, the assignments that fell on
-    them; under hyper-connections ``hc_row_defect``, how far the rows of
+    them and, under a router limited to groups, the share of its rows
+    whose kept groups include a held one; under hyper-connections ``hc_row_defect``, how far the rows of
     its two write-back matrices are from summing to 1
     (ops/hyper_connection.py ``row_defect``)."""
     return ("experts_touched",) + (
         ("local_assignments",) if cfg.experts_held else ()) + (
+        ("route_groups_held_pct",) if cfg.experts_held
+        and cfg.topk_group < cfg.n_group else ()) + (
         ("hc_row_defect",) if cfg.hc_mult else ())
 
 
@@ -643,65 +719,68 @@ def _scan_inputs(layers: dict[str, jax.Array], cfg: LlamaConfig, first: int):
 
 #: The leaves of a stack that only its ATTENTION layers have, where not
 #: every layer attends (``cfg.full_attention_interval``): stacked over
-#: those alone, as the recurrent layers' ``gdn_*`` are over theirs.
-ATTENTION_WEIGHTS = ("wq", "wk", "wv", "wo", "wz", "q_norm", "k_norm")
-_ATTENTION_LEAVES = ATTENTION_WEIGHTS + ("cache_k", "cache_v")  # dense cache
+#: those alone, as the recurrent layers' ``gdn_*`` / ``kda_*`` are over
+#: theirs. Per-head attention's, then latent attention's.
+ATTENTION_WEIGHTS = ("wq", "wk", "wv", "wo", "wz", "q_norm", "k_norm",
+                     "wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_a_norm",
+                     "wk_b", "wv_b", "wz_head")
+_ATTENTION_LEAVES = ATTENTION_WEIGHTS + (
+    "cache_k", "cache_v", "cache_c", "cache_r")             # dense cache
 _RECURRENT_CACHE = ("cache_s", "cache_conv")
+RECURRENT_PREFIXES = ("gdn_", "kda_")
 
 
-def _by_period(stack: dict, period: int) -> tuple[dict, dict]:
-    """A stack's scan inputs regrouped so that ONE scan step is one
-    period of ``period`` layers — ``period - 1`` recurrent layers, then
-    an attention layer: ``(scanned, leaves)``. Two weight trees in one
-    stack, and no program computes both mixers for a layer: which one a
-    layer runs is its place in the period, known when the program is
-    traced.
+def _by_kind(stack: dict) -> tuple[dict, dict]:
+    """A stack's scan inputs where not every layer attends, by the kind
+    of layer that has them: ``(leaves, caches)``. Two weight trees in
+    one stack, and no program computes both mixers for a layer: which
+    one a layer runs is its index in the model, known when the program
+    is traced.
 
     ``leaves`` — ``{"common": what every layer has, stacked (L, ...);
     "recurrent": (Lg, ...); "attention": (Lf, ...)}`` — are HELD, not
     scanned, and a layer's slice is taken where it is used, by its place
-    among its kind (``_period_layer``): scanned as (P, period, ...) a
+    among its kind: scanned as (P, period, ...) a
     layer's matrix is a second, static index into the step's slab, and
     the chip's compiler copies the slab's layer out before the matmul
     (33 MB a recurrent layer a decode step: 0.6 ms of a 12 ms step;
     chip, PR 46) where a dynamic slice of the whole stack is read in
     place, as the experts' stacks are (``scan_layers``). Only a dense
-    cache's slices (``cache_*``) ride the scan."""
-    scanned: dict = {}
+    cache's slices (``caches``: ``cache_*``, by kind too) ride a scan."""
     leaves: dict = {"common": {}, "recurrent": {}, "attention": {}}
+    caches: dict = {"recurrent": {}, "attention": {}}
     for name, leaf in stack.items():
         if name in _RECURRENT_CACHE:
-            scanned[name] = leaf.reshape((-1, period - 1) + leaf.shape[1:])
+            caches["recurrent"][name] = leaf
         elif name.startswith("cache_"):
-            scanned[name] = leaf
+            caches["attention"][name] = leaf
         elif name in _ATTENTION_LEAVES:
             leaves["attention"][name] = leaf
-        elif name.startswith("gdn_"):
+        elif name.startswith(RECURRENT_PREFIXES):
             leaves["recurrent"][name] = leaf
         else:
             leaves["common"][name] = leaf
-    n = jax.tree.leaves(leaves["common"])[0].shape[0]
-    scanned["period"] = jnp.arange(n // period, dtype=jnp.int32)
-    return scanned, leaves
+    return leaves, caches
 
 
-def _period_layer(lp: dict, leaves: dict, j, period: int) -> dict:
-    """A layer of the period a scan step runs (``_by_period``): its
-    slices of the held stacks, and of a dense cache's. ``j`` traced: the
-    ``j``-th recurrent layer; None: the attention layer that ends the
-    period."""
-    def at(tree, index):
-        return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
-            a, index, 0, keepdims=False), tree)
+def _layers_at(tree: dict, index) -> dict:
+    return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+        a, index, 0, keepdims=False), tree)
 
-    p = lp["period"]
-    if j is None:
-        own = at(leaves["attention"], p)
-        own.update({n: a for n, a in lp.items() if n in _ATTENTION_LEAVES})
-        return {**at(leaves["common"], (p + 1) * period - 1), **own}
-    own = at(leaves["recurrent"], p * (period - 1) + j)
-    own.update(at({n: lp[n] for n in _RECURRENT_CACHE if n in lp}, j))
-    return {**at(leaves["common"], p * period + j), **own}
+
+def _stack_periods(cfg: LlamaConfig, first: int, n: int) -> tuple:
+    """How the model's layers ``first`` .. ``first + n`` (one stack) fall
+    into periods, which are counted in the MODEL's layer indices: ``(r0,
+    P, r1)`` — a head of ``r0`` recurrent layers and the attention layer
+    that ends their period (-1: the stack begins on a period's first
+    layer, or holds no attention layer), ``P`` whole periods, and ``r1``
+    recurrent layers behind the last attention layer."""
+    period = cfg.full_attention_interval
+    full = [i for i in range(n) if cfg.layer_full[first + i]]
+    if not full:
+        return -1, 0, n
+    r0 = full[0] if full[0] != period - 1 else -1
+    return r0, len(full) - (r0 >= 0), n - 1 - full[-1]
 
 
 def _run_stack(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
@@ -791,26 +870,70 @@ def _run_stack(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
             for name in layer_stat_names(cfg)}
         return h, state, sel, out, touched
 
-    def body(carry, lp):
-        h, state, li, sel = carry
-        if not period:
+    if period:
+        n = jax.tree.leaves(layers)[0].shape[0]
+        r0, P, r1 = _stack_periods(cfg, first, n)
+        periods = jnp.arange(P, dtype=jnp.int32)
+    # the Pallas call takes its layer as a (1,) scalar-prefetch operand;
+    # the held form indexes the flattened pool with a scalar
+    li = jnp.zeros((1,) if carried else (), jnp.int32)
+    if first:
+        li = li + first
+
+    if not period:
+        def body(carry, lp):
+            h, state, li, sel = carry
             h, state, sel, out, touched = one(h, state, li, sel, lp)
             return (h, state, li + 1, sel), (out, touched)
-        # the recurrent layers are one body too, scanned inside the
-        # step: a period traced layer by layer is twice the program (137
-        # of them overran the chip's compile cache and every start was
-        # cold, 640 s; chip, PR 46)
-        def recurrent(carry, j):
+
+        (h, state, _, selection), (out, touched) = jax.lax.scan(
+            body, (h, state, li, selection), stack)
+        return h, (state if carried else out), touched, selection
+
+    # Where not every layer attends, the stack's layers are run period
+    # by period (``_stack_periods``): the recurrent layers of a period
+    # are one body, scanned — a period traced layer by layer is twice
+    # the program (137 of them overran the chip's compile cache and
+    # every start was cold, 640 s; chip, PR 46) — and the whole periods
+    # one scan over that and their attention layer. A stack that begins
+    # inside a period runs its head as one more step of that scan, a
+    # step's recurrent layers then under a loop of as many trips as its
+    # period has (``ragged_periods``); one that ends inside a period (or
+    # holds no attention layer: leading dense layers) its last recurrent
+    # layers alone.
+    leaves, caches = _by_kind(stack)
+
+    def recurrent_layers(h, state, li, r, g_of, c_of, cache):
+        """``r`` recurrent layers, the ``j``-th the stack's layer
+        ``c_of(j)`` and the ``g_of(j)``-th of its recurrent layers;
+        ``cache``: their slices of a dense cache, (r, ...)."""
+        def layer(carry, x):
             h, state = carry
-            h, state, _, out, touched = one(
-                h, state, li + j, None, _period_layer(lp, leaves, j, period))
+            j, own = x
+            at = li + j         # the layer's index in the model
+            lp = {**_layers_at(leaves["recurrent"], g_of(j)), **own}
+            lp = {**_layers_at(leaves["common"], c_of(j)), **lp}
+            h, state, _, out, touched = one(h, state, at, None, lp)
             return (h, state), (out, touched)
 
         (h, state), (rows, stat) = jax.lax.scan(
-            recurrent, (h, state), jnp.arange(period - 1, dtype=jnp.int32))
+            layer, (h, state), (jnp.arange(r, dtype=jnp.int32), cache))
+        return h, state, rows, stat
+
+    def whole_period(carry, x):
+        """``period - 1`` recurrent layers and the attention layer behind
+        them, period ``x["period"]`` of a stack that begins on a
+        period's first layer."""
+        h, state, li, sel = carry
+        p = x["period"]
+        h, state, rows, stat = recurrent_layers(
+            h, state, li, period - 1, lambda j: p * (period - 1) + j,
+            lambda j: p * period + j, x["recurrent"])
+        last = li + period - 1
+        own = {**_layers_at(leaves["attention"], p), **x["attention"]}
         h, state, sel, out, touched = one(
-            h, state, li + period - 1, sel,
-            _period_layer(lp, leaves, None, period))
+            h, state, last, sel,
+            {**_layers_at(leaves["common"], (p + 1) * period - 1), **own})
         if out is not None:     # the attention layer's rows, then the
             out = out + rows    # recurrent layers' states and tails
         if stats:
@@ -818,21 +941,131 @@ def _run_stack(layers: dict[str, jax.Array], cfg: LlamaConfig, h: jax.Array,
                                 stat, touched)
         return (h, state, li + period, sel), (out, stat if stats else None)
 
-    if period:
-        stack, leaves = _by_period(stack, period)
+    def ragged_periods(carry, sizes):
+        """Periods of UNEQUAL numbers of recurrent layers — a head of
+        ``sizes[0]`` and the whole periods behind it — as ONE scan whose
+        step runs its period's recurrent layers under a loop of that
+        many trips: the head traced beside the scanned periods is a
+        second copy of both layer bodies in every program (2.8 MB of
+        text a chunk program, 812 s to build a cold start's 160
+        programs, 308 MB of them against the 201 MB the chip tool's
+        cache keeps; chip, PR 49). A loop of a traced length stacks
+        nothing, so what the recurrent layers leave (their states and
+        tails where the pool is held, their scalars under ``stats``) is
+        written by place into buffers in the loop's carry; the attention
+        layers' come out of the scan. Returns ``(carry, out, stats)``,
+        the stats the recurrent layers' and then the attention
+        layers'."""
+        h, state, li, sel = carry
 
-    # the Pallas call takes its layer as a (1,) scalar-prefetch operand;
-    # the held form indexes the flattened pool with a scalar
-    li = jnp.zeros((1,) if carried else (), jnp.int32)
-    if first:
-        li = li + first
-    (h, state, _, selection), (out, touched) = jax.lax.scan(
-        body, (h, state, li, selection), stack)
-    if period and out is not None:
+        def recurrent_layer(h, state, li, j, g0, c0):
+            at = li + j
+            lp = {**_layers_at(leaves["recurrent"], g0 + j),
+                  **_layers_at(caches["recurrent"], g0 + j)}
+            lp = {**_layers_at(leaves["common"], c0 + j), **lp}
+            h, state, _, out, touched = one(h, state, at, None, lp)
+            return h, state, (out, touched)
+
+        left = jax.eval_shape(
+            lambda h, state, li: recurrent_layer(h, state, li, 0, 0, 0)[2],
+            h, state, li)
+        bufs = jax.tree.map(lambda x: jnp.zeros(
+            (sum(sizes),) + x.shape, x.dtype), left)
+
+        def step(carry, x):
+            h, state, li, sel, bufs = carry
+
+            def layer(j, c):
+                h, state, bufs = c
+                h, state, new = recurrent_layer(h, state, li, j, x["g0"],
+                                                x["c0"])
+                return h, state, jax.tree.map(
+                    lambda b, v: jax.lax.dynamic_update_index_in_dim(
+                        b, v, x["g0"] + j, 0), bufs, new)
+
+            h, state, bufs = jax.lax.fori_loop(0, x["r"], layer,
+                                               (h, state, bufs))
+            last = li + x["r"]
+            own = {**_layers_at(leaves["attention"], x["period"]),
+                   **x["attention"]}
+            h, state, sel, out, touched = one(
+                h, state, last, sel,
+                {**_layers_at(leaves["common"], x["c0"] + x["r"]), **own})
+            return (h, state, last + 1, sel, bufs), (out, touched)
+
+        at = [0]
+        for r in sizes:
+            at.append(at[-1] + r)
+        xs = {"period": jnp.arange(len(sizes), dtype=jnp.int32),
+              "r": jnp.asarray(sizes, jnp.int32),
+              "g0": jnp.asarray(at[:-1], jnp.int32),
+              "c0": jnp.asarray([g + i for i, g in enumerate(at[:-1])],
+                                jnp.int32),
+              "attention": cut(caches["attention"], 0, len(sizes))}
+        (h, state, li, sel, (rows, stat)), (out, touched) = jax.lax.scan(
+            step, (h, state, li, sel, bufs), xs)
+        if out is not None:
+            out = out + rows
+        if stats:
+            stat = jax.tree.map(lambda a, b: jnp.concatenate([a, b]), stat,
+                                touched)
+        return (h, state, li, sel), out, stat if stats else None
+
+    def cut(tree, lo, hi):
+        return {name: a[lo:hi] for name, a in tree.items()}
+
+    outs, touched = [], []
+    carry = (h, state, li, selection)
+    g = 0           # recurrent layers run so far
+    if r0 >= 0:     # a stack that begins inside a period
+        sizes = (r0,) + (period - 1,) * P
+        carry, out, stat = ragged_periods(carry, sizes)
+        outs.append(out)
+        touched.append(stat)
+        g = sum(sizes)
+    elif P:
+        xs_p = {"period": periods,
+                "recurrent": {
+                    name: x.reshape((P, period - 1) + x.shape[1:])
+                    for name, x in cut(caches["recurrent"], 0,
+                                       P * (period - 1)).items()},
+                "attention": cut(caches["attention"], 0, P)}
+        carry, (out, stat) = jax.lax.scan(whole_period, carry, xs_p)
         # (P, period - 1, ...) -> the recurrent layers in order
-        out = out[:2] + tuple(a.reshape((-1,) + a.shape[2:])
-                              for a in out[2:])
+        outs.append(None if out is None else out[:-2] + tuple(
+            x.reshape((-1,) + x.shape[2:]) for x in out[-2:]))
+        touched.append(stat)
+        g = P * (period - 1)
+    if r1:
+        h, state, li, sel = carry
+        h, state, rows, stat = recurrent_layers(
+            h, state, li, r1, lambda j: g + j, lambda j: n - r1 + j,
+            cut(caches["recurrent"], g, g + r1))
+        carry = (h, state, li + r1, sel)
+        outs.append(None if rows is None else (None, None) + rows)
+        touched.append(stat)
+    h, state, _, selection = carry
+    if len(outs) == 1:
+        out, touched = outs[0], touched[0]
+    else:
+        out = _join_layers(outs)
+        touched = jax.tree.map(lambda *t: jnp.concatenate(
+            [x.reshape(-1) for x in t]), *touched) if stats else None
     return h, (state if carried else out), touched, selection
+
+
+def _join_layers(outs: list):
+    """The rows several runs of layers left, each a tuple of leaves
+    stacked over ITS layers of each kind (None: it had none of that
+    kind; a whole None: a carried pool, nothing came out), joined along
+    the layer axis in the order the layers ran."""
+    if any(o is None for o in outs):
+        return None
+    return tuple(
+        None if not parts else parts[0] if len(parts) == 1
+        else jnp.concatenate(parts, axis=0)
+        for parts in ([o[i] for o in outs if o[i] is not None]
+                      for i in range(len(outs[0]))))
 
 
 def _run_model(params: Params, cfg: LlamaConfig, h: jax.Array, *args,
@@ -861,7 +1094,8 @@ def _run_model(params: Params, cfg: LlamaConfig, h: jax.Array, *args,
     else:
         outs, touched = [], None
         for name, first, n in stacks:
-            part = xs and {k: v[first:first + n] for k, v in xs.items()}
+            part = xs and {k: v[slice(*_layers_of(cfg, k, first, n))]
+                           for k, v in xs.items()}
             h, out, t, selection = _run_stack(
                 params[name], cfg, h, *args, state=state, xs=part,
                 first=first, selection=selection, **kw)
@@ -870,12 +1104,26 @@ def _run_model(params: Params, cfg: LlamaConfig, h: jax.Array, *args,
             outs.append(out)
             if "router" in params[name]:
                 touched = t
-        if state is None:
+        if state is None and cfg.recurrent:
+            state = _join_layers(outs)
+        elif state is None:
             state = jax.tree.map(lambda *a: jnp.concatenate(a, axis=0),
                                  *outs)
     if cfg.hc_mult:
         h = hc.collapse(h, cfg.hc_mult)
     return h, state, touched
+
+
+def _layers_of(cfg: LlamaConfig, name: str, first: int, n: int) -> tuple:
+    """Where the model's layers ``first`` .. ``first + n`` lie in a dense
+    cache's leaf ``name`` (``cache_*``): stacked over all layers, or
+    beside recurrent layers over the layers of its kind alone."""
+    if not cfg.recurrent:
+        return first, first + n
+    kind = int(name not in _RECURRENT_CACHE)
+    before = sum(f == kind for f in cfg.layer_full[:first])
+    return before, before + sum(
+        f == kind for f in cfg.layer_full[first:first + n])
 
 
 def _kv_step(attn, rows: tuple, index: tuple, key=None):
@@ -1312,11 +1560,11 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
         # the router reads the stream as it enters the block
         with jax.named_scope("moe_route"):
             router_logits = _router_logits(h, lp)
-    if "gdn_wqkvz" in lp:
-        with jax.named_scope("gdn_proj"):
+    if "gdn_wqkvz" in lp or "kda_wqkv" in lp:
+        with jax.named_scope(cfg.recurrent_scope + "_proj"):
             x = block_norm(h, lp, "attn_norm", cfg)
         if recur is None:
-            mixed, new_cache = _gdn_mixer(x, lp, cfg)[0], None
+            mixed, new_cache = _recurrent_mixer(cfg)(x, lp, cfg)[0], None
         else:
             mixed, new_cache = recur(x)
         h = h + mixed
@@ -1328,6 +1576,9 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
                                            with_latent=True)
             elif cfg.kv_lora_rank:
                 q, k, v = _latent_qkv(x, lp, cfg, positions, inv_freq)
+                if cfg.attn_gate == "head":     # one gate a head
+                    gate = jax.nn.sigmoid(
+                        qmm(x, lp["wz_head"]).astype(jnp.float32))
             else:
                 q = qmm(x, lp["wq"])
                 k = qmm(x, lp["wk"])
@@ -1378,6 +1629,8 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
                     attn, keep = attn
                     new_cache = (None, keep)
         with jax.named_scope("attn_proj"):
+            if cfg.attn_gate == "head":
+                gate = jnp.repeat(gate, cfg.v_head_dim, axis=-1)
             attn = attn.reshape(
                 B, S, cfg.num_heads * (cfg.v_head_dim or cfg.head_dim))
             if cfg.attn_gate:
@@ -1483,39 +1736,115 @@ def _gdn_mixer(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
         beta = jax.nn.sigmoid(ba[..., :Hv])
         g = -jnp.exp(lp["gdn_A_log"].astype(f32)) * jax.nn.softplus(
             ba[..., Hv:] + lp["gdn_dt_bias"].astype(f32))
-        if n_valid is not None:
-            valid = (jnp.arange(S)[None, :] < n_valid[:, None])[..., None]
-            beta, g = jnp.where(valid, beta, 0.0), jnp.where(valid, g, 0.0)
-        if step and step_kernel is not None:
-            o, new = step_kernel(
-                q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
-                jnp.ones((B,), bool) if n_valid is None else n_valid > 0)
-            o = o[:, None]
-        elif step:
-            o, new = gd.gated_delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                         beta[:, 0], state)
-            o = o[:, None]
-            if n_valid is not None:     # an idle row's state as it was,
-                # bit for bit and whatever it holds
-                new = jnp.where((n_valid > 0)[:, None, None, None], new,
-                                state)
-        elif scan_kernel:
+        def scan(q, k, v, g, beta, state):
+            if not scan_kernel:
+                return gd.gated_delta_chunked(q, k, v, g, beta, state)
             # v read where the convolution left it, no slice in between
             o, new = gd.gated_delta_chunked_kernel(
                 q.reshape(B, S, Hk * dk), k.reshape(B, S, Hk * dk), u, g,
                 beta, state, v_at=2 * Hk * dk)
-            o = o.reshape(B, S, Hv, dv)
-        else:
-            o, new = gd.gated_delta_chunked(q, k, v, g, beta, state)
+            return o.reshape(B, S, Hv, dv), new
+
+        o, new = _recurrence(q, k, v, g, beta, state, n_valid, step_kernel,
+                             scan)
     with jax.named_scope("gdn_proj"):
         # the norm over a head's values, its weight applied as it is,
         # times silu(z); heads joined, projected back to the stream
-        of = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
-                               + cfg.rms_norm_eps)
-        y = of * lp["gdn_norm"].astype(f32) * jax.nn.silu(
-            z.reshape(B, S, Hv, dv).astype(f32))
-        mixed = qmm(y.reshape(B, S, Hv * dv).astype(x.dtype), lp["gdn_wout"])
+        mixed = _gated_head_norm(o, z, lp["gdn_norm"], lp["gdn_wout"],
+                                 jax.nn.silu, cfg.rms_norm_eps, x.dtype)
     return mixed, new, tail
+
+
+def _recurrence(q, k, v, g, beta, state, n_valid, step_kernel, scan):
+    """The delta rule over the S tokens of every row, for either decay:
+    ``(o (B, S, H, dv), state)``. What is not the sequence's (``n_valid``:
+    ``_gdn_mixer``) decays nothing and writes nothing; S == 1 is the
+    decode step — ``step_kernel`` where the cache object runs it over
+    its own leaf, else ``gated_delta_step`` with an idle row's state
+    left as it was, bit for bit and whatever it holds —, anything longer
+    ``scan(q, k, v, g, beta, state)``, the caller's chunked form."""
+    B, S = beta.shape[:2]
+    if n_valid is not None:
+        valid = (jnp.arange(S)[None, :] < n_valid[:, None])[..., None]
+        beta, g = jnp.where(valid, beta, 0.0), jnp.where(
+            valid if g.ndim == beta.ndim else valid[..., None], g, 0.0)
+    if S != 1:
+        return scan(q, k, v, g, beta, state)
+    if step_kernel is not None:
+        o, new = step_kernel(
+            q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+            jnp.ones((B,), bool) if n_valid is None else n_valid > 0)
+        return o[:, None], new
+    o, new = gd.gated_delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                 beta[:, 0], state)
+    o = o[:, None]
+    if n_valid is not None:
+        new = jnp.where((n_valid > 0)[:, None, None, None], new, state)
+    return o, new
+
+
+def _gated_head_norm(o, z, norm, wout, gate, eps, dtype):
+    """A recurrent mixer's output: ``o`` (B, S, H, dv) RMS-normed over a
+    head's values (the weight applied as it is), times ``gate(z)``, the
+    heads joined and projected back to the stream."""
+    B, S, H, dv = o.shape
+    of = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps)
+    y = of * norm.astype(jnp.float32) * gate(
+        z.reshape(B, S, H, dv).astype(jnp.float32))
+    return qmm(y.reshape(B, S, H * dv).astype(dtype), wout)
+
+
+def _kda_mixer(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
+               state: Optional[jax.Array] = None,
+               tail: Optional[jax.Array] = None,
+               n_valid: Optional[jax.Array] = None, step_kernel=None):
+    """``_gdn_mixer`` for a decay a CHANNEL of a head's keys
+    (``cfg.linear_decay`` "channel": Kimi Delta Attention), the same
+    arguments, results and rules for what is not the sequence's: q, k, v
+    one projection behind the convolution (as many key as value heads),
+    the log-decay ``g = floor * sigmoid(exp(A_log) (x W_f + dt_bias))``
+    (H x dk a token, float32, in (``cfg.linear_decay_floor``, 0)), the
+    write strength ``sigmoid(x W_b)`` a head, the output normed a head
+    and gated by ``sigmoid(x W_g)``. S == 1 is the decode step
+    (``gated_delta_step`` with the vector decay, or the cache object's
+    kernel over its own leaf), anything longer ``gd.kda_chunked``."""
+    B, S, _ = x.shape
+    H, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+                 cfg.linear_value_head_dim)
+    Ch, f32 = cfg.linear_channels, jnp.float32
+    if tail is None:        # a sequence's start
+        state = jnp.zeros((B, H, dk, dv), f32)
+        tail = jnp.zeros((B, cfg.linear_conv_kernel_dim - 1, Ch), x.dtype)
+    with jax.named_scope("kda_proj"):
+        u = qmm(x, lp["kda_wqkv"])
+        a, b = qmm_f32(x, lp["kda_wf"]), qmm_f32(x, lp["kda_wb"])
+        z = qmm(x, lp["kda_wg"])
+        # the head split stays out of the matmul (``decoder_layer``)
+        u, a, b, z = jax.lax.optimization_barrier((u, a, b, z))
+    with jax.named_scope("kda_conv"):
+        u, tail = gd.causal_conv(u, tail, lp["kda_conv"], n_valid)
+    step = S == 1
+    with jax.named_scope("kda_step" if step else "kda_scan"):
+        q = u[..., :H * dk].reshape(B, S, H, dk)
+        k = u[..., H * dk:2 * H * dk].reshape(B, S, H, dk)
+        v = u[..., 2 * H * dk:].reshape(B, S, H, dv)
+        q, k = gd.l2norm(q) * dk ** -0.5, gd.l2norm(k)
+        beta = jax.nn.sigmoid(b)
+        rate = jnp.exp(lp["kda_A_log"].astype(f32))[:, None]    # (H, 1)
+        g = cfg.linear_decay_floor * jax.nn.sigmoid(
+            rate * (a + lp["kda_dt_bias"].astype(f32)).reshape(B, S, H, dk))
+        o, new = _recurrence(q, k, v, g, beta, state, n_valid, step_kernel,
+                             lambda *a: gd.kda_chunked(*a))
+    with jax.named_scope("kda_proj"):
+        mixed = _gated_head_norm(o, z, lp["kda_norm"], lp["kda_wout"],
+                                 jax.nn.sigmoid, cfg.rms_norm_eps, x.dtype)
+    return mixed, new, tail
+
+
+def _recurrent_mixer(cfg: LlamaConfig):
+    """The recurrent layers' token mixer: the member of the family the
+    configuration states (``linear_decay``)."""
+    return _kda_mixer if cfg.linear_decay == "channel" else _gdn_mixer
 
 
 def _gdn_recur(cfg: LlamaConfig, load, store=None, step=None,
@@ -1524,19 +1853,22 @@ def _gdn_recur(cfg: LlamaConfig, load, store=None, step=None,
     ``(load, store, step)`` (models/kv_cache.py ``RecurrentKV.recur``).
     The state's read and write are the recurrence's own and carry its
     scope."""
+    mixer = _recurrent_mixer(cfg)
+
     def recur(x, lp, li, state):
         li = li.reshape(())
         lg = li - li // cfg.full_attention_interval
-        scope = "gdn_step" if x.shape[1] == 1 else "gdn_scan"
+        scope = cfg.recurrent_scope + (
+            "_step" if x.shape[1] == 1 else "_scan")
         with jax.named_scope(scope):
             s0, tail0 = load(lg, state, lp)
         if step is not None:        # the kernel steps the carried leaf
             kernel = lambda *a: step(lg, state, *a)         # noqa: E731
-            mixed, state, tail = _gdn_mixer(x, lp, cfg, None, tail0,
-                                            n_valid, kernel)
+            mixed, state, tail = mixer(x, lp, cfg, None, tail0,
+                                       n_valid, kernel)
             s = None
         else:
-            mixed, s, tail = _gdn_mixer(x, lp, cfg, s0, tail0, n_valid)
+            mixed, s, tail = mixer(x, lp, cfg, s0, tail0, n_valid)
         if store is None:
             return mixed, (s, tail)
         with jax.named_scope(scope):
@@ -1564,16 +1896,19 @@ def _latent_qkv(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
                 with_latent: bool = False):
     """A latent-attention layer's projections of its normed input ``x``
     (B, S, D): ``(q, c, k_r)`` — the queries (B, S, H, nope + rope)
-    through the low-rank pair ``wq_a`` (normed) and ``wq_b``, their rope
-    part rotated; the normed latent (B, S, R); the ONE rotated key part
+    through the low-rank pair ``wq_a`` (normed) and ``wq_b`` (or the one
+    matrix ``wq``), their rope part rotated; the normed latent (B, S, R); the ONE rotated key part
     (B, S, rope) all heads share. ``(c, k_r)`` is what a token leaves in
     the cache; how the heads' keys and values come out of it, expanded or
     absorbed, is the cache object's (models/kv_cache.py). ``with_latent``
     appends the normed query latent (B, S, Rq), the indexer's input."""
     B, S, _ = x.shape
     R, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    c_q = rmsnorm(qmm(x, lp["wq_a"]), lp["q_a_norm"], cfg.rms_norm_eps)
-    q = qmm(c_q, lp["wq_b"])
+    if "wq_a" in lp:
+        c_q = rmsnorm(qmm(x, lp["wq_a"]), lp["q_a_norm"], cfg.rms_norm_eps)
+        q = qmm(c_q, lp["wq_b"])
+    else:       # ONE query matrix and no norm (``q_lora_rank`` 0)
+        q = qmm(x, lp["wq"])
     kv = qmm(x, lp["wkv_a"])
     # the head split stays out of the matmul (``decoder_layer`` says why)
     q, kv = jax.lax.optimization_barrier((q, kv))

@@ -172,7 +172,7 @@ class RoundRecord:
         "harvest_wait_ms", "first_readback_ms", "emit_ms",
         "tokens_emitted", "first_tokens", "spec_accepted",
         "experts_touched", "tail_resort_pct", "local_assignments",
-        "hc_row_defect", "t_parts",
+        "hc_row_defect", "route_groups_held_pct", "t_parts",
         # finalization
         "t_done", "device_ms", "round_ms", "bw_util", "drift_ratio", "done",
         # bookkeeping
@@ -275,6 +275,11 @@ class RoundRecord:
         # the decode program returns beside ``experts_touched``. 0
         # where the tree holds every expert.
         self.local_assignments = 0.0
+        # Under a router limited to groups and an expert share: the
+        # share (%) of the round's live rows whose kept groups include
+        # one this tree holds experts of (parallel/moe.py), mean over
+        # the expert layers and the round's steps. 0 elsewhere.
+        self.route_groups_held_pct = 0.0
         # How far the rows of a layer's hyper-connection write-back
         # matrices were from summing to 1 (ops/hyper_connection.py
         # ``row_defect``), mean over the expert layers, their two
@@ -356,6 +361,8 @@ class RoundRecord:
                 "tail_resort_pct": round(self.tail_resort_pct, 2),
                 "local_assignments": round(self.local_assignments, 2),
                 "hc_row_defect": self.hc_row_defect,
+                "route_groups_held_pct": round(
+                    self.route_groups_held_pct, 2),
                 "kv_restore_pages": self.kv_restore_pages,
                 "hbm_bytes_est": self.hbm_bytes,
                 "bw_util": round(self.bw_util, 4),
@@ -452,7 +459,8 @@ class RoundRecorder:
                       experts_touched: float = 0.0,
                       tail_resort_pct: float = 0.0,
                       local_assignments: float = 0.0,
-                      hc_row_defect: float = 0.0) -> None:
+                      hc_row_defect: float = 0.0,
+                      route_groups_held_pct: float = 0.0) -> None:
         """One harvested device output of this round (harvest thread).
         The last part — once the scheduler has sealed the expected
         count — finalizes the record."""
@@ -470,6 +478,8 @@ class RoundRecorder:
             rec.local_assignments = float(local_assignments)
         if hc_row_defect:
             rec.hc_row_defect = float(hc_row_defect)
+        if route_groups_held_pct:
+            rec.route_groups_held_pct = float(route_groups_held_pct)
         rec.t_parts.append(time.monotonic())
         finalize = False
         with self._lock:

@@ -96,8 +96,11 @@ def l2norm(x: jax.Array) -> jax.Array:
 def gated_delta_step(q, k, v, g, beta, state):
     """One token a row: ``q, k`` (B, H, dk), ``v`` (B, H, dv), ``g,
     beta`` (B, H), ``state`` (B, H, dk, dv). Returns ``(o (B, H, dv)
-    float32, state)``."""
-    s = state.astype(jnp.float32) * jnp.exp(g)[..., None, None]
+    float32, state)``. ``g`` (B, H, dk) is a decay a CHANNEL of the
+    head's keys: row ``c`` of the state decays by ``exp(g[c])``."""
+    decay = jnp.exp(g)[..., None] if g.ndim == q.ndim \
+        else jnp.exp(g)[..., None, None]
+    s = state.astype(jnp.float32) * decay
     d = beta[..., None] * (v - jnp.sum(s * k[..., None], axis=-2))
     s = s + k[..., None] * d[..., None, :]
     return jnp.sum(s * q[..., None], axis=-2), s.astype(state.dtype)
@@ -122,24 +125,35 @@ def gated_delta_step_kernel(q, k, v, g, beta, active, states, layer, *,
     written once — plain XLA reads the state four times a step (two
     reductions, the update, the slice out of the leaf). ``active`` (B,)
     bool: an idle row's state is written back as it was read, bit for
-    bit. Returns ``(o (B, H, dv) float32, states)``."""
+    bit. Returns ``(o (B, H, dv) float32, states)``.
+
+    ``g`` (B, H, dk), a decay a CHANNEL of a head's keys, runs the
+    kernel's twin ``kda_delta_step``: the same grid, reads and writes,
+    the decays down the sublanes beside the key (one more transpose of
+    a (hb, dk) tile) so that the state's ROWS decay each at its own
+    rate."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     Lg, B, H, dk, dv = states.shape
     hb = min(H, _STEP_HEADS)
     f32 = jnp.float32
+    channel = g.ndim == 3
 
     def kernel(layer_ref, q_ref, k_ref, v_ref, dec_ref, beta_ref, act_ref,
                s_ref, o_ref, s_out_ref):
         # a head's key and query down the sublanes, beside its (dk, dv)
         # state: one transpose of the group's (hb, dk) tile
         kt, qt = k_ref[0].T, q_ref[0].T                     # (dk, hb)
+        if channel:
+            dt = dec_ref[0].T                               # (dk, hb)
         for h in range(hb):
             row = slice(h, h + 1)
             old = s_ref[0, 0, h]                            # (dk, dv)
             kc = kt[:, row]                                 # (dk, 1)
-            s = old.astype(f32) * dec_ref[0, row]           # x (1, dv)
+            # x (1, dv): the head's one decay; x (dk, 1): a row its own
+            s = old.astype(f32) * (dt[:, row] if channel
+                                   else dec_ref[0, row])
             d = beta_ref[0, row] * (
                 v_ref[0, row] - jnp.sum(s * kc, axis=0, keepdims=True))
             s = s + kc * d
@@ -158,7 +172,8 @@ def gated_delta_step_kernel(q, k, v, g, beta, active, states, layer, *,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(B, H // hb),
-            in_specs=[keys, keys, vals, vals, vals, vals, slab],
+            in_specs=[keys, keys, vals, keys if channel else vals, vals,
+                      vals, slab],
             out_specs=[vals, slab]),
         out_shape=[jax.ShapeDtypeStruct((B, H, dv), f32),
                    jax.ShapeDtypeStruct(states.shape, states.dtype)],
@@ -166,9 +181,10 @@ def gated_delta_step_kernel(q, k, v, g, beta, active, states, layer, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-        name="gated_delta_step",
+        name="kda_delta_step" if channel else "gated_delta_step",
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), q.astype(f32),
-      k.astype(f32), v.astype(f32), over_values(jnp.exp(g)),
+      k.astype(f32), v.astype(f32),
+      jnp.exp(g.astype(f32)) if channel else over_values(jnp.exp(g)),
       over_values(beta), over_values(jnp.broadcast_to(
           active[:, None], (B, H))), states)
     return o, states
@@ -236,6 +252,35 @@ def _unit_lower_inverse(a: jax.Array) -> jax.Array:
     return x[..., 0, :, :]
 
 
+def _blocks(a, nb: int, c: int):
+    """(B, T, H, ...) -> (nb, B, H, c, ...): blocks of ``c`` tokens."""
+    B, _, H = a.shape[:3]
+    a = a.reshape((B, nb, c, H) + a.shape[3:])
+    return jnp.moveaxis(jnp.moveaxis(a, 3, 1), 2, 0)
+
+
+def _carry_blocks(state, w, u, qk, q_in, k_out, g_out, T: int):
+    """The short scan over the blocks that carries the state, for either
+    chunked form: ``D = U - W S``, ``o = q_in S + qk D``, ``S <- g_out S
+    + k_out^T D`` (``g_out`` a head's scalar or a column a key channel).
+    Returns ``(o (B, T, H, dv), state)``."""
+    def step(s, x):
+        w, u, qk, q_in, k_out, g_out = x
+        sf = s.astype(jnp.float32)
+        d = u - jnp.matmul(w, sf, precision=_HI)
+        o = jnp.matmul(q_in, sf, precision=_HI) \
+            + jnp.matmul(qk, d, precision=_HI)
+        sf = g_out * sf + jnp.einsum("...ck,...cv->...kv", k_out, d,
+                                     precision=_HI)
+        return sf.astype(s.dtype), o
+
+    state, o = jax.lax.scan(step, state, (w, u, qk, q_in, k_out, g_out))
+    # (nb, B, H, c, dv) -> (B, T, H, dv)
+    nb, B, H, c, dv = o.shape
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 2), 1, 3).reshape(B, nb * c, H, dv)
+    return o[:, :T], state
+
+
 def gated_delta_chunked(q, k, v, g, beta, state, block: int = 64):
     """The recurrence over blocks of ``block`` tokens (module
     docstring): the same ``o`` (B, T, H, dv) float32 and final state as
@@ -249,12 +294,7 @@ def gated_delta_chunked(q, k, v, g, beta, state, block: int = 64):
         g, beta = (jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
                    for a in (g, beta))
     nb = (T + pad) // c
-
-    def blocks(a):      # (B, T, H, ...) -> (nb, B, H, c, ...)
-        a = a.reshape((B, nb, c, H) + a.shape[3:])
-        return jnp.moveaxis(jnp.moveaxis(a, 3, 1), 2, 0)
-
-    q, k, v, g, beta = (blocks(a) for a in (q, k, v, g, beta))
+    q, k, v, g, beta = (_blocks(a, nb, c) for a in (q, k, v, g, beta))
     G = jnp.cumsum(g, axis=-1)                              # (nb, B, H, c)
     i = jnp.arange(c)
     at_or_below = i[:, None] >= i[None, :]
@@ -272,22 +312,67 @@ def gated_delta_chunked(q, k, v, g, beta, state, block: int = 64):
     q_in = q * jnp.exp(G)[..., None]
     k_out = k * jnp.exp(G[..., -1:] - G)[..., None]
     g_out = jnp.exp(G[..., -1])[..., None, None]            # (nb, B, H, 1, 1)
+    return _carry_blocks(state, w, u, qk, q_in, k_out, g_out, T)
 
-    def step(s, x):
-        w, u, qk, q_in, k_out, g_out = x
-        sf = s.astype(jnp.float32)
-        d = u - jnp.matmul(w, sf, precision=_HI)
-        o = jnp.matmul(q_in, sf, precision=_HI) \
-            + jnp.matmul(qk, d, precision=_HI)
-        sf = g_out * sf + jnp.einsum("...ck,...cv->...kv", k_out, d,
-                                     precision=_HI)
-        return sf.astype(s.dtype), o
 
-    state, o = jax.lax.scan(step, state, (w, u, qk, q_in, k_out, g_out))
-    # (nb, B, H, c, dv) -> (B, T, H, dv)
-    o = jnp.moveaxis(jnp.moveaxis(o, 0, 2), 1, 3).reshape(
-        B, nb * c, H, o.shape[-1])
-    return o[:, :T], state
+def kda_chunked(q, k, v, g, beta, state, block: int = 64):
+    """:func:`gated_delta_chunked` for a decay a CHANNEL of a head's
+    keys, ``g`` (B, T, H, dk): the same ``o`` and final state as
+    :func:`gated_delta_recurrent` over the same operands.
+
+    With ``G`` the running sum of ``g`` inside a block (a vector a
+    token) the block's matrices are ``A_ij = beta_i sum_c k_i[c] k_j[c]
+    exp(G_i[c] - G_j[c])`` (``j < i``) and ``q_i`` against the same for
+    ``j <= i``: the decay no longer factors out of ``k k^T``, so keys
+    and queries carry it INTO the products. ``exp(-G_j)`` alone would
+    overflow (a channel may decay by e^-5 a token, e^-320 a block), so
+    the products are formed a SUB-BLOCK of ``_SOLVE`` key tokens at a
+    time, relative to its first token ``b0``: the rows' factor
+    ``exp(G_i - G_b0)`` is at most 1 for every row at or after ``b0``
+    (the rows before it are above the diagonal and masked; their
+    exponent is held at 0), the keys' ``exp(G_b0 - G_j)`` at most
+    ``exp(15 x 5)``, finite in float32 — which is what a model's lower
+    bound on ``g`` is for (clamped at 80 here, so a caller without one
+    loses accuracy, not finiteness). Everything else is as in the
+    scalar form: the corrections solve ``(I + A) D = beta (V - K+ S0)``
+    with ``K+ = k exp(G)`` (``_unit_lower_inverse``), a short scan over
+    the blocks carries the state, ``S <- diag(exp(G_c)) S0 + sum_j (k_j
+    exp(G_c - G_j)) d_j^T``. A token with ``g = 0`` and ``beta = 0``
+    leaves the state as it found it."""
+    B, T, H, dk = q.shape
+    c = block
+    pad = -T % c
+    if pad:     # a ragged last block: tokens that leave the state alone
+        q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                      for a in (q, k, v, g))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    nb = (T + pad) // c
+    sub = min(c, _SOLVE)
+    q, k, v, g, beta = (_blocks(a, nb, c) for a in (q, k, v, g, beta))
+    G = jnp.cumsum(g, axis=-2)                          # (nb, B, H, c, dk)
+    qk_rows = jnp.concatenate([q, k], axis=-2)          # (..., 2c, dk)
+    G_rows = jnp.concatenate([G, G], axis=-2)
+    cols = []
+    for b0 in range(0, c, sub):
+        ref = G[..., b0:b0 + 1, :]
+        rows = qk_rows * jnp.exp(jnp.minimum(G_rows - ref, 0.0))
+        keys = k[..., b0:b0 + sub, :] * jnp.exp(jnp.minimum(
+            ref - G[..., b0:b0 + sub, :], 80.0))
+        cols.append(jnp.einsum("...id,...jd->...ij", rows, keys,
+                               precision=_HI))
+    prod = jnp.concatenate(cols, axis=-1)               # (..., 2c, c)
+    i = jnp.arange(c)
+    qk = jnp.where(i[:, None] >= i[None, :], prod[..., :c, :], 0.0)
+    a = jnp.where(i[:, None] > i[None, :],
+                  beta[..., :, None] * prod[..., c:, :], 0.0)
+    inv = _unit_lower_inverse(a)
+    grow = jnp.exp(G)
+    w = jnp.matmul(inv, k * grow * beta[..., None], precision=_HI)
+    u = jnp.matmul(inv, v * beta[..., None], precision=_HI)
+    q_in = q * grow
+    k_out = k * jnp.exp(G[..., -1:, :] - G)
+    g_out = jnp.exp(G[..., -1, :])[..., None]           # (nb, B, H, dk, 1)
+    return _carry_blocks(state, w, u, qk, q_in, k_out, g_out, T)
 
 
 _SCAN_BLOCK = 64    # tokens a block of the scan kernel, as the XLA form's

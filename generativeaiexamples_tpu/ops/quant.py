@@ -46,7 +46,11 @@ _QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "wz", "w_gate", "w_up",
                      "wq_a", "wq_b", "wkv_a", "wk_b", "wv_b",
                      # a recurrent layer's two wide ones (its ``gdn_wba``,
                      # 64 columns that set decay and write strength, stays)
-                     "gdn_wqkvz", "gdn_wout")
+                     "gdn_wqkvz", "gdn_wout",
+                     # and where the decay is a channel's: q, k and v, the
+                     # decay's and the output gate's projections, the
+                     # output (``kda_wb``, a column a head, stays)
+                     "kda_wqkv", "kda_wf", "kda_wg", "kda_wout")
 _LAYER_STACKS = ("layers", "dense_layers")
 
 

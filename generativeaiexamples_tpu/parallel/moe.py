@@ -262,12 +262,45 @@ def router_scores(logits: jax.Array, lp: dict[str, jax.Array],
     (T, E) float32. Softmax scoring: the logits, and None (the softmax
     over the chosen). Sigmoid scoring: each expert's own score; the
     stored ``router_bias`` moves the choice only ("selection") or the
-    scores themselves ("scores")."""
+    scores themselves ("scores"). Under a limit to groups
+    (``cfg.n_group`` groups of consecutive experts, ``cfg.topk_group``
+    kept) the scores for the choice are zero outside the kept groups, as
+    the published rule fills them (``group_limit``)."""
     if cfg.router_score_func == "softmax":
         return logits, None
     scores = jax.nn.sigmoid(logits)
     biased = scores + lp["router_bias"] if cfg.router_bias else scores
-    return biased, biased if cfg.router_bias == "scores" else scores
+    weigh = biased if cfg.router_bias == "scores" else scores
+    if cfg.topk_group < cfg.n_group:
+        biased = jnp.where(group_limit(biased, cfg), biased, 0.0)
+    return biased, weigh
+
+
+def group_limit(biased: jax.Array, cfg: LlamaConfig) -> jax.Array:
+    """(T, E) bool: the experts a token may choose among. A group's score
+    is the sum of its two largest (biased) scores; the ``topk_group``
+    best of the ``n_group`` groups stay (ties to the lower group, as
+    ``top_k`` breaks them)."""
+    T, E = biased.shape
+    groups = biased.reshape(T, cfg.n_group, E // cfg.n_group)
+    score = jnp.sum(jax.lax.top_k(groups, 2)[0], axis=-1)   # (T, n_group)
+    _, kept = jax.lax.top_k(score, cfg.topk_group)
+    keep = jnp.sum(jax.nn.one_hot(kept, cfg.n_group, dtype=jnp.int32),
+                   axis=1) > 0
+    return jnp.repeat(keep, E // cfg.n_group, axis=1)
+
+
+def _groups_held_pct(select: jax.Array, cfg: LlamaConfig, row_mask,
+                     S: int) -> jax.Array:
+    """The share (%) of the live tokens whose kept groups include one
+    this tree holds experts of: who may send this chip anything at all.
+    ``select`` is zero outside a token's kept groups (``router_scores``)
+    and a kept expert's biased score is not."""
+    first, held = cfg.experts_first, cfg.experts_held
+    may = jnp.any(select[:, first:first + held] != 0, axis=1)
+    live = jnp.ones_like(may) if row_mask is None \
+        else jnp.repeat(row_mask, S)
+    return 100.0 * jnp.sum(may & live) / jnp.maximum(jnp.sum(live), 1)
 
 
 def scale_chosen(weight: jax.Array, cfg: LlamaConfig) -> jax.Array:
@@ -358,6 +391,9 @@ def dropless_moe_ffn(x: jax.Array, router_logits: jax.Array,
             rt["weight"] = rt["weight"] * rt["held"]
             if aux is not None:
                 aux["local_assignments"] = rt["assigned"]
+                if cfg.topk_group < cfg.n_group:
+                    aux["route_groups_held_pct"] = _groups_held_pct(
+                        select, cfg, row_mask, S)
 
     ffn = (grouped_ffn.grouped_expert_ffn
            if grouped_ffn.use_kernel(D, w_gate.shape[-1], bm, x.dtype)

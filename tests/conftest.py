@@ -30,6 +30,27 @@ import contextlib  # noqa: E402
 import pytest  # noqa: E402
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _release_compiled_programs():
+    """Drop a test module's compiled programs when it is done. Every
+    XLA:CPU executable a process keeps is a handful of memory mappings;
+    a module of model tests leaves ~10 000 (tests/test_recurrent_layers.py
+    alone), a worker of the tier-1 run goes through a dozen and a half
+    such modules, and the kernel allows a process 65 530
+    (``vm.max_map_count``): past it ``mmap`` fails inside the next
+    compile, whichever test it is, and the worker dies of a segmentation
+    fault in ``backend_compile_and_load`` (seen at PR 49 in four runs of
+    four, each in another plain-model test; ``jax.clear_caches()`` takes
+    a process from 3308 mappings back to 688). Set up first, so torn
+    down after the module's own fixtures have stopped their engines."""
+    yield
+    import gc
+
+    import jax
+    jax.clear_caches()
+    gc.collect()
+
+
 @pytest.fixture(scope="session")
 def cpu_devices():
     import jax

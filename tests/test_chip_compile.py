@@ -1087,3 +1087,106 @@ def test_gated_delta_scan_kernel_compiles(topo, rows):
     text = compiled.as_text()
     assert "gated_delta_scan" in text and "tpu_custom_call" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+# ------------------------------------- a decay a channel (PR 49)
+
+
+def test_kda_step_kernel_compiles(topo):
+    """The decode step's twin for a decay a CHANNEL of a head's keys over
+    the cache's whole state leaf at ling-3.0-flash's widths (10 recurrent
+    layers x 32 slots x 32 heads of 128 x 128), in place: the decays
+    come in as wide as the keys and are turned beside them."""
+    from generativeaiexamples_tpu.ops.gated_delta import (
+        gated_delta_step_kernel, step_kernel_supported)
+    dev = SingleDeviceSharding(topo.devices[0])
+    Lg, B, H, dk, dv = 10, 32, 32, 128, 128
+    assert step_kernel_supported(H, dk, dv)
+    f32 = jnp.float32
+    args = (sds((B, H, dk), f32, dev), sds((B, H, dk), f32, dev),
+            sds((B, H, dv), f32, dev), sds((B, H, dk), f32, dev),
+            sds((B, H), f32, dev), sds((B,), jnp.bool_, dev),
+            sds((Lg, B, H, dk, dv), f32, dev), sds((), jnp.int32, dev))
+    compiled = jax.jit(gated_delta_step_kernel, donate_argnums=(6,)).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert "kda_delta_step" in text and "tpu_custom_call" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def ling_cfg():
+    """Six layers at published widths in periods of three: a dense KDA
+    layer, then an expert stack that begins INSIDE a period — [K L] [K K
+    L] with group 0's experts: the head and the whole period are two
+    steps of one scan (``llama._run_stack`` ``ragged_periods``)."""
+    cfg = dataclasses.replace(
+        get_model_config("ling-3.0-flash"), num_layers=6,
+        num_dense_layers=1, full_attention_interval=3, experts_held=64)
+    assert llama._stack_periods(cfg, 1, 5) == (1, 1, 0)
+    return cfg
+
+
+@pytest.mark.parametrize("rows", [4])
+def test_kda_latent_chunk_program_compiles(topo, tpu_backend, rows):
+    """``ling-3.0-flash``'s 512-token chunk program of four prompts'
+    rows over a state a slot beside a latent pool, the cell's 68-page
+    window: the chunked scan for a decay a channel is XLA's (under the
+    scope ``kda_scan``), the latent layers' prefix walk the chunk
+    kernel, and the program fits beside the engine's reserve."""
+    cfg = ling_cfg()
+    dev = SingleDeviceSharding(topo.devices[0])
+    cache = on(jax.eval_shape(lambda: llama.init_paged_kv_cache(
+        cfg, 2177, PAGE, slots=32)), dev)
+    i32 = lambda *shape: sds(shape, jnp.int32, dev)  # noqa: E731
+
+    def chunk(params, tok, pos, cache, tbl, valid, start, slots):
+        return llama.apply_prefill_paged(params, cfg, tok, pos, cache, tbl,
+                                         valid, start, use_kernel=True,
+                                         slots=slots)
+
+    compiled = jax.jit(chunk, donate_argnums=(3,)).lower(
+        on(param_shapes(cfg), dev), i32(rows, 512), i32(rows, 512), cache,
+        i32(rows, 68), i32(rows), i32(rows) if rows > 1 else i32(),
+        i32(rows)).compile()
+    assert_fits(compiled)
+    text = compiled.as_text()
+    assert re.search(r'op_name="[^"]*/kda_scan/', text)
+    assert re.search(r'op_name="[^"]*/kda_state/', text)
+    assert "chunk_attention_prefix" in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (1 << 30 if rows == 1 else 3 << 30), temp
+
+
+def test_kda_latent_decode_step_compiles(topo, tpu_backend):
+    """The decode step at the cell's 32 rows: the state leaf and the
+    latent pool in the layer scan's carry and donated, the recurrence
+    the kernel ``kda_delta_step`` under the scope ``kda_step``, the
+    latent read the latent decode kernel, ``route_groups_held_pct``
+    among its results."""
+    cfg = ling_cfg()
+    dev = SingleDeviceSharding(topo.devices[0])
+    cache = on(jax.eval_shape(lambda: llama.init_paged_kv_cache(
+        cfg, 2177, PAGE, slots=32)), dev)
+    i32 = lambda *shape: sds(shape, jnp.int32, dev)  # noqa: E731
+    B = 32
+
+    def step(params, tok, pos, cache, tbl, wp, off, active):
+        return llama.apply_decode_paged(params, cfg, tok, pos, cache, tbl,
+                                        pos[:, 0] + 1, wp, off,
+                                        use_kernel=True, return_hidden=True,
+                                        active=active, stats=True)
+
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        on(param_shapes(cfg), dev), i32(B, 1), i32(B, 1), cache, i32(B, 68),
+        i32(B), i32(B), sds((B,), jnp.bool_, dev)).compile()
+    assert_fits(compiled)
+    m = compiled.memory_analysis()
+    pool = sum(math.prod(x.shape) * x.dtype.itemsize for x in cache.values())
+    assert m.alias_size_in_bytes >= pool            # donated, not copied
+    text = compiled.as_text()
+    assert re.search(r'op_name="[^"]*/kda_step/[^"]*kda_delta_step', text)
+    assert re.search(r'op_name="[^"]*/attn/[^"]*latent_attn_decode', text)
+    assert set(jax.eval_shape(step, on(param_shapes(cfg), dev), i32(B, 1),
+                              i32(B, 1), cache, i32(B, 68), i32(B), i32(B),
+                              sds((B,), jnp.bool_, dev))[2]) == {
+        "experts_touched", "local_assignments", "route_groups_held_pct"}

@@ -402,7 +402,7 @@ def test_lora_refuses_latent_attention(built):
 
 
 def test_configuration_states_its_latent_attention_whole():
-    with pytest.raises(ValueError, match="needs q_lora_rank"):
+    with pytest.raises(ValueError, match="needs qk_nope_head_dim"):
         LlamaConfig(kv_lora_rank=64, num_kv_heads=1)
     with pytest.raises(ValueError, match="num_kv_heads is 1"):
         dataclasses.replace(CFG, num_kv_heads=4)

@@ -300,11 +300,11 @@ def test_the_draw_makes_the_state_matter():
 
 
 @pytest.mark.parametrize("change,says", [
-    (dict(num_layers=5), "divides num_layers"),
+    (dict(num_layers=1), "num_layers holds once"),
     (dict(full_attention_interval=1), "at least 2"),
     (dict(linear_num_value_heads=3), "multiple of the key heads"),
     (dict(linear_conv_kernel_dim=1), "at least 2 taps"),
-    (dict(sliding_window=64, window_layers=(1, 0)), "ONE stack"),
+    (dict(sliding_window=64, window_layers=(1, 0)), "window or rope"),
     (dict(partial_rotary_factor=0.0), "partial_rotary_factor"),
     (dict(full_attention_interval=0), "recurrent layers'"),
     (dict(num_shared_experts=0), "shared_expert_gate needs"),
