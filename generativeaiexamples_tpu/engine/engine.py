@@ -287,6 +287,12 @@ _STATS_TEMPLATE = {
     # 0 where the tree holds every expert.
     "local_assignments_sum": 0.0,
     "local_assignments_rounds": 0,
+    # ... and the padded rows a layer's dispatch gather walked to serve
+    # them (RoundRecord.route_rows_read), summed over the same rounds;
+    # ``stats`` derives route_rows_per_assignment, rows read an
+    # assignment held
+    "route_rows_read_sum": 0.0,
+    "route_rows_read_rounds": 0,
     # Hyper-connections (models/configs.py ``hc_mult``): the sum over
     # decode rounds of how far the rows of a layer's write-back matrices
     # were from summing to 1 (ops/hyper_connection.py ``row_defect``),
@@ -319,7 +325,7 @@ def engine_stat_keys() -> tuple[str, ...]:
             + ("dispatch_queue_depth", "queue_waiting",
                "sched_prefill_share", "sched_prefill_fill",
                "spec_acceptance_rate", "spec_tokens_per_step",
-               "sched_cost_drift_ratio",
+               "sched_cost_drift_ratio", "route_rows_per_assignment",
                "kv_tier_host_pages", "kv_restore_hit_rate",
                "kv_bytes_per_token", "index_bytes_per_token",
                "state_bytes", "slot_bytes", "prefix_cache_off", "uptime_s")
@@ -1500,6 +1506,12 @@ class Engine:
             round(out["spec_verify_tokens"]
                   / out["spec_verify_slot_steps"], 4)
             if out["spec_verify_slot_steps"] else 0.0)
+        # An expert share: padded rows the dispatch gather walked for
+        # each assignment that fell on a held expert (0.0 without one).
+        out["route_rows_per_assignment"] = (
+            round(out["route_rows_read_sum"]
+                  / out["local_assignments_sum"], 2)
+            if out["local_assignments_sum"] else 0.0)
         # Construction-time feature downgrades — derived from the list
         # (written once at build, before any reader exists).
         out["downgrades"] = len(self._downgrades)

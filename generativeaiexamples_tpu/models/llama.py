@@ -671,12 +671,15 @@ def layer_stat_names(cfg: LlamaConfig) -> tuple[str, ...]:
     """The scalars a layer reports under ``stats``: with dropless experts
     (parallel/moe.py) the distinct experts its rows reached among those
     it holds and, where it holds a share, the assignments that fell on
-    them and, under a router limited to groups, the share of its rows
-    whose kept groups include a held one; under hyper-connections ``hc_row_defect``, how far the rows of
-    its two write-back matrices are from summing to 1
+    them, the padded rows its walk of the layout gathered for them
+    (``route_rows_read``) and, under a router limited to groups, the
+    share of its rows whose kept groups include a held one; under
+    hyper-connections ``hc_row_defect``, how far the rows of its two
+    write-back matrices are from summing to 1
     (ops/hyper_connection.py ``row_defect``)."""
     return ("experts_touched",) + (
-        ("local_assignments",) if cfg.experts_held else ()) + (
+        ("local_assignments", "route_rows_read") if cfg.experts_held
+        else ()) + (
         ("route_groups_held_pct",) if cfg.experts_held
         and cfg.topk_group < cfg.n_group else ()) + (
         ("hc_row_defect",) if cfg.hc_mult else ())

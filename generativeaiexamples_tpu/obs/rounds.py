@@ -172,7 +172,8 @@ class RoundRecord:
         "harvest_wait_ms", "first_readback_ms", "emit_ms",
         "tokens_emitted", "first_tokens", "spec_accepted",
         "experts_touched", "tail_resort_pct", "local_assignments",
-        "hc_row_defect", "route_groups_held_pct", "t_parts",
+        "hc_row_defect", "route_groups_held_pct", "route_rows_read",
+        "t_parts",
         # finalization
         "t_done", "device_ms", "round_ms", "bw_util", "drift_ratio", "done",
         # bookkeeping
@@ -275,6 +276,11 @@ class RoundRecord:
         # the decode program returns beside ``experts_touched``. 0
         # where the tree holds every expert.
         self.local_assignments = 0.0
+        # What serving them walked: the padded rows a layer's dispatch
+        # gather read for the held assignments (parallel/moe.py
+        # ``route_sorted`` ``rows_read``: trips x a trip's rows), the
+        # same mean (``route_rows_per_assignment`` is their ratio).
+        self.route_rows_read = 0.0
         # Under a router limited to groups and an expert share: the
         # share (%) of the round's live rows whose kept groups include
         # one this tree holds experts of (parallel/moe.py), mean over
@@ -304,6 +310,15 @@ class RoundRecord:
         self._done_parts = 0
         self._sealed = False
         self._cb: Optional[Callable[["RoundRecord"], None]] = None
+
+    @property
+    def route_rows_per_assignment(self) -> float:
+        """Padded rows the dispatch gather read for each assignment that
+        fell on a held expert: near the block height where every touched
+        expert has few rows, larger by the walk's empty blocks. 0 where
+        the tree holds every expert."""
+        return (self.route_rows_read / self.local_assignments
+                if self.local_assignments else 0.0)
 
     def to_dict(self) -> dict:
         """JSON-ready view for ``/debug/rounds`` and the slow-round
@@ -360,6 +375,8 @@ class RoundRecord:
                 "experts_touched": round(self.experts_touched, 2),
                 "tail_resort_pct": round(self.tail_resort_pct, 2),
                 "local_assignments": round(self.local_assignments, 2),
+                "route_rows_per_assignment": round(
+                    self.route_rows_per_assignment, 2),
                 "hc_row_defect": self.hc_row_defect,
                 "route_groups_held_pct": round(
                     self.route_groups_held_pct, 2),
@@ -460,7 +477,8 @@ class RoundRecorder:
                       tail_resort_pct: float = 0.0,
                       local_assignments: float = 0.0,
                       hc_row_defect: float = 0.0,
-                      route_groups_held_pct: float = 0.0) -> None:
+                      route_groups_held_pct: float = 0.0,
+                      route_rows_read: float = 0.0) -> None:
         """One harvested device output of this round (harvest thread).
         The last part — once the scheduler has sealed the expected
         count — finalizes the record."""
@@ -480,6 +498,8 @@ class RoundRecorder:
             rec.hc_row_defect = float(hc_row_defect)
         if route_groups_held_pct:
             rec.route_groups_held_pct = float(route_groups_held_pct)
+        if route_rows_read:
+            rec.route_rows_read = float(route_rows_read)
         rec.t_parts.append(time.monotonic())
         finalize = False
         with self._lock:

@@ -35,6 +35,8 @@ Design (TPU-first):
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
@@ -170,11 +172,44 @@ def ep_sparse_moe_ffn(mesh: Mesh, x: jax.Array, lp: dict[str, jax.Array],
 # ------------------------------------------------------------- dropless
 
 
-def dropless_block_rows(n_tokens: int) -> int:
+def dropless_block_rows(n_tokens: int, per_expert: float | None = None
+                        ) -> int:
     """Rows of one block of the grouped product: a decode batch's rows in
     one block an expert (a row chooses an expert at most once), a prefill
-    chunk's in blocks of 64 (its experts average T*k/E rows)."""
-    return min(64, max(16, -(-n_tokens // 16) * 16))
+    chunk's in blocks of 64 (its experts average T*k/E rows). Under an
+    expert share the caller says how many rows an expert is to hold
+    (``per_expert``: T*k / num_experts expected, with what room it wants)
+    and the block is that, in the kernel's 16-row tiles, and no taller
+    than without: a share's experts average 8-16 rows of a 512-token
+    chunk, so a 64-row block is three quarters padding that the gather,
+    the product's grid and the way back all carry."""
+    rows = min(64, max(16, -(-n_tokens // 16) * 16))
+    if per_expert is None:
+        return rows
+    return min(rows, max(16, 16 * math.ceil(per_expert / 16)))
+
+
+def share_walk(n_tokens: int, k: int, n_experts: int, held: int,
+               bm: int) -> int:
+    """Blocks one trip of a share's walk holds (``few``): the blocks the
+    held experts are EXPECTED to fill, an eighth for a router's skew and
+    two standard deviations, from static shapes alone. An expert's rows
+    are Binomial(T, k / n_experts) under a balanced router and it fills
+    ceil(rows / bm) blocks; the held experts are taken as independent.
+    A step that fills more (a more skewed router, rows crowding onto the
+    held experts) takes a second trip: the count decides how much is
+    walked, never what is served."""
+    p = min(k / n_experts, 1 - 1e-9)
+    mean = var = 0.0
+    for c in range(1, n_tokens + 1):
+        q = math.exp(math.lgamma(n_tokens + 1) - math.lgamma(c + 1)
+                     - math.lgamma(n_tokens - c + 1) + c * math.log(p)
+                     + (n_tokens - c) * math.log1p(-p))
+        b = -(-c // bm)
+        mean, var = mean + q * b, var + q * b * b
+    var = max(var - mean * mean, 0.0)
+    few = math.ceil(1.125 * held * mean + 2 * math.sqrt(held * var))
+    return max(1, min(few, n_tokens * k // bm + held))
 
 
 def route_sorted(select: jax.Array, k: int, bm: int,
@@ -197,9 +232,22 @@ def route_sorted(select: jax.Array, k: int, bm: int,
       block_expert  (NB,)        expert of each block
       n_blocks      ()           blocks that hold rows (the loop's bound)
       touched       ()           distinct experts with at least one row
-      assigned      ()           assignments that hold a row (``share``)
-      held          (T, k) bool  assignments to experts held here (``share``)
-    with R = NB * bm, NB = T*k // bm + E (static; E the count held).
+    with R = NB * bm, NB = T*k // bm + E (static).
+
+    Under ``share`` = (first, E) — the E experts held here of the
+    ``select`` columns — what falls on an expert held elsewhere is
+    dropped BEFORE the sort: it takes no row, no block and no rank, the
+    blocks name the held experts from 0, and ``row_of`` is 0 there. The
+    layout is then WALKED, ``few`` blocks a trip (``share_walk``: what
+    the held experts are expected to fill; the blocks that hold rows
+    come first), so NB is T*k // bm + E rounded up to whole trips — its
+    worst case, every assignment held here, stays servable — and the
+    share's entries are
+      held          (T, k) bool  assignments to experts held here
+      assigned      ()           assignments that hold a row
+      few           int          blocks a trip of the walk (static)
+      rows_read     ()           padded rows the walk's trips gather:
+                                 ceil(n_blocks / few) * few * bm
     """
     T, E = select.shape
     A = T * k
@@ -235,6 +283,9 @@ def route_sorted(select: jax.Array, k: int, bm: int,
     bend = jnp.cumsum(blocks)
     bstart = bend - blocks                      # an expert's first block
     NB = A // bm + E
+    if share is not None:       # whole trips of the walk
+        few = share_walk(T, k, select.shape[1], E, bm)
+        NB = -(-NB // few) * few
     # the expert of block b: how many experts' blocks end at or before b
     block_expert = jnp.minimum(jnp.sum(
         bend[None, :] <= jnp.arange(NB, dtype=jnp.int32)[:, None], axis=1),
@@ -253,6 +304,9 @@ def route_sorted(select: jax.Array, k: int, bm: int,
     if held is not None:
         rt["held"] = held.reshape(T, k)
         rt["assigned"] = jnp.sum(counts).astype(jnp.float32)
+        rt["few"] = few
+        rt["rows_read"] = (-(-bend[-1] // few) * (few * bm)).astype(
+            jnp.float32)
     return rt
 
 
@@ -358,9 +412,16 @@ def dropless_moe_ffn(x: jax.Array, router_logits: jax.Array,
     normalised over its k; what falls on experts held elsewhere takes no
     row and no weight read here, and the output is the PARTIAL sum over
     the held experts — what this chip adds before the exchange that
-    nothing here stands in for. ``touched`` then counts among the held,
-    and ``aux`` (a dict, if given) receives ``local_assignments``, the
-    assignments that fell on them.
+    nothing here stands in for. Everything after the top-k is then sized
+    by what fell on the HELD experts, not by all T * k: the block height
+    follows an expert's expected rows (``dropless_block_rows``), the
+    dispatch gather and the product's grid walk the blocks that hold
+    rows (``share_walk`` blocks a trip, the trips counted from the
+    data), and the weights and the sum run over those rows
+    (``_walk_share``). ``touched`` then counts among the held, and
+    ``aux`` (a dict, if given) receives ``local_assignments``, the
+    assignments that fell on them, and ``route_rows_read``, the padded
+    rows the walk gathered to serve them.
 
     ``lp`` holds the layer's own (E, in, out) expert stacks or — where
     the caller keeps the stacks out of its layer scan and hands
@@ -371,17 +432,25 @@ def dropless_moe_ffn(x: jax.Array, router_logits: jax.Array,
     B, S, D = x.shape
     T = B * S
     k = cfg.num_experts_per_tok
-    bm = dropless_block_rows(T)
     relu = cfg.mlp == "relu_glu"
     x_flat = x.reshape(T, D)
     w_gate, w_up, w_down = (lp[n] if lp[n].ndim == 4 else lp[n][None]
                             for n in ("w_gate", "w_up", "w_down"))
     li = jnp.asarray(lp.get("layer_index", 0), jnp.int32)
+    share = (cfg.experts_first, cfg.experts_held) \
+        if cfg.experts_held else None
+    bm = dropless_block_rows(T)
+    if share:
+        # an expert's expected rows; with as much again for room where
+        # the kernel tiles the FFN width, so that a second block of an
+        # expert — which re-reads its matrices there — stays rare
+        F = w_gate.shape[-1]
+        tiled = grouped_ffn.ffn_tile(D, F, w_gate.dtype.itemsize) < F
+        bm = dropless_block_rows(
+            T, T * k / cfg.num_experts * (2 if tiled else 1))
     with jax.named_scope("moe_route"):
         select, weigh = router_scores(
             router_logits.reshape(T, cfg.num_experts), lp, cfg)
-        share = (cfg.experts_first, cfg.experts_held) \
-            if cfg.experts_held else None
         rt = route_sorted(
             select, k, bm,
             None if row_mask is None else jnp.repeat(row_mask, S), weigh,
@@ -391,6 +460,7 @@ def dropless_moe_ffn(x: jax.Array, router_logits: jax.Array,
             rt["weight"] = rt["weight"] * rt["held"]
             if aux is not None:
                 aux["local_assignments"] = rt["assigned"]
+                aux["route_rows_read"] = rt["rows_read"]
                 if cfg.topk_group < cfg.n_group:
                     aux["route_groups_held_pct"] = _groups_held_pct(
                         select, cfg, row_mask, S)
@@ -399,51 +469,93 @@ def dropless_moe_ffn(x: jax.Array, router_logits: jax.Array,
            if grouped_ffn.use_kernel(D, w_gate.shape[-1], bm, x.dtype)
            else block_loop_ffn)
 
-    NB = rt["block_expert"].shape[0]
-    few = NB if not share else cfg.experts_held - (
-        -4 * T * k * cfg.experts_held // (cfg.num_experts * bm))
-    if 2 * few > NB:
+    if not share:
         with jax.named_scope("moe_route"):
             x_pad = jnp.where(rt["valid"][:, None], x_flat[rt["token"]], 0)
         with jax.named_scope("moe_experts"):
             y_pad = ffn(x_pad, rt["block_expert"], rt["n_blocks"], li,
                         w_gate, w_up, w_down, bm=bm, relu=relu)
         return _combine_sorted(y_pad, rt, x.dtype, (B, S, D))
+    out = _walk_share(
+        x_flat, rt, lambda x_pad, block_expert, n_blocks: ffn(
+            x_pad, block_expert, n_blocks, li, w_gate, w_up, w_down, bm=bm,
+            relu=relu), bm)
+    return out.astype(x.dtype).reshape(B, S, D), rt["touched"]
 
-    # A thin share's layout is walked ``few`` blocks at a time. It is
-    # sized for every assignment falling on a held expert (T*k // bm + E
-    # blocks) and a share is expected 1 in num_experts / experts_held of
-    # them: gathered whole, a chunk program's 17152 padded rows, 97 % of
-    # them padding, were 15 % of it at D 7168 (chip, PR 39). The blocks
-    # that hold rows come first, so four times the expectation is one
-    # trip and a step whose rows did crowd onto the held experts takes
-    # more: nothing is dropped either way, and no trip holds more than
-    # ``few`` blocks of rows.
+
+def _walk_share(x_flat: jax.Array, rt: dict, ffn, bm: int) -> jax.Array:
+    """A share does the work of what is HELD HERE: (T, D) float32, each
+    token's weighted sum over its assignments to held experts.
+
+    The layout is sized for every assignment falling on a held expert
+    and 1 in num_experts / experts_held of them is expected to: gathered
+    whole, a 512-token chunk's 13312 padded rows held ~1280 assignments,
+    and the way back gathered, masked and summed all T * k of them in
+    float32, three in four of weight 0 — a quarter of a chunk program
+    (chip, PR 47). So the layout is walked ``few`` blocks a trip
+    (``share_walk``), the blocks that hold rows first: a trip gathers
+    its rows' tokens, runs the grouped product ``ffn(x_pad,
+    block_expert, n_blocks)`` over its blocks alone, and adds each row's
+    weighted output to its token by a product with the (T, rows) matrix
+    that holds a row's weight in its token's line — exact in float32
+    (``_exact_parts``): the sum ``_combine_sorted`` makes, its addends
+    in row order — with no gather back and no (T, k, D) tensor. A step
+    whose rows crowd onto the held experts takes more trips: nothing
+    is dropped, and no trip holds more than ``few`` blocks of rows."""
+    T, D = x_flat.shape
+    few = rt["few"]
     seg = few * bm
-    pad = -NB % few
-    valid, token = (jnp.pad(rt[n], (0, pad * bm)) for n in ("valid", "token"))
-    block_expert = jnp.pad(rt["block_expert"], (0, pad))
-    w = rt["weight"][..., None]                                 # (T, k, 1)
+    with jax.named_scope("moe_route"):
+        # each padded row's weight, by a scatter over the T * k
+        # assignments (what is held elsewhere or idle weighs 0 and is
+        # sent out of range): a gather costs by the index, ~13 ns each
+        # whatever a row's width (chip, PR 50), and the layout has more
+        # rows than a step has assignments
+        w = rt["weight"].reshape(-1)
+        R = rt["valid"].shape[0]
+        w_row = jnp.zeros((R,), jnp.float32).at[
+            jnp.where(w > 0, rt["row_of"].reshape(-1), R)].set(
+            w, mode="drop")
+        tokens = jnp.arange(T, dtype=jnp.int32)[:, None]
 
-    def segment(i, out):
+    def trip(i, out):
         at = lambda a, n: jax.lax.dynamic_slice_in_dim(a, i * n, n)
         with jax.named_scope("moe_route"):
-            x_pad = jnp.where(at(valid, seg)[:, None],
-                              x_flat[at(token, seg)], 0)
+            token, valid = at(rt["token"], seg), at(rt["valid"], seg)
+            x_pad = jnp.where(valid[:, None], x_flat[token], 0)
         with jax.named_scope("moe_experts"):
-            y_pad = ffn(x_pad, at(block_expert, few),
-                        jnp.clip(rt["n_blocks"] - i * few, 0, few), li,
-                        w_gate, w_up, w_down, bm=bm, relu=relu)
+            y_pad = ffn(x_pad, at(rt["block_expert"], few),
+                        jnp.clip(rt["n_blocks"] - i * few, 0, few))
         with jax.named_scope("moe_route"):
-            row = rt["row_of"] - i * seg
-            here = (w > 0) & ((row >= 0) & (row < seg))[..., None]
-            y = jnp.where(here, y_pad[jnp.clip(row, 0, seg - 1)].astype(
-                jnp.float32), 0.0)
-            return out + jnp.sum(y * w, axis=1)
+            # blocks without rows are left unwritten by the kernel
+            y = jnp.where(valid[:, None], y_pad, 0)
+            hit = token[None, :] == tokens                      # (T, seg)
+            for part in _exact_parts(at(w_row, seg), y.dtype):
+                out = out + jnp.dot(
+                    jnp.where(hit, part[None, :], 0), y,
+                    preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST)
+            return out
 
-    out = jax.lax.fori_loop(0, -(-rt["n_blocks"] // few), segment,
-                            jnp.zeros((T, D), jnp.float32))
-    return out.astype(x.dtype).reshape(B, S, D), rt["touched"]
+    return jax.lax.fori_loop(0, -(-rt["n_blocks"] // few), trip,
+                             jnp.zeros((T, D), jnp.float32))
+
+
+def _exact_parts(w: jax.Array, dtype) -> tuple:
+    """float32 ``w`` as terms of ``dtype`` that sum to it exactly: itself
+    for float32, three bfloat16 terms (8 + 8 + 8 of its 24 mantissa
+    bits) for bfloat16 — so that a product of ``w`` with bfloat16 values
+    is three single-pass products, each exact in its float32 sum, where
+    ONE float32 product at ``HIGHEST`` is six passes and, at a chunk's
+    (512, ~2400) x (~2400, D), seven seconds of every program's compile
+    (compile for a described v5e, PR 50)."""
+    if jnp.dtype(dtype) != jnp.dtype(jnp.bfloat16):
+        return (w,)
+    parts = []
+    for _ in range(3):
+        parts.append(w.astype(jnp.bfloat16))
+        w = w - parts[-1].astype(jnp.float32)
+    return tuple(parts)
 
 
 def _combine_sorted(y_pad, rt, dtype, shape):

@@ -1189,4 +1189,5 @@ def test_kda_latent_decode_step_compiles(topo, tpu_backend):
     assert set(jax.eval_shape(step, on(param_shapes(cfg), dev), i32(B, 1),
                               i32(B, 1), cache, i32(B, 68), i32(B), i32(B),
                               sds((B,), jnp.bool_, dev))[2]) == {
-        "experts_touched", "local_assignments", "route_groups_held_pct"}
+        "experts_touched", "local_assignments", "route_rows_read",
+        "route_groups_held_pct"}

@@ -93,9 +93,25 @@ def test_what_is_held_elsewhere_is_dropped_before_the_sort():
     assert int(rt["valid"].sum()) == held.sum()
     assert int(rt["n_blocks"]) == len(set(np.asarray(idx)[held]))
     assert float(rt["touched"]) == len(set(np.asarray(idx)[held]))
-    # static shapes follow the HELD experts, and blocks name them from 0
-    assert rt["block_expert"].shape[0] == T * K // bm + 4
+    # static shapes follow the HELD experts — every assignment held here
+    # stays servable, in whole trips of the walk — and blocks name them
+    # from 0
+    few = moe.share_walk(T, K, E, 4, bm)
+    assert rt["few"] == few and 1 <= few <= T * K // bm + 4
+    assert rt["block_expert"].shape[0] == -(-(T * K // bm + 4) // few) * few
+    assert rt["token"].shape == rt["valid"].shape \
+        == (rt["block_expert"].shape[0] * bm,)
     assert rt_all["block_expert"].shape[0] == T * K // bm + E
+    assert "few" not in rt_all and "rows_read" not in rt_all
+    # a held assignment's padded row names its token, and no other row
+    # is valid
+    src = np.flatnonzero(held.reshape(-1))
+    rows = np.asarray(rt["row_of"]).reshape(-1)[src]
+    assert np.array_equal(np.sort(rows), np.flatnonzero(rt["valid"]))
+    assert np.array_equal(np.asarray(rt["token"])[rows], src // K)
+    # the walk's trips, from the returned counters
+    trips = -(-int(rt["n_blocks"]) // few)
+    assert float(rt["rows_read"]) == trips * few * bm
     live = np.asarray(rt["block_expert"][:int(rt["n_blocks"])])
     assert set(live) == {int(e) - 4 for e in np.asarray(idx)[held]}
     # the weights are still those of all k (normalised together later)
@@ -108,54 +124,146 @@ def test_what_is_held_elsewhere_is_dropped_before_the_sort():
 
 @pytest.mark.parametrize("crowded", [False, True],
                          ids=["as_expected", "crowded"])
-def test_a_thin_share_gathers_only_the_blocks_that_can_hold_rows(crowded):
-    """2 of 64 experts held, 512 tokens: the layout has 34 blocks and
-    the expected load fills two, so the walk's one trip of six blocks
-    holds every row; when a selection bias sends EVERY token to the held
-    pair (16 blocks) it takes three. Both against a sum written out per
-    token: nothing is dropped either way."""
-    T, D, F, n, held, first = 512, 64, 32, 64, 2, 6
+@pytest.mark.parametrize("grouped", [False, True],
+                         ids=["any_expert", "group_limited"])
+@pytest.mark.parametrize("T", [24, 512], ids=["decode_rows", "chunk_rows"])
+@pytest.mark.parametrize("first,held", [(6, 2), (16, 16)],
+                         ids=["thin_share", "quarter_share"])
+def test_a_thin_share_gathers_only_the_blocks_that_can_hold_rows(
+        first, held, T, grouped, crowded):
+    """2 or 16 of 64 experts held, a chunk's 512 tokens or a decode
+    step's 24 rows (five of them idle): the layout is sized for every
+    assignment falling here and walked ``share_walk`` blocks a trip —
+    what the held experts are expected to fill — so a draw as expected
+    takes one trip and, when a selection bias sends EVERY token to the
+    held experts, more (a chunk's: its 16-row blocks fill several times
+    a trip's count). Both against a sum written out per token: nothing
+    is dropped either way, under a router limited to groups too. The
+    trips are read from the returned counters: ceil(n_blocks / few), and
+    ``rows_read`` is what they gathered."""
+    D, F, n = 64, 32, 64
     cfg = LlamaConfig(
         vocab_size=64, hidden_size=D, intermediate_size=F,
         moe_intermediate_size=F, num_layers=1, num_heads=4, num_kv_heads=2,
         head_dim=16, num_experts=n, num_experts_per_tok=K,
         moe_impl="dropless", router_score_func="sigmoid",
         router_norm_topk=True, router_scale=2.5, router_bias="selection",
+        n_group=8 if grouped else 1, topk_group=4 if grouped else 1,
         experts_held=held, experts_first=first, weight_init="unit_stream")
     ks = jax.random.split(jax.random.key(5), 5)
-    x = jax.random.normal(ks[0], (1, T, D), jnp.float32)
-    logits = jax.random.normal(ks[1], (1, T, n), jnp.float32)
+    decode = T < 64
+    shape = (T, 1) if decode else (1, T)
+    x = jax.random.normal(ks[0], shape + (D,), jnp.float32)
+    logits = jax.random.normal(ks[1], shape + (n,), jnp.float32)
+    mask = jnp.arange(T) % 5 != 2 if decode else None
+    live = np.ones((T,), bool) if mask is None else np.asarray(mask)
     bias = jnp.zeros((n,)).at[first:first + held].set(
         5.0 if crowded else 0.0)
     lp = {"router_bias": bias,
           "w_gate": jax.random.normal(ks[2], (held, D, F)) / 8,
           "w_up": jax.random.normal(ks[3], (held, D, F)) / 8,
           "w_down": jax.random.normal(ks[4], (held, F, D)) / 8}
-    aux = {}
-    got, touched = jax.jit(lambda x, lg, lp: moe.dropless_moe_ffn(
-        x, lg, lp, cfg, aux=aux))(x, logits, lp)
-    # the layout, and whether this draw fits one trip of six blocks
-    select, weigh = moe.router_scores(logits[0], lp, cfg)
-    rt = moe.route_sorted(select, K, 64, None, weigh, (first, held))
-    assert rt["block_expert"].shape[0] == 34
-    assert (int(rt["n_blocks"]) > 6) == crowded
+
+    def layer(x, lg, lp):
+        aux = {}
+        return moe.dropless_moe_ffn(x, lg, lp, cfg, mask, aux), aux
+
+    (got, touched), aux = jax.jit(layer)(x, logits, lp)
+    got = got.reshape(T, D)
+    # the layout as the layer makes it, and the trips its walk takes
+    select, weigh = moe.router_scores(logits.reshape(T, n), lp, cfg)
+    bm = moe.dropless_block_rows(T, T * K / n)
+    assert bm == (16 if decode else 32)         # not 64: an expert's rows
+    rt = moe.route_sorted(select, K, bm, mask, weigh, (first, held))
+    few = moe.share_walk(T, K, n, held, bm)
+    assert rt["few"] == few < T * K // bm + held
+    trips = -(-int(rt["n_blocks"]) // few)
+    assert float(aux["route_rows_read"]) == float(rt["rows_read"]) \
+        == trips * few * bm
+    if not decode:
+        assert (trips > 1) == crowded
+    elif not crowded:
+        assert trips == 1
     # per token: its chosen experts' outputs, weighted over all K, the
     # held ones kept
     _, idx = jax.lax.top_k(select, K)
     w = moe.scale_chosen(jnp.take_along_axis(weigh, idx, axis=1), cfg)
-    want = jnp.zeros((T, D))
+    xs = x.reshape(T, D)
+    want, n_held = jnp.zeros((T, D)), 0
     for j in range(K):
         e = idx[:, j] - first
-        here = (e >= 0) & (e < held)
+        here = (e >= 0) & (e < held) & live
+        n_held += int(here.sum())
         e = jnp.clip(e, 0, held - 1)
-        gate = jax.nn.silu(jnp.einsum("td,tdf->tf", x[0], lp["w_gate"][e]))
-        up = jnp.einsum("td,tdf->tf", x[0], lp["w_up"][e])
+        gate = jax.nn.silu(jnp.einsum("td,tdf->tf", xs, lp["w_gate"][e]))
+        up = jnp.einsum("td,tdf->tf", xs, lp["w_up"][e])
         y = jnp.einsum("tf,tfd->td", gate * up, lp["w_down"][e])
         want = want + jnp.where(here[:, None], y * w[:, j:j + 1], 0.0)
-    assert float(jnp.max(jnp.abs(got[0] - want))) < 2e-5
-    assert float(touched) == held
-    assert float(rt["assigned"]) == (2 * T if crowded else
-                                     float(jnp.sum(rt["held"])))
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-5
+    assert not np.asarray(got)[~live].any()
+    assert float(aux["local_assignments"]) == float(rt["assigned"]) == n_held
+    if crowded:     # every live token's K (its two) on the held experts
+        assert n_held == min(K, held) * live.sum()
+        assert float(touched) == held or decode
+
+
+def test_rows_read_follow_the_blocks_that_hold_rows():
+    """Where every touched expert has few rows the walk reads about a
+    block's rows an assignment: sixteen rows send one assignment each to
+    sixteen different held experts (and sixteen rows send none) — one
+    trip of ``few`` = 16 blocks, 16 padded rows an assignment; with every
+    row sending one, two rows an expert, 8."""
+    T, n, bm = 32, 64, 16
+    few = moe.share_walk(T, 2, n, 16, bm)
+    assert few == 16
+    for senders, per_assignment in ((16, 16.0), (32, 8.0)):
+        t = np.arange(T)
+        scores = np.zeros((T, n), np.float32)
+        scores[t, 16 + t % 16] = np.where(t < senders, 2.0, 0.0)   # held
+        scores[t, 40 + t % 8] += 1.5            # each row's other choice,
+        scores[t, 48 + t % 8] += 1.0            # and a non-sender's second
+        rt = moe.route_sorted(jnp.asarray(scores), 2, bm, share=(16, 16))
+        assert float(rt["assigned"]) == senders
+        assert int(rt["n_blocks"]) == 16 and float(rt["rows_read"]) == 256
+        ratio = float(rt["rows_read"]) / float(rt["assigned"])
+        assert ratio == per_assignment <= bm + 1
+
+
+def test_the_way_back_is_exact_in_bfloat16_too():
+    """A bfloat16 layer's way back is three single-pass products: a
+    row's float32 weight as three bfloat16 terms that sum to it bit for
+    bit, so each product with a bfloat16 output is exact in the float32
+    sum. Against the weighted sum written out over the SAME bfloat16
+    expert outputs, in float32."""
+    w = jax.random.uniform(jax.random.key(0), (4096,), jnp.float32) \
+        * jnp.exp(3 * jax.random.normal(jax.random.key(1), (4096,)))
+    parts = moe._exact_parts(w, jnp.bfloat16)
+    assert len(parts) == 3 and all(p.dtype == jnp.bfloat16 for p in parts)
+    assert jnp.array_equal(sum(p.astype(jnp.float32) for p in parts), w)
+    assert moe._exact_parts(w, jnp.float32) == (w,)
+    T, D, n, bm = 48, 32, 64, 16
+    ks = jax.random.split(jax.random.key(7), 3)
+    x = jax.random.normal(ks[0], (T, D), jnp.bfloat16)
+    rt = moe.route_sorted(jax.random.normal(ks[1], (T, n)), K, bm,
+                          share=(16, 16))
+    rt["weight"] = rt["weight"] * rt["held"]
+    mix = jax.random.normal(ks[2], (16, D, D), jnp.bfloat16) / 6
+
+    def ffn(x_pad, block_expert, n_blocks):     # a block's expert's matrix
+        return jnp.einsum("bmd,bde->bme", x_pad.reshape(-1, bm, D),
+                          mix[block_expert]).reshape(x_pad.shape)
+
+    got = moe._walk_share(x, rt, ffn, bm)
+    assert got.dtype == jnp.float32
+    # the one trip's outputs, made again outside the loop
+    seg = rt["few"] * bm
+    x_pad = jnp.where(rt["valid"][:seg, None], x[rt["token"][:seg]], 0)
+    y = np.asarray(ffn(x_pad, rt["block_expert"][:rt["few"]],
+                       rt["n_blocks"]).astype(jnp.float32))
+    rows, w = np.asarray(rt["row_of"]), np.asarray(rt["weight"])
+    assert int(rt["n_blocks"]) <= rt["few"]     # this draw fits the trip
+    want = sum(w[:, j:j + 1] * y[rows[:, j]] for j in range(K))
+    assert float(np.max(np.abs(np.asarray(got) - want))) < 1e-6
 
 
 def test_tree_holds_the_share_and_the_router_every_column():

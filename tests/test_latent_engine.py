@@ -91,6 +91,17 @@ def test_the_share_and_the_cache_are_counted(engine):
     mean = st["local_assignments_sum"] / st["local_assignments_rounds"]
     assert 0 < mean <= CFG.num_experts_per_tok
     assert any(r.local_assignments > 0 for r in engine.rounds.records())
+    # what serving them walked: whole trips of 16-row blocks, rows read
+    # an assignment held on the round record and in the stats
+    assert st["route_rows_read_rounds"] == st["local_assignments_rounds"]
+    assert st["route_rows_per_assignment"] == pytest.approx(
+        st["route_rows_read_sum"] / st["local_assignments_sum"], abs=0.01)
+    recs = [r for r in engine.rounds.records()
+            if r.engine_tag == engine.engine_tag and r.local_assignments > 0]
+    assert all(r.route_rows_read >= 16 and r.route_rows_per_assignment
+               == pytest.approx(r.route_rows_read / r.local_assignments)
+               for r in recs)
+    assert "route_rows_per_assignment" in recs[-1].to_dict()["outcome"]
 
 
 def test_a_prefix_cache_hit_reads_the_latent_pool(engine, params):

@@ -12,7 +12,15 @@ commit: ``("kimi-k2-instruct", "chunk_kernel")`` and ``("kimi-k2-instruct",
 "rows_kernel")`` — under ``use_kernel`` a latent chunk program's prefix is
 ONE call of ``chunk_attention_prefix`` where it was a ``scan`` of gathers,
 expansions and per-block updates. The other nine, ``chunk_jnp`` among
-them, are the hashes they were: no other program moved."""
+them, are the hashes they were: no other program moved. RE-PINNED ON
+PURPOSE at PR 50, all SIX ``kimi-k2-instruct`` hashes from that PR's one
+commit: every one of them holds an expert share, and under a share
+``parallel/moe.py`` now sizes the block height, the dispatch gather, the
+grouped product's grid and the way back by what fell on the HELD experts
+(``share_walk``, ``_walk_share``). The five ``trinity-mini`` hashes —
+all experts held, no share — are the hashes they were, as are all of
+``tests/test_layer_kinds_moe.py`` ``PINS``: the proof that no program
+without a share moved."""
 
 import hashlib
 import json
@@ -30,17 +38,17 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PAGE, B = 128, 2
 PINS = {
     ("kimi-k2-instruct", "chunk_jnp"):
-        "1b1a43f1d9237be71fb3cf2e0062a53a41ac3a28110efb07ec2b6495d1baee1a",
+        "5322b87075ab92b57cab6e6326c653cd82c0919d5ccfb61d56262f9fb57b7c8c",
     ("kimi-k2-instruct", "chunk_kernel"):
-        "24a2c31e3fd46159abd6f1e84b17a200454e14acd2f1ef1e10699c946e44da1a",
+        "1b79a53699deca80275f9ee80b17ef0d74b0ff572ad3f8c60a9ba40f432bfb88",
     ("kimi-k2-instruct", "rows_kernel"):
-        "40edeb650b035b5e4626c2121615f9f3dfea95167f013b2cbf325edf858ba0c3",
+        "3c63b8cc65a9f70cfbde15188421b84e4dd5f8dd1cab9214820a133cd2a0c234",
     ("kimi-k2-instruct", "step_kernel"):
-        "49ab8437bc55dd5c1315c4292b6f69d616a1c16f17ceb1ed62bc1bea74956046",
+        "57fe494ddaec6092d390f0c95715563f8681eb2cfaa978b3d1ffd9bd715da465",
     ("kimi-k2-instruct", "verify"):
-        "14cd34b19dce3a4db24903c074b59e0231a11a4b81e4bf9aeb9bff08648d7143",
+        "e8ba6d4396dc48f9d229de824fed37fce0e4bcde73007c0cd02b152a8438d841",
     ("kimi-k2-instruct", "apply"):
-        "32657c29cc173ba7701f6699972e15f1e387ae5131f07b4c0dc94bb771681ad5",
+        "2887f69d8f3addeeeb698300b231cf3d0f7629a689fb2d69b86651fea08ced4d",
     ("trinity-mini", "chunk_jnp"):
         "c07f576f00f5d1b03658fbd62f6f1bfd4f9304474f82c10f64847a90b7f79118",
     ("trinity-mini", "rows_jnp"):

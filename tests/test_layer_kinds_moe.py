@@ -220,7 +220,10 @@ def test_engine_chunked_path_follows_the_reference(params, ids, ref,
             pass
         assert s.finish_reason == "length" and len(s.token_ids) == n_new
         stats = eng.stats
-        recs = [r for r in eng.rounds.records() if r.decode_slots]
+        # this engine's rounds: the recorder is the process's, and under
+        # one worker it still holds other tests' engines' records
+        recs = [r for r in eng.rounds.records()
+                if r.engine_tag == eng.engine_tag and r.decode_slots]
     finally:
         eng.stop()
     seq = np.asarray(prompt + s.token_ids)[None]
@@ -232,11 +235,16 @@ def test_engine_chunked_path_follows_the_reference(params, ids, ref,
         assert gap < 1e-3, (i, tok, gap)
     # the counters: one row reaches 3 experts a layer; the window
     # layers (3 of 4) skip the page behind the window
-    assert stats["experts_touched_rounds"] >= 1
-    assert stats["experts_touched_sum"] / stats["experts_touched_rounds"] \
-        == pytest.approx(3.0)
-    assert recs and all(r.experts_touched == pytest.approx(3.0)
-                        for r in recs)
+    # ... in every round that emitted a token. Under a loaded host the
+    # loop dispatches a surplus round after the last token (ROADMAP S15):
+    # it decodes no row, reports 0 and adds 0 to the sum.
+    rounds = stats["experts_touched_sum"] / 3.0
+    assert rounds == pytest.approx(round(rounds))
+    assert 1 <= round(rounds) <= stats["experts_touched_rounds"]
+    emitted = [r for r in recs if r.tokens_emitted]
+    assert emitted and all(r.experts_touched == pytest.approx(3.0)
+                           for r in emitted), [
+        (r.round_id, r.tokens_emitted, r.experts_touched) for r in recs]
     assert stats["kv_pages_skipped"] > 0
     assert all(r.kv_pages_skipped > 0 for r in recs)
     assert "kv_pages_skipped" in recs[0].to_dict()["outcome"]
