@@ -992,7 +992,8 @@ class Engine:
             os.environ.get("ROUND_SLOW_MS", "0") or 0)
         # Harvest pipeline: the scheduler enqueues each dispatched
         # program's output (first-token scalars, decode-round token
-        # blocks) onto ``_harvest_q`` in dispatch order; the harvest
+        # blocks, a non-final chunk's marker scalar) with its
+        # ProgramRun onto ``_harvest_q`` in dispatch order; the harvest
         # worker blocks on the host copies there, OFF the scheduling
         # path, and posts finish decisions back on ``_completed`` for
         # the scheduler to retire (slot/page/device bookkeeping stays
@@ -2912,17 +2913,22 @@ class Engine:
                 kind = item[0]
                 t0 = time.monotonic()
                 if kind == "mark":
-                    # A prefill-only round's completion marker: the
-                    # scalar's readback lands when the round's last
-                    # chunk has executed on the device — the execution
-                    # half of its RoundRecord completes here.
-                    _, rec, marker = item
-                    np.asarray(marker)  # blocks off-thread
-                    wait = time.monotonic() - t0
+                    # A non-final chunk program's completion marker: a
+                    # scalar OUTPUT of the program (never part of the
+                    # donated state), so its readback lands when THAT
+                    # program has executed on the device — its
+                    # ProgramRun, one part of its RoundRecord, completes
+                    # here.
+                    _, rec, run, marker = item
+                    with phase("engine_harvest_wait", record=False,
+                               round_id=-1 if rec is None else rec.round_id,
+                               program="" if run is None else run.name) as ph:
+                        np.asarray(marker)  # blocks off-thread
                     if self._gen != gen:
                         return
-                    self.rounds.complete_part(rec,
-                                              harvest_wait_ms=wait * 1e3)
+                    self.rounds.complete_part(
+                        rec, harvest_wait_ms=ph.seconds * 1e3,
+                        program=run, t_done=ph.t1)
                     self._wake.set()
                     continue
                 if kind == "offload":
@@ -2952,11 +2958,11 @@ class Engine:
                     self._wake.set()
                     continue
                 if kind == "first":
-                    _, req, first_tok, rec = item
+                    _, req, first_tok, rec, run = item
                     rid = -1 if rec is None else rec.round_id
                     with phase("engine_first_readback", round_id=rid) as ph:
                         arr = np.asarray(first_tok)  # blocks off-thread
-                    wait = ph.seconds
+                    wait, t_read = ph.seconds, ph.t1
                     self._bump("first_readback_ms", wait * 1e3)
                     self._bump("first_readbacks")
                     st = req.stream
@@ -2978,19 +2984,22 @@ class Engine:
                                 req.stream.source_ids = [
                                     int(x) for x in arr[2:]]
                                 self._emit_token(req, int(arr[0]))
+                    # the final chunk program's own readback: its
+                    # ProgramRun is done at t_read, before the emit
                     self.rounds.first_token(rec, wait_ms=wait * 1e3,
                                             counted=emitted_first,
-                                            emit_ms=ph.seconds * 1e3)
+                                            emit_ms=ph.seconds * 1e3,
+                                            program=run, t_done=t_read)
                 else:
-                    rec = item[-1]
+                    rec, run = item[-2:]
                     rid = -1 if rec is None else rec.round_id
                     with phase("engine_harvest_wait", round_id=rid) as ph:
                         if kind == "verify":
-                            _, members, toks_dev, acc_dev, drafted, _ = item
+                            _, members, toks_dev, acc_dev, drafted = item[:5]
                             accs = np.asarray(acc_dev)  # blocks off-thread
                             round_stats = {}
                         else:
-                            _, members, toks_dev, round_stats, _ = item
+                            _, members, toks_dev, round_stats = item[:4]
                             accs = drafted = None
                         # (K, B); blocks off-thread
                         toks = np.asarray(toks_dev)
@@ -3001,7 +3010,7 @@ class Engine:
                                 for k, v in round_stats.items():
                                     self._stats[f"{k}_sum"] += v
                                     self._stats[f"{k}_rounds"] += 1
-                    wait = ph.seconds
+                    wait, t_read = ph.seconds, ph.t1
                     self._bump("harvest_wait_ms", wait * 1e3)
                     self._bump("harvest_rounds")
                     if self._gen != gen:
@@ -3048,7 +3057,8 @@ class Engine:
                         rec, tokens=sum(emitted.values()),
                         spec_accepted=accepted,
                         harvest_wait_ms=wait * 1e3,
-                        emit_ms=ph.seconds * 1e3, **round_stats)
+                        emit_ms=ph.seconds * 1e3, program=run,
+                        t_done=t_read, **round_stats)
                     with self._pipe_lock:
                         # Guarded by the generation check just above: a
                         # worker disowned during the readback must not
@@ -3339,10 +3349,11 @@ class Engine:
 
         Round telemetry: the plan opens a RoundRecord (scheduler-side
         half; ``plan_s`` is the host time the plan took), each dispatch
-        fills its execution fields, and the harvest worker completes it
-        — a prefill-only round gets a completion MARKER in the harvest
-        queue (a scalar output of the last chunk's program, so its
-        readback lands exactly when the chunk's device work finishes).
+        fills its execution fields and appends its ProgramRun, and the
+        harvest worker completes it a program at a time — a non-final
+        chunk program puts a completion MARKER in the harvest queue (a
+        scalar output of the program, so its readback lands exactly when
+        its device work finishes), a final one its first token.
         The ``engine_round`` span covers begin to seal and carries the
         record's id, so trace and /debug/rounds join."""
         if not (plan.decode_steps or plan.chunks):
@@ -3413,14 +3424,15 @@ class Engine:
                 self._bump("sched_decode_tokens", plan.decode_cost_tokens)
         prefilled = padded = programs = 0
         grants: list[tuple[str, int]] = []
-        marker = None
 
-        def ran(members, m) -> None:
+        def ran(members, run, ph) -> None:
             """Account one dispatched chunk program: ``members`` the
-            (request, tokens) it carried, ``m`` its completion marker."""
-            nonlocal prefilled, padded, programs, marker
+            (request, tokens) it carried, ``run`` its record, ``ph`` the
+            closed ``chunk_dispatch`` span around its launch."""
+            nonlocal prefilled, padded, programs
             programs += 1
-            marker = m if m is not None else marker
+            if run is not None:
+                run.t_launch1 = ph.t1
             # Prefill traffic estimate: a program streams the weights
             # once and writes its tokens' KV.
             rec.hbm_bytes += self._param_bytes
@@ -3437,11 +3449,11 @@ class Engine:
                        request_id=req.stream.request_id,
                        **self._chunk_shape(req, grant)) as ch:
                 ok = True if req.slot >= 0 else self._begin_prefill(req, rec)
-                n, m = self._advance_prefill(req, grant, rec) if ok \
+                n, run = self._advance_prefill(req, grant, rec, ch) if ok \
                     else (0, None)
                 ch.record = bool(n)
             if n:
-                ran([(req, n)], m)
+                ran([(req, n)], run, ch)
             return ok
 
         def run_rows(held: list) -> None:
@@ -3458,9 +3470,9 @@ class Engine:
                     with phase("chunk_dispatch", round_id=rid,
                                request_id=members[0].stream.request_id,
                                tokens=r * C, padded=r * C, mode="rows",
-                               rows=r):
-                        m = self._advance_prefill_rows(members)
-                    ran([(req, C) for req in members], m)
+                               rows=r) as ch:
+                        run = self._advance_prefill_rows(members, rec, ch)
+                    ran([(req, C) for req in members], run, ch)
                 self._guard_live()
 
         if plan.chunks:
@@ -3522,18 +3534,8 @@ class Engine:
                 self._bump("scan_kernel_chunks", programs)
             if decoded:
                 self._bump("sched_interleaved_rounds")
-        parts = int(decoded)
-        if prefilled and marker is not None:
-            # Completion marker: a scalar OUTPUT of the last chunk's
-            # program (never part of the donated state). The harvest
-            # worker's np.asarray on it blocks until that program —
-            # and, the device stream being FIFO, every earlier chunk
-            # of this round — has executed: the honest end-of-round
-            # signal for prefill work that otherwise produces no
-            # readback until a slot arms.
-            parts += 1
-            self._assert_harvestable(marker)
-            self._harvest_q.put(("mark", rec, marker))
+        # one harvest-side completion a launched program
+        parts = len(rec.programs)
         if parts == 0:
             self.rounds.discard(rec)
         else:
@@ -3859,11 +3861,11 @@ class Engine:
             return True
         return False
 
-    def _advance_prefill_rows(self, members: list):
+    def _advance_prefill_rows(self, members: list, rec, ph):
         """``_advance_prefill`` for several prompts at once: each
         member's next whole largest-bucket chunk, non-final, in ONE
-        program (``programs.make_extend_rows``). Returns the completion
-        marker."""
+        program (``programs.make_extend_rows``). Returns its
+        ProgramRun."""
         C = self._buckets[-1]
         faults.inject("engine.dispatch")  # chaos: slow/failed prefill
         t_chunk = time.monotonic()
@@ -3883,22 +3885,37 @@ class Engine:
             req.pf["dispatch_s"] += t_done - t_chunk
             self._chunk_span(req, t_chunk, t_done, C, C)
             req.pf_pos += C
-        return marker
+        return self._launched_chunk(
+            rec, ph, marker, "extend_rows", tokens=len(members) * C,
+            padded=len(members) * C, rows=len(members))
 
-    def _advance_prefill(self, req: _Request, grant: int,
-                         rec=None) -> tuple[int, Optional[object]]:
+    def _launched_chunk(self, rec, ph, marker, name: str, **what):
+        """A chunk program is on the device queue: open its ProgramRun
+        on the round (``ph`` the ``chunk_dispatch`` span still open
+        around the launch) and, for a non-final one, hand its completion
+        ``marker`` to the harvest worker (a final chunk's first token is
+        ``_arm_slot``'s to hand over). Returns the run."""
+        run = self.rounds.launch(rec, name, t_launch0=ph.t0, **what)
+        if marker is not None:
+            self._assert_harvestable(marker)
+            self._harvest_q.put(("mark", rec, run, marker))
+        return run
+
+    def _advance_prefill(self, req: _Request, grant: int, rec,
+                         ph) -> tuple[int, Optional[object]]:
         """Admission half 2, run once per round plan: dispatch ONE
-        prefill chunk of up to ``grant`` tokens (bucket-shape padded).
-        The final chunk arms the slot and hands the first token to the
-        harvest worker. Returns ``(tokens computed, completion
-        marker)`` — the marker is a device scalar that data-depends on
-        the dispatched program (round telemetry reads it to time the
-        round's end); ``(0, None)`` when nothing dispatched. Short cold
+        prefill chunk of up to ``grant`` tokens (bucket-shape padded)
+        inside the ``chunk_dispatch`` span ``ph``. The final chunk arms
+        the slot and hands the first token to the harvest worker; a
+        non-final one hands it a marker, a device scalar that
+        data-depends on the dispatched program (its readback times the
+        program's end). Returns ``(tokens computed, the program's
+        ProgramRun)``; ``(0, None)`` when nothing dispatched. Short cold
         prompts whose whole extent fits the grant keep the ONE-dispatch
         fused prefill+insert path — the TTFT-critical case is still a
         single program."""
         if req.rag is not None:
-            return self._dispatch_rag(req, rec)
+            return self._dispatch_rag(req, rec, ph)
         pf = req.pf
         if self._prefill_aborted(req):
             return 0, None
@@ -3920,30 +3937,34 @@ class Engine:
                 self._state, self.params, toks, jnp.int32(n),
                 jnp.int32(req.slot), jnp.asarray(pf["row"]),
                 *self._sampling_args(req), req.greedy)
-            marker = first_tok
+            marker, name, window = None, "prefill_insert", 0
         else:
+            window = pf["window"]
             args = (self._state, self.params, toks, jnp.int32(req.pf_pos),
                     jnp.int32(req.pf_pos + n), jnp.int32(req.slot))
             seed = (pf["seed"],) if chunk.seen == "seed" else ()
             if not final:
                 new_state, marker = self.programs.chunk_extend_fn(
-                    pf["window"], chunk.seen)(*args, pf["row_win"], *seed)
-                first_tok = None
+                    window, chunk.seen)(*args, pf["row_win"], *seed)
+                name = "extend"
             else:
                 new_state, first_tok = self.programs.chunk_final_fn(
-                    pf["window"], req.greedy, chunk.seen == "seed")(
+                    window, req.greedy, chunk.seen == "seed")(
                     *args, jnp.asarray(pf["row"]), pf["row_win"],
                     *self._sampling_args(req), *seed)
-                marker = first_tok
+                marker, name = None, "final"
         self._guard_live()
         self._state = new_state
         t_done = time.monotonic()
         pf["dispatch_s"] += t_done - t_chunk
         self._chunk_span(req, t_chunk, t_done, n, chunk.padded)
         req.pf_pos += n
+        run = self._launched_chunk(rec, ph, marker, name, tokens=n,
+                                   padded=chunk.padded, rows=1,
+                                   window=window)
         if final:
-            self._arm_slot(req, first_tok, rec)
-        return n, marker
+            self._arm_slot(req, first_tok, rec, run)
+        return n, run
 
     @staticmethod
     def _sampling_args(req: _Request) -> tuple:
@@ -3966,14 +3987,16 @@ class Engine:
             st.timeline.child(st.state, "req_chunk", t0, t1,
                               self._round_seq, tokens, padded)
 
-    def _arm_slot(self, req: _Request, first_tok, rec=None) -> None:
+    def _arm_slot(self, req: _Request, first_tok, rec=None,
+                  run=None) -> None:
         """Prefill complete: publish cache blocks, mark the slot armed
         for decode rounds, and hand the first-token readback to the
         harvest worker (its wait overlaps the decode rounds dispatched
         right after — FIFO order in the queue keeps it ahead of them).
         ``rec``: the round record of the ARMING round — the harvest
         worker attributes the first-token readback wait (and the first
-        token itself) to it."""
+        token itself) to it; ``run``: the ProgramRun of the program that
+        computed the token, done when that readback returns."""
         pf = req.pf
         self._register_prefix(req, pf["hashes"], pf["k_use"])
         # Cumulative host dispatch time across every chunk of this
@@ -3992,15 +4015,15 @@ class Engine:
         req.pf = None
         req.prefill_done = True
         self._assert_harvestable(first_tok)
-        self._harvest_q.put(("first", req, first_tok, rec))
+        self._harvest_q.put(("first", req, first_tok, rec, run))
 
-    def _dispatch_rag(self, req: _Request, rec=None
+    def _dispatch_rag(self, req: _Request, rec, ph
                       ) -> tuple[int, Optional[object]]:
         """Fused-RAG admission: retrieval + assembly + prefill happen in
         ONE device program, so the dispatch is atomic — the scheduler
         charges the whole assembled bucket against the round budget (a
         grant can't split an on-device assembly). Returns ``(tokens,
-        completion marker)`` like ``_advance_prefill``."""
+        ProgramRun)`` like ``_advance_prefill``."""
         pf = req.pf
         faults.inject("engine.dispatch")  # chaos: slow/failed prefill
         t0 = time.monotonic()
@@ -4018,8 +4041,11 @@ class Engine:
         t1 = time.monotonic()
         pf["dispatch_s"] += t1 - t0
         self._chunk_span(req, t0, t1, fused.spec.bucket, fused.spec.bucket)
-        self._arm_slot(req, first_tok, rec)
-        return fused.spec.bucket, first_tok
+        run = self._launched_chunk(rec, ph, None, "rag",
+                                   tokens=fused.spec.bucket,
+                                   padded=fused.spec.bucket, rows=1)
+        self._arm_slot(req, first_tok, rec, run)
+        return fused.spec.bucket, run
 
     def _dispatch_round(self, steps: int, rec=None) -> bool:
         """Dispatch one decode round of ``steps`` fused steps (the plan
@@ -4056,14 +4082,18 @@ class Engine:
         ba = self.programs.ba_for(len(members))
         with phase("loop_dispatch",
                    round_id=-1 if rec is None else rec.round_id,
-                   steps=steps, rows=len(members), ba=ba):
-            self._launch_round(members, window, steps, greedy, ba, rec)
+                   steps=steps, rows=len(members), ba=ba) as ph:
+            run = self._launch_round(members, window, steps, greedy, ba,
+                                     rec, ph)
+        if run is not None:
+            run.t_launch1 = ph.t1
         return True
 
     def _launch_round(self, members: dict, window: int, steps: int,
-                      greedy: bool, ba: int, rec) -> None:
-        """The ``loop_dispatch`` span's body: launch the round program,
-        start its async readback, and hand it to the harvest worker."""
+                      greedy: bool, ba: int, rec, ph):
+        """The ``loop_dispatch`` span's body (``ph``): launch the round
+        program, start its async readback, and hand it to the harvest
+        worker with its ProgramRun, which it returns."""
         B = self.cfg.max_slots
         key = jax.random.fold_in(self._base_key, next(self._step_counter))
         act = np.full((ba,), B, np.int32)
@@ -4126,7 +4156,12 @@ class Engine:
         # stream the moment it has the round, and whoever reads the
         # stats then must find the round that ended it
         self._bump("decode_steps", steps)
-        self._harvest_q.put(("round", members, toks, round_stats, rec))
+        run = self.rounds.launch(
+            rec, "decode_round", tokens=len(members) * steps,
+            padded=ba * steps, rows=len(members), steps=steps,
+            t_launch0=ph.t0)
+        self._harvest_q.put(("round", members, toks, round_stats, rec, run))
+        return run
 
     def _count_tail(self, ba: int, rows_per_slot: int,
                     kernel: bool) -> None:
@@ -4215,7 +4250,7 @@ class Engine:
             drafted[slot] = k
         with phase("loop_dispatch",
                    round_id=-1 if rec is None else rec.round_id,
-                   steps=1, rows=len(members), ba=ba):
+                   steps=1, rows=len(members), ba=ba) as ph:
             key = jax.random.fold_in(self._base_key, next(self._step_counter))
             t0 = time.monotonic()
             new_state, (toks, acc) = self.programs.verify_fn(
@@ -4259,7 +4294,13 @@ class Engine:
             self._assert_harvestable(toks, acc)
             self._bump("decode_steps")      # before the hand-off, as above
             self._bump("spec_verify_rounds")
-            self._harvest_q.put(("verify", members, toks, acc, drafted, rec))
+            run = self.rounds.launch(
+                rec, "verify_round", tokens=len(members), padded=ba * S,
+                rows=len(members), steps=1, t_launch0=ph.t0)
+            self._harvest_q.put(("verify", members, toks, acc, drafted, rec,
+                                 run))
+        if run is not None:
+            run.t_launch1 = ph.t1
         return True
 
     def _emit_token(self, req: _Request, token: int) -> None:

@@ -46,12 +46,22 @@ Timing semantics (what the fields mean):
   end-to-end wall, including host dispatch. This is what the drift
   gauge and the slow-round dump judge, so a host-side stall (a fault
   injection, a GC pause, a compile) is visible, not just device time.
-- ``device_ms`` — the pipelined service-time estimate: completion time
-  minus the later of (this round's dispatch end, the PREVIOUS round's
-  completion). Under dispatch-ahead the raw dispatch→harvest latency
-  double-counts queue wait; this estimator converges on the true
-  per-round device time and is what the online cost calibrator feeds
-  on.
+- ``programs`` — one :class:`ProgramRun` a device program the round
+  dispatched, in launch order: what it was and carried, when its launch
+  began and ended (scheduler thread) and when its own readback returned
+  (harvest thread). ``service_ms`` = done minus the later of (its launch
+  end, the engine's PREVIOUS program's done): on a FIFO device that is
+  never idle, the program's time on the chip. Under dispatch-ahead the
+  raw launch→readback latency double-counts queue wait; this estimator
+  does not.
+- ``device_ms`` — the sum of the round's programs' ``service_ms``: the
+  pipelined per-round device-time estimate the online cost calibrator
+  feeds on.
+- ``done_during_launch`` (on the program whose launch was the round's
+  longest) — how many earlier programs' ``t_done`` fell inside that
+  launch. A long launch with completions inside it waited for room in a
+  queue the chip was draining; one with none saw the chip, the runtime
+  or the machine stand still.
 """
 
 from __future__ import annotations
@@ -66,6 +76,10 @@ from typing import Any, Callable, Optional
 from ..utils.logging import get_logger
 
 logger = get_logger(__name__)
+
+#: ``t_done`` stamps kept an engine (the program clock): more than a
+#: device queue holds, so a launch counts every completion inside it.
+_DONE_STAMPS = 64
 
 #: Tokens-per-round ladder: one decode round emits steps x slots tokens
 #: (8..512 typical); prefill-heavy rounds grant up to a few pages.
@@ -88,9 +102,15 @@ ROUND_METRICS: dict[str, tuple[str, str]] = {
         "(includes host dispatch — the drift/dump signal)"),
     "engine_round_device_seconds": (
         "histogram",
-        "pipelined per-round device service-time estimate (completion "
-        "minus max(dispatch end, previous completion)) — what the "
-        "online cost calibrator feeds on"),
+        "pipelined per-round device service-time estimate (the sum of "
+        "its programs' service times) — what the online cost calibrator "
+        "feeds on"),
+    "engine_program_seconds": (
+        "histogram",
+        "per-program device service-time estimate, labeled by program "
+        "(decode_round, verify_round, prefill_insert, extend, "
+        "extend_rows, final, rag): its own readback minus max(its "
+        "launch end, the previous program's readback)"),
     "engine_round_tokens": (
         "histogram",
         "tokens per completed round: decode/verify tokens emitted + "
@@ -121,6 +141,8 @@ ROUND_METRICS: dict[str, tuple[str, str]] = {
 # registry lookup per metric (the obs/metrics.py stage-children
 # convention).
 _metric_cache: dict[str, object] = {}
+# ... and engine_program_seconds' children by program name
+_program_children: dict[str, object] = {}
 
 
 def _round_metric(name: str):
@@ -139,9 +161,83 @@ def _round_metric(name: str):
     else:
         buckets = (ROUND_TOKEN_BUCKETS if name == "engine_round_tokens"
                    else obs_metrics.STAGE_BUCKETS)
-        m = reg.histogram(name, help_txt, buckets=buckets)
+        labels = (("program",) if name == "engine_program_seconds"
+                  else ())
+        m = reg.histogram(name, help_txt, buckets=buckets,
+                          labelnames=labels)
     _metric_cache[name] = m
     return m
+
+
+#: The device programs a round dispatches, under the names
+#: ``engine/programs.py`` builds them: the label values of
+#: ``engine_program_seconds``.
+PROGRAM_NAMES = ("decode_round", "verify_round", "prefill_insert", "extend",
+                 "extend_rows", "final", "rag")
+
+
+class ProgramRun:
+    """One dispatched device program, on the round that dispatched it.
+
+    The scheduler thread writes what it was and the launch
+    (``RoundRecorder.launch``; ``t_launch1`` once the span around the
+    jitted call has closed), the harvest thread the completion
+    (``t_done``: the instant the program's OWN readback returned — its
+    marker scalar, its first token, its token block — before anything
+    is detokenised or emitted; ``t_prev_done``: the engine's previous
+    program's). ``time.monotonic()`` throughout, the clock of
+    ``RoundRecord.t_start``."""
+
+    __slots__ = ("name", "tokens", "padded", "rows", "steps", "window",
+                 "t_launch0", "t_launch1", "t_done", "t_prev_done",
+                 "done_during_launch")
+
+    def __init__(self, name: str, tokens: int, padded: int, rows: int,
+                 steps: int, window: int, t_launch0: float):
+        self.name = name
+        # real tokens a chunk program carried; rows x steps of a decode
+        # or verify program
+        self.tokens = tokens
+        self.padded = padded        # tokens of the shape it ran in
+        self.rows = rows
+        self.steps = steps          # 0 for a chunk program
+        self.window = window        # a chunk program's page window, else 0
+        self.t_launch0 = t_launch0
+        self.t_launch1 = t_launch0
+        self.t_done = 0.0
+        self.t_prev_done = 0.0
+        # set when the round finalises, on its longest launch only
+        self.done_during_launch: Optional[int] = None
+
+    @property
+    def launch_ms(self) -> float:
+        return (self.t_launch1 - self.t_launch0) * 1e3
+
+    @property
+    def service_ms(self) -> float:
+        """The program's time on the chip: its readback minus the later
+        of its launch end and the previous program's readback (0 until
+        it is done)."""
+        if not self.t_done:
+            return 0.0
+        return max(0.0, self.t_done - max(self.t_launch1,
+                                          self.t_prev_done)) * 1e3
+
+    def to_dict(self, t_start: float) -> dict:
+        """JSON-ready; instants in ms from the round's ``t_start``."""
+        out = {
+            "name": self.name, "tokens": self.tokens,
+            "padded": self.padded, "rows": self.rows, "steps": self.steps,
+            "window": self.window,
+            "launch_at_ms": round((self.t_launch0 - t_start) * 1e3, 3),
+            "launch_ms": round(self.launch_ms, 3),
+            "done_at_ms": (round((self.t_done - t_start) * 1e3, 3)
+                           if self.t_done else None),
+            "service_ms": round(self.service_ms, 3),
+        }
+        if self.done_during_launch is not None:
+            out["done_during_launch"] = self.done_during_launch
+        return out
 
 
 class RoundRecord:
@@ -173,7 +269,7 @@ class RoundRecord:
         "tokens_emitted", "first_tokens", "spec_accepted",
         "experts_touched", "tail_resort_pct", "local_assignments",
         "hc_row_defect", "route_groups_held_pct", "route_rows_read",
-        "t_parts",
+        "t_parts", "programs",
         # finalization
         "t_done", "device_ms", "round_ms", "bw_util", "drift_ratio", "done",
         # bookkeeping
@@ -293,10 +389,13 @@ class RoundRecord:
         # returns. ~1e-6 (``hc_eps``) where the coefficients are float32
         # and every normalisation ran; 0 on the plain residual path.
         self.hc_row_defect = 0.0
-        # The harvest thread's stamp of each completed part, in the
-        # device's FIFO order (the decode output before the chunks'
-        # marker): where a round that dispatched both divides.
+        # The harvest thread's stamp of the round's parts, in the
+        # device's FIFO order: its decode output once emitted, then its
+        # LAST chunk program's ``t_done`` (where a round that dispatched
+        # both divides).
         self.t_parts: list[float] = []
+        # One ProgramRun a dispatched device program, in launch order.
+        self.programs: list[ProgramRun] = []
         # Stamp of the LAST part — the round's completion on the
         # program's own clock (t_start + round_ms says when the record
         # was finalized, which the scheduler thread may do later).
@@ -356,10 +455,13 @@ class RoundRecord:
                 "harvest_wait_ms": round(self.harvest_wait_ms, 3),
                 "first_readback_ms": round(self.first_readback_ms, 3),
                 "emit_ms": round(self.emit_ms, 3),
+                # the sum of the programs' service_ms
                 "device_ms": round(self.device_ms, 3),
                 "round_ms": round(self.round_ms, 3),
                 "done_ms": (round((self.t_done - self.t_start) * 1e3, 3)
                             if self.t_done else None),
+                "programs": [p.to_dict(self.t_start)
+                             for p in self.programs],
             },
             "outcome": {
                 "tokens_emitted": self.tokens_emitted,
@@ -402,12 +504,15 @@ class RoundRecorder:
         # the sequence, so dashboards and tests can detect a reset as a
         # gap, never as a replayed id.
         self._ids = itertools.count()
-        # Pipelined-completion clock PER ENGINE TAG: multi-engine
-        # processes (fleet bench, capacity sweeps) share this recorder,
-        # and engine A's completion must not truncate engine B's
-        # device-time estimate — that estimate feeds B's cost
-        # calibrator.
-        self._last_complete_t: dict[str, float] = {}
+        # The program clock PER ENGINE TAG: the ``t_done`` of an
+        # engine's latest programs, newest last (a program's service
+        # starts at the previous one's; a stalled launch counts those
+        # that fell inside it). Multi-engine processes (fleet bench,
+        # capacity sweeps) share this recorder, and engine A's
+        # completion must not truncate engine B's device-time estimate
+        # — that estimate feeds B's cost calibrator. Bounded: a launch
+        # can see no more completions than the device queue held.
+        self._program_done_t: "dict[str, deque[float]]" = {}
 
     # --------------------------------------------------- scheduler side
 
@@ -442,6 +547,22 @@ class RoundRecorder:
             except ValueError:
                 pass  # already rotated out of the bounded ring
 
+    def launch(self, rec: Optional[RoundRecord], name: str, *,
+               tokens: int, padded: int, rows: int, steps: int = 0,
+               window: int = 0, t_launch0: float) -> Optional[ProgramRun]:
+        """One device program of this round is being launched (scheduler
+        thread, inside the span around its jitted call: ``t_launch0`` is
+        that span's begin, and the caller writes ``t_launch1`` when it
+        has closed). Each launched program is one of the round's parts:
+        hand its run to ``complete_part`` / ``first_token`` with its
+        readback."""
+        if rec is None:
+            return None
+        run = ProgramRun(name, int(tokens), int(padded), int(rows),
+                         int(steps), int(window), t_launch0)
+        rec.programs.append(run)
+        return run
+
     def seal(self, rec: RoundRecord, *, parts: int,
              prefill_tokens: int = 0,
              prefill_padded_tokens: int = 0,
@@ -449,9 +570,10 @@ class RoundRecorder:
              modeled_ms: float = 0.0) -> None:
         """Close the dispatch half (scheduler thread): ``parts`` is how
         many harvest-side completion signals this round will produce
-        (the decode/verify output and/or the prefill completion marker).
-        Finalizes immediately if the harvest thread already drained
-        every part (it can outrun the scheduler on short rounds)."""
+        (one a launched program: the decode/verify output, each chunk
+        program's marker or first token). Finalizes immediately if the
+        harvest thread already drained every part (it can outrun the
+        scheduler on short rounds)."""
         rec.prefill_tokens = int(prefill_tokens)
         rec.prefill_padded_tokens = int(prefill_padded_tokens)
         if grants:
@@ -478,10 +600,13 @@ class RoundRecorder:
                       local_assignments: float = 0.0,
                       hc_row_defect: float = 0.0,
                       route_groups_held_pct: float = 0.0,
-                      route_rows_read: float = 0.0) -> None:
-        """One harvested device output of this round (harvest thread).
-        The last part — once the scheduler has sealed the expected
-        count — finalizes the record."""
+                      route_rows_read: float = 0.0,
+                      program: Optional[ProgramRun] = None,
+                      t_done: float = 0.0) -> None:
+        """One harvested device output of this round (harvest thread):
+        ``program`` the run it completes, ``t_done`` the instant its
+        readback returned. The last part — once the scheduler has sealed
+        the expected count — finalizes the record."""
         if rec is None:
             return
         rec.tokens_emitted += int(tokens)
@@ -500,37 +625,66 @@ class RoundRecorder:
             rec.route_groups_held_pct = float(route_groups_held_pct)
         if route_rows_read:
             rec.route_rows_read = float(route_rows_read)
-        rec.t_parts.append(time.monotonic())
-        finalize = False
-        with self._lock:
-            rec._done_parts += 1
-            finalize = rec._sealed and rec._done_parts >= rec._parts
-        if finalize:
-            self._finalize(rec)
+        if program is None or program.steps:
+            # the decode output's part, stamped once emitted; the
+            # chunks' part is their last program's t_done (_finalize)
+            rec.t_parts.append(time.monotonic())
+        self._part_done(rec, program, t_done)
 
     def first_token(self, rec: Optional[RoundRecord], *,
                     wait_ms: float = 0.0, counted: bool = True,
-                    emit_ms: float = 0.0) -> None:
+                    emit_ms: float = 0.0,
+                    program: Optional[ProgramRun] = None,
+                    t_done: float = 0.0) -> None:
         """A first-token readback attributed to the round that armed the
-        request (harvest thread). Does NOT count toward the round's
-        completion parts — the prefill completion marker follows it in
-        FIFO order and owns the completion signal."""
+        request (harvest thread): the completion of the final chunk
+        ``program`` that computed it, one of the round's parts."""
         if rec is None:
             return
         rec.first_readback_ms += float(wait_ms)
         rec.emit_ms += float(emit_ms)
         if counted:
             rec.first_tokens += 1
+        if program is not None:
+            self._part_done(rec, program, t_done)
+
+    def _part_done(self, rec: RoundRecord, program: Optional[ProgramRun],
+                   t_done: float) -> None:
+        """Count one part; stamp its program on the engine's program
+        clock (harvest thread, the device's FIFO order)."""
+        finalize = False
+        with self._lock:
+            if program is not None:
+                clock = self._program_done_t.get(rec.engine_tag)
+                if clock is None:
+                    clock = self._program_done_t[rec.engine_tag] = deque(
+                        maxlen=_DONE_STAMPS)
+                program.t_prev_done = clock[-1] if clock else 0.0
+                program.t_done = t_done or time.monotonic()
+                clock.append(program.t_done)
+            rec._done_parts += 1
+            finalize = rec._sealed and rec._done_parts >= rec._parts
+        if finalize:
+            self._finalize(rec)
 
     def _finalize(self, rec: RoundRecord) -> None:
         now = time.monotonic()
+        runs = rec.programs
+        last_chunk = next((p for p in reversed(runs) if not p.steps), None)
+        if last_chunk is not None:
+            rec.t_parts.append(last_chunk.t_done)
         rec.t_done = rec.t_parts[-1] if rec.t_parts else now
         rec.round_ms = (now - rec.t_start) * 1e3
-        with self._lock:
-            busy_from = max(rec.t_dispatch_done,
-                            self._last_complete_t.get(rec.engine_tag, 0.0))
-            self._last_complete_t[rec.engine_tag] = now
-        rec.device_ms = max(0.0, (now - busy_from) * 1e3)
+        rec.device_ms = sum(p.service_ms for p in runs)
+        if runs:
+            # the round's longest launch: did the device go on
+            # completing programs while the host was inside it?
+            slow = max(runs, key=lambda p: p.launch_ms)
+            with self._lock:
+                slow.done_during_launch = sum(
+                    1 for t in self._program_done_t.get(rec.engine_tag, ())
+                    if slow.t_launch0 <= t <= slow.t_launch1
+                    and t < slow.t_done)
         cb = rec._cb
         rec._cb = None
         if cb is not None:
@@ -548,7 +702,7 @@ class RoundRecorder:
         across reset — pinned by the thread-safety test)."""
         with self._lock:
             self._ring.clear()
-            self._last_complete_t.clear()
+            self._program_done_t.clear()
 
     def records(self) -> list[RoundRecord]:
         with self._lock:
@@ -596,6 +750,17 @@ class RoundRecorder:
                 "spec_drafted": sum(r.spec_drafted for r in complete),
                 "spec_accepted": sum(r.spec_accepted for r in complete),
             })
+            by_name: dict[str, list] = {}
+            for r in complete:
+                for p in r.programs:
+                    by_name.setdefault(p.name, []).append(p.service_ms)
+            # a line a program name: count, p50, p90 of service_ms
+            agg["programs"] = {
+                name: {"count": len(ms),
+                       "p50_service_ms": round(ms[len(ms) // 2], 3),
+                       "p90_service_ms": round(ms[len(ms) * 9 // 10], 3)}
+                for name, ms in ((k, sorted(v))
+                                 for k, v in sorted(by_name.items()))}
         limit = max(0, int(limit))
         recent = recs[-limit:] if limit else []
         return {
@@ -615,6 +780,12 @@ def record_round_metrics(rec: RoundRecord,
     _round_metric("engine_round_seconds").observe(rec.round_ms / 1e3)
     _round_metric("engine_round_device_seconds").observe(
         rec.device_ms / 1e3)
+    for p in rec.programs:
+        child = _program_children.get(p.name)
+        if child is None:   # benign race, as _round_metric's
+            child = _program_children[p.name] = _round_metric(
+                "engine_program_seconds").labels(p.name)
+        child.observe(p.service_ms / 1e3)
     _round_metric("engine_round_tokens").observe(
         rec.tokens_emitted + rec.first_tokens + rec.prefill_tokens)
     _round_metric("engine_round_bw_util").set(rec.bw_util)

@@ -160,12 +160,14 @@ class phase:
     keyword arguments as the event's stats) and on exit hands the
     elapsed time to :func:`record_stage` under the same name — a span's
     name IS its ``engine_stage_seconds`` stage. ``seconds`` holds the
-    elapsed time after exit. ``record=False`` keeps the span and skips
+    elapsed time after exit, ``t0`` and ``t1`` the ``time.monotonic()``
+    instants it began and ended at (a program's launch and readback
+    stamps are these reads, not a second pair). ``record=False`` keeps the span and skips
     the stage record (set it inside the block too: a phase that turned
     out to do no work stays out of the histogram). Takes no lock,
     allocates nothing but itself, reads no environment."""
 
-    __slots__ = ("name", "record", "seconds", "_ann", "_t0")
+    __slots__ = ("name", "record", "seconds", "t0", "_ann")
 
     def __init__(self, name: str, record: bool = True, **args: Any):
         self.name = name
@@ -175,11 +177,16 @@ class phase:
 
     def __enter__(self) -> "phase":
         self._ann.__enter__()
-        self._t0 = time.monotonic()
+        self.t0 = time.monotonic()
         return self
 
+    @property
+    def t1(self) -> float:
+        """The instant the phase ended (after exit)."""
+        return self.t0 + self.seconds
+
     def __exit__(self, *exc) -> None:
-        self.seconds = time.monotonic() - self._t0
+        self.seconds = time.monotonic() - self.t0
         self._ann.__exit__(*exc)
         if self.record:
             record_stage(self.name, self.seconds)

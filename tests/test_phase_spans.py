@@ -215,6 +215,35 @@ def test_round_ids_join_the_round_records(traced):
     assert any(r.plan_ms > 0 for r in done)
 
 
+def test_every_launch_and_readback_span_has_its_program_run(traced):
+    """One ProgramRun a dispatched program: its launch IS the
+    ``loop_dispatch`` / ``chunk_dispatch`` span that launched something,
+    and a non-final chunk's readback an ``engine_harvest_wait`` span of
+    its own (argument ``program``), a final's the first-token span."""
+    recs = [r for r in traced["engine"].rounds.records() if r.done]
+    runs = [p for r in recs for p in r.programs]
+    assert runs and all(p.t_done for p in runs)
+    spans = traced["spans"]
+    n_chunk = len(spans["chunk_dispatch"])
+    assert len(spans["loop_dispatch"]) + n_chunk == len(runs)
+    waits = spans["engine_harvest_wait"]
+    marks = [s for _, _, s in waits if "program" in s]
+    extends = [p for p in runs if p.name in ("extend", "extend_rows")]
+    assert marks and len(marks) == len(extends)
+    assert {s["program"] for s in marks} == {p.name for p in extends}
+    assert len(waits) - len(marks) == sum(p.steps > 0 for p in runs)
+    assert len(spans["engine_first_readback"]) == sum(
+        p.name in ("final", "prefill_insert") for p in runs)
+    # the launch stamps are the spans' own clock reads: the runs'
+    # launches last what the dispatch spans lasted (the annotation is
+    # entered a moment before the clock is read and left a moment after)
+    spans_ms = sum(d for name in ("loop_dispatch", "chunk_dispatch")
+                   for _, d, _ in spans[name]) * 1e-6
+    mine_ms = sum(p.launch_ms for p in runs)
+    assert mine_ms <= spans_ms + 0.01
+    assert spans_ms - mine_ms < max(2.0, 0.1 * spans_ms), (spans_ms, mine_ms)
+
+
 def test_children_lie_inside_their_round_span(traced):
     rounds = {s["round_id"]: (t, t + d)
               for t, d, s in traced["spans"]["engine_round"]}

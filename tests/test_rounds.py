@@ -23,7 +23,8 @@ from generativeaiexamples_tpu.engine.scheduler import (
     online_calib_enabled)
 from generativeaiexamples_tpu.models import llama
 from generativeaiexamples_tpu.models.configs import LlamaConfig
-from generativeaiexamples_tpu.obs.rounds import (ROUND_METRICS,
+from generativeaiexamples_tpu.obs.rounds import (PROGRAM_NAMES,
+                                                 ROUND_METRICS,
                                                  RoundRecorder,
                                                  debug_rounds_response)
 from generativeaiexamples_tpu.utils import faults
@@ -122,6 +123,14 @@ def test_snapshot_aggregates_and_limit():
     json.dumps(snap)   # JSON-clean
 
 
+def _launch(rec, r, name, t0, t1, **what):
+    """One program launched over [t0, t1] (scheduler thread's half)."""
+    what = dict(dict(tokens=4, padded=4, rows=1), **what)
+    run = rec.launch(r, name, t_launch0=t0, **what)
+    run.t_launch1 = t1
+    return run
+
+
 def test_shared_recorder_isolates_engines():
     """Multi-engine processes share the global recorder: one engine's
     completion must not truncate another's device-time estimate (the
@@ -129,14 +138,17 @@ def test_shared_recorder_isolates_engines():
     rec = RoundRecorder(cap=32)
     a = rec.begin(engine_tag="eA", decode_steps=4)
     b = rec.begin(engine_tag="eB", decode_steps=4)
+    now = time.monotonic()
+    pa = _launch(rec, a, "decode_round", now, now, steps=4)
+    pb = _launch(rec, b, "decode_round", now, now, steps=4)
     rec.seal(a, parts=1, modeled_ms=1.0)
     rec.seal(b, parts=1, modeled_ms=1.0)
     t_sealed = max(a.t_dispatch_done, b.t_dispatch_done)
     time.sleep(0.05)
-    rec.complete_part(a, tokens=4)        # A completes first...
+    rec.complete_part(a, tokens=4, program=pa)  # A completes first...
     time.sleep(0.05)
-    rec.complete_part(b, tokens=4)        # ...B's clock starts at ITS
-    # dispatch end, not at A's completion: both device_ms cover their
+    rec.complete_part(b, tokens=4, program=pb)  # ...B's clock starts at
+    # ITS launch end, not at A's completion: both device_ms cover their
     # own full ~0.05-0.1 s window.
     assert b.device_ms >= 90.0
     assert a.device_ms >= 45.0
@@ -145,6 +157,103 @@ def test_shared_recorder_isolates_engines():
     assert [r["engine"] for r in snap_a["rounds"]] == ["eA"]
     assert snap_a["aggregates"]["rounds_completed"] == 1
     assert rec.snapshot(limit=10)["aggregates"]["rounds_completed"] == 2
+
+
+# ------------------------------------------- a record a device program
+
+
+@pytest.mark.parametrize("chunks", [1, 2, 3, 4])
+def test_a_rounds_programs_sum_to_its_device_ms(chunks):
+    """A round of one decode program and 1-4 chunk programs: each is
+    stamped as its OWN readback returns, in the device's FIFO order; a
+    program's service runs from the later of its launch end and the
+    previous program's readback; the services sum to ``device_ms``."""
+    rec = RoundRecorder(cap=8)
+    T = time.monotonic()
+    r = rec.begin(engine_tag="t", decode_steps=4)
+    dec = _launch(rec, r, "decode_round", T, T + 0.001, tokens=8, padded=16,
+                  rows=2, steps=4)
+    names = ["extend_rows"] * (chunks == 4) + ["extend"] * (chunks - 1)
+    names = names[:chunks - 1] + ["final"]
+    runs = []
+    for i, name in enumerate(names):
+        rows = 4 if name == "extend_rows" else 1
+        runs.append(_launch(rec, r, name, T + 0.002 + i * 0.001,
+                            T + 0.0025 + i * 0.001, tokens=16 * rows,
+                            padded=16 * rows, rows=rows, window=8))
+    rec.seal(r, parts=1 + chunks, prefill_tokens=16 * chunks)
+    assert [p.name for p in r.programs] == ["decode_round"] + names
+    rec.complete_part(r, tokens=8, program=dec, t_done=T + 0.020)
+    for i, run in enumerate(runs[:-1]):
+        assert not r.done
+        rec.complete_part(r, program=run, t_done=T + 0.050 + i * 0.030)
+    assert not r.done
+    # the final chunk is stamped by its first token's readback
+    t_first = T + 0.050 + (chunks - 1) * 0.030
+    rec.first_token(r, wait_ms=1.0, program=runs[-1], t_done=t_first)
+    assert r.done and r.first_tokens == 1
+    stamps = [p.t_done for p in r.programs]
+    assert stamps == sorted(stamps) and stamps[-1] == t_first
+    for prev, p in zip(r.programs, r.programs[1:]):
+        assert p.t_prev_done == prev.t_done
+    assert dec.service_ms == pytest.approx(19.0)        # from its launch end
+    assert [p.service_ms for p in runs] == pytest.approx([30.0] * chunks)
+    assert r.device_ms == pytest.approx(sum(p.service_ms
+                                            for p in r.programs))
+    # request_life's stamps: the decode part once emitted, then the LAST
+    # chunk program's own readback
+    assert len(r.t_parts) == 2 and r.t_parts[1] == t_first == r.t_done
+    if chunks == 4:
+        assert (runs[0].name, runs[0].rows, runs[0].tokens) == (
+            "extend_rows", 4, 64)
+    d = r.to_dict()["execution"]
+    assert [p["name"] for p in d["programs"]] == ["decode_round"] + names
+    assert d["device_ms"] == pytest.approx(
+        sum(p["service_ms"] for p in d["programs"]), abs=0.01)
+    agg = rec.snapshot()["aggregates"]["programs"]
+    assert agg["decode_round"] == {"count": 1, "p50_service_ms": 19.0,
+                                   "p90_service_ms": 19.0}
+    assert agg["final"]["count"] == 1
+    json.dumps(rec.snapshot())
+
+
+def test_a_stalled_launch_counts_what_the_device_completed_meanwhile():
+    """``done_during_launch`` on a round's longest launch: completions
+    inside it mean the host waited for room in a queue the chip was
+    draining; none, that the chip (or the machine) stood still."""
+    rec = RoundRecorder(cap=8)
+    T = time.monotonic()
+    a = rec.begin(engine_tag="t")
+    a1 = _launch(rec, a, "extend", T, T + 0.001)
+    a2 = _launch(rec, a, "extend", T + 0.001, T + 0.002)
+    rec.seal(a, parts=2)
+    # the host blocks 20 ms launching b's program...
+    b = rec.begin(engine_tag="t")
+    b1 = _launch(rec, b, "extend", T + 0.005, T + 0.025)
+    b2 = _launch(rec, b, "final", T + 0.025, T + 0.026)
+    rec.seal(b, parts=2)
+    # ... while the device completes both of a's
+    rec.complete_part(a, program=a1, t_done=T + 0.010)
+    rec.complete_part(a, program=a2, t_done=T + 0.020)
+    rec.complete_part(b, program=b1, t_done=T + 0.030)
+    rec.first_token(b, program=b2, t_done=T + 0.040)
+    assert a.done and b.done
+    assert b1.done_during_launch == 2 and b2.done_during_launch is None
+    assert b1.launch_ms == pytest.approx(20.0)
+    # a's own longest launch saw nothing complete: nothing was queued
+    assert [a1.done_during_launch, a2.done_during_launch].count(0) == 1
+    # a launch that blocks while the device completes NOTHING
+    c = rec.begin(engine_tag="t")
+    c1 = _launch(rec, c, "decode_round", T + 0.050, T + 0.090, steps=4)
+    rec.seal(c, parts=1)
+    rec.complete_part(c, tokens=4, program=c1, t_done=T + 0.100)
+    assert c1.done_during_launch == 0
+    assert c1.service_ms == pytest.approx(10.0)
+    stalled = b.to_dict()["execution"]["programs"][0]
+    assert stalled["done_during_launch"] == 2
+    assert stalled["launch_ms"] == pytest.approx(20.0)
+    assert "done_during_launch" not in \
+        b.to_dict()["execution"]["programs"][1]
 
 
 def test_thread_safety_no_torn_records():
@@ -165,6 +274,8 @@ def test_thread_safety_no_torn_records():
             for i in range(N):
                 r = rec.begin(engine_tag="t", decode_steps=4)
                 r.decode_slots = 1
+                now = time.monotonic()
+                _launch(rec, r, "decode_round", now, now, steps=4)
                 rec.seal(r, parts=1, prefill_tokens=(i % 3) * PAGE,
                          modeled_ms=1.0)
                 pipe.put(r)
@@ -182,7 +293,8 @@ def test_thread_safety_no_torn_records():
                 if r is None:
                     return
                 rec.complete_part(r, tokens=r.round_id % 7,
-                                  harvest_wait_ms=0.01)
+                                  harvest_wait_ms=0.01,
+                                  program=r.programs[0])
         except Exception as exc:  # noqa: BLE001
             errors.append(exc)
 
@@ -197,6 +309,9 @@ def test_thread_safety_no_torn_records():
                         # deterministic emission
                         assert (d["outcome"]["tokens_emitted"]
                                 == d["round_id"] % 7), d
+                        # ... and its program is stamped
+                        (p,) = d["execution"]["programs"]
+                        assert p["done_at_ms"] is not None, d
                 seen_ids.extend(r.round_id for r in rec.records())
         except Exception as exc:  # noqa: BLE001
             errors.append(exc)
@@ -485,7 +600,14 @@ def test_round_metrics_surface_declared_and_fed():
         "engine_rounds_total", "engine_round_seconds",
         "engine_round_device_seconds", "engine_round_tokens",
         "engine_round_bw_util", "engine_round_hbm_bytes_total",
-        "sched_cost_drift_ratio", "engine_round_slow_dumps_total"}
+        "sched_cost_drift_ratio", "engine_round_slow_dumps_total",
+        "engine_program_seconds"}
+    # one series a program name, fed a completed program
+    text = obs_metrics.REGISTRY.render_prometheus()
+    for name in ("prefill_insert", "decode_round"):
+        assert ('engine_program_seconds_count{program="%s"}' % name) \
+            in text
+    assert not obs_metrics.lint_prometheus(text)
 
 
 def test_round_spans_emitted_when_tracing_on(monkeypatch):
@@ -762,3 +884,138 @@ def test_a_long_answer_keeps_every_boundary_while_the_ring_wraps():
         s.first_token_time - s.submit_time, abs=1e-9)
     assert durs["engine_admit_dispatch"] == pytest.approx(
         sum(sp.t1 - sp.t0 for sp in tl.spans if sp.name == "req_chunk"))
+
+
+# ---------------------------------- a record a dispatched device program
+
+
+def _census(eng):
+    """Every ProgramRun of the engine's records, after its rounds are
+    done; asserts each is whole."""
+    _wait_rounds_done(eng)
+    recs = eng.rounds.records()
+    assert recs and all(r.done for r in recs)
+    runs = [p for r in recs for p in r.programs]
+    for r in recs:
+        assert r.device_ms == pytest.approx(
+            sum(p.service_ms for p in r.programs))
+        assert sum(p.done_during_launch is not None
+                   for p in r.programs) == 1
+    for p in runs:
+        assert p.name in PROGRAM_NAMES
+        assert p.t_launch0 <= p.t_launch1 and p.t_done > p.t_launch0
+        assert 0 < p.tokens <= p.padded and p.rows >= 1
+        assert (p.steps > 0) == (p.name in ("decode_round", "verify_round"))
+    # the harvest thread stamped them in the order they were launched
+    stamps = [p.t_done for p in runs]
+    assert stamps == sorted(stamps)
+    for prev, p in zip(runs, runs[1:]):
+        assert p.t_prev_done == prev.t_done
+    return runs
+
+
+def test_every_dispatched_program_has_one_completed_run():
+    """One slot-limited engine, prompts of one chunk and of several, one
+    cancelled between its chunks and one past its deadline: every
+    dispatched program — ``sched_chunk_programs`` plus the harvested
+    rounds — has exactly one completed ProgramRun."""
+    eng = _engine(max_slots=2, prefill_buckets=(16,), max_prefill_bucket=16,
+                  sched_round_budget_tokens=32, prefix_cache=False)
+    with eng:
+        warm = eng.submit([3] * 8, SamplingParams(max_tokens=2, **GREEDY))
+        warm.text()
+        faults.set_plan("engine.dispatch=delay:0.03")
+        try:
+            whole = eng.submit([5] * 40, SamplingParams(max_tokens=8,
+                                                        **GREEDY))
+            gone = eng.submit([6] * 64, SamplingParams(max_tokens=8,
+                                                       **GREEDY))
+            t_end = time.monotonic() + 30
+            while not any(sp.name == "req_chunk"
+                          for sp in gone.timeline.spans):
+                assert time.monotonic() < t_end
+                time.sleep(0.002)
+            gone.cancel()            # between its chunks
+            late = eng.submit([7] * 64, SamplingParams(max_tokens=8,
+                                                       **GREEDY),
+                              deadline_t=time.monotonic() + 0.12)
+            short = eng.submit([9] * 8, SamplingParams(max_tokens=8,
+                                                       **GREEDY))
+            for s in (whole, gone, late, short):
+                s.text()
+        finally:
+            faults.clear()
+        runs = _census(eng)
+        stats = eng.stats
+    assert gone.finish_reason == "cancelled"
+    assert late.finish_reason in ("deadline", "deadline_queue")
+    assert whole.finish_reason == short.finish_reason == "length"
+    chunks = [p for p in runs if not p.steps]
+    assert len(chunks) == stats["sched_chunk_programs"]
+    assert len(runs) - len(chunks) == stats["harvest_rounds"]
+    assert sum(p.tokens for p in chunks) == stats["sched_prefill_tokens"]
+    assert sum(p.padded for p in chunks) \
+        == stats["sched_prefill_padded_tokens"]
+    assert sum(p.steps for p in runs) == stats["decode_steps"]
+    names = {p.name for p in runs}
+    assert {"prefill_insert", "extend", "final", "decode_round"} <= names
+    assert all(p.window > 0 for p in chunks
+               if p.name in ("extend", "final"))
+    # a cancelled prompt's chunks ran and are recorded; it has no final
+    # (warm, whole and short have; late only if it beat its deadline)
+    assert 3 <= sum(p.name in ("final", "prefill_insert")
+                    for p in chunks) <= 4
+
+
+def test_the_program_of_several_prompts_is_one_run_of_four_rows():
+    """Four long prompts on an idle engine: their whole-bucket chunks go
+    out as ``extend_rows`` programs of four rows, one ProgramRun each."""
+    eng = _engine(max_slots=4, prefill_buckets=(16,), max_prefill_bucket=16,
+                  sched_round_budget_tokens=64, prefix_cache=False)
+    with eng:
+        streams = [eng.submit([4 + i] * 40, SamplingParams(max_tokens=4,
+                                                           **GREEDY))
+                   for i in range(4)]
+        for s in streams:
+            s.text()
+        runs = _census(eng)
+        stats = eng.stats
+    rows = [p for p in runs if p.name == "extend_rows"]
+    assert rows and all((p.rows, p.tokens, p.padded, p.window)
+                        == (4, 64, 64, 0) for p in rows)
+    assert len([p for p in runs if not p.steps]) \
+        == stats["sched_chunk_programs"]
+
+
+def test_a_stalled_launch_is_named_in_the_slow_round_event(caplog):
+    """An UNTRACED run whose launch blocks (a fault at the chunk
+    programs' ``engine.dispatch`` site) logs ONE ``slow_round`` event
+    for that round; its ``programs`` name the blocked program, how long
+    its launch took and what the device completed meanwhile (nothing:
+    the engine was idle, so the stall was not the queue's)."""
+    eng = _engine()
+    with eng:
+        eng.submit([3] * 8, SamplingParams(max_tokens=2, **GREEDY)).text()
+        _wait_rounds_done(eng)
+        before = {r.round_id for r in eng.rounds.records()}
+        faults.set_plan("engine.dispatch=delay:0.4*1")
+        try:
+            with caplog.at_level(logging.WARNING):
+                eng.submit([6] * 8, SamplingParams(max_tokens=2,
+                                                   **GREEDY)).text()
+                _wait_rounds_done(eng)
+        finally:
+            faults.clear()
+        stalled = min((r for r in eng.rounds.records()
+                       if r.round_id not in before),
+                      key=lambda r: r.round_id)
+    events = [json.loads(r.getMessage().split(" ", 1)[1])
+              for r in caplog.records if "slow_round" in r.getMessage()]
+    mine = [e for e in events
+            if e["round"]["round_id"] == stalled.round_id]
+    assert len(mine) == 1
+    (prog,) = mine[0]["round"]["execution"]["programs"]
+    assert prog["name"] == "prefill_insert" and prog["tokens"] == 8
+    assert prog["launch_ms"] >= 400.0
+    assert prog["done_during_launch"] == 0
+    assert prog["service_ms"] < prog["launch_ms"]
