@@ -424,21 +424,27 @@ class ProgramSpec:
                 f"{model_cfg.qk_rope_head_dim}: the chunk kernel takes "
                 f"lane-width pages and keys, whole sublane tiles of values"))
         scan_kernel = False
-        # (a decay a channel has no scan kernel to arm or fall short of:
-        # its chunked form is XLA's, ops/gated_delta.py ``kda_chunked``)
-        if model_cfg.recurrent and model_cfg.linear_decay == "head":
-            scan = (page_size, model_cfg.linear_num_key_heads,
-                    model_cfg.linear_num_value_heads,
-                    model_cfg.linear_key_head_dim,
-                    model_cfg.linear_value_head_dim)
-            scan_kernel = gated_delta.scan_kernel_armed(*scan)
+        # either decay's chunked scan has its kernel (ops/gated_delta.py
+        # ``gated_delta_chunked_kernel``, ``kda_chunked_kernel``): which
+        # is the configuration's ``linear_decay``, and each reads its
+        # path off the shapes
+        if model_cfg.recurrent:
+            channel = model_cfg.linear_decay == "channel"
+            heads = (model_cfg.linear_num_key_heads,
+                     model_cfg.linear_num_value_heads)
+            widths = (model_cfg.linear_key_head_dim,
+                      model_cfg.linear_value_head_dim)
+            scan_kernel = gated_delta.kda_scan_kernel_armed(
+                page_size, heads[1], *widths) if channel \
+                else gated_delta.scan_kernel_armed(page_size, *heads, *widths)
             if not scan_kernel and jax.default_backend() == "tpu":
                 downgrades.append((
                     "scan_kernel", "xla_chunked",
-                    f"page {scan[0]}, key / value heads {scan[1]} / "
-                    f"{scan[2]} of {scan[3]} / {scan[4]}: the scan kernel "
-                    f"takes whole 64-token blocks and 128-lane heads, two "
-                    f"value heads a key head, in whole groups"))
+                    f"page {page_size}, key / value heads {heads[0]} / "
+                    f"{heads[1]} of {widths[0]} / {widths[1]}: the scan "
+                    f"kernel takes whole 64-token blocks and 128-lane heads, "
+                    + ("in whole groups of eight" if channel else
+                       "two value heads a key head, in whole groups")))
         tail = resolve_tail(params, model_cfg, mesh)
         if tail.downgrade:
             downgrades.append(tail.downgrade)

@@ -1810,7 +1810,11 @@ def _kda_mixer(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
     write strength ``sigmoid(x W_b)`` a head, the output normed a head
     and gated by ``sigmoid(x W_g)``. S == 1 is the decode step
     (``gated_delta_step`` with the vector decay, or the cache object's
-    kernel over its own leaf), anything longer ``gd.kda_chunked``."""
+    kernel over its own leaf), anything longer the chunked scan: ONE
+    Pallas kernel (``gd.kda_chunked_kernel``) where
+    ``gd.kda_scan_kernel_armed`` says so of the shapes (a TPU, whole
+    64-token blocks, 128-lane heads in whole groups), else the XLA form
+    ``gd.kda_chunked``."""
     B, S, _ = x.shape
     H, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
                  cfg.linear_value_head_dim)
@@ -1827,6 +1831,8 @@ def _kda_mixer(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
     with jax.named_scope("kda_conv"):
         u, tail = gd.causal_conv(u, tail, lp["kda_conv"], n_valid)
     step = S == 1
+    # the scan as the kernel, where it takes the shapes here
+    scan_kernel = not step and gd.kda_scan_kernel_armed(S, H, dk, dv)
     with jax.named_scope("kda_step" if step else "kda_scan"):
         q = u[..., :H * dk].reshape(B, S, H, dk)
         k = u[..., H * dk:2 * H * dk].reshape(B, S, H, dk)
@@ -1836,8 +1842,17 @@ def _kda_mixer(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
         rate = jnp.exp(lp["kda_A_log"].astype(f32))[:, None]    # (H, 1)
         g = cfg.linear_decay_floor * jax.nn.sigmoid(
             rate * (a + lp["kda_dt_bias"].astype(f32)).reshape(B, S, H, dk))
+        def scan(q, k, v, g, beta, state):
+            if not scan_kernel:
+                return gd.kda_chunked(q, k, v, g, beta, state)
+            # v read where the convolution left it, no slice in between
+            o, new = gd.kda_chunked_kernel(
+                q.reshape(B, S, H * dk), k.reshape(B, S, H * dk), u,
+                g.reshape(B, S, H * dk), beta, state, v_at=2 * H * dk)
+            return o.reshape(B, S, H, dv), new
+
         o, new = _recurrence(q, k, v, g, beta, state, n_valid, step_kernel,
-                             lambda *a: gd.kda_chunked(*a))
+                             scan)
     with jax.named_scope("kda_proj"):
         mixed = _gated_head_norm(o, z, lp["kda_norm"], lp["kda_wout"],
                                  jax.nn.sigmoid, cfg.rms_norm_eps, x.dtype)

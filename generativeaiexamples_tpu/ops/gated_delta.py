@@ -15,7 +15,10 @@ H) and a state (B, H, dk, dv), computed in float32 and handed back in
 the dtype it came in (the cache's is float32, always: a test hands in
 bf16 to show what that would cost). The recurrence token by token is
 what the chunked form is held to, the chunked form what its kernel is
-held to, each at 2e-5 of the largest value (tests/test_gated_delta.py):
+held to, each at 2e-5 of the largest value (tests/test_gated_delta.py).
+``g`` (B, T, H, dk), a decay a CHANNEL of a head's keys (KDA), has the
+same four: step and recurrence are one code for both, the chunked form
+:func:`kda_chunked` and its kernel :func:`kda_chunked_kernel` twins:
 
 - :func:`gated_delta_step`: one token a row, the decode step. The work
   is the state read once and written once (2 x 64 KiB a head at 128 x
@@ -25,8 +28,7 @@ held to, each at 2e-5 of the largest value (tests/test_gated_delta.py):
   that (:func:`gated_delta_step_kernel`; 4.4 ms a step of plain XLA
   fusions became the kernel at PR 46).
 - :func:`gated_delta_recurrent`: ``gated_delta_step`` scanned over the
-  tokens. T dependent steps: what the chunked form is held to
-  (tests/test_gated_delta.py), never a served path.
+  tokens. T dependent steps: the oracle, never a served path.
 - :func:`gated_delta_chunked`: blocks of ``block`` tokens. With ``G`` the
   running sum of ``g`` inside a block and ``A = tril(beta_i exp(G_i -
   G_j) k_i.k_j, -1)``, the block's corrections solve ``(I + A) D =
@@ -35,18 +37,16 @@ held to, each at 2e-5 of the largest value (tests/test_gated_delta.py):
   (``_unit_lower_inverse``). Everything that does not involve
   ``S0`` is computed for all blocks at once; a short scan over the
   blocks carries the state. A token with ``g = 0`` and ``beta = 0``
-  leaves the state as it found it: that is how the caller pads (a ragged
-  last block here, the tokens past a chunk's valid length there). A
+  leaves the state as it found it: that is how the caller pads. A
   dozen float32 XLA stages whose operands and results cross HBM: the
   kernel's oracle, the CPU's path and the ragged lengths'.
-- :func:`gated_delta_chunked_kernel`: the chunked form as ONE Pallas
-  kernel, on a TPU wherever the shapes allow
-  (:func:`scan_kernel_armed`): the blocks of a (row, group of heads) in
-  order, the group's states in VMEM, a block's decays, ``k k^T``,
-  inverse, corrections and state update never leaving the chip, the
-  operands read as the mixer has them — (B, T, heads x width), q and k
-  by KEY head. The same arithmetic: float32 operands at HIGHEST
-  precision, the inverse by substitution and merges.
+- :func:`gated_delta_chunked_kernel` (PR 47; the vector decay's since
+  PR 52): the chunked form as ONE Pallas kernel, on a TPU wherever the
+  shapes allow (``scan_kernel_armed``, ``kda_scan_kernel_armed``): the
+  blocks of a (row, group of heads) in order, the group's states in
+  VMEM, a block's decays, ``k k^T``, inverse, corrections and state
+  update never leaving the chip, the operands read as the mixer has
+  them. Float32 at HIGHEST precision, the inverse as the XLA form's.
 
 :func:`causal_conv` is the depthwise causal convolution in front of it,
 whose cache is the last ``K - 1`` inputs of a sequence.
@@ -660,4 +660,284 @@ def _scan_kernel(q, k, v, g, beta, state, *, v_at: int, interpret: bool):
         name="gated_delta_scan",
     )(q.astype(f32), k.astype(f32), v.astype(f32), g.astype(f32),
       beta.astype(f32), state)
+    return o, state
+
+
+_KDA_HEADS = 8      # heads a grid step of the vector decay's scan holds
+
+
+def kda_scan_kernel_supported(tokens: int, heads: int, dk: int,
+                              dv: int) -> bool:
+    """Whether :func:`kda_chunked_kernel` takes these shapes: whole
+    blocks of tokens, heads of 128 lanes (as many key as value heads),
+    whole groups of them."""
+    return (tokens > 0 and tokens % _SCAN_BLOCK == 0
+            and dk == 128 and dv == 128 and heads % _KDA_HEADS == 0)
+
+
+def kda_scan_kernel_armed(tokens: int, heads: int, dk: int, dv: int) -> bool:
+    """:func:`scan_kernel_armed` for the vector decay: on a TPU, where
+    :func:`kda_chunked_kernel` takes the shapes; everywhere else
+    :func:`kda_chunked` runs."""
+    return jax.default_backend() == "tpu" and kda_scan_kernel_supported(
+        tokens, heads, dk, dv)
+
+
+def kda_chunked_kernel(q, k, v, g, beta, state, *, v_at: int = 0,
+                       interpret: Optional[bool] = None):
+    """:func:`kda_chunked` as ONE Pallas kernel, over the operands as
+    the mixer has them: ``q, k`` (B, T, H * dk) l2-normed, ``v`` (B, T,
+    H * dv) — or a wider array that holds the values from lane ``v_at``
+    on (the convolution's whole output) —, ``g`` (B, T, H * dk) float32,
+    ``beta`` (B, T, H), ``state`` (B, H, dk, dv). Returns ``(o (B, T,
+    H * dv) float32, state)``.
+
+    Grid (row, group of ``_KDA_HEADS`` heads, block of 64 tokens), the
+    blocks in order, the group's states float32 in VMEM from the first
+    block to the last, as in :func:`gated_delta_chunked_kernel`; a PAIR
+    of heads is to this kernel what a key head's pair of value heads is
+    to that one (their token-by-token matrices side by side on 128
+    lanes, block-diagonal in a (128, 128) tile over both heads' token
+    rows). What :func:`kda_chunked` computes, in the order a kernel
+    wants it:
+
+    - ``G``, the running sum of ``g`` inside the block, by six shifted
+      adds down the sublanes (no product).
+    - ``[q; k] k^T`` a sub-block of 16 tokens at a time, relative to one
+      of its tokens — of 16 ROW tokens and their MIDDLE token ``m``,
+      where the XLA form takes 16 key tokens and their first: a row's
+      factor ``exp(G_i - G_m)`` is one matrix for the block, the keys'
+      ``exp(G_m - G_j)`` one a sub-block (far below 1 for the keys of
+      earlier sub-blocks, as it should be). At the published floor of
+      -5 a token neither exponent passes 40; both are held at 80, so a
+      decay below the floor loses accuracy, not finiteness. Two
+      sub-blocks' keys fill the 128 columns of one product, so a head's
+      four are two products of 64 rows, each row reading its own
+      sub-block's half. (The XLA form's rows 16 tokens past a key
+      sub-block's first carry ``exp(-80)`` at the floor, where a head's
+      128 channels are small enough to go subnormal and be flushed: it
+      stands 7e-5 of the largest output from the recurrence there, this
+      one 4e-7: tests/test_kda_delta.py.)
+    - ``(I + a)^-1`` by substitution and two levels of merges, exactly
+      as the scalar kernel (``a^T`` from a pair's tile transposed: ``k
+      k^T`` with the decay inside is not symmetric).
+    - ``D = inv (beta V - K+ S)`` — the corrections' own equation, one
+      product with the inverse where ``W`` and ``U`` apart are two —,
+      ``o = q_in S + qk D``, ``S <- diag(exp(G_c)) S + k_out^T D``, the
+      channels' whole decay turned down the sublanes as a (8, 128)
+      tile's transpose.
+
+    Every product takes float32 operands at HIGHEST precision; a
+    coefficient over its block's lanes is a product with a 0 / 1 matrix
+    (exact). ``beta`` is handed in by group (a (T, group) block; a
+    head's column is broadcast over its lanes on the chip). The body
+    keeps to ``lax`` forms for the warm start's sake (PERF.md section 6,
+    PR 47)."""
+    H = beta.shape[-1]
+    if not kda_scan_kernel_supported(g.shape[1], H, *state.shape[-2:]):
+        raise ValueError(
+            f"no scan kernel for T={g.shape[1]}, heads {H}, widths "
+            f"{state.shape[-2:]}")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _kda_scan_kernel(q, k, v, g, beta, state, v_at=v_at,
+                            interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("v_at", "interpret"))
+def _kda_scan_kernel(q, k, v, g, beta, state, *, v_at: int, interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, H = beta.shape
+    d = state.shape[-1]         # a head's lanes: key and value width
+    c, s16, Hg = _SCAN_BLOCK, _SOLVE, _KDA_HEADS
+    nb, P, f32 = T // c, Hg // 2, jnp.float32
+    if v_at % (Hg * d):         # no whole block of a group's values
+        v, v_at = v[..., v_at:v_at + H * d], 0
+    exp = jax.lax.exp
+
+    def iota(shape, axis):
+        return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+    def keep(mask, x, other=0.0):
+        return jax.lax.select(mask, jnp.broadcast_to(x, mask.shape),
+                              jnp.full(mask.shape, other, x.dtype))
+
+    b16, b32, bc_ = (n.bit_length() - 1 for n in (s16, 2 * s16, c))
+
+    def kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s_in_ref, o_ref,
+               s_out_ref, s_scr):
+        t = pl.program_id(2)
+
+        @pl.when(t == 0)
+        def _():
+            s_scr[...] = s_in_ref[0].astype(f32)
+
+        # (c, W): rows a token, lanes (head, token) — the group's heads
+        # side by side, a PAIR of them a 128-lane tile
+        W = Hg * c
+        row, lane = iota((c, W), 0), iota((c, W), 1)
+        col = lane & (c - 1)
+        below, same16 = row > col, row >> b16 == col >> b16
+        offs = ((s16, (row >> b32 == col >> b32)
+                 & (row >> b16 > col >> b16)),
+                (2 * s16, row >> b32 > col >> b32))
+        # (2c, W): a pair's two heads block-diagonal in its 2c lanes
+        own = iota((2 * c, W), 0) >> bc_ == (iota((2 * c, W), 1) >> bc_) & 1
+        sub, ln = iota((s16, W), 0), iota((s16, W), 1) & (s16 - 1)
+        ones = (iota((2 * c, 2 * c), 0) >> b16
+                == iota((2 * c, 2 * c), 1) >> b16).astype(jnp.bfloat16)
+        # (c, 2c), a pair's tile: its first head's lanes; the rows of the
+        # first of two sub-blocks (rows q 16, q 16, k 16, k 16)
+        first, first2 = (iota((n, 2 * c), 1) < c for n in (c, 2 * c))
+        even = iota((c, 2 * c), 0) >> b16 & 1 == 0
+
+        def pairs(x):       # (., P * n) -> its P lane slices
+            n = x.shape[1] // P
+            return [x[:, p * n:(p + 1) * n] for p in range(P)]
+
+        def head(x, h):     # (., Hg * d) -> head h's d lanes
+            return x[:, h * d:(h + 1) * d]
+
+        def both(x2):       # (c, W) side by side -> (2c, W) diagonal
+            return keep(own, jnp.concatenate([x2, x2], axis=0))
+
+        q, k, G = q_ref[0], k_ref[0], g_ref[0]              # (c, Hg * d)
+        # the running sum of g inside the block
+        token = iota((c, Hg * d), 0)
+        for n in (1, 2, 4, 8, 16, 32):
+            G = G + keep(token >= n, pltpu.roll(G, n, 0))
+        # [q; k] k^T: a row relative to its sub-block's middle token,
+        # the keys relative to the same token, a sub-block of rows at a
+        # time
+        def held(x):        # exp(min(x, 80)): finite whatever the decay
+            return exp(jax.lax.min(x, jnp.full_like(x, 80.0)))
+
+        mids = [G[m:m + 1] for m in range(s16 // 2, c, s16)]
+        decayed = held(G - jnp.concatenate(
+            [jnp.broadcast_to(x, (s16, Hg * d)) for x in mids], axis=0))
+        qd, kd = q * decayed, k * decayed
+        keys = [k * held(x - G) for x in mids]
+        tiles = []
+        for p in range(P):
+            side = []
+            for e in (0, 1):
+                h, halves = 2 * p + e, []
+                for m in (0, 2 * s16):      # sub-blocks 0, 1 then 2, 3
+                    rows = slice(m, m + 2 * s16)
+                    x = _dot(
+                        jnp.concatenate([head(qd, h)[rows],
+                                         head(kd, h)[rows]], axis=0),
+                        jnp.concatenate([head(keys[m >> b16], h),
+                                         head(keys[(m >> b16) + 1], h)],
+                                        axis=0), ((1,), (1,)))  # (c, 2c)
+                    # a row's own sub-block's columns, in this head's
+                    # half of the pair's lanes
+                    turned = pltpu.roll(x, c, 1)
+                    halves.append(jax.lax.select(even, x, turned) if e == 0
+                                  else jax.lax.select(even, turned, x))
+                side.append(jnp.concatenate(
+                    [halves[0][:2 * s16], halves[1][:2 * s16],
+                     halves[0][2 * s16:], halves[1][2 * s16:]], axis=0))
+            tiles.append(jax.lax.select(first2, *side))
+        qk = keep(row >= col, jnp.concatenate([x[:c] for x in tiles],
+                                              axis=1))
+        kk = jnp.concatenate([x[c:] for x in tiles], axis=1)
+        # a head's write strength down its lanes: a (c, 1) column of the
+        # group's block, broadcast
+        betas = beta_ref[0, 0]                              # (c, Hg)
+        bd = jnp.concatenate([jnp.broadcast_to(betas[:, h:h + 1], (c, d))
+                              for h in range(Hg)], axis=1)
+        bt = jnp.concatenate([jax.lax.select(
+            first,          # (no slice of a mask: Mosaic refuses one)
+            jnp.broadcast_to(betas[:, 2 * p:2 * p + 1], (c, 2 * c)),
+            jnp.broadcast_to(betas[:, 2 * p + 1:2 * p + 2], (c, 2 * c)))
+            for p in range(P)], axis=1)
+        a2 = both(keep(below, bt * kk))                     # (2c, W)
+        # a^T, a head's (c, c) at a time: the pair's diagonal tile
+        # transposed, its two heads' rows added back side by side
+        a_t = jnp.concatenate(
+            [x[:c] + x[c:] for x in (y.T for y in pairs(a2))], axis=1)
+        diag_t = keep(same16, a_t)
+        # the diagonal blocks' inverses, every head of the group at
+        # once: (16, W) rows j of a block, lanes (head, block, l)
+        packed = sum(diag_t[m * s16:(m + 1) * s16] for m in range(c // s16))
+        # row i's coefficients a_ij over their block's lanes
+        steps = (s16 - 1) * s16
+        coeff = jnp.concatenate([_spread(x, ones) for x in pairs(keep(
+            (iota((steps, W), 0) >> b16) + 1
+            == iota((steps, W), 1) & (s16 - 1),
+            jnp.concatenate([packed] * (s16 - 1), axis=0)))],
+            axis=1)                                 # (15 * 16, W)
+        eye = (sub == ln).astype(f32)
+        x = eye
+        for i in range(1, s16):
+            new = eye[i:i + 1] - jnp.sum(
+                coeff[(i - 1) * s16:i * s16] * x, axis=0, keepdims=True)
+            x = jax.lax.select(sub == i, jnp.broadcast_to(new, x.shape), x)
+        inv = both(keep(same16, jnp.concatenate([x] * (c // s16), axis=0)))
+        # a level: X - (X a_off) X, whose only rows that change are the
+        # second block's of each pair of n-row blocks
+        for n, off in offs:
+            odd = [slice(r, r + n) for r in range(n, 2 * c, 2 * n)]
+            low = jnp.concatenate([
+                _dot(_dot(lhs, mid), rhs) for lhs, mid, rhs in zip(
+                    pairs(jnp.concatenate([inv[r] for r in odd], axis=0)),
+                    pairs(keep(jnp.concatenate([off, off], axis=0), a2)),
+                    pairs(inv))], axis=1)
+            rows = []
+            for m, r in enumerate(odd):
+                rows += [inv[r.start - n:r.start],
+                         inv[r] - low[m * n:(m + 1) * n]]
+            inv = jnp.concatenate(rows, axis=0)
+        grow = exp(G)
+        kb, vb, q_in = k * grow * bd, v_ref[0] * bd, q * grow
+        k_out = k * exp(G[c - 1:c] - G)
+        # a channel's whole decay over the block, DOWN the sublanes as
+        # a head's state has its key channels: a tile's transpose
+        whole = exp(G[c - 8:])                              # (8, Hg * d)
+        mix = pairs(both(qk))
+        for p, inv_p in enumerate(pairs(inv)):
+            rest, qs = [], []
+            for h in (2 * p, 2 * p + 1):
+                ks = _dot(jnp.concatenate([head(kb, h), head(q_in, h)],
+                                          axis=0), s_scr[h])    # (2c, d)
+                rest.append(head(vb, h) - ks[:c])
+                qs.append(ks[c:])
+            # token rows of the pair's two heads stacked: (2c, d)
+            cor = _dot(inv_p, jnp.concatenate(rest, axis=0))
+            o = jnp.concatenate(qs, axis=0) + _dot(mix[p], cor)
+            for e in (0, 1):
+                rows = slice(e * c, (e + 1) * c)
+                h = 2 * p + e
+                o_ref[0, :, h * d:(h + 1) * d] = o[rows]
+                s_scr[h] = head(whole, h).T[:, 7:8] * s_scr[h] + _dot(
+                    head(k_out, h), cor[rows], ((0,), (0,)))
+
+        @pl.when(t == nb - 1)
+        def _():
+            s_out_ref[0] = s_scr[...].astype(s_out_ref.dtype)
+
+    def tokens(at=0):
+        return pl.BlockSpec((1, c, Hg * d), lambda b, h, t: (b, t, at + h))
+
+    states = pl.BlockSpec((1, Hg, d, d), lambda b, h, t: (b, h, 0, 0))
+    o, state = pl.pallas_call(
+        kernel,
+        grid=(B, H // Hg, nb),
+        in_specs=[tokens(), tokens(), tokens(v_at // (Hg * d)), tokens(),
+                  pl.BlockSpec((1, 1, c, Hg), lambda b, h, t: (b, h, t, 0)),
+                  states],
+        out_specs=[tokens(), states],
+        out_shape=[jax.ShapeDtypeStruct((B, T, H * d), f32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        scratch_shapes=[pltpu.VMEM((Hg, d, d), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="kda_delta_scan",
+    )(q.astype(f32), k.astype(f32), v.astype(f32), g.astype(f32),
+      # a group's heads a (T, group) block of their own
+      jnp.moveaxis(beta.astype(f32).reshape(B, T, H // Hg, Hg), 2, 1), state)
     return o, state

@@ -1114,6 +1114,32 @@ def test_kda_step_kernel_compiles(topo):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+@pytest.mark.parametrize("rows", [1, 4])
+def test_kda_scan_kernel_compiles(topo, rows):
+    """The chunked scan for a decay a CHANNEL as ONE kernel at
+    ling-3.0-flash's widths (a 512-token chunk of one prompt and of
+    four, 32 heads of 128 x 128, the values read behind q and k in the
+    convolution's output): Mosaic takes the shifted adds down the
+    sublanes, the turn of a product's lanes by half a tile, a pair's
+    tile transposed and the (8, 128) tile of decays turned; nothing but
+    the operands and results is on the program's books."""
+    from generativeaiexamples_tpu.ops.gated_delta import (
+        kda_chunked_kernel, kda_scan_kernel_supported)
+    dev = SingleDeviceSharding(topo.devices[0])
+    T, H, dk, dv = 512, 32, 128, 128
+    assert kda_scan_kernel_supported(T, H, dk, dv)
+    f32 = jnp.float32
+    args = (sds((rows, T, H * dk), f32, dev), sds((rows, T, H * dk), f32, dev),
+            sds((rows, T, 3 * H * dv), f32, dev),
+            sds((rows, T, H * dk), f32, dev), sds((rows, T, H), f32, dev),
+            sds((rows, H, dk, dv), f32, dev))
+    compiled = jax.jit(lambda *a: kda_chunked_kernel(
+        *a, v_at=2 * H * dk, interpret=False)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "kda_delta_scan" in text and "tpu_custom_call" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 def ling_cfg():
     """Six layers at published widths in periods of three: a dense KDA
     layer, then an expert stack that begins INSIDE a period — [K L] [K K
@@ -1130,9 +1156,10 @@ def ling_cfg():
 def test_kda_latent_chunk_program_compiles(topo, tpu_backend, rows):
     """``ling-3.0-flash``'s 512-token chunk program of four prompts'
     rows over a state a slot beside a latent pool, the cell's 68-page
-    window: the chunked scan for a decay a channel is XLA's (under the
-    scope ``kda_scan``), the latent layers' prefix walk the chunk
-    kernel, and the program fits beside the engine's reserve."""
+    window: the chunked scan for a decay a channel is the kernel
+    ``kda_delta_scan`` (under the scope ``kda_scan``), the latent
+    layers' prefix walk the chunk kernel, and the program fits beside
+    the engine's reserve."""
     cfg = ling_cfg()
     dev = SingleDeviceSharding(topo.devices[0])
     cache = on(jax.eval_shape(lambda: llama.init_paged_kv_cache(
@@ -1150,7 +1177,7 @@ def test_kda_latent_chunk_program_compiles(topo, tpu_backend, rows):
         i32(rows)).compile()
     assert_fits(compiled)
     text = compiled.as_text()
-    assert re.search(r'op_name="[^"]*/kda_scan/', text)
+    assert re.search(r'op_name="[^"]*/kda_scan/[^"]*kda_delta_scan', text)
     assert re.search(r'op_name="[^"]*/kda_state/', text)
     assert "chunk_attention_prefix" in text
     temp = compiled.memory_analysis().temp_size_in_bytes

@@ -131,11 +131,11 @@ def test_a_dense_cache_follows_the_reference(p32, ids, want):
     each kind ride the scans of a stack that begins inside a period."""
     cache = llama.init_kv_cache(CFG, 1, 96, jnp.float32)
     assert set(cache) == {"c", "r", "s", "conv"}
+    half = jax.jit(lambda tok, pos, cache: llama.apply(     # one trace
+        p32, CFG, tok, pos, cache))
     with HI():
-        a, cache = llama.apply(p32, CFG, ids[:, :48], jnp.arange(48)[None],
-                               cache)
-        b, cache = llama.apply(p32, CFG, ids[:, 48:],
-                               jnp.arange(48, T)[None], cache)
+        a, cache = half(ids[:, :T // 2], jnp.arange(T // 2)[None], cache)
+        b, cache = half(ids[:, T // 2:], jnp.arange(T // 2, T)[None], cache)
     assert rel(jnp.concatenate([a[0], b[0]]), want) < 2e-5
 
 
@@ -368,6 +368,78 @@ def test_a_slots_old_state_is_not_read_at_position_zero(p32, ids, want):
     assert rel(got, want[:32]) < 2e-5
 
 
+def test_an_engine_over_the_scan_kernel_follows_the_reference(monkeypatch):
+    """The model at widths the scan kernel takes (one group of 128-lane
+    heads), a prompt of two chunks — the second from the state and tail
+    the first left, and padded — through chunk programs whose scan is
+    the kernel (interpreted here, armed as a TPU arms it), then a decode
+    round: every served token is the reference's choice after the tokens
+    before it, and every chunk program is counted."""
+    from generativeaiexamples_tpu.engine.engine import (Engine, EngineConfig,
+                                                        SamplingParams)
+    from generativeaiexamples_tpu.models.tokenizer import ByteTokenizer
+    G = gd._KDA_HEADS
+    model = {**MODEL, "num_layers": 6, "linear_num_key_heads": G,
+             "linear_num_value_heads": G,
+             "linear_key_head_dim": 128, "linear_value_head_dim": 128}
+    cfg = LlamaConfig(**model)
+    p = llama.init_params(cfg, jax.random.key(0), jnp.float32)
+    monkeypatch.setattr(gd, "kda_scan_kernel_armed",
+                        gd.kda_scan_kernel_supported)
+    traced, kernel = [], gd.kda_chunked_kernel
+    monkeypatch.setattr(gd, "kda_chunked_kernel",
+                        lambda *a, **kw: traced.append(a[0].shape)
+                        or kernel(*a, **kw))
+    eng = Engine(p, cfg, ByteTokenizer(), EngineConfig(
+        max_slots=2, max_input_length=128, max_output_length=8,
+        prefill_buckets=(64,), max_prefill_bucket=64, page_size=64,
+        steps_per_round=4, kv_pool_tokens=None, dtype="float32"))
+    ids = [int(t) for t in np.random.default_rng(5).integers(3, 250, 100)]
+    eng.start()
+    try:
+        stream = eng.submit(ids, SamplingParams(
+            max_tokens=4, temperature=0.0, ignore_eos=True))
+        list(stream)
+    finally:
+        eng.stop()
+    out = list(stream.token_ids)
+    after = np.arange(len(ids) - 1, len(ids) + len(out) - 1)
+    rows = np.asarray(ref.forward(p, model, np.asarray([ids + out]), after))
+    for tok, row in zip(out, rows):
+        assert row[tok] >= row.max() - 1e-4 * np.abs(row).max()
+    stats = eng.stats
+    assert (1, 64, 128 * G) in traced
+    assert stats["scan_kernel"] == 1 and stats["downgrades"] == 0
+    assert stats["scan_kernel_chunks"] == stats["sched_chunk_programs"] == 2
+
+
+def test_a_tpu_arms_the_scan_kernel_or_names_the_downgrade(monkeypatch):
+    """``ProgramSpec.resolve`` on a TPU: the vector decay's scan kernel
+    armed where the shapes are a whole group of 128-lane heads, and
+    where they fall short (this file's 16-lane heads) ONE downgrade by
+    name, ``scan_kernel -> xla_chunked``."""
+    from generativeaiexamples_tpu.engine.programs import ProgramSpec
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def resolve(cfg):
+        shapes = jax.eval_shape(lambda: llama.init_params(
+            cfg, jax.random.key(0), jnp.float32))
+        return ProgramSpec.resolve(shapes, cfg, page_size=64, max_slots=2,
+                                   pmax=4, dtype=jnp.float32, mesh=None,
+                                   eos_id=2)
+
+    short = resolve(CFG)
+    assert not short.scan_kernel
+    assert [d[:2] for d in short.downgrades if d[0] == "scan_kernel"] == [
+        ("scan_kernel", "xla_chunked")]
+    G = gd._KDA_HEADS
+    wide = resolve(dataclasses.replace(
+        CFG, linear_num_key_heads=G, linear_num_value_heads=G,
+        linear_key_head_dim=128, linear_value_head_dim=128))
+    assert wide.scan_kernel
+    assert "scan_kernel" not in [d[0] for d in wide.downgrades]
+
+
 def test_pipeline_and_ring_refuse_by_name(p32):
     with pytest.raises(NotImplementedError, match="recurrent layers"):
         llama._refuse_kinds(CFG, "apply_sp")
@@ -447,8 +519,9 @@ def test_a_checkpoint_by_the_assumed_names_is_the_tree(p32, ids):
             assert got[stack][name].shape == want.shape, name
             np.testing.assert_allclose(got[stack][name], want, rtol=1e-6,
                                        atol=1e-7, err_msg=name)
-    a, _ = llama.apply(got, CFG, ids[:, :24], jnp.arange(24)[None])
-    b, _ = llama.apply(p32, CFG, ids[:, :24], jnp.arange(24)[None])
+    forward = jax.jit(lambda p: llama.apply(        # one trace for both
+        p, CFG, ids[:, :24], jnp.arange(24)[None]))
+    (a, _), (b, _) = forward(got), forward(p32)
     assert rel(a[0], b[0]) <= 1e-5
 
 

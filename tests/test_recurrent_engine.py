@@ -45,9 +45,10 @@ def _logits(params, ids, n):
 
 
 def plain_greedy(params, ids, n):
-    """The plain forward's own greedy chain, no cache: one buffer, a
-    forward a token (causal: what follows a position does not move it)."""
-    buf = np.zeros(512 + N_OUT, np.int32)
+    """The plain forward's own greedy chain, no cache: one buffer (the
+    longest prompt here is 400 tokens), a forward a token (causal: what
+    follows a position does not move it)."""
+    buf = np.zeros(400 + N_OUT, np.int32)
     buf[:len(ids)] = ids
     for at in range(len(ids), len(ids) + n):
         buf[at] = int(jnp.argmax(_logits(params, jnp.asarray(buf), at)))
@@ -248,15 +249,16 @@ def test_chunk_programs_over_the_scan_kernel_serve_the_xla_forms_tokens(
     whole group of key heads with two value heads each, pages of whole
     blocks): its chunk programs with the kernel (interpreted here, armed
     as a TPU arms it) serve the greedy tokens and leave the state of the
-    same engine over the XLA form — a prompt of several chunks, the last
-    padded, and a one-bucket prompt — and count every chunk program."""
+    same engine over the XLA form — a prompt of two chunks, the second
+    from the first's state and padded, and a one-bucket prompt — and
+    count every chunk program."""
     from generativeaiexamples_tpu.ops import gated_delta as gd
     cfg = dataclasses.replace(
         CFG, linear_key_head_dim=128, linear_value_head_dim=128,
         linear_num_key_heads=gd._SCAN_PAIRS,
         linear_num_value_heads=2 * gd._SCAN_PAIRS)
     p = llama.init_params(cfg, jax.random.key(3), dtype=jnp.float32)
-    prompts = [prompt(200, 21), prompt(40, 22)]
+    prompts = [prompt(100, 21), prompt(40, 22)]
     want, want_s, plain = _served_by(p, cfg, prompts)
     assert plain["scan_kernel"] == 0 == plain["scan_kernel_chunks"]
     assert plain["downgrades"] == 0 and plain["sched_chunk_programs"] > 0
