@@ -427,8 +427,9 @@ class ProgramSpec:
         # either decay's chunked scan has its kernel (ops/gated_delta.py
         # ``gated_delta_chunked_kernel``, ``kda_chunked_kernel``): which
         # is the configuration's ``linear_decay``, and each reads its
-        # path off the shapes
-        if model_cfg.recurrent:
+        # path off the shapes; a state-space layer's (``"ssd"``) has none
+        # yet, so none is wanted and none is missed
+        if model_cfg.recurrent and model_cfg.linear_decay != "ssd":
             channel = model_cfg.linear_decay == "channel"
             heads = (model_cfg.linear_num_key_heads,
                      model_cfg.linear_num_value_heads)

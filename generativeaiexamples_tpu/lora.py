@@ -62,7 +62,7 @@ def init_lora(cfg: LlamaConfig, base_params: llama.Params, key: jax.Array,
             f"(full_attention_interval={cfg.full_attention_interval}): the "
             f"targets are stacked over the attention layers only, which "
             f"the merge and the train step do not know, and the recurrent "
-            f"mixer's projections (gdn_*) are no target")
+            f"mixer's projections (gdn_* / kda_* / ssd_*) are no target")
     lora: LoraParams = {}
     keys = jax.random.split(key, len(targets))
     for k_rng, name in zip(keys, targets):

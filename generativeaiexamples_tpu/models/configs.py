@@ -119,10 +119,21 @@ class LlamaConfig:
     #         ``wo``, the MLP or experts) before it is added to the
     #         stream: four norms a block
     #   embed_scale: the embedding output times this
+    #   residual_multiplier: what a sub-block (mixer, MLP) writes to the
+    #         stream times this (1.0: nothing in the program)
+    #   attention_multiplier: what per-head or latent attention scores
+    #         are multiplied by where it is NOT head_dim ** -0.5 (0.0:
+    #         that; ``score_scale`` is what every kernel and reader takes)
+    #   logits_divisor: the logits divided by this — applied ONCE, to the
+    #         normed hidden row (``llama.unembed_norm``), which every tail
+    #         (materialised, scanned, the head kernels) passes through
     qk_norm: bool = False
     attn_gate: bool | str = False
     post_norms: bool = False
     embed_scale: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_divisor: float = 1.0
     # Latent attention (``kv_lora_rank`` > 0; every default is the model
     # above): the block's input is projected to ONE latent row a token,
     # ``kv_lora_rank`` values (normed) beside one rotary key part of
@@ -200,9 +211,12 @@ class LlamaConfig:
     hc_res_clamp: float = 30.0
     # Recurrent layers beside attention layers (``full_attention_interval``
     # > 0; 0 is the model above, whose programs it leaves as they are):
-    # layer ``i`` is an attention layer when ``(i + 1) %
-    # full_attention_interval == 0`` and a GATED DELTA-RULE layer
-    # otherwise (ops/gated_delta.py has the equations): its token mixer
+    # layer ``i`` is an attention layer when ``i % full_attention_interval
+    # == full_attention_place`` (-1: the period's LAST layer, ``(i + 1) %
+    # full_attention_interval == 0``; a model whose attention layers sit
+    # in mid-period states where) and a RECURRENT layer otherwise — a
+    # gated delta rule (ops/gated_delta.py has the equations) or a
+    # state-space layer (``linear_decay`` "ssd"): its token mixer
     # keeps no row a token but ONE state a sequence, ``linear_num_value_heads``
     # matrices of ``linear_key_head_dim`` x ``linear_value_head_dim`` in
     # float32, and the last ``linear_conv_kernel_dim - 1`` inputs of a
@@ -226,13 +240,23 @@ class LlamaConfig:
     #         (``linear_decay_floor``, 0); the state's rows decay each at
     #         its own rate; a decay projection and an output gate as wide
     #         as the heads, the gate a sigmoid; as many key as value
-    #         heads)
+    #         heads) | "ssd" (a state-space layer, Mamba-2: NO delta rule;
+    #         a head's state ``linear_value_head_dim`` x
+    #         ``linear_key_head_dim`` (P x N) decays by ``exp(dt A)``, ``dt
+    #         = softplus(x w_dt + dt_bias)`` a scalar a head that is the
+    #         write strength too, ``A = -exp(A_log)``; the
+    #         ``linear_num_key_heads`` are the GROUPS that share an input
+    #         and an output vector B, C of ``linear_key_head_dim`` values;
+    #         a skip ``D x``; the convolution has a bias; the output is
+    #         gated by silu(z) BEFORE one norm over a group's whole width
+    #         — ops/ssd.py has the equations)
     #   partial_rotary_factor: the share of an attention head's values the
     #         rotary embedding turns, from the first on (pairs (i, i +
     #         half of THAT part)); the rest pass
     #   shared_expert_gate: the shared expert's output times
     #         sigmoid(x . w), ``w`` one vector a layer (``ws_gate_w``)
     full_attention_interval: int = 0
+    full_attention_place: int = -1
     linear_num_key_heads: int = 0
     linear_num_value_heads: int = 0
     linear_key_head_dim: int = 0
@@ -386,8 +410,13 @@ class LlamaConfig:
                     "recurrent layers (full_attention_interval) need the "
                     "five linear_* sizes, a convolution of at least 2 taps "
                     "and value heads a multiple of the key heads")
+            if not -1 <= self.full_attention_place < n:
+                raise ValueError(
+                    "full_attention_place is the attention layer's index "
+                    "in its period, or -1 for the last")
             if (self.index_topk or self.hc_mult
-                    or self.sliding_window or self.rope_layers
+                    or self.sliding_window
+                    or len(set(self.rope_layers)) > 1
                     or self.norm != "rmsnorm" or self.attn_bias
                     or self.router_input != "mlp_norm"
                     or (self.num_experts and self.moe_impl != "dropless")):
@@ -395,24 +424,25 @@ class LlamaConfig:
                     "recurrent layers (full_attention_interval) run beside "
                     "per-head or latent attention: no learned sparse "
                     "attention, hyper-connections, "
-                    "window or rope pattern, layernorm1p, attention bias, "
-                    "block_input router or capacity routing")
-            if self.linear_decay not in ("head", "channel") or (
+                    "window or rope pattern of two kinds, layernorm1p, "
+                    "attention bias, block_input router or capacity routing")
+            if self.linear_decay not in ("head", "channel", "ssd") or (
                     self.linear_decay == "channel" and not (
                         self.linear_decay_floor < 0
                         and self.linear_num_key_heads
                         == self.linear_num_value_heads)) or (
-                    self.linear_decay == "head"
+                    self.linear_decay != "channel"
                     and self.linear_decay_floor):
                 raise ValueError(
-                    "linear_decay is \"head\" or \"channel\"; a decay a "
+                    "linear_decay is \"head\", \"channel\" or \"ssd\"; a "
+                    "decay a "
                     "channel has a negative linear_decay_floor (its "
                     "log-decay's lower bound) and as many key as value "
                     "heads")
         elif (self.linear_num_key_heads or self.linear_num_value_heads
               or self.linear_key_head_dim or self.linear_value_head_dim
               or self.linear_conv_kernel_dim or self.linear_decay != "head"
-              or self.linear_decay_floor):
+              or self.linear_decay_floor or self.full_attention_place != -1):
             raise ValueError("the linear_* keys are the recurrent layers' "
                              "(full_attention_interval)")
         if not 0.0 < self.partial_rotary_factor <= 1.0 or (
@@ -476,14 +506,25 @@ class LlamaConfig:
         """Per layer, 1 where its token mixer is attention, 0 where it is
         the recurrence (``full_attention_interval``)."""
         n = self.full_attention_interval
-        return tuple(int(not n or (i + 1) % n == 0)
+        at = self.full_attention_place % n if n else 0
+        return tuple(int(not n or i % n == at)
                      for i in range(self.num_layers))
+
+    def full_before(self, li):
+        """The attention layers below layer ``li`` (an int, or a traced
+        index): an attention layer's place among its kind, and what a
+        recurrent layer's index less it is ITS place among its kind."""
+        n = self.full_attention_interval
+        shift = n - 1 - self.full_attention_place % n
+        return (li + shift if shift else li) // n
 
     @property
     def recurrent_scope(self) -> str:
-        """What a recurrent layer's leaves (``gdn_*`` / ``kda_*``) and
-        stage names start with: the member of the family it runs."""
-        return "kda" if self.linear_decay == "channel" else "gdn"
+        """What a recurrent layer's leaves (``gdn_*`` / ``kda_*`` /
+        ``ssd_*``) and stage names start with: the member of the family
+        it runs (``linear_decay``) — the one place it is chosen."""
+        return {"head": "gdn", "channel": "kda", "ssd": "ssd"}[
+            self.linear_decay]
 
     @property
     def rotary_dim(self) -> int:
@@ -494,7 +535,8 @@ class LlamaConfig:
     @property
     def linear_channels(self) -> int:
         """The channels a recurrent layer's convolution runs over: q, k
-        and v of every head."""
+        and v of every head (a state-space layer's x of every head and a
+        B and a C a group: the same count)."""
         return (2 * self.linear_num_key_heads * self.linear_key_head_dim
                 + self.linear_num_value_heads * self.linear_value_head_dim)
 
@@ -520,13 +562,14 @@ class LlamaConfig:
 
     @property
     def score_scale(self) -> float:
-        """What the attention scores are multiplied by: head_dim ** -0.5,
-        times YaRN's m ** 2 where the configuration has it."""
+        """What the attention scores are multiplied by: head_dim ** -0.5
+        (or the ``attention_multiplier`` the configuration states), times
+        YaRN's m ** 2 where the configuration has it."""
         m = 1.0
         if self.rope_mscale_all_dim and self.rope_scaling_factor > 1:
             m = 0.1 * self.rope_mscale_all_dim * math.log(
                 self.rope_scaling_factor) + 1.0
-        return self.head_dim ** -0.5 * m * m
+        return (self.attention_multiplier or self.head_dim ** -0.5) * m * m
 
     @property
     def q_dim(self) -> int:
@@ -720,6 +763,27 @@ LING_3_0_FLASH = LlamaConfig(
     linear_conv_kernel_dim=4, linear_decay="channel",
     linear_decay_floor=-5.0, weight_init="unit_stream")
 
+# A 3B dense hybrid (ibm-granite granite-4.0-h-micro config.json,
+# model_type granitemoehybrid): 40 layers in four periods of [five
+# Mamba-2 layers, ONE attention layer, four Mamba-2 layers]; a Mamba-2
+# layer keeps 64 heads of a 64 x 128 state, one B / C group, a 4-tap
+# convolution with bias; the attention layers are GQA 32 / 8 of 64
+# WITHOUT rotary embedding and score by 1/64; every block's FFN is the
+# dense "shared" SwiGLU of width 8192 (no routed experts); the stream
+# takes a sub-block's output times 0.22, the embedding times 12, and the
+# logits over the tied head are divided by 8.
+GRANITE_4_0_H_MICRO = LlamaConfig(
+    vocab_size=100352, hidden_size=2048, intermediate_size=8192,
+    num_layers=40, num_heads=32, num_kv_heads=8, head_dim=64,
+    max_position_embeddings=131072, rope_theta=10000.0, rms_norm_eps=1e-5,
+    tie_word_embeddings=True, rope_layers=(0,), embed_scale=12.0,
+    residual_multiplier=0.22, attention_multiplier=0.015625,
+    logits_divisor=8.0, full_attention_interval=10,
+    full_attention_place=5, linear_num_key_heads=1,
+    linear_num_value_heads=64, linear_key_head_dim=128,
+    linear_value_head_dim=64, linear_conv_kernel_dim=4,
+    linear_decay="ssd", weight_init="unit_stream")
+
 NEMOTRON_8B = LlamaConfig(vocab_size=256000, hidden_size=4096,
                           intermediate_size=16384, num_layers=32,
                           num_heads=32, num_kv_heads=32, head_dim=128,
@@ -769,6 +833,7 @@ MODEL_REGISTRY: dict[str, LlamaConfig] = {
     "xing4.0-29b-a4b": XING4_0_29B_A4B,
     "qwen3-next-80b-a3b-instruct": QWEN3_NEXT_80B_A3B,
     "ling-3.0-flash": LING_3_0_FLASH,
+    "granite-4.0-h-micro": GRANITE_4_0_H_MICRO,
     "gptnext-tiny": GPTNEXT_TINY,
     "llama-tiny": LLAMA_TINY,
     "golden-tiny": GOLDEN_TINY,

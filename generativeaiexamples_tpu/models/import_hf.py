@@ -139,12 +139,29 @@ _KDA_JOINED = {     # the tree's leaf: its published parts, in order
     "kda_wqkv": tuple(f"self_attn.{n}_proj.weight" for n in "qkv"),
     "kda_conv": tuple(f"self_attn.{n}_conv1d.weight" for n in "qkv"),
 }
+# A model whose recurrent layers are state-space layers (``model_type:
+# granitemoehybrid``; cfg.linear_decay "ssd") WITHOUT routed experts: a
+# Mamba-2 layer's leaves by the published modelling code's names
+# (``mamba.*``; ``in_proj`` is ``[z | xBC | dt]`` in one matrix, stored
+# taken apart: ``_SSD_SPLITS``), an attention layer's by the plain names
+# above, and the block's one dense MLP as ``shared_mlp.input_linear``
+# (gate | up in one matrix) and ``shared_mlp.output_linear``.
+_HF_SSD_LAYER_KEYS = {
+    "mamba.conv1d.bias": ("ssd_conv_b", False),
+    "mamba.dt_bias": ("ssd_dt_bias", False),
+    "mamba.A_log": ("ssd_A_log", False),
+    "mamba.D": ("ssd_D", False),
+    "mamba.norm.weight": ("ssd_norm", False),
+    "mamba.out_proj.weight": ("ssd_wout", True),
+    "shared_mlp.output_linear.weight": ("w_down", True),
+}
 _HF_LATENT_GATE = "self_attn.g_proj.weight"
 _HF_KV_B = "self_attn.kv_b_proj.weight"
 # buffers and coefficients the program reads in float32
 _FLOAT32_LEAVES = ("router_bias", "hc_attn_alpha", "hc_attn_b",
                    "hc_mlp_alpha", "hc_mlp_b", "gdn_A_log", "gdn_dt_bias",
-                   "kda_A_log", "kda_dt_bias")
+                   "kda_A_log", "kda_dt_bias", "ssd_A_log", "ssd_dt_bias",
+                   "ssd_D")
 
 
 def _split_q_gate(w: np.ndarray, cfg: LlamaConfig) -> tuple:
@@ -350,6 +367,25 @@ _RECURRENT_SPLITS = {
 }
 
 
+def _split_in_proj(w: np.ndarray, cfg: LlamaConfig) -> dict:
+    """``mamba.in_proj`` (z + xBC + dt, D) as its two stored leaves."""
+    wide = cfg.linear_num_value_heads * cfg.linear_value_head_dim \
+        + cfg.linear_channels
+    if w.shape[0] != wide + cfg.linear_num_value_heads:
+        raise ModelLoadError(
+            f"mamba.in_proj has {w.shape[0]} rows; z | xBC | dt of this "
+            f"configuration are {wide + cfg.linear_num_value_heads}")
+    return {"ssd_win": w[:wide].T, "ssd_wdt": w[wide:].T}
+
+
+_SSD_SPLITS = {
+    "mamba.in_proj.weight": _split_in_proj,
+    "mamba.conv1d.weight": lambda w, cfg: {"ssd_conv": w[:, 0, :]},
+    "shared_mlp.input_linear.weight": lambda w, cfg: {
+        "w_gate": w[:w.shape[0] // 2].T, "w_up": w[w.shape[0] // 2:].T},
+}
+
+
 def params_from_named_tensors(
         tensors: Iterator[tuple[str, Any]], cfg: LlamaConfig,
         dtype: jnp.dtype = jnp.bfloat16) -> Params:
@@ -373,9 +409,13 @@ def params_from_named_tensors(
     channel = cfg.linear_decay == "channel"
     # the published conventions of the model whose decay is a head's:
     # grouped projections, zero-centred norms
-    grouped = recurrent and not channel
+    grouped = recurrent and cfg.linear_decay == "head"
+    splits = _RECURRENT_SPLITS if grouped else {}
     if grouped:
         hf_keys.update(_HF_RECURRENT_LAYER_KEYS)
+    elif recurrent and not channel:     # state-space layers
+        hf_keys.update(_HF_SSD_LAYER_KEYS)
+        splits = _SSD_SPLITS
     kda_parts: dict[tuple, np.ndarray] = {}
     # an expert share keeps the experts it holds, numbered from its first
     first_expert, held = cfg.experts_first, cfg.held_experts
@@ -410,8 +450,8 @@ def params_from_named_tensors(
         if not m:
             continue  # rotary inv_freq buffers etc.
         idx, rest = int(m.group(1)), m.group(2)
-        if grouped and rest in _RECURRENT_SPLITS:
-            for name, part in _RECURRENT_SPLITS[rest](arr, cfg).items():
+        if rest in splits:
+            for name, part in splits[rest](arr, cfg).items():
                 put_layer(name, idx, part)
             continue
         if channel and not cfg.layer_full[idx]:     # a KDA layer's own
@@ -581,6 +621,65 @@ def bailing_hybrid_config(hf: dict, *, num_layers: int | None = None,
         linear_decay="channel",
         linear_decay_floor=float(hf["kda_lower_bound"]),
         tie_word_embeddings=bool(hf["tie_word_embeddings"]), **more)
+
+
+def granitemoehybrid_config(hf: dict, **more) -> LlamaConfig:
+    """The ``LlamaConfig`` of a published ``granitemoehybrid``
+    ``config.json`` (``hf``, its keys as published). What no key states
+    is listed in benchmarks/configs/granite-4.0-h-micro.json
+    ``assumed``. Refuses BY NAME what the program has no form for:
+    routed experts beside the block's dense MLP (``num_local_experts``
+    > 0), biases, a rotary embedding beside the state-space layers, and
+    attention layers that do not sit at ONE place of a period."""
+    types = list(hf["layer_types"])
+    full = [i for i, t in enumerate(types) if t == "attention"]
+    L = int(hf["num_hidden_layers"])
+    if hf.get("num_local_experts"):
+        raise ModelLoadError(
+            f"granitemoehybrid: num_local_experts="
+            f"{hf['num_local_experts']}: routed experts beside the "
+            f"block's dense MLP are not supported")
+    want = {"attention_bias": False, "mamba_proj_bias": False,
+            "position_embedding_type": "nope", "mamba_conv_bias": True,
+            "hidden_act": "silu"}
+    stated = [k for k, v in want.items() if hf.get(k, v) != v]
+    if stated:
+        raise ModelLoadError(
+            f"granitemoehybrid: {stated} states a variant of the block "
+            f"that is not supported")
+    if len(types) != L or len(full) < 1 or len(full) == L:
+        raise ModelLoadError("granitemoehybrid: layer_types names no "
+                             "state-space or no attention layer")
+    period = full[1] - full[0] if len(full) > 1 else L
+    place = full[0]
+    if place >= period or full != list(range(place, L, period)):
+        raise ModelLoadError(
+            f"granitemoehybrid: attention layers {full} do not sit at one "
+            f"place of a period")
+    heads, p = hf["mamba_n_heads"], hf["mamba_d_head"]
+    if heads * p != hf["mamba_expand"] * hf["hidden_size"]:
+        raise ModelLoadError("granitemoehybrid: mamba_n_heads x "
+                             "mamba_d_head is not mamba_expand x hidden_size")
+    return LlamaConfig(
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        intermediate_size=hf["shared_intermediate_size"], num_layers=L,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf["hidden_size"] // hf["num_attention_heads"],
+        max_position_embeddings=hf["max_position_embeddings"],
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rms_norm_eps=hf["rms_norm_eps"],
+        tie_word_embeddings=bool(hf["tie_word_embeddings"]),
+        rope_layers=(0,), embed_scale=float(hf["embedding_multiplier"]),
+        residual_multiplier=float(hf["residual_multiplier"]),
+        attention_multiplier=float(hf["attention_multiplier"]),
+        logits_divisor=float(hf["logits_scaling"]),
+        full_attention_interval=period, full_attention_place=place,
+        linear_num_key_heads=hf["mamba_n_groups"],
+        linear_num_value_heads=heads,
+        linear_key_head_dim=hf["mamba_d_state"], linear_value_head_dim=p,
+        linear_conv_kernel_dim=hf["mamba_d_conv"], linear_decay="ssd",
+        **more)
 
 
 def load_checkpoint(path: str, cfg: LlamaConfig,

@@ -121,16 +121,37 @@ def kv_cache_of(cfg: LlamaConfig):
 
 
 class HeadKV:
-    """Per-head K and V: ``{"k","v"}: (L, n_pages, KV, page, hd)``."""
+    """Per-head K and V: ``{"k","v"}: (L, n_pages, KV, page, hd)``.
+
+    **Heads of 64 values, packed two a lane row** (``pack_heads``, where
+    the pool is on ONE chip: ``RecurrentKV``, which refuses a mesh). A
+    pool whose last axis is 64 is half a vector register's lanes: the
+    decode kernel does not take it and the chip stores it padded to
+    twice its bytes. Under lane-width pages such a pool is built as (L,
+    n_pages, KV / 2, page, 128) — kv heads ``2j`` and ``2j + 1`` side by
+    side in row ``j`` — which is a pure reshape of a token's (KV, hd)
+    rows, so every gathered read and every write below is as it was. The
+    kernel then sees KV / 2 heads of 128: a query goes in beside zeros
+    in the other head's half (its scores are its own head's, exactly),
+    and of its 128 output values its own half is kept."""
 
     leaves = ("k", "v")
 
-    def __init__(self, cfg: LlamaConfig, layers: Optional[int] = None):
+    def __init__(self, cfg: LlamaConfig, layers: Optional[int] = None,
+                 pack_heads: bool = False):
         """``layers``: the layers that keep rows (all of them; the
         attention layers where ``RecurrentKV`` holds this as its paged
         part)."""
         self.cfg = cfg
         self.n_layers = cfg.num_layers if layers is None else layers
+        self.pack_heads = pack_heads
+
+    def _pack(self, page: int) -> int:
+        """KV heads a stored row holds (class docstring): 2 or 1."""
+        cfg = self.cfg
+        return 2 if (self.pack_heads and cfg.head_dim == 64
+                     and cfg.num_kv_heads % 2 == 0 and page % 128 == 0) \
+            else 1
 
     # ---------------------------------------------------------------- build
 
@@ -145,8 +166,13 @@ class HeadKV:
                   dtype: jnp.dtype = jnp.bfloat16,
                   quantized: bool = False) -> KVCache:
         cfg = self.cfg
-        shape = (self.n_layers, n_pages, cfg.num_kv_heads, page_size,
-                 cfg.head_dim)
+        pack = self._pack(page_size)
+        if pack > 1 and quantized:
+            raise NotImplementedError(
+                "an int8 KV pool of packed heads: a row's scale would be "
+                "two heads'")
+        shape = (self.n_layers, n_pages, cfg.num_kv_heads // pack,
+                 page_size, cfg.head_dim * pack)
         if not quantized:
             return {"k": jnp.zeros(shape, dtype),
                     "v": jnp.zeros(shape, dtype)}
@@ -175,6 +201,14 @@ class HeadKV:
     def page_size(kv_cache: KVCache) -> int:
         return kv_cache["k"].shape[3]
 
+    @property
+    def scale(self) -> Optional[float]:
+        """What the scores are multiplied by where the configuration
+        states it (``attention_multiplier``: ``cfg.score_scale``); None
+        leaves every reader its own head_dim ** -0.5."""
+        cfg = self.cfg
+        return cfg.score_scale if cfg.attention_multiplier else None
+
     @staticmethod
     def quantized(kv_cache: KVCache) -> bool:
         """Whether a paged pool carries int8 rows + scale leaves."""
@@ -183,8 +217,10 @@ class HeadKV:
     def kernel_supported(self, page: int) -> bool:
         from ..ops.paged_attention import kernel_supported
         cfg = self.cfg
-        return kernel_supported(page, cfg.num_heads, cfg.num_kv_heads,
-                                cfg.head_dim)
+        pack = self._pack(page)
+        return kernel_supported(page, cfg.num_heads,
+                                cfg.num_kv_heads // pack,
+                                cfg.head_dim * pack)
 
     def pool_spec(self, mesh, quantized: bool = False) -> dict:
         from ..parallel.sharding import paged_kv_cache_spec
@@ -209,8 +245,11 @@ class HeadKV:
         - whole pages (``offsets`` None): a chunk's new (L, C, KV, hd), C
           a page multiple, to its C / page physical ``pages``.
         """
-        L, N, KV, page, _ = kv_cache["k"].shape
-        new = {"k": new_k, "v": new_v}
+        L, N, KV, page, W = kv_cache["k"].shape
+        # a token's (KV, hd) rows as the pool keeps them (packed heads:
+        # two side by side, a reshape)
+        new = {"k": new_k.reshape(new_k.shape[:-2] + (KV, W)),
+               "v": new_v.reshape(new_v.shape[:-2] + (KV, W))}
         if self.quantized(kv_cache):
             from ..ops.kv_quant import quantize_rows
             new["k"], new["ks"] = quantize_rows(new_k)    # scales: (..., KV)
@@ -240,16 +279,13 @@ class HeadKV:
         multiple, into the S / page physical pages ``dest`` (the engine's
         admission; bucket overhang past the slot's extent goes to the
         trash page)."""
-        cfg = self.cfg
         L, _, S = k_new.shape[:3]
-        page = self.page_size(kv_cache)
+        KV, page, W = kv_cache["k"].shape[2:]
         nb = S // page
         # (L,1,S,KV,hd) -> (L, nb, KV, page, hd): pool layout keeps KV
         # ahead of page (see llama.init_paged_kv_cache).
-        kp = k_new.reshape(L, nb, page, cfg.num_kv_heads,
-                           cfg.head_dim).swapaxes(2, 3)
-        vp = v_new.reshape(L, nb, page, cfg.num_kv_heads,
-                           cfg.head_dim).swapaxes(2, 3)
+        kp = k_new.reshape(L, nb, page, KV, W).swapaxes(2, 3)
+        vp = v_new.reshape(L, nb, page, KV, W).swapaxes(2, 3)
         cache = kv_cache
         if self.quantized(kv_cache):
             from ..ops.kv_quant import quantize_rows
@@ -297,7 +333,7 @@ class HeadKV:
         """Attention over the keys given by absolute position: the
         call's own tokens, or a dense cache's rows."""
         return gqa_attention(q, k, v, positions, kv_valid_len,
-                             window=lp.get("window"))
+                             window=lp.get("window"), scale=self.scale)
 
     def put_dense(self, lp, k, v, row_start):
         """A layer's slices of the dense cache (``lp["cache_k"]`` /
@@ -321,7 +357,8 @@ class HeadKV:
             return g.at[rows, positions].set(new.astype(g.dtype))
 
         return gqa_attention(q, window("k", k), window("v", v), positions,
-                             kv_valid_len, window=lp.get("window"))
+                             kv_valid_len, window=lp.get("window"),
+                             scale=self.scale)
 
     def attend_prefix(self, q, k, v, lp, kv_cache, block_table, start,
                       kv_valid_len, layer):
@@ -331,7 +368,7 @@ class HeadKV:
             q, k, v, kv_cache["k"], kv_cache["v"], kv_cache.get("ks"),
             kv_cache.get("vs"), block_table, start, kv_valid_len,
             self.page_size(kv_cache), self.cfg, window=lp.get("window"),
-            layer=layer)
+            layer=layer, scale=self.scale)
 
     def kernel_attend(self, kv_cache: KVCache, block_table, pos_in_win,
                       write_page, write_offset, mesh, act_dtype):
@@ -344,6 +381,14 @@ class HeadKV:
         # the current token's K/V pass in compute dtype, not pool dtype.
         dt = act_dtype if self.quantized(kv_cache) else kv_cache["k"].dtype
         interp = jax.default_backend() != "tpu"
+        KV, hd = kv_cache["k"].shape[2], cfg.head_dim
+        pack = cfg.num_kv_heads // KV
+        # the kernel's default scale is its own head width's
+        scale = self.scale if pack == 1 else cfg.score_scale
+        # which part of a packed row a query head's kv head lies in
+        part = pack > 1 and (
+            jnp.arange(cfg.num_heads)
+            // (cfg.num_heads // cfg.num_kv_heads) % pack)[:, None]
 
         # ``win``: the layer's window as one more (1,) operand, only in a
         # model that has window layers
@@ -351,7 +396,8 @@ class HeadKV:
             attn, *leaves = paged_attention_decode(
                 q, pool["k"], pool["v"], tbl, lens, ck, cv, wp, off, li,
                 pool_ks=pool.get("ks"), pool_vs=pool.get("vs"),
-                interpret=interp, window=win[0] if win else None)
+                interpret=interp, window=win[0] if win else None,
+                scale=scale)
             return attn, dict(zip(("k", "v", "ks", "vs"), leaves))
 
         if mesh is not None and "tp" in mesh.shape:
@@ -374,9 +420,19 @@ class HeadKV:
 
         def attend(q, k, v, lp, li, pool):
             win = (lp["window"][None],) if "window" in lp else ()
+            q, k, v = q[:, 0], k[:, 0].astype(dt), v[:, 0].astype(dt)
+            if pack > 1:    # packed heads (class docstring)
+                q = jnp.concatenate([jnp.where(part == r, q, 0)
+                                     for r in range(pack)], axis=-1)
+                k, v = (a.reshape(a.shape[0], KV, pack * hd)
+                        for a in (k, v))
             attn, pool = call_kernel(
-                q[:, 0], pool, k[:, 0].astype(dt), v[:, 0].astype(dt), li,
-                block_table, pos_in_win, write_page, write_offset, *win)
+                q, pool, k, v, li, block_table, pos_in_win, write_page,
+                write_offset, *win)
+            if pack > 1:
+                attn = sum(jnp.where(part == r,
+                                     attn[..., r * hd:(r + 1) * hd], 0)
+                           for r in range(pack))
             return attn[:, None], pool
 
         return attend
@@ -391,11 +447,11 @@ class RecurrentKV:
 
     def __init__(self, cfg: LlamaConfig):
         self.cfg = cfg
-        self.period = cfg.full_attention_interval
         self.n_full = sum(cfg.layer_full)
         self.n_recurrent = cfg.num_layers - self.n_full
-        paged = LatentKV if cfg.kv_lora_rank else HeadKV
-        self.paged = paged(cfg, layers=self.n_full)
+        # (heads of 64 packed two a lane row: this cache is one chip's)
+        self.paged = LatentKV(cfg, layers=self.n_full) if cfg.kv_lora_rank \
+            else HeadKV(cfg, layers=self.n_full, pack_heads=True)
         self.leaves = self.paged.leaves + ("s", "conv")
         self.scope = cfg.recurrent_scope + "_state"
 
@@ -410,8 +466,13 @@ class RecurrentKV:
 
     def _state_shapes(self, slots: int) -> tuple[tuple, tuple]:
         cfg = self.cfg
-        return ((self.n_recurrent, slots, cfg.linear_num_value_heads,
-                 cfg.linear_key_head_dim, cfg.linear_value_head_dim),
+        # a head's state: key width x value width; a state-space layer's
+        # (P, N) the other way round, its 128-wide N on the lanes
+        # (ops/ssd.py says why)
+        head = (cfg.linear_key_head_dim, cfg.linear_value_head_dim)
+        if cfg.linear_decay == "ssd":
+            head = head[::-1]
+        return ((self.n_recurrent, slots, cfg.linear_num_value_heads) + head,
                 (self.n_recurrent, slots,
                  (cfg.linear_conv_kernel_dim - 1) * cfg.linear_channels))
 
@@ -464,19 +525,20 @@ class RecurrentKV:
 
     def attend_window(self, q, k, v, lp, kv_cache, layer, *args):
         return self.paged.attend_window(q, k, v, lp, kv_cache,
-                                        layer // self.period, *args)
+                                        self.cfg.full_before(layer), *args)
 
     def attend_prefix(self, q, k, v, lp, kv_cache, block_table, start,
                       kv_valid_len, layer, **kernel):
         return self.paged.attend_prefix(q, k, v, lp, kv_cache, block_table,
                                         start, kv_valid_len,
-                                        layer // self.period, **kernel)
+                                        self.cfg.full_before(layer),
+                                        **kernel)
 
     def kernel_attend(self, kv_cache: KVCache, *args):
         inner = self.paged.kernel_attend(kv_cache, *args)
 
         def attend(q, k, v, lp, li, pool):
-            attn, new = inner(q, k, v, lp, li // self.period, pool)
+            attn, new = inner(q, k, v, lp, self.cfg.full_before(li), pool)
             return attn, {**pool, **new}
 
         return attend
@@ -503,9 +565,11 @@ class RecurrentKV:
         ``state``; else None: the forward collects the rows and
         ``write`` puts them). ``kernel`` (a carried pool, the rows all
         the slots): the decode step runs as the Pallas kernel over the
-        WHOLE state leaf in place — ``step(lg, state, q, k, v, g, beta,
-        active) -> (o, state)``; ``load`` then hands no ``S`` (None) and
-        ``store`` takes none."""
+        WHOLE state leaf in place — ``step(lg, state, *operands, active)
+        -> (o, state)``, the operands the configuration's member of the
+        family takes (a delta rule's ``q, k, v, g, beta``; a state-space
+        layer's ``x, dt, A, B, C, D``); ``load`` then hands no ``S``
+        (None) and ``store`` takes none."""
         def row(leaf, lg):
             if slots is None:
                 a = jax.lax.dynamic_index_in_dim(leaf, lg, 0, False)
@@ -539,19 +603,27 @@ class RecurrentKV:
                     new[name] = leaf.at[lg, slots].set(rows)
             return {**state, **new}
 
-        def step(lg, state, q, k, v, g, beta, active):
-            from ..ops.gated_delta import gated_delta_step_kernel
-            o, s = gated_delta_step_kernel(
-                q, k, v, g, beta, active, state["s"], lg,
-                interpret=jax.default_backend() != "tpu")
+        def step(lg, state, *operands):
+            if self.cfg.linear_decay == "ssd":
+                from ..ops.ssd import ssd_step_kernel as kernel
+            else:
+                from ..ops.gated_delta import \
+                    gated_delta_step_kernel as kernel
+            o, s = kernel(*operands, state["s"], lg,
+                          interpret=jax.default_backend() != "tpu")
             return o, {**state, "s": s}
 
         return (load, store if carried else None,
                 step if kernel else None)
 
     def step_kernel_supported(self) -> bool:
+        from ..ops import ssd
         from ..ops.gated_delta import step_kernel_supported
         cfg = self.cfg
+        if cfg.linear_decay == "ssd":
+            return ssd.step_kernel_supported(
+                cfg.linear_num_value_heads, cfg.linear_num_key_heads,
+                cfg.linear_value_head_dim, cfg.linear_key_head_dim)
         return step_kernel_supported(cfg.linear_num_value_heads,
                                      cfg.linear_key_head_dim,
                                      cfg.linear_value_head_dim)
@@ -594,7 +666,8 @@ def _paged_prefix_attention(q, k_self, v_self, kc, vc, ksc, vsc,
                             block_table, start, kv_valid_len, page: int,
                             cfg: LlamaConfig, block_pages: int = 8,
                             window: Optional[jax.Array] = None,
-                            layer: jax.Array | int = 0):
+                            layer: jax.Array | int = 0,
+                            scale: Optional[float] = None):
     """Chunk queries attend [pooled prefix] + [their own chunk], with the
     prefix STREAMED from the pool in ``block_pages``-page blocks under an
     online softmax.
@@ -622,6 +695,8 @@ def _paged_prefix_attention(q, k_self, v_self, kc, vc, ksc, vsc,
                   p - window < j <= p, and prefix blocks wholly behind
                   the FIRST query's window are skipped like those past
                   the prefix
+    scale:        what the scores are multiplied by where the model
+                  states it (``HeadKV.scale``); None = head_dim ** -0.5
     layer:        () int32 — the layer to read of a whole pool. A
                   block's pages are gathered by (layer, page) in ONE
                   step, over the pool's flattened leading axes: a
@@ -634,7 +709,7 @@ def _paged_prefix_attention(q, k_self, v_self, kc, vc, ksc, vsc,
     B, C, H, hd = q.shape
     KV = cfg.num_kv_heads
     G = H // KV
-    scale = 1.0 / (hd ** 0.5)
+    scale = 1.0 / (hd ** 0.5) if scale is None else scale
     P = block_table.shape[1]
     nb = -(-P // block_pages)
     tbl = jnp.pad(block_table[0], (0, nb * block_pages - P))

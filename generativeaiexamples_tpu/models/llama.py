@@ -72,6 +72,7 @@ import jax.numpy as jnp
 
 from ..ops import gated_delta as gd
 from ..ops import hyper_connection as hc
+from ..ops import ssd
 from ..ops.quant import matmul as qmm
 from ..ops.quant import matmul_f32 as qmm_f32
 from ..ops.rmsnorm import layernorm1p, rmsnorm
@@ -120,6 +121,10 @@ GDN_SCOPES = ("gdn_proj", "gdn_conv", "gdn_step", "gdn_scan", "gdn_state")
 #: rate (``cfg.linear_decay`` "channel": ``_kda_mixer``; the decode
 #: step's kernel is ``kda_delta_step``).
 KDA_SCOPES = ("kda_proj", "kda_conv", "kda_step", "kda_scan", "kda_state")
+#: The same five where the recurrent layer is a state-space layer
+#: (``cfg.linear_decay`` "ssd": ``_ssd_mixer``, ops/ssd.py; the decode
+#: step's kernel is ``ssd_step``, the chunked form plain XLA).
+SSD_SCOPES = ("ssd_proj", "ssd_conv", "ssd_step", "ssd_scan", "ssd_state")
 
 
 def _embed(params: "Params", tokens: jax.Array,
@@ -209,6 +214,17 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
                                       "unit_stream_thin_experts")
     q_gain = 16 if stream_draw else 1
     resid = 2 * cfg.num_layers if stream_draw else 1
+    # (5) What the configuration multiplies, the draw divides by, as the
+    # norm weights of ``extras`` carry the gains their norms take out: a
+    # block still adds a tenth of the stream under a residual multiplier
+    # r (``wo``, ``w_down`` and a recurrent mixer's output projection
+    # over r), and a query's scores still deviate by 4 under a stated
+    # score scale (``wq`` over its ratio to head_dim ** -0.5) — so a
+    # program that leaves either out differs at the logits by the factor.
+    if stream_draw and cfg.residual_multiplier != 1.0:
+        resid = resid * cfg.residual_multiplier ** 2
+    if stream_draw and cfg.attention_multiplier and not cfg.kv_lora_rank:
+        q_gain = q_gain / (cfg.attention_multiplier ** 2 * hd)
     # (4) "unit_stream_thin_experts": the ROUTED experts' ``w_down`` at
     # a fifth of (2), as ``post_mlp_norm`` below and for its reason.
     # Where a tree holds EVERY expert of a layer, each near-tie that
@@ -540,6 +556,45 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
             "kda_wout": norm(next(k), (L, Hv * dv, D), Hv * dv * resid),
         }
 
+    def state_space(k, L):
+        """The leaves of ``L`` state-space layers (``cfg.linear_decay``
+        "ssd"), drawn as ``mamba_ssm`` initialises what it does not
+        draw from the fan-in (assumed: the published checkpoint is not
+        read) so that a program without the decay, the step's bias or
+        the skip differs at the logits:
+
+        - ``ssd_A_log``: ``A`` uniform in [1, 16]; ``ssd_dt_bias``: the
+          inverse softplus of a step log-uniform in [1e-3, 1e-1] — a
+          head's state halves in half a token to 700; ``ssd_D`` = 1;
+        - ``ssd_win`` (z | x B C, the published order less ``dt``) at
+          the fan-in deviation; ``ssd_wdt``, the step's own column a
+          head, kept apart (it stays bf16 where the wide ones are
+          int8, as ``gdn_wba`` does) at half of it: a token moves its
+          own step by e^0.5 either way;
+        - ``ssd_conv``: every tap of deviation 0.5, ``ssd_conv_b`` 0.3;
+        - ``ssd_norm``: the gated norm's weight over the whole inner
+          width, a tenth off 1; ``ssd_wout`` writes to the stream,
+          scaled as ``wo`` is."""
+        Hv, dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
+        Ch, K = cfg.linear_channels, cfg.linear_conv_kernel_dim
+        step = jnp.exp(jax.random.uniform(
+            next(k), (L, Hv), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+        return {
+            "ssd_win": norm(next(k), (L, D, Hv * dv + Ch), D),
+            "ssd_wdt": norm(next(k), (L, D, Hv), 4 * D),
+            "ssd_conv": (0.5 * jax.random.normal(
+                next(k), (L, Ch, K), jnp.float32)).astype(dtype),
+            "ssd_conv_b": (0.3 * jax.random.normal(
+                next(k), (L, Ch), jnp.float32)).astype(dtype),
+            "ssd_A_log": jnp.log(jax.random.uniform(
+                next(k), (L, Hv), jnp.float32, 1.0, 16.0)),
+            "ssd_dt_bias": jnp.log(jnp.expm1(step)),    # softplus^-1
+            "ssd_D": jnp.ones((L, Hv), jnp.float32),
+            "ssd_norm": (1.0 + 0.1 * jax.random.normal(
+                next(k), (L, Hv * dv), jnp.float32)).astype(dtype),
+            "ssd_wout": norm(next(k), (L, Hv * dv, D), Hv * dv * resid),
+        }
+
     kx = iter(jax.random.split(jax.random.fold_in(key, 1), 32))
     layers = stack(k, L, bool(cfg.num_experts), cfg.num_dense_layers)
     layers.update(extras(kx, layers))
@@ -567,14 +622,15 @@ def init_params(cfg: LlamaConfig, key: jax.Array,
     if cfg.recurrent:
         # a stack's recurrent layers, stacked over those alone (eight
         # keys a stack of a decay a head: the committed trees' draw)
-        channel = cfg.linear_decay == "channel"
-        kr = iter(jax.random.split(
-            jax.random.fold_in(key, 6 if channel else 4),
-            (16 if channel else 8) * len(cfg.layer_stacks)))
+        draw, fold, keys = {"head": (recurrent, 4, 8),
+                            "channel": (kda, 6, 16),
+                            "ssd": (state_space, 7, 16)}[cfg.linear_decay]
+        kr = iter(jax.random.split(jax.random.fold_in(key, fold),
+                                   keys * len(cfg.layer_stacks)))
         for name, first, n in cfg.layer_stacks:
             Lg = n - sum(cfg.layer_full[first:first + n])
             if Lg:
-                params[name].update((kda if channel else recurrent)(kr, Lg))
+                params[name].update(draw(kr, Lg))
     if cfg.shared_expert_gate:
         # the shared expert's gate reads the normed stream (unit
         # entries): a pre-activation of deviation 1.5, so the gate lies
@@ -653,7 +709,7 @@ def layer_kinds(cfg: LlamaConfig, first: int = 0,
     if any(cfg.layer_windows):
         kinds["window"] = jnp.asarray(cfg.layer_windows[first:last],
                                       jnp.int32)
-    if not all(cfg.layer_rope):
+    if any(cfg.layer_rope) and not all(cfg.layer_rope):
         kinds["rope"] = jnp.asarray(cfg.layer_rope[first:last], bool)
     if cfg.index_topk:
         # a full layer's place among the MODEL's full layers (its layer
@@ -730,7 +786,7 @@ ATTENTION_WEIGHTS = ("wq", "wk", "wv", "wo", "wz", "q_norm", "k_norm",
 _ATTENTION_LEAVES = ATTENTION_WEIGHTS + (
     "cache_k", "cache_v", "cache_c", "cache_r")             # dense cache
 _RECURRENT_CACHE = ("cache_s", "cache_conv")
-RECURRENT_PREFIXES = ("gdn_", "kda_")
+RECURRENT_PREFIXES = ("gdn_", "kda_", "ssd_")
 
 
 def _by_kind(stack: dict) -> tuple[dict, dict]:
@@ -775,9 +831,14 @@ def _stack_periods(cfg: LlamaConfig, first: int, n: int) -> tuple:
     """How the model's layers ``first`` .. ``first + n`` (one stack) fall
     into periods, which are counted in the MODEL's layer indices: ``(r0,
     P, r1)`` — a head of ``r0`` recurrent layers and the attention layer
-    that ends their period (-1: the stack begins on a period's first
-    layer, or holds no attention layer), ``P`` whole periods, and ``r1``
-    recurrent layers behind the last attention layer."""
+    behind them where those are not a whole run of ``period - 1`` (-1:
+    they are, or the stack holds no attention layer), ``P`` whole runs
+    of ``period - 1`` recurrent layers and an attention layer, and
+    ``r1`` recurrent layers behind the last attention layer. A stack
+    may begin inside a period (leading dense layers in front of it), and
+    the attention layer may sit anywhere in its period
+    (``cfg.full_attention_place``): at place 5 of 10, 40 layers are a
+    head of 5, three runs of 9 and a tail of 4."""
     period = cfg.full_attention_interval
     full = [i for i in range(n) if cfg.layer_full[first + i]]
     if not full:
@@ -1563,14 +1624,14 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
         # the router reads the stream as it enters the block
         with jax.named_scope("moe_route"):
             router_logits = _router_logits(h, lp)
-    if "gdn_wqkvz" in lp or "kda_wqkv" in lp:
+    if "gdn_wqkvz" in lp or "kda_wqkv" in lp or "ssd_win" in lp:
         with jax.named_scope(cfg.recurrent_scope + "_proj"):
             x = block_norm(h, lp, "attn_norm", cfg)
         if recur is None:
             mixed, new_cache = _recurrent_mixer(cfg)(x, lp, cfg)[0], None
         else:
             mixed, new_cache = recur(x)
-        h = h + mixed
+        h = _residual(cfg, h, mixed)
     else:
         with jax.named_scope("attn_proj"):
             x = block_norm(h, lp, "attn_norm", cfg)
@@ -1613,6 +1674,8 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
                     qr, kr = apply_rope(q, k, positions, inv_freq)
                     q = jnp.where(lp["rope"], qr, q)
                     k = jnp.where(lp["rope"], kr, k)
+                elif not any(cfg.layer_rope):
+                    pass        # no layer rotates: nothing of it is traced
                 elif cfg.partial_rotary_factor != 1.0:
                     q, k = apply_rope_partial(q, k, positions, inv_freq)
                 else:
@@ -1644,7 +1707,7 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
             if cfg.post_norms:
                 attn_out = block_norm(attn_out, lp, "post_attn_norm", cfg)
             if not hyper:
-                h = h + attn_out
+                h = _residual(cfg, h, attn_out)
     if hyper:
         stream = hc.hc_post(stream, attn_out, h_post, h_res)
         h, h_post, h_res = hc.hc_pre(stream, _hc_weights(lp, "mlp"),
@@ -1674,7 +1737,7 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
             if cfg.post_norms:
                 mlp = block_norm(mlp, lp, "post_mlp_norm", cfg)
             if not hyper:
-                return h + mlp, new_cache
+                return _residual(cfg, h, mlp), new_cache
         return hc.hc_post(stream, mlp, h_post, h_res), new_cache
     with jax.named_scope("mlp"):
         x = block_norm(h, lp, "mlp_norm", cfg)
@@ -1682,8 +1745,18 @@ def decoder_layer(h: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
         if cfg.post_norms:
             mlp = block_norm(mlp, lp, "post_mlp_norm", cfg)
         if not hyper:
-            return h + mlp, new_cache
+            return _residual(cfg, h, mlp), new_cache
     return hc.hc_post(stream, mlp, h_post, h_res), new_cache
+
+
+def _residual(cfg: LlamaConfig, h: jax.Array, out: jax.Array) -> jax.Array:
+    """The stream plus what a sub-block writes to it, times the
+    configuration's ``residual_multiplier`` (1.0: the plain sum, and
+    nothing else in the program)."""
+    if cfg.residual_multiplier == 1.0:
+        return h + out
+    return (h.astype(jnp.float32) + out.astype(jnp.float32)
+            * cfg.residual_multiplier).astype(h.dtype)
 
 
 def _gdn_mixer(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
@@ -1859,10 +1932,79 @@ def _kda_mixer(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
     return mixed, new, tail
 
 
+def _ssd_mixer(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
+               state: Optional[jax.Array] = None,
+               tail: Optional[jax.Array] = None,
+               n_valid: Optional[jax.Array] = None, step_kernel=None):
+    """``_gdn_mixer`` for a state-space layer (``cfg.linear_decay``
+    "ssd": Mamba-2, ops/ssd.py), the same arguments, results and rules
+    for what is not the sequence's (its step size ``dt`` is 0 there: it
+    decays nothing and writes nothing): ``z | x B C`` one projection and
+    the step's own column a head another, the convolution (with a bias)
+    over x, B and C, ``dt = softplus(. + dt_bias)`` (float32, no clamp),
+    ``A = -exp(A_log)``, the state (B, H, P, N) — N on the lanes —, the
+    output gated by ``silu(z)`` BEFORE one RMS norm over a group's whole
+    inner width (``_gated_head_norm`` norms a head and then gates), and
+    projected back. S == 1 is the decode step (``ssd.ssd_step``, or the
+    cache object's kernel over its own leaf: ``step_kernel(x, dt, A, B,
+    C, D, active) -> (y, state)``), anything longer the chunked form
+    ``ssd.ssd_chunked``, plain XLA."""
+    B, S, _ = x.shape
+    G, H = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    N, P = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    Ch, f32, inner = cfg.linear_channels, jnp.float32, H * P
+    if tail is None:        # a sequence's start
+        state = jnp.zeros((B, H, P, N), f32)
+        tail = jnp.zeros((B, cfg.linear_conv_kernel_dim - 1, Ch), x.dtype)
+    with jax.named_scope("ssd_proj"):
+        zu = qmm(x, lp["ssd_win"])
+        dt = qmm_f32(x, lp["ssd_wdt"])
+        # the head split stays out of the matmul (``decoder_layer``)
+        zu, dt = jax.lax.optimization_barrier((zu, dt))
+        z, u = zu[..., :inner], zu[..., inner:]
+    with jax.named_scope("ssd_conv"):
+        u, tail = gd.causal_conv(u, tail, lp["ssd_conv"], n_valid,
+                                 lp["ssd_conv_b"])
+    step = S == 1
+    with jax.named_scope("ssd_step" if step else "ssd_scan"):
+        xs = u[..., :inner].reshape(B, S, H, P)
+        Bm = u[..., inner:inner + G * N].reshape(B, S, G, N)
+        Cm = u[..., inner + G * N:].reshape(B, S, G, N)
+        dt = jax.nn.softplus(dt + lp["ssd_dt_bias"].astype(f32))
+        if n_valid is not None:
+            dt = jnp.where((jnp.arange(S)[None, :]
+                            < n_valid[:, None])[..., None], dt, 0.0)
+        A = -jnp.exp(lp["ssd_A_log"].astype(f32))
+        D = lp["ssd_D"].astype(f32)
+        if not step:
+            y, new = ssd.ssd_chunked(xs, dt, A, Bm, Cm, D, state)
+        elif step_kernel is not None:
+            y, new = step_kernel(
+                xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], D,
+                jnp.ones((B,), bool) if n_valid is None else n_valid > 0)
+            y = y[:, None]
+        else:
+            y, new = ssd.ssd_step(xs[:, 0], dt[:, 0], A, Bm[:, 0],
+                                  Cm[:, 0], D, state)
+            y = y[:, None]
+            if n_valid is not None:     # an idle row's, bit for bit
+                new = jnp.where((n_valid > 0)[:, None, None, None], new,
+                                state)
+    with jax.named_scope("ssd_proj"):
+        y = (y.reshape(B, S, inner) * jax.nn.silu(z.astype(f32))).reshape(
+            B, S, G, inner // G)
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True)
+                              + cfg.rms_norm_eps)
+        y = y.reshape(B, S, inner) * lp["ssd_norm"].astype(f32)
+        mixed = qmm(y.astype(x.dtype), lp["ssd_wout"])
+    return mixed, new, tail
+
+
 def _recurrent_mixer(cfg: LlamaConfig):
     """The recurrent layers' token mixer: the member of the family the
     configuration states (``linear_decay``)."""
-    return _kda_mixer if cfg.linear_decay == "channel" else _gdn_mixer
+    return {"head": _gdn_mixer, "channel": _kda_mixer,
+            "ssd": _ssd_mixer}[cfg.linear_decay]
 
 
 def _gdn_recur(cfg: LlamaConfig, load, store=None, step=None,
@@ -1875,7 +2017,7 @@ def _gdn_recur(cfg: LlamaConfig, load, store=None, step=None,
 
     def recur(x, lp, li, state):
         li = li.reshape(())
-        lg = li - li // cfg.full_attention_interval
+        lg = li - cfg.full_before(li)
         scope = cfg.recurrent_scope + (
             "_step" if x.shape[1] == 1 else "_scan")
         with jax.named_scope(scope):
@@ -2022,9 +2164,17 @@ def unembed_norm(params: Params, cfg: LlamaConfig, h: jax.Array
     projection itself via ``lm_head_tile``."""
     with jax.named_scope("tail"):
         if cfg.norm == "layernorm1p":
-            return layernorm1p(h, params["final_norm"],
-                               params["final_norm_b"], cfg.rms_norm_eps)
-        return rmsnorm(h, params["final_norm"], cfg.rms_norm_eps)
+            hn = layernorm1p(h, params["final_norm"],
+                             params["final_norm_b"], cfg.rms_norm_eps)
+        else:
+            hn = rmsnorm(h, params["final_norm"], cfg.rms_norm_eps)
+        if cfg.logits_divisor != 1.0:
+            # the logits over a stated constant: on the normed row, so
+            # every tail's logits (and what penalties, temperature and
+            # ``score`` read) are the divided ones
+            hn = (hn.astype(jnp.float32)
+                  * (1.0 / cfg.logits_divisor)).astype(hn.dtype)
+        return hn
 
 
 # lm_head QTensor leaves sliced along the vocab (output) axis; K-axis

@@ -30,7 +30,8 @@ _CHUNK = 512  # key-block size for the online-softmax path
 def gqa_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                   q_positions: jax.Array, kv_valid_len: jax.Array | None = None,
                   *, causal: bool = True,
-                  window: jax.Array | None = None) -> jax.Array:
+                  window: jax.Array | None = None,
+                  scale: float | None = None) -> jax.Array:
     """Grouped-query attention over an absolute-position KV buffer.
 
     q_positions: (B, S) int32 — absolute position of each query token.
@@ -41,6 +42,8 @@ def gqa_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         position i), which is what the slotted cache guarantees.
     window: () int32 (traced: a layer's own) or None — the query at
         position p attends only keys p - window < i <= p; 0 = no window.
+    scale: what the scores are multiplied by where the model states it
+        (``LlamaConfig.score_scale``); None = head_dim ** -0.5.
 
     Long key buffers take a flash-style chunked path: keys are consumed in
     ``_CHUNK`` blocks with an online softmax, so peak memory holds one
@@ -53,9 +56,10 @@ def gqa_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     chunk = next((c for c in (_CHUNK, 256, 128) if T % c == 0), None)
     if T > _CHUNK and chunk is not None:
         return _gqa_chunked(q, k, v, q_positions, kv_valid_len,
-                            causal=causal, chunk=chunk, window=window)
+                            causal=causal, chunk=chunk, window=window,
+                            scale=scale)
     return _gqa_dense(q, k, v, q_positions, kv_valid_len, causal=causal,
-                      window=window)
+                      window=window, scale=scale)
 
 
 def _window_mask(mask, key_idx, q_positions, window):
@@ -66,11 +70,12 @@ def _window_mask(mask, key_idx, q_positions, window):
     return mask & (key_idx[None, None, :] >= lo[:, :, None])
 
 
-def _gqa_dense(q, k, v, q_positions, kv_valid_len, *, causal, window=None):
+def _gqa_dense(q, k, v, q_positions, kv_valid_len, *, causal, window=None,
+               scale=None):
     B, S, H, hd = q.shape
     _, T, KV, _ = k.shape
     G = H // KV
-    scale = 1.0 / (hd ** 0.5)
+    scale = 1.0 / (hd ** 0.5) if scale is None else scale
 
     qf = q.astype(jnp.float32).reshape(B, S, KV, G, hd)
     kf = k.astype(jnp.float32)
@@ -94,14 +99,14 @@ def _gqa_dense(q, k, v, q_positions, kv_valid_len, *, causal, window=None):
 
 
 def _gqa_chunked(q, k, v, q_positions, kv_valid_len, *, causal, chunk,
-                 window=None):
+                 window=None, scale=None):
     """Online-softmax over key blocks. Operands stay in their storage
     dtype into the MXU (f32 accumulation via preferred_element_type) —
     casting whole K/V to f32 up front doubled their HBM traffic."""
     B, S, H, hd = q.shape
     _, T, KV, _ = k.shape
     G = H // KV
-    scale = 1.0 / (hd ** 0.5)
+    scale = 1.0 / (hd ** 0.5) if scale is None else scale
     qr = q.reshape(B, S, KV, G, hd)
     n_blocks = T // chunk
 

@@ -64,10 +64,12 @@ _HI = jax.lax.Precision.HIGHEST
 
 
 def causal_conv(u: jax.Array, tail: jax.Array, weight: jax.Array,
-                n_valid: Optional[jax.Array] = None
+                n_valid: Optional[jax.Array] = None,
+                bias: Optional[jax.Array] = None
                 ) -> tuple[jax.Array, jax.Array]:
-    """Depthwise causal convolution with SiLU, no bias: ``out_t =
-    silu(sum_j weight[:, j] * ext[t + j])`` over ``ext`` = the sequence's
+    """Depthwise causal convolution with SiLU: ``out_t = silu(sum_j
+    weight[:, j] * ext[t + j] (+ bias))`` (``bias`` (Ch,), where the
+    model has one) over ``ext`` = the sequence's
     last ``K - 1`` inputs (``tail`` (B, K - 1, Ch); zeros before the
     sequence) followed by ``u`` (B, T, Ch). Returns float32 (B, T, Ch)
     and the new tail: the ``K - 1`` inputs up to the row's last VALID
@@ -84,6 +86,8 @@ def causal_conv(u: jax.Array, tail: jax.Array, weight: jax.Array,
     else:
         new_tail = jax.vmap(lambda e, n: jax.lax.dynamic_slice(
             e, (n, 0), (K - 1, Ch)))(ext, jnp.clip(n_valid, 0, T))
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
     return jax.nn.silu(out), new_tail.astype(tail.dtype)
 
 

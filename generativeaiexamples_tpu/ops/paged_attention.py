@@ -85,9 +85,12 @@ def paged_attention_decode(q: jax.Array, pool_k: jax.Array,
                            *, pool_ks: jax.Array | None = None,
                            pool_vs: jax.Array | None = None,
                            interpret: bool = False,
-                           window: jax.Array | None = None):
+                           window: jax.Array | None = None,
+                           scale: float | None = None):
     """GQA decode attention + KV append over a paged pool, one query token
-    per slot.
+    per slot. ``scale``: what the scores are multiplied by where the
+    model states it (``LlamaConfig.score_scale``); None = head_dim **
+    -0.5.
 
     q:            (B, H, hd)           current token's queries
     pool_k/v:     (L, N, KV, page, hd) shared page pool, all layers (the
@@ -137,13 +140,13 @@ def paged_attention_decode(q: jax.Array, pool_k: jax.Array,
     B, H, hd = q.shape
     L, N, KV, page, _ = pool_k.shape
     G = H // KV
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     quant = pool_ks is not None
     if quant:
         return _paged_attention_decode_quant(
             q, pool_k, pool_v, pool_ks, pool_vs, block_table, lengths,
             cur_k, cur_v, write_page, write_offset, layer,
-            interpret=interpret, window=window)
+            interpret=interpret, window=window, scale=scale)
     Gs = group_size(B)
     windowed = window is not None
 
@@ -377,7 +380,8 @@ def _first_key(length, window):
 def _paged_attention_decode_quant(q, pool_k, pool_v, pool_ks, pool_vs,
                                   block_table, lengths, cur_k, cur_v,
                                   write_page, write_offset, layer,
-                                  *, interpret=False, window=None):
+                                  *, interpret=False, window=None,
+                                  scale=None):
     """int8-KV variant of the decode kernel (see paged_attention_decode).
 
     Same slot-grouped program structure — flat cross-slot page loop,
@@ -408,7 +412,7 @@ def _paged_attention_decode_quant(q, pool_k, pool_v, pool_ks, pool_vs,
     B, H, hd = q.shape
     L, N, KV, page, _ = pool_k.shape
     G = H // KV
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     cd = q.dtype  # compute dtype for the MXU dots
     Gs = group_size(B)
     windowed = window is not None
@@ -667,7 +671,8 @@ def _paged_attention_decode_quant(q, pool_k, pool_v, pool_ks, pool_vs,
 
 
 def paged_attention_decode_reference(q, pool_k, pool_v, block_table,
-                                     lengths, cur_k, cur_v, window=0):
+                                     lengths, cur_k, cur_v, window=0,
+                                     scale=None):
     """Pure-jnp attention oracle with identical masking/softmax semantics
     (tests + non-TPU backends); the pool append is left to the caller.
     This is the gather formulation the kernel replaces."""
@@ -675,7 +680,7 @@ def paged_attention_decode_reference(q, pool_k, pool_v, block_table,
     N, KV, page, _ = pool_k.shape
     W = block_table.shape[1]
     G = H // KV
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
 
     kg = pool_k[block_table].swapaxes(2, 3).reshape(B, W * page, KV, hd)
     vg = pool_v[block_table].swapaxes(2, 3).reshape(B, W * page, KV, hd)

@@ -50,7 +50,10 @@ _QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "wz", "w_gate", "w_up",
                      # and where the decay is a channel's: q, k and v, the
                      # decay's and the output gate's projections, the
                      # output (``kda_wb``, a column a head, stays)
-                     "kda_wqkv", "kda_wf", "kda_wg", "kda_wout")
+                     "kda_wqkv", "kda_wf", "kda_wg", "kda_wout",
+                     # a state-space layer's two wide ones (``ssd_wdt``,
+                     # the step's column a head, stays)
+                     "ssd_win", "ssd_wout")
 _LAYER_STACKS = ("layers", "dense_layers")
 
 

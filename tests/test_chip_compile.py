@@ -1218,3 +1218,27 @@ def test_kda_latent_decode_step_compiles(topo, tpu_backend):
                               sds((B,), jnp.bool_, dev))[2]) == {
         "experts_touched", "local_assignments", "route_rows_read",
         "route_groups_held_pct"}
+
+
+def test_ssd_step_kernel_compiles(topo):
+    """The decode step of a state-space layer over the cache's whole
+    state leaf at granite-4.0-h-micro's widths (36 layers x 32 slots x
+    64 heads of 64 x 128, N on the lanes), in place: the 2.4 GB leaf is
+    aliased through the call and appears once in the program."""
+    from generativeaiexamples_tpu.ops.ssd import (ssd_step_kernel,
+                                                  step_kernel_supported)
+    dev = SingleDeviceSharding(topo.devices[0])
+    Lg, B, H, P, N = 36, 32, 64, 64, 128
+    assert step_kernel_supported(H, 1, P, N)
+    f32 = jnp.float32
+    args = (sds((B, H, P), f32, dev), sds((B, H), f32, dev),
+            sds((H,), f32, dev), sds((B, 1, N), f32, dev),
+            sds((B, 1, N), f32, dev), sds((H,), f32, dev),
+            sds((B,), jnp.bool_, dev), sds((Lg, B, H, P, N), f32, dev),
+            sds((), jnp.int32, dev))
+    compiled = jax.jit(ssd_step_kernel, donate_argnums=(7,)).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert "ssd_step" in text and "tpu_custom_call" in text
+    # no second copy of the leaf beside the donated one
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

@@ -38,14 +38,23 @@ def prompt(n, seed):
     return [int(t) for t in np.random.default_rng(seed).integers(3, 250, n)]
 
 
+@jax.jit
+def _logits(params, ids, n):
+    out, _ = llama.apply(params, CFG, ids[None],
+                         jnp.arange(ids.shape[0])[None])
+    return out[0, n - 1]
+
+
 def plain_greedy(params, ids, n):
-    """The plain forward's own greedy chain, no cache."""
-    ids = list(ids)
-    for _ in range(n):
-        logits, _ = llama.apply(params, CFG, jnp.asarray(ids)[None],
-                                jnp.arange(len(ids))[None])
-        ids.append(int(jnp.argmax(logits[0, -1])))
-    return ids[-n:]
+    """The plain forward's own greedy chain, no cache: ONE compiled
+    program over a fixed buffer (the longest prompt here is 447 tokens),
+    a forward a token (causal: what follows a position does not move
+    it) — a forward a LENGTH compiled the model once a token."""
+    buf = np.zeros(512, np.int32)
+    buf[:len(ids)] = ids
+    for at in range(len(ids), len(ids) + n):
+        buf[at] = int(jnp.argmax(_logits(params, jnp.asarray(buf), at)))
+    return [int(t) for t in buf[len(ids):len(ids) + n]]
 
 
 def serve(engine, ids, n=N_OUT):

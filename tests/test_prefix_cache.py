@@ -190,13 +190,23 @@ def _build(prompt_cap=None, pool_tokens=None, prefix=True, kv_quant="",
     return Engine(params, CFG, ByteTokenizer(), cfg), params
 
 
+@jax.jit
+def _padded_logits(params, ids):
+    out, _ = llama.apply(params, CFG, ids[None],
+                         jnp.arange(ids.shape[0], dtype=jnp.int32)[None])
+    return out[0]
+
+
 def _greedy_reference(params, prompt_ids, n_steps):
     ids = list(prompt_ids)
+    # ONE compiled program a padded length (causal: what follows a
+    # position does not move it): a forward a LENGTH compiled the model
+    # once a token
+    length = -(-(len(ids) + n_steps) // 64) * 64
     for _ in range(n_steps):
-        tokens = jnp.asarray(np.asarray(ids, np.int32)[None, :])
-        pos = jnp.arange(len(ids), dtype=jnp.int32)[None, :]
-        logits, _ = llama.apply(params, CFG, tokens, pos)
-        ids.append(int(jnp.argmax(logits[0, -1])))
+        logits = _padded_logits(params, jnp.asarray(
+            ids + [0] * (length - len(ids)), jnp.int32))
+        ids.append(int(jnp.argmax(logits[len(ids) - 1])))
     return ids[len(prompt_ids):]
 
 

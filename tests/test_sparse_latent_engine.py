@@ -192,7 +192,7 @@ def test_the_read_per_selected_metric_names_its_reader_and_its_cell():
     entry = next(m for m in spec.doc["per_layer"] if m["name"] == name)
     names = [m["name"] for m in spec.doc["per_layer"]]     # appended: after
     assert names.index(name) > names.index("sparse_selected_pct")  # PR 40's
-    assert entry["workloads"] == ["glm-5.2.long-context-mixed-16"]
+    assert entry["workloads"][0] == "glm-5.2.long-context-mixed-16"
     assert (entry["moves"], entry["layer"], entry["unit"], entry["source"],
             entry["better"]) == ("out_tok_per_s", "kernels", "count",
                                  "program_counter", "lower")

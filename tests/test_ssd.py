@@ -1,0 +1,142 @@
+"""ops/ssd.py: a state-space layer's recurrence (Mamba-2: a scalar decay
+a head, no delta rule) in its four forms, float32 on the CPU: the
+recurrence token by token is what the chunked form is held to at every
+block size and ragged length, the step what the Pallas kernel over the
+cache's whole state leaf is held to (interpreted here), in place, an
+idle row's state left bit for bit."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from generativeaiexamples_tpu.ops import ssd
+
+
+def draw(seed, B, T, H=4, P=8, N=16, G=1, steps="mixed", equal=False):
+    ks = jax.random.split(jax.random.key(seed), 6)
+    x = jax.random.normal(ks[0], (B, T, H, P))
+    Bm = jax.random.normal(ks[1], (B, T, G, N))
+    Cm = jax.random.normal(ks[2], (B, T, G, N))
+    if equal:   # a run of one repeated token: a page of spaces
+        x, Bm, Cm = (jnp.broadcast_to(a[:, :1], a.shape)
+                     for a in (x, Bm, Cm))
+    lo, hi = {"mixed": (-7.0, -2.0), "tiny": (-30.0, -25.0),
+              "large": (0.0, 2.0)}[steps]
+    dt = jax.nn.softplus(jax.random.uniform(ks[3], (B, T, H), minval=lo,
+                                            maxval=hi))
+    A = -jax.random.uniform(ks[4], (H,), minval=1.0, maxval=16.0)
+    s = jax.random.normal(ks[5], (B, H, P, N))
+    return x, dt, A, Bm, Cm, jnp.ones((H,)), s
+
+
+def close(got, want, tol=1e-5):
+    scale = float(jnp.max(jnp.abs(want))) or 1.0
+    assert float(jnp.max(jnp.abs(got - want))) <= tol * scale
+
+
+@pytest.mark.parametrize("T,block,G", [
+    (1, 4, 1), (7, 4, 2), (64, 16, 1), (100, 64, 1), (100, 16, 2),
+    (33, 8, 1)])
+def test_the_chunked_form_is_the_recurrence(T, block, G):
+    args = draw(T + block, 2, T, G=G)
+    y0, s0 = ssd.ssd_recurrent(*args)
+    y1, s1 = ssd.ssd_chunked(*args, block=block)
+    close(y1, y0)
+    close(s1, s0)
+
+
+@pytest.mark.parametrize("steps,equal", [
+    ("mixed", True), ("tiny", False), ("tiny", True), ("large", False)],
+    ids=["equal_tokens", "tiny_steps", "tiny_equal", "large_steps"])
+def test_runs_that_cancel_or_underflow_stay_the_recurrence(steps, equal):
+    """Every exponent is a difference taken before the ``exp``: a run of
+    large steps (exp(-32 a token): a block's total underflows) and a run
+    of tiny ones (every decay 1 - 1e-12) are the recurrence still."""
+    args = draw(5, 1, 96, steps=steps, equal=equal)
+    y0, s0 = ssd.ssd_recurrent(*args)
+    y1, s1 = ssd.ssd_chunked(*args, block=32)
+    assert bool(jnp.all(jnp.isfinite(y1))) and bool(jnp.all(jnp.isfinite(s1)))
+    close(y1, y0)
+    close(s1, s0)
+
+
+def test_the_step_is_the_written_equations():
+    x, dt, A, Bm, Cm, D, s = draw(3, 2, 1)
+    y, new = ssd.ssd_step(x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], D, s)
+    for b in range(2):
+        for h in range(4):
+            S = np.exp(float(dt[b, 0, h] * A[h])) * np.asarray(s[b, h]) \
+                + float(dt[b, 0, h]) * np.outer(x[b, 0, h], Bm[b, 0, 0])
+            np.testing.assert_allclose(new[b, h], S, rtol=1e-5, atol=1e-6)
+            np.testing.assert_allclose(
+                y[b, h], S @ np.asarray(Cm[b, 0, 0]) + np.asarray(x[b, 0, h]),
+                rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("T,at", [(96, 40), (64, 64)])
+def test_two_chunks_carry_the_state(T, at):
+    x, dt, A, Bm, Cm, D, s = draw(11, 2, T)
+    y0, s0 = ssd.ssd_chunked(x, dt, A, Bm, Cm, D, s, block=16)
+    ya, mid = ssd.ssd_chunked(x[:, :at], dt[:, :at], A, Bm[:, :at],
+                              Cm[:, :at], D, s, block=16)
+    if at == T:
+        close(mid, s0, 1e-6)
+        return
+    yb, s1 = ssd.ssd_chunked(x[:, at:], dt[:, at:], A, Bm[:, at:],
+                             Cm[:, at:], D, mid, block=16)
+    close(jnp.concatenate([ya, yb], 1), y0)
+    close(s1, s0)
+
+
+@pytest.mark.parametrize("T,n", [(70, 50), (32, 0)])
+def test_a_step_of_zero_leaves_the_state_alone(T, n):
+    """How the caller pads: tokens whose ``dt`` is 0 decay nothing and
+    write nothing, whatever their x, B and C hold."""
+    x, dt, A, Bm, Cm, D, s = draw(13, 2, T)
+    dt = jnp.where(jnp.arange(T)[None, :, None] < n, dt, 0.0)
+    _, got = ssd.ssd_chunked(x, dt, A, Bm, Cm, D, s, block=16)
+    if n:
+        _, want = ssd.ssd_recurrent(x[:, :n], dt[:, :n], A, Bm[:, :n],
+                                    Cm[:, :n], D, s)
+        close(got, want)
+    else:
+        np.testing.assert_array_equal(got, s)
+
+
+def test_the_step_kernel_is_the_step_in_place():
+    """The kernel over the WHOLE leaf (3 layers x 3 slots), layer 1: the
+    live rows' state and read-out are the step's, the idle row's state
+    and every other layer's are bit for bit what they were."""
+    x, dt, A, Bm, Cm, D, s = draw(17, 3, 1, N=128)
+    leaf = jnp.stack([s, 2.0 * s, 3.0 * s])
+    active = jnp.asarray([True, False, True])
+    dt1 = jnp.where(active[:, None], dt[:, 0], 0.0)
+    y, new = jax.jit(lambda *a: ssd.ssd_step_kernel(*a, interpret=True))(
+        x[:, 0], dt1, A, Bm[:, 0], Cm[:, 0], D, active, leaf, jnp.int32(1))
+    y0, s0 = ssd.ssd_step(x[:, 0], dt1, A, Bm[:, 0], Cm[:, 0], D, leaf[1])
+    close(y[active], y0[active])
+    close(new[1][active], s0[active], 1e-6)
+    np.testing.assert_array_equal(new[1, 1], leaf[1, 1])
+    np.testing.assert_array_equal(new[0], leaf[0])
+    np.testing.assert_array_equal(new[2], leaf[2])
+
+
+def test_an_idle_rows_garbage_stays_garbage():
+    """A stale slot may hold anything (inf): the idle row is copied, not
+    multiplied by one."""
+    x, dt, A, Bm, Cm, D, s = draw(19, 2, 1, N=128)
+    leaf = s.at[1].set(jnp.inf)[None]
+    active = jnp.asarray([True, False])
+    _, new = ssd.ssd_step_kernel(
+        x[:, 0], jnp.where(active[:, None], dt[:, 0], 0.0), A, Bm[:, 0],
+        Cm[:, 0], D, active, leaf, jnp.int32(0), interpret=True)
+    np.testing.assert_array_equal(new[0, 1], leaf[0, 1])
+    assert bool(jnp.all(jnp.isfinite(new[0, 0])))
+
+
+def test_the_kernel_is_taken_only_where_it_fits():
+    assert ssd.step_kernel_supported(64, 1, 64, 128)    # granite-4.0-h
+    assert not ssd.step_kernel_supported(64, 8, 64, 128)    # B, C a group
+    assert not ssd.step_kernel_supported(64, 1, 64, 64)     # half the lanes
+    assert not ssd.step_kernel_supported(128, 1, 128, 128)  # 8 MiB a row
