@@ -230,10 +230,10 @@ _STATS_TEMPLATE = {
     "tail_kernel": 0,
     "tail_kernel_rounds": 0,
     # 1 where the chunk programs' recurrence (a model with recurrent
-    # layers: ops/gated_delta.py) runs the chunked scan as ONE Pallas
-    # kernel (a TPU, whole 64-token blocks, 128-lane heads in pairs — in
-    # groups of eight where the decay is a channel's), 0 where it runs
-    # the XLA stages or there is no recurrent layer.
+    # layers: ops/gated_delta.py, ops/ssd.py) runs the chunked scan as ONE
+    # Pallas kernel (a TPU, whole 64-token blocks, 128-lane heads in pairs
+    # — in eights where the decay is a channel's; 64-value heads, sixteen
+    # a step, a state-space layer's), 0 where it runs XLA stages or none.
     # Static per engine; a TPU engine with recurrent layers that reads 0
     # also counts a downgrade. ``scan_kernel_chunks``: the chunk
     # programs dispatched whose recurrence ran it (every one of an

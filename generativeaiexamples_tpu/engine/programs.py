@@ -31,7 +31,7 @@ from jax.sharding import Mesh
 from ..models import llama
 from ..models.configs import LlamaConfig
 from ..models.kv_cache import kv_cache_of
-from ..ops import gated_delta, head_argmax
+from ..ops import gated_delta, head_argmax, ssd
 from ..ops.fused_sampler import (choose_tile, fused_unembed_sample,
                                  fused_unembed_sample_tp,
                                  fused_verify_sample,
@@ -382,8 +382,8 @@ class ProgramSpec:
     # (llama.layer_stat_names): dropless experts, hyper-connections
     layer_stats: bool
     # a recurrent layer's chunked scan as the Pallas kernel
-    # (ops/gated_delta.py) in every chunk program: the mixer reads it
-    # off a chunk's shapes, and every chunk is whole pages
+    # (ops/gated_delta.py, ops/ssd.py) in every chunk program: the mixer
+    # reads it off a chunk's shapes, and every chunk is whole pages
     scan_kernel: bool
     tail: Tail
     # (feature, fallback, reason) of every gate that resolved below the
@@ -424,28 +424,35 @@ class ProgramSpec:
                 f"{model_cfg.qk_rope_head_dim}: the chunk kernel takes "
                 f"lane-width pages and keys, whole sublane tiles of values"))
         scan_kernel = False
-        # either decay's chunked scan has its kernel (ops/gated_delta.py
-        # ``gated_delta_chunked_kernel``, ``kda_chunked_kernel``): which
-        # is the configuration's ``linear_decay``, and each reads its
-        # path off the shapes; a state-space layer's (``"ssd"``) has none
-        # yet, so none is wanted and none is missed
-        if model_cfg.recurrent and model_cfg.linear_decay != "ssd":
-            channel = model_cfg.linear_decay == "channel"
-            heads = (model_cfg.linear_num_key_heads,
-                     model_cfg.linear_num_value_heads)
-            widths = (model_cfg.linear_key_head_dim,
+        # every member's chunked scan has its kernel (ops/gated_delta.py
+        # ``gated_delta_chunked_kernel``, ``kda_chunked_kernel``;
+        # ops/ssd.py ``ssd_chunked_kernel``): which is the
+        # configuration's ``linear_decay``, and each reads its path off
+        # the shapes (a state-space layer's heads are the value heads,
+        # its groups of B and C the key heads, its state's width the keys')
+        if model_cfg.recurrent:
+            Hk, Hv = (model_cfg.linear_num_key_heads,
+                      model_cfg.linear_num_value_heads)
+            dk, dv = (model_cfg.linear_key_head_dim,
                       model_cfg.linear_value_head_dim)
-            scan_kernel = gated_delta.kda_scan_kernel_armed(
-                page_size, heads[1], *widths) if channel \
-                else gated_delta.scan_kernel_armed(page_size, *heads, *widths)
+            scan_kernel, takes = {
+                "head": (gated_delta.scan_kernel_armed(
+                    page_size, Hk, Hv, dk, dv),
+                    "128-lane heads, two value heads a key head, in whole "
+                    "groups"),
+                "channel": (gated_delta.kda_scan_kernel_armed(
+                    page_size, Hv, dk, dv),
+                    "128-lane heads, in whole groups of eight"),
+                "ssd": (ssd.scan_kernel_armed(page_size, Hv, Hk, dv, dk),
+                        "one group of B and C, 64-value heads over 128-lane "
+                        "states, in whole groups of sixteen"),
+            }[model_cfg.linear_decay]
             if not scan_kernel and jax.default_backend() == "tpu":
                 downgrades.append((
                     "scan_kernel", "xla_chunked",
-                    f"page {page_size}, key / value heads {heads[0]} / "
-                    f"{heads[1]} of {widths[0]} / {widths[1]}: the scan "
-                    f"kernel takes whole 64-token blocks and 128-lane heads, "
-                    + ("in whole groups of eight" if channel else
-                       "two value heads a key head, in whole groups")))
+                    f"page {page_size}, key / value heads {Hk} / {Hv} of "
+                    f"{dk} / {dv}: the scan kernel takes whole 64-token "
+                    f"blocks and {takes}"))
         tail = resolve_tail(params, model_cfg, mesh)
         if tail.downgrade:
             downgrades.append(tail.downgrade)

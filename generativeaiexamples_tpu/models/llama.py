@@ -1947,8 +1947,11 @@ def _ssd_mixer(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
     inner width (``_gated_head_norm`` norms a head and then gates), and
     projected back. S == 1 is the decode step (``ssd.ssd_step``, or the
     cache object's kernel over its own leaf: ``step_kernel(x, dt, A, B,
-    C, D, active) -> (y, state)``), anything longer the chunked form
-    ``ssd.ssd_chunked``, plain XLA."""
+    C, D, active) -> (y, state)``), anything longer the chunked form: ONE
+    Pallas kernel (``ssd.ssd_chunked_kernel``) where
+    ``ssd.scan_kernel_armed`` says so of the shapes (a TPU, whole
+    64-token blocks, one group, 64-value heads over 128-lane states),
+    else the XLA form ``ssd.ssd_chunked``."""
     B, S, _ = x.shape
     G, H = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     N, P = cfg.linear_key_head_dim, cfg.linear_value_head_dim
@@ -1966,6 +1969,8 @@ def _ssd_mixer(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
         u, tail = gd.causal_conv(u, tail, lp["ssd_conv"], n_valid,
                                  lp["ssd_conv_b"])
     step = S == 1
+    # the scan as the kernel, where it takes the shapes here
+    scan_kernel = not step and ssd.scan_kernel_armed(S, H, G, P, N)
     with jax.named_scope("ssd_step" if step else "ssd_scan"):
         xs = u[..., :inner].reshape(B, S, H, P)
         Bm = u[..., inner:inner + G * N].reshape(B, S, G, N)
@@ -1976,7 +1981,11 @@ def _ssd_mixer(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
                             < n_valid[:, None])[..., None], dt, 0.0)
         A = -jnp.exp(lp["ssd_A_log"].astype(f32))
         D = lp["ssd_D"].astype(f32)
-        if not step:
+        if scan_kernel:
+            # x read where the convolution left it, no slice in between
+            y, new = ssd.ssd_chunked_kernel(u, dt, A, Bm[:, :, 0],
+                                            Cm[:, :, 0], D, state)
+        elif not step:
             y, new = ssd.ssd_chunked(xs, dt, A, Bm, Cm, D, state)
         elif step_kernel is not None:
             y, new = step_kernel(

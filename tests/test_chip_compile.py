@@ -1242,3 +1242,31 @@ def test_ssd_step_kernel_compiles(topo):
     assert "ssd_step" in text and "tpu_custom_call" in text
     # no second copy of the leaf beside the donated one
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("rows", [1, 4])
+def test_ssd_scan_kernel_compiles(topo, rows):
+    """The chunked form of a state-space layer as ONE kernel at
+    granite-4.0-h-micro's widths (a 512-token chunk of one prompt and of
+    four, 64 heads of 64 x 128, one group; ``x`` read out of the
+    convolution's whole output): Mosaic takes the float32 products at
+    HIGHEST precision, the contraction over tokens in the state's write,
+    the read-out against the state transposed, the 0 / 1 spreads and a
+    pair's (8, 128) tile of decays turned; nothing but the operands and
+    results is on the program's books."""
+    from generativeaiexamples_tpu.ops.ssd import (scan_kernel_supported,
+                                                  ssd_chunked_kernel)
+    dev = SingleDeviceSharding(topo.devices[0])
+    T, H, P, N = 512, 64, 64, 128
+    assert scan_kernel_supported(T, H, 1, P, N)
+    f32 = jnp.float32
+    args = (sds((rows, T, H * P + 2 * N), f32, dev),
+            sds((rows, T, H), f32, dev),
+            sds((H,), f32, dev), sds((rows, T, N), f32, dev),
+            sds((rows, T, N), f32, dev), sds((H,), f32, dev),
+            sds((rows, H, P, N), f32, dev))
+    compiled = jax.jit(lambda *a: ssd_chunked_kernel(
+        *a, interpret=False)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "ssd_scan" in text and "tpu_custom_call" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

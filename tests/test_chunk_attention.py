@@ -371,11 +371,13 @@ def test_chunked_prefill_matches_the_one_full_pass(built, use_kernel):
     table = jnp.array([[1, 2, 3, 0]])
     outs = []
     with jax.default_matmul_precision("highest"):
+        # one traced program for the two chunks
+        chunk = jax.jit(lambda p, *a: llama.apply_prefill_paged(
+            p, CFG, *a, with_logits=True, use_kernel=use_kernel))
         for c0 in range(0, 256, PAGE):
-            logits, pool = llama.apply_prefill_paged(
-                p, CFG, ids[:, c0:c0 + PAGE], pos[:, c0:c0 + PAGE], pool,
-                table, jnp.array([c0 + PAGE]), jnp.int32(c0 // PAGE),
-                with_logits=True, use_kernel=use_kernel)
+            logits, pool = chunk(
+                p, ids[:, c0:c0 + PAGE], pos[:, c0:c0 + PAGE], pool,
+                table, jnp.array([c0 + PAGE]), jnp.int32(c0 // PAGE))
             outs.append(logits[0])
     got = jnp.concatenate(outs)
     assert bool(jnp.all(jnp.isfinite(got)))

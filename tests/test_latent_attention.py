@@ -134,11 +134,13 @@ def prefilled(built):
     table = jnp.array([[1, 2, 3, 0]])
     outs = []
     with jax.default_matmul_precision("highest"):
+        # one traced program for the two chunks
+        chunk = jax.jit(lambda p, *a: llama.apply_prefill_paged(
+            p, CFG, *a, with_logits=True))
         for c0 in range(0, N_PRE, PAGE):
-            logits, pool = llama.apply_prefill_paged(
-                p, CFG, ids[:, c0:c0 + PAGE], pos[:, c0:c0 + PAGE], pool,
-                table, jnp.array([c0 + PAGE]), jnp.int32(c0 // PAGE),
-                with_logits=True)
+            logits, pool = chunk(
+                p, ids[:, c0:c0 + PAGE], pos[:, c0:c0 + PAGE], pool,
+                table, jnp.array([c0 + PAGE]), jnp.int32(c0 // PAGE))
             outs.append(logits[0])
     return pool, table, jnp.concatenate(outs)
 
@@ -160,11 +162,14 @@ def test_decode_through_the_pool_matches_the_one_full_pass(
     p, ids, pos, want = built
     pool, table, _ = prefilled
     with jax.default_matmul_precision("highest"):
+        # one traced program for the five steps
+        step = jax.jit(lambda p, *a: llama.apply_decode_paged(
+            p, CFG, *a, use_kernel=use_kernel))
         for t in range(N_PRE, N_PRE + 5):
-            logits, pool = llama.apply_decode_paged(
-                p, CFG, ids[:, t:t + 1], pos[:, t:t + 1], pool, table,
+            logits, pool = step(
+                p, ids[:, t:t + 1], pos[:, t:t + 1], pool, table,
                 jnp.array([t + 1]), table[0, t // PAGE][None],
-                jnp.array([t % PAGE]), use_kernel=use_kernel)
+                jnp.array([t % PAGE]))
             assert bool(jnp.all(jnp.isfinite(logits)))
             assert err(logits[0, 0], want[t]) < 5e-5, t
 

@@ -83,9 +83,10 @@ def test_kv_cache_decode_matches_full_forward(hf_model_and_params):
                                np.asarray(full_logits[:, :S_prefill]),
                                rtol=1e-4, atol=1e-4)
 
+    step = jax.jit(lambda p, *a: llama.apply(p, cfg, *a))   # one trace
     for t in range(S_prefill, S_total):
-        step_logits, cache = llama.apply(
-            params, cfg, jnp.asarray(tokens[:, t:t + 1]),
+        step_logits, cache = step(
+            params, jnp.asarray(tokens[:, t:t + 1]),
             jnp.asarray(all_pos[:, t:t + 1]), cache)
         np.testing.assert_allclose(np.asarray(step_logits[:, 0]),
                                    np.asarray(full_logits[:, t]),

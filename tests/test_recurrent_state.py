@@ -96,11 +96,12 @@ def test_idle_rows_of_a_decode_step_harm_nothing(p32, use_kernel):
     ids = ids_of(5, 2 * page)
     table = jnp.asarray([[1, 2, 3], [4, 5, 6]])
     pool = llama.init_paged_kv_cache(cfg, 7, page, jnp.float32, slots=2)
+    tok, pos = ids[:, :page], jnp.arange(page)[None]
+    fill = jax.jit(lambda p, pool, tbl, slots: llama.apply_prefill_paged(
+        p, cfg, tok, pos, pool, tbl, jnp.asarray([page]), jnp.int32(0),
+        slots=slots))       # one traced program for the two slots
     for slot in (0, 1):
-        tok, pos = ids[:, :page], jnp.arange(page)[None]
-        _, pool = llama.apply_prefill_paged(
-            p, cfg, tok, pos, pool, table[slot:slot + 1],
-            jnp.asarray([page]), jnp.int32(0), slots=jnp.asarray([slot]))
+        _, pool = fill(p, pool, table[slot:slot + 1], jnp.asarray([slot]))
     args = (jnp.asarray([[7], [9]]), jnp.asarray([[page], [0]]), pool, table,
             jnp.asarray([page + 1, 1]), jnp.asarray([2, 0]),
             jnp.asarray([0, 0]))
@@ -124,12 +125,14 @@ def test_rows_keep_their_state_by_slot(p32):
     tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]])
     slots = jnp.asarray([3, 1])
     outs = []
+    rows = jax.jit(lambda p, *a: llama.apply_prefill_paged(
+        p, CFG, *a, slots=slots))       # one traced program for both chunks
     for start in (0, 16):
         tok = jnp.concatenate([a, b])[:, start:start + 16]
         pos = jnp.broadcast_to(start + jnp.arange(16), (2, 16))
-        h, pool = llama.apply_prefill_paged(
-            p32, CFG, tok, pos, pool, tables, jnp.asarray([start + 16] * 2),
-            jnp.asarray([start // PAGE] * 2), slots=slots)
+        h, pool = rows(
+            p32, tok, pos, pool, tables, jnp.asarray([start + 16] * 2),
+            jnp.asarray([start // PAGE] * 2))
         outs.append(llama.unembed(p32, CFG, h))
     got = jnp.concatenate(outs, axis=1)
     for row, ids in enumerate((a, b)):

@@ -95,7 +95,7 @@ def test_the_state_is_reserved_beside_the_pool(engine):
     assert cache["s"].shape == (6, 4, 4, 8, 16) and cache["k"].shape[0] == 2
     assert cache["s"].nbytes + cache["conv"].nbytes == st["state_bytes"]
     assert st["prefix_cache_off"] == 1 and st["downgrades"] == 0
-    assert st["scan_kernel"] == 0       # no scan kernel yet: none wanted
+    assert st["scan_kernel"] == 0       # the CPU: the XLA form
 
 
 def refused(params, match, **kw):

@@ -313,20 +313,97 @@ def test_heads_of_64_are_packed_two_a_lane_row():
             jnp.asarray([n]), jnp.int32(0), with_logits=True,
             slots=jnp.asarray([0]))
         assert rel(got[0, :n], want[0, :n]) < 2e-5
+        active = jnp.asarray([True, False])
+        # one traced program a path for the two steps
+        step = {kern: jax.jit(
+            lambda p, *a, kern=kern: llama.apply_decode_paged(
+                p, cfg, *a, use_kernel=kern, active=active))
+            for kern in (False, True)}
         for at in (n, n + 1):
             pos = jnp.asarray([[at], [0]])
             args = (jnp.asarray([[int(ids[0, at])], [5]]), pos, pool, table,
                     pos[:, 0] + 1, jnp.asarray([table[0, at // page], 0]),
                     jnp.asarray([at % page, 0]))
-            active = jnp.asarray([True, False])
-            gathered, a = llama.apply_decode_paged(
-                p, cfg, *args, use_kernel=False, active=active)
-            kernel, pool = llama.apply_decode_paged(
-                p, cfg, *args, use_kernel=True, active=active)
+            gathered, a = step[False](p, *args)
+            kernel, pool = step[True](p, *args)
             assert rel(gathered[0], want[0, at:at + 1]) < 2e-5
             assert rel(kernel[0], want[0, at:at + 1]) < 2e-5
             np.testing.assert_array_equal(a["k"][:, 1:3], pool["k"][:, 1:3])
             np.testing.assert_allclose(a["s"], pool["s"], atol=1e-5)
+
+
+def test_an_engine_over_the_scan_kernel_follows_the_reference(monkeypatch):
+    """The model at widths the scan kernel takes (one group, sixteen heads
+    of 64 values over 128-lane states), a prompt of two chunks — the
+    second from the state and tail the first left, and padded — through
+    chunk programs whose scan is the kernel (interpreted here, armed as
+    a TPU arms it) over ``x`` where the convolution left it, then a
+    decode round: every served token is the reference's choice after the
+    tokens before it, and every chunk program is counted."""
+    from generativeaiexamples_tpu.engine.engine import (Engine, EngineConfig,
+                                                        SamplingParams)
+    from generativeaiexamples_tpu.models.tokenizer import ByteTokenizer
+    from generativeaiexamples_tpu.ops import ssd
+    model = {**MODEL, "num_layers": 4, "linear_num_value_heads": 16,
+             "linear_value_head_dim": 64, "linear_key_head_dim": 128}
+    cfg = LlamaConfig(**model)
+    p = llama.init_params(cfg, jax.random.key(0), jnp.float32)
+    monkeypatch.setattr(ssd, "scan_kernel_armed", ssd.scan_kernel_supported)
+    traced, kernel = [], ssd.ssd_chunked_kernel
+    monkeypatch.setattr(ssd, "ssd_chunked_kernel",
+                        lambda *a, **kw: traced.append(a[0].shape)
+                        or kernel(*a, **kw))
+    eng = Engine(p, cfg, ByteTokenizer(), EngineConfig(
+        max_slots=2, max_input_length=128, max_output_length=8,
+        prefill_buckets=(64,), max_prefill_bucket=64, page_size=64,
+        steps_per_round=4, kv_pool_tokens=None, dtype="float32"))
+    ids = [int(t) for t in np.random.default_rng(5).integers(3, 250, 100)]
+    eng.start()
+    try:
+        stream = eng.submit(ids, SamplingParams(
+            max_tokens=4, temperature=0.0, ignore_eos=True))
+        list(stream)
+    finally:
+        eng.stop()
+    out = list(stream.token_ids)
+    after = np.arange(len(ids) - 1, len(ids) + len(out) - 1)
+    rows = np.asarray(ref.forward(p, model, np.asarray([ids + out]), after))
+    for tok, row in zip(out, rows):
+        assert row[tok] >= row.max() - 1e-4 * np.abs(row).max()
+    stats = eng.stats
+    assert (1, 64, 16 * 64 + 2 * 128) in traced     # the convolution's width
+    assert stats["scan_kernel"] == 1 and stats["downgrades"] == 0
+    assert stats["scan_kernel_chunks"] == stats["sched_chunk_programs"] == 2
+
+
+def test_a_tpu_arms_the_scan_kernel_or_names_the_downgrade(monkeypatch):
+    """``ProgramSpec.resolve`` on a TPU: the state-space scan's kernel
+    armed where the shapes are one group of 64-value heads over 128-lane
+    states in whole grid steps, and where they fall short (this file's
+    8-value heads over 16 lanes) ONE downgrade by name, ``scan_kernel ->
+    xla_chunked``."""
+    from generativeaiexamples_tpu.engine.programs import ProgramSpec
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def resolve(cfg):
+        shapes = jax.eval_shape(lambda: llama.init_params(
+            cfg, jax.random.key(0), jnp.float32))
+        return ProgramSpec.resolve(shapes, cfg, page_size=64, max_slots=2,
+                                   pmax=4, dtype=jnp.float32, mesh=None,
+                                   eos_id=2)
+
+    short = resolve(CFG)
+    assert not short.scan_kernel
+    assert [d[:2] for d in short.downgrades if d[0] == "scan_kernel"] == [
+        ("scan_kernel", "xla_chunked")]
+    wide = resolve(dataclasses.replace(
+        CFG, linear_num_value_heads=16, linear_value_head_dim=64,
+        linear_key_head_dim=128))
+    assert wide.scan_kernel
+    assert "scan_kernel" not in [d[0] for d in wide.downgrades]
+    granite = resolve(dataclasses.replace(
+        MODEL_REGISTRY["granite-4.0-h-micro"], num_layers=10))
+    assert granite.scan_kernel and not granite.downgrades
 
 
 # ------------------------------------------------------------------ the ends
