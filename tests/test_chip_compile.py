@@ -35,6 +35,9 @@ PAGE = 128
 CFG = dataclasses.replace(get_model_config("llama-2-7b-chat"), num_layers=2)
 HBM_BYTES = 16 << 30    # one v5e chip
 
+# the chip's compiler is the subject: every pass, as on the machine
+pytestmark = pytest.mark.usefixtures("full_optimisation")
+
 
 @pytest.fixture(scope="module")
 def topo():

@@ -28,6 +28,9 @@ PAGE = 128
 CFG = dataclasses.replace(get_model_config("smallthinker-21b-a3b-instruct"),
                           num_layers=4)
 
+# the chip's compiler is the subject: every pass, as on the machine
+pytestmark = pytest.mark.usefixtures("full_optimisation")
+
 
 @pytest.fixture(scope="module")
 def topo():

@@ -31,6 +31,10 @@ WIDE = dataclasses.replace(CFG, num_heads=2, head_dim=192,
                            qk_nope_head_dim=128, qk_rope_head_dim=64,
                            v_head_dim=128)
 
+# a case below reads the COMPILED chunk program's operation names, as a
+# trace of the optimised program carries them
+pytestmark = pytest.mark.usefixtures("full_optimisation")
+
 
 def err(got, want):
     return float(jnp.max(jnp.abs(got.astype(jnp.float32)

@@ -7,6 +7,7 @@ a latent pool or a share yet refuses it BY NAME when the engine is
 configured."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -38,14 +39,14 @@ def prompt(n, seed):
     return [int(t) for t in np.random.default_rng(seed).integers(3, 250, n)]
 
 
-@jax.jit
-def _logits(params, ids, n):
-    out, _ = llama.apply(params, CFG, ids[None],
+@functools.partial(jax.jit, static_argnums=0)
+def _logits(cfg, params, ids, n):
+    out, _ = llama.apply(params, cfg, ids[None],
                          jnp.arange(ids.shape[0])[None])
     return out[0, n - 1]
 
 
-def plain_greedy(params, ids, n):
+def plain_greedy(params, ids, n, cfg=CFG):
     """The plain forward's own greedy chain, no cache: ONE compiled
     program over a fixed buffer (the longest prompt here is 447 tokens),
     a forward a token (causal: what follows a position does not move
@@ -53,7 +54,7 @@ def plain_greedy(params, ids, n):
     buf = np.zeros(512, np.int32)
     buf[:len(ids)] = ids
     for at in range(len(ids), len(ids) + n):
-        buf[at] = int(jnp.argmax(_logits(params, jnp.asarray(buf), at)))
+        buf[at] = int(jnp.argmax(_logits(cfg, params, jnp.asarray(buf), at)))
     return [int(t) for t in buf[len(ids):len(ids) + n]]
 
 
@@ -216,9 +217,4 @@ def test_a_fully_armed_engine_reports_no_downgrade(monkeypatch):
     ids = prompt(300, 3)
     with eng:
         got = serve(eng, ids, 4)
-    want = list(ids)
-    for _ in range(4):
-        logits, _ = llama.apply(p, cfg, jnp.asarray(want)[None],
-                                jnp.arange(len(want))[None])
-        want.append(int(jnp.argmax(logits[0, -1])))
-    assert got == want[-4:]
+    assert got == plain_greedy(p, ids, 4, cfg)

@@ -30,6 +30,11 @@ CFG = LlamaConfig(vocab_size=259 + 5, hidden_size=64, intermediate_size=128,
 PAGE = 16
 SAMPLED = dict(temperature=0.7, top_p=0.9, top_k=0, random_seed=5,
                ignore_eos=True)
+
+# the traced engine's programs are read as compiled text and as the
+# device operations a profile of the optimised programs names
+pytestmark = pytest.mark.usefixtures("full_optimisation")
+
 #: every span of docs/observability.md's table -> the arguments it carries
 SPANS = {
     "loop_drain": (), "loop_plan": (), "loop_idle": (),

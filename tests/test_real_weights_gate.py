@@ -113,15 +113,13 @@ def test_quantization_preserves_quality(golden):
     assert len(b_text) > 10
 
 
-def test_score_endpoint_serves_golden(golden):
+def test_score_endpoint_serves_golden(golden, serve_app):
     """/v1/score over the live HTTP server with the golden model: the
     long-document NLL surface returns trained-quality numbers."""
     import requests
 
     from generativeaiexamples_tpu.serving.model_server import (
         create_server_app)
-
-    from conftest import serve_app
 
     params, tok = golden
     eng = _engine(params, tok)

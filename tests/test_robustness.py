@@ -696,3 +696,44 @@ def test_chat_client_surfaces_structured_429(monkeypatch):
     assert ei.value.err_type == "queue_full"
     assert ei.value.retry_after_s == 7.0
     assert ei.value.request_id == "rid-9"
+
+
+# ------------------------------------------------------- the harness itself
+
+def test_the_harness_set_its_flags_before_jax_was_imported():
+    """``tests/conftest.py`` sets them through the environment, which
+    ``jax`` reads once, at import: a plugin or a ``conftest.py`` that
+    imported ``jax`` first would leave every program of this worker to
+    XLA's expensive passes (or on another platform), and nothing else
+    would say so."""
+    assert jax.config.read("jax_disable_most_optimizations")
+    assert jax.default_backend() == "cpu" and len(jax.devices()) == 8
+
+
+def test_a_case_past_its_limit_fails_by_name_and_the_module_goes_on(
+        tmp_path, repo_root):
+    """``tests/conftest.py``'s per-case limit: a case that sleeps past
+    it fails with its node id in the message, and the next case of its
+    file still runs. The sleeping case brings the armed alarm forward to
+    a fraction of a second (the handler is the fixture's own); a file
+    outside ``tests/`` gets the fixtures by loading the harness as a
+    plugin, the only module on the child's path."""
+    import os
+    import subprocess
+    import sys
+
+    (tmp_path / "test_two_cases.py").write_text(
+        "import signal, time\n"
+        "def test_sleeps():\n"
+        "    signal.setitimer(signal.ITIMER_REAL, 0.2)\n"
+        "    time.sleep(30)\n"
+        "def test_after_it():\n"
+        "    assert signal.getitimer(signal.ITIMER_REAL)[0] > 200\n")
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", "test_two_cases.py", "-q",
+         "-p", "conftest", "-p", "no:cacheprovider"],
+        cwd=tmp_path, capture_output=True, text=True, timeout=120,
+        env=dict(os.environ, PYTHONPATH=str(repo_root / "tests")))
+    out = proc.stdout + proc.stderr
+    assert "1 failed, 1 passed" in out, out
+    assert "test_two_cases.py::test_sleeps ran past 300 s" in out, out
