@@ -269,6 +269,13 @@ _STATS_TEMPLATE = {
     # layer: over the decode kernel each live row's context in whole
     # blocks of the kernel's pages, gathered every slot's whole window.
     "kv_rows_read": 0,
+    # Recurrent layers whose decode step is the Pallas kernel over the
+    # state leaf (ProgramSpec.state_step_kernel): slot-steps of the
+    # decode rounds whose slot held no decoding sequence — state the
+    # kernel neither fetched nor wrote — and slot-steps it stepped. From
+    # the dispatch plan. 0 for every other model.
+    "state_rows_idle": 0,
+    "state_rows_live": 0,
     # Dropless experts (moe_impl "dropless"): the sum over decode rounds
     # of the mean distinct experts a layer's rows reached in a step, and
     # the rounds that reported one (their ratio is the mean experts a
@@ -4149,6 +4156,12 @@ class Engine:
                 self._bump("kv_rows_selected", selected * steps)
                 self._bump("kv_rows_indexed", indexed * steps)
                 self._bump("kv_rows_read", read * steps)
+            if self.programs.spec.state_step_kernel:
+                # the recurrent layers' step kernel walks the live rows
+                # of all B slots' state and moves no other
+                rec.state_rows_idle_pct = 100.0 * (B - len(members)) / B
+                self._bump("state_rows_idle", (B - len(members)) * steps)
+                self._bump("state_rows_live", len(members) * steps)
         for req in members.values():
             req.proj_pos = min(req.proj_pos + steps, req.extent)
         self._count_inflight()

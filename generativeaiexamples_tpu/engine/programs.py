@@ -466,6 +466,16 @@ class ProgramSpec:
             scan_kernel=scan_kernel, tail=tail,
             downgrades=tuple(downgrades))
 
+    @property
+    def state_step_kernel(self) -> bool:
+        """Whether a decode round steps its recurrent layers' state as
+        the Pallas kernel over the whole leaf (``llama._recurrent_step``:
+        the kernel path, a geometry the kernel takes), which walks the
+        live rows and leaves an idle slot's state where it lies."""
+        return bool(self.model_cfg.recurrent and self.use_kernel
+                    and kv_cache_of(
+                        self.model_cfg).step_kernel_supported())
+
     def pin_cache(self, cache):
         """Constrain pool leaves to row-major inside a jitted program so
         every producer hands the next program (and Pallas) the same

@@ -553,7 +553,7 @@ class RecurrentKV:
                          jnp.zeros((), rows.dtype), rows)
 
     def recur(self, kv_cache: Optional[KVCache], slots, fresh,
-              carried: bool = False, kernel: bool = False):
+              carried: bool = False, live=None):
         """``(load, store, step)`` for a forward's recurrent layers:
         ``load(lg, state, lp)`` gives the rows' ``(S, conv tail)``
         entering recurrent layer ``lg`` — read from the pool by ``slots``
@@ -563,10 +563,12 @@ class RecurrentKV:
         ``store(lg, state, s, conv)`` puts what they leave back into a
         CARRIED pool (``carried``: the pool rides the layer scan,
         ``state``; else None: the forward collects the rows and
-        ``write`` puts them). ``kernel`` (a carried pool, the rows all
-        the slots): the decode step runs as the Pallas kernel over the
-        WHOLE state leaf in place — ``step(lg, state, *operands, active)
-        -> (o, state)``, the operands the configuration's member of the
+        ``write`` puts them). ``live`` (a carried pool, the rows all
+        the slots: ``ops/gated_delta.py`` ``live_first`` of the rows
+        that hold a sequence, computed once a step): the decode step
+        runs as the Pallas kernel over the WHOLE state leaf in place,
+        the idle rows' state unmoved — ``step(lg, state, *operands) ->
+        (o, state)``, the operands the configuration's member of the
         family takes (a delta rule's ``q, k, v, g, beta``; a state-space
         layer's ``x, dt, A, B, C, D``); ``load`` then hands no ``S``
         (None) and ``store`` takes none."""
@@ -585,7 +587,7 @@ class RecurrentKV:
         def load(lg, state, lp):
             pool = state if carried else kv_cache
             tail = row(pool["conv"], lg)
-            return (None if kernel else row(pool["s"], lg),
+            return (None if live is not None else row(pool["s"], lg),
                     tail.reshape(tail.shape[0], -1,
                                  self.cfg.linear_channels))
 
@@ -609,12 +611,12 @@ class RecurrentKV:
             else:
                 from ..ops.gated_delta import \
                     gated_delta_step_kernel as kernel
-            o, s = kernel(*operands, state["s"], lg,
+            o, s = kernel(*operands, live, state["s"], lg,
                           interpret=jax.default_backend() != "tpu")
             return o, {**state, "s": s}
 
         return (load, store if carried else None,
-                step if kernel else None)
+                step if live is not None else None)
 
     def step_kernel_supported(self) -> bool:
         from ..ops import ssd

@@ -1270,24 +1270,30 @@ def apply_decode_paged(params: Params, cfg: LlamaConfig, tokens: jax.Array,
         params, cfg, h, positions, inv_freq, kv_valid_len, attend,
         state=kv_cache, row_mask=active, stats=stats,
         selection=_no_selection(cfg, tokens, kv_cache, block_table),
-        **_recurrent_step(cfg, kvc, None, slots, active, carried=True))
+        **_recurrent_step(cfg, kvc, None, slots, active,
+                          carried=tokens.shape[0]))
     return _step_result(params, cfg, h, cache, touched, return_hidden,
                         stats)
 
 
 def _recurrent_step(cfg: LlamaConfig, kvc, kv_cache: Optional[KVCache],
-                    slots, active, carried: bool = False) -> dict:
+                    slots, active, carried: int = 0) -> dict:
     """``_run_stack``'s ``recur`` for one token a row over the rows'
     slots (nothing for a model without recurrent layers): every row
     continues its sequence, idle rows touch nothing. Over a carried pool
-    whose rows are all its slots the step is the Pallas kernel over the
-    state leaf, where it takes the geometry."""
+    (``carried``: its rows) whose rows are all its slots the step is the
+    Pallas kernel over the state leaf, where it takes the geometry: it
+    walks the live rows alone, in an order computed HERE — once a step,
+    not once a layer."""
     if not cfg.recurrent:
         return {}
     n_valid = None if active is None else active.astype(jnp.int32)
-    kernel = carried and slots is None and kvc.step_kernel_supported()
+    live = None
+    if carried and slots is None and kvc.step_kernel_supported():
+        live = gd.live_first(jnp.ones((carried,), bool) if active is None
+                             else active)
     return {"recur": _gdn_recur(
-        cfg, *kvc.recur(kv_cache, slots, None, carried, kernel),
+        cfg, *kvc.recur(kv_cache, slots, None, bool(carried), live),
         n_valid=n_valid)}
 
 
@@ -1776,7 +1782,7 @@ def _gdn_mixer(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
     nothing (``g = 0``, ``beta = 0``), and the convolution's new tail
     ends at the last valid one. A paged cache forgives garbage rows past
     a length; a state does not. S == 1 is the decode step
-    (``gated_delta_step``; ``step_kernel(q, k, v, g, beta, active) ->
+    (``gated_delta_step``; ``step_kernel(q, k, v, g, beta) ->
     (o, state)`` in its place where the cache object runs it as the
     kernel over its own leaf: ``state`` is then None and what comes back
     is the cache's), anything longer the chunked scan: ONE Pallas kernel
@@ -1839,7 +1845,7 @@ def _recurrence(q, k, v, g, beta, state, n_valid, step_kernel, scan):
     its own leaf, else ``gated_delta_step`` with an idle row's state
     left as it was, bit for bit and whatever it holds —, anything longer
     ``scan(q, k, v, g, beta, state)``, the caller's chunked form."""
-    B, S = beta.shape[:2]
+    S = beta.shape[1]
     if n_valid is not None:
         valid = (jnp.arange(S)[None, :] < n_valid[:, None])[..., None]
         beta, g = jnp.where(valid, beta, 0.0), jnp.where(
@@ -1847,9 +1853,7 @@ def _recurrence(q, k, v, g, beta, state, n_valid, step_kernel, scan):
     if S != 1:
         return scan(q, k, v, g, beta, state)
     if step_kernel is not None:
-        o, new = step_kernel(
-            q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
-            jnp.ones((B,), bool) if n_valid is None else n_valid > 0)
+        o, new = step_kernel(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
         return o[:, None], new
     o, new = gd.gated_delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
                                  beta[:, 0], state)
@@ -1947,7 +1951,7 @@ def _ssd_mixer(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
     inner width (``_gated_head_norm`` norms a head and then gates), and
     projected back. S == 1 is the decode step (``ssd.ssd_step``, or the
     cache object's kernel over its own leaf: ``step_kernel(x, dt, A, B,
-    C, D, active) -> (y, state)``), anything longer the chunked form: ONE
+    C, D) -> (y, state)``), anything longer the chunked form: ONE
     Pallas kernel (``ssd.ssd_chunked_kernel``) where
     ``ssd.scan_kernel_armed`` says so of the shapes (a TPU, whole
     64-token blocks, one group, 64-value heads over 128-lane states),
@@ -1988,9 +1992,8 @@ def _ssd_mixer(x: jax.Array, lp: dict[str, jax.Array], cfg: LlamaConfig,
         elif not step:
             y, new = ssd.ssd_chunked(xs, dt, A, Bm, Cm, D, state)
         elif step_kernel is not None:
-            y, new = step_kernel(
-                xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], D,
-                jnp.ones((B,), bool) if n_valid is None else n_valid > 0)
+            y, new = step_kernel(xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0],
+                                 D)
             y = y[:, None]
         else:
             y, new = ssd.ssd_step(xs[:, 0], dt[:, 0], A, Bm[:, 0],

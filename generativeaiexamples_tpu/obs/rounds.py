@@ -262,7 +262,7 @@ class RoundRecord:
         "pages_touched", "hbm_bytes",
         "kv_restore_pages", "blocked_on_pages", "kv_pages_skipped",
         "kv_rows_selected", "kv_rows_indexed", "kv_selected_pct",
-        "kv_rows_read", "kv_read_per_selected",
+        "kv_rows_read", "kv_read_per_selected", "state_rows_idle_pct",
         "dispatch_ms", "modeled_ms", "t_dispatch_done",
         # execution (harvest thread)
         "harvest_wait_ms", "first_readback_ms", "emit_ms",
@@ -345,6 +345,14 @@ class RoundRecord:
         # form ran, and how far a masked read is from a gathered one.
         self.kv_rows_read = 0
         self.kv_read_per_selected = 0.0
+        # ``state_rows_idle_pct`` (from the plan, scheduler thread): of
+        # the slots whose recurrent state the decode steps' kernel walks
+        # (ops/ssd.py ``ssd_step_kernel``, ops/gated_delta.py
+        # ``gated_delta_step_kernel``), the share, in percent, that held
+        # no decoding sequence — rows the kernel neither fetched nor
+        # wrote. NOT SET (no attribute) on every round of a model
+        # without recurrent layers or whose step is XLA's, and on a
+        # round that decoded nothing.
         self.dispatch_ms = 0.0
         self.modeled_ms = 0.0
         self.t_dispatch_done = self.t_start
@@ -474,6 +482,8 @@ class RoundRecord:
                 "kv_selected_pct": round(self.kv_selected_pct, 2),
                 "kv_rows_read": self.kv_rows_read,
                 "kv_read_per_selected": round(self.kv_read_per_selected, 2),
+                "state_rows_idle_pct": getattr(
+                    self, "state_rows_idle_pct", None),
                 "experts_touched": round(self.experts_touched, 2),
                 "tail_resort_pct": round(self.tail_resort_pct, 2),
                 "local_assignments": round(self.local_assignments, 2),

@@ -119,17 +119,45 @@ def step_kernel_supported(heads: int, dk: int, dv: int) -> bool:
     return dk % 128 == 0 and dv % 128 == 0 and heads % _STEP_HEADS == 0
 
 
-def gated_delta_step_kernel(q, k, v, g, beta, active, states, layer, *,
-                            interpret: bool = False):
+def live_first(active):
+    """The decode kernels' visiting order over a state leaf's rows:
+    ``(order (B,) int32, n_live (1,) int32)`` from ``active`` (B,) bool,
+    the live rows first, each group in slot order. Grid step ``i <
+    n_live`` works on row ``order[i]``; every later step names the LAST
+    live row's block again (:func:`_visited`) and leaves the state
+    alone, so an idle row's state is neither fetched nor written. A
+    sort of a few dozen booleans: ONCE a decode step, outside the layer
+    loop (models/llama.py ``_recurrent_step``)."""
+    order = jnp.argsort(jnp.logical_not(active), stable=True)
+    return (order.astype(jnp.int32),
+            jnp.sum(active, dtype=jnp.int32).reshape(1))
+
+
+def _visited(i, order, n_live):
+    """The row whose state grid step ``i`` names: its own while rows are
+    live, then the last live row's over and over — the pipeline moves a
+    block only when its index changes. Row ``order[0]`` where none is
+    live: that one block is fetched, and the kernel's first step copies
+    it to the output buffer that goes back at the end of the grid."""
+    return order[jnp.minimum(i, jnp.maximum(n_live[0] - 1, 0))]
+
+
+def gated_delta_step_kernel(q, k, v, g, beta, live, states, layer, *,
+                            interpret=False):
     """:func:`gated_delta_step` over the cache's WHOLE state leaf, in
     place: ``states`` (Lg, B, H, dk, dv) aliased to the second result,
     ``layer`` () int32 the recurrent layer to step, row ``b`` its slot
     ``b``. One Pallas kernel, a grid over (row, group of heads): a
     head's 64 KiB are read once, decayed, corrected, read out and
     written once — plain XLA reads the state four times a step (two
-    reductions, the update, the slice out of the leaf). ``active`` (B,)
-    bool: an idle row's state is written back as it was read, bit for
-    bit. Returns ``(o (B, H, dv) float32, states)``.
+    reductions, the update, the slice out of the leaf). ``live``:
+    :func:`live_first` of the rows that hold a sequence. The grid walks
+    the live rows; the steps left over name the last live step's block
+    (its row AND its last group of heads), which moves nothing, so an
+    idle row's state stays where it lies in the aliased leaf, bit for
+    bit, at no cost in bytes, and its ``o`` is 0. Returns ``(o (B, H,
+    dv) float32, states)``. ``interpret``: as ``pallas_call``'s (the TPU
+    interpreter's parameters model the pipeline's buffers: the tests').
 
     ``g`` (B, H, dk), a decay a CHANNEL of a head's keys, runs the
     kernel's twin ``kda_delta_step``: the same grid, reads and writes,
@@ -144,53 +172,75 @@ def gated_delta_step_kernel(q, k, v, g, beta, active, states, layer, *,
     f32 = jnp.float32
     channel = g.ndim == 3
 
-    def kernel(layer_ref, q_ref, k_ref, v_ref, dec_ref, beta_ref, act_ref,
-               s_ref, o_ref, s_out_ref):
-        # a head's key and query down the sublanes, beside its (dk, dv)
-        # state: one transpose of the group's (hb, dk) tile
-        kt, qt = k_ref[0].T, q_ref[0].T                     # (dk, hb)
-        if channel:
-            dt = dec_ref[0].T                               # (dk, hb)
-        for h in range(hb):
-            row = slice(h, h + 1)
-            old = s_ref[0, 0, h]                            # (dk, dv)
-            kc = kt[:, row]                                 # (dk, 1)
-            # x (1, dv): the head's one decay; x (dk, 1): a row its own
-            s = old.astype(f32) * (dt[:, row] if channel
-                                   else dec_ref[0, row])
-            d = beta_ref[0, row] * (
-                v_ref[0, row] - jnp.sum(s * kc, axis=0, keepdims=True))
-            s = s + kc * d
-            o_ref[0, row] = jnp.sum(s * qt[:, row], axis=0, keepdims=True)
-            s_out_ref[0, 0, h] = jnp.where(act_ref[0, row] > 0,
-                                           s.astype(old.dtype), old)
+    def kernel(layer_ref, order_ref, n_ref, q_ref, k_ref, v_ref, dec_ref,
+               beta_ref, s_ref, o_ref, s_out_ref):
+        i, n = pl.program_id(0), n_ref[0]
+
+        @pl.when(i < n)
+        def _():
+            # a head's key and query down the sublanes, beside its (dk,
+            # dv) state: one transpose of the group's (hb, dk) tile
+            kt, qt = k_ref[0].T, q_ref[0].T                 # (dk, hb)
+            if channel:
+                dt = dec_ref[0].T                           # (dk, hb)
+            for h in range(hb):
+                row = slice(h, h + 1)
+                kc = kt[:, row]                             # (dk, 1)
+                # x (1, dv): the head's one decay; x (dk, 1): a row its
+                # own
+                s = s_ref[0, 0, h].astype(f32) * (
+                    dt[:, row] if channel else dec_ref[0, row])
+                d = beta_ref[0, row] * (
+                    v_ref[0, row] - jnp.sum(s * kc, axis=0, keepdims=True))
+                s = s + kc * d
+                o_ref[0, row] = jnp.sum(s * qt[:, row], axis=0,
+                                        keepdims=True)
+                s_out_ref[0, 0, h] = s.astype(s_out_ref.dtype)
+
+        @pl.when(i >= n)
+        def _():
+            o_ref[...] = jnp.zeros(o_ref.shape, f32)
+
+        # no live row: the one block every step names goes back as it
+        # came (no other step writes the output buffer)
+        @pl.when((n == 0) & (i == 0) & (pl.program_id(1) == 0))
+        def _():
+            s_out_ref[...] = s_ref[...]
 
     def over_values(x):         # (B, H) -> (B, H, dv): a row a head
         return jnp.broadcast_to(x.astype(f32)[..., None], (B, H, dv))
 
-    keys = pl.BlockSpec((1, hb, dk), lambda b, h, layer: (b, h, 0))
-    vals = pl.BlockSpec((1, hb, dv), lambda b, h, layer: (b, h, 0))
-    slab = pl.BlockSpec((1, 1, hb, dk, dv),
-                        lambda b, h, layer: (layer[0], b, h, 0, 0))
+    def small(b, h, layer, order, n):   # the (row, group) a step names
+        return (_visited(b, order, n),
+                jnp.where(b < n[0], h, H // hb - 1), 0)
+
+    def leaf(b, h, layer, order, n):
+        return (layer[0],) + small(b, h, layer, order, n) + (0,)
+
+    keys = pl.BlockSpec((1, hb, dk), small)
+    vals = pl.BlockSpec((1, hb, dv), small)
+    slab = pl.BlockSpec((1, 1, hb, dk, dv), leaf)
     o, states = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(B, H // hb),
+            num_scalar_prefetch=3, grid=(B, H // hb),
             in_specs=[keys, keys, vals, keys if channel else vals, vals,
-                      vals, slab],
-            out_specs=[vals, slab]),
+                      slab],
+            out_specs=[
+                pl.BlockSpec((1, hb, dv),
+                             lambda b, h, layer, order, n: (order[b], h, 0)),
+                slab]),
         out_shape=[jax.ShapeDtypeStruct((B, H, dv), f32),
                    jax.ShapeDtypeStruct(states.shape, states.dtype)],
-        input_output_aliases={7: 1},
+        input_output_aliases={8: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="kda_delta_step" if channel else "gated_delta_step",
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), q.astype(f32),
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), *live, q.astype(f32),
       k.astype(f32), v.astype(f32),
       jnp.exp(g.astype(f32)) if channel else over_values(jnp.exp(g)),
-      over_values(beta), over_values(jnp.broadcast_to(
-          active[:, None], (B, H))), states)
+      over_values(beta), states)
     return o, states
 
 

@@ -152,16 +152,20 @@ def step_kernel_supported(heads: int, groups: int, P: int, N: int) -> bool:
             and heads * P * N * 4 <= _STEP_BYTES)
 
 
-def ssd_step_kernel(x, dt, A, Bm, Cm, D, active, states, layer, *,
-                    interpret: bool = False):
+def ssd_step_kernel(x, dt, A, Bm, Cm, D, live, states, layer, *,
+                    interpret=False):
     """:func:`ssd_step` over the cache's WHOLE state leaf, in place:
     ``states`` (Lg, B, H, P, N) aliased to the second result, ``layer``
     () int32 the recurrent layer to step, row ``b`` its slot ``b``,
     ``Bm, Cm`` (B, 1, N). One Pallas kernel, a grid over the rows: a
     row's H states of the layer (2 MiB at 64 x 64 x 128) are read once,
-    decayed, written, read out and stored once. ``active`` (B,) bool: an
-    idle row's state is written back as it was read, bit for bit, and
-    its ``y`` is 0. Returns ``(y (B, H, P) float32, states)``.
+    decayed, written, read out and stored once. ``live``:
+    :func:`~.gated_delta.live_first` of the rows that hold a sequence.
+    The grid walks the live rows; the steps left over name the last
+    live row's block, which moves nothing, so an idle row's 2 MiB stay
+    where they lie in the aliased leaf, bit for bit, at no cost in
+    bytes, and its ``y`` is 0. Returns ``(y (B, H, P) float32,
+    states)``. ``interpret``: as ``pallas_call``'s.
 
     What a head needs down the sublanes beside its (P, N) state — its
     ``dt x`` and its decay — comes in with the heads on the LANES, (B,
@@ -170,14 +174,16 @@ def ssd_step_kernel(x, dt, A, Bm, Cm, D, active, states, layer, *,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from .gated_delta import _visited
+
     Lg, B, H, P, N = states.shape
     f32 = jnp.float32
 
-    def kernel(layer_ref, act_ref, xd_ref, dec_ref, b_ref, c_ref, s_ref,
-               y_ref, s_out_ref):
-        live = act_ref[pl.program_id(0)] > 0
+    def kernel(layer_ref, order_ref, n_ref, xd_ref, dec_ref, b_ref, c_ref,
+               s_ref, y_ref, s_out_ref):
+        i, n = pl.program_id(0), n_ref[0]
 
-        @pl.when(live)
+        @pl.when(i < n)
         def _():
             xd, dec = xd_ref[0], dec_ref[0]                 # (P, H)
             bv, cv = b_ref[0], c_ref[0]                     # (1, N)
@@ -192,34 +198,47 @@ def ssd_step_kernel(x, dt, A, Bm, Cm, D, active, states, layer, *,
                 s_out_ref[0, 0, h] = s.astype(s_out_ref.dtype)
             y_ref[0] = y
 
-        @pl.when(jnp.logical_not(live))
+        @pl.when(i >= n)
+        def _():
+            y_ref[...] = jnp.zeros(y_ref.shape, f32)
+
+        # no live row: the one block every step names goes back as it
+        # came (no other step writes the output buffer)
+        @pl.when((n == 0) & (i == 0))
         def _():
             s_out_ref[...] = s_ref[...]
-            y_ref[...] = jnp.zeros(y_ref.shape, f32)
 
     def heads_last(a):      # (B, H, P) -> (B, P, H)
         return jnp.swapaxes(a.astype(f32), 1, 2)
 
+    def small(i, layer, order, n):      # the row a step names
+        return (_visited(i, order, n), 0, 0)
+
+    def leaf(i, layer, order, n):
+        return (layer[0], _visited(i, order, n), 0, 0, 0)
+
     dec = jnp.broadcast_to(jnp.exp(dt * A)[..., None], (B, H, P))
-    cols = pl.BlockSpec((1, P, H), lambda b, layer, act: (b, 0, 0))
-    vec = pl.BlockSpec((1, 1, N), lambda b, layer, act: (b, 0, 0))
-    slab = pl.BlockSpec((1, 1, H, P, N),
-                        lambda b, layer, act: (layer[0], b, 0, 0, 0))
+    cols = pl.BlockSpec((1, P, H), small)
+    vec = pl.BlockSpec((1, 1, N), small)
+    slab = pl.BlockSpec((1, 1, H, P, N), leaf)
     y, states = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(B,),
+            num_scalar_prefetch=3, grid=(B,),
             in_specs=[cols, cols, vec, vec, slab],
-            out_specs=[cols, slab]),
+            out_specs=[
+                pl.BlockSpec((1, P, H),
+                             lambda i, layer, order, n: (order[i], 0, 0)),
+                slab]),
         out_shape=[jax.ShapeDtypeStruct((B, P, H), f32),
                    jax.ShapeDtypeStruct(states.shape, states.dtype)],
-        input_output_aliases={6: 1},
+        input_output_aliases={7: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=32 << 20),
         interpret=interpret,
         name="ssd_step",
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), active.astype(jnp.int32),
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), *live,
       heads_last(x * dt[..., None]), heads_last(dec), Bm.astype(f32),
       Cm.astype(f32), states)
     return jnp.swapaxes(y, 1, 2) + D[:, None] * x, states

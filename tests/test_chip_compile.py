@@ -1057,7 +1057,8 @@ def test_gated_delta_step_kernel_compiles(topo, state):
     f32 = jnp.float32
     args = (sds((B, H, dk), f32, dev), sds((B, H, dk), f32, dev),
             sds((B, H, dv), f32, dev), sds((B, H), f32, dev),
-            sds((B, H), f32, dev), sds((B,), jnp.bool_, dev),
+            sds((B, H), f32, dev),
+            (sds((B,), jnp.int32, dev), sds((1,), jnp.int32, dev)),
             sds((Lg, B, H, dk, dv), state, dev), sds((), jnp.int32, dev))
     compiled = jax.jit(gated_delta_step_kernel, donate_argnums=(6,)).lower(
         *args).compile()
@@ -1108,7 +1109,8 @@ def test_kda_step_kernel_compiles(topo):
     f32 = jnp.float32
     args = (sds((B, H, dk), f32, dev), sds((B, H, dk), f32, dev),
             sds((B, H, dv), f32, dev), sds((B, H, dk), f32, dev),
-            sds((B, H), f32, dev), sds((B,), jnp.bool_, dev),
+            sds((B, H), f32, dev),
+            (sds((B,), jnp.int32, dev), sds((1,), jnp.int32, dev)),
             sds((Lg, B, H, dk, dv), f32, dev), sds((), jnp.int32, dev))
     compiled = jax.jit(gated_delta_step_kernel, donate_argnums=(6,)).lower(
         *args).compile()
@@ -1237,7 +1239,8 @@ def test_ssd_step_kernel_compiles(topo):
     args = (sds((B, H, P), f32, dev), sds((B, H), f32, dev),
             sds((H,), f32, dev), sds((B, 1, N), f32, dev),
             sds((B, 1, N), f32, dev), sds((H,), f32, dev),
-            sds((B,), jnp.bool_, dev), sds((Lg, B, H, P, N), f32, dev),
+            (sds((B,), jnp.int32, dev), sds((1,), jnp.int32, dev)),
+            sds((Lg, B, H, P, N), f32, dev),
             sds((), jnp.int32, dev))
     compiled = jax.jit(ssd_step_kernel, donate_argnums=(7,)).lower(
         *args).compile()

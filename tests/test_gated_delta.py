@@ -309,13 +309,28 @@ def test_the_kernel_is_taken_only_where_it_fits(monkeypatch):
         linear_num_value_heads=2 * P + 2), 128) == 0
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["float32", "bf16_state"])
-def test_the_step_kernel_is_the_step_in_place(dtype):
-    """The Pallas kernel (interpreted) over a cache's whole state leaf:
-    the named layer's live rows stepped as ``gated_delta_step`` steps
-    them, an idle row's state and every other layer's bit for bit as
-    they were — NaN included."""
+# which of a leaf's four rows hold a sequence
+ACTIVITY = {"none": (0, 0, 0, 0), "all": (1, 1, 1, 1),
+            "leading_idle": (0, 0, 1, 1), "trailing_idle": (1, 1, 0, 0),
+            "alternating": (0, 1, 0, 1), "one_live_in_the_middle": (0, 0, 1, 0)}
+
+
+@pytest.mark.parametrize("rows,dtype", [
+    (rows, jnp.float32) for rows in ACTIVITY.values()] + [
+    (ACTIVITY["alternating"], jnp.bfloat16)],
+    ids=list(ACTIVITY) + ["alternating_bf16_state"])
+def test_the_step_kernel_is_the_step_in_place(rows, dtype):
+    """The Pallas kernel over a cache's whole state leaf (3 layers x 4
+    slots x two groups of heads), under the TPU interpreter — which
+    models the pipeline's buffers, so an output buffer no step wrote
+    goes back as NaN (plain ``interpret=True`` reads the block anew
+    every step and cannot see that): the named layer's live rows
+    stepped as ``gated_delta_step`` steps them; an idle row's state —
+    whatever a stale slot holds, ``inf`` and NaN too: it is never moved
+    — and every other layer's bit for bit as they were, with no row
+    live as well."""
+    from jax.experimental.pallas import tpu as pltpu
+
     Lg, Bk, Hk, dk, dv = 3, 4, 16, 128, 128
     ks = jax.random.split(jax.random.key(0), 6)
     def unit(x):
@@ -326,19 +341,27 @@ def test_the_step_kernel_is_the_step_in_place(dtype):
     v = jax.random.normal(ks[2], (Bk, Hk, dv))
     g = -0.1 * jax.random.uniform(ks[3], (Bk, Hk))
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (Bk, Hk)))
-    states = jax.random.normal(ks[5], (Lg, Bk, Hk, dk, dv)).astype(dtype)
-    states = states.at[1, 1].set(jnp.nan)               # the idle row's
-    active = jnp.asarray([True, False, True, True])
+    active = jnp.asarray(rows, bool)
+    live, idle = np.asarray(active), ~np.asarray(active)
+    states = jax.random.normal(ks[5], (Lg, Bk, Hk, dk, dv))
+    garbage = states[1].at[:, 0].set(jnp.inf).at[:, Hk - 1].set(jnp.nan)
+    states = states.at[1].set(jnp.where(
+        active[:, None, None, None], states[1], garbage)).astype(dtype)
     assert _gd.step_kernel_supported(Hk, dk, dv)
     assert not _gd.step_kernel_supported(Hk, 16, dv)
-    o, new = _gd.gated_delta_step_kernel(q, k, v, g, beta, active, states,
-                                         jnp.int32(1), interpret=True)
+    o, new = jax.jit(lambda *a: _gd.gated_delta_step_kernel(
+        *a, interpret=pltpu.InterpretParams(uninitialized_memory="nan")))(
+        q, k, v, g, beta, _gd.live_first(active), states, jnp.int32(1))
     want_o, want_s = _gd.gated_delta_step(q, k, v, g, beta, states[1])
-    live = jnp.asarray([0, 2, 3])
-    close(o[live], want_o[live], 1e-6)
-    close(new[1][live].astype(jnp.float32),
-          want_s[live].astype(jnp.float32), 1e-6 if dtype == jnp.float32
-          else 1e-2)
-    assert new.dtype == dtype and bool(jnp.all(jnp.isnan(new[1, 1])))
+    if live.any():
+        close(o[live], want_o[live], 1e-6)
+        close(new[1][live].astype(jnp.float32),
+              want_s[live].astype(jnp.float32),
+              1e-6 if dtype == jnp.float32 else 1e-2)
+    bits = lambda a: np.asarray(a).view(                    # noqa: E731
+        np.uint32 if dtype == jnp.float32 else np.uint16)
+    assert new.dtype == dtype
+    np.testing.assert_array_equal(bits(new[1])[idle], bits(states[1])[idle])
     for other in (0, 2):
-        np.testing.assert_array_equal(new[other], states[other])
+        np.testing.assert_array_equal(bits(new[other]), bits(states[other]))
+    assert not np.any(np.asarray(o)[idle])      # nothing is read out

@@ -237,31 +237,52 @@ def test_a_heads_decays_averaged_are_another_recurrence():
     assert not close(o_mean, o, 1e-2)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["float32", "bf16_state"])
-def test_the_step_kernels_twin_is_the_step_in_place(dtype):
-    """The kernel ``kda_delta_step`` (interpreted) over the whole state
-    leaf: the stepped layer's live rows are ``gated_delta_step``'s with
-    the vector decay, an idle row and every other layer bit for bit."""
-    Lg, Bk, Hk, dk, dv = 3, 3, 8, 128, 128
+# which of a leaf's four rows hold a sequence
+ACTIVITY = {"none": (0, 0, 0, 0), "all": (1, 1, 1, 1),
+            "leading_idle": (0, 0, 1, 1), "trailing_idle": (1, 1, 0, 0),
+            "alternating": (0, 1, 0, 1), "one_live_in_the_middle": (0, 0, 1, 0)}
+
+
+@pytest.mark.parametrize("rows,dtype", [
+    (rows, jnp.float32) for rows in ACTIVITY.values()] + [
+    (ACTIVITY["alternating"], jnp.bfloat16)],
+    ids=list(ACTIVITY) + ["alternating_bf16_state"])
+def test_the_step_kernels_twin_is_the_step_in_place(rows, dtype):
+    """The kernel ``kda_delta_step`` over the whole state leaf (3 layers
+    x 4 slots x two groups of heads), under the TPU interpreter — which
+    models the pipeline's buffers, so an output buffer no step wrote
+    goes back as NaN (plain ``interpret=True`` cannot see that): the
+    stepped layer's live rows are ``gated_delta_step``'s with the vector
+    decay; an idle row — ``inf`` and NaN in it too: it is never moved —
+    and every other layer bit for bit, with no row live as well."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    Lg, Bk, Hk, dk, dv = 3, 4, 16, 128, 128
     ks = jax.random.split(jax.random.key(1), 6)
     q = gd.l2norm(jax.random.normal(ks[0], (Bk, Hk, dk))) * dk ** -0.5
     k = gd.l2norm(jax.random.normal(ks[1], (Bk, Hk, dk)))
     v = jax.random.normal(ks[2], (Bk, Hk, dv))
     g = -5.0 * jax.nn.sigmoid(jax.random.normal(ks[3], (Bk, Hk, dk)))
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (Bk, Hk)))
-    states = jax.random.normal(ks[5], (Lg, Bk, Hk, dk, dv)).astype(dtype)
-    active = jnp.asarray([True, False, True])
+    active = jnp.asarray(rows, bool)
+    live, idle = np.asarray(active), ~np.asarray(active)
+    states = jax.random.normal(ks[5], (Lg, Bk, Hk, dk, dv))
+    garbage = states[1].at[:, 0].set(jnp.inf).at[:, Hk - 1].set(jnp.nan)
+    states = states.at[1].set(jnp.where(
+        active[:, None, None, None], states[1], garbage)).astype(dtype)
     o, new = jax.jit(lambda *a: gd.gated_delta_step_kernel(
-        *a, interpret=True))(q, k, v, g, beta, active, states, jnp.int32(1))
+        *a, interpret=pltpu.InterpretParams(uninitialized_memory="nan")))(
+        q, k, v, g, beta, gd.live_first(active), states, jnp.int32(1))
     o_ref, s_ref = gd.gated_delta_step(q, k, v, g, beta, states[1])
-    live = np.asarray(active)
     tol = 2e-5 if dtype == jnp.float32 else 1e-2
     np.testing.assert_allclose(o[live], o_ref[live], rtol=tol, atol=tol)
     np.testing.assert_allclose(new[1][live].astype(jnp.float32),
                                s_ref[live].astype(jnp.float32),
                                rtol=tol, atol=tol)
-    np.testing.assert_array_equal(new[1, 1], states[1, 1])
-    np.testing.assert_array_equal(new[0], states[0])
-    np.testing.assert_array_equal(new[2], states[2])
+    bits = lambda a: np.asarray(a).view(                    # noqa: E731
+        np.uint32 if dtype == jnp.float32 else np.uint16)
     assert new.dtype == dtype
+    np.testing.assert_array_equal(bits(new[1])[idle], bits(states[1])[idle])
+    for other in (0, 2):
+        np.testing.assert_array_equal(bits(new[other]), bits(states[other]))
+    assert not np.any(np.asarray(o)[idle])      # nothing is read out

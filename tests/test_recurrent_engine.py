@@ -153,6 +153,8 @@ def test_an_engine_without_the_state_reports_none():
     st = eng.stats
     assert st["state_bytes"] == st["slot_bytes"] == 0
     assert st["prefix_cache_off"] == 0 and eng._prefix_cache is not None
+    assert not eng.programs.spec.state_step_kernel
+    assert st["state_rows_idle"] == 0 == st["state_rows_live"]
 
 
 def test_suspend_resume_and_handoff_refuse(engine):
@@ -290,3 +292,95 @@ def test_a_tpu_engine_that_cannot_take_the_scan_kernel_says_so(
     p = llama.init_params(cfg, jax.random.key(0), dtype=jnp.float32)
     plain = Engine(p, cfg, ByteTokenizer(), EngineConfig(**ENGINE))
     assert plain.downgrades == [] and plain.stats["scan_kernel"] == 0
+
+
+# -------------------------- the decode step as the kernel over the leaf
+
+# widths the two decode kernels take: the paged attention's and the
+# recurrence's own step over the cache's whole state leaf
+KCFG = dataclasses.replace(CFG, head_dim=128, linear_key_head_dim=128,
+                           linear_value_head_dim=128,
+                           linear_num_value_heads=8)
+
+
+@pytest.fixture(scope="module")
+def kernel_engine():
+    """An engine whose decode rounds run the Pallas kernels (wanted by
+    the environment: the CPU does not want them by itself; interpreted):
+    every recurrent layer's step is ``gated_delta_step_kernel`` over all
+    four slots' state, which walks the live rows and moves no other."""
+    p = llama.init_params(KCFG, jax.random.key(3), dtype=jnp.float32)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("GENAI_TPU_PAGED_KERNEL", "1")
+        eng = Engine(p, KCFG, ByteTokenizer(), EngineConfig(**{
+            **ENGINE, "page_size": 128, "prefill_buckets": (128,),
+            "max_prefill_bucket": 128}))
+    eng.rounds = RoundRecorder(cap=512)
+    assert eng._use_kernel and eng.programs.spec.state_step_kernel
+    assert eng.downgrades == []
+    eng.start()
+    yield eng
+    eng.stop()
+
+
+def test_a_slot_mid_prefill_keeps_its_state_across_decode_rounds(
+        kernel_engine):
+    """The case where an idle row's state is live data: a prompt of
+    four chunks admitted beside a decoding neighbour, every dispatch
+    slowed so that the neighbour's decode rounds come back between the
+    chunk programs (the CPU queues four chunks in a millisecond). In
+    those rounds the prompt's slot is idle — the step kernel names no
+    block of it — and the next chunk reads the state the last one left:
+    its tokens are its tokens alone, and the neighbour's its own."""
+    from generativeaiexamples_tpu.utils import faults
+    eng = kernel_engine
+    near, far = prompt(40, 31), prompt(500, 32)
+    alone = [serve(eng, near, 32), serve(eng, far)]
+    seen = len(eng.rounds.records())
+    faults.set_plan("engine.dispatch=delay:0.3")
+    try:
+        first = submit(eng, near, 32)
+        for _ in first:
+            break                   # decoding: the rounds have begun
+        second = submit(eng, far)
+        assert [list(first) and list(first.token_ids),
+                list(second) and list(second.token_ids)] == alone
+    finally:
+        faults.clear()
+    recs = eng.rounds.records()[seen:]
+    chunks = [r.round_id for r in recs if any(
+        rid == second.timeline.request_id for rid, _ in r.grants)]
+    between = [r for r in recs if r.decode_slots
+               and chunks[0] <= r.round_id <= chunks[-1]]
+    assert len(chunks) == 4 and len(between) >= 2 and all(
+        r.state_rows_idle_pct == 75.0 for r in between)
+
+
+def test_the_rows_the_step_kernel_leaves_alone_are_counted(
+        kernel_engine, engine):
+    """One live row of four slots: three quarters of the state leaf's
+    rows idle in every decoding round, by the record and by the
+    counters; a round that decoded nothing, an engine whose step is
+    XLA's and a model without recurrent layers carry no such field."""
+    before = dict(kernel_engine.stats)
+    seen = len(kernel_engine.rounds.records())
+    serve(kernel_engine, prompt(200, 33))
+    st = kernel_engine.stats
+    steps = st["decode_steps"] - before["decode_steps"]
+    assert st["state_rows_idle"] - before["state_rows_idle"] == 3 * steps
+    assert st["state_rows_live"] - before["state_rows_live"] == steps > 0
+    recs = kernel_engine.rounds.records()[seen:]
+    decoding = [r for r in recs if r.decode_slots]
+    assert decoding and all(r.state_rows_idle_pct == 75.0 for r in decoding)
+    assert decoding[-1].to_dict()["outcome"]["state_rows_idle_pct"] == 75.0
+    chunks = [r for r in recs if not r.decode_slots]
+    assert chunks and not any(hasattr(r, "state_rows_idle_pct")
+                              for r in chunks)
+    assert chunks[0].to_dict()["outcome"]["state_rows_idle_pct"] is None
+    # the XLA step (16-lane heads, the CPU's path): nothing to report
+    assert not engine.programs.spec.state_step_kernel
+    serve(engine, prompt(40, 34))
+    assert not any(hasattr(r, "state_rows_idle_pct")
+                   for r in engine.rounds.records())
+    assert engine.stats["state_rows_idle"] == 0 == engine.stats[
+        "state_rows_live"]

@@ -182,35 +182,49 @@ def test_the_kernel_takes_ragged_rows_and_a_run_of_equal_tokens():
         close(new[b:b + 1], s0, 2e-5)
 
 
-def test_the_step_kernel_is_the_step_in_place():
-    """The kernel over the WHOLE leaf (3 layers x 3 slots), layer 1: the
-    live rows' state and read-out are the step's, the idle row's state
-    and every other layer's are bit for bit what they were."""
-    x, dt, A, Bm, Cm, D, s = draw(17, 3, 1, N=128)
-    leaf = jnp.stack([s, 2.0 * s, 3.0 * s])
-    active = jnp.asarray([True, False, True])
+# which of a leaf's four rows hold a sequence
+ACTIVITY = {"none": (0, 0, 0, 0), "all": (1, 1, 1, 1),
+            "leading_idle": (0, 0, 1, 1), "trailing_idle": (1, 1, 0, 0),
+            "alternating": (0, 1, 0, 1), "one_live_in_the_middle": (0, 0, 1, 0)}
+
+
+def bits(a):
+    return np.asarray(a).view(np.uint32)
+
+
+@pytest.mark.parametrize("rows", ACTIVITY.values(), ids=ACTIVITY.keys())
+def test_the_step_kernel_is_the_step_in_place(rows):
+    """The kernel over the WHOLE leaf (3 layers x 4 slots), layer 1,
+    under the TPU interpreter — which models the pipeline's buffers, so
+    an output buffer no step wrote goes back as NaN (plain
+    ``interpret=True`` reads the block anew every step and cannot see
+    that): the live rows' state and read-out are the step's; an idle
+    row's state — whatever a stale slot holds, ``inf`` and NaN too: it
+    is never moved, let alone multiplied by one — and every other
+    layer's are bit for bit what they were, with no row live as well."""
+    from jax.experimental.pallas import tpu as pltpu
+    from generativeaiexamples_tpu.ops.gated_delta import live_first
+
+    x, dt, A, Bm, Cm, D, s = draw(17, 4, 1, N=128)
+    active = jnp.asarray(rows, bool)
+    live, idle = np.asarray(active), ~np.asarray(active)
+    garbage = s.at[:, 0].set(jnp.inf).at[:, 1].set(jnp.nan)
+    leaf = jnp.stack([s, jnp.where(active[:, None, None, None], 2.0 * s,
+                                   garbage), 3.0 * s])
     dt1 = jnp.where(active[:, None], dt[:, 0], 0.0)
-    y, new = jax.jit(lambda *a: ssd.ssd_step_kernel(*a, interpret=True))(
-        x[:, 0], dt1, A, Bm[:, 0], Cm[:, 0], D, active, leaf, jnp.int32(1))
+    y, new = jax.jit(lambda *a: ssd.ssd_step_kernel(
+        *a, interpret=pltpu.InterpretParams(uninitialized_memory="nan")))(
+        x[:, 0], dt1, A, Bm[:, 0], Cm[:, 0], D, live_first(active), leaf,
+        jnp.int32(1))
     y0, s0 = ssd.ssd_step(x[:, 0], dt1, A, Bm[:, 0], Cm[:, 0], D, leaf[1])
-    close(y[active], y0[active])
-    close(new[1][active], s0[active], 1e-6)
-    np.testing.assert_array_equal(new[1, 1], leaf[1, 1])
-    np.testing.assert_array_equal(new[0], leaf[0])
-    np.testing.assert_array_equal(new[2], leaf[2])
-
-
-def test_an_idle_rows_garbage_stays_garbage():
-    """A stale slot may hold anything (inf): the idle row is copied, not
-    multiplied by one."""
-    x, dt, A, Bm, Cm, D, s = draw(19, 2, 1, N=128)
-    leaf = s.at[1].set(jnp.inf)[None]
-    active = jnp.asarray([True, False])
-    _, new = ssd.ssd_step_kernel(
-        x[:, 0], jnp.where(active[:, None], dt[:, 0], 0.0), A, Bm[:, 0],
-        Cm[:, 0], D, active, leaf, jnp.int32(0), interpret=True)
-    np.testing.assert_array_equal(new[0, 1], leaf[0, 1])
-    assert bool(jnp.all(jnp.isfinite(new[0, 0])))
+    if live.any():
+        close(y[live], y0[live])
+        close(new[1][live], s0[live], 1e-6)
+    np.testing.assert_array_equal(bits(new[1])[idle], bits(leaf[1])[idle])
+    np.testing.assert_array_equal(bits(new[0]), bits(leaf[0]))
+    np.testing.assert_array_equal(bits(new[2]), bits(leaf[2]))
+    # an idle row reads out nothing: what is left is the skip
+    np.testing.assert_array_equal(y[idle], (D[:, None] * x[:, 0])[idle])
 
 
 def test_the_kernel_is_taken_only_where_it_fits():
